@@ -14,12 +14,6 @@ Provides the capability surface of DeepSpeed (reference: deepspeed/__init__.py:6
 
 from deepspeed_tpu.version import __version__, __version_info__
 
-# imported for its side effect as well as the shims: jax_compat flips
-# jax_threefry_partitionable ON so RNG draws are sharding-invariant —
-# it must happen before the first engine births params sharded, i.e.
-# at package import, not at the first lazy shard_map use
-from deepspeed_tpu.utils import jax_compat  # noqa: F401
-
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine
 from deepspeed_tpu.accelerator import get_accelerator

@@ -241,12 +241,18 @@ def sharding_pin_scope(enabled: bool):
         _PIN_SHARDINGS.reset(token)
 
 
+def pins_enabled() -> bool:
+    """False while tracing inside ``sharding_pin_scope(False)`` — the
+    program being traced is single-device and must not see the mesh."""
+    return _PIN_SHARDINGS.get()
+
+
 def pin_sharding(x, sharding):
     """``with_sharding_constraint`` that ``sharding_pin_scope(False)``
     turns into a no-op — every intermediate-layout pin in model code
     should route through this so single-device serving programs can
     shed the training-mesh pins at trace time."""
-    if not _PIN_SHARDINGS.get():
+    if not pins_enabled():
         return x
     import jax.lax
     return jax.lax.with_sharding_constraint(x, sharding)

@@ -38,7 +38,7 @@ def _pipe_sharding():
     all-auto concrete mesh."""
     from deepspeed_tpu.utils.jax_compat import get_abstract_mesh
     cur = get_abstract_mesh()
-    if cur is not None and not cur.empty:
+    if not cur.empty:
         return NamedSharding(cur, P(PIPE_AXIS))
     return NamedSharding(get_topology().mesh, P(PIPE_AXIS))
 

@@ -710,8 +710,8 @@ class ContinuousBatchingScheduler:
                             weight_passes=passes))
                     publish_report(self.metrics.registry, report)
                 except Exception as e:      # noqa: BLE001 — best-effort
-                    logger.debug(f"costmodel: {vname} analysis "
-                                 f"failed: {e}")
+                    logger.warning(f"costmodel: {vname} analysis "
+                                   f"failed: {e}")
             return jitted(*args)
 
         return wrapper

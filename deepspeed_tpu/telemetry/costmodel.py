@@ -138,13 +138,13 @@ def _aval_bytes(aval) -> int:
 
 def _sub_jaxprs(eqn):
     """Every (Closed)Jaxpr reachable from an equation's params."""
-    import jax
+    from deepspeed_tpu.utils.jax_compat import ClosedJaxpr, Jaxpr
     for v in eqn.params.values():
         items = v if isinstance(v, (tuple, list)) else (v,)
         for it in items:
-            if isinstance(it, jax.core.ClosedJaxpr):
+            if isinstance(it, ClosedJaxpr):
                 yield it.jaxpr
-            elif isinstance(it, jax.core.Jaxpr):
+            elif isinstance(it, Jaxpr):
                 yield it
 
 

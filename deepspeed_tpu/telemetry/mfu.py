@@ -19,9 +19,12 @@ from typing import Optional
 PEAK_FLOPS_ENV = "DS_PEAK_FLOPS"
 
 #: dense bf16 peak FLOPs per chip by device-kind substring (lowercase).
-#: Sources: published TPU system specs (per-chip, not per-core).
+#: Sources: published TPU system specs (per-chip, not per-core).  A v5e
+#: reports ``device_kind == "TPU v5 lite"``; its 197 TFLOP/s bf16 is the
+#: Google Cloud "TPU v5e" page's figure.
 PEAK_FLOPS_BY_KIND = {
     "v5p": 459e12,
+    "v5 lite": 197e12,
     "v5e": 197e12,
     "v5litepod": 197e12,
     "v4": 275e12,

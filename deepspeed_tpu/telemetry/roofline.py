@@ -37,9 +37,12 @@ ICI_GBPS_ENV = "DS_ICI_GBPS"
 DCN_GBPS_ENV = "DS_DCN_GBPS"
 
 #: HBM bandwidth per chip (GB/s) by device-kind substring (lowercase).
-#: Sources: published TPU system specs (per-chip).
+#: Sources: published TPU system specs (per-chip); "v5 lite" is the
+#: device_kind a v5e reports (Google Cloud "TPU v5e" page: 819 GB/s HBM,
+#: 1,600 Gbit/s ICI).
 HBM_GBPS_BY_KIND = {
     "v5p": 2765.0,
+    "v5 lite": 819.0,
     "v5e": 819.0,
     "v5litepod": 819.0,
     "v4": 1228.0,
@@ -53,6 +56,7 @@ HBM_GBPS_BY_KIND = {
 #: v4 2400 Gbps, v5e 1600 Gbps, v5p 4800 Gbps), /8 to GB/s.
 ICI_GBPS_BY_KIND = {
     "v5p": 600.0,
+    "v5 lite": 200.0,
     "v5e": 200.0,
     "v5litepod": 200.0,
     "v4": 300.0,

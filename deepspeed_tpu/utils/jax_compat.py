@@ -1,94 +1,12 @@
-"""jax version-compatibility shims.
+"""The jax names the package routes through one place.
 
-The container bakes a jax where ``shard_map`` still lives in
-``jax.experimental.shard_map`` and spells its replication-check kwarg
-``check_rep``; current jax exposes ``jax.shard_map`` with ``check_vma``.
-The codebase is written against the current API — every ``shard_map``
-import routes through here so both toolchains drive the same call sites.
+Written for the one installation there is (jax 0.9.0): ``jax.shard_map``
+with ``check_vma`` / ``axis_names``, ``lax.axis_size``,
+``jax.sharding.get_abstract_mesh`` and ``jax.extend.core`` for the jaxpr
+types.  Call sites import them from here so a future jax that moves one
+is a one-file edit.
 """
-import jax
-
-try:                                    # current jax
-    from jax import shard_map as _shard_map
-    _CURRENT = True
-except ImportError:                     # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CURRENT = False
-
-# Sharding-invariant RNG.  This jax still defaults
-# ``jax_threefry_partitionable`` to False, under which a jitted
-# ``jax.random.*`` draw with a SHARDED out_sharding produces DIFFERENT
-# bits than the same draw replicated — so an engine that births params
-# sharded (out_shardings=param_shardings at init) silently initializes
-# e.g. the vocab-parallel embedding differently under TP than under
-# plain DP, breaking TP↔DP train parity at step 0 (the frozen tier-1
-# TP-parity failures traced back to exactly this).  The partitionable
-# formulation computes the same counters per element regardless of
-# partitioning, making generation sharding-invariant; current jax
-# defaults it to True.  Values differ from the legacy stream, which is
-# fine — nothing persists RNG-derived expectations across processes.
-try:
-    import os as _os
-    if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
-        # respect an explicit user choice (env var); otherwise flip —
-        # bystander code importing this package does see a different
-        # (but valid) random stream than it would without the import
-        jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:                  # future jax: flag removed (on
-    pass                                # by default, no-op)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-              axis_names=None, **kw):
-    """``axis_names`` (current API: the axes mapped MANUALLY) translates
-    to the old API's complement kwarg ``auto`` (the axes left to the
-    partitioner)."""
-    if _CURRENT:
-        kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-    else:
-        kw["check_rep"] = check_vma
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-#: partially-auto shard_map (manual over some mesh axes, partitioner-auto
-#: over others) is only sound on current jax — the old experimental
-#: lowering CHECK-aborts the PROCESS inside backend_compile when the auto
-#: set contains a >1-sized axis.  Callers gate their partial-auto tiers on
-#: this and fall back to fully-automatic GSPMD.
-HAS_PARTIAL_AUTO_SHARD_MAP = _CURRENT
-
-#: this jaxlib's CPU backend has no cross-process collective
-#: implementation AT ALL — any multi-process computation (even
-#: multihost_utils.sync_global_devices' psum) dies with
-#: "INVALID_ARGUMENT: Multiprocess computations aren't implemented on
-#: the CPU backend".  Current jax runs CPU cross-host collectives over
-#: gloo.  The multiprocess parity tests gate on this.
-HAS_MULTIPROCESS_CPU_COLLECTIVES = _CURRENT
-
-
-def get_abstract_mesh():
-    """Current trace context's abstract mesh, or None when this jax
-    predates ``jax.sharding.get_abstract_mesh``.  None is always sound on
-    old jax: the only caller that needs the trace-context mesh is the
-    partial-auto shard_map tier, which is gated off there — callers fall
-    back to the concrete topology mesh."""
-    import jax
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    return None
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis inside a shard_map/pmap body —
-    ``jax.lax.axis_size`` on current jax; recovered from the trace-time
-    axis env on older jax (still a python int, not a tracer)."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax._src import core
-    return core.get_axis_env().axis_size(axis_name)
+from jax import shard_map  # noqa: F401
+from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: F401
+from jax.lax import axis_size  # noqa: F401
+from jax.sharding import get_abstract_mesh  # noqa: F401

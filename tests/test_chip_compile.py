@@ -1,0 +1,169 @@
+"""Compiles for a TPU v5e that is described, not attached (the chip's
+compiler is installed on CPU-only boxes): the training path's kernels at
+GPT-2 760M width go through Mosaic, the data-sharded flash kernel goes
+through the partitioner, and the library knows the chip's peaks.
+
+A compile that passes is not a chip run — it says nothing about results
+or times.  ``chip_smoke.py`` is the run."""
+import os
+import subprocess
+import sys
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KERNEL = "tpu_custom_call"
+B, S, H, HD = 12, 1024, 16, 96          # gpt2-760m micro-batch 12, seq 1024
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e 2x2 host.  Executables built
+    for them land in the persistent cache but cannot be read back without
+    a chip, so the cache is off while this module runs."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:      # no libtpu / no topology support here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield devices
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def _arg(dev, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(dev))
+
+
+def _sum_sq(fn):
+    return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+
+
+def _ds_flash(q, k, v):
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import \
+        ds_flash_attention
+    return ds_flash_attention(q, k, v, causal=True)
+
+
+def _stock_flash(q, k, v):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=True)
+
+
+def _quantize(x):
+    from deepspeed_tpu.ops.pallas.quantization import _pallas_quantize_2d
+    return _pallas_quantize_2d(x)
+
+
+def _qgemm(x, q, s):
+    from deepspeed_tpu.ops.pallas.qgemm import ds_qgemm
+    return ds_qgemm(x, q, s, interpret=False)
+
+
+def _decode(q, k, v, n, ks=None, vs=None):
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        decode_attention_pallas
+    return decode_attention_pallas(q, k, v, n, k_scale=ks, v_scale=vs)
+
+
+_QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
+_CACHE = (8, 1024, 16, 96)
+KERNEL_CASES = {
+    "ds_flash_fwd": (_ds_flash, _QKV),
+    "ds_flash_fwd_bwd": (jax.grad(_sum_sq(_ds_flash), (0, 1, 2)), _QKV),
+    "stock_flash_fwd": (_stock_flash, _QKV),
+    "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
+                            _QKV),
+    "quantize_bf16": (_quantize, [((1024, 1536), jnp.bfloat16)]),
+    "quantize_f32": (_quantize, [((1024, 1536), jnp.float32)]),
+    "qgemm_m8": (_qgemm, [((8, 1536), jnp.bfloat16),
+                          ((1536, 6144), jnp.int8),
+                          ((1536, 24), jnp.float32)]),
+    "qgemm_m512": (_qgemm, [((512, 1536), jnp.bfloat16),
+                            ((1536, 6144), jnp.int8),
+                            ((1536, 24), jnp.float32)]),
+    "decode_bf16": (_decode, [((8, 16, 96), jnp.bfloat16),
+                              (_CACHE, jnp.bfloat16), (_CACHE, jnp.bfloat16),
+                              ((8,), jnp.int32)]),
+    "decode_int8": (_decode, [((8, 16, 96), jnp.bfloat16),
+                              (_CACHE, jnp.int8), (_CACHE, jnp.int8),
+                              ((8,), jnp.int32),
+                              (_CACHE[:3], jnp.float32),
+                              (_CACHE[:3], jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    fn, args = KERNEL_CASES[case]
+    compiled = jax.jit(fn).lower(
+        *(_arg(v5e[0], shape, dtype) for shape, dtype in args)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("manual_outside", [False, True],
+                         ids=["gspmd", "inside_data_manual_shard_map"])
+def test_data_sharded_flash_grad_partitions(v5e, manual_outside):
+    """jax.grad of causal_attention with the batch sharded over data=4:
+    bare, the partitioner refuses the Mosaic call; through the shard_map
+    wrap each chip runs the kernel on its B/4 slice.  The second case
+    nests it inside a shard_map already manual over data (the quantized
+    gradient-exchange tier), where the wrap maps only the axes left."""
+    from deepspeed_tpu.comm.mesh import MeshTopology, set_topology
+    from deepspeed_tpu.ops.attention import causal_attention
+    from deepspeed_tpu.utils.jax_compat import shard_map
+    topo = MeshTopology(devices=v5e)
+    set_topology(topo)
+    assert topo.mesh.shape["data"] == 4
+    batch_axes = ("data", "hpz") if manual_outside \
+        else topo.data_parallel_axes
+    loss = _sum_sq(lambda q, k, v: causal_attention(q, k, v, impl="flash"))
+    if manual_outside:
+        def loss(q, k, v, local=loss):
+            return shard_map(
+                lambda *a: jax.lax.psum(local(*a), batch_axes),
+                mesh=topo.mesh, in_specs=(P(batch_axes),) * 3,
+                out_specs=P(), axis_names=set(batch_axes),
+                check_vma=False)(q, k, v)
+    x = jax.ShapeDtypeStruct(
+        (4 * B, S, H, HD), jnp.bfloat16,
+        sharding=NamedSharding(topo.mesh, P(batch_axes)))
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).compile() \
+        .as_text()
+    calls = [l for l in text.splitlines() if KERNEL in l]
+    assert calls
+    assert all(f"bf16[{B},{H},{S},{HD}]" in l for l in calls), calls[0][:200]
+
+
+def test_library_knows_the_chips_peaks(v5e):
+    from deepspeed_tpu.telemetry.mfu import peak_flops_per_device
+    from deepspeed_tpu.telemetry.roofline import (hbm_bytes_per_s,
+                                                  ici_bytes_per_s)
+    assert v5e[0].device_kind == "TPU v5 lite"
+    assert peak_flops_per_device(v5e[0], env={}) == 197e12
+    assert hbm_bytes_per_s(v5e[0], env={}) == 819e9
+    assert ici_bytes_per_s(v5e[0], env={}) == 200e9
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_measurement_scripts_refuse_the_cpu(script):
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, script)], capture_output=True,
+        text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert '"metric"' not in out.stdout
+    assert "TPU" in out.stderr

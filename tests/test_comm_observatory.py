@@ -29,7 +29,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from deepspeed_tpu.utils.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu
@@ -73,7 +73,7 @@ def test_dp_grad_allreduce_parity_acceptance():
         return jax.lax.psum(g, "data")
 
     f = shard_map(grad_shard, mesh=mesh, in_specs=(P(), P("data")),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     rep = costmodel.analyze_fn(f, w, x, name="train/dp_grad")
     row = rep.collectives["all_reduce|data|float32"]
     param_bytes = w.size * w.dtype.itemsize
@@ -100,7 +100,7 @@ def test_collective_family_accounting():
 
     x = jnp.zeros((n * 4,), jnp.float32)
     f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     rep = costmodel.analyze_fn(f, x, name="probe/collectives")
     shard_bytes = (x.size // n) * x.dtype.itemsize
     ag = rep.collectives["all_gather|data|float32"]

@@ -6,13 +6,11 @@ The Pallas kernel runs in interpret mode on the CPU test mesh; numeric
 parity is asserted against the XLA reference implementation, and the cached
 generate path is asserted token-identical to the O(S²) no-cache oracle.
 """
-import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental import pallas as pl
 
 import deepspeed_tpu.ops.pallas.decode_attention as da
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
@@ -20,12 +18,6 @@ from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.sampling import apply_top_k, apply_top_p, sample
 from deepspeed_tpu.models.gpt2 import gpt2_model
 from deepspeed_tpu.models.llama import llama_model
-
-
-@pytest.fixture
-def interpret_pallas(monkeypatch):
-    monkeypatch.setattr(
-        pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True))
 
 
 @pytest.mark.parametrize("B,H,KV,hd,Smax,bs", [

@@ -359,16 +359,6 @@ def test_pallas_block_sparse_bwd_noncausal_and_empty_rows():
 
 # ------------------------------------------- from-scratch flash kernel
 
-import functools
-from jax.experimental import pallas as pl
-
-
-@pytest.fixture
-def interpret_pallas(monkeypatch):
-    monkeypatch.setattr(
-        pl, "pallas_call", functools.partial(pl.pallas_call,
-                                             interpret=True))
-
 def _dense_ref_attn(q, k, v, seg=None, causal=True):
     import jax
     import jax.numpy as jnp

@@ -26,22 +26,6 @@ import textwrap
 import numpy as np
 import pytest
 
-from deepspeed_tpu.utils.jax_compat import HAS_MULTIPROCESS_CPU_COLLECTIVES
-
-#: env-blocked on this jaxlib (ROADMAP item 6 triage, PR 7): the CPU
-#: backend has NO cross-process collective implementation — the worker
-#: dies at the bootstrap barrier inside multihost_utils'
-#: broadcast_one_to_all psum with "INVALID_ARGUMENT: Multiprocess
-#: computations aren't implemented on the CPU backend", before any
-#: deepspeed_tpu code runs.  Repro: drop the marker and run any leg —
-#: both workers exit 1 with that XlaRuntimeError in the first
-#: comm.barrier.  Current jax runs CPU cross-host collectives over
-#: gloo, where these pass.
-requires_multiprocess_cpu = pytest.mark.skipif(
-    not HAS_MULTIPROCESS_CPU_COLLECTIVES,
-    reason="this jaxlib's CPU backend cannot run multi-process "
-           "computations (no collectives impl; see module note)")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 WORKER = textwrap.dedent("""
@@ -159,14 +143,12 @@ def _run_two_process(leg, tmp_path):
     return losses[0]
 
 
-@requires_multiprocess_cpu
 def test_two_process_zero2_matches_single_process(devices8, tmp_path):
     ref = _reference_losses("dp")
     got = _run_two_process("dp", tmp_path)
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
-@requires_multiprocess_cpu
 def test_two_process_tensor_parallel_parity(devices8, tmp_path):
     """tp=2 × dp=4 over two processes: the TP all-reduces run inside the
     compiled SPMD program while the dp gradient reduction crosses the
@@ -176,7 +158,6 @@ def test_two_process_tensor_parallel_parity(devices8, tmp_path):
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
-@requires_multiprocess_cpu
 def test_two_process_pipeline_parity(devices8, tmp_path):
     """pp=2 × dp=2 over two processes: the pipe axis is outermost, so
     stage 0 lives entirely on process 0 and stage 1 on process 1 — every

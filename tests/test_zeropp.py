@@ -7,27 +7,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import (HAS_PARTIAL_AUTO_SHARD_MAP,
-                                            shard_map)
+from deepspeed_tpu.utils.jax_compat import shard_map
 
 import deepspeed_tpu
 
-#: environment-blocked (ROADMAP hygiene item 6): these tests assert the
-#: qgZ exchange ENGAGES on meshes with a wide model/pipe axis, but the
-#: tier needs partially-auto shard_map (manual over data/hpz, auto over
-#: the rest), and this jax's experimental lowering CHECK-aborts the
-#: PROCESS inside backend_compile when any auto axis is >1 (reproduced
-#: in PR 2; see utils/jax_compat.HAS_PARTIAL_AUTO_SHARD_MAP).  The
-#: engine therefore gates the tier off here — _get_qgz_plan() returns
-#: None by design, and the engage assert can never hold.  Repro: flip
-#: the gate in runtime/engine._get_qgz_plan and run any of these — the
-#: worker dies with a CHECK failure, not a python error.  They pass on
-#: current jax (where HAS_PARTIAL_AUTO_SHARD_MAP is True).
-requires_partial_auto = pytest.mark.skipif(
-    not HAS_PARTIAL_AUTO_SHARD_MAP,
-    reason="qgZ-on-wide-mesh needs partially-auto shard_map; this jax's "
-           "lowering CHECK-aborts the process, so the engine gates the "
-           "tier off (env-blocked; see module note)")
 from deepspeed_tpu.ops.pallas.quantization import (
     block_quantize_int8, block_dequantize_int8)
 from deepspeed_tpu.runtime.zero.zeropp import quantized_psum_scatter
@@ -276,7 +259,6 @@ def test_qgz_int8_on_the_wire(devices8):
     assert any("s8[" in l for l in comm_lines), comm_lines[:5]
 
 
-@requires_partial_auto
 def test_qgz_engages_on_hybrid_tp_mesh(devices8):
     """TP×DP mesh: the generalized tier is manual over the data axis and
     auto over model — qgZ engages (round-2 VERDICT item 1: no more
@@ -411,7 +393,6 @@ def _pipe_train(engine, gas, steps, seed):
     return out
 
 
-@requires_partial_auto
 def test_qgz_under_pipeline_gpipe(devices8):
     """round-3 VERDICT item 4: the quantized gradient exchange composes
     with the scanned-GPipe pipeline (the tier's shard_map keeps the pipe
@@ -439,7 +420,6 @@ def test_qgz_under_pipeline_gpipe(devices8):
     assert any("s8[" in l for l in comm), comm[:5]
 
 
-@requires_partial_auto
 def test_qgz_under_pipeline_chunked(devices8):
     """Chunked GPipe (num_pipe_buffers) + qgZ: the tier scans pipeline
     chunks and still tracks the dense run."""
