@@ -23,6 +23,8 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models.model import (Model, maybe_stream, qdot,
                                         resolve_size)
 from deepspeed_tpu.ops.attention import causal_attention
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_MLP)
 
 
 @dataclass(frozen=True)
@@ -229,6 +231,7 @@ def _lora_add(y, lora, name, h):
     return lora_add(y, lora, name, h)
 
 
+@jax.named_scope(SCOPE_ATTN)
 def _block_qkv(x, layer, config: GPT2Config, lora=None):
     """LN1 + QKV projection; x [B, S, D] -> q/k/v [B, S, H, hd].
     ``lora(name, h)`` is the per-layer gather-LoRA callback (ISSUE 20)."""
@@ -244,8 +247,14 @@ def _block_qkv(x, layer, config: GPT2Config, lora=None):
 
 def _block_finish(x, attn, layer, config: GPT2Config, lora=None):
     """Post-attention half: proj + residual + MLP; x/attn [B, S, D]."""
-    proj = qdot(attn, layer["proj_w"]) + layer["proj_b"].astype(x.dtype)
-    x = x + _lora_add(proj, lora, "proj_w", attn)
+    with jax.named_scope(SCOPE_ATTN):
+        proj = qdot(attn, layer["proj_w"]) + layer["proj_b"].astype(x.dtype)
+        x = x + _lora_add(proj, lora, "proj_w", attn)
+    with jax.named_scope(SCOPE_MLP):
+        return _block_mlp(x, layer, config, lora)
+
+
+def _block_mlp(x, layer, config: GPT2Config, lora=None):
     h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], config.layer_norm_eps)
     h = _lora_add(qdot(h, layer["mlp_in_w"])
                   + layer["mlp_in_b"].astype(h.dtype),
@@ -260,13 +269,15 @@ def _block_finish(x, attn, layer, config: GPT2Config, lora=None):
     return x
 
 
+@jax.named_scope(SCOPE_BLOCK)
 def _block(x, layer, config: GPT2Config, rng=None, segment_ids=None):
     """One transformer block; shapes [B, S, D]."""
     B, S, D = x.shape
     q, kk, v = _block_qkv(x, layer, config)
-    attn = causal_attention(q, kk, v, impl=config.attention_impl,
-                            segment_ids=segment_ids)
-    attn = attn.reshape(B, S, D)
+    with jax.named_scope(SCOPE_ATTN):
+        attn = causal_attention(q, kk, v, impl=config.attention_impl,
+                                segment_ids=segment_ids)
+        attn = attn.reshape(B, S, D)
     # named residual: the save_attn remat policy keeps attention outputs and
     # recomputes the (cheap, MXU-bound) linear parts in the backward pass —
     # re-running the flash kernel is the expensive half of full remat
@@ -283,7 +294,9 @@ def forward(params: dict, batch: dict, config: GPT2Config, rng=None):
     tokens = batch["input_ids"]
     B, S = tokens.shape
     dtype = jnp.dtype(config.dtype)
-    x = params["wte"].astype(dtype)[tokens] + params["wpe"].astype(dtype)[:S]
+    with jax.named_scope(SCOPE_EMBED):
+        x = (params["wte"].astype(dtype)[tokens]
+             + params["wpe"].astype(dtype)[:S])
 
     # stream-inside-remat: with ZeRO-Infinity param offload the layer slice is
     # transferred host→device *inside* the remat boundary, so backward
@@ -302,9 +315,10 @@ def forward(params: dict, batch: dict, config: GPT2Config, rng=None):
     from deepspeed_tpu.models.model import scan_blocks
     x = scan_blocks(block_fn, x, params["blocks"], rng, batch,
                     config.num_layers, allow_ltd=seg is None)
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
-                    config.layer_norm_eps)
-    logits = x @ params["wte"].astype(dtype).T   # tied embedding
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
+                        config.layer_norm_eps)
+        logits = x @ params["wte"].astype(dtype).T   # tied embedding
     return logits
 
 
@@ -559,6 +573,7 @@ def count_params(config: GPT2Config) -> int:
     return V * D + S * D + L * per_layer + 2 * D
 
 
+@jax.named_scope(SCOPE_EMBED)
 def embed(params, batch, config: GPT2Config):
     tokens = batch["input_ids"]
     dtype = jnp.dtype(config.dtype)
@@ -566,6 +581,7 @@ def embed(params, batch, config: GPT2Config):
     return params["wte"].astype(dtype)[tokens] + params["wpe"].astype(dtype)[:S]
 
 
+@jax.named_scope(SCOPE_HEAD_LOSS)
 def head(params, x, config: GPT2Config):
     dtype = jnp.dtype(config.dtype)
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],

@@ -350,10 +350,15 @@ class Model:
 def _default_lm_loss(apply_fn):
     import jax.numpy as jnp
     import optax
+    from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
 
     def loss_fn(params, batch, rng=None):
-        tokens = batch["input_ids"]
         logits = apply_fn(params, batch, rng)
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            return token_loss(logits, batch)
+
+    def token_loss(logits, batch):
+        tokens = batch["input_ids"]
         targets = tokens[:, 1:]
         logits = logits[:, :-1]
         mask = batch.get("attention_mask")

@@ -349,7 +349,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
         in_specs += [pl.BlockSpec((1, bq, 1), lambda b, h, i: (b, i, 0)),
                      pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0))]
     oT, lse = pl.pallas_call(
-        kernel, grid=(B, H, S // bq), **_ikw,
+        kernel, grid=(B, H, S // bq), name="ds_flash_fwd", **_ikw,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
@@ -434,7 +434,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         dq_specs += [pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
                      pl.BlockSpec((1, S, 1), lambda b, h, i: (b, 0, 0))]
     dkT, dvT = pl.pallas_call(
-        dkv_kernel, grid=(B, S // bk, H), **_ikw,
+        dkv_kernel, grid=(B, S // bk, H), name="ds_flash_bwd_dkv", **_ikw,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd),
@@ -449,7 +449,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         _dq_kernel, sm_scale=sm, causal=causal, block_q=bq, block_k=bk,
         seq_len=S, has_seg=has_seg)
     dqT = pl.pallas_call(
-        dq_kernel, grid=(B, H, S // bq), **_ikw,
+        dq_kernel, grid=(B, H, S // bq), name="ds_flash_bwd_dq", **_ikw,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(

@@ -20,6 +20,7 @@ API parity:
 """
 import os
 import time
+import weakref
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -35,6 +36,9 @@ from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.zero.policy import ZeroShardingPolicy
 from deepspeed_tpu.runtime.fp16.loss_scaler import (
     create_loss_scaler, has_overflow, update_scale)
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
+    register_program)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
     SynchronizedWallClockTimer, ThroughputTimer, TRAIN_BATCH_TIMER)
@@ -72,6 +76,15 @@ def _np_fast_cast(x: np.ndarray, dtype):
             out = np.where(nonfinite, trunc, out)
         return out.view(dtype)
     return x.astype(dtype)
+
+
+def _abstract(x, sharding=None):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
+def _abstract_placed(x):
+    """Shape, dtype and the sharding the array already has."""
+    return _abstract(x, x.sharding)
 
 
 def _global_norm(tree):
@@ -795,6 +808,7 @@ class DeepSpeedEngine:
         from deepspeed_tpu.telemetry.memory import memory_enabled
         self._mem_on = tcfg.enabled and memory_enabled(tcfg.memory)
         self._mem_compiled_done = False
+        self._program_map_registered = False
         if self._mem_on:
             try:
                 from deepspeed_tpu.telemetry.iostat import get_iostat
@@ -1672,9 +1686,10 @@ class DeepSpeedEngine:
                 else:
                     dense_now = count <= onebit["freeze_step"]
                     new_vi, new_vc = ob["var_interval"], ob["var_counter"]
-                loss_sum, grads, new_ob = qgz_fn(
-                    params, stacked_batch, rng, scale, cs,
-                    dense_now, ob)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss_sum, grads, new_ob = qgz_fn(
+                        params, stacked_batch, rng, scale, cs,
+                        dense_now, ob)
                 grads = policy.constrain_grads(grads, grad_specs)
                 new_state, metrics = self._apply_grads(state, grads)
                 # overflow steps roll back every 1-bit residual/counter
@@ -1701,17 +1716,21 @@ class DeepSpeedEngine:
                 return new_state, metrics
 
             if qgz_fn is not None:
-                loss_sum, grads = qgz_fn(params, stacked_batch, rng, scale,
-                                         cs)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss_sum, grads = qgz_fn(params, stacked_batch, rng,
+                                             scale, cs)
                 grads = policy.constrain_grads(grads, grad_specs)
             else:
                 def micro(carry, mb):
                     grads_acc, loss_acc = carry
-                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                        params, mb, rng, scale / gas, cs)
-                    grads = _tree_cast(grads, self.grad_dtype)
-                    grads = policy.constrain_grads(grads, grad_specs)
-                    grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+                    with jax.named_scope(SCOPE_FWD_BWD):
+                        loss, grads = jax.value_and_grad(
+                            self._scaled_loss_fn)(
+                                params, mb, rng, scale / gas, cs)
+                    with jax.named_scope(SCOPE_ACCUMULATE):
+                        grads = _tree_cast(grads, self.grad_dtype)
+                        grads = policy.constrain_grads(grads, grad_specs)
+                        grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
                     return (grads_acc, loss_acc + loss), None
 
                 zero_grads = jax.tree.map(
@@ -1792,8 +1811,9 @@ class DeepSpeedEngine:
                 scale = (state["scaler"].cur_scale if fp16
                          else jnp.float32(1.0))
                 cs = state["step"] if use_compress else None
-                loss_sum, grads = qgz_fn(params, stacked_batch, rng, scale,
-                                         cs)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss_sum, grads = qgz_fn(params, stacked_batch, rng,
+                                             scale, cs)
                 grads = policy.constrain_grads(grads, grad_specs)
                 new_state, metrics = self._apply_grads(state, grads)
                 metrics["loss"] = loss_sum / scale
@@ -1815,8 +1835,9 @@ class DeepSpeedEngine:
             scale = state["scaler"].cur_scale if fp16 else jnp.float32(1.0)
 
             if not chunked:
-                loss, grads = jax.value_and_grad(loss_of_chunk)(
-                    params, stacked_batch, rng, scale, cs)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss, grads = jax.value_and_grad(loss_of_chunk)(
+                        params, stacked_batch, rng, scale, cs)
             else:
                 n_chunks = gas // n_buffers
                 chunks = jax.tree.map(
@@ -1825,11 +1846,14 @@ class DeepSpeedEngine:
 
                 def body(carry, chunk):
                     g_acc, l_acc = carry
-                    l, g = jax.value_and_grad(loss_of_chunk)(
-                        params, chunk, rng, scale / n_chunks, cs)
-                    g = _tree_cast(g, self.grad_dtype)
-                    g = policy.constrain_grads(g, grad_specs)
-                    return (jax.tree.map(jnp.add, g_acc, g), l_acc + l), None
+                    with jax.named_scope(SCOPE_FWD_BWD):
+                        l, g = jax.value_and_grad(loss_of_chunk)(
+                            params, chunk, rng, scale / n_chunks, cs)
+                    with jax.named_scope(SCOPE_ACCUMULATE):
+                        g = _tree_cast(g, self.grad_dtype)
+                        g = policy.constrain_grads(g, grad_specs)
+                        g_acc = jax.tree.map(jnp.add, g_acc, g)
+                    return (g_acc, l_acc + l), None
 
                 zeros = jax.tree.map(
                     lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
@@ -1875,10 +1899,11 @@ class DeepSpeedEngine:
                 return (model.head_loss_fn(p, y, b).astype(jnp.float32)
                         * (scale / gas))
 
-            loss_sum, grads = pipeline_1f1b_loss_and_grad(
-                lambda h, lp: model.block_fn(lp, h), model.embed_fn,
-                head_loss, cparams, model.blocks_key, stacked_batch,
-                n_stages)
+            with jax.named_scope(SCOPE_FWD_BWD):
+                loss_sum, grads = pipeline_1f1b_loss_and_grad(
+                    lambda h, lp: model.block_fn(lp, h), model.embed_fn,
+                    head_loss, cparams, model.blocks_key, stacked_batch,
+                    n_stages)
             grads = _tree_cast(grads, self.grad_dtype)
             grads = policy.constrain_grads(grads, grad_specs)
             new_state, metrics = self._apply_grads(state, grads)
@@ -1887,6 +1912,7 @@ class DeepSpeedEngine:
 
         return train_step
 
+    @jax.named_scope(SCOPE_OPTIMIZER)
     def _apply_grads(self, state, grads):
         """Shared epilogue: unscale, overflow check, update, skip-on-overflow."""
         fp16 = self._config.fp16.enabled
@@ -2030,13 +2056,16 @@ class DeepSpeedEngine:
                 scale = (state["scaler"].cur_scale
                          if self._config.fp16.enabled else jnp.float32(1.0))
                 gas = self.gradient_accumulation_steps()
-                loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                    state["params"], batch, rng, scale / gas,
-                    state["step"] if self._compression_plans is not None
-                    else None)
-                grads = _tree_cast(grads, self.grad_dtype)
-                grads = self.zero_policy.constrain_grads(grads, self.grad_specs)
-                grads = jax.tree.map(jnp.add, grads_acc, grads)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
+                        state["params"], batch, rng, scale / gas,
+                        state["step"] if self._compression_plans is not None
+                        else None)
+                with jax.named_scope(SCOPE_ACCUMULATE):
+                    grads = _tree_cast(grads, self.grad_dtype)
+                    grads = self.zero_policy.constrain_grads(
+                        grads, self.grad_specs)
+                    grads = jax.tree.map(jnp.add, grads_acc, grads)
                 return loss / scale * gas, grads
             gos = self._grad_out_shardings()
             fn = jax.jit(
@@ -2055,12 +2084,15 @@ class DeepSpeedEngine:
 
                 def micro(carry, mb):
                     grads_acc, loss_acc = carry
-                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                        params, mb, rng, scale / gas)
-                    grads = _tree_cast(grads, self.grad_dtype)
-                    grads = policy.constrain_grads(grads, grad_specs)
-                    return (jax.tree.map(jnp.add, grads_acc, grads),
-                            loss_acc + loss), None
+                    with jax.named_scope(SCOPE_FWD_BWD):
+                        loss, grads = jax.value_and_grad(
+                            self._scaled_loss_fn)(
+                                params, mb, rng, scale / gas)
+                    with jax.named_scope(SCOPE_ACCUMULATE):
+                        grads = _tree_cast(grads, self.grad_dtype)
+                        grads = policy.constrain_grads(grads, grad_specs)
+                        grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+                    return (grads_acc, loss_acc + loss), None
 
                 zeros = jax.tree.map(
                     lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
@@ -2079,8 +2111,9 @@ class DeepSpeedEngine:
             def grad_micro(state, mb, rng):
                 scale = (state["scaler"].cur_scale
                          if self._config.fp16.enabled else jnp.float32(1.0))
-                loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                    state["params"], mb, rng, scale / gas)
+                with jax.named_scope(SCOPE_FWD_BWD):
+                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
+                        state["params"], mb, rng, scale / gas)
                 # grads keep the params' storage dtype: a full-tensor fp32
                 # convert would materialise each stacked leaf on device (8 GB
                 # per MLP leaf at 6.7B); the streamed optimizer upcasts per
@@ -2093,6 +2126,7 @@ class DeepSpeedEngine:
         elif name == "grad_acc":
             # gas accumulation for the streamed-optimizer path; leaves bounce
             # through device whole-leaf (transient HBM = largest leaf)
+            @jax.named_scope(SCOPE_ACCUMULATE)
             def acc_fn(a, b):
                 return jax.tree.map(jnp.add, a, b)
             gos = self._grad_out_shardings()
@@ -2278,9 +2312,10 @@ class DeepSpeedEngine:
         step = self.global_steps + 1
         t0 = time.perf_counter()
         span_args = {"step": step}
-        if self._step_cost_ok:
+        if self._step_cost_ok and self.tracer.enabled:
             # cost annotation (ISSUE 13): once the step program's
-            # CostReport exists, every train/step span carries it
+            # CostReport exists, every train/step span of the armed
+            # tracer carries it (a profiler-only span has no arguments)
             from deepspeed_tpu.telemetry.costmodel import get_report
             rep = get_report("train/step")
             if rep is not None:
@@ -2449,6 +2484,7 @@ class DeepSpeedEngine:
             rng = self._next_rng()
             self._maybe_cost_report(batch, rng)
             self._maybe_memory_report(batch, rng)
+            self._maybe_register_program_map(batch)
             # one fused program: fwd+bwd+apply dispatch together (the
             # per-phase split lives in the fwd/bwd/step timers when the
             # micro API drives them)
@@ -2840,6 +2876,24 @@ class DeepSpeedEngine:
             from deepspeed_tpu.utils.logging import logger
             logger.warning(f"costmodel: train/step analysis failed: {e}")
 
+    def _maybe_register_program_map(self, batch):
+        """On the first fused dispatch, publish the step under
+        ``"train/step"`` for ``get_program_map`` (telemetry/tracing.py): a
+        thunk and the batch's abstract signature, nothing more — the
+        executable's text is fetched and parsed when someone first asks.
+        The table holds the engine weakly."""
+        if self._program_map_registered:
+            return
+        self._program_map_registered = True
+        signature = jax.tree.map(_abstract_placed, batch)
+        alive = weakref.ref(self)
+
+        def step_text():
+            engine = alive()
+            return (None if engine is None
+                    else engine._compile_train_step(signature).as_text())
+        register_program(TRAIN_STEP_PROGRAM, step_text)
+
     def compile_train_step(self, batch):
         """The fused step ``train_batch`` runs for ``batch`` (leaves lead
         with gas), compiled ahead of time from shapes alone — no state is
@@ -2847,15 +2901,15 @@ class DeepSpeedEngine:
         kernels and collectives the backend kept, ``.memory_analysis()``
         the bytes per device.  With the persistent compilation cache on it
         is a cache load once the step has run."""
-        def abstract(x, sharding=None):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+        return self._compile_train_step(jax.tree.map(
+            _abstract_placed, self._shard_batch(batch, stacked=True)))
+
+    def _compile_train_step(self, signature):
         fn = self._get_compiled("train_step")
         with self._train_scope(), self._ltd_scope(), self._aq_scope():
             return fn.lower(
-                jax.tree.map(abstract, self.state, self.state_shardings),
-                jax.tree.map(lambda x: abstract(x, x.sharding),
-                             self._shard_batch(batch, stacked=True)),
-                abstract(self._rng)).compile()
+                jax.tree.map(_abstract, self.state, self.state_shardings),
+                signature, _abstract(self._rng)).compile()
 
     def _maybe_memory_report(self, batch, rng):
         """Opt-in activation-peak accounting (ISSUE 14): compile the

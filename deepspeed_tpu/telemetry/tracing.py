@@ -1,19 +1,31 @@
-"""Chrome-trace / Perfetto span tracer (ISSUE 4 tentpole).
+"""One timeline, two sinks — and the compiled step's account of itself.
 
-``DS_TRACE=/path/trace.json`` (or the ``telemetry.trace`` config key)
-arms a process-wide tracer; every subsystem then emits spans into ONE
-timeline — train-step phases (fwd/bwd/step through the engine timers),
+**Host spans.**  Every subsystem emits spans through the process-wide
+tracer: train-step phases (fwd/bwd/step through the engine timers),
 serving scheduler iterations (admit/prefill/decode), checkpoint
 stage/publish, and resilience events (faults fired, health transitions,
-drains).  Load the file in ``chrome://tracing`` or https://ui.perfetto.dev.
+drains).  A span lands in up to two places:
 
-Correlation ids stitch the timeline together: a span opened with
+- the **profiler's trace**: every ``span`` (of the armed tracer *and* of
+  the null tracer), ``begin``/``end`` and ``instant`` enters a
+  ``jax.profiler.TraceAnnotation("ds/" + name)``.  That is a TraceMe:
+  nanoseconds while no profiler session runs, and inside any session —
+  the benchmark's, an operator's — an event on ``/host:CPU`` on the same
+  clock as the ``/device:TPU:n`` lines (``ds/train/step``,
+  ``ds/train/fused_step``, ``ds/ckpt/stage`` ...).  The program never
+  starts or stops a session itself;
+- the **Chrome-trace file**: ``DS_TRACE=/path/trace.json`` (or the
+  ``telemetry.trace`` config key) arms a :class:`SpanTracer`, which also
+  keeps the spans in memory with correlation ids and writes them for
+  ``chrome://tracing`` / https://ui.perfetto.dev.
+
+Correlation ids stitch the file's timeline together: a span opened with
 ``corr="train-step-12"`` pushes that id onto a thread-local stack, and
 every nested span/instant that does not name its own id inherits it —
 so a fault injected inside step 12's checkpoint save carries
 ``train-step-12`` without the fault injector knowing about steps.
 
-Event model (Chrome trace-event format):
+Event model of the file (Chrome trace-event format):
 - spans are matched ``B``/``E`` pairs per (pid, tid) — the context
   manager guarantees LIFO nesting, which ``scripts/trace_validate.py``
   asserts;
@@ -23,18 +35,37 @@ Event model (Chrome trace-event format):
   so a drain/exit still lands the file.
 
 When no trace path is armed, every hook routes through
-:data:`NULL_TRACER` — a no-op whose ``span()`` costs one context-manager
-enter/exit, safe for hot paths.
+:data:`NULL_TRACER`, whose ``span()`` is the bare TraceAnnotation and
+whose other hooks do nothing.
+
+**Inside the compiled step** the host cannot see, so the program names
+its own parts: ``jax.named_scope``s with the fixed names of
+:data:`STEP_SCOPES` and Pallas kernels with those of
+:data:`KERNEL_NAMES`.  Scopes are HLO metadata only — no runtime cost,
+no change to what XLA fuses.  A device trace does not carry that
+metadata (an ``XLA Ops`` event is the instruction's text without
+``metadata={...}``), but it does carry the **instruction name**, and so
+does the executable's own text.  :func:`get_program_map` publishes the
+table between the two: instruction name -> its scope path, phase
+(:func:`phase_of`), kernel name, collective kind and wire bytes.  It is
+built **lazily**: the engine registers a thunk on the first fused
+dispatch, and the text of the executable is fetched and parsed only
+when someone first asks.
 """
 import atexit
 import json
 import os
+import re
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 TRACE_ENV = "DS_TRACE"
+#: prefix of every host span in a profiler session (``/host:CPU``)
+ANNOTATION_PREFIX = "ds/"
 
 
 class SpanTracer:
@@ -78,7 +109,7 @@ class SpanTracer:
 
     def current_corr(self) -> Optional[str]:
         """Innermost correlation id on this thread (None outside spans)."""
-        for corr in reversed(self._stack()):
+        for corr, _ in reversed(self._stack()):
             if corr is not None:
                 return corr
         return None
@@ -113,13 +144,17 @@ class SpanTracer:
               args: Optional[Dict] = None):
         """Open a span (``E`` must follow on the same thread, LIFO)."""
         corr = corr if corr is not None else self.current_corr()
-        self._stack().append(corr)
+        annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
+        annotation.__enter__()
+        self._stack().append((corr, annotation))
         self._emit(self._event("B", name, cat, corr, args))
 
     def end(self, name: str, args: Optional[Dict] = None):
         st = self._stack()
-        corr = st.pop() if st else None
+        corr, annotation = st.pop() if st else (None, None)
         self._emit(self._event("E", name, "", corr, args))
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
 
     @contextmanager
     def span(self, name: str, cat: str = "", corr: Optional[str] = None,
@@ -136,7 +171,8 @@ class SpanTracer:
         corr = corr if corr is not None else self.current_corr()
         ev = self._event("i", name, cat, corr, args)
         ev["s"] = "p"                     # process-scoped instant
-        self._emit(ev)
+        with TraceAnnotation(ANNOTATION_PREFIX + name):
+            self._emit(ev)
 
     # ------------------------------------------------------------- output
     def drain(self):
@@ -177,7 +213,8 @@ class SpanTracer:
 
 
 class _NullTracer:
-    """Disabled tracer: every hook is a no-op (shared singleton)."""
+    """Disabled tracer (shared singleton): a span is still an event in
+    whatever profiler session is running; nothing else is recorded."""
 
     enabled = False
     path = None
@@ -188,9 +225,8 @@ class _NullTracer:
     def end(self, *a, **kw):
         pass
 
-    @contextmanager
-    def span(self, *a, **kw):
-        yield self
+    def span(self, name, *a, **kw):
+        return TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def instant(self, *a, **kw):
         pass
@@ -248,3 +284,238 @@ def get_tracer():
     if _ACTIVE is None:
         return configure_tracer()
     return _ACTIVE
+
+
+# ===================================================== the compiled step
+#: Fixed ``jax.named_scope`` names inside the compiled train step — part
+#: of the program's interface: readers of a device trace key on them.
+SCOPE_FWD_BWD = "ds.fwd_bwd"        # the value_and_grad call of a micro-step
+SCOPE_ACCUMULATE = "ds.accumulate"  # gradient cast + add into the accumulator
+SCOPE_OPTIMIZER = "ds.optimizer"    # _apply_grads: norm, clip, scaler, update
+SCOPE_EMBED = "ds.embed"            # model: token + position embedding
+SCOPE_BLOCK = "ds.block"            # model: one transformer block ...
+SCOPE_ATTN = "attn"                 # ... LN1, QKV, attention, projection
+SCOPE_MLP = "mlp"                   # ... LN2, MLP
+SCOPE_HEAD_LOSS = "ds.head_loss"    # model: final LN, logits, cross-entropy
+STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
+               SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
+               SCOPE_HEAD_LOSS)
+#: ``name=`` of each ``pl.pallas_call`` of the flash kernel
+KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq")
+PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
+          "other")
+#: the name the engine registers its fused train step under (the cost
+#: model's table uses the same)
+TRAIN_STEP_PROGRAM = "train/step"
+
+_DS_SCOPE = re.compile(r"(?:^|[/(])(ds\.[a-z_]+)")
+_KERNEL = re.compile(r"/([^/()]+)/pallas_call$")
+
+
+def phase_of(op_name: Optional[str]) -> str:
+    """Which phase of the step an instruction belongs to, from its own
+    ``op_name`` (``jit(train_step)/ds.fwd_bwd/transpose(jvp(ds.embed))/
+    while/body/checkpoint/rematted_computation/ds.block/attn/dot_general``).
+    ``ds.optimizer`` and ``ds.accumulate`` name their phase; under any
+    other ``ds.*`` scope jax's own transform names decide:
+    ``rematted_computation`` is the recompute (the second forward of a
+    ``jax.checkpoint``, with the residuals the backward needs),
+    ``transpose(`` the backward, all else (``jvp(`` or bare) the forward.
+    An instruction under no ``ds.*`` scope is ``other``."""
+    scopes = _DS_SCOPE.findall(op_name or "")
+    if not scopes:
+        return "other"
+    if SCOPE_OPTIMIZER in scopes:
+        return "optimizer"
+    if SCOPE_ACCUMULATE in scopes:
+        return "accumulate"
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    return "forward"
+
+
+# -- the executable's text -> {instruction name: scope, phase, ...}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([^\s(]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%([^\s,)}]+)")
+_ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)[a-z0-9]*)\[([0-9,]*)\]")
+_GROUPS_IOTA = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_GROUPS_LIST = re.compile(r"replica_groups=\{\{([0-9,]*)\}")
+#: HLO opcode (less ``-start`` / ``-done``) -> the cost model's family
+_COLLECTIVE_OPS = {"all-gather": "all_gather", "all-reduce": "all_reduce",
+                   "reduce-scatter": "reduce_scatter",
+                   "all-to-all": "all_to_all",
+                   "collective-permute": "ppermute"}
+
+
+def _split_shape(rest: str):
+    """``<shape> <opcode>(operands...), attrs`` -> (shape, opcode, tail)."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += ch == "("
+            depth -= ch == ")"
+            if depth == 0:
+                break
+        shape, tail = rest[:i + 1], rest[i + 2:]
+    else:
+        shape, _, tail = rest.partition(" ")
+    return shape, tail.partition("(")[0], tail
+
+
+def _shape_bytes(shape: str) -> int:
+    """Bytes of every array in an HLO shape (a tuple's elements summed;
+    tiling and memory-space annotations carry no ``[...]`` and are
+    skipped).  An element type's width is the first number in its name
+    (``bf16``, ``s32``, ``f8e4m3fn``, ``c64``); ``pred`` and anything
+    under a byte count one."""
+    total = 0
+    for _, bits, dims in _ARRAY.findall(shape):
+        size = max(int(bits or 8) // 8, 1)
+        for d in dims.split(","):
+            size *= int(d) if d else 1
+        total += size
+    return total
+
+
+def _group_size(tail: str) -> Optional[int]:
+    m = _GROUPS_IOTA.search(tail)
+    if m:
+        return int(m.group(2))
+    m = _GROUPS_LIST.search(tail)
+    if m:
+        return len([x for x in m.group(1).split(",") if x])
+    return None
+
+
+def parse_program_text(text: str) -> Dict[str, Dict[str, Any]]:
+    """The step-program map of one executable's HLO text
+    (``compiled.as_text()``).  Pure: text in, table out.
+
+    Every instruction of every computation that is not a fused
+    computation's body gets a row ``{"scope", "phase", "kernel",
+    "collective", "wire_bytes"}``.  A fusion carries the ``op_name`` XLA
+    gave it, which is its root's.  ``collective`` is the HLO opcode less
+    ``-start``/``-done`` (``all-gather``, ``all-reduce`` ...), also for a
+    fusion that wraps one (TPU: ``%async-collective-start/done``, a
+    ``calls=%all-reduce-scatter`` fusion reads ``reduce-scatter``).
+    ``wire_bytes`` = payload x ``costmodel.ring_wire_factor``: the payload
+    is the result shape's bytes (the full tensor for an all-gather or an
+    all-reduce; a reduce-scatter's result is one shard, so x group size,
+    the cost model's convention), the group size is read from
+    ``replica_groups``; a ``-start`` half carries ``None`` so that a
+    start/done pair counts once."""
+    from deepspeed_tpu.telemetry.costmodel import ring_wire_factor
+    rows, body_collective = [], {}
+    computation = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        shape, opcode, tail = _split_shape(rest)
+        base = opcode
+        for suffix in ("-start", "-done"):
+            if base.endswith(suffix):
+                base = base[:-len(suffix)]
+        if base in _COLLECTIVE_OPS:
+            # the first collective of a computation names what a fusion
+            # calling it wraps; its replica_groups are that fusion's
+            body_collective.setdefault(computation,
+                                       (base, _group_size(tail)))
+        rows.append((name, computation, shape, opcode, base, tail))
+
+    fused = set()
+    for _, _, _, opcode, _, tail in rows:
+        if opcode == "fusion":
+            fused.update(_CALLS.findall(tail))
+
+    table = {}
+    for name, computation, shape, opcode, base, tail in rows:
+        if computation in fused:
+            continue
+        op_name = _OP_NAME.search(tail)
+        scope = op_name.group(1) if op_name else None
+        kernel = collective = wire = None
+        half = opcode[len(base):]           # "", "-start" or "-done"
+        if 'custom_call_target="tpu_custom_call"' in tail:
+            k = _KERNEL.search(scope or "")
+            kernel = k.group(1) if k else "pallas_call"
+        if base in _COLLECTIVE_OPS:
+            collective, group = base, _group_size(tail)
+        elif opcode == "fusion":
+            # TPU: a fusion may wrap a collective.  Only the two kinds
+            # whose *name* says so count as one — a compute fusion that an
+            # asynchronous gather runs under calls a computation holding
+            # that gather too, and is compute.
+            called = _CALLS.search(tail)
+            called = called.group(1) if called else ""
+            inner = body_collective.get(called, (None, None))
+            if called.startswith("all-reduce-scatter"):
+                collective, group = "reduce-scatter", inner[1]
+            elif name.startswith("async-collective-") and inner[0]:
+                collective, group = inner
+                half = "-start" if "-start" in name else "-done"
+        if collective is not None and half != "-start":
+            payload = _shape_bytes(shape)
+            if collective == "reduce-scatter" and group:
+                payload *= group
+            wire = int(round(payload * ring_wire_factor(
+                _COLLECTIVE_OPS[collective], group)))
+        table[name] = {"scope": scope, "phase": phase_of(scope),
+                       "kernel": kernel, "collective": collective,
+                       "wire_bytes": wire}
+    return table
+
+
+# -- process-wide table by program name, beside costmodel.get_report
+_PROGRAM_LOCK = threading.Lock()
+_PROGRAM_THUNKS: Dict[str, Callable[[], Optional[str]]] = {}
+_PROGRAM_MAPS: Dict[str, Dict[str, Dict[str, Any]]] = {}
+
+
+def register_program(name: str, text_thunk: Callable[[], Optional[str]]):
+    """Publish a program under ``name``.  ``text_thunk()`` returns the
+    HLO text of the executable that runs (or None if it can no longer be
+    had); it is NOT called here — only by the first
+    :func:`get_program_map` that asks."""
+    with _PROGRAM_LOCK:
+        _PROGRAM_THUNKS[name] = text_thunk
+        _PROGRAM_MAPS.pop(name, None)
+
+
+def get_program_map(name: str = TRAIN_STEP_PROGRAM):
+    """``{instruction name: {"scope", "phase", "kernel", "collective",
+    "wire_bytes"}}`` of the program registered under ``name`` (see
+    :func:`parse_program_text`), or None if none is.  The first call
+    pays for the executable's text (with the persistent compile cache a
+    load, ~2 s for a 760M step) and the parse; later calls return the
+    same table."""
+    with _PROGRAM_LOCK:
+        if name in _PROGRAM_MAPS:
+            return _PROGRAM_MAPS[name]
+        thunk = _PROGRAM_THUNKS.get(name)
+    if thunk is None:
+        return None
+    text = thunk()
+    if text is None:
+        return None
+    table = parse_program_text(text)
+    with _PROGRAM_LOCK:
+        if _PROGRAM_THUNKS.get(name) is thunk:
+            _PROGRAM_MAPS[name] = table
+    return table
+
+
+def reset_programs():
+    """Tests: forget every registered program."""
+    with _PROGRAM_LOCK:
+        _PROGRAM_THUNKS.clear()
+        _PROGRAM_MAPS.clear()
