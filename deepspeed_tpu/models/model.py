@@ -43,7 +43,12 @@ def param_stream_scope(enabled: bool = True, mesh=None, layer_specs=None,
       ``runtime/zero/zeropp.gather_with_quantized_grad`` (None = leaf
       skips).  Each layer slice all-gathers over the manual zero axes in
       the forward (int8 wire when qwZ is also on) and its cotangent
-      reduce-scatters as int8 chunks in the backward."""
+      reduce-scatters as int8 chunks in the backward.
+    - ``gather`` — plain ZeRO-3: ``layer_specs`` is a flat list of
+      (grad_spec, target_spec) pairs (None = leaf is not ZeRO-sharded); the
+      leaf's slice is all-gathered to the target layout here, where the
+      layer is used, and its cotangent leaves in the gradient's layout
+      (runtime/zero/policy.gather_on_use)."""
     value = (mode, mesh, layer_specs) if enabled else False
     token = _PARAM_STREAM.set(value)
     try:
@@ -119,11 +124,12 @@ def _maybe_dequant(tree, keep_gemm_weights: bool = False,
 
 def maybe_stream(layer_tree, keep_quantized: bool = False,
                  keep_moe_quantized: bool = False):
-    """Inside a layer-scan body: move this layer's (possibly host-resident)
-    params to device memory, and/or reconstruct int8-quantized weights
+    """Inside a layer-scan body: bring this layer's params to where the
+    block computes — all-gather its ZeRO-3 shards, move a host-resident
+    slice to device memory — and/or reconstruct int8-quantized weights
     (``QuantizedTensor`` leaves) in compute dtype.  No-op otherwise.
-    Call *inside* the remat boundary so the backward pass re-streams the
-    layer instead of pinning its device copy in HBM.
+    Call *inside* the remat boundary so the backward pass re-gathers or
+    re-streams the layer instead of pinning its full copy in HBM.
 
     ``keep_quantized`` (serving decode paths): leave the layer's 2-D
     quantized projection weights as ``QuantizedTensor`` — the model's
@@ -140,11 +146,15 @@ def maybe_stream(layer_tree, keep_quantized: bool = False,
     import jax
     mode, mesh, layer_specs = cfg
     leaves, treedef = jax.tree_util.tree_flatten(layer_tree)
-    if mode == "qwz":
-        from deepspeed_tpu.runtime.zero.zeropp import quantized_weight_gather
+    if mode in ("qwz", "gather"):
+        if mode == "qwz":
+            from deepspeed_tpu.runtime.zero.zeropp import \
+                quantized_weight_gather as gather
+        else:
+            from deepspeed_tpu.runtime.zero.policy import \
+                gather_on_use as gather
         assert layer_specs is not None and len(layer_specs) == len(leaves)
-        moved = [w if sp is None
-                 else quantized_weight_gather(w, mesh, sp[0], sp[1])
+        moved = [w if sp is None else gather(w, mesh, sp[0], sp[1])
                  for w, sp in zip(leaves, layer_specs)]
         return jax.tree_util.tree_unflatten(treedef, moved)
     if mode == "qgz":

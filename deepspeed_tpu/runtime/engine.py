@@ -18,6 +18,7 @@ API parity:
 - ``save_checkpoint`` / ``load_checkpoint`` with tag dirs + ``latest`` file
   (engine.py:2943/:2620).
 """
+import functools
 import os
 import time
 import weakref
@@ -224,7 +225,8 @@ class DeepSpeedEngine:
             param_persistence_threshold=(zc.param_persistence_threshold
                                          if zc.stage >= 3 else 0),
             hpz_partition_size=zc.zero_hpz_partition_size,
-            mics_shard_size=zc.mics_shard_size)
+            mics_shard_size=zc.mics_shard_size,
+            stacked_key=getattr(model, "blocks_key", None))
         off = zc.offload_optimizer
         self._offload_device = off.device if off is not None else "none"
         self._offload = self._offload_device in ("cpu", "nvme")
@@ -296,27 +298,7 @@ class DeepSpeedEngine:
             lambda s: jax.ShapeDtypeStruct(s.shape, storage_dtype)
             if jnp.issubdtype(s.dtype, jnp.floating) else s, shapes)
         self.param_specs = self.zero_policy.param_specs(shapes, logical)
-        self._warned_qwz_no_blocks = False
         bk_ = getattr(model, "blocks_key", "blocks")
-        needs_off_dim0 = (
-            ((zc.zero_quantized_weights or zc.zero_quantized_gradients)
-             and zc.stage == 3)
-            # per-layer streaming slices the stacked dim too: a zero shard
-            # on dim 0 would turn each layer access into a cross-device
-            # gather of the stack instead of a local slice
-            or (self._offload_param and self._multi_device))
-        if needs_off_dim0 and isinstance(self.param_specs, dict) \
-                and bk_ in self.param_specs:
-            # qwZ quantizes (and the streamed tier transfers) each LAYER
-            # slice before its gather, so the zero shard must not sit on
-            # the stacked layer dim (where the scan's slice — not an
-            # all-gather — would materialise the full layer); move it onto
-            # the weight dims
-            self.param_specs[bk_] = self._move_zero_off_dim0(
-                self.param_specs[bk_], shapes[bk_],
-                logical[bk_] if isinstance(logical, dict) and bk_ in logical
-                else None,
-                self.zero_policy.param_axes)
         if zc.zero_quantized_gradients and (self._offload
                                             or self._offload_param):
             logger.warning(
@@ -444,15 +426,6 @@ class DeepSpeedEngine:
                        if self._param_nvme and isinstance(logical, dict)
                        else logical)
         self.grad_specs = self.zero_policy.grad_specs(params, logical_eff)
-        if self._offload_param and self._multi_device and isinstance(
-                self.grad_specs, dict) and bk_ in self.grad_specs:
-            # grads DMA out per layer slice in the backward scan — same
-            # no-shard-on-dim-0 rule as the param storage
-            self.grad_specs[bk_] = self._move_zero_off_dim0(
-                self.grad_specs[bk_], shapes[bk_],
-                logical[bk_] if isinstance(logical, dict) and bk_ in logical
-                else None,
-                self.zero_policy.zero_axes)
         self.grad_shardings = self.zero_policy.shardings(self.grad_specs)
         devices_flat = list(self.mesh.devices.flat)
         if self._offload_param and not self._param_nvme \
@@ -1015,35 +988,6 @@ class DeepSpeedEngine:
             f"batch {self.train_batch_size()} = {self.train_micro_batch_size_per_gpu()}"
             f"×{self.gradient_accumulation_steps()}×{self.topology.dp_world_size}",
             ranks=[0])
-
-    def _move_zero_off_dim0(self, spec_tree, shape_tree, logical_tree, axes):
-        """Re-derive zero shardings for a layer-stacked subtree with the
-        stacked dim 0 forced unsharded (see call sites for why)."""
-        zero_axes = set(self.zero_policy.zero_axes)
-
-        def _off_dim0(spec, shp, lg):
-            t = tuple(spec)
-            lead = t[0] if t else None
-            lead_axes = ((lead,) if isinstance(lead, str)
-                         else tuple(lead or ()))
-            if not (lead_axes and set(lead_axes) & zero_axes):
-                return spec
-            lg_sub = (P(*tuple(lg)[1:]) if lg is not None else None)
-            sub = self.zero_policy._sharded_spec(
-                shp.shape[1:], lg_sub, axes=axes)
-            return P(None, *tuple(sub))
-
-        is_p = lambda x: isinstance(x, P)
-        specs_flat, treedef = jax.tree_util.tree_flatten(
-            spec_tree, is_leaf=is_p)
-        shapes_flat = jax.tree.leaves(shape_tree)
-        if logical_tree is not None:
-            lg_flat = jax.tree.leaves(logical_tree, is_leaf=is_p)
-        else:
-            lg_flat = [None] * len(specs_flat)
-        fixed = [_off_dim0(sp, shp, lg) for sp, shp, lg
-                 in zip(specs_flat, shapes_flat, lg_flat)]
-        return jax.tree_util.tree_unflatten(treedef, fixed)
 
     # ------------------------------------------------------------------ config api
     def train_batch_size(self) -> int:
@@ -2163,25 +2107,26 @@ class DeepSpeedEngine:
         return self._stream_scope()
 
     def _stream_scope(self):
-        """param_stream_scope when offload_param is on (tracing of the wrapped
-        compiled fn happens on its first call, inside this scope)."""
+        """param_stream_scope for the per-layer parameter movement of this
+        engine's tier: the host→device stream when offload_param is on,
+        else the ZeRO-3 gather of each layer's slice (int8 under qwZ);
+        tracing of the wrapped compiled fn happens on its first call,
+        inside this scope."""
         from deepspeed_tpu.models.model import param_stream_scope
         import contextlib
         if not self._offload_param:
-            zc = self._config.zero_config
-            if zc.zero_quantized_weights and zc.stage == 3:
-                return self._qwz_scope()
-            return contextlib.nullcontext()
+            plan = self._layer_gather_plan
+            if plan is None:
+                return contextlib.nullcontext()
+            return param_stream_scope(True, mesh=self.mesh,
+                                      layer_specs=plan[1], mode=plan[0])
         bk = getattr(self.model, "blocks_key", "blocks")
         # stream each layer to its LOGICAL (tensor-parallel) layout: ZeRO
         # storage axes are dropped, so the transfer is also the stage-3
         # per-layer gather (reference fetch_sub_module,
         # partitioned_param_coordinator.py:256)
-        logical = getattr(self.model, "logical_specs", None)
-        src = (logical[bk] if isinstance(logical, dict) and bk in logical
-               else self.param_specs[bk])
         is_p = lambda x: isinstance(x, P)
-        specs = jax.tree.leaves(src, is_leaf=is_p)
+        specs = jax.tree.leaves(self._block_logical_specs(), is_leaf=is_p)
         shardings = jax.tree.leaves(
             self.param_shardings[bk],
             is_leaf=lambda x: isinstance(x, NamedSharding))
@@ -2193,38 +2138,61 @@ class DeepSpeedEngine:
         return param_stream_scope(True, mesh=self.mesh,
                                   layer_specs=layer_specs)
 
-    def _qwz_scope(self):
-        """ZeRO++ qwZ (zero_quantized_weights): per-layer weights quantize to
-        int8 before the stage-3 all-gather and dequantize after — the gather
-        moves 1 byte/param instead of 2/4 (reference
-        partition_parameters.py:652 + zeropp.md:13)."""
-        from deepspeed_tpu.models.model import param_stream_scope
-        import contextlib
+    def _block_logical_specs(self):
+        """Logical (TP-only) specs of the layer-stacked subtree."""
+        bk = getattr(self.model, "blocks_key", "blocks")
+        logical = getattr(self.model, "logical_specs", None)
+        if isinstance(logical, dict) and bk in logical:
+            return logical[bk]
+        return jax.tree.map(lambda _: P(), self.param_specs[bk],
+                            is_leaf=lambda x: isinstance(x, P))
+
+    @functools.cached_property
+    def _layer_gather_plan(self):
+        """ZeRO-3 gathers one layer at a time: inside the layer scan each
+        ZeRO-sharded leaf's slice is all-gathered from its storage layout
+        to its logical one where the layer is used (models/model.py
+        maybe_stream, reference fetch_sub_module,
+        partitioned_param_coordinator.py:256).  Under ZeRO++ qwZ
+        (zero_quantized_weights) the slice quantizes to int8 before the
+        gather and dequantizes after — 1 byte/param on the wire instead of
+        2/4 (reference partition_parameters.py:652 + zeropp.md:13).
+        ``(mode, layer_specs)`` for ``param_stream_scope``, or None where
+        there is nothing to gather."""
+        zc = self._config.zero_config
+        qwz = zc.zero_quantized_weights and zc.stage == 3
         bk = getattr(self.model, "blocks_key", "blocks")
         if not (isinstance(self.param_specs, dict)
                 and bk in self.param_specs):
-            if not self._warned_qwz_no_blocks:
+            if qwz:
                 logger.warning(
                     f"zero_quantized_weights needs a layer-stacked '{bk}' "
                     f"params subtree; model has none — qwZ disabled")
-                self._warned_qwz_no_blocks = True
-            return contextlib.nullcontext()
+            return None
         is_p = lambda x: isinstance(x, P)
         storage = jax.tree.leaves(self.param_specs[bk], is_leaf=is_p)
-        logical = getattr(self.model, "logical_specs", None)
-        src = (logical[bk] if isinstance(logical, dict) and bk in logical
-               else jax.tree.map(lambda _: P(), self.param_specs[bk],
-                                 is_leaf=is_p))
-        targets = jax.tree.leaves(src, is_leaf=is_p)
+        targets = jax.tree.leaves(self._block_logical_specs(), is_leaf=is_p)
+        # qwZ re-scatters the cotangent to the storage layout; the plain
+        # gather hands it over in the gradient's (the same but under hpZ,
+        # where gradients shard over more axes than the stored params)
+        back = storage if qwz else jax.tree.leaves(self.grad_specs[bk],
+                                                   is_leaf=is_p)
         pairs = []
-        for st, tg in zip(storage, targets):
+        for st, tg, bw in zip(storage, targets, back):
             st_l = P(*tuple(st)[1:])     # layer slice: leading dim stripped
             tg_l = P(*tuple(tg)[1:])
             # only leaves where the gather actually moves data (zero-sharded
-            # storage) get the quantized path
-            pairs.append((st_l, tg_l) if st_l != tg_l else None)
-        return param_stream_scope(True, mesh=self.mesh, layer_specs=pairs,
-                                  mode="qwz")
+            # storage) take part
+            pairs.append((P(*tuple(bw)[1:]), tg_l) if st_l != tg_l else None)
+        if not qwz and not any(pairs):
+            # stage < 3 or a ZeRO world of one: nothing to gather
+            return None
+        if not getattr(getattr(self.model, "config", None), "remat", False):
+            logger.warning(
+                "ZeRO-3 without per-layer remat keeps every gathered "
+                "layer's copy alive for backward — set the model's "
+                "remat=True to bound HBM at O(1 layer) of parameters")
+        return ("qwz" if qwz else "gather"), pairs
 
     #: batch keys carrying a trailing sequence dim (safe to truncate)
     _SEQ_KEYS = ("input_ids", "labels", "attention_mask", "position_ids")

@@ -21,9 +21,20 @@ sharded params at use sites — and overlaps them with compute (the reference's
 ``overlap_comm`` side-stream, stage_1_and_2.py:963, is automatic).
 
 Sharding rule per array: add the ZeRO mesh axes to the first dimension that is
-divisible by the ZeRO world size and not already sharded by the logical (TP) spec.
-Small params below ``param_persistence_threshold`` stay replicated, matching the
-reference's persistence heuristic (parameter_offload.py:360).
+divisible by the ZeRO world size and not already sharded by the logical (TP) spec
+(an axis of size one shards nothing: with tensor parallelism off, a row-parallel
+weight takes the ZeRO axes on its leading weight dimension like any other, which
+is where the TPU's fused reduce-scatter can take the gradient) — except dim 0 of
+a leaf under the model's layer-stacked subtree (``stacked_key``), which is never
+a ZeRO dimension: the layer scan slices that axis, and a ZeRO shard on it makes
+XLA gather the whole stack inside the forward and the backward loop (every
+iteration gathers all L layers and slices one out).  The shard sits on a weight
+dimension instead, the same one for parameters, gradients and optimizer state,
+and the engine gathers one layer's slice where the layer is used
+(``models/model.py`` ``maybe_stream``, mode ``gather``).
+Small params below ``param_persistence_threshold`` (a leaf's total size) stay
+replicated, matching the reference's persistence heuristic
+(parameter_offload.py:360).
 """
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -47,24 +58,26 @@ def _canon(entries) -> P:
     return P(*entries)
 
 
+def _axes_of(entry) -> Tuple:
+    """Mesh axes of one PartitionSpec entry (None, a name, or several)."""
+    if entry is None:
+        return ()
+    return tuple(entry) if isinstance(entry, (tuple, list)) else (entry,)
+
+
 def _used_axes(entries) -> set:
-    used = set()
-    for e in entries:
-        if e is None:
-            continue
-        if isinstance(e, (tuple, list)):
-            used.update(e)
-        else:
-            used.add(e)
-    return used
+    return {a for e in entries for a in _axes_of(e)}
 
 
 def add_zero_axes_to_spec(shape: Tuple[int, ...],
                           logical_spec: Optional[P],
                           zero_axes: Tuple[str, ...],
                           mesh: jax.sharding.Mesh,
-                          min_size: int = 0) -> P:
+                          min_size: int = 0,
+                          first_dim: int = 0) -> P:
     """Extend ``logical_spec`` (TP sharding) with the ZeRO axes on a free dim.
+    Dimensions before ``first_dim`` never take them (1 for a layer-stacked
+    leaf: its layer axis).
 
     Falls back to the unmodified logical spec (replication over the DP group)
     when no dimension is cleanly divisible — the reference keeps such params
@@ -76,30 +89,60 @@ def add_zero_axes_to_spec(shape: Tuple[int, ...],
     free_zero = tuple(a for a in zero_axes if a not in used)
     if not free_zero:
         return _canon(entries)
-    zero_world = 1
-    for a in free_zero:
-        zero_world *= mesh.shape[a]
+
+    def world(axes):
+        size = 1
+        for a in axes:
+            size *= mesh.shape[a]
+        return size
+
+    zero_world = world(free_zero)
     total = 1
     for s in shape:
         total *= s
     if zero_world <= 1 or total < max(min_size, 1):
         return _canon(entries)
-    for i, dim in enumerate(shape):
-        if entries[i] is None and dim % zero_world == 0 and dim >= zero_world:
-            entries[i] = free_zero if len(free_zero) > 1 else free_zero[0]
-            return _canon(entries)
-    # second pass: compose with existing sharding on a dim (e.g. TP-sharded dim
-    # also divisible by zero world on the per-shard size)
-    for i, dim in enumerate(shape):
-        if entries[i] is not None:
-            cur = entries[i] if isinstance(entries[i], tuple) else (entries[i],)
-            cur_world = 1
-            for a in cur:
-                cur_world *= mesh.shape[a]
-            if dim % (cur_world * zero_world) == 0:
-                entries[i] = tuple(cur) + free_zero
+    dims = [(i, dim, _axes_of(entries[i]))
+            for i, dim in enumerate(shape)][first_dim:]
+    # first a dim the logical spec does not shard (no axes, or axes of size
+    # one: tensor parallelism off), then one it does (e.g. a TP-sharded dim
+    # whose per-shard size the ZeRO world divides too)
+    for actually_sharded in (False, True):
+        for i, dim, cur in dims:
+            cur_world = world(cur)
+            if (cur_world > 1) == actually_sharded and dim \
+                    and dim % (cur_world * zero_world) == 0:
+                merged = cur + free_zero
+                entries[i] = merged if len(merged) > 1 else merged[0]
                 return _canon(entries)
     return _canon(_spec_tuple(logical_spec, len(shape)))
+
+
+def gather_on_use(w, mesh, grad_spec: P, target_spec: P):
+    """The ZeRO-3 gather of one layer's slice, stated where the layer is
+    used (inside the scan body and the remat boundary): ``w`` arrives in its
+    storage layout and leaves in ``target_spec`` (the logical layout, no
+    ZeRO axes), so the partitioner must all-gather the weight slice — it may
+    not move activations to the shards by cost, and it has nothing of
+    whole-stack size to gather outside the loop.  The cotangent is pinned
+    to ``grad_spec``, the layout of the slice's gradient: it leaves the
+    backward loop as a reduce-scatter, not as an all-reduce of the
+    replicated gradient."""
+    target = NamedSharding(mesh, target_spec)
+    grad = NamedSharding(mesh, grad_spec)
+
+    @jax.custom_vjp
+    def f(x):
+        return jax.lax.with_sharding_constraint(x, target)
+
+    def fwd(x):
+        return f(x), None
+
+    def bwd(_, g):
+        return (jax.lax.with_sharding_constraint(g, grad),)
+
+    f.defvjp(fwd, bwd)
+    return f(w)
 
 
 @dataclass
@@ -110,6 +153,9 @@ class ZeroShardingPolicy:
     param_persistence_threshold: int = 0
     hpz_partition_size: int = 1
     mics_shard_size: int = -1
+    #: top-level key of the params subtree whose leaves carry a leading
+    #: layer axis (``model.blocks_key``); None = the model has none
+    stacked_key: Optional[str] = None
 
     def __post_init__(self):
         if self.stage not in (0, 1, 2, 3):
@@ -131,39 +177,41 @@ class ZeroShardingPolicy:
         self.mesh = self.topology.mesh
 
     # -- per-leaf specs -------------------------------------------------------
-    def _sharded_spec(self, shape, logical_spec, axes=None) -> P:
+    def _sharded_spec(self, shape, logical_spec, axes=None,
+                      stacked=False) -> P:
         return add_zero_axes_to_spec(shape, logical_spec,
                                      axes or self.zero_axes,
-                                     self.mesh, self.param_persistence_threshold)
+                                     self.mesh, self.param_persistence_threshold,
+                                     first_dim=int(stacked))
 
-    def param_spec(self, shape, logical_spec=None) -> P:
-        """Storage sharding of master params between steps."""
+    def param_spec(self, shape, logical_spec=None, stacked=False) -> P:
+        """Storage sharding of master params between steps.  ``stacked``:
+        the leaf's dim 0 is the layer axis (see the module docstring)."""
         if self.stage >= 3:
             return self._sharded_spec(shape, logical_spec,
-                                      axes=self.param_axes)
+                                      axes=self.param_axes, stacked=stacked)
         return logical_spec if logical_spec is not None else P()
 
-    def grad_spec(self, shape, logical_spec=None) -> P:
+    def grad_spec(self, shape, logical_spec=None, stacked=False) -> P:
         if self.stage >= 2:
-            return self._sharded_spec(shape, logical_spec)
+            return self._sharded_spec(shape, logical_spec, stacked=stacked)
         return logical_spec if logical_spec is not None else P()
 
-    def optimizer_spec(self, shape, logical_spec=None) -> P:
+    def optimizer_spec(self, shape, logical_spec=None, stacked=False) -> P:
         if self.stage >= 1:
-            return self._sharded_spec(shape, logical_spec)
+            return self._sharded_spec(shape, logical_spec, stacked=stacked)
         return logical_spec if logical_spec is not None else P()
 
     # -- pytree-level ---------------------------------------------------------
     def _tree_specs(self, params, logical_specs, fn):
-        if logical_specs is None:
-            return jax.tree.map(
-                lambda p: fn(p.shape if hasattr(p, "shape") else (), None),
-                params)
+        def leaf(path, p, s=None):
+            stacked = (self.stacked_key is not None and bool(path)
+                       and getattr(path[0], "key", None) == self.stacked_key)
+            return fn(getattr(p, "shape", ()), s, stacked=stacked)
         # logical_specs must be a pytree matching params with PartitionSpec
         # leaves (use P() for replicated, not None — None is an empty pytree).
-        return jax.tree.map(
-            lambda p, s: fn(p.shape if hasattr(p, "shape") else (), s),
-            params, logical_specs)
+        rest = () if logical_specs is None else (logical_specs,)
+        return jax.tree_util.tree_map_with_path(leaf, params, *rest)
 
     def param_specs(self, params, logical_specs=None):
         return self._tree_specs(params, logical_specs, self.param_spec)

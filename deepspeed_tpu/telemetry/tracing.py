@@ -514,6 +514,41 @@ def get_program_map(name: str = TRAIN_STEP_PROGRAM):
     return table
 
 
+#: an instruction inside a layer loop of the step: a ``while`` body below
+#: the micro-step's ``ds.fwd_bwd`` (the scan over layers, or its transpose)
+_IN_LAYER_LOOP = re.compile(r"ds\.fwd_bwd/.*\bwhile/body\b")
+
+
+def in_layer_loop(row: Dict[str, Any]) -> bool:
+    return bool(_IN_LAYER_LOOP.search(row["scope"] or ""))
+
+
+def layer_loop_gathers(name: str = TRAIN_STEP_PROGRAM):
+    """Whether ZeRO-3 gathers one layer at a time, from the program's own
+    map: ``{"rows", "max_wire_bytes", "wire_bytes_per_iteration",
+    "by_phase"}`` of the all-gathers inside the layer loops — how many
+    instructions, the largest one's bytes on the wire and their sum over
+    one iteration of each loop (a step executes it once per layer;
+    ``by_phase`` splits rows and bytes by the map's phase).  None where no
+    program is registered.  On request only: it pays for the map
+    (:func:`get_program_map`) if nobody has yet."""
+    table = get_program_map(name)
+    if table is None:
+        return None
+    hit = [row for row in table.values()
+           if row["collective"] == "all-gather"
+           and row["wire_bytes"] is not None and in_layer_loop(row)]
+    by_phase: Dict[str, Dict[str, int]] = {}
+    for row in hit:
+        acc = by_phase.setdefault(row["phase"], {"rows": 0, "wire_bytes": 0})
+        acc["rows"] += 1
+        acc["wire_bytes"] += row["wire_bytes"]
+    return {"rows": len(hit),
+            "max_wire_bytes": max((r["wire_bytes"] for r in hit), default=0),
+            "wire_bytes_per_iteration": sum(r["wire_bytes"] for r in hit),
+            "by_phase": by_phase}
+
+
 def reset_programs():
     """Tests: forget every registered program."""
     with _PROGRAM_LOCK:
