@@ -25,6 +25,7 @@ from harness.device import device_fields, peak_bytes
 from harness.spans import Spans
 
 KERNEL = "tpu_custom_call"      # how a Mosaic kernel reads in HLO text
+STEP_PROGRAM = "train/step"     # the name the engine registers its step under
 IN_FLIGHT_STEPS = 2             # dispatched and not yet waited for, at most
 TRACE_STEPS = 4                 # steady optimizer steps under the profiler
 COLLECTIVES = ("all-gather", "all-reduce", "all-to-all", "reduce-scatter",
@@ -96,9 +97,17 @@ def state_bytes_per_device(engine):
     return per
 
 
+def missing_kernels(program_map, wanted):
+    """Those of the kernel names ``wanted`` that no instruction of the
+    program's map ({instruction: {"kernel": name or None, ...}}) carries."""
+    have = {row.get("kernel") for row in (program_map or {}).values()}
+    return sorted(set(wanted) - have)
+
+
 def inspect_program(engine, batch, checks, problems):
     """Counts from the compiled step (a cache load once the step has
-    run): Mosaic custom calls and collectives by kind."""
+    run): Mosaic custom calls and collectives by kind; with
+    ``require_kernels``, the kernels the program's own map names."""
     from deepspeed_tpu.ops.attention import flash_status
     from deepspeed_tpu.telemetry.costmodel import get_report
     text = engine.compile_train_step(batch).as_text()
@@ -108,7 +117,15 @@ def inspect_program(engine, batch, checks, problems):
         check(status and all(v is True for v in status.values()),
               f"flash kernel not chosen for every shape: {status}", problems)
         check(kernels > 0, f"no {KERNEL} in the compiled step", problems)
-    report = get_report("train/step")
+    if checks.get("require_kernels"):
+        # one Mosaic call does not show that every kernel was chosen (an
+        # expert FFN that fell back beside a flash kernel that did not)
+        from deepspeed_tpu.telemetry.tracing import get_program_map
+        missing = missing_kernels(get_program_map(STEP_PROGRAM),
+                                  checks["require_kernels"])
+        check(not missing, f"no instruction of the compiled step is the "
+                           f"kernel {missing}", problems)
+    report = get_report(STEP_PROGRAM)
     say(line="program", flash_status=status, kernel_calls=kernels,
         collectives={c: len(re.findall(rf" {c}(?:-start)?\(", text))
                      for c in COLLECTIVES},
@@ -170,16 +187,21 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
     chips = len(devices)
 
     model = build_model(config)
-    mcfg = model.config
+    # the configuration's own sizes, which build_model has just held the
+    # model to, with the parameters as counted: what the FLOPs function,
+    # the plain reference and the readers are handed, whatever the family
+    sizes = {**config["model"], "n_params": model.meta["n_params"]}
     engine, mesh = build_engine(config, traffic, model, seed, devices)
     global_micro = traffic["micro_batch_per_chip"] * \
         engine.topology.dp_world_size
     gas = traffic["gradient_accumulation_steps"]
     tokens_per_step = gas * global_micro * traffic["seq_len"]
     s_eff = datagen.effective_context(traffic)
-    flops_per_token = flops.train_flops_per_token(
-        model.meta["n_params"], mcfg.num_layers, mcfg.d_model, s_eff)
-    stream = datagen.BatchStream(traffic, mcfg.vocab_size, global_micro, seed)
+    flops_function = config.get("flops", {}).get(
+        "train", "train_flops_per_token")
+    flops_per_token = flops.resolve(flops_function)(sizes, s_eff)
+    stream = datagen.BatchStream(traffic, sizes["vocab_size"], global_micro,
+                                 seed)
     phases.mark("initialize_s")
     losses = []                 # device scalars, one per optimizer step
 
@@ -200,9 +222,7 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
         batch_sharding = jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec(mesh.axis_names))
         ref_loss = reference.step_loss(
-            engine.state["params"], first,
-            {"num_heads": mcfg.num_heads,
-             "layer_norm_eps": mcfg.layer_norm_eps},
+            engine.state["params"], first, sizes,
             max(1, checks["reference_chunk_tokens_per_chip"]
                 // traffic["seq_len"]) * chips,
             lambda x: jax.device_put(x, batch_sharding))
@@ -287,7 +307,8 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
           f"{mark_after}); lower reference_chunk_tokens_per_chip", problems)
     say(line="run", cell=cell, seed=seed, steps=steps, window_s=window_s,
         tokens_per_step=tokens_per_step, s_eff=s_eff,
-        flops_per_token=flops_per_token, n_params=model.meta["n_params"],
+        flops_per_token=flops_per_token, flops_function=flops_function,
+        n_params=sizes["n_params"],
         reference_loss=ref_loss, first_loss=host_losses[0],
         loss_vs_reference=host_losses[0] - ref_loss,
         losses=host_losses, setup_phases=phases,
@@ -307,9 +328,7 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
             "steps": steps, "spans": spans, "peaks": peaks, "s_eff": s_eff,
             "traffic": traffic,
             "tokens_per_step_per_chip": tokens_per_step / chips,
-            "model": {"num_layers": mcfg.num_layers, "d_model": mcfg.d_model,
-                      "num_heads": mcfg.num_heads,
-                      "n_params": model.meta["n_params"]}}, keep_trace)
+            "model": sizes}, keep_trace)
     else:
         rate = steps * tokens_per_step / window_s / chips
         result["metrics"] = {

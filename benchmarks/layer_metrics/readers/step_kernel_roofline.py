@@ -12,8 +12,7 @@ params:
 None / raises as step_phase does."""
 import re
 
-from harness import flops
-from layer_metrics.readers import step_phase
+from layer_metrics.readers import kernel_roofline, step_phase
 
 
 def read(ctx, params):
@@ -33,9 +32,5 @@ def read(ctx, params):
         raise step_phase.BrokenJoin(
             f"no Mosaic call of the traced step is named {sorted(kernels)} "
             f"in the program's map")
-    model = ctx["model"]
-    need = getattr(flops, params["flops"])(
-        ctx["tokens_per_step_per_chip"], model["num_layers"],
-        model["d_model"], ctx["s_eff"], params["passes"])
-    floor_ms = need / ctx["peaks"]["bf16_flops_per_s"] * 1e3
-    return 100.0 * floor_ms / (worst * 1e-6 / ctx["steps"])
+    return 100.0 * kernel_roofline.floor_ms(ctx, params) \
+        / (worst * 1e-6 / ctx["steps"])

@@ -109,7 +109,7 @@ def test_known_answers_on_hand_made_events(program):
     # copy.9 has no row; the other module's fusion.1 is not the step's
     assert value("step.unattributed_ms_per_step", ctx) \
         == pytest.approx(ms(50))
-    need = lambda passes: flops.causal_attention_flops(512, 2, 256, 256,
+    need = lambda passes: flops.causal_attention_flops(512, MODEL, 256,
                                                        passes)
     assert value("attention.flash_fwd_roofline", ctx) == pytest.approx(
         100 * need(["fwd", "fwd"]) / 197e12 / (200e-9 / 2))
@@ -227,7 +227,7 @@ def test_phases_sum_to_the_self_total_on_the_recording(recorded, program):
 def test_kernels_and_gathers_on_the_recording(recorded, program):
     program(hand_made_map(recorded))
     ctx = context(recorded, steps=4)
-    need = lambda passes: flops.causal_attention_flops(512, 2, 256, 256,
+    need = lambda passes: flops.causal_attention_flops(512, MODEL, 256,
                                                        passes)
     fwd_ms = need(["fwd", "fwd"]) / 197e12 * 1e3 \
         / (value("attention.flash_fwd_roofline", ctx) / 100)
