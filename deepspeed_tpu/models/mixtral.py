@@ -1,6 +1,12 @@
 """Mixtral-style MoE decoder: Llama blocks with top-k-routed expert SwiGLU
 FFNs, expert-parallel over the ``expert`` mesh axis (BASELINE.md config 5:
 Mixtral-8x7B EP + Ulysses SP).
+
+OLMoE (Muennighoff et al. 2024, arXiv:2409.02060) is the same family with
+three differences, each a field of :class:`MixtralConfig` and all set by
+the size ``olmoe-1b-7b``: RMSNorm on q and k (``qk_norm``), the chosen
+gates left un-normalised (``norm_topk_prob=False``), and the paper's two
+router losses (``load_balance="all_choices"``, ``router_z_loss_coef``).
 """
 from dataclasses import dataclass
 from functools import partial
@@ -11,11 +17,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import Model, qdot, resolve_size
+from deepspeed_tpu.models.model import Model, qdot, resolve_size, token_loss
 from deepspeed_tpu.models.llama import _rms_norm, rope
 from deepspeed_tpu.moe.layer import MoEConfig, moe_layer
 from deepspeed_tpu.moe.sharded_moe import topkgating
 from deepspeed_tpu.ops.attention import causal_attention
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_MLP)
 
 
 @dataclass(frozen=True)
@@ -44,6 +52,16 @@ class MixtralConfig:
     #: residual-dequant fallback (ISSUE 8).
     moe_dispatch: str = "auto"
     aux_loss_coef: float = 0.01
+    #: form of the load-balance term that ``aux_loss_coef`` weighs
+    #: (moe/sharded_moe.py LOAD_BALANCE_FORMS)
+    load_balance: str = "first_choice"
+    #: weight of mean_t(logsumexp(router logits)^2), summed over layers
+    router_z_loss_coef: float = 0.0
+    #: divide the top-k gate values by their sum (Mixtral); OLMoE does not
+    norm_topk_prob: bool = True
+    #: RMSNorm with a learned scale on the q and on the k projection, each
+    #: over its whole width, before the heads are split and rotated (OLMoE)
+    qk_norm: bool = False
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-5
     dtype: str = "bfloat16"
@@ -65,6 +83,9 @@ class MixtralConfig:
                          capacity_factor=self.capacity_factor,
                          eval_capacity_factor=eval_cf,
                          aux_loss_coef=self.aux_loss_coef,
+                         z_loss_coef=self.router_z_loss_coef,
+                         norm_topk_prob=self.norm_topk_prob,
+                         load_balance=self.load_balance,
                          activation="silu_glu",
                          dispatch_mode=self.moe_dispatch)
 
@@ -78,6 +99,16 @@ MIXTRAL_SIZES = {
                    num_heads=16, num_kv_heads=8, d_model=1024, d_ff=3584,
                    num_experts=8, top_k=2),
     "8x7b": dict(),
+    # OLMoE-1B-7B (huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct
+    # config.json): 64 experts of width 1024, 8 per token, MHA 16 x 128;
+    # loss weights 0.01 / 0.001 are the paper's.  6.9B parameters at its
+    # 16 layers; one chip trains num_layers=2 (benchmarks/configs)
+    "olmoe-1b-7b": dict(vocab_size=50304, max_seq_len=4096, num_layers=16,
+                        num_heads=16, num_kv_heads=16, d_model=2048,
+                        d_ff=1024, num_experts=64, top_k=8, rope_theta=1e4,
+                        rms_norm_eps=1e-5, qk_norm=True,
+                        norm_topk_prob=False, load_balance="all_choices",
+                        aux_loss_coef=0.01, router_z_loss_coef=0.001),
 }
 
 
@@ -89,6 +120,8 @@ def init_params(config: MixtralConfig, rng) -> dict:
     std = 0.02
     res_std = std / (2 * L) ** 0.5
     norm = partial(jax.random.normal, dtype=jnp.float32)
+    qk_norm = {"q_norm": jnp.ones((L, H * hd)),
+               "k_norm": jnp.ones((L, KV * hd))} if config.qk_norm else {}
     return {
         "wte": norm(next(k), (V, D)) * std,
         "blocks": {
@@ -97,6 +130,7 @@ def init_params(config: MixtralConfig, rng) -> dict:
             "wk": norm(next(k), (L, D, KV * hd)) * std,
             "wv": norm(next(k), (L, D, KV * hd)) * std,
             "wo": norm(next(k), (L, H * hd, D)) * res_std,
+            **qk_norm,
             "mlp_norm": jnp.ones((L, D)),
             "moe": {
                 "router": norm(next(k), (L, D, E)) * std,
@@ -119,6 +153,7 @@ def logical_specs(config: MixtralConfig) -> dict:
             "wk": P(None, None, "model"),
             "wv": P(None, None, "model"),
             "wo": P(None, "model", None),
+            **({"q_norm": P(), "k_norm": P()} if config.qk_norm else {}),
             "mlp_norm": P(),
             "moe": {
                 "router": P(),
@@ -137,10 +172,12 @@ def _qkv(x, layer, config: MixtralConfig, positions=None):
     B, S, D = x.shape
     H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
     h = _rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
-    q = rope(qdot(h, layer["wq"]).reshape(B, S, H, hd),
-             config.rope_theta, positions)
-    kk = rope(qdot(h, layer["wk"]).reshape(B, S, KV, hd),
-              config.rope_theta, positions)
+    q, kk = qdot(h, layer["wq"]), qdot(h, layer["wk"])
+    if config.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], config.rms_norm_eps)
+        kk = _rms_norm(kk, layer["k_norm"], config.rms_norm_eps)
+    q = rope(q.reshape(B, S, H, hd), config.rope_theta, positions)
+    kk = rope(kk.reshape(B, S, KV, hd), config.rope_theta, positions)
     v = qdot(h, layer["wv"]).reshape(B, S, KV, hd)
     return q, kk, v
 
@@ -148,21 +185,25 @@ def _qkv(x, layer, config: MixtralConfig, positions=None):
 def _moe_finish(x, attn_flat, layer, config: MixtralConfig, train: bool,
                 rng=None):
     """Attention output projection + residual + routed-expert FFN."""
-    x = x + qdot(attn_flat, layer["wo"])
-    h = _rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
-    moe_out, aux = moe_layer(layer["moe"], h, config.moe, train=train,
-                             rng=rng)
-    return x + moe_out, aux
+    with jax.named_scope(SCOPE_ATTN):
+        x = x + qdot(attn_flat, layer["wo"])
+    with jax.named_scope(SCOPE_MLP):
+        h = _rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
+        moe_out, aux = moe_layer(layer["moe"], h, config.moe, train=train,
+                                 rng=rng)
+        return x + moe_out, aux
 
 
+@jax.named_scope(SCOPE_BLOCK)
 def _block(carry, layer, config: MixtralConfig, train: bool, rng=None,
            segment_ids=None):
     x = carry
     B, S, D = x.shape
     H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
-    q, kk, v = _qkv(x, layer, config)
-    attn = causal_attention(q, kk, v, impl=config.attention_impl,
-                            segment_ids=segment_ids)
+    with jax.named_scope(SCOPE_ATTN):
+        q, kk, v = _qkv(x, layer, config)
+        attn = causal_attention(q, kk, v, impl=config.attention_impl,
+                                segment_ids=segment_ids)
     attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
     return _moe_finish(x, attn.reshape(B, S, H * hd), layer, config,
                        train, rng)
@@ -172,7 +213,8 @@ def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
                      rng=None):
     tokens = batch["input_ids"]
     dtype = jnp.dtype(config.dtype)
-    x = params["wte"].astype(dtype)[tokens]
+    with jax.named_scope(SCOPE_EMBED):
+        x = params["wte"].astype(dtype)[tokens]
     seg = batch.get("segment_ids") if isinstance(batch, dict) else None
     # stream-inside-remat (see models/model.py maybe_stream)
     def block_fn(x, layer):
@@ -184,8 +226,9 @@ def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
         block_fn = jax.checkpoint(
             block_fn, policy=remat_policy(config.remat_policy))
     x, aux = lax.scan(block_fn, x, params["blocks"])
-    x = _rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    return x @ params["lm_head"].astype(dtype), jnp.sum(aux)
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        x = _rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        return x @ params["lm_head"].astype(dtype), jnp.sum(aux)
 
 
 # --------------------------------------------------------------------- decode
@@ -224,6 +267,9 @@ def _serving_fns(config: MixtralConfig):
         norm="rms", eps=config.rms_norm_eps, qkv="split",
         qkv_bias=False, out_bias=False, mlp="none",
         rotary_dims=config.head_dim, rope_theta=config.rope_theta)
+
+    if config.qk_norm:
+        fused_spec = None       # the megakernel has no norm on q and k
 
     def fused_weights(layer):
         return {"n1_s": layer["attn_norm"], "wq": layer["wq"],
@@ -274,7 +320,6 @@ def count_params(config: MixtralConfig) -> int:
 
 
 def mixtral_model(size: str = "8x7b", **overrides) -> Model:
-    import optax
     cfg_kwargs = resolve_size(MIXTRAL_SIZES, size, "mixtral")
     cfg_kwargs.update(overrides)
     config = MixtralConfig(**cfg_kwargs)
@@ -284,11 +329,11 @@ def mixtral_model(size: str = "8x7b", **overrides) -> Model:
         3 * config.num_layers * config.num_experts * config.d_model * config.d_ff)
 
     def loss_fn(params, batch, rng=None):
-        tokens = batch["input_ids"]
         logits, aux = forward_with_aux(params, batch, config, train=True, rng=rng)
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1].astype(jnp.float32), tokens[:, 1:]).mean()
-        return ce + aux
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            # inside a document only, where the batch is packed; aux = the
+            # weighted router losses summed over layers (moe/layer.py)
+            return token_loss(logits, batch) + aux
 
     return Model(
         config=config,

@@ -357,34 +357,39 @@ class Model:
         return self.loss_fn(params, batch, rng)
 
 
-def _default_lm_loss(apply_fn):
+def token_loss(logits, batch):
+    """Mean next-token cross-entropy of ``logits`` [B, S, V] over the
+    positions that count: position t is scored against token t+1, not
+    where ``attention_mask`` is 0, and not across a document boundary of
+    a packed sequence (``segment_ids``)."""
     import jax.numpy as jnp
     import optax
+    tokens = batch["input_ids"]
+    targets = tokens[:, 1:]
+    logits = logits[:, :-1]
+    mask = batch.get("attention_mask")
+    losses = optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), targets)
+    m = None
+    if mask is not None:
+        m = mask[:, 1:].astype(jnp.float32)
+    seg = batch.get("segment_ids")
+    if seg is not None:
+        # packed sequences: the last token of one segment must not be
+        # scored against the first token of the next
+        same = (seg[:, 1:] == seg[:, :-1]).astype(jnp.float32)
+        m = same if m is None else m * same
+    if m is not None:
+        return (losses * m).sum() / jnp.maximum(m.sum(), 1.0)
+    return losses.mean()
+
+
+def _default_lm_loss(apply_fn):
     from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
 
     def loss_fn(params, batch, rng=None):
         logits = apply_fn(params, batch, rng)
         with jax.named_scope(SCOPE_HEAD_LOSS):
             return token_loss(logits, batch)
-
-    def token_loss(logits, batch):
-        tokens = batch["input_ids"]
-        targets = tokens[:, 1:]
-        logits = logits[:, :-1]
-        mask = batch.get("attention_mask")
-        losses = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), targets)
-        m = None
-        if mask is not None:
-            m = mask[:, 1:].astype(jnp.float32)
-        seg = batch.get("segment_ids")
-        if seg is not None:
-            # packed sequences: the last token of one segment must not be
-            # scored against the first token of the next
-            same = (seg[:, 1:] == seg[:, :-1]).astype(jnp.float32)
-            m = same if m is None else m * same
-        if m is not None:
-            return (losses * m).sum() / jnp.maximum(m.sum(), 1.0)
-        return losses.mean()
 
     return loss_fn

@@ -23,6 +23,14 @@ Two dispatch formulations (``MoEConfig.dispatch_mode``, ISSUE 8):
   kernel is real (single TPU device / interpret) or the host is
   single-device — a multi-device host where only the unsharded
   ragged_dot reference would run keeps the sharded einsum formulation.
+
+Training through the grouped formulation is what a model's builder asks
+for (``dispatch_mode="grouped"``, e.g. ``mixtral_model(...,
+moe_dispatch="grouped")``): forward, ``dx`` and ``dw`` then run as the
+named ``ds_ggemm_*`` kernels under ``lax.scan`` + ``jax.checkpoint`` and
+no token is dropped at any imbalance.  Its parts carry the
+``jax.named_scope``s ``router`` / ``dispatch`` / ``experts`` /
+``combine`` (telemetry/tracing.py ``STEP_SCOPES``).
 """
 import contextlib
 import os
@@ -37,6 +45,9 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.comm.mesh import get_topology, EXPERT_AXIS
 from deepspeed_tpu.moe.sharded_moe import (topkgating, topk_routing,
                                            GateOutput)
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_COMBINE, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_ROUTER,
+    count_in_step)
 
 
 @dataclass(frozen=True)
@@ -52,6 +63,14 @@ class MoEConfig:
     activation: str = "silu_glu"               # silu_glu (Mixtral) | gelu
     aux_loss_coef: float = 0.01
     z_loss_coef: float = 0.0
+    #: divide the k chosen gate values by their sum (Mixtral, the
+    #: reference); False keeps the softmax probabilities as they are
+    #: (OLMoE ``norm_topk_prob: false``)
+    norm_topk_prob: bool = True
+    #: form of the load-balance term (sharded_moe.LOAD_BALANCE_FORMS):
+    #: "first_choice" (the reference) or "all_choices" (OLMoE / Hugging
+    #: Face ``load_balancing_loss_func``)
+    load_balance: str = "first_choice"
     #: Residual MoE (reference moe/layer.py:28 ``use_residual``, the PR-MoE
     #: building block, arXiv:2201.05596): a dense FFN runs beside the
     #: routed experts and a learned 2-way softmax coefficient mixes them
@@ -293,42 +312,58 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     T, D = xt.shape
     E, k = config.num_experts, config.top_k
     dt = xt.dtype
-    logits = _routing_logits(params, xt, config)
-    routing = topk_routing(
-        logits, config.top_k,
-        rng if (train and config.noisy_gate_policy) else None,
-        config.z_loss_coef)
+    with jax.named_scope(SCOPE_ROUTER):
+        logits = _routing_logits(params, xt, config)
+        routing = _route(logits, config, train, rng)
     _emit_router_health(logits, routing, config)
     eids = routing.expert_idx.reshape(-1)               # [T*k]
     gates = routing.gate_weights.reshape(-1)            # [T*k] fp32
-    tids = jnp.arange(T * k, dtype=jnp.int32) // k
-    rows = jnp.take(xt, tids, axis=0)                   # [T*k, D]
+    with jax.named_scope(SCOPE_DISPATCH):
+        tids = jnp.arange(T * k, dtype=jnp.int32) // k
+        rows = jnp.take(xt, tids, axis=0)               # [T*k, D]
 
     w_gate = params.get("w_gate")
     w_in, w_out = params["w_in"], params["w_out"]
 
     R = T * k
-    kernel_real = gg_kernel_real()
-    if kernel_real and not train and R <= gg.SLOT_MAX_ROWS:
+    slots = gg_kernel_real() and not train and R <= gg.SLOT_MAX_ROWS
+    if slots:
         # decode/verify-sized: the slot kernels stream each DISTINCT
         # routed expert's weights exactly once — the top-k-distinct
         # expert floor — with no scatter/gather at all
         plan = gg.make_slot_plan(eids, E)
         mm = partial(gg.ds_ggemm_slots, plan=plan, out_dtype=dt)
-        y = _glu(mm, rows, w_gate, w_in, config)
-        y = mm(y, w_out)
-        out_rows = y
+        with jax.named_scope(SCOPE_EXPERTS):
+            y = mm(_glu(mm, rows, w_gate, w_in, config), w_out)
     else:
-        plan = gg.make_group_plan(eids, E)
-        x_pad = gg.scatter_to_groups(rows, plan)
+        with jax.named_scope(SCOPE_DISPATCH):
+            plan = gg.make_group_plan(eids, E)
+            x_pad = gg.scatter_to_groups(rows, plan)
+        # both are shapes: the step's own account of what its grouped
+        # calls compute (rows past the routed ones are zeros)
+        count_in_step(grouped_routed_rows=R,
+                      grouped_padded_rows=plan.padded_rows)
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
-        h = _glu(mm, x_pad, w_gate, w_in, config)
-        y = mm(h, w_out)                                # [Mp, D]
-        out_rows = gg.gather_from_groups(y, plan)       # [T*k, D]
-    combined = jnp.sum(
-        (gates.astype(dt)[:, None] * out_rows).reshape(T, k, D), axis=1)
+        with jax.named_scope(SCOPE_EXPERTS):
+            h = _glu(mm, x_pad, w_gate, w_in, config)
+            y = mm(h, w_out)                            # [Mp, D]
+    with jax.named_scope(SCOPE_COMBINE):
+        # the slot kernels' rows are in flat routed order already
+        out_rows = y if slots else gg.gather_from_groups(y, plan)
+        combined = jnp.sum(
+            (gates.astype(dt)[:, None] * out_rows).reshape(T, k, D),
+            axis=1)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
     return combined, aux, (jnp.int32(R), jnp.int32(0))
+
+
+def _route(logits, config: MoEConfig, train: bool, rng):
+    """The one selection both dispatch formulations consume."""
+    return topk_routing(
+        logits, config.top_k,
+        rng if (train and config.noisy_gate_policy) else None,
+        config.z_loss_coef, normalize=config.norm_topk_prob,
+        load_balance=config.load_balance)
 
 
 def _glu(mm, x, w_gate, w_in, config: MoEConfig):
@@ -385,14 +420,14 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
         return _finish_residual(params, x, moe_out, aux, config)
     # qdot: int8 serving keeps the (stacked-2-D) router quantized — the
     # fused-dequant qgemm consumes it; plain arrays take the same matmul
-    logits = wsc(_routing_logits(params, xt, config), tok_sh)
     cf = config.capacity_factor if train else config.eval_capacity_factor
     noise = rng if (train and config.noisy_gate_policy) else None
     # selection runs ONCE and feeds both the capacity tensors and the
     # router-health tap — the grouped path consumes the same decision,
     # so the two modes publish bitwise-identical health numbers
-    routing = topk_routing(logits, config.top_k, noise,
-                           config.z_loss_coef)
+    with jax.named_scope(SCOPE_ROUTER):
+        logits = wsc(_routing_logits(params, xt, config), tok_sh)
+        routing = _route(logits, config, train, rng)
     _emit_router_health(logits, routing, config)
     gate: GateOutput = topkgating(logits, config.top_k, cf,
                                   config.min_capacity, noise,

@@ -33,13 +33,28 @@ class TopKRouting(NamedTuple):
     gate_weights: jnp.ndarray     # [T, k] fp32 normalized gate values
 
 
+#: forms of the load-balance term ``E * sum_e f_e * P_e`` (P_e = mean
+#: router probability of expert e): ``first_choice`` takes f_e from each
+#: token's first choice alone (the reference's top-1 form, sum_e f_e = 1);
+#: ``all_choices`` counts every one of the k (token, choice) pairs sent to
+#: e, over T (Hugging Face ``load_balancing_loss_func``, sum_e f_e = k)
+LOAD_BALANCE_FORMS = ("first_choice", "all_choices")
+
+
 def topk_routing(logits: jnp.ndarray, k: int,
                  noise_rng: Optional[jax.Array] = None,
-                 z_loss_coef: float = 0.0) -> TopKRouting:
+                 z_loss_coef: float = 0.0, normalize: bool = True,
+                 load_balance: str = "first_choice") -> TopKRouting:
     """The selection/aux half of :func:`topkgating`, verbatim (iterative
     argmax with -1e9 suppression, top-1 aux loss, per-token gate
     normalization) — extracted so capacity enforcement is a property of
-    the DISPATCH, not of the routing decision."""
+    the DISPATCH, not of the routing decision.  ``normalize=False`` keeps
+    the chosen softmax probabilities as they are (OLMoE's
+    ``norm_topk_prob: false``); ``load_balance`` picks the form of
+    ``l_aux`` (:data:`LOAD_BALANCE_FORMS`)."""
+    if load_balance not in LOAD_BALANCE_FORMS:
+        raise ValueError(f"load_balance {load_balance!r}: choose one of "
+                         f"{LOAD_BALANCE_FORMS}")
     T, E = logits.shape
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
 
@@ -68,10 +83,16 @@ def topk_routing(logits: jnp.ndarray, k: int,
             gates, idx[:, None], axis=1)[:, 0])
         remaining = remaining - jax.nn.one_hot(idx, E) * 1e9
 
-    denom = sum(chosen_gates)
-    denom = jnp.maximum(denom, jnp.finfo(jnp.float32).eps)
     expert_idx = jnp.stack(chosen_idx, axis=1).astype(jnp.int32)
-    gate_weights = jnp.stack([g / denom for g in chosen_gates], axis=1)
+    if load_balance == "all_choices":
+        fe = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32),
+                     axis=(0, 1)) / T
+        l_aux = jnp.sum(me * fe) * E
+    if normalize:
+        denom = sum(chosen_gates)
+        denom = jnp.maximum(denom, jnp.finfo(jnp.float32).eps)
+        chosen_gates = [g / denom for g in chosen_gates]
+    gate_weights = jnp.stack(chosen_gates, axis=1)
     return TopKRouting(l_aux, z_loss, expert_idx, gate_weights)
 
 

@@ -35,6 +35,14 @@ layout) serves correctness and autodiff; ``interpret=True`` (or
 ``DS_GGEMM_INTERPRET=1``) runs the real kernels in interpret mode so the
 CPU tier-1 suite exercises them.  Block shapes are sweepable via
 ``DS_GGEMM_BLOCKS="bm,bk,bn"`` / ``scripts/ggemm_sweep.py``.
+
+Every ``pl.pallas_call`` here has a ``name=``, which is how the
+step-program map (``get_program_map`` of telemetry/tracing.py) tells
+these kernels from the flash kernel in a compiled step: ``ds_ggemm_fwd``,
+``ds_ggemm_dx`` (the same kernel body on a transposed right-hand side),
+``ds_ggemm_dw`` (the three of ``KERNEL_NAMES`` that training runs),
+``ds_ggemm_q`` (int8 weights) and ``ds_ggemm_slots`` /
+``ds_ggemm_slots_q`` (decode-sized).
 """
 import functools
 import os
@@ -348,6 +356,8 @@ def _pallas_ggemm(x, w, gids, block_m, *, block_k, block_n, interpret,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N_pad), out_dtype),
         interpret=interpret,
+        name=("ds_ggemm_q" if scales is not None
+              else "ds_ggemm_dx" if transpose_rhs else "ds_ggemm_fwd"),
     )(gids, *operands)
     return out[:, :N]
 
@@ -384,6 +394,7 @@ def _pallas_tgmm(x, dy, gids, block_m, num_experts, *, block_k, block_n,
         out_shape=jax.ShapeDtypeStruct(
             (num_experts, K_pad, N_pad), jnp.dtype(out_dtype)),
         interpret=interpret,
+        name="ds_ggemm_dw",
     )(gids, x, dy)
     return out[:, :K, :N]
 
@@ -522,6 +533,7 @@ def _pallas_ggemm_slots(x, w, plan: SlotPlan, *, block_k, block_n,
         ),
         out_shape=jax.ShapeDtypeStruct((bm, N_pad), out_dtype),
         interpret=interpret,
+        name="ds_ggemm_slots_q" if scales is not None else "ds_ggemm_slots",
     )(plan.active, plan.valid, *operands)
     return out[:R, :N]
 

@@ -39,7 +39,7 @@ from deepspeed_tpu.runtime.fp16.loss_scaler import (
     create_loss_scaler, has_overflow, update_scale)
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
-    register_program)
+    register_program, step_account)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
     SynchronizedWallClockTimer, ThroughputTimer, TRAIN_BATCH_TIMER)
@@ -1606,7 +1606,7 @@ class DeepSpeedEngine:
                 "stage-3 quantized-exchange tier (compressing per-shard "
                 "would disagree across devices); training uncompressed")
 
-        def train_step(state, stacked_batch, rng):
+        def step_body(state, stacked_batch, rng):
             """stacked_batch leaves: [gas, global_micro, ...]."""
             params, opt_state = state["params"], state["opt_state"]
             scaler = state["scaler"]
@@ -1688,6 +1688,12 @@ class DeepSpeedEngine:
             metrics["loss"] = loss_sum / scale
             return new_state, metrics
 
+        def train_step(state, stacked_batch, rng):
+            # this body runs while the step is traced: what the model's
+            # code counts of itself (tracing.count_in_step) is the account
+            # of this program.  (The name is the compiled module's.)
+            with step_account(TRAIN_STEP_PROGRAM):
+                return step_body(state, stacked_batch, rng)
         return train_step
 
     def _build_pipeline_train_step(self):

@@ -297,11 +297,20 @@ SCOPE_BLOCK = "ds.block"            # model: one transformer block ...
 SCOPE_ATTN = "attn"                 # ... LN1, QKV, attention, projection
 SCOPE_MLP = "mlp"                   # ... LN2, MLP
 SCOPE_HEAD_LOSS = "ds.head_loss"    # model: final LN, logits, cross-entropy
+# inside ``mlp``, where it is a routed-expert layer (moe/layer.py):
+SCOPE_ROUTER = "router"             # router matmul, softmax, top-k, aux terms
+SCOPE_DISPATCH = "dispatch"         # rows by (token, choice), sort, scatter
+SCOPE_EXPERTS = "experts"           # the grouped GEMMs and the activation
+SCOPE_COMBINE = "combine"           # gather back, gate-weighted sum over k
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
-               SCOPE_HEAD_LOSS)
-#: ``name=`` of each ``pl.pallas_call`` of the flash kernel
-KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq")
+               SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
+               SCOPE_COMBINE)
+#: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
+#: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
+#: on a transposed right-hand side) and dw
+KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
+                "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -309,7 +318,9 @@ PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
 TRAIN_STEP_PROGRAM = "train/step"
 
 _DS_SCOPE = re.compile(r"(?:^|[/(])(ds\.[a-z_]+)")
-_KERNEL = re.compile(r"/([^/()]+)/pallas_call$")
+# ``.../experts/ds_ggemm_fwd/pallas_call``; where the kernel's name is the
+# outermost scope under a transform, ``transpose(jvp(ds_ggemm_dx))/...``
+_KERNEL = re.compile(r"[/(]([^/()]+)\)*/pallas_call$")
 
 
 def phase_of(op_name: Optional[str]) -> str:
@@ -549,8 +560,52 @@ def layer_loop_gathers(name: str = TRAIN_STEP_PROGRAM):
             "by_phase": by_phase}
 
 
+# -- counts the step states about itself while it is traced
+_STEP_COUNTERS: Dict[str, Dict[str, int]] = {}
+_ACCOUNT_OPEN: Optional[str] = None
+
+
+@contextmanager
+def step_account(name: str = TRAIN_STEP_PROGRAM):
+    """Entered by the engine in the traced body of its step: whatever the
+    model code below calls :func:`count_in_step` with while this trace
+    runs is the account of the program ``name``.  Every trace starts it
+    anew, so it describes the step as last traced."""
+    global _ACCOUNT_OPEN
+    outer, _ACCOUNT_OPEN = _ACCOUNT_OPEN, name
+    _STEP_COUNTERS[name] = {}
+    try:
+        yield
+    finally:
+        _ACCOUNT_OPEN = outer
+
+
+def count_in_step(**counters: int):
+    """Trace-time, static values only (shapes): no host callback, nothing
+    in the compiled step.  A no-op outside :func:`step_account`."""
+    if _ACCOUNT_OPEN is not None:
+        _STEP_COUNTERS[_ACCOUNT_OPEN].update(
+            {k: int(v) for k, v in counters.items()})
+
+
+def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
+    """What one grouped GEMM call of the step computes, beside
+    :func:`layer_loop_gathers`: ``{"routed_rows_per_call",
+    "padded_rows_per_call"}``, shapes that moe/layer.py wrote when the
+    plan was traced (every grouped call of a step has the same: R =
+    tokens x top_k routed rows inside ``round_up(R, bm) + E*bm`` padded
+    ones, the rest zeros).  None where no step with a grouped dispatch
+    was traced."""
+    account = _STEP_COUNTERS.get(name, {})
+    if "grouped_padded_rows" not in account:
+        return None
+    return {"routed_rows_per_call": account["grouped_routed_rows"],
+            "padded_rows_per_call": account["grouped_padded_rows"]}
+
+
 def reset_programs():
     """Tests: forget every registered program."""
     with _PROGRAM_LOCK:
         _PROGRAM_THUNKS.clear()
         _PROGRAM_MAPS.clear()
+    _STEP_COUNTERS.clear()

@@ -1,0 +1,100 @@
+"""Gradients of one micro-batch of a benchmark cell on the chip, at the
+cell's own size: the engine's (``engine.forward``: its kernels, remat and
+precision) against ``jax.grad`` of the cell's plain reference (float32,
+``highest``) at the engine's own parameters.
+
+    chiprun --chips 1 -- python scripts/olmoe_grad_check.py --seed <n> ...
+
+One JSON line per seed: for every gradient leaf ``max |a - b| / max |b|``
+and ``|a - b|_2 / |b|_2``, engine against reference, and the same for the
+reference with bf16 products against itself (the noise a bf16 program
+cannot be under).  A leaf whose gradient never arrived reads 1.0.
+"""
+import argparse
+import gc
+import importlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
+
+from drivers.train_steps import build_engine, build_model    # noqa: E402
+from harness import datagen                                   # noqa: E402
+from harness.manifest import Manifest                         # noqa: E402
+
+
+def errors(got, want):
+    """{leaf: max |a - b| / max |b| and |a - b|_2 / |b|_2}."""
+    out = {}
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        out[jax.tree_util.keystr(path)] = {
+            "max_rel": float(np.abs(a - b).max() / np.abs(b).max()),
+            "l2_rel": float(np.linalg.norm(a - b) / np.linalg.norm(b))}
+    return out
+
+
+def one_seed(workload, config, traffic, seed):
+    model = build_model(config)
+    sizes = {**config["model"], "n_params": model.meta["n_params"]}
+    engine, _ = build_engine(config, traffic, model, seed, jax.devices())
+    gas = traffic["gradient_accumulation_steps"]
+    first = datagen.BatchStream(traffic, sizes["vocab_size"],
+                                traffic["micro_batch_per_chip"], seed).next()
+    micro = {k: np.asarray(v)[0] for k, v in first.items()}
+    loss = float(engine.forward(micro))
+    # the engine scales a micro-batch's gradient by 1 / gas
+    got = jax.tree.map(lambda g: np.asarray(g.astype(jnp.float32)) * gas,
+                       engine._pending_grads)
+    params = jax.tree.map(np.asarray, engine.state["params"])
+    # the reference's float32 gradient needs the room the engine's state has
+    for leaf in jax.tree.leaves((engine.state, engine._pending_grads)):
+        leaf.delete()
+    del engine
+    gc.collect()
+
+    reference = importlib.import_module("references." + config["reference"])
+    params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
+
+    def grad(matmul_dtype):
+        fn = jax.jit(jax.value_and_grad(lambda p: reference.micro_batch_loss(
+            p, jnp.asarray(micro["input_ids"]),
+            jnp.asarray(micro["segment_ids"]), sizes,
+            matmul_dtype=matmul_dtype, remat=True)))
+        with jax.default_matmul_precision("highest"):
+            value, grads = fn(params)
+        return float(value), jax.tree.map(np.asarray, grads)
+
+    want_loss, want = grad(None)
+    low_loss, low = grad(jnp.bfloat16)
+    print(json.dumps({
+        "workload": workload, "seed": seed,
+        "device": jax.devices()[0].device_kind,
+        "loss": {"engine": loss, "reference": want_loss,
+                 "reference_bf16": low_loss},
+        "engine_vs_reference": errors(got, want),
+        "reference_bf16_vs_reference": errors(low, want)}), flush=True)
+
+
+if __name__ == "__main__":
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", default="olmoe-1b-7b.packed-s4096-gas8")
+    parser.add_argument("--seed", type=int, nargs="+", required=True)
+    parser.add_argument("--rehearse", action="store_true",
+                        help="the cell's toy sizes, for a run on the CPU")
+    args = parser.parse_args()
+    if args.rehearse:
+        sys.path.insert(0, os.path.join(ROOT, "benchmarks", "tests"))
+        from rehearse import toy
+        _, config, traffic = toy(Manifest(ROOT), args.workload)
+    else:
+        _, config, traffic = Manifest(ROOT).cell(args.workload)
+    for seed in args.seed:
+        one_seed(args.workload, config, traffic, seed)

@@ -1,7 +1,8 @@
 """Compiles for a TPU v5e that is described, not attached (the chip's
 compiler is installed on CPU-only boxes): the training path's kernels at
-GPT-2 760M width go through Mosaic, the data-sharded flash kernel goes
-through the partitioner, and the library knows the chip's peaks.
+GPT-2 760M width, and the grouped GEMM kernels at OLMoE-1B-7B's, go
+through Mosaic, the data-sharded flash kernel goes through the
+partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
 or times.  ``chip_smoke.py`` is the run."""
@@ -79,11 +80,26 @@ def _decode(q, k, v, n, ks=None, vs=None):
     return decode_attention_pallas(q, k, v, n, k_scale=ks, v_scale=vs)
 
 
+def _ggemm(x, w, gids):
+    """The differentiable grouped GEMM as moe/layer.py's training path
+    calls it (ds_ggemm with the reference switched off: no TPU here)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    return gg._ggemm_diff(x, w, gids, gg.DEFAULT_BLOCK_M, GG_E,
+                          (gg.DEFAULT_BLOCK_K, gg.DEFAULT_BLOCK_N), False)
+
+
+# olmoe-1b-7b.packed-s4096-gas8: 4096 tokens x 8 choices = 32,768 routed
+# rows over 64 experts, padded to 32,768 + 64 * 128; D 2048 -> F 1024
+GG_E, GG_ROWS, GG_D, GG_F = 64, 32768 + 64 * 128, 2048, 1024
+_GGEMM = [((GG_ROWS, GG_D), jnp.bfloat16), ((GG_E, GG_D, GG_F), jnp.bfloat16),
+          ((GG_ROWS // 128,), jnp.int32)]
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
     "ds_flash_fwd": (_ds_flash, _QKV),
     "ds_flash_fwd_bwd": (jax.grad(_sum_sq(_ds_flash), (0, 1, 2)), _QKV),
+    "ds_ggemm_fwd": (_ggemm, _GGEMM),
+    "ds_ggemm_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)), _GGEMM),
     "stock_flash_fwd": (_stock_flash, _QKV),
     "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
                             _QKV),
@@ -106,12 +122,28 @@ KERNEL_CASES = {
 }
 
 
+#: the kernel names (``name=`` of the pl.pallas_call) a case's compiled
+#: text must hold, where the benchmark reads a kernel by its name
+NAMED_KERNELS = {
+    "ds_flash_fwd_bwd": {"ds_flash_fwd", "ds_flash_bwd_dkv",
+                         "ds_flash_bwd_dq"},
+    "ds_ggemm_fwd": {"ds_ggemm_fwd"},
+    "ds_ggemm_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+}
+
+
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernel_compiles_for_v5e(v5e, case):
     fn, args = KERNEL_CASES[case]
     compiled = jax.jit(fn).lower(
         *(_arg(v5e[0], shape, dtype) for shape, dtype in args)).compile()
     assert KERNEL in compiled.as_text()
+    if case in NAMED_KERNELS:
+        # the program's own map tells these Mosaic calls apart by name
+        from deepspeed_tpu.telemetry.tracing import parse_program_text
+        named = {row["kernel"] for row in
+                 parse_program_text(compiled.as_text()).values()}
+        assert NAMED_KERNELS[case] <= named, named
 
 
 @pytest.mark.parametrize("manual_outside", [False, True],

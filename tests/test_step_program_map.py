@@ -14,8 +14,8 @@ from deepspeed_tpu.telemetry import reset_tracer
 from deepspeed_tpu.telemetry.costmodel import ring_wire_factor
 from deepspeed_tpu.telemetry.tracing import (
     KERNEL_NAMES, NULL_TRACER, PHASES, STEP_SCOPES, SpanTracer,
-    get_program_map, parse_program_text, phase_of, register_program,
-    reset_programs)
+    count_in_step, get_program_map, grouped_gemm_rows, parse_program_text,
+    phase_of, register_program, reset_programs, step_account)
 from tests.util import base_config, random_batch, tiny_gpt2
 
 
@@ -80,9 +80,11 @@ def test_phase_of(case):
 def test_fixed_names():
     assert STEP_SCOPES == ("ds.fwd_bwd", "ds.accumulate", "ds.optimizer",
                            "ds.embed", "ds.block", "attn", "mlp",
-                           "ds.head_loss")
+                           "ds.head_loss", "router", "dispatch", "experts",
+                           "combine")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
-                            "ds_flash_bwd_dq")
+                            "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
+                            "ds_ggemm_dw")
 
 
 # ------------------------------------------------------- the text's parser
@@ -152,6 +154,35 @@ def test_parse_hand_written_text():
                  "rematted_computation/ds.block/mlp/mul",
         "phase": "recompute", "kernel": None, "collective": None,
         "wire_bytes": None}
+
+
+# ------------------------------------------- the step's account of itself
+ROWS = dict(grouped_routed_rows=32768, grouped_padded_rows=40960)
+ACCOUNT_CASES = {
+    # what is counted where -> what grouped_gemm_rows("train/step") says
+    "inside": ("train/step", ROWS, {"routed_rows_per_call": 32768,
+                                    "padded_rows_per_call": 40960}),
+    "another_program": ("eval/step", ROWS, None),
+    "no_grouped_dispatch": ("train/step", dict(other=1), None),
+    "outside_any_account": (None, ROWS, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ACCOUNT_CASES))
+def test_step_account(case):
+    """Counts are kept under the program whose trace is running, a count
+    outside any trace is dropped, and a new trace starts the account
+    anew (it describes the step as last traced)."""
+    program, counters, want = ACCOUNT_CASES[case]
+    if program is None:
+        count_in_step(**counters)
+    else:
+        with step_account(program):
+            count_in_step(**counters)
+    assert grouped_gemm_rows("train/step") == want
+    with step_account("train/step"):
+        pass
+    assert grouped_gemm_rows("train/step") is None
 
 
 # ------------------------------------------------------ a compiled toy step
