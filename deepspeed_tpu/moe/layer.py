@@ -302,12 +302,17 @@ def _expert_ffn(params, x, config: MoEConfig):
 
 
 def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
-    """Megablocks-style drop-free dispatch (ISSUE 8 tentpole): argsort
-    the [T·k] routed (token, choice) pairs by expert, run the expert FFN
-    as grouped GEMMs over the sorted rows (ops/pallas/grouped_gemm.py —
-    zero capacity padding, no [T, E, C] tensors), and combine each
-    token's k outputs by gather + normalized-gate weighting.  Returns
-    (combined [T, D], aux scalar, (dispatched, dropped))."""
+    """Megablocks-style drop-free dispatch (ISSUE 8 tentpole): sort the
+    [T·k] routed (token, choice) pairs by expert into a group-padded
+    layout, run the expert FFN as grouped GEMMs over it
+    (ops/pallas/grouped_gemm.py — zero capacity padding, no [T, E, C]
+    tensors), and combine each token's k outputs by gate weighting.  The
+    rows move by gathers only, through the plan's two index maps: one
+    from the token-major ``xt`` straight into the padded layout
+    (``dispatch_rows``: no [T·k, D] copy), one back out of the experts'
+    output (``combine_rows``), and their hand-written backward passes are
+    gathers too — no scatter is traced here.  Returns (combined [T, D],
+    aux scalar, (dispatched, dropped))."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     T, D = xt.shape
     E, k = config.num_experts, config.top_k
@@ -318,27 +323,29 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     _emit_router_health(logits, routing, config)
     eids = routing.expert_idx.reshape(-1)               # [T*k]
     gates = routing.gate_weights.reshape(-1)            # [T*k] fp32
-    with jax.named_scope(SCOPE_DISPATCH):
-        tids = jnp.arange(T * k, dtype=jnp.int32) // k
-        rows = jnp.take(xt, tids, axis=0)               # [T*k, D]
 
     w_gate = params.get("w_gate")
     w_in, w_out = params["w_in"], params["w_out"]
 
     R = T * k
-    slots = gg_kernel_real() and not train and R <= gg.SLOT_MAX_ROWS
-    if slots:
+    if gg_kernel_real() and not train and R <= gg.SLOT_MAX_ROWS:
         # decode/verify-sized: the slot kernels stream each DISTINCT
         # routed expert's weights exactly once — the top-k-distinct
-        # expert floor — with no scatter/gather at all
-        plan = gg.make_slot_plan(eids, E)
+        # expert floor — over the raw rows in flat routed order: no
+        # plan and no group-padded layout at all
+        with jax.named_scope(SCOPE_DISPATCH):
+            rows = jnp.repeat(xt, k, axis=0)            # [T*k, D]
+            plan = gg.make_slot_plan(eids, E)
         mm = partial(gg.ds_ggemm_slots, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
             y = mm(_glu(mm, rows, w_gate, w_in, config), w_out)
+        with jax.named_scope(SCOPE_COMBINE):
+            combined = jnp.sum(
+                (gates.astype(dt)[:, None] * y).reshape(T, k, D), axis=1)
     else:
         with jax.named_scope(SCOPE_DISPATCH):
             plan = gg.make_group_plan(eids, E)
-            x_pad = gg.scatter_to_groups(rows, plan)
+            x_pad = gg.dispatch_rows(xt, plan, k)       # [Mp, D]
         # both are shapes: the step's own account of what its grouped
         # calls compute (rows past the routed ones are zeros)
         count_in_step(grouped_routed_rows=R,
@@ -347,12 +354,8 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
         with jax.named_scope(SCOPE_EXPERTS):
             h = _glu(mm, x_pad, w_gate, w_in, config)
             y = mm(h, w_out)                            # [Mp, D]
-    with jax.named_scope(SCOPE_COMBINE):
-        # the slot kernels' rows are in flat routed order already
-        out_rows = y if slots else gg.gather_from_groups(y, plan)
-        combined = jnp.sum(
-            (gates.astype(dt)[:, None] * out_rows).reshape(T, k, D),
-            axis=1)
+        with jax.named_scope(SCOPE_COMBINE):
+            combined = gg.combine_rows(y, gates, plan, k)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
     return combined, aux, (jnp.int32(R), jnp.int32(0))
 

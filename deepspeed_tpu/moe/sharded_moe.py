@@ -79,9 +79,11 @@ def topk_routing(logits: jnp.ndarray, k: int,
     for _ in range(k):
         idx = jnp.argmax(remaining, axis=-1)
         chosen_idx.append(idx)
-        chosen_gates.append(jnp.take_along_axis(
-            gates, idx[:, None], axis=1)[:, 0])
-        remaining = remaining - jax.nn.one_hot(idx, E) * 1e9
+        picked = jax.nn.one_hot(idx, E)
+        # the chosen gate as a masked sum (one term and zeros: exact), so
+        # that its transpose is a product and not a scatter-add
+        chosen_gates.append(jnp.sum(gates * picked, axis=1))
+        remaining = remaining - picked * 1e9
 
     expert_idx = jnp.stack(chosen_idx, axis=1).astype(jnp.int32)
     if load_balance == "all_choices":

@@ -7,14 +7,22 @@ every expert to capacity ``C`` — measured at roughly HALF dense MFU on
 the 760M-class MoE bench (PERF.md round 5).  This module reformulates
 expert computation as ONE ragged GEMM over tokens sorted by expert:
 
-1. :func:`make_group_plan` — argsort the flat ``[T·k]`` expert choices,
-   pad each expert's contiguous group up to a multiple of the M-tile
-   (``block_m``; empty experts keep one all-zero tile so backward tiles
-   are always written), and precompute the CSR-like padded offsets plus
-   a per-M-tile expert id (``block_group_ids``, non-decreasing).  The
-   padded row count is **static** (``round_up(T·k, bm) + E·bm``) so the
-   whole pipeline jits; the only waste is < one tile per expert, versus
-   the capacity formulation's ``E·C - T·k`` slots.
+1. :func:`make_group_plan` — one stable sort of the flat ``[T·k]``
+   expert choices, together with the padding entries that round each
+   expert's contiguous group up to a multiple of the M-tile (``block_m``;
+   empty experts keep one all-zero tile so backward tiles are always
+   written), gives ``padded_to_row``; sorted back it gives its inverse
+   ``row_to_padded``; beside them the per-M-tile expert id
+   (``block_group_ids``, non-decreasing).  The padded row count is
+   **static** (``round_up(T·k, bm) + E·bm``) so the whole pipeline jits;
+   the only waste is < one tile per expert, versus the capacity
+   formulation's ``E·C - T·k`` slots.  Rows move through the two maps
+   by gathers only — :func:`dispatch_rows` from the token-major
+   activations straight into the padded layout, :func:`combine_rows`
+   back out with the gate-weighted sum, each with a hand-written
+   backward that gathers through the other map (autodiff would write a
+   scatter-add, which costs 3× a gather of the same rows on a v5e) —
+   and the plan itself holds no scatter either.
 2. :func:`ds_ggemm` — one Pallas kernel over grid ``(m_tiles, N/bn,
    K/bk)``: the M-grid walks group boundaries via a scalar-prefetched
    ``block_group_ids`` map (the block_sparse_attention idiom), so each
@@ -81,8 +89,11 @@ class GroupPlan(NamedTuple):
     """Static-shape layout for one routed batch (see module docstring).
 
     ``row_to_padded[f]`` maps flat routed element ``f`` (token-major:
-    ``f = t * top_k + choice``) to its row in the group-padded array —
-    scatter inputs through it, gather expert outputs back through it.
+    ``f = t * top_k + choice``) to its row in the group-padded array, and
+    ``padded_to_row[p]`` is its inverse: the flat element that sits in
+    padded row ``p``, ``R`` (out of range) on a padding row.  Rows move
+    through the pair by gathers alone, in both directions and in both
+    backward passes (:func:`dispatch_rows`, :func:`combine_rows`).
     """
     block_m: int                   # static M-tile the layout is padded to
     padded_rows: int               # static padded row count (Mp)
@@ -91,6 +102,7 @@ class GroupPlan(NamedTuple):
     group_sizes: jnp.ndarray       # [E] padded rows per expert (⋅bm, ≥ bm)
     block_group_ids: jnp.ndarray   # [num_blocks] expert per M-tile (sorted)
     row_to_padded: jnp.ndarray     # [R] flat element -> padded row
+    padded_to_row: jnp.ndarray     # [Mp] padded row -> flat element | R
     counts: jnp.ndarray            # [E] true routed counts (telemetry)
 
 
@@ -98,28 +110,40 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
                     block_m: Optional[int] = None) -> GroupPlan:
     """``expert_ids`` [R] int32 (R static, e.g. T·top_k) -> GroupPlan.
 
-    All outputs have static shapes; values are data-dependent.  Stable
-    argsort keeps token order within an expert (determinism + the exact
-    addition order the parity tests pin down).
+    All outputs have static shapes; values are data-dependent.  No
+    scatter: the counts are a comparison and a sum, and ONE stable sort
+    lays the padded rows out — the R routed elements keyed by their
+    expert, followed by the ``Mp - R`` padding entries, each keyed by the
+    expert whose group it completes (left-over ones after every group).
+    Its payload is ``padded_to_row``; the stable order keeps token order
+    within an expert (determinism + the exact addition order the parity
+    tests pin down) and puts an expert's padding after its tokens.
+    ``row_to_padded`` is the same permutation sorted back by element.
     """
     R = int(expert_ids.shape[0])
     E = int(num_experts)
     bm = int(block_m or default_block_m())
     eids = expert_ids.astype(jnp.int32)
-    order = jnp.argsort(eids, stable=True)
-    sorted_eids = jnp.take(eids, order)
-    counts = jnp.zeros((E,), jnp.int32).at[eids].add(1)
+    experts = jnp.arange(E, dtype=jnp.int32)
+    counts = jnp.sum((eids[:, None] == experts[None, :]).astype(jnp.int32),
+                     axis=0)
     blocks_e = jnp.maximum(-(-counts // bm), 1)        # ≥1 tile per expert
     group_sizes = blocks_e * bm
-    pstart = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes)])
-    start = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
-    rank = jnp.arange(R, dtype=jnp.int32) - jnp.take(start, sorted_eids)
-    prow_sorted = jnp.take(pstart, sorted_eids) + rank
-    row_to_padded = jnp.zeros((R,), jnp.int32).at[order].set(prow_sorted)
     padded_rows = _round_up(R, bm) + E * bm            # static upper bound
     num_blocks = padded_rows // bm
+    # padding entry j completes the first group whose cumulative padding
+    # exceeds j; the trailing unused tiles' entries read E and sort last
+    pad_end = jnp.cumsum(group_sizes - counts)         # [E]
+    pad_eids = jnp.sum(
+        (jnp.arange(padded_rows - R, dtype=jnp.int32)[:, None]
+         >= pad_end[None, :]).astype(jnp.int32), axis=1)
+    flat = jnp.arange(padded_rows, dtype=jnp.int32)
+    _, padded_to_row = jax.lax.sort(
+        (jnp.concatenate([eids, pad_eids]), jnp.minimum(flat, R)),
+        num_keys=1, is_stable=True)
+    _, padded_of = jax.lax.sort((padded_to_row, flat), num_keys=1,
+                                is_stable=True)
+    row_to_padded = padded_of[:R]
     cum_blocks = jnp.cumsum(blocks_e)                  # [E]
     bidx = jnp.arange(num_blocks, dtype=jnp.int32)
     # tile b belongs to the first expert whose cumulative tile count
@@ -129,18 +153,125 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
                    axis=1)
     gids = jnp.minimum(gids, E - 1).astype(jnp.int32)
     return GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
-                     row_to_padded, counts)
+                     row_to_padded, padded_to_row, counts)
+
+
+# ----------------------------------------------------------- row movement
+# Routed elements and non-padding padded rows are in bijection and the
+# plan holds both directions, so every movement — and every transpose of
+# one, which autodiff would write as a scatter-add — is a gather.
+def _rows_or_zeros(x, idx):
+    """x[idx] along dim 0, exact zeros where ``idx`` is out of range."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _rows(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+@jax.custom_vjp
+def _to_groups(rows, padded_to_row, row_to_padded):
+    return _rows_or_zeros(rows, padded_to_row)
+
+
+@jax.custom_vjp
+def _from_groups(padded, padded_to_row, row_to_padded):
+    return _rows(padded, row_to_padded)
+
+
+def _keeping_maps(move):
+    return lambda x, *maps: (move(x, *maps), maps)
+
+
+# each is the other's transpose
+_to_groups.defvjp(_keeping_maps(_to_groups),
+                  lambda maps, g: (_from_groups(g, *maps), None, None))
+_from_groups.defvjp(_keeping_maps(_from_groups),
+                    lambda maps, g: (_to_groups(g, *maps), None, None))
 
 
 def scatter_to_groups(rows: jnp.ndarray, plan: GroupPlan) -> jnp.ndarray:
-    """rows [R, D] (flat routed order) -> group-padded [Mp, D] (pad = 0)."""
-    out = jnp.zeros((plan.padded_rows,) + rows.shape[1:], rows.dtype)
-    return out.at[plan.row_to_padded].set(rows)
+    """rows [R, D] (flat routed order) -> group-padded [Mp, D] (pad = 0):
+    a gather by ``padded_to_row``, its backward one by ``row_to_padded``."""
+    return _to_groups(rows, plan.padded_to_row, plan.row_to_padded)
 
 
 def gather_from_groups(padded: jnp.ndarray, plan: GroupPlan) -> jnp.ndarray:
     """group-padded [Mp, D] -> [R, D] rows in flat routed order."""
-    return jnp.take(padded, plan.row_to_padded, axis=0)
+    return _from_groups(padded, plan.padded_to_row, plan.row_to_padded)
+
+
+def _token_rows(xt, token_of_row):
+    """xt [T, D] -> [Mp, D]: padded row p reads token ``token_of_row[p]``,
+    and ``T``, one past the last, reads zeros — a sentinel row appended to
+    the (small) token-major operand, where ``mode="fill"`` would pay a
+    second pass over the [Mp, D] result."""
+    zero = jnp.zeros((1,) + xt.shape[1:], xt.dtype)
+    return _rows(jnp.concatenate([xt, zero]), token_of_row)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(xt, padded_to_row, row_to_padded, top_k):
+    return _token_rows(xt, padded_to_row // top_k)
+
+
+def _dispatch_fwd(xt, padded_to_row, row_to_padded, top_k):
+    return _dispatch(xt, padded_to_row, row_to_padded, top_k), row_to_padded
+
+
+def _dispatch_bwd(top_k, row_to_padded, g):
+    # a token's top_k cotangent rows summed in float32, rounded once
+    rows = _rows(g, row_to_padded).reshape(-1, top_k, *g.shape[1:])
+    return (jnp.sum(rows.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def dispatch_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
+    """Token-major ``xt`` [T, D] -> group-padded [Mp, D] in ONE gather
+    (padded row ``p`` reads token ``padded_to_row[p] // top_k``; padding
+    rows are exact zeros, which ``ds_ggemm_dw`` relies on).  Equals
+    ``scatter_to_groups(repeat(xt, top_k), plan)`` without the [T·k, D]
+    copy; backward: gather by ``row_to_padded``, sum over ``top_k``."""
+    return _dispatch(xt, plan.padded_to_row, plan.row_to_padded, top_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(y, gates, padded_to_row, row_to_padded, top_k):
+    rows = _rows(y, row_to_padded)
+    return jnp.sum((gates.astype(y.dtype)[:, None] * rows).reshape(
+        -1, top_k, y.shape[1]), axis=1)
+
+
+def _combine_fwd(y, gates, padded_to_row, row_to_padded, top_k):
+    return (_combine(y, gates, padded_to_row, row_to_padded, top_k),
+            (y, gates, padded_to_row, row_to_padded))
+
+
+def _combine_bwd(top_k, res, g):
+    y, gates, padded_to_row, row_to_padded = res
+    dy = (_rows_or_zeros(gates.astype(y.dtype), padded_to_row)[:, None]
+          * _token_rows(g, padded_to_row // top_k))
+    rows = _rows(y, row_to_padded).reshape(-1, top_k, y.shape[1])
+    dgates = jnp.sum(rows.astype(jnp.float32)
+                     * g.astype(jnp.float32)[:, None, :], axis=-1)
+    return dy, dgates.reshape(gates.shape).astype(gates.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def combine_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
+                 top_k: int):
+    """Group-padded expert outputs ``y`` [Mp, D] and flat ``gates``
+    [T·top_k] -> [T, D]: each token's ``top_k`` rows, gathered by
+    ``row_to_padded``, weighted in ``y``'s dtype and summed.  Backward:
+    ``dy[p] = gates[padded_to_row[p]] · dout[padded_to_row[p] // top_k]``
+    (zeros on padding rows) and ``dgates[f] = y[row_to_padded[f]] ·
+    dout[f // top_k]`` accumulated in float32."""
+    return _combine(y, gates, plan.padded_to_row, plan.row_to_padded, top_k)
 
 
 # ------------------------------------------------------------- reference
@@ -149,7 +280,8 @@ def _full_group_sizes(plan: GroupPlan) -> jnp.ndarray:
     to span the operand; trailing all-zero tiles fold into the last
     group, matching the block_group_ids clamp)."""
     tail = plan.padded_rows - jnp.sum(plan.group_sizes)
-    return plan.group_sizes.at[plan.num_experts - 1].add(tail)
+    last = jnp.arange(plan.num_experts) == plan.num_experts - 1
+    return plan.group_sizes + jnp.where(last, tail, 0)
 
 
 def _ref_ggemm(x, w, plan: GroupPlan, transpose_rhs, out_dtype):

@@ -39,7 +39,7 @@ import math
 import os
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 COSTMODEL_ENV = "DS_PERF_COSTMODEL"
 
@@ -148,6 +148,20 @@ def _sub_jaxprs(eqn):
                 yield it
 
 
+def primitive_names(jaxpr) -> List[str]:
+    """The primitive of every equation of a traced program, recursively
+    through sub-jaxprs (scan/cond/jit bodies) — e.g. that a gradient
+    holds no ``scatter``/``scatter-add`` (how the grouped MoE layer's
+    gathers-only row movement is read off the jaxpr on a CPU)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)      # accept ClosedJaxpr
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in _sub_jaxprs(eqn):
+            names.extend(primitive_names(sub))
+    return names
+
+
 def count_pallas_launches(jaxpr) -> int:
     """Kernel-launch SITES in a traced program: ``pallas_call``
     equations, recursively through sub-jaxprs (scan/cond/jit bodies).
@@ -155,14 +169,7 @@ def count_pallas_launches(jaxpr) -> int:
     CPU, where interpret-mode kernels still trace as ``pallas_call``
     equations.  This is the PR 12 fused-decode launch-count contract
     (``<= L + k`` fused vs ``~(4-6)L`` unfused) as a shared API."""
-    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)      # accept ClosedJaxpr
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += 1
-        for sub in _sub_jaxprs(eqn):
-            n += count_pallas_launches(sub)
-    return n
+    return primitive_names(jaxpr).count("pallas_call")
 
 
 def _dot_flops(eqn) -> int:

@@ -79,11 +79,124 @@ def test_group_plan_layout_invariants():
                   if starts[e] <= row0 < starts[e + 1]]
         if owners:                       # trailing tiles clamp to E-1
             assert gids[b] == owners[0]
+    # the two maps are inverses: padded_to_row names the element in each
+    # row of a group and reads R, out of range, on every padding row
+    p2r = np.asarray(plan.padded_to_row)
+    assert p2r.shape == (plan.padded_rows,)
+    np.testing.assert_array_equal(p2r[r2p], np.arange(R))
+    others = np.setdiff1d(np.arange(plan.padded_rows), r2p)
+    assert (p2r[others] == R).all()
+    # stable: an expert's elements keep their flat order, from the first
+    # row of its group, and its padding follows them
+    for e in range(E):
+        group = p2r[starts[e]:starts[e + 1]]
+        np.testing.assert_array_equal(
+            group[:counts[e]], np.flatnonzero(np.asarray(eids) == e))
+        assert (group[counts[e]:] == R).all()
     # scatter/gather round-trips
     rows = jnp.asarray(rng.standard_normal((R, 4)), jnp.float32)
     padded = gg.scatter_to_groups(rows, plan)
     np.testing.assert_array_equal(
         np.asarray(gg.gather_from_groups(padded, plan)), np.asarray(rows))
+    assert not np.asarray(padded)[others].any()
+
+
+def _scatter_formulation(eids, E, bm):
+    """The layout as it was built before the plan held both maps (kept
+    here as the reference): argsort, counts and row_to_padded by scatter."""
+    R = eids.shape[0]
+    order = jnp.argsort(eids, stable=True)
+    sorted_eids = jnp.take(eids, order)
+    counts = jnp.zeros((E,), jnp.int32).at[eids].add(1)
+    group_sizes = jnp.maximum(-(-counts // bm), 1) * bm
+    zero = jnp.zeros((1,), jnp.int32)
+    pstart = jnp.concatenate([zero, jnp.cumsum(group_sizes)])
+    start = jnp.concatenate([zero, jnp.cumsum(counts)])
+    rank = jnp.arange(R, dtype=jnp.int32) - jnp.take(start, sorted_eids)
+    return jnp.zeros((R,), jnp.int32).at[order].set(
+        jnp.take(pstart, sorted_eids) + rank)
+
+
+def _scatter_dispatch(xt, r2p, k, padded_rows):
+    """take -> zero-fill -> scatter, differentiated by plain autodiff."""
+    rows = jnp.take(xt, jnp.arange(r2p.shape[0]) // k, axis=0)
+    return jnp.zeros((padded_rows, xt.shape[1]), xt.dtype).at[r2p].set(rows)
+
+
+def _take_combine(y, gates, r2p, k):
+    out_rows = jnp.take(y, r2p, axis=0)
+    return jnp.sum((gates.astype(y.dtype)[:, None] * out_rows).reshape(
+        -1, k, y.shape[1]), axis=1)
+
+
+ROW_MOVEMENT_CASES = {
+    # name: (T, top_k, E, block_m, how the experts are chosen)
+    "uniform": (16, 2, 4, 8, "uniform"),
+    "empty_expert": (12, 2, 8, 8, "empty"),
+    "one_expert": (10, 2, 4, 8, "one"),
+    "ragged_rows": (13, 3, 5, 8, "random"),      # R = 39, not 8's multiple
+    "top_1": (21, 1, 4, 8, "random"),
+    "top_8": (9, 8, 16, 16, "random"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_MOVEMENT_CASES))
+def test_rows_move_by_gathers_as_the_scatter_formulation_did(case):
+    """dispatch_rows / combine_rows (gathers through the plan's two maps,
+    hand-written backward) against take -> scatter -> ... -> take under
+    plain autodiff: the same layout, the same forward to the bit, the
+    same gradients, and no scatter in the traced gradient."""
+    T, k, E, bm, how = ROW_MOVEMENT_CASES[case]
+    rng = np.random.default_rng(sorted(ROW_MOVEMENT_CASES).index(case))
+    R, D = T * k, 6
+    eids = {"uniform": np.arange(R) % E,
+            "empty": rng.integers(0, E - 3, (R,)),
+            "one": np.full((R,), 2),
+            "random": rng.integers(0, E, (R,))}[how]
+    eids = jnp.asarray(eids, jnp.int32)
+    plan = gg.make_group_plan(eids, E, block_m=bm)
+    r2p = _scatter_formulation(eids, E, bm)
+    np.testing.assert_array_equal(plan.row_to_padded, r2p)
+
+    xt = jnp.asarray(rng.standard_normal((T, D)), jnp.float32)
+    y = jnp.asarray(rng.standard_normal((plan.padded_rows, D)), jnp.float32)
+    gates = jnp.asarray(rng.random((R,)), jnp.float32)
+    c_pad = jnp.asarray(rng.standard_normal(y.shape), jnp.float32)
+    c_out = jnp.asarray(rng.standard_normal(xt.shape), jnp.float32)
+    for dt in (jnp.float32, jnp.bfloat16):
+        np.testing.assert_array_equal(
+            gg.dispatch_rows(xt.astype(dt), plan, k),
+            _scatter_dispatch(xt.astype(dt), r2p, k, plan.padded_rows))
+        np.testing.assert_array_equal(
+            gg.combine_rows(y.astype(dt), gates, plan, k),
+            _take_combine(y.astype(dt), gates, r2p, k))
+    np.testing.assert_array_equal(
+        gg.scatter_to_groups(jnp.repeat(xt, k, axis=0), plan),
+        gg.dispatch_rows(xt, plan, k))
+
+    def new(xt_, y_, gates_):
+        return (jnp.sum(gg.dispatch_rows(xt_, plan, k) * c_pad)
+                + jnp.sum(gg.combine_rows(y_, gates_, plan, k) * c_out))
+
+    def old(xt_, y_, gates_):
+        return (jnp.sum(_scatter_dispatch(xt_, r2p, k, plan.padded_rows)
+                        * c_pad)
+                + jnp.sum(_take_combine(y_, gates_, r2p, k) * c_out))
+
+    got = jax.grad(new, argnums=(0, 1, 2))(xt, y, gates)
+    want = jax.grad(old, argnums=(0, 1, 2))(xt, y, gates)
+    for name, a, b in zip(("d xt", "d y", "d gates"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+    # a padding row's cotangent is an exact zero, as the scatter's was
+    pad = np.setdiff1d(np.arange(plan.padded_rows), np.asarray(r2p))
+    assert not np.asarray(got[1])[pad].any()
+
+    def scatters(fn):
+        from deepspeed_tpu.telemetry.costmodel import primitive_names
+        return [n for n in primitive_names(jax.make_jaxpr(
+            jax.grad(fn, argnums=(0, 1, 2)))(xt, y, gates))
+            if "scatter" in n]
+    assert scatters(old) and not scatters(new)
 
 
 @pytest.mark.parametrize("eid_case", ["mixed", "empty_expert",

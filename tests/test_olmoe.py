@@ -232,6 +232,19 @@ def test_scopes_kernel_names_and_row_counts_of_a_toy_step():
     for phase in ("forward", "recompute", "backward"):
         assert any(row["phase"] == phase and "/experts/" in row["scope"]
                    for row in table.values() if row["scope"]), phase
+    # the rows' movement, hand-written backward included, stays under the
+    # scopes that moe.dispatch_ms_per_step reads (the recompute needs no
+    # combined output, only what its backward gathers from) ...
+    for part, phases in (("dispatch", ("forward", "recompute", "backward")),
+                         ("combine", ("forward", "backward"))):
+        for phase in phases:
+            assert any(row["phase"] == phase
+                       and f"/mlp/{part}/" in row["scope"]
+                       for row in table.values() if row["scope"]), \
+                (part, phase)
+    # ... and none of it is a scatter
+    assert not any("scatter" in s for s in scopes
+                   if "/mlp/dispatch/" in s or "/mlp/combine/" in s)
     # the step's own account: B*S tokens x 2 choices, padded per expert
     rows = tracing.grouped_gemm_rows("train/step")
     bm = 128
@@ -239,6 +252,34 @@ def test_scopes_kernel_names_and_row_counts_of_a_toy_step():
     assert rows["padded_rows_per_call"] == -(-B * S * 2 // bm) * bm + 4 * bm
     # no host callback anywhere in the step
     assert not any("callback" in s for s in scopes)
+
+
+@pytest.mark.parametrize("experts", ["kernels", "ragged_dot"])
+def test_no_scatter_in_the_gradient_of_the_grouped_layer(experts,
+                                                         monkeypatch):
+    """The counter of the gathers-only row movement, read off the jaxpr:
+    jax.grad through the routed-expert layer in grouped mode — router,
+    plan, dispatch, experts (interpreted kernels or the reference),
+    combine — holds no scatter primitive of any kind."""
+    from deepspeed_tpu.telemetry.costmodel import primitive_names
+    monkeypatch.setenv("DS_GGEMM_INTERPRET",
+                       "1" if experts == "kernels" else "0")
+    config = moe_layer.MoEConfig(
+        d_model=16, d_ff=32, num_experts=8, top_k=2,
+        dispatch_mode="grouped", norm_topk_prob=False,
+        load_balance="all_choices", z_loss_coef=0.001)
+    params = moe_layer.init_moe_params(config, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 16))
+
+    def loss(params, x):
+        out, aux = moe_layer.moe_layer(params, x, config, train=True)
+        return jnp.sum(out ** 2) + aux
+
+    names = primitive_names(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x))
+    assert ("pallas_call" in names) == (experts == "kernels")
+    assert "gather" in names and "sort" in names
+    assert not [n for n in names if "scatter" in n]
 
 
 ROUTING_CASES = {
