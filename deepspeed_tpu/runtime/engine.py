@@ -32,23 +32,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import (MeshTopology, set_topology, SEQ_AXIS)
 from deepspeed_tpu.runtime.config import DeepSpeedConfig, MeshConfig
+from deepspeed_tpu.runtime import step_programs
 from deepspeed_tpu.runtime.optimizers import build_optimizer
 from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.zero.policy import ZeroShardingPolicy
 from deepspeed_tpu.runtime.fp16.loss_scaler import (
-    create_loss_scaler, has_overflow, update_scale)
+    create_loss_scaler, update_scale)
+from deepspeed_tpu.runtime.step_programs import tree_cast as _tree_cast
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
-    register_program, step_account)
+    TRAIN_STEP_PROGRAM, register_program)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
     SynchronizedWallClockTimer, ThroughputTimer, TRAIN_BATCH_TIMER)
-
-
-def _tree_cast(tree, dtype):
-    return jax.tree.map(
-        lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        tree)
 
 
 def _np_fast_cast(x: np.ndarray, dtype):
@@ -86,12 +81,6 @@ def _abstract(x, sharding=None):
 def _abstract_placed(x):
     """Shape, dtype and the sharding the array already has."""
     return _abstract(x, x.sharding)
-
-
-def _global_norm(tree):
-    leaves = [jnp.sum(jnp.square(l.astype(jnp.float32)))
-              for l in jax.tree.leaves(tree)]
-    return jnp.sqrt(sum(leaves))
 
 
 class DeepSpeedEngine:
@@ -826,7 +815,6 @@ class DeepSpeedEngine:
         self._num_on = tcfg.enabled and numerics_enabled(ncfg.enabled)
         self._num_groups = None
         self._num_leaf_group = None
-        self._nf_inject_group = None     # trace-time chaos injection
         self._last_save_dir = None
         self.numerics = None
         self._fp_interval = 0
@@ -974,6 +962,21 @@ class DeepSpeedEngine:
                     "(models/gpt2.py, llama.py do) — token dropping will be "
                     "a no-op")
 
+        # what the traced step programs read of this engine
+        # (runtime/step_programs.py), fixed from here on
+        self._step_ctx = step_programs.StepContext(
+            model=self.model, optimizer=self.optimizer,
+            zero_policy=self.zero_policy, grad_specs=self.grad_specs,
+            grad_dtype=self.grad_dtype, compute_dtype=self.compute_dtype,
+            fp16=self._config.fp16.enabled,
+            scaler_config=self.scaler_config,
+            gas=self.gradient_accumulation_steps(),
+            compression_plans=self._compression_plans,
+            use_streamed=self._use_streamed,
+            num_groups=self._num_groups,
+            num_leaf_group=self._num_leaf_group,
+            pipe_cfg=self._config._param_dict.get("pipeline", {}) or {})
+
         if training_data is not None:
             from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader
             self.training_dataloader = DeepSpeedDataLoader(
@@ -1076,36 +1079,6 @@ class DeepSpeedEngine:
             return MonitorMaster(self._config.monitor_config)
         except Exception:
             return None
-
-    # ------------------------------------------------------------------ loss fn
-    def _compress_traced(self, params, step):
-        """Apply the compression-training plans to the compute params with
-        traced schedule gates (reference engine.py:2044 scheduler-per-step;
-        no-op without a compression config)."""
-        if self._compression_plans is None:
-            return params
-        from deepspeed_tpu.compression import compress_params_traced
-        return compress_params_traced(params, step, self._compression_plans)
-
-    def _scaled_loss_fn(self, params, batch, rng, scale, compress_step=None):
-        if self._use_streamed and isinstance(params, dict):
-            # blocks stay fp32 in pinned host; the models cast each weight at
-            # point of use (after the per-layer stream), so the AD transpose
-            # stays per-slice — a whole-tree cast here would materialise full
-            # stacked fp32 converts on device in the backward pass
-            bk = getattr(self.model, "blocks_key", "blocks")
-            cparams = {k: (v if k == bk
-                           else _tree_cast(v, self.compute_dtype))
-                       for k, v in params.items()}
-        else:
-            cparams = _tree_cast(params, self.compute_dtype)
-        if compress_step is not None:
-            # INSIDE the grad: pruning masks zero the pruned positions'
-            # gradients (w*mask transpose) and the quantizer's STE backward
-            # actually runs — reference QAT/pruning semantics
-            cparams = self._compress_traced(cparams, compress_step)
-        loss = self.model.loss(cparams, batch, rng)
-        return loss.astype(jnp.float32) * scale
 
     # ------------------------------------------------------------------ train step
     @staticmethod
@@ -1435,8 +1408,8 @@ class DeepSpeedEngine:
                 def loss_fn(prm, mb, rng_, sc):
                     cparams = _tree_cast(prm, self.compute_dtype)
                     if compress_step is not None:
-                        cparams = self._compress_traced(cparams,
-                                                        compress_step)
+                        cparams = step_programs.compress(
+                            self._step_ctx, cparams, compress_step)
                     if wrap_any:
                         leaves = jax.tree.leaves(cparams)
                         leaves = [
@@ -1448,16 +1421,24 @@ class DeepSpeedEngine:
                     loss = self.model.loss(cparams, mb, rng_)
                     return loss.astype(jnp.float32) * sc
 
-                def micro(carry, mb):
-                    g_acc, l_acc = carry
-                    # loss pre-scaled by 1/n_manual: every exchange below
-                    # (and the wrapper VJPs) SUMS over the manual axes, so
-                    # the sum lands on the global-batch mean
-                    loss, g = jax.value_and_grad(loss_fn)(
-                        p, mb, r, s / (gas * n_manual))
-                    g = _tree_cast(g, self.grad_dtype)
-                    return (jax.tree.map(jnp.add, g_acc, g),
-                            l_acc + loss), None
+                def summed(batches):
+                    """Scan the leading axis of ``batches``, each entry
+                    an n-th of the step."""
+                    n = jax.tree.leaves(batches)[0].shape[0]
+
+                    def micro(carry, mb):
+                        g_acc, l_acc = carry
+                        # loss pre-scaled by 1/n_manual: every exchange
+                        # below (and the wrapper VJPs) SUMS over the manual
+                        # axes, so the sum lands on the global-batch mean
+                        loss, g = jax.value_and_grad(loss_fn)(
+                            p, mb, r, s / (n * n_manual))
+                        g = _tree_cast(g, self.grad_dtype)
+                        return (jax.tree.map(jnp.add, g_acc, g),
+                                l_acc + loss), None
+
+                    return jax.lax.scan(
+                        micro, (zeros, jnp.float32(0.0)), batches)[0]
 
                 zeros = jax.tree.map(
                     lambda x: jnp.zeros(x.shape, self.grad_dtype), p)
@@ -1468,23 +1449,11 @@ class DeepSpeedEngine:
                         p, b, r, s / n_manual)
                     local_g = _tree_cast(local_g, self.grad_dtype)
                 elif pipeline:
-                    chunks = jax.tree.map(
+                    local_g, local_l = summed(jax.tree.map(
                         lambda x: x.reshape(pipe_chunks, gas // pipe_chunks,
-                                            *x.shape[1:]), b)
-
-                    def chunk_body(carry, cb):
-                        g_acc, l_acc = carry
-                        l, g = jax.value_and_grad(loss_fn)(
-                            p, cb, r, s / (pipe_chunks * n_manual))
-                        g = _tree_cast(g, self.grad_dtype)
-                        return (jax.tree.map(jnp.add, g_acc, g),
-                                l_acc + l), None
-
-                    (local_g, local_l), _ = jax.lax.scan(
-                        chunk_body, (zeros, jnp.float32(0.0)), chunks)
+                                            *x.shape[1:]), b))
                 else:
-                    (local_g, local_l), _ = jax.lax.scan(
-                        micro, (zeros, jnp.float32(0.0)), b)
+                    local_g, local_l = summed(b)
 
                 g_leaves = jax.tree.leaves(local_g)
                 err_leaves = (jax.tree.leaves(err) if err is not None
@@ -1584,366 +1553,6 @@ class DeepSpeedEngine:
 
         return grad_fn
 
-    def _build_train_step(self):
-        if self.model.meta.get("pipeline"):
-            return self._build_pipeline_train_step()
-        gas = self.gradient_accumulation_steps()
-        fp16 = self._config.fp16.enabled
-        grad_specs = self.grad_specs
-        policy = self.zero_policy
-
-        qgz_fn = self._qgz_grad_fn()
-        plan = self._get_qgz_plan()
-        onebit = plan["onebit"] if plan is not None else None
-        wrapped_any = plan is not None and (
-            plan["block_scope"] is not None
-            or any(w is not None for w in plan["nonblock_wrap"]))
-        use_compress = (self._compression_plans is not None
-                        and not wrapped_any)
-        if self._compression_plans is not None and wrapped_any:
-            logger.warning(
-                "compression_training: plans are not applied in the "
-                "stage-3 quantized-exchange tier (compressing per-shard "
-                "would disagree across devices); training uncompressed")
-
-        def step_body(state, stacked_batch, rng):
-            """stacked_batch leaves: [gas, global_micro, ...]."""
-            params, opt_state = state["params"], state["opt_state"]
-            scaler = state["scaler"]
-            scale = scaler.cur_scale if fp16 else jnp.float32(1.0)
-            cs = state["step"] if use_compress else None
-
-            if qgz_fn is not None and onebit is not None:
-                # dense-vs-1-bit decision per step (reference schedule):
-                # OnebitAdam/Lamb sync densely through freeze_step;
-                # ZeroOneAdam syncs densely only at variance-update steps
-                # (var_schedule_step recurrence, mirrored by the optimizer)
-                from deepspeed_tpu.runtime.fp16.onebit.zoadam import \
-                    var_schedule_step
-                ob = state["onebit"]
-                count = state["step"] + 1
-                if onebit["kind"] == "zerooneadam":
-                    dense_now, new_vi, new_vc = var_schedule_step(
-                        count, ob["var_interval"], ob["var_counter"],
-                        onebit["var_freeze_step"],
-                        onebit["var_update_scaler"])
-                else:
-                    dense_now = count <= onebit["freeze_step"]
-                    new_vi, new_vc = ob["var_interval"], ob["var_counter"]
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss_sum, grads, new_ob = qgz_fn(
-                        params, stacked_batch, rng, scale, cs,
-                        dense_now, ob)
-                grads = policy.constrain_grads(grads, grad_specs)
-                new_state, metrics = self._apply_grads(state, grads)
-                # overflow steps roll back every 1-bit residual/counter
-                # (the reference skips the whole optimizer step, exchange
-                # included)
-                ov = metrics["overflow"]
-                keep = lambda old, new: jnp.where(ov, old, new)
-                # the residuals live in the loss-scaled gradient domain;
-                # when the dynamic scaler moves (overflow backoff or
-                # window growth) they must move with it or error feedback
-                # mis-weights the carried correction by the scale ratio
-                ratio = (new_state["scaler"].cur_scale / scaler.cur_scale
-                         if fp16 else jnp.float32(1.0))
-                rescale = lambda old, new: keep(old, new) * ratio
-                new_state["onebit"] = {
-                    "error": jax.tree.map(rescale, ob["error"],
-                                          new_ob["error"]),
-                    "server": jax.tree.map(rescale, ob["server"],
-                                           new_ob["server"]),
-                    "var_interval": keep(ob["var_interval"], new_vi),
-                    "var_counter": keep(ob["var_counter"], new_vc),
-                }
-                metrics["loss"] = loss_sum / scale
-                return new_state, metrics
-
-            if qgz_fn is not None:
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss_sum, grads = qgz_fn(params, stacked_batch, rng,
-                                             scale, cs)
-                grads = policy.constrain_grads(grads, grad_specs)
-            else:
-                def micro(carry, mb):
-                    grads_acc, loss_acc = carry
-                    with jax.named_scope(SCOPE_FWD_BWD):
-                        loss, grads = jax.value_and_grad(
-                            self._scaled_loss_fn)(
-                                params, mb, rng, scale / gas, cs)
-                    with jax.named_scope(SCOPE_ACCUMULATE):
-                        grads = _tree_cast(grads, self.grad_dtype)
-                        grads = policy.constrain_grads(grads, grad_specs)
-                        grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-                    return (grads_acc, loss_acc + loss), None
-
-                zero_grads = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
-                zero_grads = policy.constrain_grads(zero_grads, grad_specs)
-                (grads, loss_sum), _ = jax.lax.scan(
-                    micro, (zero_grads, jnp.float32(0.0)), stacked_batch)
-
-            new_state, metrics = self._apply_grads(state, grads)
-            # undo loss scaling for the reported loss; mean over micro steps
-            metrics["loss"] = loss_sum / scale
-            return new_state, metrics
-
-        def train_step(state, stacked_batch, rng):
-            # this body runs while the step is traced: what the model's
-            # code counts of itself (tracing.count_in_step) is the account
-            # of this program.  (The name is the compiled module's.)
-            with step_account(TRAIN_STEP_PROGRAM):
-                return step_body(state, stacked_batch, rng)
-        return train_step
-
-    def _build_pipeline_train_step(self):
-        """Pipelined models consume the [gas, micro, ...] stack (gas ≙ the
-        pipeline's microbatch count; reference PipelineEngine.train_batch,
-        runtime/pipe/engine.py:297).
-
-        Memory profile: with ``pipeline.num_pipe_buffers = N`` the stack is
-        processed in chunks of N microbatches inside a grad-accumulation
-        scan, so only one chunk's activations are live for backward — the
-        1F1B memory bound (reference schedule.py:176 ``num_pipe_buffers``).
-        The trade is the reference's too: each chunk pays its own
-        fill/drain bubble, (S-1)/(N+S-1) vs (S-1)/(M+S-1) for the all-live
-        schedule (num_pipe_buffers unset/M keeps the old behaviour)."""
-        fp16 = self._config.fp16.enabled
-        gas = self.gradient_accumulation_steps()
-        pipe_cfg = self._config._param_dict.get("pipeline", {}) or {}
-        n_buffers = int(pipe_cfg.get("num_pipe_buffers", 0) or 0)
-        policy, grad_specs = self.zero_policy, self.grad_specs
-        n_stages = int(self.model.meta.get("num_stages", 1))
-        sched = str(pipe_cfg.get("schedule", "") or "").lower()
-        if sched not in ("", "1f1b", "gpipe"):
-            raise ValueError(
-                f"pipeline.schedule={sched!r}: expected '1f1b' or 'gpipe' "
-                "(default: all-live/chunked GPipe)")
-        if sched == "1f1b" and n_stages > 1:
-            if gas < n_stages:
-                logger.warning(
-                    f"pipeline.schedule='1f1b' needs gradient_accumulation_"
-                    f"steps >= pipeline stages ({n_stages}), got {gas}; "
-                    "running the all-live schedule")
-            else:
-                if pipe_cfg.get("num_pipe_buffers"):
-                    logger.warning(
-                        "pipeline.num_pipe_buffers is ignored under "
-                        "schedule='1f1b' (the interleaved schedule's ring "
-                        "buffers are sized by the stage count)")
-                return self._build_1f1b_train_step(n_stages)
-        chunked = 0 < n_buffers < gas and gas % n_buffers == 0
-        if chunked and n_buffers < n_stages:
-            logger.warning(
-                f"pipeline.num_pipe_buffers={n_buffers} < pipeline stages "
-                f"{n_stages}: a chunk cannot fill the pipeline; running "
-                f"all-live")
-            chunked = False
-        elif n_buffers and not chunked and n_buffers < gas:
-            logger.warning(
-                f"pipeline.num_pipe_buffers={n_buffers} does not divide "
-                f"gradient_accumulation_steps={gas}; running all-live")
-
-        # quantized/sparse exchange tier under GPipe (round-3 VERDICT
-        # item 4): the tier's shard_map keeps the pipe axis auto, so the
-        # scanned pipeline composes with the int8 gradient wire
-        qgz_fn = self._qgz_grad_fn()
-        if qgz_fn is not None:
-            plan = self._get_qgz_plan()
-            wrapped_any = (plan["block_scope"] is not None
-                           or any(w is not None
-                                  for w in plan["nonblock_wrap"]))
-            use_compress = (self._compression_plans is not None
-                            and not wrapped_any)
-
-            def qgz_train_step(state, stacked_batch, rng):
-                params = state["params"]
-                scale = (state["scaler"].cur_scale if fp16
-                         else jnp.float32(1.0))
-                cs = state["step"] if use_compress else None
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss_sum, grads = qgz_fn(params, stacked_batch, rng,
-                                             scale, cs)
-                grads = policy.constrain_grads(grads, grad_specs)
-                new_state, metrics = self._apply_grads(state, grads)
-                metrics["loss"] = loss_sum / scale
-                return new_state, metrics
-
-            return qgz_train_step
-
-        def loss_of_chunk(params, chunk_batch, rng, scale, cs=None):
-            cparams = _tree_cast(params, self.compute_dtype)
-            if cs is not None:
-                cparams = self._compress_traced(cparams, cs)
-            loss = self.model.loss(cparams, chunk_batch, rng)
-            return loss.astype(jnp.float32) * scale
-
-        def train_step(state, stacked_batch, rng):
-            params = state["params"]
-            cs = (state["step"] if self._compression_plans is not None
-                  else None)
-            scale = state["scaler"].cur_scale if fp16 else jnp.float32(1.0)
-
-            if not chunked:
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss, grads = jax.value_and_grad(loss_of_chunk)(
-                        params, stacked_batch, rng, scale, cs)
-            else:
-                n_chunks = gas // n_buffers
-                chunks = jax.tree.map(
-                    lambda x: x.reshape(n_chunks, n_buffers, *x.shape[1:]),
-                    stacked_batch)
-
-                def body(carry, chunk):
-                    g_acc, l_acc = carry
-                    with jax.named_scope(SCOPE_FWD_BWD):
-                        l, g = jax.value_and_grad(loss_of_chunk)(
-                            params, chunk, rng, scale / n_chunks, cs)
-                    with jax.named_scope(SCOPE_ACCUMULATE):
-                        g = _tree_cast(g, self.grad_dtype)
-                        g = policy.constrain_grads(g, grad_specs)
-                        g_acc = jax.tree.map(jnp.add, g_acc, g)
-                    return (g_acc, l_acc + l), None
-
-                zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
-                zeros = policy.constrain_grads(zeros, grad_specs)
-                # each chunk is already weighted by scale/n_chunks, so the
-                # sum over chunks is the full-batch mean at full scale
-                (grads, loss), _ = jax.lax.scan(
-                    body, (zeros, jnp.float32(0.0)), chunks)
-
-            grads = _tree_cast(grads, self.grad_dtype)
-            grads = policy.constrain_grads(grads, grad_specs)
-            new_state, metrics = self._apply_grads(state, grads)
-            metrics["loss"] = loss / scale
-            return new_state, metrics
-
-        return train_step
-
-    def _build_1f1b_train_step(self, n_stages: int):
-        """True one-pass 1F1B pipeline schedule (config ``pipeline.schedule
-        = "1f1b"``; reference runtime/pipe/schedule.py:189 TrainSchedule):
-        one fill/drain for the whole batch at O(n_stages) live activations
-        — see runtime/pipe/pipeline.pipeline_1f1b_loss_and_grad."""
-        from deepspeed_tpu.runtime.pipe.pipeline import \
-            pipeline_1f1b_loss_and_grad
-        fp16 = self._config.fp16.enabled
-        gas = self.gradient_accumulation_steps()
-        policy, grad_specs = self.zero_policy, self.grad_specs
-        model = self.model
-        if self._compression_plans is not None:
-            logger.warning(
-                "compression_training is not applied under the 1f1b "
-                "pipeline schedule (the manual fwd/bwd interleave bypasses "
-                "the compression transform); training uncompressed")
-
-        def train_step(state, stacked_batch, rng):
-            params = state["params"]
-            scale = state["scaler"].cur_scale if fp16 else jnp.float32(1.0)
-            cparams = _tree_cast(params, self.compute_dtype)
-
-            def head_loss(p, y, b):
-                # the pipelined model's single loss definition (shared
-                # with the GPipe schedule), scaled per microbatch
-                return (model.head_loss_fn(p, y, b).astype(jnp.float32)
-                        * (scale / gas))
-
-            with jax.named_scope(SCOPE_FWD_BWD):
-                loss_sum, grads = pipeline_1f1b_loss_and_grad(
-                    lambda h, lp: model.block_fn(lp, h), model.embed_fn,
-                    head_loss, cparams, model.blocks_key, stacked_batch,
-                    n_stages)
-            grads = _tree_cast(grads, self.grad_dtype)
-            grads = policy.constrain_grads(grads, grad_specs)
-            new_state, metrics = self._apply_grads(state, grads)
-            metrics["loss"] = loss_sum / scale
-            return new_state, metrics
-
-        return train_step
-
-    @jax.named_scope(SCOPE_OPTIMIZER)
-    def _apply_grads(self, state, grads):
-        """Shared epilogue: unscale, overflow check, update, skip-on-overflow."""
-        fp16 = self._config.fp16.enabled
-        params, opt_state, scaler = (state["params"], state["opt_state"],
-                                     state["scaler"])
-        scale = scaler.cur_scale if fp16 else jnp.float32(1.0)
-        if (self._nf_inject_group is not None
-                and self._num_leaf_group is not None):
-            # train.nonfinite chaos fault (ISSUE 15): NaN-poison the
-            # chosen leaf group's gradient at TRACE time — the engine
-            # compiles a dedicated step variant per injected group, so
-            # the healthy compiled step is untouched
-            from deepspeed_tpu.telemetry.numerics import inject_nonfinite
-            grads = inject_nonfinite(grads, self._num_leaf_group,
-                                     self._nf_inject_group)
-        grads = jax.tree.map(lambda g: g / scale, grads)
-        grad_norm = _global_norm(grads)
-        num_stats = None
-        if self._num_leaf_group is not None and self._num_groups:
-            # in-graph numerics stats (ISSUE 15): per-group grad norms
-            # + the non-finite provenance bitmap, device-resident until
-            # the bank resolves (no host sync here)
-            from deepspeed_tpu.telemetry.numerics import group_stats
-            num_stats = group_stats(grads, self._num_leaf_group,
-                                    len(self._num_groups))
-        if fp16:
-            overflow = has_overflow(grads)
-            safe_grads = jax.tree.map(
-                lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
-        else:
-            overflow = jnp.bool_(False)
-            safe_grads = grads
-        updates, new_opt = self.optimizer.update(safe_grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        update_ratio = None
-        if num_stats is not None:
-            # ||update|| / ||param||: the step-size health signal (a
-            # collapsing or exploding ratio flags through the MAD
-            # detector as anomaly/num_update_ratio).  Overflow steps
-            # report 0.0 — the update was skipped.
-            unorm = _global_norm(updates)
-            pnorm = _global_norm(params)
-            update_ratio = jnp.where(
-                overflow, jnp.float32(0.0),
-                unorm / jnp.maximum(pnorm, jnp.float32(1e-12)))
-        if fp16:
-            new_params = jax.tree.map(
-                lambda old, new: jnp.where(overflow, old, new),
-                params, new_params)
-            new_opt = jax.tree.map(
-                lambda old, new: jnp.where(overflow, old, new)
-                if hasattr(new, "shape") and old.shape == new.shape else new,
-                opt_state, new_opt)
-        new_scaler = (update_scale(scaler, overflow, self.scaler_config)
-                      if fp16 else scaler)
-        # skipped (overflow) steps must not advance the LR schedule step
-        # (reference: skipped steps leave the scheduler untouched)
-        step_inc = jnp.where(overflow, jnp.int32(0), jnp.int32(1))
-        # dict(state, ...) keeps auxiliary subtrees (e.g. the 1-bit
-        # error-feedback buffers) intact through paths that don't manage
-        # them (micro-step apply); train_step overwrites them itself
-        new_state = dict(
-            state,
-            params=new_params,
-            opt_state=new_opt,
-            step=state["step"] + step_inc,
-            scaler=new_scaler,
-        )
-        metrics = {
-            # contract (both execution tiers, see zero/offload.py): a skipped
-            # overflow step reports grad_norm 0.0, not the meaningless inf
-            "grad_norm": jnp.where(overflow, jnp.float32(0.0), grad_norm),
-            "overflow": overflow,
-            "loss_scale": new_scaler.cur_scale,
-        }
-        if num_stats is not None:
-            metrics["num_group_norms"] = num_stats[0]
-            metrics["num_nonfinite"] = num_stats[1]
-            metrics["num_update_ratio"] = update_ratio
-        return new_state, metrics
-
     def _grad_out_shardings(self):
         """Grad out_shardings for the offload paths.  With pinned-host params
         on a non-TPU backend, explicit out_shardings make JAX emit a host
@@ -1972,6 +1581,16 @@ class DeepSpeedEngine:
         from deepspeed_tpu.compression import activation_quant_scope
         return activation_quant_scope(self._aq[0])
 
+    def _step_program(self, name: str, nf_group=None):
+        """The traced function of a step program (runtime/step_programs.py),
+        before ``jax.jit``.  ``nf_group``: the leaf group a
+        ``train_step@nf<g>`` chaos variant NaN-poisons."""
+        if name == "train_step":
+            return step_programs.build_train_step(
+                self._step_ctx, qgz_fn=self._qgz_grad_fn(),
+                plan=self._get_qgz_plan(), nf_group=nf_group)
+        return step_programs.PROGRAMS[name](self._step_ctx)
+
     def _get_compiled(self, name: str):
         # model code reads the global topology while it traces (the
         # attention shard_map, MoE, pipeline): with two engines in one
@@ -1985,116 +1604,28 @@ class DeepSpeedEngine:
             key = f"{key}@aq"
         if key in self._compiled:
             return self._compiled[key]
+        # @nf<g> variants are the train.nonfinite chaos flavors of the
+        # fused step: the same build, handed the group to poison
+        program, _, nf = name.partition("@nf")
         # batch args are pre-placed by _shard_batch (per-leaf ndim-aware
         # shardings), so jit infers their shardings from the arguments.
-        if name == "train_step" or name.startswith("train_step@nf"):
-            # @nf<g> variants are the train.nonfinite chaos flavors:
-            # identical build, but _apply_grads reads the trace-time
-            # injection flag the caller holds during the first call
-            fn = jax.jit(
-                self._build_train_step(),
-                out_shardings=(self.state_shardings, None),
-                donate_argnums=(0,))
-        elif name == "loss":
-            fn = jax.jit(
-                lambda state, batch, rng: self._scaled_loss_fn(
-                    state["params"], batch, rng, jnp.float32(1.0),
-                    state["step"] if self._compression_plans is not None
-                    else None))
-        elif name == "grad":
-            def grad_fn(state, batch, rng, grads_acc):
-                scale = (state["scaler"].cur_scale
-                         if self._config.fp16.enabled else jnp.float32(1.0))
-                gas = self.gradient_accumulation_steps()
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                        state["params"], batch, rng, scale / gas,
-                        state["step"] if self._compression_plans is not None
-                        else None)
-                with jax.named_scope(SCOPE_ACCUMULATE):
-                    grads = _tree_cast(grads, self.grad_dtype)
-                    grads = self.zero_policy.constrain_grads(
-                        grads, self.grad_specs)
-                    grads = jax.tree.map(jnp.add, grads_acc, grads)
-                return loss / scale * gas, grads
-            gos = self._grad_out_shardings()
-            fn = jax.jit(
-                grad_fn,
-                out_shardings=(None, gos) if gos is not None else None,
-                donate_argnums=(3,))
-        elif name == "grad_step":
-            # offload path: scan the gas micro-batches, stop at gradients
-            gas = self.gradient_accumulation_steps()
-            policy, grad_specs = self.zero_policy, self.grad_specs
-
-            def grad_step(state, stacked_batch, rng):
-                params = state["params"]
-                scale = (state["scaler"].cur_scale
-                         if self._config.fp16.enabled else jnp.float32(1.0))
-
-                def micro(carry, mb):
-                    grads_acc, loss_acc = carry
-                    with jax.named_scope(SCOPE_FWD_BWD):
-                        loss, grads = jax.value_and_grad(
-                            self._scaled_loss_fn)(
-                                params, mb, rng, scale / gas)
-                    with jax.named_scope(SCOPE_ACCUMULATE):
-                        grads = _tree_cast(grads, self.grad_dtype)
-                        grads = policy.constrain_grads(grads, grad_specs)
-                        grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-                    return (grads_acc, loss_acc + loss), None
-
-                zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
-                zeros = policy.constrain_grads(zeros, grad_specs)
-                (grads, loss_sum), _ = jax.lax.scan(
-                    micro, (zeros, jnp.float32(0.0)), stacked_batch)
-                return loss_sum / scale, grads
-
-            fn = jax.jit(grad_step, out_shardings=(None, self.grad_shardings))
-        elif name == "grad_micro":
-            # offload_param path: ONE micro-batch per call, python-level grad
-            # accumulation on host — the gas-scan would keep full fp32 grads
-            # resident on device, exactly what param offload must avoid
-            gas = self.gradient_accumulation_steps()
-
-            def grad_micro(state, mb, rng):
-                scale = (state["scaler"].cur_scale
-                         if self._config.fp16.enabled else jnp.float32(1.0))
-                with jax.named_scope(SCOPE_FWD_BWD):
-                    loss, grads = jax.value_and_grad(self._scaled_loss_fn)(
-                        state["params"], mb, rng, scale / gas)
-                # grads keep the params' storage dtype: a full-tensor fp32
-                # convert would materialise each stacked leaf on device (8 GB
-                # per MLP leaf at 6.7B); the streamed optimizer upcasts per
-                # layer slice instead
-                return loss / scale * gas, grads
-
-            gos = self._grad_out_shardings()
-            fn = jax.jit(grad_micro,
-                         out_shardings=(None, gos) if gos is not None else None)
-        elif name == "grad_acc":
-            # gas accumulation for the streamed-optimizer path; leaves bounce
-            # through device whole-leaf (transient HBM = largest leaf)
-            @jax.named_scope(SCOPE_ACCUMULATE)
-            def acc_fn(a, b):
-                return jax.tree.map(jnp.add, a, b)
-            gos = self._grad_out_shardings()
-            fn = (jax.jit(acc_fn, out_shardings=gos, donate_argnums=(0,))
-                  if gos is not None
-                  else jax.jit(acc_fn, donate_argnums=(0,)))
-        elif name == "apply":
-            fn = jax.jit(
-                self._apply_grads,
-                out_shardings=(self.state_shardings, None),
-                donate_argnums=(0, 1))
-        elif name == "zero_grads":
-            def make_zeros(params):
-                return jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, self.grad_dtype), params)
-            fn = jax.jit(make_zeros, out_shardings=self._grad_out_shardings())
-        else:
-            raise KeyError(name)
+        gos = self._grad_out_shardings()
+        loss_and_grads = (None, gos) if gos is not None else None
+        placement = {
+            "train_step": dict(out_shardings=(self.state_shardings, None),
+                               donate_argnums=(0,)),
+            "loss": {},
+            "grad": dict(out_shardings=loss_and_grads, donate_argnums=(3,)),
+            "grad_step": dict(out_shardings=(None, self.grad_shardings)),
+            "grad_micro": dict(out_shardings=loss_and_grads),
+            "grad_acc": dict(out_shardings=gos, donate_argnums=(0,)),
+            "apply": dict(out_shardings=(self.state_shardings, None),
+                          donate_argnums=(0, 1)),
+            "zero_grads": dict(out_shardings=gos),
+        }[program]
+        fn = jax.jit(
+            self._step_program(program, int(nf) if nf else None),
+            **placement)
         self._compiled[key] = fn
         return fn
 
@@ -2462,16 +1993,10 @@ class DeepSpeedEngine:
             # one fused program: fwd+bwd+apply dispatch together (the
             # per-phase split lives in the fwd/bwd/step timers when the
             # micro API drives them)
-            try:
-                # the flag is read at TRACE time (first call of the
-                # @nf variant); it must be live for the call window
-                self._nf_inject_group = nf_group
-                with self.tracer.span("train/fused_step", cat="train"), \
-                        self._train_scope(), self._ltd_scope(), \
-                        self._aq_scope():
-                    self.state, metrics = fn(self.state, batch, rng)
-            finally:
-                self._nf_inject_group = None
+            with self.tracer.span("train/fused_step", cat="train"), \
+                    self._train_scope(), self._ltd_scope(), \
+                    self._aq_scope():
+                self.state, metrics = fn(self.state, batch, rng)
         self._finish_step(metrics)
         # syncing on the loss every step stalls the async dispatch
         # pipeline; only pay it when the user asked for wall-clock
@@ -2556,7 +2081,7 @@ class DeepSpeedEngine:
             grads, self.compute_dtype, scale, self.state["step"])
         self.state["params"] = new_params
         # overflow steps don't advance the schedule/bias-correction step
-        # (reference skip semantics; matches _apply_grads)
+        # (reference skip semantics; matches step_programs.apply_grads)
         self.state["step"] = self.state["step"] + jnp.where(
             overflow, jnp.int32(0), jnp.int32(1))
         if fp16:
@@ -2840,7 +2365,7 @@ class DeepSpeedEngine:
             from deepspeed_tpu.telemetry.roofline import publish_report
             with self._train_scope(), self._ltd_scope(), self._aq_scope():
                 report = analyze_fn(
-                    self._build_train_step(), self.state, batch, rng,
+                    self._step_program("train_step"), self.state, batch, rng,
                     name="train/step",
                     detail={"tokens_per_step": self.train_batch_size()
                             * max(self._last_seq_len or 0, 0)})
@@ -2903,7 +2428,7 @@ class DeepSpeedEngine:
                 compiled_memory_stats, get_memory_ledger)
             with self._train_scope(), self._ltd_scope(), self._aq_scope():
                 stats = compiled_memory_stats(
-                    self._build_train_step(), self.state, batch, rng)
+                    self._step_program("train_step"), self.state, batch, rng)
             if stats:
                 get_memory_ledger().set_bytes(
                     "device", "activations",
@@ -3416,7 +2941,8 @@ class DeepSpeedEngine:
         rng = rng if rng is not None else self._next_rng()
 
         def loss_fn(p):
-            return self._scaled_loss_fn(p, batch, rng, jnp.float32(1.0))
+            return step_programs.scaled_loss(self._step_ctx, p, batch, rng,
+                                             jnp.float32(1.0))
 
         return ev.compute_eigenvalue(loss_fn, self.state["params"])
 

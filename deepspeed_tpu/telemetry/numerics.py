@@ -9,7 +9,7 @@ timeline, and no forensic record.  This module is that layer:
 - **in-graph stats** — per-leaf-group grad norms, a per-group
   non-finite count bitmap, and the update/param norm ratio are computed
   ON DEVICE inside the fused train step (:func:`group_stats`, wired in
-  ``engine._apply_grads``) and banked as device scalars exactly like
+  ``step_programs.apply_grads``) and banked as device scalars exactly like
   the overflow flag (:class:`NumericsState`), so the hot path pays ZERO
   extra host syncs; one lazy ``resolve()`` fetches the whole backlog in
   a single transfer and a non-finite step names the **first offending

@@ -291,7 +291,7 @@ def get_tracer():
 #: of the program's interface: readers of a device trace key on them.
 SCOPE_FWD_BWD = "ds.fwd_bwd"        # the value_and_grad call of a micro-step
 SCOPE_ACCUMULATE = "ds.accumulate"  # gradient cast + add into the accumulator
-SCOPE_OPTIMIZER = "ds.optimizer"    # _apply_grads: norm, clip, scaler, update
+SCOPE_OPTIMIZER = "ds.optimizer"    # apply_grads: norm, clip, scaler, update
 SCOPE_EMBED = "ds.embed"            # model: token + position embedding
 SCOPE_BLOCK = "ds.block"            # model: one transformer block ...
 SCOPE_ATTN = "attn"                 # ... LN1, QKV, attention, projection

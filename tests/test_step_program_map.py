@@ -257,6 +257,85 @@ def test_map_of_a_compiled_step(stage, gas, devices8, fresh_compiles):
         assert by_phase["accumulate"] > 0
 
 
+# ------------------------------ every step program carries its scopes
+#: which of ds.fwd_bwd / ds.accumulate / ds.optimizer a program must hold:
+#: fwd_bwd where it differentiates, accumulate where it sums gradients,
+#: optimizer where it updates — and no other
+PROGRAM_SCOPES = {
+    "train_step": {"ds.fwd_bwd", "ds.accumulate", "ds.optimizer"},
+    "loss": set(),
+    "grad": {"ds.fwd_bwd", "ds.accumulate"},
+    "grad_step": {"ds.fwd_bwd", "ds.accumulate"},
+    "grad_micro": {"ds.fwd_bwd"},
+    "grad_acc": {"ds.accumulate"},
+    "apply": {"ds.optimizer"},
+    "zero_grads": set(),
+}
+#: the pipelined builds of the fused step (all-live: one pass, nothing to
+#: accumulate; 1f1b: its own interleaved forward/backward)
+PIPELINE_SCOPES = {
+    "all_live": ({}, {"ds.fwd_bwd", "ds.optimizer"}),
+    "chunked": ({"num_pipe_buffers": 2},
+                {"ds.fwd_bwd", "ds.accumulate", "ds.optimizer"}),
+    "1f1b": ({"schedule": "1f1b"}, {"ds.fwd_bwd", "ds.optimizer"}),
+}
+
+
+def _phase_scopes(lowered):
+    """The three phase scopes among the op names of a lowered program."""
+    import re
+    return set(re.findall(r"ds\.(?:fwd_bwd|accumulate|optimizer)\b",
+                          lowered.as_text(debug_info=True)))
+
+
+def test_scope_table_covers_every_program():
+    from deepspeed_tpu.runtime import step_programs
+    assert set(PROGRAM_SCOPES) == set(step_programs.PROGRAMS)
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAM_SCOPES))
+def test_step_program_carries_its_scopes(name, devices8):
+    engine, batch = _engine(2, 2)
+    stacked = engine._shard_batch(batch, stacked=True)
+    micro = engine._shard_batch({k: v[0] for k, v in batch.items()},
+                                stacked=False)
+    grads = engine._get_compiled("zero_grads")(engine.state["params"])
+    state, rng = engine.state, engine._rng
+    args = {
+        "train_step": (state, stacked, rng), "loss": (state, micro, rng),
+        "grad": (state, micro, rng, grads),
+        "grad_step": (state, stacked, rng),
+        "grad_micro": (state, micro, rng), "grad_acc": (grads, grads),
+        "apply": (state, grads), "zero_grads": (state["params"],),
+    }[name]
+    fn = engine._get_compiled(name)
+    assert _phase_scopes(fn.lower(*args)) == PROGRAM_SCOPES[name]
+    if name == "train_step":
+        # the benchmark finds the fused step by its module's name
+        assert engine.compile_train_step(batch).as_text().startswith(
+            "HloModule jit_train_step,")
+        # a chaos variant is the same build, handed its group
+        nf = engine._get_compiled("train_step@nf1")
+        poisoned = nf.lower(*args)
+        assert _phase_scopes(poisoned) == PROGRAM_SCOPES[name]
+        assert poisoned.as_text() != fn.lower(*args).as_text()
+
+
+@pytest.mark.parametrize("schedule", sorted(PIPELINE_SCOPES))
+def test_pipeline_step_carries_its_scopes(schedule, devices8):
+    from deepspeed_tpu.runtime.pipe.pipeline import pipeline_model
+    pipe_cfg, want = PIPELINE_SCOPES[schedule]
+    engine, *_ = deepspeed_tpu.initialize(
+        model=pipeline_model(tiny_gpt2(), num_stages=2),
+        config=base_config(
+            gradient_accumulation_steps=4, pipeline=pipe_cfg,
+            mesh={"pipe_parallel_size": 2, "data_parallel_size": 4}))
+    batch = engine._shard_batch(
+        {"input_ids": np.zeros((4, 4, 16), np.int32)}, stacked=True)
+    fn = engine._get_compiled("train_step")
+    assert _phase_scopes(fn.lower(engine.state, batch, engine._rng)) == want
+
+
 def test_the_map_is_lazy(monkeypatch, capsys):
     engine, batch = _engine(2, 1)
     asked = {"compile": 0, "as_text": 0}
