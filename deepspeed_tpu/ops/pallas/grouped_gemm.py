@@ -1,11 +1,10 @@
-"""Ragged grouped GEMM Pallas kernel (``ds_ggemm``) — megablocks-style
-expert dispatch (ISSUE 8 tentpole; Gale et al. 2022, arXiv:2211.15841).
+"""Ragged grouped GEMM Pallas kernels (``ds_ggemm``) — megablocks-style
+expert dispatch (Gale et al. 2022, arXiv:2211.15841).
 
 The GShard einsum dispatch in ``moe/layer.py`` materializes dense
 ``[T, E, C]`` combine/dispatch tensors (two O(T·E·C·D) einsums) and pads
-every expert to capacity ``C`` — measured at roughly HALF dense MFU on
-the 760M-class MoE bench (PERF.md round 5).  This module reformulates
-expert computation as ONE ragged GEMM over tokens sorted by expert:
+every expert to capacity ``C``.  This module reformulates expert
+computation as ONE ragged GEMM over tokens sorted by expert:
 
 1. :func:`make_group_plan` — one stable sort of the flat ``[T·k]``
    expert choices, together with the padding entries that round each
@@ -13,30 +12,59 @@ expert computation as ONE ragged GEMM over tokens sorted by expert:
    empty experts keep one all-zero tile so backward tiles are always
    written), gives ``padded_to_row``; sorted back it gives its inverse
    ``row_to_padded``; beside them the per-M-tile expert id
-   (``block_group_ids``, non-decreasing).  The padded row count is
-   **static** (``round_up(T·k, bm) + E·bm``) so the whole pipeline jits;
-   the only waste is < one tile per expert, versus the capacity
-   formulation's ``E·C - T·k`` slots.  Rows move through the two maps
-   by gathers only — :func:`dispatch_rows` from the token-major
-   activations straight into the padded layout, :func:`combine_rows`
-   back out with the gate-weighted sum, each with a hand-written
-   backward that gathers through the other map (autodiff would write a
-   scatter-add, which costs 3× a gather of the same rows on a v5e) —
-   and the plan itself holds no scatter either.
-2. :func:`ds_ggemm` — one Pallas kernel over grid ``(m_tiles, N/bn,
-   K/bk)``: the M-grid walks group boundaries via a scalar-prefetched
-   ``block_group_ids`` map (the block_sparse_attention idiom), so each
-   M-tile contracts against exactly its expert's ``[K, N]`` slice of the
-   stacked ``[E, K, N]`` weights — zero top-k slot padding, no dense
-   ``[T, E, C]`` tensors anywhere.
-3. int8 weights ride the exact ``qgemm`` per-tile VMEM scale-expansion
-   design (selector-matmul dequant immediately before the MXU dot), so
-   routed experts stream at the same int8 weight floor as dense layers.
-4. backward (float path): ``dx`` reuses the forward kernel with a
-   transposed-RHS contraction; ``dw`` is a tgmm kernel (same grid
-   transposed, M innermost) accumulating per-expert outer products and
-   flushing on group change — per-step expert FLOPs stay ∝ routed
-   tokens in BOTH directions.
+   (``block_group_ids``, non-decreasing) and the number of tiles that
+   hold a group (``used_blocks``).  The padded row count is **static**
+   (``round_up(T·k, bm) + E·bm``) so the whole pipeline jits; the waste
+   is < one tile per expert, versus the capacity formulation's
+   ``E·C - T·k`` slots, plus the tiles that trail behind the last group
+   (about one in ten at 512 rows an expert), which the kernels neither
+   fetch nor multiply.  Rows move through the two maps by gathers only —
+   :func:`dispatch_rows` from the token-major activations straight into
+   the padded layout, :func:`combine_rows` back out with the
+   gate-weighted sum, each with a hand-written backward that gathers
+   through the other map (autodiff would write a scatter-add, which costs
+   3× a gather of the same rows on a v5e) — and the plan itself holds no
+   scatter either.
+2. :func:`ds_ggemm` — one Pallas kernel over grid ``(N/bn, m_tiles,
+   K/bk)``, N outermost and K innermost: the M-grid walks group
+   boundaries via the scalar-prefetched ``block_group_ids`` (the
+   block_sparse_attention idiom), so each M-tile contracts against
+   exactly its expert's ``[K, N]`` slice of the stacked ``[E, K, N]``
+   weights — zero top-k slot padding, no dense ``[T, E, C]`` tensors
+   anywhere.  A tile at or past ``used_blocks`` repeats the block index
+   of the last tile that holds rows (so nothing is copied for it), does
+   no MXU work and writes zeros.
+3. The blocks (:func:`_choose_blocks`) come from the shapes, the dtypes
+   and a VMEM budget that is a constant per device kind.  **Resident**:
+   ``bk = K``, one contraction per tile and no scratch accumulator, with
+   the widest ``bn`` dividing N whose double-buffered ``[K, bn]`` panel
+   fits beside the row and output tiles.  The weight block's index is
+   then ``(g[i], 0, j)``: equal for consecutive M-tiles of one expert,
+   so Pallas copies an expert's panel once per call and N block, not once
+   per M-tile (``E·K·N`` weight values a call instead of
+   ``m_tiles·K·N``), and the call raises its VMEM limit where the panel
+   passes what Mosaic grants unasked.  **Streamed**: where no whole-K
+   panel fits, or where it would move more bytes (rows are re-read once
+   per N block), the K-innermost ``(512, 1024)`` blocks with an fp32
+   scratch accumulator, every M-tile fetching its expert's blocks again.
+   Blocks given by the caller or by ``DS_GGEMM_BLOCKS`` are taken as
+   given, and one block over K is the resident regime.
+4. int8 weights ride the exact ``qgemm`` per-tile VMEM scale-expansion
+   design (selector-matmul dequant immediately before the MXU dot) at
+   the K-innermost blocks, so routed experts stream at the same int8
+   weight floor as dense layers.
+5. backward (float path): ``dx`` reuses the forward kernel with a
+   transposed-RHS contraction (its panel is ``[K, N]`` read along N);
+   ``dw`` is a tgmm kernel (grid ``(K/bk, N/bn, m_tiles)``, M innermost)
+   accumulating per-expert outer products in a ``[bk, bn]`` fp32 scratch
+   and flushing on group change, its blocks by the same rule — with
+   ``bk = K`` and ``bn = N`` both row operands are read once.  Per-step
+   expert FLOPs stay ∝ routed tokens in BOTH directions.
+
+What each call moves as tiled is counted while a step is traced
+(``telemetry/tracing.py``: ``grouped_gemm_rows``): per kernel and weight
+shape the blocks, the regime, and the weight and operand bytes of one
+call.
 
 Off-TPU the jnp reference (``jax.lax.ragged_dot`` over the same padded
 layout) serves correctness and autodiff; ``interpret=True`` (or
@@ -61,11 +89,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# default tile shapes (the qgemm defaults: bm capped at the MXU row dim,
-# bk/bn sized so the dominant VMEM tenant stays ~0.5-1 MB double-buffered)
+#: the M-tile the plan pads to (the MXU's row dimension), and the
+#: (bk, bn) of the K-innermost tiling: the int8 and slot kernels', and
+#: what a float call falls back to when no whole-K weight panel fits VMEM
+#: (:func:`_choose_blocks`)
 DEFAULT_BLOCK_M = 128
-DEFAULT_BLOCK_K = 512
-DEFAULT_BLOCK_N = 1024
+_BLOCKS_KN = (512, 1024)
+
+#: VMEM the buffers a grouped call names may fill, by substring of the
+#: device kind (a v5e reports "TPU v5 lite" and has 128 MiB).  Kinds not
+#: listed get ``_VMEM_UNASKED``: what fits the 16 MiB Mosaic grants a call
+#: that asks for nothing, a quarter left for the compiler's own scratch.
+#: A call whose buffers pass that raises its limit to the device's budget
+#: plus ``_VMEM_HEADROOM``.
+_VMEM_BUDGET = (("v5 lite", 64 << 20),)
+_VMEM_UNASKED = 12 << 20
+_VMEM_HEADROOM = 32 << 20
 
 
 def _round_up(n: int, m: int) -> int:
@@ -101,6 +140,8 @@ class GroupPlan(NamedTuple):
     num_experts: int               # static E
     group_sizes: jnp.ndarray       # [E] padded rows per expert (⋅bm, ≥ bm)
     block_group_ids: jnp.ndarray   # [num_blocks] expert per M-tile (sorted)
+    used_blocks: jnp.ndarray       # [1] M-tiles that hold a group; the rest
+    #                                trail behind the last one, all zeros
     row_to_padded: jnp.ndarray     # [R] flat element -> padded row
     padded_to_row: jnp.ndarray     # [Mp] padded row -> flat element | R
     counts: jnp.ndarray            # [E] true routed counts (telemetry)
@@ -147,13 +188,15 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
     cum_blocks = jnp.cumsum(blocks_e)                  # [E]
     bidx = jnp.arange(num_blocks, dtype=jnp.int32)
     # tile b belongs to the first expert whose cumulative tile count
-    # exceeds b; trailing unused tiles clamp to E-1 (all-zero rows, so
-    # they compute and write zeros — monotonicity preserved for tgmm)
+    # exceeds b; the tiles past the last group (``used_blocks`` on) clamp
+    # to E-1, which keeps the ids monotone for tgmm: all-zero rows, which
+    # the kernels neither fetch nor multiply — they write zeros
     gids = jnp.sum((bidx[:, None] >= cum_blocks[None, :]).astype(jnp.int32),
                    axis=1)
     gids = jnp.minimum(gids, E - 1).astype(jnp.int32)
     return GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
-                     row_to_padded, padded_to_row, counts)
+                     cum_blocks[-1:].astype(jnp.int32), row_to_padded,
+                     padded_to_row, counts)
 
 
 # ----------------------------------------------------------- row movement
@@ -300,26 +343,53 @@ def _ref_ggemm_q(x, q, scales, plan: GroupPlan, out_dtype):
 
 
 # --------------------------------------------------------------- kernels
-def _ggemm_kernel(gid_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k,
-                  transpose_rhs, precision):
-    """One (i, j, k) step: accumulate x_tile @ w[g[i]]_tile into the fp32
-    scratch (K innermost, the qgemm accumulation pattern)."""
+# Grid (N/bn, m_tiles, K/bk).  Both scalar-prefetched operands reach every
+# kernel and index map: ``gid_ref`` [m_tiles] names each M-tile's expert,
+# ``used_ref`` [1] how many tiles hold a group.
+def _accumulate(i, used_ref, o_ref, acc, n_k, product):
+    """o = Σ_k product() for a tile that holds rows, zeros for one that
+    trails (no MXU work; the next grouped call and ``ds_ggemm_dw`` read
+    those rows).  Where one block spans K (``n_k`` 1) there is no scratch
+    and the product goes straight out."""
+    live = i < used_ref[0]
+    if n_k == 1:
+        @pl.when(live)
+        def _rows():
+            o_ref[:] = product().astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(live))
+        def _trailing():
+            o_ref[:] = jnp.zeros_like(o_ref)
+        return
+    acc_ref, = acc
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:]                                   # [bm, bk]
-    w = w_ref[0]                                   # [bk, bn] | [bn, bk]
-    contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
-    acc_ref[:] += jax.lax.dot_general(
-        x, w.astype(x.dtype), (contract, ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
+    @pl.when(live)
+    def _rows():
+        acc_ref[:] += product()
 
     @pl.when(k_idx == n_k - 1)
     def _finalize():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+
+
+def _ggemm_kernel(gid_ref, used_ref, x_ref, w_ref, o_ref, *acc, n_k,
+                  transpose_rhs, precision):
+    """One (j, i, k) step: x_tile @ w[g[i]]_tile in fp32."""
+    contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
+
+    def product():
+        x = x_ref[:]                               # [bm, bk]
+        w = w_ref[0]                               # [bk, bn] | [bn, bk]
+        return jax.lax.dot_general(
+            x, w.astype(x.dtype), (contract, ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+    _accumulate(pl.program_id(1), used_ref, o_ref, acc, n_k, product)
 
 
 def _dequant_tile(qt, s, j, qblock, block_n, dtype):
@@ -337,33 +407,29 @@ def _dequant_tile(qt, s, j, qblock, block_n, dtype):
     return (qt.astype(jnp.float32) * s_exp).astype(dtype)
 
 
-def _ggemm_q_kernel(gid_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *,
+def _ggemm_q_kernel(gid_ref, used_ref, x_ref, q_ref, s_ref, o_ref, *acc,
                     qblock, block_n, n_k, precision):
     """int8 expert tile: fused dequant (:func:`_dequant_tile`) of expert
     g[i]'s [bk, bn] tile; the int8 bytes are the only HBM weight
     traffic."""
-    j = pl.program_id(1)
-    k_idx = pl.program_id(2)
+    j = pl.program_id(0)
 
-    @pl.when(k_idx == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def product():
+        x = x_ref[:]                                # [bm, bk]
+        w = _dequant_tile(q_ref[0], s_ref[0], j, qblock, block_n, x.dtype)
+        return jax.lax.dot(x, w, preferred_element_type=jnp.float32,
+                           precision=precision)
 
-    x = x_ref[:]                                    # [bm, bk]
-    w = _dequant_tile(q_ref[0], s_ref[0], j, qblock, block_n, x.dtype)
-    acc_ref[:] += jax.lax.dot(x, w, preferred_element_type=jnp.float32,
-                              precision=precision)
-
-    @pl.when(k_idx == n_k - 1)
-    def _finalize():
-        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+    _accumulate(pl.program_id(1), used_ref, o_ref, acc, n_k, product)
 
 
-def _tgmm_kernel(gid_ref, x_ref, dy_ref, o_ref, acc_ref, *, nm, precision):
+def _tgmm_kernel(gid_ref, used_ref, x_ref, dy_ref, o_ref, acc_ref, *, nm,
+                 precision):
     """dw[e] = Σ_{rows of group e} x_row ⊗ dy_row.  Grid (K/bk, N/bn,
     m_tiles) with M innermost: group_ids are non-decreasing, so each
     expert's (k, j) output tile is visited in ONE contiguous run —
-    accumulate across the run, flush on group change (or last tile)."""
+    accumulate across the run, flush on group change (or last tile).
+    The trailing tiles lengthen expert E-1's run and add nothing."""
     i = pl.program_id(2)
     g = gid_ref[i]
     prev = gid_ref[jnp.maximum(i - 1, 0)]
@@ -375,18 +441,20 @@ def _tgmm_kernel(gid_ref, x_ref, dy_ref, o_ref, acc_ref, *, nm, precision):
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:]                                    # [bm, bk]
-    dy = dy_ref[:]                                  # [bm, bn]
-    acc_ref[:] += jax.lax.dot_general(
-        x, dy.astype(x.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
+    @pl.when(i < used_ref[0])
+    def _rows():
+        x = x_ref[:]                                # [bm, bk]
+        dy = dy_ref[:]                              # [bm, bn]
+        acc_ref[:] += jax.lax.dot_general(
+            x, dy.astype(x.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
 
     @pl.when(last)
     def _flush():
         o_ref[0] = acc_ref[:].astype(o_ref.dtype)
 
 
-# --------------------------------------------------------- pallas drivers
+# ------------------------------------------------------- the block shapes
 def _fit_block(dim, requested, quantum=128):
     """qgemm's divisor-fitting rule: shrink to a quantum-multiple that
     divides a 128-aligned dim (padding a non-dividing weight dim would
@@ -401,6 +469,95 @@ def _fit_block(dim, requested, quantum=128):
     return b
 
 
+def _device_kind() -> str:
+    return str(jax.devices()[0].device_kind).lower()
+
+
+def _vmem_budget() -> int:
+    kind = _device_kind()
+    return next((b for sub, b in _VMEM_BUDGET if sub in kind),
+                _VMEM_UNASKED)
+
+
+class _Tiling(NamedTuple):
+    """What :func:`_choose_blocks` settled for one kernel call."""
+    bk: int
+    bn: int
+    regime: str          # "resident": one block spans K | "streamed"
+    weight_bytes: int    # expert weights the grid moves as tiled, at most
+    operand_bytes: int   # rows in and out (dw: both row operands)
+    vmem_bytes: int      # the buffers the call names
+
+
+def _choose_blocks(kernel, rows, K, N, E, bm, sizes, blocks=None):
+    """(bk, bn) of one call of ``kernel`` over ``rows`` padded rows, with
+    the account of that tiling from shapes and blocks alone (``sizes``:
+    bytes of a row, weight and result value).  Given ``blocks``, those
+    (fitted to the dims); else whichever of two moves fewer bytes — one
+    block over K beside the widest divisor of N whose buffers fit the
+    device's VMEM budget, so that a weight panel stays in VMEM across its
+    expert's M-tiles (dw: so that each row operand is read once per block
+    of the other's dim), or the K-innermost ``_BLOCKS_KN`` where no such
+    panel fits."""
+    isz, wsz, osz = sizes
+    m = rows // bm
+
+    def tile(bk, bn):
+        n_n, n_k = -(-N // bn), -(-K // bk)
+        if kernel == "ds_ggemm_dw":
+            # out [E, K, N] written once; x is re-read per N block, dy per
+            # K block; fp32 scratch and product beside the output block
+            weight = E * K * N * wsz
+            operand = rows * (K * n_n + N * n_k) * isz
+            vmem = (2 * bm * (bk + bn) * isz + 2 * bk * bn * wsz
+                    + 2 * bk * bn * 4)
+        else:
+            # the weight block's index is (g[i], k, j): with one block
+            # over K it changes only where the expert does (min(E, m)
+            # times per N block); with K innermost, on every grid step
+            weight = (min(E, m) if n_k == 1 else m) * K * N * wsz
+            operand = rows * (K * n_n * isz + N * osz)
+            vmem = (2 * bm * bk * isz + 2 * bk * bn * wsz
+                    + 2 * bm * bn * osz + (1 + (n_k > 1)) * bm * bn * 4)
+        return _Tiling(bk, bn, "resident" if n_k == 1 else "streamed",
+                       weight, operand, vmem)
+
+    if blocks is not None:
+        return tile(_fit_block(K, blocks[0]), _fit_block(N, blocks[1]))
+    streamed = tile(_fit_block(K, _BLOCKS_KN[0]), _fit_block(N, _BLOCKS_KN[1]))
+    quantum = 128
+    widths = ([b for b in range(N, 0, -quantum) if N % b == 0]
+              if N % quantum == 0 else [_round_up(N, quantum)])
+    budget = _vmem_budget()
+    for bn in widths:
+        resident = tile(_round_up(K, quantum), bn)
+        if resident.vmem_bytes <= budget:
+            return min(resident, streamed,
+                       key=lambda t: t.weight_bytes + t.operand_bytes)
+    return streamed
+
+
+def _count_call(kernel, K, N, tiling: _Tiling):
+    """One row of the step's own account (telemetry/tracing.py
+    ``grouped_gemm_rows``): what this call moves as tiled."""
+    from deepspeed_tpu.telemetry.tracing import count_in_step
+    count_in_step(grouped_calls={f"{kernel}:{K}x{N}": {
+        "kernel": kernel, "k": K, "n": N, "blocks": (tiling.bk, tiling.bn),
+        "regime": tiling.regime,
+        "weight_bytes_per_call": tiling.weight_bytes,
+        "operand_bytes_per_call": tiling.operand_bytes}})
+
+
+def _compiler_params(tiling: _Tiling):
+    """A raised VMEM limit where the call's buffers pass what it is
+    granted unasked; else nothing."""
+    if tiling.vmem_bytes <= _VMEM_UNASKED:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=_vmem_budget() + _VMEM_HEADROOM)
+
+
+# --------------------------------------------------------- pallas drivers
 def _precision_for(dtype):
     # fp32 operands need full-precision MXU passes (decode_attention.py)
     return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
@@ -429,20 +586,45 @@ def _pad_operands(x, w, scales, bk, bn, transpose_rhs):
     return x, w, scales
 
 
-def _pallas_ggemm(x, w, gids, block_m, *, block_k, block_n, interpret,
-                  out_dtype, transpose_rhs=False, scales=None):
+def _live_tile(i, used):
+    """The M-tile index a row operand fetches at grid position ``i``: a
+    trailing tile repeats the last one that holds rows, and a block whose
+    index did not change is not copied again."""
+    return jnp.minimum(i, used[0] - 1)
+
+
+def _weight_block(transpose_rhs):
+    """Index map of the weight operand over the grid (j, i, k): expert
+    g[i]'s (k, j) block."""
+    if transpose_rhs:
+        return lambda j, i, k, g, u: (g[i], j, k)
+    return lambda j, i, k, g, u: (g[i], k, j)
+
+
+def _pallas_ggemm(x, w, tiles, block_m, *, blocks, interpret, out_dtype,
+                  transpose_rhs=False, scales=None):
     """x [Mp, K] group-padded; w [E, K, N] (or [E, N, K] with
-    ``transpose_rhs``); ``gids`` [Mp // block_m] per-tile expert ids;
-    ``scales`` [E, K, nb] selects the int8 kernel."""
+    ``transpose_rhs``); ``tiles`` = the plan's ``(block_group_ids
+    [Mp // block_m], used_blocks [1])``; ``scales`` [E, K, nb] selects
+    the int8 kernel; ``blocks`` (bk, bn) or None for
+    :func:`_choose_blocks`' own."""
     Mp, K = x.shape
     bm = block_m
     num_blocks = Mp // bm
+    gids, used = tiles
     assert num_blocks * bm == Mp and gids.shape == (num_blocks,), \
         (x.shape, bm, gids.shape)
     ndim_ax = 1 if transpose_rhs else 2
     N = w.shape[ndim_ax]
-    bk = _fit_block(K, block_k)
-    bn = _fit_block(N, block_n)
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    name = ("ds_ggemm_q" if scales is not None
+            else "ds_ggemm_dx" if transpose_rhs else "ds_ggemm_fwd")
+    if scales is not None and blocks is None:
+        blocks = _BLOCKS_KN      # per-tile dequantisation: today's tiling
+    tiling = _choose_blocks(
+        name, Mp, K, N, w.shape[0], bm,
+        (x.dtype.itemsize, w.dtype.itemsize, out_dtype.itemsize), blocks)
+    bk, bn = tiling.bk, tiling.bn
     # scale-group width is defined by the UNPADDED N (quantization.py
     # shape contract: gw = ceil(N / nb)); compute before any padding
     qblock = -(-N // scales.shape[-1]) if scales is not None else None
@@ -450,11 +632,15 @@ def _pallas_ggemm(x, w, gids, block_m, *, block_k, block_n, interpret,
     K_pad = x.shape[1]
     N_pad = w.shape[ndim_ax]
     n_k = K_pad // bk
-    grid = (num_blocks, N_pad // bn, n_k)
+    # N outermost, K innermost: with one block over K the weight block's
+    # index is that of its expert and N block alone, equal for consecutive
+    # M-tiles of one expert, and the panel stays where it is
+    grid = (N_pad // bn, num_blocks, n_k)
     precision = _precision_for(x.dtype)
-    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    _count_call(name, K, N, tiling)
 
-    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k, g: (i, k))
+    x_spec = pl.BlockSpec((bm, bk),
+                          lambda j, i, k, g, u: (_live_tile(i, u), k))
     if scales is not None:
         assert not transpose_rhs, "int8 grouped GEMM has no transposed RHS"
         nb = scales.shape[-1]
@@ -463,71 +649,81 @@ def _pallas_ggemm(x, w, gids, block_m, *, block_k, block_n, interpret,
             precision=precision)
         in_specs = [
             x_spec,
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, g: (g[i], k, j)),
-            pl.BlockSpec((1, bk, nb), lambda i, j, k, g: (g[i], k, 0)),
+            pl.BlockSpec((1, bk, bn), _weight_block(False)),
+            pl.BlockSpec((1, bk, nb), lambda j, i, k, g, u: (g[i], k, 0)),
         ]
         operands = (x, w, scales.astype(jnp.float32))
     else:
-        wspec = (pl.BlockSpec((1, bn, bk), lambda i, j, k, g: (g[i], j, k))
-                 if transpose_rhs else
-                 pl.BlockSpec((1, bk, bn), lambda i, j, k, g: (g[i], k, j)))
         kernel = functools.partial(
             _ggemm_kernel, n_k=n_k, transpose_rhs=transpose_rhs,
             precision=precision)
-        in_specs = [x_spec, wspec]
+        in_specs = [x_spec,
+                    pl.BlockSpec((1, bn, bk) if transpose_rhs
+                                 else (1, bk, bn),
+                                 _weight_block(transpose_rhs))]
         operands = (x, w)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, g: (i, j)),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda j, i, k, g, u: (i, j)),
+            scratch_shapes=([pltpu.VMEM((bm, bn), jnp.float32)]
+                            if n_k > 1 else []),
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N_pad), out_dtype),
         interpret=interpret,
-        name=("ds_ggemm_q" if scales is not None
-              else "ds_ggemm_dx" if transpose_rhs else "ds_ggemm_fwd"),
-    )(gids, *operands)
+        compiler_params=_compiler_params(tiling),
+        name=name,
+    )(gids, used, *operands)
     return out[:, :N]
 
 
-def _pallas_tgmm(x, dy, gids, block_m, num_experts, *, block_k, block_n,
-                 interpret, out_dtype):
+def _pallas_tgmm(x, dy, tiles, block_m, num_experts, *, blocks, interpret,
+                 out_dtype):
     """per-expert x^T @ dy over the padded layout -> [E, K, N]."""
     Mp, K = x.shape
     _, N = dy.shape
     bm = block_m
-    bk = _fit_block(K, block_k)
-    bn = _fit_block(N, block_n)
+    gids, used = tiles
+    out_dtype = jnp.dtype(out_dtype)
+    tiling = _choose_blocks(
+        "ds_ggemm_dw", Mp, K, N, num_experts, bm,
+        (x.dtype.itemsize, out_dtype.itemsize, out_dtype.itemsize), blocks)
+    bk, bn = tiling.bk, tiling.bn
     K_pad, N_pad = _round_up(K, bk), _round_up(N, bn)
     if K_pad != K:
         x = jnp.pad(x, ((0, 0), (0, K_pad - K)))
     if N_pad != N:
         dy = jnp.pad(dy, ((0, 0), (0, N_pad - N)))
     nm = Mp // bm
+    _count_call("ds_ggemm_dw", K, N, tiling)
     kernel = functools.partial(_tgmm_kernel, nm=nm,
                                precision=_precision_for(x.dtype))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(K_pad // bk, N_pad // bn, nm),
             in_specs=[
-                pl.BlockSpec((bm, bk), lambda k, j, i, g: (i, k)),
-                pl.BlockSpec((bm, bn), lambda k, j, i, g: (i, j)),
+                pl.BlockSpec((bm, bk),
+                             lambda k, j, i, g, u: (_live_tile(i, u), k)),
+                pl.BlockSpec((bm, bn),
+                             lambda k, j, i, g, u: (_live_tile(i, u), j)),
             ],
             out_specs=pl.BlockSpec((1, bk, bn),
-                                   lambda k, j, i, g: (g[i], k, j)),
+                                   lambda k, j, i, g, u: (g[i], k, j)),
             scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (num_experts, K_pad, N_pad), jnp.dtype(out_dtype)),
+            (num_experts, K_pad, N_pad), out_dtype),
         interpret=interpret,
+        compiler_params=_compiler_params(tiling),
         name="ds_ggemm_dw",
-    )(gids, x, dy)
+    )(gids, used, x, dy)
     return out[:, :K, :N]
 
 
@@ -684,8 +880,8 @@ def ds_ggemm_slots(x, w, plan: SlotPlan, *, out_dtype=None, block_k=None,
     exactly once per step."""
     from deepspeed_tpu.models.model import QuantizedTensor
     env = _env_blocks()
-    bk = block_k or (env[1] if env else DEFAULT_BLOCK_K)
-    bn = block_n or (env[2] if env else DEFAULT_BLOCK_N)
+    bk = block_k or (env[1] if env else _BLOCKS_KN[0])
+    bn = block_n or (env[2] if env else _BLOCKS_KN[1])
     if isinstance(w, QuantizedTensor):
         w = (w.q, w.s)
     use_ref, interp = _use_reference(interpret)
@@ -719,30 +915,29 @@ def _ref_ggemm_rows(x, w, eids, out_dtype):
 
 # ----------------------------------------------------- differentiable core
 # static config (tile sizes, expert count, interpret flag) rides
-# nondiff_argnums; the traced per-tile expert map is a primal whose
-# cotangent is symbolic-zero (int32 -> float0).
+# nondiff_argnums; the traced per-tile expert map (``tiles``: the plan's
+# block_group_ids and used_blocks) is a primal whose cotangent is
+# symbolic-zero (int32 -> float0).  ``blocks``: (bk, bn) of the forward
+# call, or None for each kernel's own by :func:`_choose_blocks`.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _ggemm_diff(x, w, gids, block_m, num_experts, blocks, interpret):
-    bk, bn = blocks
-    return _pallas_ggemm(x, w, gids, block_m, block_k=bk, block_n=bn,
+def _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret):
+    return _pallas_ggemm(x, w, tiles, block_m, blocks=blocks,
                          interpret=interpret, out_dtype=x.dtype)
 
 
-def _ggemm_diff_fwd(x, w, gids, block_m, num_experts, blocks, interpret):
-    out = _ggemm_diff(x, w, gids, block_m, num_experts, blocks, interpret)
-    return out, (x, w, gids)
+def _ggemm_diff_fwd(x, w, tiles, block_m, num_experts, blocks, interpret):
+    out = _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret)
+    return out, (x, w, tiles)
 
 
 def _ggemm_diff_bwd(block_m, num_experts, blocks, interpret, res, g):
-    x, w, gids = res
-    bk, bn = blocks
+    x, w, tiles = res
     # dx: same kernel, transposed contraction against the SAME expert map
-    dx = _pallas_ggemm(g.astype(x.dtype), w, gids, block_m, block_k=bn,
-                       block_n=bk, interpret=interpret, out_dtype=x.dtype,
-                       transpose_rhs=True)
-    dw = _pallas_tgmm(x, g.astype(x.dtype), gids, block_m, num_experts,
-                      block_k=bk, block_n=bn, interpret=interpret,
-                      out_dtype=w.dtype)
+    dx = _pallas_ggemm(g.astype(x.dtype), w, tiles, block_m,
+                       blocks=blocks and blocks[::-1], interpret=interpret,
+                       out_dtype=x.dtype, transpose_rhs=True)
+    dw = _pallas_tgmm(x, g.astype(x.dtype), tiles, block_m, num_experts,
+                      blocks=blocks, interpret=interpret, out_dtype=w.dtype)
     return dx, dw, None
 
 
@@ -794,12 +989,17 @@ def ds_ggemm(x, w, plan: GroupPlan, *, out_dtype=None, block_k=None,
     """
     from deepspeed_tpu.models.model import QuantizedTensor
     env = _env_blocks()
-    bk = block_k or (env[1] if env else DEFAULT_BLOCK_K)
-    bn = block_n or (env[2] if env else DEFAULT_BLOCK_N)
+    # a block given by the caller or by DS_GGEMM_BLOCKS is taken as given;
+    # with neither, _choose_blocks settles both per kernel
+    blocks = None
+    if block_k or block_n or env:
+        blocks = (block_k or (env[1] if env else _BLOCKS_KN[0]),
+                  block_n or (env[2] if env else _BLOCKS_KN[1]))
     if isinstance(w, QuantizedTensor):
         w = (w.q, w.s)
     quantized = isinstance(w, tuple)
     use_ref, interp = _use_reference(interpret)
+    tiles = (plan.block_group_ids, plan.used_blocks)
     if quantized:
         q, scales = w
         if q.ndim != 3 or scales.ndim != 3:
@@ -814,22 +1014,22 @@ def ds_ggemm(x, w, plan: GroupPlan, *, out_dtype=None, block_k=None,
         with _maybe_span(x, {"shape": f"{x.shape[0]}x{q.shape[1]}"
                                       f"x{q.shape[2]}",
                              "experts": int(q.shape[0]), "int8": True}):
-            return _pallas_ggemm(x, q, plan.block_group_ids, plan.block_m,
-                                 block_k=bk, block_n=bn, interpret=interp,
+            return _pallas_ggemm(x, q, tiles, plan.block_m, blocks=blocks,
+                                 interpret=interp,
                                  out_dtype=out_dtype or x.dtype,
                                  scales=scales)
     if use_ref:
         return _ref_ggemm(x, w, plan, transpose_rhs, out_dtype)
     if transpose_rhs:
-        return _pallas_ggemm(x, w, plan.block_group_ids, plan.block_m,
-                             block_k=bk, block_n=bn, interpret=interp,
+        return _pallas_ggemm(x, w, tiles, plan.block_m, blocks=blocks,
+                             interpret=interp,
                              out_dtype=out_dtype or x.dtype,
                              transpose_rhs=True)
     with _maybe_span(x, {"shape": f"{x.shape[0]}x{w.shape[1]}"
                                   f"x{w.shape[2]}",
                          "experts": int(w.shape[0]), "int8": False}):
-        out = _ggemm_diff(x, w, plan.block_group_ids, plan.block_m,
-                          plan.num_experts, (bk, bn), interp)
+        out = _ggemm_diff(x, w, tiles, plan.block_m, plan.num_experts,
+                          blocks, interp)
     if out_dtype is not None:
         out = out.astype(out_dtype)
     return out

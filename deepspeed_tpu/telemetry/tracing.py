@@ -580,12 +580,20 @@ def step_account(name: str = TRAIN_STEP_PROGRAM):
         _ACCOUNT_OPEN = outer
 
 
-def count_in_step(**counters: int):
+def count_in_step(**counters):
     """Trace-time, static values only (shapes): no host callback, nothing
-    in the compiled step.  A no-op outside :func:`step_account`."""
-    if _ACCOUNT_OPEN is not None:
-        _STEP_COUNTERS[_ACCOUNT_OPEN].update(
-            {k: int(v) for k, v in counters.items()})
+    in the compiled step.  A number replaces what its name held; a dict
+    is merged into the dict kept under its name (one entry per key,
+    however often the code that counts is traced).  A no-op outside
+    :func:`step_account`."""
+    if _ACCOUNT_OPEN is None:
+        return
+    account = _STEP_COUNTERS[_ACCOUNT_OPEN]
+    for name, value in counters.items():
+        if isinstance(value, dict):
+            account.setdefault(name, {}).update(value)
+        else:
+            account[name] = int(value)
 
 
 def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
@@ -594,13 +602,23 @@ def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
     "padded_rows_per_call"}``, shapes that moe/layer.py wrote when the
     plan was traced (every grouped call of a step has the same: R =
     tokens x top_k routed rows inside ``round_up(R, bm) + E*bm`` padded
-    ones, the rest zeros).  None where no step with a grouped dispatch
-    was traced."""
+    ones, the rest zeros).  Where the step's grouped calls ran as Pallas
+    kernels, also ``"calls"``: one row per kernel and weight shape, as
+    ops/pallas/grouped_gemm.py tiled it — ``kernel``, ``k``, ``n``,
+    ``blocks`` (bk, bn), ``regime`` ("resident": the expert's weight panel
+    stays in VMEM across its M-tiles | "streamed": every M-tile fetches
+    it again) and the bytes one call moves as tiled, at most:
+    ``weight_bytes_per_call`` and ``operand_bytes_per_call`` (the rows in
+    and out).  None where no step with a grouped dispatch was traced."""
     account = _STEP_COUNTERS.get(name, {})
     if "grouped_padded_rows" not in account:
         return None
-    return {"routed_rows_per_call": account["grouped_routed_rows"],
+    rows = {"routed_rows_per_call": account["grouped_routed_rows"],
             "padded_rows_per_call": account["grouped_padded_rows"]}
+    if "grouped_calls" in account:
+        rows["calls"] = [account["grouped_calls"][key]
+                         for key in sorted(account["grouped_calls"])]
+    return rows
 
 
 def reset_programs():

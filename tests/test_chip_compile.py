@@ -1,7 +1,7 @@
 """Compiles for a TPU v5e that is described, not attached (the chip's
 compiler is installed on CPU-only boxes): the training path's kernels at
-GPT-2 760M width, and the grouped GEMM kernels at OLMoE-1B-7B's, go
-through Mosaic, the data-sharded flash kernel goes through the
+GPT-2 760M width, and the grouped GEMM kernels at OLMoE-1B-7B's (their
+weight panels resident in VMEM) and at Mixtral-8x7B's, go through Mosaic, the data-sharded flash kernel goes through the
 partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
@@ -80,19 +80,34 @@ def _decode(q, k, v, n, ks=None, vs=None):
     return decode_attention_pallas(q, k, v, n, k_scale=ks, v_scale=vs)
 
 
-def _ggemm(x, w, gids):
+def _ggemm(x, w, gids, used, blocks=None):
     """The differentiable grouped GEMM as moe/layer.py's training path
-    calls it (ds_ggemm with the reference switched off: no TPU here)."""
+    calls it (ds_ggemm with the reference switched off: no TPU here),
+    with the blocks the library chooses for a v5e."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
-    return gg._ggemm_diff(x, w, gids, gg.DEFAULT_BLOCK_M, GG_E,
-                          (gg.DEFAULT_BLOCK_K, gg.DEFAULT_BLOCK_N), False)
+    return gg._ggemm_diff(x, w, (gids, used), gg.DEFAULT_BLOCK_M,
+                          w.shape[0], blocks, False)
+
+
+def _ggemm_streamed(x, w, gids, used):
+    """The K-innermost tiling, asked for as DS_GGEMM_BLOCKS would."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    return _ggemm(x, w, gids, used, gg._BLOCKS_KN)
+
+
+def _ggemm_args(experts, rows, k, n):
+    return [((rows, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
+            ((rows // 128,), jnp.int32), ((1,), jnp.int32)]
 
 
 # olmoe-1b-7b.packed-s4096-gas8: 4096 tokens x 8 choices = 32,768 routed
 # rows over 64 experts, padded to 32,768 + 64 * 128; D 2048 -> F 1024
+# (gate, up) and back (down)
 GG_E, GG_ROWS, GG_D, GG_F = 64, 32768 + 64 * 128, 2048, 1024
-_GGEMM = [((GG_ROWS, GG_D), jnp.bfloat16), ((GG_E, GG_D, GG_F), jnp.bfloat16),
-          ((GG_ROWS // 128,), jnp.int32)]
+_GGEMM = _ggemm_args(GG_E, GG_ROWS, GG_D, GG_F)
+_GGEMM_DOWN = _ggemm_args(GG_E, GG_ROWS, GG_F, GG_D)
+# mixtral-8x7b's gate projection, a prefill of 4096 tokens x 2 choices
+_GGEMM_MIXTRAL = _ggemm_args(8, 8192 + 8 * 128, 4096, 14336)
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -100,6 +115,13 @@ KERNEL_CASES = {
     "ds_flash_fwd_bwd": (jax.grad(_sum_sq(_ds_flash), (0, 1, 2)), _QKV),
     "ds_ggemm_fwd": (_ggemm, _GGEMM),
     "ds_ggemm_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)), _GGEMM),
+    "ds_ggemm_down_fwd": (_ggemm, _GGEMM_DOWN),
+    "ds_ggemm_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                              _GGEMM_DOWN),
+    "ds_ggemm_mixtral_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                                 _GGEMM_MIXTRAL),
+    "ds_ggemm_mixtral_streamed_fwd_bwd": (
+        jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
     "stock_flash_fwd": (_stock_flash, _QKV),
     "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
                             _QKV),
@@ -129,15 +151,44 @@ NAMED_KERNELS = {
                          "ds_flash_bwd_dq"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
     "ds_ggemm_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+    "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+}
+
+#: the regime each grouped kernel of a case takes (the step account's
+#: word, telemetry/tracing.py grouped_gemm_rows): Mosaic proves below that
+#: the resident panels fit a v5e's VMEM, and that the K-innermost tiling
+#: still compiles where blocks ask for it
+_ALL_THREE = ("ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw")
+GGEMM_REGIMES = {
+    "ds_ggemm_fwd": {"ds_ggemm_fwd": "resident"},
+    "ds_ggemm_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_down_fwd": {"ds_ggemm_fwd": "resident"},
+    "ds_ggemm_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_mixtral_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    # (512, 1024) swapped for dx is one block over its contraction of 1024
+    "ds_ggemm_mixtral_streamed_fwd_bwd": {
+        "ds_ggemm_fwd": "streamed", "ds_ggemm_dx": "streamed",
+        "ds_ggemm_dw": "streamed"},
 }
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_kernel_compiles_for_v5e(v5e, case):
+def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    from deepspeed_tpu.telemetry import tracing
     fn, args = KERNEL_CASES[case]
-    compiled = jax.jit(fn).lower(
-        *(_arg(v5e[0], shape, dtype) for shape, dtype in args)).compile()
+    # the library asks jax.devices() what it runs on, and here that is a CPU
+    monkeypatch.setattr(gg, "_device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    with tracing.step_account("test/compile"):
+        tracing.count_in_step(grouped_routed_rows=0, grouped_padded_rows=0)
+        compiled = jax.jit(fn).lower(
+            *(_arg(v5e[0], shape, dtype) for shape, dtype in args)).compile()
     assert KERNEL in compiled.as_text()
+    if case in GGEMM_REGIMES:
+        calls = tracing.grouped_gemm_rows("test/compile")["calls"]
+        assert {c["kernel"]: c["regime"] for c in calls} \
+            == GGEMM_REGIMES[case], calls
     if case in NAMED_KERNELS:
         # the program's own map tells these Mosaic calls apart by name
         from deepspeed_tpu.telemetry.tracing import parse_program_text

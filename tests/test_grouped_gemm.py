@@ -79,6 +79,9 @@ def test_group_plan_layout_invariants():
                   if starts[e] <= row0 < starts[e + 1]]
         if owners:                       # trailing tiles clamp to E-1
             assert gids[b] == owners[0]
+    # used_blocks: the tiles that hold a group; the rest trail, unowned
+    assert plan.used_blocks.shape == (1,)
+    assert int(plan.used_blocks[0]) * bm == gsz.sum()
     # the two maps are inverses: padded_to_row names the element in each
     # row of a group and reads R, out of range, on every padding row
     p2r = np.asarray(plan.padded_to_row)
@@ -199,32 +202,82 @@ def test_rows_move_by_gathers_as_the_scatter_formulation_did(case):
     assert scatters(old) and not scatters(new)
 
 
-@pytest.mark.parametrize("eid_case", ["mixed", "empty_expert",
-                                      "one_expert", "ragged_T"])
+def _accounted(fn, *args):
+    """fn(*args) with the grouped calls it makes counted as a step would
+    count them: (result, {kernel: its row of grouped_gemm_rows' calls})."""
+    from deepspeed_tpu.telemetry import tracing
+    with tracing.step_account("test/ggemm"):
+        tracing.count_in_step(grouped_routed_rows=0, grouped_padded_rows=0)
+        out = fn(*args)
+    calls = tracing.grouped_gemm_rows("test/ggemm").get("calls", [])
+    return out, {c["kernel"]: c for c in calls}
+
+
+#: eid case -> (R, K, the regimes the library takes for fwd / dx / dw by
+#: the shape alone on this CPU's VMEM budget).  ``streamed``: a contraction
+#: so long that no [K, 128] panel fits, so forward and dw fall back to the
+#: K-innermost tiling (dx contracts over N and stays resident)
+GGEMM_CASES = {
+    "mixed": (26, 16, "resident"),
+    "empty_expert": (20, 16, "resident"),
+    "one_expert": (20, 16, "resident"),
+    "ragged_T": (13, 16, "resident"),
+    "trailing_tiles": (24, 16, "resident"),
+    "streamed": (26, 16384, "streamed"),
+    "streamed_trailing_tiles": (24, 16384, "streamed"),
+}
+
+
+def _ggemm_case(case, E=4):
+    R, K, regime = GGEMM_CASES[case]
+    rng = np.random.default_rng(1)
+    if case in ("mixed", "streamed"):
+        eids = _rand_eids(np.random.default_rng(2), R, E)
+    elif case == "empty_expert":
+        eids = jnp.asarray(rng.integers(0, E - 2, (R,)), jnp.int32)
+    elif case == "one_expert":
+        eids = jnp.full((R,), 2, jnp.int32)
+    elif case.endswith("trailing_tiles"):
+        # whole tiles only: every expert's padding is spare and trails
+        eids = jnp.asarray(np.repeat([0, 1, 3], 8), jnp.int32)
+    else:                                # T not divisible by block_m
+        eids = _rand_eids(np.random.default_rng(3), R, E)
+    x = jnp.asarray(rng.standard_normal((R, K)) / np.sqrt(K / 16),
+                    jnp.float32)
+    return R, K, regime, eids, x
+
+
+@pytest.mark.parametrize("eid_case", sorted(GGEMM_CASES))
 def test_ds_ggemm_float_parity(eid_case):
     """Reference AND interpret-mode kernel vs the per-row dense oracle,
-    across the ragged edge shapes the capacity formulation never sees."""
-    rng = np.random.default_rng(1)
-    E, K, N = 4, 16, 24
-    if eid_case == "mixed":
-        R, eids = 26, _rand_eids(np.random.default_rng(2), 26, E)
-    elif eid_case == "empty_expert":
-        R = 20
-        eids = jnp.asarray(rng.integers(0, E - 2, (R,)), jnp.int32)
-    elif eid_case == "one_expert":
-        R = 20
-        eids = jnp.full((R,), 2, jnp.int32)
-    else:                                # T not divisible by block_m
-        R, eids = 13, _rand_eids(np.random.default_rng(3), 13, E)
-    x = jnp.asarray(rng.standard_normal((R, K)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((E, K, N)), jnp.float32)
-    plan = gg.make_group_plan(eids, E, block_m=8)
+    across the ragged edge shapes the capacity formulation never sees and
+    both regimes of the tiling (chosen by shape).  Tiles past the last
+    routed row are written as exact zeros and never multiplied: whatever
+    their input rows hold."""
+    E, N, bm = 4, 24, 8
+    R, K, regime, eids, x = _ggemm_case(eid_case)
+    w = jnp.asarray(np.random.default_rng(7).standard_normal((E, K, N)),
+                    jnp.float32)
+    plan = gg.make_group_plan(eids, E, block_m=bm)
+    used = int(plan.used_blocks[0])
+    if eid_case.endswith("trailing_tiles"):
+        assert used == 4 and plan.num_blocks == 7
     oracle = _dense_rowwise(x, w, eids)
     for interpret in (None, True):       # None -> jnp reference on CPU
         xp = gg.scatter_to_groups(x, plan)
-        y = gg.ds_ggemm(xp, w, plan, interpret=interpret)
+        y, calls = _accounted(
+            lambda: gg.ds_ggemm(xp, w, plan, interpret=interpret))
         got = np.asarray(gg.gather_from_groups(y, plan))
         np.testing.assert_allclose(got, oracle, rtol=2e-5, atol=2e-5)
+        assert not np.asarray(y)[used * bm:].any()
+        if interpret:
+            assert calls["ds_ggemm_fwd"]["regime"] == regime, calls
+    # the kernel does not read the trailing tiles at all
+    poisoned = xp.at[used * bm:].set(jnp.nan)
+    y = gg.ds_ggemm(poisoned, w, plan, interpret=True)
+    np.testing.assert_allclose(np.asarray(gg.gather_from_groups(y, plan)),
+                               oracle, rtol=2e-5, atol=2e-5)
+    assert not np.asarray(y)[used * bm:].any()
 
 
 def test_ds_ggemm_int8_parity_and_in_place():
@@ -256,13 +309,15 @@ def test_ds_ggemm_int8_parity_and_in_place():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_ds_ggemm_backward_kernel_matches_reference():
+@pytest.mark.parametrize("eid_case", sorted(GGEMM_CASES))
+def test_ds_ggemm_backward_kernel_matches_reference(eid_case):
     """Custom-VJP kernel backward (dx via transposed-RHS forward kernel,
-    dw via the tgmm kernel; interpret mode) == ragged_dot autodiff."""
+    dw via the tgmm kernel; interpret mode) == ragged_dot autodiff, in
+    both regimes; dw of the last expert, whose run the trailing tiles
+    lengthen, included."""
+    E, N = 4, 24
+    R, K, regime, eids, x = _ggemm_case(eid_case)
     rng = np.random.default_rng(5)
-    R, E, K, N = 19, 4, 16, 24
-    eids = _rand_eids(rng, R, E)
-    x = jnp.asarray(rng.standard_normal((R, K)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((E, K, N)), jnp.float32)
     plan = gg.make_group_plan(eids, E, block_m=8)
     cot = jnp.asarray(rng.standard_normal((R, N)), jnp.float32)
@@ -275,12 +330,99 @@ def test_ds_ggemm_backward_kernel_matches_reference():
 
     gx_ref, gw_ref = jax.grad(lambda a, b: loss(a, b, None),
                               argnums=(0, 1))(x, w)
-    gx_k, gw_k = jax.grad(lambda a, b: loss(a, b, True),
-                          argnums=(0, 1))(x, w)
+    (gx_k, gw_k), calls = _accounted(
+        jax.grad(lambda a, b: loss(a, b, True), argnums=(0, 1)), x, w)
     np.testing.assert_allclose(np.asarray(gx_k), np.asarray(gx_ref),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(gw_k), np.asarray(gw_ref),
                                rtol=2e-5, atol=2e-5)
+    # dx contracts over N, so its panel is [N, K] whatever K is
+    assert {k: c["regime"] for k, c in calls.items()} == {
+        "ds_ggemm_fwd": regime, "ds_ggemm_dx": "resident",
+        "ds_ggemm_dw": regime}
+
+
+def _weight_fetches(tiles, K, N, E, bm, blocks, transpose_rhs=False):
+    """Walks the kernel's grid (j, i, k) through its own weight index map:
+    the number of times the block index changes = the weight blocks one
+    call copies (a block whose index did not change is not copied again),
+    beside the tiling the library chose."""
+    gids, used = tiles
+    rows = len(gids) * bm
+    tiling = gg._choose_blocks(
+        "ds_ggemm_dx" if transpose_rhs else "ds_ggemm_fwd", rows, K, N, E,
+        bm, (2, 2, 2), blocks)
+    index = gg._weight_block(transpose_rhs)
+    fetched, last = 0, None
+    for j in range(-(-N // tiling.bn)):
+        for i in range(len(gids)):
+            for k in range(-(-K // tiling.bk)):
+                here = index(j, i, k, gids, used)
+                fetched += here != last
+                last = here
+    return fetched, tiling
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True],
+                         ids=["fwd", "dx"])
+def test_weight_panel_is_fetched_once_per_expert(transpose_rhs):
+    """The count behind the tentpole, on the OLMoE cell's plan (32,768
+    routed rows over 64 experts, 320 M-tiles): with the library's own
+    blocks the weight block's index changes at most E * N/bn times a call
+    — once per expert and N block — where the K-innermost tiling it
+    replaces changes it on every one of m_tiles * N/bn * K/bk steps."""
+    E, bm, K, N = 64, 128, 2048, 1024
+    eids = _rand_eids(np.random.default_rng(0), 4096 * 8, E)
+    plan = gg.make_group_plan(eids, E, block_m=bm)
+    tiles = (np.asarray(plan.block_group_ids), np.asarray(plan.used_blocks))
+    m = plan.num_blocks
+    assert m == 320 and int(tiles[1][0]) < m        # some tiles trail
+    fetched, tiling = _weight_fetches(tiles, K, N, E, bm, None,
+                                      transpose_rhs)
+    n_n = N // tiling.bn
+    assert tiling.regime == "resident" and tiling.bk == K
+    assert fetched <= E * n_n
+    assert tiling.weight_bytes == E * K * N * 2     # the account's bound
+    streamed, old = _weight_fetches(tiles, K, N, E, bm, (512, 1024),
+                                    transpose_rhs)
+    assert old.regime == "streamed"
+    assert streamed == m * (N // old.bn) * (K // old.bk)
+    assert old.weight_bytes == m * K * N * 2
+
+
+@pytest.mark.parametrize("shape,regimes", [
+    # rows, K, N, E -> fwd / dx / dw on a v5e's budget
+    ((40960, 2048, 1024, 64), ("resident", "resident", "resident")),
+    ((40960, 1024, 2048, 64), ("resident", "resident", "resident")),
+    # mixtral-8x7b's down projection: the [14336, bn] float32 accumulator
+    # of dw is too wide to save bytes, and dw keeps the K-innermost tiling
+    ((9216, 14336, 4096, 8), ("resident", "resident", "streamed")),
+    # no panel at all: a contraction of 131,072
+    ((9216, 131072, 4096, 8), ("streamed", "resident", "streamed")),
+], ids=["olmoe_gate", "olmoe_down", "mixtral_down", "no_panel_fits"])
+def test_blocks_are_chosen_by_shape(monkeypatch, shape, regimes):
+    """One rule, two regimes, from shapes, dtype and the device kind: a
+    panel over all of K beside the widest N block that fits the device's
+    VMEM budget, else the K-innermost blocks; blocks given (the sweep's,
+    DS_GGEMM_BLOCKS') are taken as given."""
+    monkeypatch.setattr(gg, "_device_kind", lambda: "tpu v5 lite")
+    rows, K, N, E = shape
+    sizes = (2, 2, 2)
+    fwd = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes)
+    dx = gg._choose_blocks("ds_ggemm_dx", rows, N, K, E, 128, sizes)
+    dw = gg._choose_blocks("ds_ggemm_dw", rows, K, N, E, 128, sizes)
+    assert (fwd.regime, dx.regime, dw.regime) == regimes
+    for t, (k, n) in ((fwd, (K, N)), (dx, (N, K)), (dw, (K, N))):
+        assert n % t.bn == 0 and k % t.bk == 0
+        assert t.vmem_bytes <= gg._vmem_budget()
+        assert (t.bk == k) == (t.regime == "resident")
+    given = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes,
+                              (512, 1024))
+    assert (given.bk, given.bn, given.regime) == (512, 1024, "streamed")
+    # a device the table does not know gets what fits unasked
+    monkeypatch.setattr(gg, "_device_kind", lambda: "cpu")
+    small = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes)
+    assert small.vmem_bytes <= gg._VMEM_UNASKED
 
 
 def test_slot_kernel_parity_and_weight_stream_bound():
@@ -686,7 +828,8 @@ def test_mixtral_prefix_cache_grouped_parity(mixtral_served):
 # ------------------------------------------------------------- tooling
 def test_ggemm_sweep_smoke():
     """scripts/ggemm_sweep.py runs the interpret-mode smoke and emits
-    well-formed JSON rows for the float, int8, and slot kernels."""
+    well-formed JSON rows for the float (fwd, dx, dw), int8, and slot
+    kernels."""
     import json as _json
     env = dict(os.environ, GGEMM_SWEEP_SMOKE="1", JAX_PLATFORMS="cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -696,5 +839,11 @@ def test_ggemm_sweep_smoke():
     assert out.returncode == 0, out.stderr[-2000:]
     rows = [_json.loads(l) for l in out.stdout.splitlines() if l.strip()]
     kinds = {r.get("kind") for r in rows}
-    assert {"f", "int8", "int8_slots"} <= kinds, rows
+    assert {"f", "dx", "dw", "int8", "int8_slots"} <= kinds, rows
     assert not any("error" in r for r in rows), rows
+    # the three training kernels say what their tiling moves
+    for r in rows:
+        if r.get("kind") in ("f", "dx", "dw") and "winner" not in r:
+            assert r["regime"] in ("resident", "streamed"), r
+            assert r["bytes_per_call"] > 0 and r["GBs"] > 0, r
+            assert "pct_of_bf16_peak" in r and len(r["blocks"]) == 3, r

@@ -250,6 +250,18 @@ def test_scopes_kernel_names_and_row_counts_of_a_toy_step():
     bm = 128
     assert rows["routed_rows_per_call"] == B * S * 2
     assert rows["padded_rows_per_call"] == -(-B * S * 2 // bm) * bm + 4 * bm
+    # ... and how its grouped kernels are tiled: forward, dx and dw (here
+    # gate / up and down have one shape), an expert's weight panel
+    # fetched once per call
+    D = TOY["d_model"]
+    assert sorted((c["kernel"], c["k"], c["n"]) for c in rows["calls"]) \
+        == [(name, D, D) for name in
+            ("ds_ggemm_dw", "ds_ggemm_dx", "ds_ggemm_fwd")]
+    for call in rows["calls"]:
+        assert call["regime"] == "resident"
+        assert call["weight_bytes_per_call"] in (4 * D * D * 2,
+                                                 4 * D * D * 4)
+        assert call["operand_bytes_per_call"] > 0
     # no host callback anywhere in the step
     assert not any("callback" in s for s in scopes)
 

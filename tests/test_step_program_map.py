@@ -158,10 +158,20 @@ def test_parse_hand_written_text():
 
 # ------------------------------------------- the step's account of itself
 ROWS = dict(grouped_routed_rows=32768, grouped_padded_rows=40960)
+CALL = {"kernel": "ds_ggemm_fwd", "k": 2048, "n": 1024,
+        "blocks": (2048, 1024), "regime": "resident",
+        "weight_bytes_per_call": 64 * 2048 * 1024 * 2,
+        "operand_bytes_per_call": 40960 * (2048 + 1024) * 2}
 ACCOUNT_CASES = {
     # what is counted where -> what grouped_gemm_rows("train/step") says
     "inside": ("train/step", ROWS, {"routed_rows_per_call": 32768,
                                     "padded_rows_per_call": 40960}),
+    # the kernels' own rows (ops/pallas/grouped_gemm.py), one per kernel
+    # and weight shape however often the call is traced
+    "with_kernel_calls": ("train/step", dict(ROWS, grouped_calls={
+        "ds_ggemm_fwd:2048x1024": CALL}), {
+            "routed_rows_per_call": 32768, "padded_rows_per_call": 40960,
+            "calls": [CALL]}),
     "another_program": ("eval/step", ROWS, None),
     "no_grouped_dispatch": ("train/step", dict(other=1), None),
     "outside_any_account": (None, ROWS, None),
@@ -179,6 +189,7 @@ def test_step_account(case):
     else:
         with step_account(program):
             count_in_step(**counters)
+            count_in_step(**counters)       # traced twice, counted once
     assert grouped_gemm_rows("train/step") == want
     with step_account("train/step"):
         pass
