@@ -270,6 +270,37 @@ def scan_blocks(block_fn, x, blocks, rng, batch, num_layers: int,
     return out
 
 
+def scan_layer_kinds(x, stacks: dict, pattern: tuple, block_fns: dict):
+    """The layer loop of a model whose layers are of several kinds in a
+    repeating pattern (three linear-attention layers to one full one,
+    say).  ``stacks`` maps a kind to its layers' parameters stacked
+    ``[periods, layers of that kind in a period, ...]``; ``pattern`` names
+    the kind of each layer of one period, in order; ``block_fns[kind](x,
+    layer) -> (x, aux)`` is that kind's block, with its own remat and
+    ``maybe_stream`` inside; ``aux`` is a pytree of scalars, the same
+    from every kind.  One ``lax.scan`` runs over the periods,
+    the layers of a period in ``pattern``'s order inside its body, each
+    reading the next layer of its kind's stack.  Returns ``(x, aux summed
+    over all layers)``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def period(x, layers):
+        taken = dict.fromkeys(layers, 0)
+        aux = None
+        for kind in pattern:
+            i = taken[kind]
+            taken[kind] = i + 1
+            x, a = block_fns[kind](
+                x, jax.tree_util.tree_map(lambda w: w[i], layers[kind]))
+            aux = a if aux is None else jax.tree_util.tree_map(
+                jnp.add, aux, a)
+        return x, aux
+
+    x, aux = lax.scan(period, x, stacks)
+    return x, jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), aux)
+
+
 def resolve_size(sizes: dict, size: str, family: str) -> dict:
     """Look up a size preset, refusing typos: an unknown ``size`` silently
     falling through to the dataclass defaults once shipped a 50M-param
@@ -305,6 +336,15 @@ class Model:
     #: (params, batch, rng) -> scalar loss; defaults to causal-LM cross-entropy
     #: over ``apply_fn`` logits and ``batch["input_ids"]`` shifted by one.
     loss_fn: Optional[Callable] = None
+    #: optional (params, batch, rng) -> (``loss_fn``'s scalar, {name: int32
+    #: scalar}): the loss beside counts of what the model left out of it (the
+    #: routed rows past an expert layer's static bound).  The fused train
+    #: step returns them summed over its micro-batches
+    #: (``metrics["counts"]``); the engine adds them up under their names
+    #: (``engine.step_counts()``, the registry's ``train/step_counts``) and
+    #: warns of one that is not zero; ``meta["step_counts"]`` = {name: what
+    #: it counts} words the warning.
+    loss_with_counts_fn: Optional[Callable] = None
     #: pytree of jax.sharding.PartitionSpec (or None) matching params — the
     #: tensor-parallel ("model" axis) layout. ZeRO axes are layered on top.
     logical_specs: Any = None

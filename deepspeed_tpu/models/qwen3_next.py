@@ -1,0 +1,428 @@
+"""Qwen3-Next (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct): a decoder
+whose layers are of two kinds — three Gated-DeltaNet (linear attention,
+Yang et al. 2024, arXiv:2412.06464) layers to one gated full-attention
+layer — every layer followed by a routed-expert FFN with a gated shared
+expert.
+
+``N(x; w) = x / rms(x) * (1 + w)`` (zero-centred weight).  Layer ``i`` is
+a full-attention layer when ``(i + 1) % full_attention_interval == 0``,
+else a linear one.  Every layer: ``x <- x + Mixer(N(x))``, then
+``x <- x + MoE(N(x))``.  No biases; untied head; final ``N``.
+
+- **Gated DeltaNet mixer.**  ``[q, k, v, z] = h W_qkvz``, ``[b, a] = h
+  W_ba``; ``[q, k, v] <- silu(conv(concat(q, k, v)))``, a depthwise causal
+  convolution (width 4, no bias); per value head ``beta = sigmoid(b)``,
+  ``g = -exp(A_log) * softplus(a + dt_bias)``; ``q <- l2norm(q) /
+  sqrt(dk)``, ``k <- l2norm(k)``; the gated delta rule
+  (ops/linear_attention.py) with a float32 state; ``y = RMSNorm(o; w_o,
+  over the head, plain weight) * silu(z)``; output ``y W_out``.  With
+  packed documents the state and the convolution's history are zero at a
+  document's first token.
+- **Gated full attention.**  ``[q, gate] = h W_q`` (per head a query and
+  a gate), ``k``, ``v``; ``q <- N(q; w_q)``, ``k <- N(k; w_k)`` per head;
+  rotate-half rotary on the first ``partial_rotary_factor`` of the head;
+  causal softmax attention inside a document (GQA); output ``(attn *
+  sigmoid(gate)) W_o``.
+- **Experts.**  ``moe/layer.py``: softmax over all ``num_experts``, the
+  ``top_k`` largest renormalised, SwiGLU experts, plus ``sigmoid(h w_sg) *
+  SwiGLU_shared(h)``.  ``experts_held`` (with ``expert_offset``) makes
+  this chip's share of an expert-parallel layer: the router keeps its
+  width, the weights are the held experts', and a token's other choices
+  add nothing.
+
+Both kinds of layer are stacked on their own, ``blocks = {"linear": [P,
+n_linear, ...], "full": [P, 1, ...]}`` over the ``P`` periods, and
+``models/model.py scan_layer_kinds`` runs the loop.  Not built:
+multi-token prediction; serving (a cache that holds recurrent state
+beside keys and values — the entry points raise).
+"""
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.ad_checkpoint
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.models.model import (Model, maybe_stream,
+                                        param_stream_active, qdot,
+                                        resolve_size, scan_layer_kinds,
+                                        token_loss)
+from deepspeed_tpu.models.llama import _rms_norm, rope
+from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
+                                     init_moe_params, moe_layer,
+                                     moe_logical_specs)
+from deepspeed_tpu.ops.attention import causal_attention
+from deepspeed_tpu.ops.linear_attention import (causal_conv,
+                                                gated_delta_rule, l2norm)
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_EMBED,
+    SCOPE_GATE_NORM, SCOPE_HEAD_LOSS, SCOPE_IN_PROJ, SCOPE_LINEAR_ATTN,
+    SCOPE_MLP, SCOPE_OUT_PROJ)
+
+LINEAR, FULL = "linear", "full"
+
+
+@dataclass(frozen=True)
+class Qwen3NextConfig:
+    vocab_size: int = 151936
+    max_seq_len: int = 262144
+    num_layers: int = 48
+    full_attention_interval: int = 4
+    d_model: int = 2048
+    # gated full attention
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    # gated delta rule
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    delta_rule_chunk: int = 64
+    # experts
+    d_ff: int = 512
+    num_experts: int = 512
+    top_k: int = 10
+    norm_topk_prob: bool = True
+    #: the experts this chip holds (None = all): moe/layer.py MoEConfig
+    expert_offset: int = 0
+    experts_held: "int | None" = None
+    shared_expert_d_ff: int = 512
+    aux_loss_coef: float = 0.001
+    load_balance: str = "all_choices"
+    moe_dispatch: str = "grouped"
+    rms_norm_eps: float = 1e-6
+    dtype: str = "bfloat16"
+    remat: bool = False
+    remat_policy: str = "nothing"
+    attention_impl: str = "auto"
+
+    @property
+    def pattern(self) -> tuple:
+        """The kinds of one period's layers, in order."""
+        n = self.full_attention_interval
+        return (LINEAR,) * (n - 1) + (FULL,)
+
+    @property
+    def num_periods(self) -> int:
+        if self.num_layers % self.full_attention_interval:
+            raise ValueError(
+                f"qwen3-next: {self.num_layers} layers are not whole "
+                f"periods of {self.full_attention_interval}")
+        return self.num_layers // self.full_attention_interval
+
+    @property
+    def rotary_ndims(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def moe(self) -> MoEConfig:
+        return MoEConfig(
+            d_model=self.d_model, d_ff=self.d_ff,
+            num_experts=self.num_experts, top_k=self.top_k,
+            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
+            norm_topk_prob=self.norm_topk_prob,
+            load_balance=self.load_balance, activation="silu_glu",
+            dispatch_mode=self.moe_dispatch,
+            expert_offset=self.expert_offset,
+            experts_held=self.experts_held,
+            shared_expert_d_ff=self.shared_expert_d_ff,
+            shared_expert_gate=True)
+
+
+QWEN3_NEXT_SIZES = {
+    "tiny": dict(vocab_size=256, max_seq_len=128, num_layers=4, d_model=32,
+                 num_heads=4, num_kv_heads=2, head_dim=16,
+                 linear_num_key_heads=2, linear_num_value_heads=4,
+                 linear_key_head_dim=8, linear_value_head_dim=8,
+                 d_ff=16, num_experts=8, top_k=2, shared_expert_d_ff=16,
+                 delta_rule_chunk=16),
+    # huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct config.json: the
+    # defaults above.  79.67B parameters whole; one chip trains one period
+    # with 32 of its 512 experts held (benchmarks/configs)
+    "80b-a3b": dict(),
+}
+
+
+def _widths(config: Qwen3NextConfig):
+    Hk, Hv = config.linear_num_key_heads, config.linear_num_value_heads
+    dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
+    return Hk, Hv, dk, dv
+
+
+def init_params(config: Qwen3NextConfig, rng) -> dict:
+    """Seeded.  Assumed where the published config is silent: normal
+    weights of std 0.02 (output projections 0.02 / sqrt(2 * layers)),
+    zero-centred norm weights 0 and the per-head output norm 1, ``A_log =
+    log U(0, 16)`` and ``dt_bias = 1`` (Hugging Face's initialisation of
+    the layer), the convolution's taps normal 0.02."""
+    D, V, L = config.d_model, config.vocab_size, config.num_layers
+    n_p, n_lin = config.num_periods, config.full_attention_interval - 1
+    H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
+    Hk, Hv, dk, dv = _widths(config)
+    K = config.linear_conv_kernel_dim
+    std = 0.02
+    res_std = std / (2 * L) ** 0.5
+    norm = partial(jax.random.normal, dtype=jnp.float32)
+    k = iter(jax.random.split(rng, 24))
+
+    def moe(key, lead):
+        keys = jax.random.split(key, lead[0] * lead[1])
+        stacked = jax.vmap(partial(init_moe_params, config.moe))(keys)
+        return jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), stacked)
+
+    lin, full = (n_p, n_lin), (n_p, 1)
+    conv_ch = 2 * Hk * dk + Hv * dv
+    return {
+        "wte": norm(next(k), (V, D)) * std,
+        "blocks": {
+            LINEAR: {
+                "attn_norm": jnp.zeros(lin + (D,)),
+                "w_qkvz": norm(next(k), lin + (D, conv_ch + Hv * dv)) * std,
+                "w_ba": norm(next(k), lin + (D, 2 * Hv)) * std,
+                "conv_w": norm(next(k), lin + (K, conv_ch)) * std,
+                "A_log": jnp.log(jax.random.uniform(
+                    next(k), lin + (Hv,), minval=1e-3, maxval=16.0)),
+                "dt_bias": jnp.ones(lin + (Hv,)),
+                "o_norm": jnp.ones(lin + (dv,)),
+                "w_out": norm(next(k), lin + (Hv * dv, D)) * res_std,
+                "mlp_norm": jnp.zeros(lin + (D,)),
+                "moe": moe(next(k), lin),
+            },
+            FULL: {
+                "attn_norm": jnp.zeros(full + (D,)),
+                "wq": norm(next(k), full + (D, H * 2 * hd)) * std,
+                "wk": norm(next(k), full + (D, KV * hd)) * std,
+                "wv": norm(next(k), full + (D, KV * hd)) * std,
+                "q_norm": jnp.zeros(full + (hd,)),
+                "k_norm": jnp.zeros(full + (hd,)),
+                "wo": norm(next(k), full + (H * hd, D)) * res_std,
+                "mlp_norm": jnp.zeros(full + (D,)),
+                "moe": moe(next(k), full),
+            },
+        },
+        "final_norm": jnp.zeros((D,)),
+        "lm_head": norm(next(k), (D, V)) * std,
+    }
+
+
+def logical_specs(config: Qwen3NextConfig) -> dict:
+    lead = lambda spec: P(None, None, *spec)
+    moe = jax.tree.map(lead, moe_logical_specs(config.moe),
+                       is_leaf=lambda s: isinstance(s, P))
+    return {
+        "wte": P("model", None),
+        "blocks": {
+            LINEAR: {
+                "attn_norm": P(), "w_qkvz": P(), "w_ba": P(), "conv_w": P(),
+                "A_log": P(), "dt_bias": P(), "o_norm": P(), "w_out": P(),
+                "mlp_norm": P(), "moe": moe,
+            },
+            FULL: {
+                "attn_norm": P(),
+                "wq": P(None, None, None, "model"),
+                "wk": P(None, None, None, "model"),
+                "wv": P(None, None, None, "model"),
+                "q_norm": P(), "k_norm": P(),
+                "wo": P(None, None, "model", None),
+                "mlp_norm": P(), "moe": moe,
+            },
+        },
+        "final_norm": P(),
+        "lm_head": P(None, "model"),
+    }
+
+
+def _norm(x, w, eps):
+    """Zero-centred RMSNorm: the stored weight is the scale less one."""
+    return _rms_norm(x, 1.0 + w.astype(jnp.float32), eps)
+
+
+def _partial_rope(x, config: Qwen3NextConfig):
+    """Rotate-half rotary on the first ``rotary_ndims`` of each head."""
+    rot = config.rotary_ndims
+    xr = rope(x[..., :rot], config.rope_theta)
+    return jnp.concatenate([xr, x[..., rot:]], axis=-1)
+
+
+def _moe_finish(x, layer, config: Qwen3NextConfig, train, rng):
+    with jax.named_scope(SCOPE_MLP):
+        h = _norm(x, layer["mlp_norm"], config.rms_norm_eps)
+        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
+                                    rng=rng, return_stats=True)
+        # beside the router loss, the rows over held_rows_bound
+        return x + out, (aux, stats["dropped"])
+
+
+def _linear_mixer(x, layer, config: Qwen3NextConfig, segment_ids):
+    B, S, D = x.shape
+    Hk, Hv, dk, dv = _widths(config)
+    conv_ch = 2 * Hk * dk + Hv * dv
+    with jax.named_scope(SCOPE_IN_PROJ):
+        h = _norm(x, layer["attn_norm"], config.rms_norm_eps)
+        qkvz = qdot(h, layer["w_qkvz"])
+        ba = qdot(h, layer["w_ba"]).astype(jnp.float32)
+        qkv, z = qkvz[..., :conv_ch], qkvz[..., conv_ch:]
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., Hv:] + layer["dt_bias"].astype(jnp.float32))
+    with jax.named_scope(SCOPE_CONV):
+        qkv = jax.nn.silu(causal_conv(qkv, layer["conv_w"], segment_ids))
+    with jax.named_scope(SCOPE_DELTA_RULE):
+        q = qkv[..., :Hk * dk].reshape(B, S, Hk, dk)
+        k = qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
+        v = qkv[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
+        o = gated_delta_rule(l2norm(q) * dk ** -0.5, l2norm(k), v, g, beta,
+                             segment_ids, chunk=config.delta_rule_chunk)
+    o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
+    with jax.named_scope(SCOPE_GATE_NORM):
+        y = _rms_norm(o, layer["o_norm"], config.rms_norm_eps) \
+            * jax.nn.silu(z.reshape(B, S, Hv, dv))
+    with jax.named_scope(SCOPE_OUT_PROJ):
+        return x + qdot(y.reshape(B, S, Hv * dv), layer["w_out"])
+
+
+@jax.named_scope(SCOPE_BLOCK)
+def _linear_block(x, layer, config: Qwen3NextConfig, train, rng=None,
+                  segment_ids=None):
+    with jax.named_scope(SCOPE_LINEAR_ATTN):
+        x = _linear_mixer(x, layer, config, segment_ids)
+    return _moe_finish(x, layer, config, train, rng)
+
+
+@jax.named_scope(SCOPE_BLOCK)
+def _full_block(x, layer, config: Qwen3NextConfig, train, rng=None,
+                segment_ids=None):
+    B, S, D = x.shape
+    H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
+    eps = config.rms_norm_eps
+    with jax.named_scope(SCOPE_ATTN):
+        h = _norm(x, layer["attn_norm"], eps)
+        qg = qdot(h, layer["wq"]).reshape(B, S, H, 2, hd)
+        q, gate = qg[..., 0, :], qg[..., 1, :]
+        kk = qdot(h, layer["wk"]).reshape(B, S, KV, hd)
+        v = qdot(h, layer["wv"]).reshape(B, S, KV, hd)
+        q = _partial_rope(_norm(q, layer["q_norm"], eps), config)
+        kk = _partial_rope(_norm(kk, layer["k_norm"], eps), config)
+        attn = causal_attention(q, kk, v, impl=config.attention_impl,
+                                segment_ids=segment_ids)
+    attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
+    with jax.named_scope(SCOPE_ATTN):
+        gated = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            attn.dtype)
+        x = x + qdot(gated.reshape(B, S, H * hd), layer["wo"])
+    return _moe_finish(x, layer, config, train, rng)
+
+
+def forward_with_aux(params, batch, config: Qwen3NextConfig,
+                     train: bool = True, rng=None):
+    """-> (logits, router loss summed over layers, routed rows over
+    ``held_rows_bound`` summed over layers: int32, 0 unless the experts
+    held are a subset)."""
+    if param_stream_active():
+        raise NotImplementedError(
+            "qwen3-next: ZeRO-3 and parameter offload gather or stream one "
+            "layer of a single stacked tree at a time; this model's layers "
+            "are two stacks (linear, full) walked period by period, and "
+            "gathering at that grain is not built — use ZeRO stage 0-2")
+    tokens = batch["input_ids"]
+    dtype = jnp.dtype(config.dtype)
+    with jax.named_scope(SCOPE_EMBED):
+        x = params["wte"].astype(dtype)[tokens]
+    seg = batch.get("segment_ids") if isinstance(batch, dict) else None
+
+    def block_fn(block):
+        def fn(x, layer):
+            return block(x, maybe_stream(layer), config, train=train,
+                         rng=rng, segment_ids=seg)
+        if config.remat:
+            from deepspeed_tpu.models.gpt2 import remat_policy
+            fn = jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
+        return fn
+
+    x, aux = scan_layer_kinds(
+        x, params["blocks"], config.pattern,
+        {LINEAR: block_fn(_linear_block), FULL: block_fn(_full_block)})
+    aux, over = aux
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        x = _norm(x, params["final_norm"], config.rms_norm_eps)
+        logits = x @ params["lm_head"].astype(dtype)
+    return logits, aux, over
+
+
+def _wq_halves(grads, config: Qwen3NextConfig):
+    wq = grads["blocks"][FULL]["wq"]
+    wq = wq.reshape(wq.shape[:-1] + (config.num_heads, 2, config.head_dim))
+    return wq[..., 0, :], wq[..., 1, :]
+
+
+def count_params(config: Qwen3NextConfig) -> int:
+    import numpy as np
+    shapes = jax.eval_shape(partial(init_params, config),
+                            jax.random.PRNGKey(0))
+    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
+
+
+def _no_serving(what):
+    def refuse(*_, **__):
+        raise NotImplementedError(
+            f"qwen3-next: {what} is not built — serving a model with "
+            f"linear-attention layers needs a cache that holds each "
+            f"sequence's recurrent state (and convolution history) beside "
+            f"the full layers' keys and values (ROADMAP)")
+    return refuse
+
+
+def qwen3_next_model(size: str = "80b-a3b", **overrides) -> Model:
+    cfg_kwargs = resolve_size(QWEN3_NEXT_SIZES, size, "qwen3_next")
+    cfg_kwargs.update(overrides)
+    config = Qwen3NextConfig(**cfg_kwargs)
+    n_params = count_params(config)
+    moe = config.moe
+    # the routed experts a token's weights pass through HERE: top_k of
+    # num_experts of those held (all of them: top_k)
+    expert = 3 * config.d_model * config.d_ff
+    active = n_params - config.num_layers * expert * (
+        moe.held - config.top_k * moe.held / config.num_experts)
+
+    def loss_with_counts(params, batch, rng=None):
+        logits, aux, over = forward_with_aux(params, batch, config,
+                                             train=True, rng=rng)
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            # inside a document only, where the batch is packed; aux = the
+            # weighted load-balancing loss summed over layers
+            return token_loss(logits, batch) + aux, {ROWS_OVER_BOUND: over}
+
+    return Model(
+        config=config,
+        init_fn=partial(init_params, config),
+        apply_fn=lambda p, b, rng=None: forward_with_aux(
+            p, b, config, train=False, rng=rng)[0],
+        loss_fn=lambda p, b, rng=None: loss_with_counts(p, b, rng)[0],
+        # the rows a step's expert layers left out leave the step beside
+        # its loss (no host callback: one inside the layer loop does not
+        # compile for a TPU on this jaxlib, one outside it keeps the step
+        # out of jax's compile cache); the engine counts and warns
+        loss_with_counts_fn=loss_with_counts if moe.holds_subset else None,
+        logical_specs=logical_specs(config),
+        flops_per_token=6.0 * active,
+        meta={"name": f"qwen3-next-{size}", "n_params": n_params,
+              "active_params": active,
+              "step_counts": {ROWS_OVER_BOUND: (
+                  "routed rows past held_rows_bound, left out of the expert "
+                  "layers: the router sent the experts held here more than "
+                  "twice their even share")} if moe.holds_subset else {},
+              # parts of a leaf worth a row of their own in a gradient
+              # table (scripts/olmoe_grad_check.py)
+              "gradient_views": {
+                  "full.wq[query half]": lambda g: _wq_halves(g, config)[0],
+                  "full.wq[gate half]": lambda g: _wq_halves(g, config)[1]}},
+        init_cache_fn=_no_serving("init_cache"),
+        prefill_fn=_no_serving("prefill"),
+        decode_fn=_no_serving("decode"),
+        verify_fn=_no_serving("verify"),
+    )

@@ -47,7 +47,7 @@ from deepspeed_tpu.moe.sharded_moe import (topkgating, topk_routing,
                                            GateOutput)
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_COMBINE, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_ROUTER,
-    count_in_step)
+    SCOPE_SHARED_EXPERT, count_in_step)
 
 
 @dataclass(frozen=True)
@@ -82,20 +82,54 @@ class MoEConfig:
     #: config's ``serving.moe_dispatch`` key override (see
     #: :func:`resolve_dispatch_mode`).
     dispatch_mode: str = "einsum"
+    #: the experts THIS layer holds: ``experts_held`` from ``expert_offset``
+    #: on (None = all ``num_experts``, and then nothing here changes).  The
+    #: router keeps its ``num_experts`` outputs and ``top_k`` choices; the
+    #: expert weights are ``[experts_held, ...]``, the plan is made over the
+    #: rows routed to them (at most ``held_rows_bound`` of them; the rest
+    #: are counted) and a token's other choices add nothing — expert
+    #: parallelism's share of a layer, without its exchange.  Grouped
+    #: dispatch only.
+    expert_offset: int = 0
+    experts_held: Optional[int] = None
+    #: width of a shared expert every token passes through beside the
+    #: routed ones (0 = none), added to their sum; ``shared_expert_gate``
+    #: scales it by ``sigmoid(x . w)`` per token (Qwen3-Next)
+    shared_expert_d_ff: int = 0
+    shared_expert_gate: bool = False
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def holds_subset(self) -> bool:
+        return self.held != self.num_experts or self.expert_offset != 0
 
 
 def init_moe_params(config: MoEConfig, rng) -> dict:
-    E, D, F = config.num_experts, config.d_model, config.d_ff
+    E, D, F = config.held, config.d_model, config.d_ff
     k = iter(jax.random.split(rng, 5))
     std = 0.02
     norm = partial(jax.random.normal, dtype=jnp.float32)
     params = {
-        "router": norm(next(k), (D, E)) * std,
+        "router": norm(next(k), (D, config.num_experts)) * std,
         "w_in": norm(next(k), (E, D, F)) * std,
         "w_out": norm(next(k), (E, F, D)) * std,
     }
     if config.activation == "silu_glu":
         params["w_gate"] = norm(next(k), (E, D, F)) * std
+    if config.shared_expert_d_ff:
+        # off a branch of its own, as the residual FFN below
+        sk = iter(jax.random.split(jax.random.fold_in(rng, 23), 4))
+        Fs = config.shared_expert_d_ff
+        params["shared_in"] = norm(next(sk), (D, Fs)) * std
+        params["shared_out"] = norm(next(sk), (Fs, D)) * std
+        if config.activation == "silu_glu":
+            params["shared_gate"] = norm(next(sk), (D, Fs)) * std
+        if config.shared_expert_gate:
+            params["shared_router"] = norm(next(sk), (D, 1)) * std
     if config.use_residual:
         # dense residual FFN + the 2-way mixing coefficient head; keys
         # fold off a branch so plain-MoE seeded init stays byte-identical
@@ -117,6 +151,13 @@ def moe_logical_specs(config: MoEConfig) -> dict:
     }
     if config.activation == "silu_glu":
         specs["w_gate"] = P(EXPERT_AXIS, None, "model")
+    if config.shared_expert_d_ff:
+        specs["shared_in"] = P(None, "model")
+        specs["shared_out"] = P("model", None)
+        if config.activation == "silu_glu":
+            specs["shared_gate"] = P(None, "model")
+        if config.shared_expert_gate:
+            specs["shared_router"] = P()
     if config.use_residual:
         specs["res_in"] = P(None, "model")
         specs["res_out"] = P("model", None)
@@ -328,6 +369,8 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     w_in, w_out = params["w_in"], params["w_out"]
 
     R = T * k
+    if config.holds_subset:
+        return _held_grouped_moe(params, xt, config, routing, eids, gates)
     if gg_kernel_real() and not train and R <= gg.SLOT_MAX_ROWS:
         # decode/verify-sized: the slot kernels stream each DISTINCT
         # routed expert's weights exactly once — the top-k-distinct
@@ -358,6 +401,47 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
             combined = gg.combine_rows(y, gates, plan, k)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
     return combined, aux, (jnp.int32(R), jnp.int32(0))
+
+
+def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
+    """The grouped formulation over the experts held here
+    (``MoEConfig.experts_held``): the routing is over all experts, the
+    plan over the rows whose expert is one of ours, inside a static bound
+    (``grouped_gemm.held_rows_bound``); the rows go out by one gather and
+    come back summed into their tokens.  Rows over the bound are the
+    second number of the statistics (``moe_layer(..., return_stats=True)``;
+    the model hands their sum to the engine: :data:`ROWS_OVER_BOUND`)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    T, D = xt.shape
+    k, dt = config.top_k, xt.dtype
+    R = T * k
+    bound = gg.held_rows_bound(R, config.held, config.num_experts)
+    with jax.named_scope(SCOPE_DISPATCH):
+        plan, over = gg.make_held_group_plan(
+            eids, config.expert_offset, config.held, bound)
+        x_pad = gg.dispatch_held_rows(xt, plan, k)          # [Mp, D]
+    # shapes all: ``grouped_routed_rows`` is the EXPECTED number of held
+    # rows under even routing (the true one is data)
+    count_in_step(grouped_routed_rows=R * config.held // config.num_experts,
+                  grouped_padded_rows=plan.padded_rows,
+                  held_rows_bound=bound, experts_held=config.held,
+                  experts_routed=config.num_experts)
+    mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
+    with jax.named_scope(SCOPE_EXPERTS):
+        h = _glu(mm, x_pad, params.get("w_gate"), params["w_in"], config)
+        y = mm(h, params["w_out"])                          # [Mp, D]
+    with jax.named_scope(SCOPE_COMBINE):
+        combined = gg.combine_held_rows(y, gates, plan, k)
+    aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
+    return combined, aux, (jnp.sum(plan.counts) - over, over)
+
+
+#: the name a model gives the rows over ``held_rows_bound``, summed over its
+#: layers, among its step counts (``Model.loss_with_counts_fn``): they leave
+#: the step beside the loss, and the engine adds them up under this name
+#: (``engine.step_counts()``, the registry's ``train/step_counts``) and
+#: warns of a step in which it is not zero
+ROWS_OVER_BOUND = "moe/rows_over_bound"
 
 
 def _route(logits, config: MoEConfig, train: bool, rng):
@@ -393,8 +477,11 @@ def _routing_logits(params, xt, config: MoEConfig):
 
 
 def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
-              train: bool = True, rng=None):
-    """x: [B, S, D] -> (out [B, S, D], aux_loss scalar).
+              train: bool = True, rng=None, return_stats: bool = False):
+    """x: [B, S, D] -> (out [B, S, D], aux_loss scalar), and with
+    ``return_stats`` a third: ``{"dispatched", "dropped"}``, int32 counts
+    of routed rows computed and left out (einsum: past capacity; a held
+    subset: past ``held_rows_bound``).
 
     einsum mode: the reference's MOELayer.forward (sharded_moe.py:477)
     step-for-step, with einsum dispatch in place of explicit
@@ -420,7 +507,15 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
             params, xt, config, train, rng)
         _emit_routing_stats(n_disp, n_drop)
         moe_out = wsc(combined, tok_sh).reshape(B, S, D)
-        return _finish_residual(params, x, moe_out, aux, config)
+        out = _finish_residual(params, x, moe_out, aux, config)
+        return out + ({"dispatched": n_disp, "dropped": n_drop},) \
+            if return_stats else out
+    if config.holds_subset:
+        raise ValueError(
+            f"moe: a held subset of the experts ({config.held} of "
+            f"{config.num_experts}) runs through the grouped dispatch "
+            f"only; this call resolved to {mode!r} (dispatch_mode="
+            f"{config.dispatch_mode!r}, expert mesh axis, DS_MOE_DISPATCH)")
     # qdot: int8 serving keeps the (stacked-2-D) router quantized — the
     # fused-dequant qgemm consumes it; plain arrays take the same matmul
     cf = config.capacity_factor if train else config.eval_capacity_factor
@@ -438,7 +533,8 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
     combine_w = wsc(gate.combine_weights, tok_sh)
     dispatch_m = wsc(gate.dispatch_mask, tok_sh)
     kept = jnp.sum(dispatch_m.astype(jnp.int32))
-    _emit_routing_stats(kept, jnp.int32(T * config.top_k) - kept)
+    n_drop = jnp.int32(T * config.top_k) - kept
+    _emit_routing_stats(kept, n_drop)
     # dispatch: [T,E,C] x [T,D] -> [E,C,D]  (token->expert all-to-all)
     dispatched = jnp.einsum("tec,td->ecd",
                             dispatch_m.astype(x.dtype), xt)
@@ -451,11 +547,16 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
                               combine_w.astype(x.dtype), out), tok_sh)
     aux = gate.l_aux * config.aux_loss_coef + gate.router_z_loss
     moe_out = combined.reshape(B, S, D)
-    return _finish_residual(params, x, moe_out, aux, config)
+    out = _finish_residual(params, x, moe_out, aux, config)
+    return out + ({"dispatched": kept, "dropped": n_drop},) \
+        if return_stats else out
 
 
 def _finish_residual(params, x, moe_out, aux, config: MoEConfig):
     from deepspeed_tpu.models.model import qdot
+    if config.shared_expert_d_ff:
+        with jax.named_scope(SCOPE_SHARED_EXPERT):
+            moe_out = moe_out + _shared_expert(params, x, config)
     if config.use_residual:
         # Residual MoE (reference moe/layer.py:116-123): dense FFN beside
         # the experts, mixed by a learned per-token softmax coefficient
@@ -472,6 +573,23 @@ def _finish_residual(params, x, moe_out, aux, config: MoEConfig):
         coef = coef.astype(dt)
         moe_out = moe_out * coef[..., 0:1] + res * coef[..., 1:]
     return moe_out, aux
+
+
+def _shared_expert(params, x, config: MoEConfig):
+    """The expert every token passes through, whole on every chip; with
+    ``shared_expert_gate`` scaled per token by ``sigmoid(x . w)``."""
+    from deepspeed_tpu.models.model import qdot
+    if config.activation == "silu_glu":
+        h = jax.nn.silu(qdot(x, params["shared_gate"])) \
+            * qdot(x, params["shared_in"])
+    else:
+        h = jax.nn.gelu(qdot(x, params["shared_in"]), approximate=True)
+    out = qdot(h, params["shared_out"])
+    if config.shared_expert_gate:
+        gate = jax.nn.sigmoid(
+            qdot(x, params["shared_router"]).astype(jnp.float32))
+        out = out * gate.astype(out.dtype)
+    return out
 
 
 @dataclass

@@ -70,7 +70,7 @@ def flash_causal_attention(q, k, v, segment_ids=None, fallback=True):
             # except block here could wrap it, so a late opaque error is
             # the only alternative.  DS_FLASH_VMEM_MB is the escape hatch
             # for shapes the conservative margin mis-rejects.
-            budget = int(os.environ.get("DS_FLASH_VMEM_MB", "12"))
+            budget = _flash_vmem_budget_mib()
             raise ValueError(
                 f"impl='flash': q shape {tuple(q.shape)} ({q.dtype}) "
                 f"exceeds the flash kernel's VMEM budget "
@@ -87,7 +87,7 @@ def flash_causal_attention(q, k, v, segment_ids=None, fallback=True):
                 if not fallback:
                     if isinstance(e, ValueError):
                         raise   # genuine shape error, already actionable
-                    budget = int(os.environ.get("DS_FLASH_VMEM_MB", "12"))
+                    budget = _flash_vmem_budget_mib()
                     raise ValueError(
                         f"impl='flash': q shape {tuple(q.shape)} "
                         f"({q.dtype}) failed in the flash kernel despite "
@@ -108,6 +108,13 @@ def flash_causal_attention(q, k, v, segment_ids=None, fallback=True):
             raise
         # stock wrapper rejects the shape too: terminal exact einsum
         return xla_causal_attention(q, k, v)
+
+
+def _flash_vmem_budget_mib() -> int:
+    """The budget the VMEM check held a shape to (by device kind, or
+    DS_FLASH_VMEM_MB), for the messages below."""
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import _vmem_budget
+    return _vmem_budget() >> 20
 
 
 def _ds_vmem_ok(q, packed=False) -> bool:

@@ -1,0 +1,209 @@
+"""Linear attention by the gated delta rule (Yang, Kautz & Hatamizadeh
+2024, "Gated Delta Networks", arXiv:2412.06464) and the short causal
+convolution that feeds it — what a Gated-DeltaNet layer
+(models/qwen3_next.py) computes between its projections.
+
+Per value head, with a float32 state ``S`` [dk, dv], ``S_0 = 0``:
+
+    S   <- exp(g_t) * S_{t-1}                        decay   (g_t <= 0)
+    S_t  = S + k_t (x) beta_t (v_t - S^T k_t)        delta-rule write
+    o_t  = S_t^T q_t
+
+:func:`gated_delta_rule` computes it in the chunked form of the paper's
+section 3.  Inside a chunk of ``C`` tokens everything that does not need
+the incoming state is a batched matrix product over all chunks at once:
+with ``G_i = sum_{j<=i} g_j`` and ``L[i, j] = beta_i (k_i . k_j)
+exp(G_i - G_j)`` for ``i > j`` (the WY / UT transform),
+
+    W = (I + L)^-1 (beta k exp(G)),   U = (I + L)^-1 (beta v)
+
+with ``(I + L)^-1`` from one unit-lower-triangular solve.  Across chunks a ``lax.scan`` carries
+the state: ``v_new = U - W S``, ``o = (q exp(G)) S + (q k^T . decay) v_new``
+and ``S' = exp(G_C) S + (k exp(G_C - G))^T v_new``.  The scan's body is
+checkpointed, so the backward pass (autodiff through the scan) keeps one
+state per chunk and recomputes the rest.
+
+**Packed documents.**  With ``segment_ids`` a token sees only its own
+document: at a document's first token the state is zero, exactly as if
+``g`` were minus infinity there.  The chunked form takes that as a mask,
+not as a number: a decay factor between two positions is zero where
+their documents differ (documents are contiguous, so the positions
+between them then hold a boundary), and the factor from the incoming
+state to a position is zero where that position's document is not the one
+the last chunk ended in.  Boundaries may fall anywhere — inside a chunk,
+at its edge, around a one-token document.  Where ``chunk`` does not divide
+the sequence, the tail is padded with tokens that write nothing.
+
+:func:`causal_conv` is the depthwise causal convolution (width 4 in
+Qwen3-Next, no bias) with the same reset: a tap that would reach into the
+previous document reads zero.
+
+Both are plain XLA (no Pallas kernel yet: ROADMAP).  Their parts of a
+step carry the ``jax.named_scope``s ``conv`` and ``delta_rule``
+(telemetry/tracing.py ``STEP_SCOPES``), written by the model, and each
+call of the delta rule leaves its chunk count and chunk length in the
+step's account (``tracing.delta_rule_chunks``).
+"""
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deepspeed_tpu.telemetry.tracing import count_in_step
+
+DEFAULT_CHUNK = 64
+_HIGHEST = lax.Precision.HIGHEST      # the oracle's products
+
+
+def l2norm(x, eps: float = 1e-6):
+    """x / sqrt(sum(x^2) + eps) over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def causal_conv(x, w, segment_ids=None):
+    """Depthwise causal convolution.  ``x`` [B, S, C], ``w`` [K, C] (tap
+    ``K-1`` multiplies the current token, tap 0 the one ``K-1`` back):
+    ``y_t = sum_j w[j] * x_{t-(K-1)+j}``, a tap before the sequence's
+    start or, with ``segment_ids`` [B, S], in another document reading 0."""
+    K = w.shape[0]
+    S = x.shape[1]
+    w = w.astype(x.dtype)
+    y = x * w[K - 1]
+    for back in range(1, K):
+        past = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :S]
+        if segment_ids is not None:
+            before = jnp.pad(segment_ids, ((0, 0), (back, 0)),
+                             constant_values=-1)[:, :S]
+            past = jnp.where((before == segment_ids)[..., None], past, 0)
+        y = y + past * w[K - 1 - back]
+    return y
+
+
+def _chunked(x, n, C, Hk):
+    """[B, S, Hk * rep, ...] -> [n, B, Hk, rep, C, ...]"""
+    B, _, H = x.shape[:3]
+    x = x.reshape((B, n, C, Hk, H // Hk) + x.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(x, 2, 4), 1, 0)
+
+
+def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
+                     chunk: int = DEFAULT_CHUNK):
+    """The recurrence of the module docstring for every head at once.
+
+    ``q``, ``k`` [B, S, Hk, dk] (already normalised and scaled as the
+    layer wants them), ``v`` [B, S, Hv, dv] with ``Hv`` a multiple of
+    ``Hk`` (key head ``h`` serves value heads ``h*Hv/Hk ..``), ``g`` (log
+    decay, <= 0) and ``beta`` (write strength) [B, S, Hv], ``segment_ids``
+    [B, S] int or None.  Returns ``o`` [B, S, Hv, dv] in ``v``'s dtype.
+    Matrix products take their operands in ``v``'s dtype (the model's:
+    bfloat16 in a bf16 step, float32 in a float32 one) and accumulate in
+    float32; the decays, the triangular solve and the carried state are
+    float32.  Differentiable in all five."""
+    B, S, Hk, dk = q.shape
+    Hv, dv = v.shape[2], v.shape[3]
+    dt = v.dtype
+    C = min(int(chunk), S)
+    n = -(-S // C)
+    pad = n * C - S
+    f32 = lambda a: a.astype(jnp.float32)
+    rep = Hv // Hk
+    q, k = q.astype(dt), k.astype(dt)
+    g, beta = f32(g), f32(beta)
+    seg = (jnp.zeros((B, S), jnp.int32) if segment_ids is None
+           else segment_ids.astype(jnp.int32))
+    if pad:
+        # tokens that write nothing (beta 0), decay nothing (g 0) and
+        # belong to the last document
+        tail = lambda a: jnp.pad(a, ((0, 0), (0, pad))
+                                 + ((0, 0),) * (a.ndim - 2))
+        q, k, v, g, beta = (tail(t) for t in (q, k, v, g, beta))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
+    count_in_step(delta_rule_calls={f"{B}x{n * C}x{Hv}x{dk}x{dv}": {
+        "chunks": n, "chunk_len": C, "batch": B, "heads": Hv,
+        "dk": dk, "dv": dv}})
+    dot = lambda spec, a, b: jnp.einsum(
+        spec, a, b, preferred_element_type=jnp.float32)
+
+    # g = key head, r = the value heads it serves, i/j/c = positions
+    qc, kc = (_chunked(t, n, C, Hk)[:, :, :, 0] for t in (q, k))  # nbgcd
+    vc = _chunked(v, n, C, Hk)                               # [n,B,g,r,C,dv]
+    gc, bc = (_chunked(t, n, C, Hk) for t in (g, beta))      # [n,B,g,r,C]
+    sc = seg.reshape(B, n, C).transpose(1, 0, 2)             # [n, B, C]
+    # the document the previous chunk ended in (chunk 0: no state yet)
+    prev = jnp.concatenate([sc[:1, :, 0], sc[:-1, :, -1]], axis=0)  # [n, B]
+    G = jnp.cumsum(gc, axis=-1)
+    heads = lambda m: m[:, :, None, None]                    # over g and r
+    same = heads(sc[..., :, None] == sc[..., None, :])       # [n,B,1,1,C,C]
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    # decay from position j to position i >= j of one document, else 0;
+    # the difference is taken only where it is <= 0
+    decay = jnp.exp(jnp.where(same & lower,
+                              G[..., :, None] - G[..., None, :], -jnp.inf))
+    from_state = jnp.where(heads(sc == prev[..., None]), jnp.exp(G), 0.0)
+    to_end = jnp.exp(jnp.where(heads(sc == sc[..., -1:]),
+                               G[..., -1:] - G, -jnp.inf))
+    keep_state = from_state[..., -1]                         # [n,B,g,r]
+
+    kk = dot("nbgid,nbgjd->nbgij", kc, kc)[:, :, :, None]    # per key head
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    unit_lower = jnp.where(strict, bc[..., None] * kk * decay, 0.0) \
+        + jnp.eye(C)
+    # T = (I + L)^-1 once, in float32; W and U are products with it
+    T = lax.linalg.triangular_solve(
+        unit_lower, jnp.broadcast_to(jnp.eye(C), unit_lower.shape),
+        left_side=True, lower=True, unit_diagonal=True).astype(dt)
+    W = dot("nbgrij,nbgrjd->nbgrid", T,
+            ((bc * from_state)[..., None]
+             * f32(kc)[:, :, :, None]).astype(dt)).astype(dt)
+    U = dot("nbgrij,nbgrjd->nbgrid", T,
+            (bc[..., None] * f32(vc)).astype(dt)).astype(dt)
+    attn = (dot("nbgid,nbgjd->nbgij", qc, kc)[:, :, :, None]
+            * decay).astype(dt)
+
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        W_c, U_c, attn_c, q_c, k_c, from_c, to_c, keep_c = xs
+        held = state.astype(dt)                              # [B,g,r,dk,dv]
+        v_new = f32(U_c) - dot("bgrck,bgrkv->bgrcv", W_c, held)
+        o = from_c[..., None] * dot("bgck,bgrkv->bgrcv", q_c, held) \
+            + dot("bgrij,bgrjv->bgriv", attn_c, v_new.astype(dt))
+        state = state * keep_c[..., None, None] + dot(
+            "bgck,bgrcv->bgrkv", k_c, (to_c[..., None] * v_new).astype(dt))
+        return state, o.astype(dt)
+
+    state0 = jnp.zeros((B, Hk, rep, dk, dv), jnp.float32)
+    _, o = lax.scan(one_chunk, state0,
+                    (W, U, attn, qc, kc, from_state, to_end, keep_state))
+    o = jnp.moveaxis(o, 0, 1)                                # [B,n,g,r,C,dv]
+    return jnp.moveaxis(o, 4, 2).reshape(B, n * C, Hv, dv)[:, :S]
+
+
+def gated_delta_rule_recurrent(q, k, v, g, beta, segment_ids=None):
+    """The same by the literal per-token recurrence (a ``lax.scan`` over
+    tokens): the oracle the chunked form is tested against, and what a
+    decode step would run.  Same arguments and result."""
+    B, S, Hk, dk = q.shape
+    Hv, dv = v.shape[2], v.shape[3]
+    rep = Hv // Hk
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k = (jnp.repeat(f32(t), rep, axis=2) for t in (q, k))
+    seg = (jnp.zeros((B, S), jnp.int32) if segment_ids is None
+           else segment_ids.astype(jnp.int32))
+    first = jnp.concatenate(
+        [jnp.ones((B, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1)
+
+    def token(state, xs):
+        q_t, k_t, v_t, g_t, b_t, first_t = xs
+        keep = jnp.where(first_t[:, None], 0.0, jnp.exp(g_t))    # [B, Hv]
+        state = state * keep[..., None, None]
+        read = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=_HIGHEST)
+        state = state + k_t[..., :, None] \
+            * (b_t[..., None] * (v_t - read))[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t,
+                                 precision=_HIGHEST)
+
+    by_token = lambda a: jnp.moveaxis(f32(a), 1, 0)
+    _, o = lax.scan(token, jnp.zeros((B, Hv, dk, dv), jnp.float32),
+                    (by_token(q), by_token(k), by_token(v), by_token(g),
+                     by_token(beta), jnp.moveaxis(first, 1, 0)))
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.ops.pallas import vmem
+
 NEG_INF = -1e30
 
 
@@ -232,9 +234,18 @@ def _choose_blocks(seq_len, block_q, block_k):
     return bq, bk
 
 
-def vmem_fits(seq_len, head_dim, itemsize, block_q=512, block_k=512,
-              budget_bytes=None, packed=False):
-    """Whether one (batch, head) grid step's VMEM working set fits on-core.
+def _vmem_budget() -> int:
+    """The device kind's (ops/pallas/vmem.py; S 8192 at head width 256,
+    packed, stages 27 MB); DS_FLASH_VMEM_MB overrides it."""
+    import os
+    if os.environ.get("DS_FLASH_VMEM_MB"):
+        return int(os.environ["DS_FLASH_VMEM_MB"]) << 20
+    return vmem.budget()
+
+
+def working_set_bytes(seq_len, head_dim, itemsize, block_q=512,
+                      block_k=512, packed=False) -> int:
+    """One (batch, head) grid step's VMEM working set.
 
     The kernels stage the full-sequence K/V (forward/dq) or Q/dO (dk/dv
     pass) per grid step via whole-S BlockSpecs, so the dominant term is
@@ -242,30 +253,48 @@ def vmem_fits(seq_len, head_dim, itemsize, block_q=512, block_k=512,
     Pallas double-buffers the pipelined blocks, hence the factor 2 on
     top, plus the [1, S] fp32 lse/delta rows (sublane-padded x8) and the
     block tiles.  ``packed`` adds the dq pass's whole-S segment column,
-    whose single-lane layout pads x128.  The dispatch layer calls this
-    before selecting the kernel — ``jax.eval_shape`` probes only shapes
-    and would pass a 16k-fp32 sequence that Mosaic then rejects at
-    compile time (advisor round 3).  Budget defaults to 12 MiB of the
-    ~16 MiB/core VMEM; override with DS_FLASH_VMEM_MB."""
-    import os
-    if budget_bytes is None:
-        budget_bytes = int(os.environ.get("DS_FLASH_VMEM_MB", "12")) << 20
-    try:
-        bq, bk = _choose_blocks(seq_len, block_q, block_k)
-    except ValueError:
-        return False
+    whose single-lane layout pads x128."""
+    bq, bk = _choose_blocks(seq_len, block_q, block_k)
     hd_pad = -(-head_dim // 128) * 128
     full_kv = 2 * seq_len * hd_pad * itemsize        # K+V (or Q+dO) whole-S
     rows = 2 * 8 * seq_len * 4                       # lse+delta [1,S] fp32
     if packed:
         rows += seq_len * 128 * 4                    # dq segk [S,1] column
         # whole-S [1, S] int32 segment rows staged by the fwd/dkv/dq
-        # passes (x8 sublane pad) — small next to the column term, but
-        # keeps the heuristic conservative if the budget is ever raised
-        # above the ~4 MiB slack it currently rides on
+        # passes (x8 sublane pad) — small next to the column term
         rows += 8 * seq_len * 4
     tiles = (bq + bk) * hd_pad * (itemsize + 2 * 4)  # in tiles + fp32 acc
-    return 2 * (full_kv + rows) + tiles <= budget_bytes
+    return 2 * (full_kv + rows) + tiles
+
+
+def vmem_fits(seq_len, head_dim, itemsize, block_q=512, block_k=512,
+              budget_bytes=None, packed=False):
+    """Whether :func:`working_set_bytes` fits on-core.  The dispatch layer
+    calls this before selecting the kernel — ``jax.eval_shape`` probes
+    only shapes and would pass a 16k-fp32 sequence that Mosaic then
+    rejects at compile time (advisor round 3).  The budget is the device
+    kind's (ops/pallas/vmem.py; 12 MiB where the kind is not listed);
+    DS_FLASH_VMEM_MB overrides it."""
+    if budget_bytes is None:
+        budget_bytes = _vmem_budget()
+    try:
+        return working_set_bytes(seq_len, head_dim, itemsize, block_q,
+                                 block_k, packed) <= budget_bytes
+    except ValueError:
+        return False
+
+
+def _compiler_kw(q, block_q, block_k, packed):
+    """``compiler_params`` for the three calls: a raised VMEM limit where
+    the working set passes what a call is granted unasked, else nothing
+    (and then the call is the one it always was)."""
+    need = working_set_bytes(q.shape[1], q.shape[3], q.dtype.itemsize,
+                             block_q, block_k, packed)
+    limit = vmem.limit_for(need)
+    if limit is None:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
@@ -350,6 +379,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
                      pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0))]
     oT, lse = pl.pallas_call(
         kernel, grid=(B, H, S // bq), name="ds_flash_fwd", **_ikw,
+        **_compiler_kw(q, block_q, block_k, has_seg),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
@@ -388,6 +418,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     doT = _to_bhsd(do)
     has_seg = segment_ids is not None
+    _ckw = _compiler_kw(q, block_q, block_k, has_seg)
     # per-q stats travel as [B, H, 1, S] ROWS (sublane-padded x8, vs the
     # x128 lane padding a [..., S, 1] column layout would cost in both
     # VMEM and HBM); the backward kernels consume them transposed
@@ -435,6 +466,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
                      pl.BlockSpec((1, S, 1), lambda b, h, i: (b, 0, 0))]
     dkT, dvT = pl.pallas_call(
         dkv_kernel, grid=(B, S // bk, H), name="ds_flash_bwd_dkv", **_ikw,
+        **_ckw,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd),
@@ -450,6 +482,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         seq_len=S, has_seg=has_seg)
     dqT = pl.pallas_call(
         dq_kernel, grid=(B, H, S // bq), name="ds_flash_bwd_dq", **_ikw,
+        **_ckw,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(

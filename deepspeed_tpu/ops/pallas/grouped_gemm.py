@@ -89,22 +89,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas import vmem
+
 #: the M-tile the plan pads to (the MXU's row dimension), and the
 #: (bk, bn) of the K-innermost tiling: the int8 and slot kernels', and
 #: what a float call falls back to when no whole-K weight panel fits VMEM
 #: (:func:`_choose_blocks`)
 DEFAULT_BLOCK_M = 128
 _BLOCKS_KN = (512, 1024)
-
-#: VMEM the buffers a grouped call names may fill, by substring of the
-#: device kind (a v5e reports "TPU v5 lite" and has 128 MiB).  Kinds not
-#: listed get ``_VMEM_UNASKED``: what fits the 16 MiB Mosaic grants a call
-#: that asks for nothing, a quarter left for the compiler's own scratch.
-#: A call whose buffers pass that raises its limit to the device's budget
-#: plus ``_VMEM_HEADROOM``.
-_VMEM_BUDGET = (("v5 lite", 64 << 20),)
-_VMEM_UNASKED = 12 << 20
-_VMEM_HEADROOM = 32 << 20
 
 
 def _round_up(n: int, m: int) -> int:
@@ -186,17 +178,22 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
                                 is_stable=True)
     row_to_padded = padded_of[:R]
     cum_blocks = jnp.cumsum(blocks_e)                  # [E]
-    bidx = jnp.arange(num_blocks, dtype=jnp.int32)
-    # tile b belongs to the first expert whose cumulative tile count
-    # exceeds b; the tiles past the last group (``used_blocks`` on) clamp
-    # to E-1, which keeps the ids monotone for tgmm: all-zero rows, which
-    # the kernels neither fetch nor multiply — they write zeros
-    gids = jnp.sum((bidx[:, None] >= cum_blocks[None, :]).astype(jnp.int32),
-                   axis=1)
-    gids = jnp.minimum(gids, E - 1).astype(jnp.int32)
+    gids = _tile_group_ids(jnp.arange(num_blocks, dtype=jnp.int32),
+                           cum_blocks)
     return GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
                      cum_blocks[-1:].astype(jnp.int32), row_to_padded,
                      padded_to_row, counts)
+
+
+def _tile_group_ids(bidx, cum_blocks):
+    """The expert of each M-tile ``bidx``: tile b belongs to the first
+    expert whose cumulative tile count exceeds b; the tiles past the last
+    group (``used_blocks`` on) clamp to E-1, which keeps the ids monotone
+    for tgmm: all-zero rows, which the kernels neither fetch nor multiply
+    — they write zeros."""
+    gids = jnp.sum((bidx[:, None] >= cum_blocks[None, :]).astype(jnp.int32),
+                   axis=1)
+    return jnp.minimum(gids, cum_blocks.shape[0] - 1).astype(jnp.int32)
 
 
 # ----------------------------------------------------------- row movement
@@ -315,6 +312,134 @@ def combine_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
     (zeros on padding rows) and ``dgates[f] = y[row_to_padded[f]] ·
     dout[f // top_k]`` accumulated in float32."""
     return _combine(y, gates, plan.padded_to_row, plan.row_to_padded, top_k)
+
+
+# ------------------------------------------------------ a held subset
+# An expert layer that is told which experts it holds (expert parallelism's
+# share of a layer: models/qwen3_next.py on one chip of sixteen).  The
+# router chooses among all experts; only the rows routed to experts
+# ``[offset, offset + held)`` enter the plan.  How many those are is data,
+# and the plan's length is static, so it is a stated bound
+# (:func:`held_rows_bound`): rows past it are counted, never lost silently.
+def held_rows_bound(routed_rows: int, experts_held: int, num_experts: int,
+                    block_m: Optional[int] = None) -> int:
+    """Twice the expected share of ``routed_rows`` = tokens x top_k rows
+    that land on ``experts_held`` of ``num_experts`` under even routing,
+    rounded up to M-tiles: the receive buffer a deployment sizes the same
+    way."""
+    bm = int(block_m or default_block_m())
+    return _round_up(-(-2 * routed_rows * experts_held // num_experts), bm)
+
+
+def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
+                         experts_held: int, bound_rows: int,
+                         block_m: Optional[int] = None):
+    """``expert_ids`` [R] over ALL experts -> (GroupPlan over the
+    ``experts_held`` experts from ``expert_offset`` on, rows over the
+    bound [] int32).  The padded row count is ``bound_rows + held * bm``
+    (every held expert keeps at least one tile, as in
+    :func:`make_group_plan`); where the held rows need more tiles than
+    that, the groups are cut in expert order so that each expert still has
+    one, and what is cut is the second result.  One stable sort lays the
+    held rows out by expert; ``padded_to_row`` comes from it by
+    arithmetic, reading ``R`` on a padding row.  The plan has no
+    ``row_to_padded``: the way back sums rows into tokens
+    (:func:`combine_held_rows`), R being mostly rows held elsewhere."""
+    R = int(expert_ids.shape[0])
+    E = int(experts_held)
+    bm = int(block_m or default_block_m())
+    padded_rows = _round_up(int(bound_rows), bm) + E * bm
+    num_blocks = padded_rows // bm
+    local = expert_ids.astype(jnp.int32) - jnp.int32(expert_offset)
+    key = jnp.where((local >= 0) & (local < E), local, E)
+    experts = jnp.arange(E, dtype=jnp.int32)
+    counts = jnp.sum((key[:, None] == experts[None, :]).astype(jnp.int32),
+                     axis=0)
+    # tiles in expert order, none taking the one tile each later expert keeps
+    cum_blocks = jnp.minimum(jnp.cumsum(jnp.maximum(-(-counts // bm), 1)),
+                             num_blocks - (E - 1 - experts))
+    blocks_e = jnp.diff(cum_blocks, prepend=0)
+    group_sizes = blocks_e * bm
+    kept = jnp.minimum(counts, group_sizes)
+    over = jnp.sum(counts - kept).astype(jnp.int32)
+    _, by_expert = jax.lax.sort((key, jnp.arange(R, dtype=jnp.int32)),
+                                num_keys=1, is_stable=True)
+    bidx = jnp.arange(num_blocks, dtype=jnp.int32)
+    gids = _tile_group_ids(bidx, cum_blocks)
+    # padded row p of expert g is its ``p - group_start[g]``-th routed row
+    # where it has that many (a tile past the last group reads beyond)
+    first = jnp.cumsum(counts) - counts                # in ``by_expert``
+    group_start = (cum_blocks - blocks_e) * bm
+    within = (bidx * bm - group_start[gids])[:, None] \
+        + jnp.arange(bm, dtype=jnp.int32)[None, :]     # [num_blocks, bm]
+    source = jnp.clip(first[gids][:, None] + within, 0, R - 1)
+    padded_to_row = jnp.where(within < kept[gids][:, None],
+                              by_expert[source], R).reshape(padded_rows)
+    plan = GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
+                     cum_blocks[-1:].astype(jnp.int32), None,
+                     padded_to_row, counts)
+    return plan, over
+
+
+def _sum_into_tokens(rows, token_of_row, tokens):
+    """rows [Mp, D] summed into [tokens, D] by ``token_of_row`` (``tokens``
+    = no token: dropped), accumulated in float32 and rounded once."""
+    out = jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[
+        token_of_row].add(rows.astype(jnp.float32), mode="drop")
+    return out.astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _dispatch_held(xt, token_of_row):
+    return _token_rows(xt, token_of_row)
+
+
+_dispatch_held.defvjp(
+    lambda xt, token_of_row: (_token_rows(xt, token_of_row),
+                              (token_of_row, xt.shape[0])),
+    lambda res, g: (_sum_into_tokens(g, *res), None))
+
+
+def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
+    """As :func:`dispatch_rows` for a held-subset plan: one gather of the
+    plan's rows out of ``xt`` [T, D].  Backward: the rows' cotangents
+    summed into their tokens (a token has 0 to ``top_k`` rows here)."""
+    return _dispatch_held(xt, plan.padded_to_row // top_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_held(y, gate_of_row, token_of_row, tokens):
+    return _sum_into_tokens(gate_of_row.astype(y.dtype)[:, None] * y,
+                            token_of_row, tokens)
+
+
+def _combine_held_fwd(y, gate_of_row, token_of_row, tokens):
+    return (_combine_held(y, gate_of_row, token_of_row, tokens),
+            (y, gate_of_row, token_of_row))
+
+
+def _combine_held_bwd(tokens, res, g):
+    y, gate_of_row, token_of_row = res
+    g_rows = _token_rows(g, token_of_row)
+    dgate = jnp.sum(y.astype(jnp.float32) * g_rows.astype(jnp.float32),
+                    axis=-1).astype(gate_of_row.dtype)
+    return gate_of_row.astype(y.dtype)[:, None] * g_rows, dgate, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def combine_held_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
+                      top_k: int):
+    """As :func:`combine_rows` for a held-subset plan: expert outputs
+    ``y`` [Mp, D], each weighted by its routed element's gate (``gates``
+    flat [T*top_k]; a padding row's is 0), summed into their tokens ->
+    [T, D].  A token none of whose choices is held here gets zeros: what
+    the absent experts would have added is left out."""
+    gate_of_row = jnp.take(gates, plan.padded_to_row, mode="fill",
+                           fill_value=0)
+    return _combine_held(y, gate_of_row, plan.padded_to_row // top_k,
+                         gates.shape[0] // top_k)
 
 
 # ------------------------------------------------------------- reference
@@ -469,16 +594,6 @@ def _fit_block(dim, requested, quantum=128):
     return b
 
 
-def _device_kind() -> str:
-    return str(jax.devices()[0].device_kind).lower()
-
-
-def _vmem_budget() -> int:
-    kind = _device_kind()
-    return next((b for sub, b in _VMEM_BUDGET if sub in kind),
-                _VMEM_UNASKED)
-
-
 class _Tiling(NamedTuple):
     """What :func:`_choose_blocks` settled for one kernel call."""
     bk: int
@@ -528,7 +643,7 @@ def _choose_blocks(kernel, rows, K, N, E, bm, sizes, blocks=None):
     quantum = 128
     widths = ([b for b in range(N, 0, -quantum) if N % b == 0]
               if N % quantum == 0 else [_round_up(N, quantum)])
-    budget = _vmem_budget()
+    budget = vmem.budget()
     for bn in widths:
         resident = tile(_round_up(K, quantum), bn)
         if resident.vmem_bytes <= budget:
@@ -551,10 +666,8 @@ def _count_call(kernel, K, N, tiling: _Tiling):
 def _compiler_params(tiling: _Tiling):
     """A raised VMEM limit where the call's buffers pass what it is
     granted unasked; else nothing."""
-    if tiling.vmem_bytes <= _VMEM_UNASKED:
-        return None
-    return pltpu.CompilerParams(
-        vmem_limit_bytes=_vmem_budget() + _VMEM_HEADROOM)
+    limit = vmem.limit_for(tiling.vmem_bytes)
+    return limit and pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
 # --------------------------------------------------------- pallas drivers

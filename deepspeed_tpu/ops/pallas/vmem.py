@@ -1,0 +1,36 @@
+"""The VMEM a Mosaic call's buffers may take, by device kind: one table and
+one rule for every kernel that sizes its blocks by it (``grouped_gemm``,
+``ds_flash_attention``).
+
+A call that asks for nothing is granted 16 MiB; ``UNASKED`` is what its
+buffers may fill of that, a quarter left for the compiler's own scratch.
+On a device kind listed in ``BUDGET`` (by substring: a v5e reports "TPU v5
+lite" and has 128 MiB) a call may plan for that much instead, and one whose
+buffers pass ``UNASKED`` then asks for the budget plus ``HEADROOM``
+(:func:`limit_for`); a call that always fitted asks for nothing and is the
+call it was.
+"""
+from typing import Optional
+
+import jax
+
+BUDGET = (("v5 lite", 64 << 20),)
+UNASKED = 12 << 20
+HEADROOM = 32 << 20
+
+
+def device_kind() -> str:
+    return str(jax.devices()[0].device_kind).lower()
+
+
+def budget() -> int:
+    kind = device_kind()
+    return next((b for sub, b in BUDGET if sub in kind), UNASKED)
+
+
+def limit_for(need_bytes: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a call whose buffers take ``need_bytes``;
+    None where they fit what a call is granted unasked."""
+    if need_bytes <= UNASKED:
+        return None
+    return max(budget(), need_bytes) + HEADROOM

@@ -18,6 +18,7 @@ API parity:
 - ``save_checkpoint`` / ``load_checkpoint`` with tag dirs + ``latest`` file
   (engine.py:2943/:2620).
 """
+import collections
 import functools
 import os
 import time
@@ -705,6 +706,10 @@ class DeepSpeedEngine:
         self.global_samples = 0
         self._skipped_steps = 0
         self._pending_overflow = []   # unresolved device-side overflow flags
+        # (step, {name: device scalar}) of Model.loss_with_counts_fn, oldest
+        # first, until they are ready; and their sums so far
+        self._pending_counts = collections.deque()
+        self._step_counts = {}
         self.micro_steps = 0
         self.timers = SynchronizedWallClockTimer()
         self.tput_timer = ThroughputTimer(
@@ -1072,6 +1077,34 @@ class DeepSpeedEngine:
                 self.numerics.resolve()
             except Exception as e:
                 logger.debug(f"numerics: resolve failed ({e})")
+
+    def step_counts(self) -> dict:
+        """{name: sum over the steps run} of the counts the model's loss
+        comes with (``Model.loss_with_counts_fn``; ``{}`` without).  Waits
+        for the steps in flight."""
+        self._resolve_step_counts(wait=True)
+        return dict(self._step_counts)
+
+    def _resolve_step_counts(self, wait: bool):
+        """Add up the banked counts — only those whose step has ended
+        unless ``wait`` — and warn of each that is not zero: the model
+        left that much out of the step's loss."""
+        said = self.model.meta.get("step_counts", {})
+        while self._pending_counts:
+            step, counts = self._pending_counts[0]
+            if not wait and not all(c.is_ready() for c in counts.values()):
+                return
+            self._pending_counts.popleft()
+            for name, value in jax.device_get(counts).items():
+                value = int(value)
+                self._step_counts[name] = \
+                    self._step_counts.get(name, 0) + value
+                if value:
+                    self.telemetry_registry.inc("train/step_counts",
+                                                float(value), count=name)
+                    logger.warning(f"train step {step}: {name} = {value}"
+                                   + (f" ({said[name]})"
+                                      if name in said else ""))
 
     def _build_monitor(self):
         try:
@@ -1589,6 +1622,14 @@ class DeepSpeedEngine:
             return step_programs.build_train_step(
                 self._step_ctx, qgz_fn=self._qgz_grad_fn(),
                 plan=self._get_qgz_plan(), nf_group=nf_group)
+        if (name in ("grad", "grad_step", "grad_micro")
+                and self.model.loss_with_counts_fn is not None):
+            from deepspeed_tpu.utils.logging import warning_once
+            warning_once(
+                f"{name}: only the fused train step returns the model's "
+                f"step counts ({sorted(self.model.meta.get('step_counts', ()))}"
+                f"); on this path (micro-step API, offload tiers) they are "
+                f"not counted and nothing warns of them")
         return step_programs.PROGRAMS[name](self._step_ctx)
 
     def _get_compiled(self, name: str):
@@ -2211,6 +2252,12 @@ class DeepSpeedEngine:
                                               self._next_rng())
 
     def _finish_step(self, metrics):
+        if "counts" in metrics:
+            # the model's counts stay on the device until their step has
+            # ended: a later step reads them, or step_counts(); none waits
+            self._pending_counts.append(
+                (self.global_steps + 1, metrics["counts"]))
+            self._resolve_step_counts(wait=False)
         # numerics bank (ISSUE 15): pull the in-graph stats out of the
         # metrics dict and bank them as DEVICE scalars keyed by the
         # step id this step will carry (train-step-N corr) — the same

@@ -129,6 +129,9 @@ def wrap_lora(model, rank: int, alpha: Optional[float] = None,
     def loss_fn(params, batch, rng=None):
         return model.loss_fn(merged(params), batch, rng)
 
+    def loss_with_counts_fn(params, batch, rng=None):
+        return model.loss_with_counts_fn(merged(params), batch, rng)
+
     def fuse(params):
         """Merged base-shaped tree (reference _fuse_lora) — feed to the
         inference engine together with the UNWRAPPED model."""
@@ -159,6 +162,8 @@ def wrap_lora(model, rank: int, alpha: Optional[float] = None,
         init_fn=init_fn,
         numpy_init_fn=None, layer_init_fn=None, nonblock_init_fn=None,
         apply_fn=apply_fn, loss_fn=loss_fn,
+        loss_with_counts_fn=loss_with_counts_fn
+        if model.loss_with_counts_fn is not None else None,
         logical_specs=specs,
         trainable_mask=mask,
         fuse_fn=fuse,

@@ -74,7 +74,10 @@ def compress(ctx, params, step):
     return compress_params_traced(params, step, ctx.compression_plans)
 
 
-def scaled_loss(ctx, params, batch, rng, scale, compress_step=None):
+def scaled_loss(ctx, params, batch, rng, scale, compress_step=None,
+                counted=False):
+    """The model's loss times ``scale``; ``counted``: beside the model's
+    counts (``Model.loss_with_counts_fn``), as ``value_and_grad``'s aux."""
     if ctx.use_streamed and isinstance(params, dict):
         # blocks stay fp32 in pinned host; the models cast each weight at
         # point of use (after the per-layer stream), so the AD transpose
@@ -90,15 +93,22 @@ def scaled_loss(ctx, params, batch, rng, scale, compress_step=None):
         # gradients (w*mask transpose) and the quantizer's STE backward
         # actually runs — reference QAT/pruning semantics
         cparams = compress(ctx, cparams, compress_step)
+    if counted:
+        loss, counts = ctx.model.loss_with_counts_fn(cparams, batch, rng)
+        return loss.astype(jnp.float32) * scale, counts
     loss = ctx.model.loss(cparams, batch, rng)
     return loss.astype(jnp.float32) * scale
 
 
-def micro_grads(ctx, params, batch, rng, scale, n=None, compress_step=None):
+def micro_grads(ctx, params, batch, rng, scale, n=None, compress_step=None,
+                counted=False):
     """(scaled loss, its gradient in the params' dtype) of one micro-batch
-    of the ``n`` a step sums; ``n=None``: ``batch`` is the whole step's."""
+    of the ``n`` a step sums; ``n=None``: ``batch`` is the whole step's.
+    ``counted``: ((scaled loss, the model's counts), gradient)."""
     with jax.named_scope(SCOPE_FWD_BWD):
-        return jax.value_and_grad(functools.partial(scaled_loss, ctx))(
+        return jax.value_and_grad(
+            functools.partial(scaled_loss, ctx, counted=counted),
+            has_aux=counted)(
             params, batch, rng, scale if n is None else scale / n,
             compress_step)
 
@@ -121,19 +131,22 @@ def zero_grads(ctx, params):
 
 def accumulated_grads(ctx, params, batches, rng, scale, compress_step=None):
     """Scan ``micro_grads`` + ``accumulate`` over the leading axis of
-    ``batches``: (summed grads, summed loss)."""
+    ``batches``: (summed grads, summed loss, the model's counts summed —
+    ``{}`` where it has none)."""
     n = jax.tree.leaves(batches)[0].shape[0]
+    counted = ctx.model.loss_with_counts_fn is not None
 
     def micro(carry, mb):
         grads_acc, loss_acc = carry
         loss, grads = micro_grads(ctx, params, mb, rng, scale, n,
-                                  compress_step)
-        return (accumulate(ctx, grads_acc, grads), loss_acc + loss), None
+                                  compress_step, counted)
+        loss, counts = loss if counted else (loss, {})
+        return (accumulate(ctx, grads_acc, grads), loss_acc + loss), counts
 
-    (grads, loss_sum), _ = jax.lax.scan(
+    (grads, loss_sum), counts = jax.lax.scan(
         micro, (as_grads(ctx, zero_grads(ctx, params)), jnp.float32(0.0)),
         batches)
-    return grads, loss_sum
+    return grads, loss_sum, jax.tree.map(lambda c: jnp.sum(c, axis=0), counts)
 
 
 @jax.named_scope(SCOPE_OPTIMIZER)
@@ -216,10 +229,13 @@ def apply_grads(ctx, state, grads, nf_group=None):
     return new_state, metrics
 
 
-def update(ctx, state, grads, loss_sum, scale, nf_group=None):
-    """``apply_grads``, reporting the loss with its scaling undone."""
+def update(ctx, state, grads, loss_sum, scale, nf_group=None, counts=None):
+    """``apply_grads``, reporting the loss with its scaling undone and the
+    model's counts, where it has any."""
     new_state, metrics = apply_grads(ctx, state, grads, nf_group)
     metrics["loss"] = loss_sum / scale
+    if counts:
+        metrics["counts"] = counts
     return new_state, metrics
 
 
@@ -307,10 +323,11 @@ def build_train_step(ctx, qgz_fn=None, plan=None, nf_group=None):
         def step_body(state, stacked_batch, rng):
             """stacked_batch leaves: [gas, global_micro, ...]."""
             scale = loss_scale(ctx, state)
-            grads, loss_sum = accumulated_grads(
+            grads, loss_sum, counts = accumulated_grads(
                 ctx, state["params"], stacked_batch, rng, scale,
                 compression_step(ctx, state))
-            return update(ctx, state, grads, loss_sum, scale, nf_group)
+            return update(ctx, state, grads, loss_sum, scale, nf_group,
+                          counts)
 
     def train_step(state, stacked_batch, rng):
         # this body runs while the step is traced: what the model's
@@ -389,8 +406,8 @@ def _build_pipeline_train_step(ctx, qgz_fn, plan, nf_group):
             chunks = jax.tree.map(
                 lambda x: x.reshape(gas // n_buffers, n_buffers,
                                     *x.shape[1:]), stacked_batch)
-            grads, loss = accumulated_grads(ctx, params, chunks, rng, scale,
-                                            cs)
+            grads, loss, _ = accumulated_grads(ctx, params, chunks, rng,
+                                               scale, cs)
         return update(ctx, state, as_grads(ctx, grads), loss, scale,
                       nf_group)
 
@@ -454,8 +471,8 @@ def build_grad_step(ctx):
     """Optimizer offload: scan the gas micro-batches, stop at gradients."""
     def grad_step(state, stacked_batch, rng):
         scale = loss_scale(ctx, state)
-        grads, loss_sum = accumulated_grads(ctx, state["params"],
-                                            stacked_batch, rng, scale)
+        grads, loss_sum, _ = accumulated_grads(ctx, state["params"],
+                                               stacked_batch, rng, scale)
         return loss_sum / scale, grads
     return grad_step
 

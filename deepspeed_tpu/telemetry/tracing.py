@@ -302,10 +302,21 @@ SCOPE_ROUTER = "router"             # router matmul, softmax, top-k, aux terms
 SCOPE_DISPATCH = "dispatch"         # rows by (token, choice), sort, scatter
 SCOPE_EXPERTS = "experts"           # the grouped GEMMs and the activation
 SCOPE_COMBINE = "combine"           # gather back, gate-weighted sum over k
+SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token passes through
+# inside ``ds.block``, where the mixer is a linear-attention layer
+# (models/qwen3_next.py; ``attn`` stays the full-attention layer's):
+SCOPE_LINEAR_ATTN = "linear_attn"   # LN1 and all of the below
+SCOPE_IN_PROJ = "in_proj"           # ... q, k, v, z, decay and write strength
+SCOPE_CONV = "conv"                 # ... the short causal convolution + silu
+SCOPE_DELTA_RULE = "delta_rule"     # ... l2-norm, the gated delta rule
+SCOPE_GATE_NORM = "gate_norm"       # ... per-head RMSNorm, silu(z) gate
+SCOPE_OUT_PROJ = "out_proj"         # ... output projection + residual
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
-               SCOPE_COMBINE)
+               SCOPE_COMBINE, SCOPE_SHARED_EXPERT, SCOPE_LINEAR_ATTN,
+               SCOPE_IN_PROJ, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
+               SCOPE_OUT_PROJ)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw
@@ -615,10 +626,25 @@ def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
         return None
     rows = {"routed_rows_per_call": account["grouped_routed_rows"],
             "padded_rows_per_call": account["grouped_padded_rows"]}
+    # an expert layer that holds a subset (MoEConfig.experts_held): the
+    # routed rows are then the EXPECTED held ones under even routing, and
+    # the plan's static bound on them stands beside
+    for key in ("held_rows_bound", "experts_held", "experts_routed"):
+        if key in account:
+            rows[key] = account[key]
     if "grouped_calls" in account:
         rows["calls"] = [account["grouped_calls"][key]
                          for key in sorted(account["grouped_calls"])]
     return rows
+
+
+def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):
+    """The gated-delta-rule calls of the step as ops/linear_attention.py
+    traced them: one row per shape — ``chunks`` and ``chunk_len`` of the
+    scan, ``batch``, ``heads``, ``dk``, ``dv``.  None where the step has
+    no such call."""
+    calls = _STEP_COUNTERS.get(name, {}).get("delta_rule_calls")
+    return [calls[key] for key in sorted(calls)] if calls else None
 
 
 def reset_programs():

@@ -107,6 +107,11 @@ ENV_VARS = {
 #: ``serving/`` prefix normalization) -> one-line description
 METRICS = {
     # --- training engine
+    "train/step_counts": "what the model left out of a step's loss, by "
+                         "count (label count= the model's name for it: "
+                         "moe/rows_over_bound = routed rows past "
+                         "held_rows_bound); each step in which one is "
+                         "not zero is warned of",
     "train/steps": "train_batch iterations completed",
     "train/step_latency_s": "per-step wall-clock histogram",
     "train/tokens_per_s": "training token throughput gauge",

@@ -3,12 +3,19 @@ cell's own size: the engine's (``engine.forward``: its kernels, remat and
 precision) against ``jax.grad`` of the cell's plain reference (float32,
 ``highest``) at the engine's own parameters.
 
-    chiprun --chips 1 -- python scripts/olmoe_grad_check.py --seed <n> ...
+    chiprun --chips 1 -- python scripts/olmoe_grad_check.py \
+        --workload <cell> --seed <n> ...
 
-One JSON line per seed: for every gradient leaf ``max |a - b| / max |b|``
-and ``|a - b|_2 / |b|_2``, engine against reference, and the same for the
-reference with bf16 products against itself (the noise a bf16 program
-cannot be under).  A leaf whose gradient never arrived reads 1.0.
+Any training cell of BENCHMARK.json (the file keeps the name of the cell it
+was written for; the default is still that one).  One JSON line per seed:
+for every gradient leaf ``max |a - b| / max |b|`` and ``|a - b|_2 /
+|b|_2``, engine against reference, and the same for the reference with
+bf16 products against itself (the noise a bf16 program cannot be under).
+A leaf whose gradient never arrived reads 1.0.  Where the model names
+parts of a leaf (``meta["gradient_views"]``) each gets a row of its own,
+and where its loss comes with step counts (``Model.loss_with_counts_fn``:
+the routed rows over an expert layer's bound) the line carries them for
+that micro-batch.
 """
 import argparse
 import gc
@@ -29,13 +36,17 @@ from harness import datagen                                   # noqa: E402
 from harness.manifest import Manifest                         # noqa: E402
 
 
-def errors(got, want):
-    """{leaf: max |a - b| / max |b| and |a - b|_2 / |b|_2}."""
+def errors(got, want, views=None):
+    """{leaf: max |a - b| / max |b| and |a - b|_2 / |b|_2}; ``views``
+    ({name: tree -> array}) adds parts of leaves under names of their own."""
+    pairs = [(jax.tree_util.keystr(path), a, b) for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want))]
+    pairs += [(name, view(got), view(want))
+              for name, view in (views or {}).items()]
     out = {}
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
+    for name, a, b in pairs:
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        out[jax.tree_util.keystr(path)] = {
+        out[name] = {
             "max_rel": float(np.abs(a - b).max() / np.abs(b).max()),
             "l2_rel": float(np.linalg.norm(a - b) / np.linalg.norm(b))}
     return out
@@ -50,15 +61,24 @@ def one_seed(workload, config, traffic, seed):
                                 traffic["micro_batch_per_chip"], seed).next()
     micro = {k: np.asarray(v)[0] for k, v in first.items()}
     loss = float(engine.forward(micro))
+    views = model.meta.get("gradient_views")
+    counts = model.loss_with_counts_fn
+    if counts is not None:
+        counts = {k: int(v) for k, v in jax.jit(counts)(
+            engine.state["params"],
+            {k: jnp.asarray(v) for k, v in micro.items()})[1].items()}
     # the engine scales a micro-batch's gradient by 1 / gas
     got = jax.tree.map(lambda g: np.asarray(g.astype(jnp.float32)) * gas,
                        engine._pending_grads)
     params = jax.tree.map(np.asarray, engine.state["params"])
-    # the reference's float32 gradient needs the room the engine's state has
-    for leaf in jax.tree.leaves((engine.state, engine._pending_grads)):
-        leaf.delete()
+    # the reference's float32 gradient needs the room the engine's state
+    # has — all of it: whatever of the engine is still referenced from the
+    # telemetry it registered with goes too (everything kept is numpy now)
     del engine
     gc.collect()
+    for array in jax.live_arrays():
+        array.delete()
+    jax.clear_caches()
 
     reference = importlib.import_module("references." + config["reference"])
     params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
@@ -77,10 +97,13 @@ def one_seed(workload, config, traffic, seed):
     print(json.dumps({
         "workload": workload, "seed": seed,
         "device": jax.devices()[0].device_kind,
+        "micro_batch": list(micro["input_ids"].shape),
         "loss": {"engine": loss, "reference": want_loss,
                  "reference_bf16": low_loss},
-        "engine_vs_reference": errors(got, want),
-        "reference_bf16_vs_reference": errors(low, want)}), flush=True)
+        "step_counts": counts,
+        "engine_vs_reference": errors(got, want, views),
+        "reference_bf16_vs_reference": errors(low, want, views)}),
+        flush=True)
 
 
 if __name__ == "__main__":
@@ -89,6 +112,11 @@ if __name__ == "__main__":
     parser.add_argument("--seed", type=int, nargs="+", required=True)
     parser.add_argument("--rehearse", action="store_true",
                         help="the cell's toy sizes, for a run on the CPU")
+    parser.add_argument("--micro-batch", type=int,
+                        help="sequences per micro-batch, where the cell's "
+                             "own is more than the float32 reference's "
+                             "gradient fits beside (a TPU program's "
+                             "temporaries: 11.09 GiB of a v5e's 15.75)")
     args = parser.parse_args()
     if args.rehearse:
         sys.path.insert(0, os.path.join(ROOT, "benchmarks", "tests"))
@@ -96,5 +124,7 @@ if __name__ == "__main__":
         _, config, traffic = toy(Manifest(ROOT), args.workload)
     else:
         _, config, traffic = Manifest(ROOT).cell(args.workload)
+    if args.micro_batch:
+        traffic["micro_batch_per_chip"] = args.micro_batch
     for seed in args.seed:
         one_seed(args.workload, config, traffic, seed)

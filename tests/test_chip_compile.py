@@ -59,6 +59,12 @@ def _ds_flash(q, k, v):
     return ds_flash_attention(q, k, v, causal=True)
 
 
+def _ds_flash_packed(q, k, v, seg):
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import \
+        ds_flash_attention
+    return ds_flash_attention(q, k, v, segment_ids=seg, causal=True)
+
+
 def _stock_flash(q, k, v):
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     return flash_attention(q, k, v, causal=True)
@@ -108,6 +114,16 @@ _GGEMM = _ggemm_args(GG_E, GG_ROWS, GG_D, GG_F)
 _GGEMM_DOWN = _ggemm_args(GG_E, GG_ROWS, GG_F, GG_D)
 # mixtral-8x7b's gate projection, a prefill of 4096 tokens x 2 choices
 _GGEMM_MIXTRAL = _ggemm_args(8, 8192 + 8 * 128, 4096, 14336)
+# qwen3-next-80b-a3b.packed-s8192-gas2: 32 experts held of 512, a plan of
+# held_rows_bound 20,480 + 32 * 128 rows; D 2048 -> F 512 and back
+_GGEMM_HELD = _ggemm_args(32, 20480 + 32 * 128, 2048, 512)
+_GGEMM_HELD_DOWN = _ggemm_args(32, 20480 + 32 * 128, 512, 2048)
+# ... and its one full-attention layer: S 8192 packed, 16 query heads to 2
+# KV heads of width 256 — a working set of 29 MB, over what Mosaic grants
+# unasked, so the calls raise their VMEM limit
+_QKV_GQA_8K = [((2, 8192, 16, 256), jnp.bfloat16),
+               ((2, 8192, 2, 256), jnp.bfloat16),
+               ((2, 8192, 2, 256), jnp.bfloat16), ((2, 8192), jnp.int32)]
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -120,6 +136,12 @@ KERNEL_CASES = {
                               _GGEMM_DOWN),
     "ds_ggemm_mixtral_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
                                  _GGEMM_MIXTRAL),
+    "ds_ggemm_held_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                              _GGEMM_HELD),
+    "ds_ggemm_held_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                                   _GGEMM_HELD_DOWN),
+    "ds_flash_gqa_s8192_hd256_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA_8K),
     "ds_ggemm_mixtral_streamed_fwd_bwd": (
         jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
     "stock_flash_fwd": (_stock_flash, _QKV),
@@ -149,7 +171,10 @@ KERNEL_CASES = {
 NAMED_KERNELS = {
     "ds_flash_fwd_bwd": {"ds_flash_fwd", "ds_flash_bwd_dkv",
                          "ds_flash_bwd_dq"},
+    "ds_flash_gqa_s8192_hd256_packed_fwd_bwd": {
+        "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
+    "ds_ggemm_held_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
 }
@@ -165,6 +190,8 @@ GGEMM_REGIMES = {
     "ds_ggemm_down_fwd": {"ds_ggemm_fwd": "resident"},
     "ds_ggemm_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     "ds_ggemm_mixtral_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_held_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_held_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     # (512, 1024) swapped for dx is one block over its contraction of 1024
     "ds_ggemm_mixtral_streamed_fwd_bwd": {
         "ds_ggemm_fwd": "streamed", "ds_ggemm_dx": "streamed",
@@ -178,7 +205,7 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
     from deepspeed_tpu.telemetry import tracing
     fn, args = KERNEL_CASES[case]
     # the library asks jax.devices() what it runs on, and here that is a CPU
-    monkeypatch.setattr(gg, "_device_kind",
+    monkeypatch.setattr(gg.vmem, "device_kind",
                         lambda: v5e[0].device_kind.lower())
     with tracing.step_account("test/compile"):
         tracing.count_in_step(grouped_routed_rows=0, grouped_padded_rows=0)
@@ -195,6 +222,26 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
         named = {row["kernel"] for row in
                  parse_program_text(compiled.as_text()).values()}
         assert NAMED_KERNELS[case] <= named, named
+
+
+def test_flash_vmem_budget_is_the_device_kinds(monkeypatch):
+    """S 8192 at head width 256, packed, stages 29 MB: over what Mosaic
+    grants unasked, inside a v5e's budget — and only then do the calls ask
+    for a limit; the shapes that always fitted ask for nothing."""
+    from deepspeed_tpu.ops.pallas import ds_flash_attention as flash
+    big = jax.ShapeDtypeStruct((2, 8192, 16, 256), jnp.bfloat16)
+    small = jax.ShapeDtypeStruct((12, 1024, 16, 96), jnp.bfloat16)
+    monkeypatch.delenv("DS_FLASH_VMEM_MB", raising=False)
+    monkeypatch.setattr(flash.vmem, "device_kind", lambda: "cpu")
+    assert not flash.vmem_fits(8192, 256, 2, packed=True)
+    assert flash.vmem_fits(1024, 96, 2)
+    monkeypatch.setattr(flash.vmem, "device_kind", lambda: "tpu v5 lite")
+    assert flash.vmem_fits(8192, 256, 2, packed=True)
+    assert flash._compiler_kw(small, 512, 512, False) == {}
+    limit = flash._compiler_kw(big, 512, 512, True)[
+        "compiler_params"].vmem_limit_bytes
+    assert flash.working_set_bytes(8192, 256, 2, packed=True) < limit \
+        <= 128 << 20
 
 
 @pytest.mark.parametrize("manual_outside", [False, True],

@@ -405,7 +405,7 @@ def test_blocks_are_chosen_by_shape(monkeypatch, shape, regimes):
     panel over all of K beside the widest N block that fits the device's
     VMEM budget, else the K-innermost blocks; blocks given (the sweep's,
     DS_GGEMM_BLOCKS') are taken as given."""
-    monkeypatch.setattr(gg, "_device_kind", lambda: "tpu v5 lite")
+    monkeypatch.setattr(gg.vmem, "device_kind", lambda: "tpu v5 lite")
     rows, K, N, E = shape
     sizes = (2, 2, 2)
     fwd = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes)
@@ -414,15 +414,15 @@ def test_blocks_are_chosen_by_shape(monkeypatch, shape, regimes):
     assert (fwd.regime, dx.regime, dw.regime) == regimes
     for t, (k, n) in ((fwd, (K, N)), (dx, (N, K)), (dw, (K, N))):
         assert n % t.bn == 0 and k % t.bk == 0
-        assert t.vmem_bytes <= gg._vmem_budget()
+        assert t.vmem_bytes <= gg.vmem.budget()
         assert (t.bk == k) == (t.regime == "resident")
     given = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes,
                               (512, 1024))
     assert (given.bk, given.bn, given.regime) == (512, 1024, "streamed")
     # a device the table does not know gets what fits unasked
-    monkeypatch.setattr(gg, "_device_kind", lambda: "cpu")
+    monkeypatch.setattr(gg.vmem, "device_kind", lambda: "cpu")
     small = gg._choose_blocks("ds_ggemm_fwd", rows, K, N, E, 128, sizes)
-    assert small.vmem_bytes <= gg._VMEM_UNASKED
+    assert small.vmem_bytes <= gg.vmem.UNASKED
 
 
 def test_slot_kernel_parity_and_weight_stream_bound():
@@ -825,6 +825,93 @@ def test_mixtral_prefix_cache_grouped_parity(mixtral_served):
     assert outs_on == outs_off
 
 
+# ------------------------------------------------- a held subset's plan
+HELD_CASES = {
+    # name: (R, all experts, offset, held, bound rows, bm)
+    "inside_the_bound": (300, 16, 4, 4, 152, 8),
+    "first_experts": (300, 16, 0, 4, 152, 8),
+    "last_experts": (300, 16, 12, 4, 152, 8),
+    "every_expert": (200, 4, 0, 4, 400, 8),
+    "over_the_bound": (400, 8, 2, 2, 16, 8),
+    "bound_of_nothing": (64, 8, 6, 2, 0, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HELD_CASES))
+def test_held_plan_layout_and_rows_over_the_bound(case):
+    R, E_all, off, held, bound, bm = HELD_CASES[case]
+    eids = _rand_eids(np.random.default_rng(1), R, E_all)
+    plan, over = gg.make_held_group_plan(eids, off, held, bound, block_m=bm)
+    e = np.asarray(eids)
+    mine = (e >= off) & (e < off + held)
+    counts = np.asarray([(e == off + i).sum() for i in range(held)])
+    assert plan.padded_rows == -(-bound // bm) * bm + held * bm
+    assert plan.num_experts == held and plan.row_to_padded is None
+    np.testing.assert_array_equal(plan.counts, counts)
+    sizes = np.asarray(plan.group_sizes)
+    assert (sizes >= bm).all() and (sizes % bm == 0).all()
+    assert sizes.sum() <= plan.padded_rows
+    assert int(plan.used_blocks[0]) * bm == sizes.sum()
+    kept = np.minimum(counts, sizes)
+    assert int(over) == mine.sum() - kept.sum()
+    assert (int(over) > 0) == (case in ("over_the_bound",
+                                        "bound_of_nothing"))
+    # each group: its expert's first ``kept`` rows in token order, then R
+    p2r = np.asarray(plan.padded_to_row)
+    start = 0
+    for i in range(held):
+        want = np.flatnonzero(e == off + i)[:kept[i]]
+        np.testing.assert_array_equal(p2r[start:start + kept[i]], want)
+        assert (p2r[start + kept[i]:start + sizes[i]] == R).all()
+        gids = np.asarray(plan.block_group_ids)
+        assert (gids[start // bm:(start + sizes[i]) // bm] == i).all()
+        start += sizes[i]
+    assert (p2r[start:] == R).all()
+    assert np.all(np.diff(np.asarray(plan.block_group_ids)) >= 0)
+
+
+def test_held_rows_bound_is_twice_the_even_share():
+    assert gg.held_rows_bound(16384 * 10, 32, 512, 128) == 20480
+    assert gg.held_rows_bound(100, 3, 7, 8) == 88      # ceil(85.7) -> tile
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["ragged_dot", "kernels"])
+def test_held_rows_go_out_and_come_back(interpret):
+    """dispatch -> grouped GEMM -> combine over a held subset against a
+    dense oracle (every token through every held expert, gate 0 where not
+    chosen), forward and gradient in x, w and the gates."""
+    rng = np.random.default_rng(2)
+    T, k, E_all, off, held, D, N, bm = 40, 3, 8, 2, 3, 16, 24, 8
+    eids = _rand_eids(rng, T * k, E_all)
+    x = jnp.asarray(rng.standard_normal((T, D)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((held, D, N)), jnp.float32)
+    gates = jnp.asarray(rng.uniform(0.1, 1, (T * k,)), jnp.float32)
+    bound = gg.held_rows_bound(T * k, held, E_all, bm)
+
+    def ours(x, w, gates):
+        plan, over = gg.make_held_group_plan(eids, off, held, bound,
+                                             block_m=bm)
+        y = gg.ds_ggemm(gg.dispatch_held_rows(x, plan, k), w, plan,
+                        interpret=interpret)
+        return gg.combine_held_rows(y, gates, plan, k), over
+
+    def dense(x, w, gates):
+        onehot = jax.nn.one_hot(eids - off, held)           # 0 rows: elsewhere
+        per_expert = (gates[:, None] * onehot).reshape(T, k, held).sum(1)
+        return jnp.einsum("te,td,edn->tn", per_expert, x, w)
+
+    got, over = ours(x, w, gates)
+    assert int(over) == 0
+    np.testing.assert_allclose(got, dense(x, w, gates), atol=1e-4)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(
+        fn(*a)[0] if fn is ours else fn(*a)))
+    g_got = jax.grad(loss(ours), argnums=(0, 1, 2))(x, w, gates)
+    g_want = jax.grad(loss(dense), argnums=(0, 1, 2))(x, w, gates)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+
+
 # ------------------------------------------------------------- tooling
 def test_ggemm_sweep_smoke():
     """scripts/ggemm_sweep.py runs the interpret-mode smoke and emits
@@ -845,5 +932,9 @@ def test_ggemm_sweep_smoke():
     for r in rows:
         if r.get("kind") in ("f", "dx", "dw") and "winner" not in r:
             assert r["regime"] in ("resident", "streamed"), r
-            assert r["bytes_per_call"] > 0 and r["GBs"] > 0, r
+            # plumbing only, no timing: under six xdist workers the
+            # slope of two tiny interpret-mode chains (2 and 10 steps,
+            # ~0.2 ms apart) can come out <= 0, and the script then
+            # reports no rate (GBs null) — what this test used to trip on
+            assert r["bytes_per_call"] > 0 and "GBs" in r, r
             assert "pct_of_bf16_peak" in r and len(r["blocks"]) == 3, r

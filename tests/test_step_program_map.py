@@ -81,7 +81,9 @@ def test_fixed_names():
     assert STEP_SCOPES == ("ds.fwd_bwd", "ds.accumulate", "ds.optimizer",
                            "ds.embed", "ds.block", "attn", "mlp",
                            "ds.head_loss", "router", "dispatch", "experts",
-                           "combine")
+                           "combine", "shared_expert", "linear_attn",
+                           "in_proj", "conv", "delta_rule", "gate_norm",
+                           "out_proj")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw")
