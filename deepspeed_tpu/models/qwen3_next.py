@@ -54,7 +54,7 @@ from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
                                      moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.ops.linear_attention import (causal_conv,
-                                                gated_delta_rule, l2norm)
+                                                gated_delta_rule)
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_EMBED,
     SCOPE_GATE_NORM, SCOPE_HEAD_LOSS, SCOPE_IN_PROJ, SCOPE_LINEAR_ATTN,
@@ -276,8 +276,9 @@ def _linear_mixer(x, layer, config: Qwen3NextConfig, segment_ids):
         q = qkv[..., :Hk * dk].reshape(B, S, Hk, dk)
         k = qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
         v = qkv[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
-        o = gated_delta_rule(l2norm(q) * dk ** -0.5, l2norm(k), v, g, beta,
-                             segment_ids, chunk=config.delta_rule_chunk)
+        o = gated_delta_rule(q, k, v, g, beta, segment_ids,
+                             chunk=config.delta_rule_chunk,
+                             l2norm_scales=(dk ** -0.5, 1.0))
     o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     with jax.named_scope(SCOPE_GATE_NORM):
         y = _rms_norm(o, layer["o_norm"], config.rms_norm_eps) \

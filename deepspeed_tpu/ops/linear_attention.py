@@ -17,11 +17,19 @@ exp(G_i - G_j)`` for ``i > j`` (the WY / UT transform),
 
     W = (I + L)^-1 (beta k exp(G)),   U = (I + L)^-1 (beta v)
 
-with ``(I + L)^-1`` from one unit-lower-triangular solve.  Across chunks a ``lax.scan`` carries
-the state: ``v_new = U - W S``, ``o = (q exp(G)) S + (q k^T . decay) v_new``
-and ``S' = exp(G_C) S + (k exp(G_C - G))^T v_new``.  The scan's body is
-checkpointed, so the backward pass (autodiff through the scan) keeps one
-state per chunk and recomputes the rest.
+with ``(I + L)^-1`` taken once, in float32.  Across chunks the state is
+carried: ``v_new = U - W S``, ``o = (q exp(G)) S + (q k^T . decay) v_new``
+and ``S' = exp(G_C) S + (k exp(G_C - G))^T v_new``.
+
+One algorithm, two lowerings (:func:`_kernel_blocking` chooses by what the
+call can observe).  On one TPU, for heads that are multiples of 128 wide,
+the Mosaic kernels of ops/pallas/gated_delta_rule.py: the state stays in
+VMEM across a sequence's chunks, the inverse is taken inside the kernel
+and the backward is written by hand.  Elsewhere :func:`_chunked_xla`: the
+inverse from one unit-lower-triangular solve, a ``lax.scan`` whose body is
+checkpointed, so that the backward pass (autodiff through the scan) keeps
+one state per chunk and recomputes the rest — the fallback and, beside
+:func:`gated_delta_rule_recurrent`, the kernels' oracle.
 
 **Packed documents.**  With ``segment_ids`` a token sees only its own
 document: at a document's first token the state is zero, exactly as if
@@ -38,11 +46,12 @@ the sequence, the tail is padded with tokens that write nothing.
 Qwen3-Next, no bias) with the same reset: a tap that would reach into the
 previous document reads zero.
 
-Both are plain XLA (no Pallas kernel yet: ROADMAP).  Their parts of a
-step carry the ``jax.named_scope``s ``conv`` and ``delta_rule``
-(telemetry/tracing.py ``STEP_SCOPES``), written by the model, and each
-call of the delta rule leaves its chunk count and chunk length in the
-step's account (``tracing.delta_rule_chunks``).
+The convolution is plain XLA.  Their parts of a step carry the
+``jax.named_scope``s ``conv`` and ``delta_rule`` (telemetry/tracing.py
+``STEP_SCOPES``), written by the model, and each call of the delta rule
+leaves its chunk count, its chunk length and the lowering it took
+(``path``, with the kernels' grid blocking) in the step's account
+(``tracing.delta_rule_chunks``).
 """
 import jax
 import jax.numpy as jnp
@@ -86,19 +95,45 @@ def _chunked(x, n, C, Hk):
     return jnp.moveaxis(jnp.moveaxis(x, 2, 4), 1, 0)
 
 
+def _kernel_blocking(interpret, n, C, rep, dk, dv, dt):
+    """(the kernels' grid blocking or None, interpret) — one algorithm,
+    two lowerings, chosen by what the call can observe: the Mosaic kernels
+    of ops/pallas/gated_delta_rule.py on a TPU with one device (no
+    partitioning rule for the call yet), for shapes they take and a working
+    set inside ``vmem.budget()``; else (None) the XLA chunked form below.
+    ``interpret=True`` runs the kernels in interpret mode wherever the
+    shapes allow."""
+    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr
+    if interpret is False or not gdr.supported(dk, dv, C, rep):
+        return None, False
+    blocking = gdr.chunks_per_step(n, C, rep, dk, dv, jnp.dtype(dt).itemsize)
+    if interpret:
+        return blocking, True
+    from deepspeed_tpu.ops.attention import _on_tpu
+    on_one_tpu = _on_tpu() and jax.device_count() == 1
+    fits = blocking.vmem_bytes <= gdr.vmem.budget()
+    return (blocking if on_one_tpu and fits else None), False
+
+
 def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
-                     chunk: int = DEFAULT_CHUNK):
+                     chunk: int = DEFAULT_CHUNK, interpret=None,
+                     l2norm_scales=None):
     """The recurrence of the module docstring for every head at once.
 
     ``q``, ``k`` [B, S, Hk, dk] (already normalised and scaled as the
-    layer wants them), ``v`` [B, S, Hv, dv] with ``Hv`` a multiple of
+    layer wants them — or, with ``l2norm_scales`` = (q's, k's), as they
+    are: the call then takes ``l2norm(q) * q's`` and ``l2norm(k) * k's``
+    itself, which the kernels do on the tiles they hold), ``v`` [B, S,
+    Hv, dv] with ``Hv`` a multiple of
     ``Hk`` (key head ``h`` serves value heads ``h*Hv/Hk ..``), ``g`` (log
     decay, <= 0) and ``beta`` (write strength) [B, S, Hv], ``segment_ids``
     [B, S] int or None.  Returns ``o`` [B, S, Hv, dv] in ``v``'s dtype.
     Matrix products take their operands in ``v``'s dtype (the model's:
     bfloat16 in a bf16 step, float32 in a float32 one) and accumulate in
-    float32; the decays, the triangular solve and the carried state are
-    float32.  Differentiable in all five."""
+    float32; the decays, the inverse of ``I + L`` and the carried state are
+    float32.  Differentiable in all five.  ``interpret``: None chooses
+    the lowering (:func:`_kernel_blocking`), True runs the kernels in
+    interpret mode, False the XLA form."""
     B, S, Hk, dk = q.shape
     Hv, dv = v.shape[2], v.shape[3]
     dt = v.dtype
@@ -106,8 +141,12 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     n = -(-S // C)
     pad = n * C - S
     f32 = lambda a: a.astype(jnp.float32)
-    rep = Hv // Hk
-    q, k = q.astype(dt), k.astype(dt)
+    blocking, interpret = _kernel_blocking(interpret, n, C, Hv // Hk, dk, dv,
+                                           dt)
+    if l2norm_scales is not None and blocking is None:
+        q, k = (l2norm(t) * s for t, s in zip((q, k), l2norm_scales))
+    if l2norm_scales is None or blocking is None:
+        q, k = q.astype(dt), k.astype(dt)
     g, beta = f32(g), f32(beta)
     seg = (jnp.zeros((B, S), jnp.int32) if segment_ids is None
            else segment_ids.astype(jnp.int32))
@@ -118,9 +157,32 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
                                  + ((0, 0),) * (a.ndim - 2))
         q, k, v, g, beta = (tail(t) for t in (q, k, v, g, beta))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-    count_in_step(delta_rule_calls={f"{B}x{n * C}x{Hv}x{dk}x{dv}": {
-        "chunks": n, "chunk_len": C, "batch": B, "heads": Hv,
-        "dk": dk, "dv": dv}})
+    row = {"chunks": n, "chunk_len": C, "batch": B, "heads": Hv,
+           "dk": dk, "dv": dv,
+           "path": "xla" if blocking is None else "kernel"}
+    if blocking is not None:
+        from deepspeed_tpu.ops.pallas.gated_delta_rule import \
+            gated_delta_rule_kernels
+        row.update(heads_per_step=blocking.heads,
+                   chunks_per_step=blocking.chunks)
+        o = gated_delta_rule_kernels(q, k, v, g, beta, seg, blocking,
+                                     l2norm_scales, interpret)
+    else:
+        o = _chunked_xla(q, k, v, g, beta, seg, n, C)
+    count_in_step(delta_rule_calls={f"{B}x{n * C}x{Hv}x{dk}x{dv}": row})
+    return o[:, :S]
+
+
+def _chunked_xla(q, k, v, g, beta, seg, n, C):
+    """The chunked form as XLA einsums around a ``lax.scan``: the fallback
+    and, beside :func:`gated_delta_rule_recurrent`, the kernels' oracle.
+    Arguments as :func:`gated_delta_rule` prepared them (``n`` chunks of
+    ``C`` tokens); returns ``o`` [B, n * C, Hv, dv]."""
+    B, _, Hk, dk = q.shape
+    Hv, dv = v.shape[2], v.shape[3]
+    dt = v.dtype
+    f32 = lambda a: a.astype(jnp.float32)
+    rep = Hv // Hk
     dot = lambda spec, a, b: jnp.einsum(
         spec, a, b, preferred_element_type=jnp.float32)
 
@@ -175,7 +237,7 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     _, o = lax.scan(one_chunk, state0,
                     (W, U, attn, qc, kc, from_state, to_end, keep_state))
     o = jnp.moveaxis(o, 0, 1)                                # [B,n,g,r,C,dv]
-    return jnp.moveaxis(o, 4, 2).reshape(B, n * C, Hv, dv)[:, :S]
+    return jnp.moveaxis(o, 4, 2).reshape(B, n * C, Hv, dv)
 
 
 def gated_delta_rule_recurrent(q, k, v, g, beta, segment_ids=None):

@@ -319,9 +319,10 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_OUT_PROJ)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
-#: on a transposed right-hand side) and dw
+#: on a transposed right-hand side) and dw, and the gated delta rule's two
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
-                "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw")
+                "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
+                "ds_gdr_fwd", "ds_gdr_bwd")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -641,8 +642,12 @@ def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
 def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):
     """The gated-delta-rule calls of the step as ops/linear_attention.py
     traced them: one row per shape — ``chunks`` and ``chunk_len`` of the
-    scan, ``batch``, ``heads``, ``dk``, ``dv``.  None where the step has
-    no such call."""
+    scan, ``batch``, ``heads``, ``dk``, ``dv`` and ``path``: ``"kernel"``
+    where the call ran as the Mosaic kernels ``ds_gdr_fwd`` /
+    ``ds_gdr_bwd`` (then also ``heads_per_step`` and ``chunks_per_step``,
+    the value heads and chunks one grid step takes), ``"xla"`` where it
+    fell back to the XLA chunked form.  None where the step has no such
+    call."""
     calls = _STEP_COUNTERS.get(name, {}).get("delta_rule_calls")
     return [calls[key] for key in sorted(calls)] if calls else None
 

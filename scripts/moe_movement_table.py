@@ -29,10 +29,10 @@ _SCATTER = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) scatter\(.*"
                       r'op_name="([^"]*)"')
 
 
-def movement_rows(dev, table, tr, step_phase):
+def scope_rows(dev, table, tr, step_phase, scope=SCOPE, below="/mlp/"):
     """(steps traced, [row]) of one device: self time, executions, phase,
-    op_name below ``/mlp/`` and result shape of each instruction under the
-    two scopes."""
+    op_name below ``below`` and result shape of each instruction whose
+    scope path matches ``scope``."""
     steps = sum(1 for _, _, text in dev.events(tr.MODULES)
                 if re.search(STEP["module"], text))
     ns, calls, shape = defaultdict(int), defaultdict(int), {}
@@ -40,7 +40,7 @@ def movement_rows(dev, table, tr, step_phase):
     def moves(text):
         name = step_phase.instruction(text)
         row = table.get(name)
-        return name if row and SCOPE.search(row["scope"] or "") else None
+        return name if row and scope.search(row["scope"] or "") else None
 
     for s, e, text in step_phase.in_step(dev, dev.segments(), STEP):
         name = moves(text)
@@ -54,7 +54,8 @@ def movement_rows(dev, table, tr, step_phase):
     rows = [{"instruction": name, "phase": table[name]["phase"],
              "ms_per_step": ns[name] * 1e-6 / steps,
              "calls_per_step": calls[name] / steps,
-             "op": table[name]["scope"].split("/mlp/", 1)[1],
+             "op": table[name]["scope"].split(below, 1)[1],
+             "kernel": table[name].get("kernel"),
              "shape": shape[name]} for name in ns]
     rows.sort(key=lambda r: (r["phase"], -r["ms_per_step"]))
     return steps, rows
@@ -67,15 +68,21 @@ def scatters_in(text):
             if m and SCOPE.search(m.group(3))]
 
 
-def main():
-    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--workload", default="olmoe-1b-7b.packed-s4096-gas8")
+def cell_arguments(doc, workload):
+    parser = argparse.ArgumentParser(description=doc.split("\n\n")[0])
+    parser.add_argument("--workload", default=workload)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, default=10.0)
     parser.add_argument("--root", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     parser.add_argument("--out")
-    args = parser.parse_args()
+    return parser.parse_args()
+
+
+def traced_cell(args):
+    """Runs the cell's traced run in this process and returns what the
+    tables are made from: (device 0 of the trace, the step's program map,
+    harness.trace, readers.step_phase, the executable's text or None)."""
     root = os.path.abspath(args.root)
     bench = os.path.join(root, "benchmarks")
     sys.path[:0] = [bench, root]
@@ -102,8 +109,14 @@ def main():
     runpy.run_path(sys.argv[0], run_name="__main__")
 
     from deepspeed_tpu.telemetry import tracing
-    table = tracing.get_program_map(STEP["program"])
-    steps, rows = movement_rows(traces[-1].devices[0], table, tr, step_phase)
+    return (traces[-1].devices[0], tracing.get_program_map(STEP["program"]),
+            tr, step_phase, texts[-1] if texts else None)
+
+
+def main():
+    args = cell_arguments(__doc__, "olmoe-1b-7b.packed-s4096-gas8")
+    dev, table, tr, step_phase, text = traced_cell(args)
+    steps, rows = scope_rows(dev, table, tr, step_phase)
     sums = defaultdict(float)
     for r in rows:
         sums[f'{r["phase"]}/{r["op"].split("/", 1)[0]}'] += r["ms_per_step"]
@@ -112,7 +125,7 @@ def main():
               f'{r["shape"]:32s} {r["op"][-110:]}')
     sums = dict(sorted(sums.items()), all=sum(sums.values()))
     print(json.dumps({"steps_traced": steps, "ms_per_step": sums}))
-    scatters = scatters_in(texts[-1]) if texts and texts[-1] else None
+    scatters = scatters_in(text) if text else None
     print(json.dumps({"scatters_under_dispatch_or_combine":
                       None if scatters is None else len(scatters),
                       "first": (scatters or [])[:8]}))

@@ -1,8 +1,9 @@
 """Compiles for a TPU v5e that is described, not attached (the chip's
 compiler is installed on CPU-only boxes): the training path's kernels at
-GPT-2 760M width, and the grouped GEMM kernels at OLMoE-1B-7B's (their
-weight panels resident in VMEM) and at Mixtral-8x7B's, go through Mosaic, the data-sharded flash kernel goes through the
-partitioner, and the library knows the chip's peaks.
+GPT-2 760M width, the grouped GEMM kernels at OLMoE-1B-7B's (their
+weight panels resident in VMEM) and at Mixtral-8x7B's, and the gated delta
+rule's two at Qwen3-Next's go through Mosaic, the data-sharded flash kernel
+goes through the partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
 or times.  ``chip_smoke.py`` is the run."""
@@ -101,6 +102,18 @@ def _ggemm_streamed(x, w, gids, used):
     return _ggemm(x, w, gids, used, gg._BLOCKS_KN)
 
 
+def _gdr(q, k, v, g, beta, seg):
+    """The delta rule's kernels as ops/linear_attention.py calls them on
+    one TPU (the choice switched off: no TPU here), with the blocking the
+    library chooses."""
+    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr
+    (B, S, Hk, dk), (Hv, dv) = q.shape, v.shape[2:]
+    blocking = gdr.chunks_per_step(S // 64, 64, Hv // Hk, dk, dv,
+                                   v.dtype.itemsize)
+    return gdr.gated_delta_rule_kernels(q, k, v, g, beta, seg, blocking,
+                                        scales=(dk ** -0.5, 1.0))
+
+
 def _ggemm_args(experts, rows, k, n):
     return [((rows, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
             ((rows // 128,), jnp.int32), ((1,), jnp.int32)]
@@ -124,6 +137,11 @@ _GGEMM_HELD_DOWN = _ggemm_args(32, 20480 + 32 * 128, 512, 2048)
 _QKV_GQA_8K = [((2, 8192, 16, 256), jnp.bfloat16),
                ((2, 8192, 2, 256), jnp.bfloat16),
                ((2, 8192, 2, 256), jnp.bfloat16), ((2, 8192), jnp.int32)]
+# ... and its three delta-rule layers: 16 key / 32 value heads of 128,
+# the state 128 x 128 float32 per value head, 128 chunks of 64
+_GDR_8K = [((2, 8192, 16, 128), jnp.bfloat16)] * 2 + [
+    ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192, 32), jnp.float32),
+    ((2, 8192, 32), jnp.float32), ((2, 8192), jnp.int32)]
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -144,6 +162,9 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA_8K),
     "ds_ggemm_mixtral_streamed_fwd_bwd": (
         jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
+    "ds_gdr_s8192_packed_fwd": (_gdr, _GDR_8K),
+    "ds_gdr_s8192_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_gdr), (0, 1, 2, 3, 4)), _GDR_8K),
     "stock_flash_fwd": (_stock_flash, _QKV),
     "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
                             _QKV),
@@ -177,6 +198,8 @@ NAMED_KERNELS = {
     "ds_ggemm_held_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+    "ds_gdr_s8192_packed_fwd": {"ds_gdr_fwd"},
+    "ds_gdr_s8192_packed_fwd_bwd": {"ds_gdr_fwd", "ds_gdr_bwd"},
 }
 
 #: the regime each grouped kernel of a case takes (the step account's
@@ -288,10 +311,13 @@ def test_library_knows_the_chips_peaks(v5e):
     assert ici_bytes_per_s(v5e[0], env={}) == 200e9
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", [
+    "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1"])
 def test_measurement_scripts_refuse_the_cpu(script):
+    script, *args = script.split()
     out = subprocess.run(
-        [sys.executable, os.path.join(REPO, script)], capture_output=True,
+        [sys.executable, os.path.join(REPO, script), *args],
+        capture_output=True,
         text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode != 0
     assert '"ok": true' not in out.stdout
