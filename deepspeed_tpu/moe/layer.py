@@ -60,13 +60,24 @@ class MoEConfig:
     eval_capacity_factor: float = 2.0
     min_capacity: int = 4
     noisy_gate_policy: Optional[str] = None    # None | 'Jitter'
-    activation: str = "silu_glu"               # silu_glu (Mixtral) | gelu
+    #: silu_glu (Mixtral: three matrices) | gelu | relu2 (Nemotron-H:
+    #: ``w_out . relu(w_in . x)^2``); the last two are un-gated, two
+    #: matrices and no ``w_gate`` leaf
+    activation: str = "silu_glu"
     aux_loss_coef: float = 0.01
     z_loss_coef: float = 0.0
     #: divide the k chosen gate values by their sum (Mixtral, the
     #: reference); False keeps the softmax probabilities as they are
     #: (OLMoE ``norm_topk_prob: false``)
     norm_topk_prob: bool = True
+    #: what the router makes of its logits (sharded_moe.ROUTER_FORMS):
+    #: "softmax", or "sigmoid" scores chosen by ``score +
+    #: e_score_correction_bias`` — a leaf [num_experts] that is in the
+    #: choice only, never in the weights, so its gradient is exactly zero
+    #: (a load-driven update of it is not built: ROADMAP)
+    router: str = "softmax"
+    #: multiplies the chosen weights last, after ``norm_topk_prob``
+    routed_scaling_factor: float = 1.0
     #: form of the load-balance term (sharded_moe.LOAD_BALANCE_FORMS):
     #: "first_choice" (the reference) or "all_choices" (OLMoE / Hugging
     #: Face ``load_balancing_loss_func``)
@@ -92,6 +103,11 @@ class MoEConfig:
     #: dispatch only.
     expert_offset: int = 0
     experts_held: Optional[int] = None
+    #: ``held_rows_bound`` is this many times the held experts' even share
+    #: of the routed rows: 2 where a router spreads its tokens about
+    #: evenly; a router whose experts' loads differ several-fold (sigmoid
+    #: scores over un-gated relu2 experts at initialisation) needs more
+    held_rows_factor: int = 2
     #: width of a shared expert every token passes through beside the
     #: routed ones (0 = none), added to their sum; ``shared_expert_gate``
     #: scales it by ``sigmoid(x . w)`` per token (Qwen3-Next)
@@ -120,6 +136,8 @@ def init_moe_params(config: MoEConfig, rng) -> dict:
     }
     if config.activation == "silu_glu":
         params["w_gate"] = norm(next(k), (E, D, F)) * std
+    if config.router == "sigmoid":
+        params["e_score_correction_bias"] = jnp.zeros((config.num_experts,))
     if config.shared_expert_d_ff:
         # off a branch of its own, as the residual FFN below
         sk = iter(jax.random.split(jax.random.fold_in(rng, 23), 4))
@@ -151,6 +169,8 @@ def moe_logical_specs(config: MoEConfig) -> dict:
     }
     if config.activation == "silu_glu":
         specs["w_gate"] = P(EXPERT_AXIS, None, "model")
+    if config.router == "sigmoid":
+        specs["e_score_correction_bias"] = P()
     if config.shared_expert_d_ff:
         specs["shared_in"] = P(None, "model")
         specs["shared_out"] = P("model", None)
@@ -335,8 +355,7 @@ def _expert_ffn(params, x, config: MoEConfig):
                              _dq(params["w_gate"], dt), x)
 
     def one(w_in, w_out, xe):
-        h = jax.nn.gelu(xe @ w_in, approximate=True)
-        return h @ w_out
+        return _ungated(xe @ w_in, config) @ w_out
 
     return jax.vmap(one)(_dq(params["w_in"], dt),
                          _dq(params["w_out"], dt), x)
@@ -360,7 +379,7 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     dt = xt.dtype
     with jax.named_scope(SCOPE_ROUTER):
         logits = _routing_logits(params, xt, config)
-        routing = _route(logits, config, train, rng)
+        routing = _route(params, logits, config, train, rng)
     _emit_router_health(logits, routing, config)
     eids = routing.expert_idx.reshape(-1)               # [T*k]
     gates = routing.gate_weights.reshape(-1)            # [T*k] fp32
@@ -415,7 +434,8 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
     T, D = xt.shape
     k, dt = config.top_k, xt.dtype
     R = T * k
-    bound = gg.held_rows_bound(R, config.held, config.num_experts)
+    bound = gg.held_rows_bound(R, config.held, config.num_experts,
+                               factor=config.held_rows_factor)
     with jax.named_scope(SCOPE_DISPATCH):
         plan, over = gg.make_held_group_plan(
             eids, config.expert_offset, config.held, bound)
@@ -444,19 +464,28 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
 ROWS_OVER_BOUND = "moe/rows_over_bound"
 
 
-def _route(logits, config: MoEConfig, train: bool, rng):
+def _route(params, logits, config: MoEConfig, train: bool, rng):
     """The one selection both dispatch formulations consume."""
     return topk_routing(
         logits, config.top_k,
         rng if (train and config.noisy_gate_policy) else None,
         config.z_loss_coef, normalize=config.norm_topk_prob,
-        load_balance=config.load_balance)
+        load_balance=config.load_balance, router=config.router,
+        selection_bias=params.get("e_score_correction_bias"),
+        scale=config.routed_scaling_factor)
+
+
+def _ungated(h, config: MoEConfig):
+    """The activation of an expert of two matrices."""
+    if config.activation == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    return jax.nn.gelu(h, approximate=True)
 
 
 def _glu(mm, x, w_gate, w_in, config: MoEConfig):
     if config.activation == "silu_glu":
         return jax.nn.silu(mm(x, w_gate)) * mm(x, w_in)
-    return jax.nn.gelu(mm(x, w_in), approximate=True)
+    return _ungated(mm(x, w_in), config)
 
 
 def gg_kernel_real() -> bool:
@@ -525,7 +554,7 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
     # so the two modes publish bitwise-identical health numbers
     with jax.named_scope(SCOPE_ROUTER):
         logits = wsc(_routing_logits(params, xt, config), tok_sh)
-        routing = _route(logits, config, train, rng)
+        routing = _route(params, logits, config, train, rng)
     _emit_router_health(logits, routing, config)
     gate: GateOutput = topkgating(logits, config.top_k, cf,
                                   config.min_capacity, noise,
@@ -565,7 +594,7 @@ def _finish_residual(params, x, moe_out, aux, config: MoEConfig):
             h = (jax.nn.silu(qdot(x, params["res_gate"]))
                  * qdot(x, params["res_in"]))
         else:
-            h = jax.nn.gelu(qdot(x, params["res_in"]), approximate=True)
+            h = _ungated(qdot(x, params["res_in"]), config)
         res = qdot(h, params["res_out"])
         coef = jax.nn.softmax(
             (qdot(x, params["coef_w"])
@@ -583,7 +612,7 @@ def _shared_expert(params, x, config: MoEConfig):
         h = jax.nn.silu(qdot(x, params["shared_gate"])) \
             * qdot(x, params["shared_in"])
     else:
-        h = jax.nn.gelu(qdot(x, params["shared_in"]), approximate=True)
+        h = _ungated(qdot(x, params["shared_in"]), config)
     out = qdot(h, params["shared_out"])
     if config.shared_expert_gate:
         gate = jax.nn.sigmoid(

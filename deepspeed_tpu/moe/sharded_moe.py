@@ -40,31 +40,55 @@ class TopKRouting(NamedTuple):
 #: e, over T (Hugging Face ``load_balancing_loss_func``, sum_e f_e = k)
 LOAD_BALANCE_FORMS = ("first_choice", "all_choices")
 
+#: what a router makes of its logits: ``softmax`` over the experts (the
+#: reference; the ``top_k`` largest are chosen and their probabilities are
+#: the weights), or ``sigmoid`` scores, one expert at a time
+#: (DeepSeek-V3, arXiv:2412.19437 section 2.1.2; Nemotron-H): the choice
+#: is the ``top_k`` largest of ``score + selection_bias``, a bias the loss
+#: does not train and that is NOT in the weights, which are the chosen
+#: scores themselves
+ROUTER_FORMS = ("softmax", "sigmoid")
+
 
 def topk_routing(logits: jnp.ndarray, k: int,
                  noise_rng: Optional[jax.Array] = None,
                  z_loss_coef: float = 0.0, normalize: bool = True,
-                 load_balance: str = "first_choice") -> TopKRouting:
+                 load_balance: str = "first_choice",
+                 router: str = "softmax", selection_bias=None,
+                 scale: float = 1.0) -> TopKRouting:
     """The selection/aux half of :func:`topkgating`, verbatim (iterative
     argmax with -1e9 suppression, top-1 aux loss, per-token gate
     normalization) — extracted so capacity enforcement is a property of
     the DISPATCH, not of the routing decision.  ``normalize=False`` keeps
     the chosen softmax probabilities as they are (OLMoE's
     ``norm_topk_prob: false``); ``load_balance`` picks the form of
-    ``l_aux`` (:data:`LOAD_BALANCE_FORMS`)."""
+    ``l_aux`` (:data:`LOAD_BALANCE_FORMS`).  ``router`` picks what the
+    logits become (:data:`ROUTER_FORMS`); under ``sigmoid`` the choice is
+    by ``score + selection_bias`` ([E], no gradient reaches it), the
+    weights are the chosen scores, and ``P_e`` of the load-balance term is
+    the mean of ``score_e / sum_e' score_e'``.  ``scale`` multiplies the
+    weights last (after ``normalize``)."""
     if load_balance not in LOAD_BALANCE_FORMS:
         raise ValueError(f"load_balance {load_balance!r}: choose one of "
                          f"{LOAD_BALANCE_FORMS}")
+    if router not in ROUTER_FORMS:
+        raise ValueError(f"router {router!r}: choose one of {ROUTER_FORMS}")
     T, E = logits.shape
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    select_logits = logits.astype(jnp.float32)
+    if router == "sigmoid":
+        gates = jax.nn.sigmoid(logits.astype(jnp.float32))
+        select_logits = gates if selection_bias is None \
+            else gates + selection_bias.astype(jnp.float32)
+        balance_probs = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    else:
+        gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        select_logits = logits.astype(jnp.float32)
+        balance_probs = gates
     if noise_rng is not None:
         select_logits = select_logits + jax.random.gumbel(
             noise_rng, select_logits.shape)
 
     top1 = jnp.argmax(select_logits, axis=-1)
-    me = jnp.mean(gates, axis=0)
+    me = jnp.mean(balance_probs, axis=0)
     ce = jnp.mean(jax.nn.one_hot(top1, E, dtype=jnp.float32), axis=0)
     l_aux = jnp.sum(me * ce) * E
 
@@ -94,6 +118,8 @@ def topk_routing(logits: jnp.ndarray, k: int,
         denom = sum(chosen_gates)
         denom = jnp.maximum(denom, jnp.finfo(jnp.float32).eps)
         chosen_gates = [g / denom for g in chosen_gates]
+    if scale != 1.0:
+        chosen_gates = [g * scale for g in chosen_gates]
     gate_weights = jnp.stack(chosen_gates, axis=1)
     return TopKRouting(l_aux, z_loss, expert_idx, gate_weights)
 

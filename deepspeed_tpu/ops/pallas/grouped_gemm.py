@@ -322,13 +322,15 @@ def combine_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
 # and the plan's length is static, so it is a stated bound
 # (:func:`held_rows_bound`): rows past it are counted, never lost silently.
 def held_rows_bound(routed_rows: int, experts_held: int, num_experts: int,
-                    block_m: Optional[int] = None) -> int:
-    """Twice the expected share of ``routed_rows`` = tokens x top_k rows
-    that land on ``experts_held`` of ``num_experts`` under even routing,
-    rounded up to M-tiles: the receive buffer a deployment sizes the same
-    way."""
+                    block_m: Optional[int] = None, factor: int = 2) -> int:
+    """``factor`` times (twice, unless the layer says otherwise:
+    ``MoEConfig.held_rows_factor``) the expected share of ``routed_rows`` =
+    tokens x top_k rows that land on ``experts_held`` of ``num_experts``
+    under even routing, rounded up to M-tiles: the receive buffer a
+    deployment sizes the same way."""
     bm = int(block_m or default_block_m())
-    return _round_up(-(-2 * routed_rows * experts_held // num_experts), bm)
+    return _round_up(
+        -(-int(factor) * routed_rows * experts_held // num_experts), bm)
 
 
 def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
@@ -580,11 +582,28 @@ def _tgmm_kernel(gid_ref, used_ref, x_ref, dy_ref, o_ref, acc_ref, *, nm,
 
 
 # ------------------------------------------------------- the block shapes
+def _whole(dim, quantum=128):
+    """Whether ``dim`` is tiled by one block that is the dimension itself:
+    a multiple of 64 that no multiple of 128 divides (an expert 1856 =
+    14.5 x 128 wide).  A block equal to the whole dimension is legal in
+    Mosaic whatever its size, so nothing is padded; the compiler lays the
+    last, half-filled lane tile out itself."""
+    return dim % quantum != 0 and dim % (quantum // 2) == 0
+
+
+def _one_block(dim, quantum=128):
+    """The one block that spans ``dim``: itself, or a ragged dim padded."""
+    return dim if _whole(dim, quantum) else _round_up(dim, quantum)
+
+
 def _fit_block(dim, requested, quantum=128):
     """qgemm's divisor-fitting rule: shrink to a quantum-multiple that
     divides a 128-aligned dim (padding a non-dividing weight dim would
-    materialize a padded copy of the WHOLE expert stack); ragged dims
+    materialize a padded copy of the WHOLE expert stack); a dim that is
+    :func:`_whole` is its own block, whatever was requested; ragged dims
     (tests) keep the request and pad."""
+    if _whole(dim, quantum):
+        return dim
     b = min(requested, _round_up(dim, quantum))
     if dim % quantum == 0:
         for cand in range(max(b - b % quantum, quantum), quantum - 1,
@@ -642,10 +661,10 @@ def _choose_blocks(kernel, rows, K, N, E, bm, sizes, blocks=None):
     streamed = tile(_fit_block(K, _BLOCKS_KN[0]), _fit_block(N, _BLOCKS_KN[1]))
     quantum = 128
     widths = ([b for b in range(N, 0, -quantum) if N % b == 0]
-              if N % quantum == 0 else [_round_up(N, quantum)])
+              if N % quantum == 0 else [_one_block(N)])
     budget = vmem.budget()
     for bn in widths:
-        resident = tile(_round_up(K, quantum), bn)
+        resident = tile(_one_block(K), bn)
         if resident.vmem_bytes <= budget:
             return min(resident, streamed,
                        key=lambda t: t.weight_bytes + t.operand_bytes)
@@ -678,7 +697,8 @@ def _precision_for(dtype):
 
 def _pad_operands(x, w, scales, bk, bn, transpose_rhs):
     """Zero-pad K/N to tile multiples (tests and odd adapter shapes only
-    — every real model dim divides the fitted blocks)."""
+    — a real model dim is a multiple of 128, which the fitted blocks
+    divide, or of 64, which is one block: :func:`_whole`)."""
     Mp, K = x.shape
     kdim, ndim = (2, 1) if transpose_rhs else (1, 2)
     K_pad, N_pad = _round_up(K, bk), _round_up(w.shape[ndim], bn)

@@ -311,12 +311,17 @@ SCOPE_CONV = "conv"                 # ... the short causal convolution + silu
 SCOPE_DELTA_RULE = "delta_rule"     # ... l2-norm, the gated delta rule
 SCOPE_GATE_NORM = "gate_norm"       # ... per-head RMSNorm, silu(z) gate
 SCOPE_OUT_PROJ = "out_proj"         # ... output projection + residual
+# inside ``ds.block``, where the mixer is a state-space layer
+# (models/nemotron_h.py), over ``in_proj`` / ``conv`` / ``gate_norm`` /
+# ``out_proj`` as above:
+SCOPE_SSM = "ssm"                   # the norm and all of the below
+SCOPE_SCAN = "scan"                 # ... the state-space scan (SSD)
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
                SCOPE_COMBINE, SCOPE_SHARED_EXPERT, SCOPE_LINEAR_ATTN,
                SCOPE_IN_PROJ, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
-               SCOPE_OUT_PROJ)
+               SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, and the gated delta rule's two
@@ -648,8 +653,22 @@ def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):
     the value heads and chunks one grid step takes), ``"xla"`` where it
     fell back to the XLA chunked form.  None where the step has no such
     call."""
-    calls = _STEP_COUNTERS.get(name, {}).get("delta_rule_calls")
+    return _account_rows(name, "delta_rule_calls")
+
+
+def _account_rows(name: str, counter: str):
+    """The rows a dict counter of the step's account holds, by key."""
+    calls = _STEP_COUNTERS.get(name, {}).get(counter)
     return [calls[key] for key in sorted(calls)] if calls else None
+
+
+def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
+    """The state-space scans of the step as ops/state_space.py traced
+    them: one row per shape — ``chunks`` and ``chunk_len`` of the scan,
+    ``batch``, ``heads``, ``groups``, ``head_dim``, ``state`` and ``path``
+    (``"xla"``: the chunked form as XLA einsums around a ``lax.scan``; no
+    kernel yet).  None where the step has no such call."""
+    return _account_rows(name, "ssd_calls")
 
 
 def reset_programs():

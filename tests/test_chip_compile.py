@@ -142,6 +142,16 @@ _QKV_GQA_8K = [((2, 8192, 16, 256), jnp.bfloat16),
 _GDR_8K = [((2, 8192, 16, 128), jnp.bfloat16)] * 2 + [
     ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192, 32), jnp.float32),
     ((2, 8192, 32), jnp.float32), ((2, 8192), jnp.int32)]
+# nemotron-3-nano-30b-a3b.packed-s8192-gas2: 8 experts held of 128, 16,384
+# tokens x 6 choices, a plan of held_rows_bound 24,576 (four times the even
+# share) + 8 * 128 rows; D 2688 -> F 1856 = 14.5 x 128 and back: one block
+# spans 1856, nothing padded
+_GGEMM_RELU2 = _ggemm_args(8, 24576 + 8 * 128, 2688, 1856)
+_GGEMM_RELU2_DOWN = _ggemm_args(8, 24576 + 8 * 128, 1856, 2688)
+# ... and its one attention layer: 32 query heads to 2 KV heads of 128
+_QKV_GQA16_8K = [((2, 8192, 32, 128), jnp.bfloat16),
+                 ((2, 8192, 2, 128), jnp.bfloat16),
+                 ((2, 8192, 2, 128), jnp.bfloat16), ((2, 8192), jnp.int32)]
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -158,8 +168,14 @@ KERNEL_CASES = {
                               _GGEMM_HELD),
     "ds_ggemm_held_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
                                    _GGEMM_HELD_DOWN),
+    "ds_ggemm_relu2_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                               _GGEMM_RELU2),
+    "ds_ggemm_relu2_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                                    _GGEMM_RELU2_DOWN),
     "ds_flash_gqa_s8192_hd256_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA_8K),
+    "ds_flash_gqa16_s8192_hd128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA16_8K),
     "ds_ggemm_mixtral_streamed_fwd_bwd": (
         jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
     "ds_gdr_s8192_packed_fwd": (_gdr, _GDR_8K),
@@ -194,8 +210,13 @@ NAMED_KERNELS = {
                          "ds_flash_bwd_dq"},
     "ds_flash_gqa_s8192_hd256_packed_fwd_bwd": {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
+    "ds_flash_gqa16_s8192_hd128_packed_fwd_bwd": {
+        "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
     "ds_ggemm_held_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+    "ds_ggemm_relu2_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+    "ds_ggemm_relu2_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx",
+                                    "ds_ggemm_dw"},
     "ds_ggemm_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_gdr_s8192_packed_fwd": {"ds_gdr_fwd"},
@@ -215,6 +236,8 @@ GGEMM_REGIMES = {
     "ds_ggemm_mixtral_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     "ds_ggemm_held_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     "ds_ggemm_held_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_relu2_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_relu2_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     # (512, 1024) swapped for dx is one block over its contraction of 1024
     "ds_ggemm_mixtral_streamed_fwd_bwd": {
         "ds_ggemm_fwd": "streamed", "ds_ggemm_dx": "streamed",
@@ -232,9 +255,14 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
                         lambda: v5e[0].device_kind.lower())
     with tracing.step_account("test/compile"):
         tracing.count_in_step(grouped_routed_rows=0, grouped_padded_rows=0)
-        compiled = jax.jit(fn).lower(
-            *(_arg(v5e[0], shape, dtype) for shape, dtype in args)).compile()
+        lowered = jax.jit(fn).lower(
+            *(_arg(v5e[0], shape, dtype) for shape, dtype in args))
+        compiled = lowered.compile()
     assert KERNEL in compiled.as_text()
+    if "relu2" in case:
+        # a dim of 14.5 x 128 is one block: no padded copy of the expert
+        # stack (or of the rows) is written beside the kernels
+        assert "stablehlo.pad" not in lowered.as_text()
     if case in GGEMM_REGIMES:
         calls = tracing.grouped_gemm_rows("test/compile")["calls"]
         assert {c["kernel"]: c["regime"] for c in calls} \

@@ -237,7 +237,7 @@ def test_a_row_over_the_bound_is_counted(monkeypatch):
     _, _, stats = moe_layer.moe_layer(layer, h, cfg, return_stats=True)
     assert int(stats["dispatched"]) == 16 + 4 * 8       # the plan, full
     assert int(stats["dropped"]) > 0
-    eids = moe_layer._route(moe_layer._routing_logits(
+    eids = moe_layer._route(layer, moe_layer._routing_logits(
         layer, h.reshape(-1, 64), cfg), cfg, True, None).expert_idx
     here = int(jnp.sum((eids >= 8) & (eids < 12)))
     assert int(stats["dropped"]) + int(stats["dispatched"]) == here
