@@ -40,8 +40,17 @@ chunked form is tested against, and what a decode step would run.
 The model writes the ``jax.named_scope`` ``scan`` around the call
 (telemetry/tracing.py ``STEP_SCOPES``); each call leaves its chunk count,
 chunk length, heads and groups in the step's account
-(``tracing.ssd_chunks``).  XLA's lowering only: Mosaic kernels that keep
-the state in VMEM across a sequence's chunks are queued (ROADMAP).
+and the lowering it took (``tracing.ssd_chunks``).
+
+One algorithm, two lowerings (:func:`_kernel_blocking` chooses by what the
+call can observe).  On one TPU, for a state size of whole lane tiles, a
+chunk of one (128) and heads of whole sublane tiles, the Mosaic kernels
+of ops/pallas/state_space.py: a group's state stays in VMEM across a
+sequence's chunks, a head's decay matrix is built in registers and never
+written to HBM, the backward is written by hand.  Elsewhere
+:func:`_chunked_xla`, einsums around a ``lax.scan`` with autodiff's
+backward — the fallback and, beside :func:`ssd_recurrent`, the kernels'
+oracle.
 """
 import jax
 import jax.numpy as jnp
@@ -60,15 +69,38 @@ def _chunked(t, n, C, G):
     return jnp.moveaxis(jnp.moveaxis(t, 2, 4), 1, 0)
 
 
+def _kernel_blocking(interpret, n, C, r, P, N, dtype):
+    """(the kernels' grid blocking or None, interpret) — one algorithm,
+    two lowerings, chosen by what the call can observe: the Mosaic kernels
+    of ops/pallas/state_space.py on a TPU with one device (no partitioning
+    rule for the call yet), for shapes they take and a working set inside
+    ``vmem.budget()``; else (None) the XLA chunked form below.
+    ``interpret=True`` runs the kernels in interpret mode wherever the
+    shapes allow."""
+    from deepspeed_tpu.ops.pallas import state_space as kernels
+    if interpret is False or not kernels.supported(P, N, r, C):
+        return None, False
+    blocking = kernels.chunks_per_step(n, C, r, P, N,
+                                       jnp.dtype(dtype).itemsize)
+    if interpret:
+        return blocking, True
+    from deepspeed_tpu.ops.attention import _on_tpu
+    on_one_tpu = _on_tpu() and jax.device_count() == 1
+    fits = blocking.vmem_bytes <= kernels.vmem.budget()
+    return (blocking if on_one_tpu and fits else None), False
+
+
 def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
-             chunk: int = DEFAULT_CHUNK):
+             chunk: int = DEFAULT_CHUNK, interpret=None):
     """The recurrence of the module docstring for every head at once.
 
     ``x`` [b, S, H, P]; ``dt`` [b, S, H] (already softplus'd, > 0); ``A``
     [H] (< 0); ``B``, ``C`` [b, S, G, N] with ``H`` a multiple of ``G``;
     ``D`` [H] or None (no skip term); ``segment_ids`` [b, S] int or None.
     Returns ``y`` [b, S, H, P] in ``x``'s dtype.  Differentiable in ``x``,
-    ``dt``, ``A``, ``B``, ``C`` and ``D``."""
+    ``dt``, ``A``, ``B``, ``C`` and ``D``.  ``interpret``: None chooses the
+    lowering (:func:`_kernel_blocking`), True runs the kernels in
+    interpret mode, False the XLA form."""
     b, S, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     dtype = x.dtype
@@ -76,12 +108,11 @@ def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
     n = -(-S // Cn)
     pad = n * Cn - S
     f32 = lambda t: t.astype(jnp.float32)
-    dot = lambda spec, u, v: jnp.einsum(
-        spec, u, v, preferred_element_type=jnp.float32)
+    blocking, interpret = _kernel_blocking(interpret, n, Cn, H // G, P, N,
+                                           dtype)
     dt, A = f32(dt), f32(A)
     seg = (jnp.zeros((b, S), jnp.int32) if segment_ids is None
            else segment_ids.astype(jnp.int32))
-    x_in = x
     if pad:
         # tokens of step 0: they decay nothing and write nothing, and
         # belong to the last document
@@ -89,15 +120,39 @@ def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
                                  + ((0, 0),) * (t.ndim - 2))
         x, dt, B, C = (tail(t) for t in (x, dt, B, C))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-    count_in_step(ssd_calls={f"{b}x{n * Cn}x{H}x{P}x{N}": {
-        "chunks": n, "chunk_len": Cn, "batch": b, "heads": H, "groups": G,
-        "head_dim": P, "state": N, "path": "xla"}})
+    B, C = B.astype(dtype), C.astype(dtype)
+    row = {"chunks": n, "chunk_len": Cn, "batch": b, "heads": H, "groups": G,
+           "head_dim": P, "state": N,
+           "path": "xla" if blocking is None else "kernel"}
+    if blocking is not None:
+        from deepspeed_tpu.ops.pallas.state_space import ssd_kernels
+        row.update(heads_per_step=blocking.heads,
+                   chunks_per_step=blocking.chunks)
+        y = ssd_kernels(x, dt, A, B, C, D, seg, blocking, interpret)
+    else:
+        y = _chunked_xla(x, dt, A, B, C, D, seg, n, Cn)
+    count_in_step(ssd_calls={f"{b}x{n * Cn}x{H}x{P}x{N}": row})
+    return y[:, :S]
+
+
+def _chunked_xla(x, dt, A, B, C, D, seg, n, Cn):
+    """The chunked form as XLA einsums around a ``lax.scan``, the backward
+    autodiff's: the fallback and, beside :func:`ssd_recurrent`, the
+    kernels' oracle.  Arguments as :func:`ssd_scan` prepared them (``n``
+    chunks of ``Cn`` tokens, float32 ``dt`` and ``A``, ``B`` and ``C`` in
+    ``x``'s dtype); returns ``y`` [b, n * Cn, H, P] in ``x``'s dtype."""
+    b, _, H, P = x.shape
+    G, N = B.shape[2], B.shape[3]
+    dtype = x.dtype
+    f32 = lambda t: t.astype(jnp.float32)
+    dot = lambda spec, u, v: jnp.einsum(
+        spec, u, v, preferred_element_type=jnp.float32)
 
     # g = group, r = the heads it serves, i/j = positions, p, s = the
     # state's two dims
     xc = _chunked(x, n, Cn, G)                               # [n,b,g,r,C,P]
     dtc = _chunked(dt, n, Cn, G)                             # [n,b,g,r,C]
-    Bc, Cc = (_chunked(t.astype(dtype), n, Cn, G)[:, :, :, 0]
+    Bc, Cc = (_chunked(t, n, Cn, G)[:, :, :, 0]
               for t in (B, C))                               # [n,b,g,C,N]
     sc = seg.reshape(b, n, Cn).transpose(1, 0, 2)            # [n, b, C]
     # the document the previous chunk ended in (chunk 0: no state yet)
@@ -131,9 +186,9 @@ def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
     y = y + from_state[..., None] * dot(
         "nbgis,nbgrps->nbgrip", Cc, incoming.astype(dtype))
     y = jnp.moveaxis(y, 0, 1)                                # [b,n,g,r,C,P]
-    y = jnp.moveaxis(y, 4, 2).reshape(b, n * Cn, H, P)[:, :S]
+    y = jnp.moveaxis(y, 4, 2).reshape(b, n * Cn, H, P)
     if D is not None:
-        y = y + f32(D)[:, None] * f32(x_in)
+        y = y + f32(D)[:, None] * f32(x)
     return y.astype(dtype)
 
 

@@ -324,10 +324,11 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
-#: on a transposed right-hand side) and dw, and the gated delta rule's two
+#: on a transposed right-hand side) and dw, the gated delta rule's two and
+#: the state-space scan's two
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
-                "ds_gdr_fwd", "ds_gdr_bwd")
+                "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -665,9 +666,12 @@ def _account_rows(name: str, counter: str):
 def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     """The state-space scans of the step as ops/state_space.py traced
     them: one row per shape — ``chunks`` and ``chunk_len`` of the scan,
-    ``batch``, ``heads``, ``groups``, ``head_dim``, ``state`` and ``path``
-    (``"xla"``: the chunked form as XLA einsums around a ``lax.scan``; no
-    kernel yet).  None where the step has no such call."""
+    ``batch``, ``heads``, ``groups``, ``head_dim``, ``state`` and ``path``:
+    ``"kernel"`` where the call ran as the Mosaic kernels ``ds_ssd_fwd`` /
+    ``ds_ssd_bwd`` (then also ``heads_per_step`` and ``chunks_per_step``,
+    the heads — one group's — and chunks one grid step takes), ``"xla"``
+    where it fell back to the chunked form as XLA einsums around a
+    ``lax.scan``.  None where the step has no such call."""
     return _account_rows(name, "ssd_calls")
 
 

@@ -1,8 +1,9 @@
 """Compiles for a TPU v5e that is described, not attached (the chip's
 compiler is installed on CPU-only boxes): the training path's kernels at
 GPT-2 760M width, the grouped GEMM kernels at OLMoE-1B-7B's (their
-weight panels resident in VMEM) and at Mixtral-8x7B's, and the gated delta
-rule's two at Qwen3-Next's go through Mosaic, the data-sharded flash kernel
+weight panels resident in VMEM) and at Mixtral-8x7B's, the gated delta
+rule's two at Qwen3-Next's and the state-space scan's two at Nemotron-H's
+go through Mosaic, the data-sharded flash kernel
 goes through the partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
@@ -114,6 +115,17 @@ def _gdr(q, k, v, g, beta, seg):
                                         scales=(dk ** -0.5, 1.0))
 
 
+def _ssd(x, dt, A, Bm, Cm, D, seg):
+    """The state-space scan's kernels as ops/state_space.py calls them on
+    one TPU (the choice switched off: no TPU here), with the blocking the
+    library chooses."""
+    from deepspeed_tpu.ops.pallas import state_space as ssd
+    (b, S, H, P), (G, N) = x.shape, Bm.shape[2:]
+    blocking = ssd.chunks_per_step(S // 128, 128, H // G, P, N,
+                                   x.dtype.itemsize)
+    return ssd.ssd_kernels(x, dt, A, Bm, Cm, D, seg, blocking)
+
+
 def _ggemm_args(experts, rows, k, n):
     return [((rows, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
             ((rows // 128,), jnp.int32), ((1,), jnp.int32)]
@@ -152,6 +164,12 @@ _GGEMM_RELU2_DOWN = _ggemm_args(8, 24576 + 8 * 128, 1856, 2688)
 _QKV_GQA16_8K = [((2, 8192, 32, 128), jnp.bfloat16),
                  ((2, 8192, 2, 128), jnp.bfloat16),
                  ((2, 8192, 2, 128), jnp.bfloat16), ((2, 8192), jnp.int32)]
+# ... and its four Mamba-2 layers: 64 heads of 64 in 8 groups of state 128,
+# a group's state 128 x 512 float32, 64 chunks of 128
+_SSD_8K = [((2, 8192, 64, 64), jnp.bfloat16), ((2, 8192, 64), jnp.float32),
+           ((64,), jnp.float32), ((2, 8192, 8, 128), jnp.bfloat16),
+           ((2, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32),
+           ((2, 8192), jnp.int32)]
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -181,6 +199,9 @@ KERNEL_CASES = {
     "ds_gdr_s8192_packed_fwd": (_gdr, _GDR_8K),
     "ds_gdr_s8192_packed_fwd_bwd": (
         jax.grad(_sum_sq(_gdr), (0, 1, 2, 3, 4)), _GDR_8K),
+    "ds_ssd_s8192_packed_fwd": (_ssd, _SSD_8K),
+    "ds_ssd_s8192_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_8K),
     "stock_flash_fwd": (_stock_flash, _QKV),
     "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
                             _QKV),
@@ -221,6 +242,8 @@ NAMED_KERNELS = {
     "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_gdr_s8192_packed_fwd": {"ds_gdr_fwd"},
     "ds_gdr_s8192_packed_fwd_bwd": {"ds_gdr_fwd", "ds_gdr_bwd"},
+    "ds_ssd_s8192_packed_fwd": {"ds_ssd_fwd"},
+    "ds_ssd_s8192_packed_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
 }
 
 #: the regime each grouped kernel of a case takes (the step account's
@@ -340,7 +363,8 @@ def test_library_knows_the_chips_peaks(v5e):
 
 
 @pytest.mark.parametrize("script", [
-    "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1"])
+    "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1",
+    "scripts/ssd_table.py"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(
