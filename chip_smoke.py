@@ -33,6 +33,7 @@ import deepspeed_tpu
 from bench import train_config, train_flops_per_token
 from deepspeed_tpu.models.gpt2 import gpt2_model
 from deepspeed_tpu.ops.attention import flash_status
+from deepspeed_tpu.telemetry import tracing
 from deepspeed_tpu.telemetry.costmodel import get_report
 from deepspeed_tpu.telemetry.mfu import peak_flops_per_device
 from deepspeed_tpu.utils.compile_cache import enable_compile_cache
@@ -52,20 +53,17 @@ def check(cond, msg):
         raise AssertionError(msg)
 
 
-class CompileLog:
-    """Counts backend compilations (persistent-cache loads included) and
-    their seconds, from jax's own monitoring events."""
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.count = 0
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == self.EVENT:
-            self.count += 1
-            self.seconds += duration
+def backend_compiles(since=0.0):
+    """(count, seconds) of the backend compilations, persistent-cache
+    loads included, that ended after ``since`` (``time.perf_counter()``),
+    from the program's own account of what it traced, lowered and
+    compiled (``telemetry.tracing.setup_account``: jax's monitoring
+    events, one listener set in the program).  A folded row of unnamed
+    programs counts whole if its last event ended after ``since``."""
+    rows = [r for r in tracing.setup_account()["rows"]
+            if r["stage"] in ("compile", "cache_load") and r["end"] > since]
+    return (sum(r.get("count", 1) for r in rows),
+            sum(r["self_s"] for r in rows))
 
 
 def device_fields():
@@ -167,7 +165,7 @@ def phase_kernel_vs_reference(size="760m", num_layers=2, seq=1024, micro=12,
         flash_status={str(k): v for k, v in flash_status().items()})
 
 
-def phase_trainer(compiles, size="760m", seq=1024, micro=12, warmup=2,
+def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
                   steps=5, mosaic=True, **widths):
     """bench.py's configuration through initialize/train_batch: warm-up,
     then timed steps on a fixed seeded batch."""
@@ -178,8 +176,7 @@ def phase_trainer(compiles, size="760m", seq=1024, micro=12, warmup=2,
     model = gpt2_model(size, max_seq_len=seq, dtype="bfloat16", remat=True,
                        **widths)
     cfg = model.config
-    c0, s0 = compiles.count, compiles.seconds
-    t0 = time.perf_counter()
+    t_start = t0 = time.perf_counter()
     engine, *_ = deepspeed_tpu.initialize(
         model=model, config=train_config(micro, zero_stage=2))
     global_batch = micro * engine.topology.dp_world_size
@@ -190,15 +187,14 @@ def phase_trainer(compiles, size="760m", seq=1024, micro=12, warmup=2,
     losses = [engine.train_batch(batch=batch) for _ in range(warmup)]
     jax.block_until_ready(losses)
     warmup_s = time.perf_counter() - t0
-    compile_s = compiles.seconds - s0
+    setup_compiles, compile_s = backend_compiles(since=t_start)
     kernel_sites = require_flash(engine)
 
-    c1 = compiles.count
-    t0 = time.perf_counter()
+    t_timed = t0 = time.perf_counter()
     timed = [engine.train_batch(batch=batch) for _ in range(steps)]
     jax.block_until_ready(timed)
     step_s = (time.perf_counter() - t0) / steps
-    timed_compiles = compiles.count - c1
+    timed_compiles, _ = backend_compiles(since=t_timed)
     _, calls = kernel_calls(engine, batch, mosaic)
 
     losses = [float(x) for x in losses + timed]
@@ -210,7 +206,7 @@ def phase_trainer(compiles, size="760m", seq=1024, micro=12, warmup=2,
                "num_layers": cfg.num_layers, "d_model": cfg.d_model,
                "seq": seq, "micro": micro, "zero_stage": 2},
         setup={"init_s": init_s, "warmup_s": warmup_s,
-               "compile_s": compile_s, "compiles": c1 - c0},
+               "compile_s": compile_s, "compiles": setup_compiles},
         step_s=step_s, tokens_per_s_per_chip=tokens_per_s / n, mfu=mfu,
         peak_flops_per_chip=peak, timed_steps=steps,
         timed_compiles=timed_compiles, pallas_call_sites=kernel_sites,
@@ -322,12 +318,11 @@ def main():
                              "reference, nothing else")
     args = parser.parse_args()
     phase_device(args.chips, enable_compile_cache())
-    compiles = CompileLog()
     if args.chips == 4:
         phase_zero3_four_chips()
     else:
         phase_kernel_vs_reference()
-        phase_trainer(compiles)
+        phase_trainer()
     print(json.dumps({"ok": True, "device": device_fields()}), flush=True)
 
 

@@ -40,6 +40,7 @@ from deepspeed_tpu.runtime.zero.policy import ZeroShardingPolicy
 from deepspeed_tpu.runtime.fp16.loss_scaler import (
     create_loss_scaler, update_scale)
 from deepspeed_tpu.runtime.step_programs import tree_cast as _tree_cast
+from deepspeed_tpu.telemetry import tracing
 from deepspeed_tpu.telemetry.tracing import (
     TRAIN_STEP_PROGRAM, register_program)
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -96,7 +97,6 @@ class DeepSpeedEngine:
                  collate_fn=None,
                  mpu=None,
                  dont_change_device: bool = False):
-        # ---- topology first (batch math needs dp world size) ----------------
         raw = config
         if isinstance(raw, str):
             import json
@@ -104,6 +104,19 @@ class DeepSpeedEngine:
                 raw_dict = json.load(f)
         else:
             raw_dict = dict(raw)
+        # the tracer is armed before the first span, so that engine/init
+        # is in the DS_TRACE file too; the set-up account
+        # (telemetry/tracing.py) holds it either way
+        from deepspeed_tpu.telemetry import configure_tracer
+        configure_tracer((raw_dict.get("telemetry") or {}).get("trace"))
+        with tracing.setup_span(tracing.SPAN_ENGINE_INIT) as init_span:
+            self._init(init_span, raw_dict, model, optimizer,
+                       model_parameters, training_data, lr_scheduler, mesh,
+                       collate_fn, mpu)
+
+    def _init(self, init_span, raw_dict, model, optimizer, model_parameters,
+              training_data, lr_scheduler, mesh, collate_fn, mpu):
+        # ---- topology first (batch math needs dp world size) ----------------
         mesh_cfg = MeshConfig(**raw_dict.get("mesh", {}))
         zo_raw = raw_dict.get("zero_optimization", {})
         hpz_size = int(zo_raw.get("zero_hpz_partition_size", 1) or 1)
@@ -209,6 +222,7 @@ class DeepSpeedEngine:
         set_memory_config_default(self._config.telemetry_config.memory)
 
         # ---- ZeRO sharding policy -------------------------------------------
+        init_span.phase(tracing.SPAN_INIT_SHARDINGS)
         zc = self._config.zero_config
         self.zero_policy = ZeroShardingPolicy(
             stage=zc.stage, topology=self.topology,
@@ -330,6 +344,7 @@ class DeepSpeedEngine:
         self._nonblock_shardings = (
             {k: v for k, v in self.param_shardings.items() if k != bk_}
             if self._param_nvme else self.param_shardings)
+        init_span.phase(tracing.SPAN_INIT_PARAMS)
         if model_parameters is None:
             if self._offload_param:
                 # host-side init: params are *stored* in pinned host memory,
@@ -434,6 +449,7 @@ class DeepSpeedEngine:
             params, logical_eff)
 
         # ---- optimizer -------------------------------------------------------
+        init_span.phase(tracing.SPAN_INIT_OPTIMIZER)
         self.lr_schedule = None
         base_lr = float((self._config.optimizer_params or {}).get("lr", 1e-3))
         if self._config.scheduler_name:
@@ -624,6 +640,7 @@ class DeepSpeedEngine:
                                     out_shardings=self.opt_shardings)(params)
 
         # ---- loss scaling ----------------------------------------------------
+        init_span.phase(None)
         f = self._config.fp16
         scaler, self.scaler_config = create_loss_scaler(
             enabled=f.enabled, loss_scale=f.loss_scale,
@@ -1664,9 +1681,11 @@ class DeepSpeedEngine:
                           donate_argnums=(0, 1)),
             "zero_grads": dict(out_shardings=gos),
         }[program]
-        fn = jax.jit(
-            self._step_program(program, int(nf) if nf else None),
-            **placement)
+        traced = self._step_program(program, int(nf) if nf else None)
+        # jax reports what it traces, lowers and compiles by the function's
+        # name: the set-up account files it under the program's
+        tracing.name_program(traced.__name__, program)
+        fn = jax.jit(traced, **placement)
         self._compiled[key] = fn
         return fn
 
@@ -1868,9 +1887,10 @@ class DeepSpeedEngine:
                 span_args.update(cost_flops=rep.flops,
                                  cost_hbm_bytes=rep.hbm_bytes,
                                  cost_pallas_launches=rep.pallas_launches)
-        with self.tracer.span("train/step", cat="train",
-                              corr=f"train-step-{step}",
-                              args=span_args):
+        with tracing.setup_span(tracing.SPAN_TRAIN_STEP,
+                                step=self.global_steps, tracer=self.tracer,
+                                cat="train", corr=f"train-step-{step}",
+                                args=span_args):
             loss = self._train_batch_impl(data_iter=data_iter, batch=batch)
             # still inside the train/step span so an anomaly instant
             # lands between this step's B/E pair (the serve side keeps
@@ -2028,16 +2048,20 @@ class DeepSpeedEngine:
                 "train_step" if nf_group is None
                 else f"train_step@nf{nf_group}")
             rng = self._next_rng()
-            self._maybe_cost_report(batch, rng)
-            self._maybe_memory_report(batch, rng)
-            self._maybe_register_program_map(batch)
             # one fused program: fwd+bwd+apply dispatch together (the
             # per-phase split lives in the fwd/bwd/step timers when the
             # micro API drives them)
-            with self.tracer.span("train/fused_step", cat="train"), \
+            with tracing.setup_span(tracing.SPAN_FUSED_STEP,
+                                    tracer=self.tracer, cat="train"), \
                     self._train_scope(), self._ltd_scope(), \
                     self._aq_scope():
                 self.state, metrics = fn(self.state, batch, rng)
+            # the observers of the first step read shapes only, and come
+            # after its dispatch: the device works while the host walks
+            # the step once more, and jit's trace is the program's first
+            self._maybe_cost_report(batch, rng)
+            self._maybe_memory_report(batch, rng)
+            self._maybe_register_program_map(batch)
         self._finish_step(metrics)
         # syncing on the loss every step stalls the async dispatch
         # pipeline; only pay it when the user asked for wall-clock
@@ -2410,7 +2434,8 @@ class DeepSpeedEngine:
         try:
             from deepspeed_tpu.telemetry.costmodel import analyze_fn
             from deepspeed_tpu.telemetry.roofline import publish_report
-            with self._train_scope(), self._ltd_scope(), self._aq_scope():
+            with tracing.setup_span(tracing.SPAN_COST_ANALYZE), \
+                    self._train_scope(), self._ltd_scope(), self._aq_scope():
                 report = analyze_fn(
                     self._step_program("train_step"), self.state, batch, rng,
                     name="train/step",
@@ -2436,8 +2461,10 @@ class DeepSpeedEngine:
 
         def step_text():
             engine = alive()
-            return (None if engine is None
-                    else engine._compile_train_step(signature).as_text())
+            if engine is None:
+                return None
+            with tracing.setup_span(tracing.SPAN_PROGRAM_TEXT):
+                return engine._compile_train_step(signature).as_text()
         register_program(TRAIN_STEP_PROGRAM, step_text)
 
     def compile_train_step(self, batch):
@@ -2452,7 +2479,8 @@ class DeepSpeedEngine:
 
     def _compile_train_step(self, signature):
         fn = self._get_compiled("train_step")
-        with self._train_scope(), self._ltd_scope(), self._aq_scope():
+        with tracing.setup_span(tracing.SPAN_COMPILE_AOT), \
+                self._train_scope(), self._ltd_scope(), self._aq_scope():
             return fn.lower(
                 jax.tree.map(_abstract, self.state, self.state_shardings),
                 signature, _abstract(self._rng)).compile()
@@ -2473,7 +2501,8 @@ class DeepSpeedEngine:
         try:
             from deepspeed_tpu.telemetry.memory import (
                 compiled_memory_stats, get_memory_ledger)
-            with self._train_scope(), self._ltd_scope(), self._aq_scope():
+            with tracing.setup_span(tracing.SPAN_MEMORY_COMPILED), \
+                    self._train_scope(), self._ltd_scope(), self._aq_scope():
                 stats = compiled_memory_stats(
                     self._step_program("train_step"), self.state, batch, rng)
             if stats:

@@ -51,17 +51,32 @@ table between the two: instruction name -> its scope path, phase
 built **lazily**: the engine registers a thunk on the first fused
 dispatch, and the text of the executable is fetched and parsed only
 when someone first asks.
+
+**Before the first step** the program keeps an account of its own start
+(:func:`setup_account`), armed or not: the spans it opens at its own
+boundaries (:data:`SETUP_SPANS`, through :func:`setup_span` and so
+through the tracer above) and one row for every trace, lowering and
+backend compile that jax reports through ``jax.monitoring`` — by
+program, by stage (``trace`` / ``lower`` / ``compile`` /
+``cache_load``), by the span that caused it, on the tracer's clock.  The
+listeners run only when jax traces, lowers or compiles: a steady step
+calls none.
 """
 import atexit
+import itertools
 import json
 import os
 import re
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
+
+from deepspeed_tpu.telemetry.registry import get_registry
+from deepspeed_tpu.utils.logging import logger
 
 TRACE_ENV = "DS_TRACE"
 #: prefix of every host span in a profiler session (``/host:CPU``)
@@ -174,6 +189,19 @@ class SpanTracer:
         with TraceAnnotation(ANNOTATION_PREFIX + name):
             self._emit(ev)
 
+    def interval(self, name: str, start: float, end: float, cat: str = "",
+                 args: Optional[Dict] = None):
+        """A span that has already ended, from ``start`` to ``end`` on
+        this tracer's clock (``time.perf_counter()`` seconds): one
+        ``B``/``E`` pair on the calling thread.  For work that reports
+        itself when it is over (jax's compile events); it must lie inside
+        whatever span is open on this thread, as work done there does."""
+        corr = self.current_corr()
+        for ph, t in (("B", start), ("E", end)):
+            ev = self._event(ph, name, cat, corr, args if ph == "B" else None)
+            ev["ts"] = max((t - self._t0) * 1e6, 0.0)
+            self._emit(ev)
+
     # ------------------------------------------------------------- output
     def drain(self):
         """Snapshot + clear the buffer (sorted by ts); flush() callers
@@ -229,6 +257,9 @@ class _NullTracer:
         return TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def instant(self, *a, **kw):
+        pass
+
+    def interval(self, *a, **kw):
         pass
 
     def current_corr(self):
@@ -675,9 +706,419 @@ def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "ssd_calls")
 
 
+# ==================================================== where a start goes
+#: Fixed names of the host spans the program opens at its own boundaries
+#: before (and around) its first steps — part of the program's interface,
+#: beside :data:`STEP_SCOPES` and :data:`KERNEL_NAMES`: readers of the
+#: set-up account, of a profiler session (``ds/engine/init`` ...) and of
+#: the ``DS_TRACE`` file key on them.
+SPAN_ENGINE_INIT = "engine/init"            # DeepSpeedEngine.__init__, and in it
+SPAN_INIT_SHARDINGS = "engine/init/shardings"   # ... ZeRO policy, specs
+SPAN_INIT_PARAMS = "engine/init/params"     # ... parameters built and placed
+SPAN_INIT_OPTIMIZER = "engine/init/optimizer"   # ... optimizer and its state
+SPAN_TRAIN_STEP = "train/step"              # one train_batch call, and in it
+SPAN_FUSED_STEP = "train/fused_step"        # ... the fused program's call
+SPAN_COST_ANALYZE = "costmodel/analyze"     # the cost report's jaxpr walk
+SPAN_MEMORY_COMPILED = "memory/compiled"    # DS_MEM_COMPILED's extra compile
+SPAN_PROGRAM_TEXT = "program_map/text"      # get_program_map's first asker
+SPAN_COMPILE_AOT = "compile/aot"            # compile_train_step: lower + compile
+SETUP_SPANS = (SPAN_ENGINE_INIT, SPAN_INIT_SHARDINGS, SPAN_INIT_PARAMS,
+               SPAN_INIT_OPTIMIZER, SPAN_TRAIN_STEP, SPAN_FUSED_STEP,
+               SPAN_COST_ANALYZE, SPAN_MEMORY_COMPILED, SPAN_PROGRAM_TEXT,
+               SPAN_COMPILE_AOT)
+#: spans that look at a program and do not run it: what they trace, lower
+#: or compile is no recompile of it
+OBSERVER_SPANS = (SPAN_COST_ANALYZE, SPAN_MEMORY_COMPILED, SPAN_PROGRAM_TEXT,
+                  SPAN_COMPILE_AOT)
+#: the stages of a row: jax traced the function, lowered it to MLIR, and
+#: the backend compiled it (``compile``) or the persistent cache had it
+#: (``cache_load``)
+STAGES = ("trace", "lower", "compile", "cache_load")
+#: a per-step span is kept in the account when it began at one of an
+#: engine's first steps (a benchmark's warm-up and its first timed step)
+#: or when jax traced, lowered or compiled inside it
+SETUP_STEPS_KEPT = 32
+_PER_STEP_SPANS = (SPAN_TRAIN_STEP, SPAN_FUSED_STEP)
+
+_JAX_STAGE = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+              "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")     # jit(train_step) -> train_step
+
+
+class _OpenSpan:
+    __slots__ = ("id", "name", "start", "parent", "step", "child_s", "keep")
+
+    def __init__(self, id_, name, start, parent, step):
+        self.id, self.name, self.start = id_, name, start
+        self.parent, self.step = parent, step
+        self.child_s, self.keep = 0.0, False
+
+
+class _OpenEvent:
+    __slots__ = ("event", "program", "cause", "child_s", "retrace",
+                 "recompile", "hit", "missed", "seconds")
+
+    def __init__(self, event, program, cause):
+        self.event, self.program, self.cause = event, program, cause
+        self.child_s = 0.0
+        self.retrace = self.recompile = self.hit = self.missed = False
+        self.seconds = {}
+
+
+class SetupAccount:
+    """The process's account of its own start; see :func:`setup_account`
+    for what it holds.  One per process (:func:`reset_programs` drops it
+    and its listeners).  Spans and jax's events nest by thread: each
+    thread has a stack of its open spans and one of the jax events that
+    have begun on it and not ended."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self._ids = itertools.count()
+        self._wall_offset = None
+        self.spans: List[Dict[str, Any]] = []
+        self.rows: List[Dict[str, Any]] = []
+        self.other: Dict[tuple, Dict[str, Any]] = {}
+        self.names: Dict[str, str] = {}     # jax's fun_name -> program
+        self.traced = set()                 # programs, since the last init
+        self.ran = set()                    # ... compiled or loaded by a call
+        self.warned = set()
+        self.last_s: Dict[str, Dict[str, float]] = {}
+        self.steps = 0
+        self._listeners = (
+            (jax.monitoring.register_scalar_listener,
+             jax.monitoring.unregister_scalar_listener, self._on_begin),
+            (jax.monitoring.register_event_time_span_listener,
+             jax.monitoring.unregister_event_time_span_listener,
+             self._on_end),
+            (jax.monitoring.register_event_listener,
+             jax.monitoring.unregister_event_listener, self._on_cache),
+            (jax.monitoring.register_event_duration_secs_listener,
+             jax.monitoring.unregister_event_duration_listener,
+             self._on_cache_seconds))
+
+    def listen(self):
+        for register, _, listener in self._listeners:
+            register(listener)
+
+    def unlisten(self):
+        for _, unregister, listener in self._listeners:
+            unregister(listener)
+
+    def _thread(self):
+        t = self._tls
+        if not hasattr(t, "spans"):
+            t.spans, t.events = [], []
+        return t
+
+    # -------------------------------------------------------------- spans
+    def open_span(self, name: str, step: Optional[int]) -> _OpenSpan:
+        t = self._thread()
+        parent = t.spans[-1] if t.spans else None
+        if name == SPAN_ENGINE_INIT:
+            # a new engine's programs are new programs
+            self.traced.clear()
+            self.ran.clear()
+            self.steps = step = 0
+        elif step is None:
+            step = parent.step if parent is not None else self.steps
+        elif name == SPAN_TRAIN_STEP:
+            self.steps = step + 1
+        span = _OpenSpan(next(self._ids), name, time.perf_counter(), parent,
+                         step)
+        t.spans.append(span)
+        return span
+
+    def close_span(self, span: _OpenSpan):
+        end = time.perf_counter()
+        spans = self._thread().spans
+        if spans and spans[-1] is span:     # not so after reset_programs
+            spans.pop()
+        if span.parent is not None:
+            span.parent.child_s += end - span.start
+        if not (span.keep or span.name not in _PER_STEP_SPANS
+                or span.step < SETUP_STEPS_KEPT):
+            return
+        if span.parent is not None:
+            span.parent.keep = True
+        self.spans.append({
+            "id": span.id, "name": span.name, "start": span.start,
+            "end": end, "step": span.step,
+            "parent": None if span.parent is None else span.parent.id,
+            "self_s": end - span.start - span.child_s})
+
+    # ------------------------------------------------------- jax's events
+    def _open_event(self, t, event: str, fun_name: str) -> _OpenEvent:
+        inner = _WRAPPED.match(fun_name)
+        outer = t.events[-1] if t.events else None
+        # the engine's programs are jitted at top level: a function of the
+        # same name traced inside another's trace is not one of them
+        program = None if outer is not None else self.names.get(
+            inner.group(1) if inner else fun_name)
+        ev = _OpenEvent(event, program, t.spans[-1] if t.spans else None)
+        observed = ev.cause is not None and ev.cause.name in OBSERVER_SPANS
+        if _JAX_STAGE[event] == "trace":
+            if outer is not None and _JAX_STAGE[outer.event] == "trace":
+                ev.retrace = outer.retrace
+            elif program is not None:
+                ev.retrace = program in self.traced
+                self.traced.add(program)
+        ev.recompile = (program is not None and program in self.ran
+                        and not observed)
+        return ev
+
+    def _on_begin(self, event, _value, fun_name="", **_):
+        if event in _JAX_STAGE:
+            t = self._thread()
+            t.events.append(self._open_event(t, event, fun_name))
+
+    def _on_cache(self, event, **_):
+        events = self._thread().events
+        if event == _CACHE_HIT:
+            get_registry().inc("compile/cache_hits")
+            if events:
+                events[-1].hit = True
+        elif event == _CACHE_MISS:
+            get_registry().inc("compile/cache_misses")
+            if events:
+                events[-1].missed = True
+
+    def _on_cache_seconds(self, event, seconds, **_):
+        key = _CACHE_SECONDS.get(event)
+        events = self._thread().events if key else None
+        if events:
+            events[-1].seconds[key] = seconds
+
+    def _wall_to_clock(self) -> float:
+        """jax stamps its events with ``time.time()``; the account's clock
+        is the tracer's (``time.perf_counter()``): one offset, taken once
+        and again only if the wall clock has been stepped."""
+        offset = time.perf_counter() - time.time()
+        if self._wall_offset is None \
+                or abs(offset - self._wall_offset) > 1e-3:
+            self._wall_offset = offset
+        return self._wall_offset
+
+    def _on_end(self, event, start, end, fun_name="", **_):
+        stage = _JAX_STAGE.get(event)
+        if stage is None:
+            return
+        t = self._thread()
+        if t.events and t.events[-1].event == event:
+            ev = t.events.pop()
+        else:                         # its beginning was not reported
+            ev = self._open_event(t, event, fun_name)
+        offset = self._wall_to_clock()
+        start, end = start + offset, end + offset
+        cause = ev.cause
+        if cause is not None:
+            start = max(start, cause.start)
+        seconds = end - start
+        if t.events:
+            t.events[-1].child_s += seconds
+        elif cause is not None:
+            cause.child_s += seconds
+        for span in t.spans:
+            span.keep = True
+        if stage == "compile" and ev.hit:
+            stage = "cache_load"
+        row = {"program": ev.program or "other", "stage": stage,
+               "start": start, "end": end,
+               "self_s": seconds - ev.child_s,
+               "cause": None if cause is None else cause.name,
+               "span": None if cause is None else cause.id,
+               "step": self.steps if cause is None else cause.step,
+               "retrace": ev.retrace, "recompile": ev.recompile,
+               "missed": int(ev.missed), **ev.seconds}
+        with self._lock:
+            if ev.program is None:
+                self._fold(row)
+            else:
+                self.rows.append(row)
+        if ev.program is not None:
+            self._named_row_ended(ev, row, seconds)
+        tracer = get_tracer()
+        if tracer.enabled:
+            tracer.interval(
+                "setup/" + stage, start, end, cat="setup",
+                args={"program": row["program"], "fun_name": fun_name,
+                      "retrace": ev.retrace, "recompile": ev.recompile})
+
+    def _fold(self, row):
+        """Events of functions the engine did not name (eager ops'
+        one-primitive programs, the caller's own code) are one row per
+        stage and causing span: a count and seconds.  Bounded by the
+        spans kept, not by the events."""
+        key = (row["stage"], row["span"], row["retrace"])
+        have = self.other.get(key)
+        if have is None:
+            self.other[key] = {**row, "count": 1}
+            return
+        have["count"] += 1
+        have["end"] = row["end"]
+        for name in ("self_s", "missed", *_CACHE_SECONDS.values()):
+            if name in row:
+                have[name] = have.get(name, 0) + row[name]
+
+    def _named_row_ended(self, ev, row, seconds):
+        program, stage = row["program"], row["stage"]
+        if stage in ("trace", "lower"):
+            self.last_s.setdefault(program, {})[stage] = seconds
+            return
+        if row["cause"] not in OBSERVER_SPANS:
+            self.ran.add(program)
+        if not ev.recompile:
+            return
+        # the in-program form of a benchmark's "no compile inside the
+        # window": a program that had run was traced and compiled again
+        get_registry().inc("compile/recompiles")
+        if program not in self.warned:
+            self.warned.add(program)
+            last = self.last_s.get(program, {})
+            logger.warning(
+                f"recompile: {program} at step {row['step']} (a new shape, "
+                f"placement or static argument): trace "
+                f"{last.get('trace', 0.0):.3f} s, lower "
+                f"{last.get('lower', 0.0):.3f} s, {stage} {seconds:.3f} s; "
+                f"further recompiles of it only count "
+                f"(compile/recompiles)")
+
+    # -------------------------------------------------------------- output
+    def snapshot(self) -> Dict[str, Any]:
+        with self._lock:
+            rows = [dict(r) for r in self.rows] \
+                + [dict(r) for r in self.other.values()]
+            spans = [dict(s) for s in self.spans]
+        rows.sort(key=lambda r: r["start"])
+        spans.sort(key=lambda s: s["start"])
+        return {"spans": spans, "rows": rows, "steps": self.steps}
+
+
+_SETUP: Optional[SetupAccount] = None
+
+
+def _setup() -> SetupAccount:
+    global _SETUP
+    account = _SETUP
+    if account is None:
+        with _PROGRAM_LOCK:
+            if _SETUP is None:
+                _SETUP = SetupAccount()
+                _SETUP.listen()
+            account = _SETUP
+    return account
+
+
+class setup_span:
+    """``with setup_span(name):`` — a span of :data:`SETUP_SPANS`: the
+    tracer's span of that name (a ``ds/<name>`` TraceAnnotation in any
+    profiler session, a ``B``/``E`` pair of the ``DS_TRACE`` file) and,
+    armed or not, a span of the set-up account.  ``step`` is the
+    optimizer step count at which it begins (a ``train/step`` says its
+    own; anything else inherits its parent's).  ``tracer`` defaults to
+    the active one; other keywords go to its ``span`` (``cat`` defaults
+    to ``"setup"``).
+
+    ``span.phase(name)`` ends the child span opened by the last call, if
+    any, and opens the child ``name`` (None: none) — for a long body
+    whose parts follow one another."""
+    __slots__ = ("name", "step", "tracer", "kw", "_account", "_tracer_span",
+                 "_open", "_phase")
+
+    def __init__(self, name: str, step: Optional[int] = None, tracer=None,
+                 **kw):
+        kw.setdefault("cat", "setup")
+        self.name, self.step, self.tracer, self.kw = name, step, tracer, kw
+        self._phase = None
+
+    def __enter__(self):
+        self._account = _setup()
+        self._tracer_span = (self.tracer or get_tracer()).span(
+            self.name, **self.kw)
+        self._tracer_span.__enter__()
+        self._open = self._account.open_span(self.name, self.step)
+        return self
+
+    def phase(self, name: Optional[str]):
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+        self._phase = None
+        if name is not None:
+            self._phase = setup_span(name, tracer=self.tracer).__enter__()
+
+    def __exit__(self, *exc):
+        self.phase(None)
+        self._account.close_span(self._open)
+        return self._tracer_span.__exit__(*exc)
+
+
+def name_program(fun_name: str, program: str):
+    """The engine jits ``program`` (a name ``_get_compiled`` knows) from a
+    function jax will report as ``fun_name``: its traces, lowerings and
+    compiles get rows of their own in the set-up account."""
+    _setup().names[fun_name] = program
+
+
+def setup_account() -> Dict[str, Any]:
+    """Where this process's start went, kept in memory whether or not a
+    tracer is armed — set-up is tens of rows a process, not rows a step.
+    ``{"spans": [...], "rows": [...], "steps": n}``, every time in
+    seconds on the tracer's clock (``time.perf_counter()``), sorted by
+    ``start``; ``steps`` is how many ``train/step`` spans have begun.
+
+    **spans** the program opened through :func:`setup_span` and has
+    closed (:data:`SETUP_SPANS`): ``id``, ``name``, ``start``, ``end``,
+    ``parent`` (the id of the span it was opened in, on the same thread,
+    or None), ``step`` (the optimizer step count at which it began) and
+    ``self_s`` — its duration less what its child spans and the rows
+    directly under it cover.  A ``train/step`` or ``train/fused_step`` is
+    kept only from a step under :data:`SETUP_STEPS_KEPT` or if jax
+    traced, lowered or compiled inside it.
+
+    **rows**, one per trace, lowering and backend compile that jax
+    reported (``jax.monitoring``; a steady call of a compiled program
+    reports nothing): ``program`` — the engine's name for the function
+    (``train_step``, ``grad``, ``apply`` ...: :func:`name_program`) or
+    ``"other"``; ``stage`` of :data:`STAGES` (a backend compile in which
+    the persistent cache reported a hit is a ``cache_load`` and carries
+    the cache's ``retrieval_s`` and ``saved_s``; ``missed`` counts the
+    compiles that were written to the cache as new entries); ``start``,
+    ``end``; ``self_s`` — the duration less the events nested in it on
+    the same thread (a jitted function traced inside another's trace,
+    an eager op compiled while a program is traced), so that self times
+    add up and durations do not; ``cause`` and ``span`` — the name and id
+    of the innermost span open on that thread when it began (None:
+    outside every span of the program — the caller's own code); ``step``;
+    ``retrace`` — a trace of a program this engine had traced before, or
+    anything traced inside one; ``recompile`` — the program had already
+    been compiled or loaded by a call, and no observer span
+    (:data:`OBSERVER_SPANS`) caused this (a ``trace`` row of microseconds
+    with no ``lower`` after it is jit's Python path finding its own
+    caches warm: jax reports that too, and nothing was compiled).  Rows of
+    ``"other"`` are folded
+    by stage and causing span and carry a ``count``; ``start`` is then
+    the first event's and ``end`` the last's."""
+    account = _SETUP
+    if account is None:
+        return {"spans": [], "rows": [], "steps": 0}
+    return account.snapshot()
+
+
 def reset_programs():
-    """Tests: forget every registered program."""
+    """Tests: forget every registered program, and the set-up account
+    with its listeners."""
+    global _SETUP
     with _PROGRAM_LOCK:
         _PROGRAM_THUNKS.clear()
         _PROGRAM_MAPS.clear()
+        account, _SETUP = _SETUP, None
+    if account is not None:
+        account.unlisten()
     _STEP_COUNTERS.clear()

@@ -119,6 +119,14 @@ METRICS = {
     "train/mfu": "model FLOPs utilization vs device peak",
     "train/profiled_flops_per_s": "flops-profiler measured FLOP/s",
     "train/profiled_mfu": "flops-profiler measured MFU",
+    # --- compiles, from jax's own events (telemetry/tracing.py)
+    "compile/recompiles": "backend compiles (cache loads included) of a "
+                          "step program that had already run: a new "
+                          "shape, placement or static argument; the first "
+                          "of each program is logged with its step",
+    "compile/cache_hits": "persistent compile cache hits",
+    "compile/cache_misses": "compiles written to the persistent compile "
+                            "cache as new entries",
     # --- checkpointing
     "ckpt/saves": "checkpoint publishes (sync + async)",
     "ckpt/restores": "checkpoint restores",
