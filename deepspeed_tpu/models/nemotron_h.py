@@ -274,17 +274,24 @@ def _ssm_mixer(x, layer, config: NemotronHConfig, segment_ids):
     with jax.named_scope(SCOPE_IN_PROJ):
         h = _rms_norm(x, layer["norm"], config.norm_eps)
         zxbcdt = qdot(h, layer["w_in"])
-        z, xbc = zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + conv_ch]
+        z = zxbcdt[..., :d_in]
         dt = jax.nn.softplus(f32(zxbcdt[..., d_in + conv_ch:])
                              + f32(layer["dt_bias"]))
         A = -jnp.exp(f32(layer["A_log"]))
     with jax.named_scope(SCOPE_CONV):
-        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], segment_ids)
-                          + layer["conv_b"].astype(xbc.dtype))
+        # x, B and C each from the projection itself and as the array the
+        # scan takes, positions along lanes: how XLA lays this layer's
+        # arrays out by itself, and how the scan's kernels read them
+        xs, Bs, Cs = (
+            causal_conv(zxbcdt, layer["conv_w"][:, first:first + width],
+                        segment_ids, bias=layer["conv_b"][first:first + width],
+                        activation="silu", positions="lanes",
+                        first_channel=d_in + first)
+            for first, width in ((0, d_in), (d_in, G * N),
+                                 (d_in + G * N, G * N)))
     with jax.named_scope(SCOPE_SCAN):
-        y = ssd_scan(xbc[..., :d_in].reshape(B, S, Hm, Pd), dt, A,
-                     xbc[..., d_in:d_in + G * N].reshape(B, S, G, N),
-                     xbc[..., d_in + G * N:].reshape(B, S, G, N),
+        y = ssd_scan(xs.reshape(B, S, Hm, Pd), dt, A,
+                     Bs.reshape(B, S, G, N), Cs.reshape(B, S, G, N),
                      layer["D"], segment_ids, chunk=config.chunk_size)
     y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
     with jax.named_scope(SCOPE_GATE_NORM):

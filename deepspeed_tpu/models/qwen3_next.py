@@ -266,16 +266,21 @@ def _linear_mixer(x, layer, config: Qwen3NextConfig, segment_ids):
         h = _norm(x, layer["attn_norm"], config.rms_norm_eps)
         qkvz = qdot(h, layer["w_qkvz"])
         ba = qdot(h, layer["w_ba"]).astype(jnp.float32)
-        qkv, z = qkvz[..., :conv_ch], qkvz[..., conv_ch:]
+        z = qkvz[..., conv_ch:]
         beta = jax.nn.sigmoid(ba[..., :Hv])
         g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., Hv:] + layer["dt_bias"].astype(jnp.float32))
     with jax.named_scope(SCOPE_CONV):
-        qkv = jax.nn.silu(causal_conv(qkv, layer["conv_w"], segment_ids))
+        # q, k and v each from the projection itself and as the array the
+        # delta rule takes: no slice of [B, S, conv_ch] before or after
+        q, k, v = (
+            causal_conv(qkvz, layer["conv_w"][:, first:first + width],
+                        segment_ids, activation="silu", first_channel=first)
+            for first, width in ((0, Hk * dk), (Hk * dk, Hk * dk),
+                                 (2 * Hk * dk, Hv * dv)))
     with jax.named_scope(SCOPE_DELTA_RULE):
-        q = qkv[..., :Hk * dk].reshape(B, S, Hk, dk)
-        k = qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
-        v = qkv[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
+        q, k = q.reshape(B, S, Hk, dk), k.reshape(B, S, Hk, dk)
+        v = v.reshape(B, S, Hv, dv)
         o = gated_delta_rule(q, k, v, g, beta, segment_ids,
                              chunk=config.delta_rule_chunk,
                              l2norm_scales=(dk ** -0.5, 1.0))

@@ -42,16 +42,21 @@ the last chunk ended in.  Boundaries may fall anywhere — inside a chunk,
 at its edge, around a one-token document.  Where ``chunk`` does not divide
 the sequence, the tail is padded with tokens that write nothing.
 
-:func:`causal_conv` is the depthwise causal convolution (width 4 in
-Qwen3-Next, no bias) with the same reset: a tap that would reach into the
-previous document reads zero.
+:func:`causal_conv` is the depthwise causal convolution (width 4 in both
+hybrids; Nemotron-H's with a bias) with the same reset — a tap that would
+reach into the previous document reads zero — and with the bias and the
+``silu`` both models apply straight after it.  It too has two lowerings
+(:func:`_conv_blocking`): on one TPU the Mosaic kernels of
+ops/pallas/causal_conv.py (each row read once; taps, reset, bias and
+``silu`` in float32 registers; the backward by hand), in the orientation
+the caller names; elsewhere :func:`_causal_conv_xla`, shifted copies with
+autodiff's backward.
 
-The convolution is plain XLA.  Their parts of a step carry the
-``jax.named_scope``s ``conv`` and ``delta_rule`` (telemetry/tracing.py
-``STEP_SCOPES``), written by the model, and each call of the delta rule
-leaves its chunk count, its chunk length and the lowering it took
-(``path``, with the kernels' grid blocking) in the step's account
-(``tracing.delta_rule_chunks``).
+Their parts of a step carry the ``jax.named_scope``s ``conv`` and
+``delta_rule`` (telemetry/tracing.py ``STEP_SCOPES``), written by the
+model, and each call leaves its shape and the lowering it took (``path``,
+with the kernels' grid blocking) in the step's account
+(``tracing.delta_rule_chunks``, ``tracing.conv_calls``).
 """
 import jax
 import jax.numpy as jnp
@@ -69,11 +74,80 @@ def l2norm(x, eps: float = 1e-6):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def causal_conv(x, w, segment_ids=None):
-    """Depthwise causal convolution.  ``x`` [B, S, C], ``w`` [K, C] (tap
+def _fits_one_tpu(blocking, vmem) -> bool:
+    """What a call can observe before it takes Mosaic kernels: a TPU, one
+    device (no partitioning rule for the calls yet) and a working set
+    inside the device kind's budget."""
+    from deepspeed_tpu.ops.attention import _on_tpu
+    return (_on_tpu() and jax.device_count() == 1
+            and blocking.vmem_bytes <= vmem.budget())
+
+
+def _conv_blocking(interpret, S, C, K, dtype, positions, first):
+    """(the convolution kernels' blocking or None, interpret), chosen as
+    :func:`_kernel_blocking` chooses: the Mosaic kernels of
+    ops/pallas/causal_conv.py on a TPU with one device (no partitioning
+    rule for the call yet), for shapes they take and a working set inside
+    ``vmem.budget()``; else (None) the XLA form."""
+    from deepspeed_tpu.ops.pallas import causal_conv as kernels
+    if interpret is False or not kernels.supported(S, C, K, positions,
+                                                   first):
+        return None, False
+    blocking = kernels.slab_width(S, C, jnp.dtype(dtype).itemsize, positions,
+                                  first)
+    if interpret:
+        return blocking, True
+    return (blocking if _fits_one_tpu(blocking, kernels.vmem) else None), \
+        False
+
+
+def causal_conv(x, w, segment_ids=None, bias=None, activation=None,
+                positions="sublanes", *, first_channel=0, interpret=None):
+    """Depthwise causal convolution.  ``x`` [B, S, Cx], ``w`` [K, C] (tap
     ``K-1`` multiplies the current token, tap 0 the one ``K-1`` back):
-    ``y_t = sum_j w[j] * x_{t-(K-1)+j}``, a tap before the sequence's
-    start or, with ``segment_ids`` [B, S], in another document reading 0."""
+    ``y_t = act(bias + sum_j w[j] * x_{t-(K-1)+j})`` [B, S, C], a tap
+    before the sequence's start or, with ``segment_ids`` [B, S], in another
+    document reading 0; ``bias`` [C] or None, ``activation`` None or
+    ``"silu"``.  ``x`` may be wider than ``w``: the convolution takes its
+    channels ``first_channel`` to ``first_channel + C`` (a caller hands
+    over the projection it would slice, and the kernels read the part by
+    their blocks' index).
+
+    ``positions``: which axis of the kernels' slabs holds positions —
+    ``"sublanes"`` (slabs of ``x`` as it is, channels along lanes) or
+    ``"lanes"`` (slabs of ``x`` with its last two axes swapped: for a
+    caller whose array the compiler lays out positions-minor, and whose
+    next kernel takes it so).  The result is the same.  ``interpret``: None
+    chooses the lowering (:func:`_conv_blocking`), True runs the kernels in
+    interpret mode, False the XLA form."""
+    if activation not in (None, "silu"):
+        raise ValueError(f"causal_conv: activation {activation!r}")
+    B, S, _ = x.shape
+    K, C = w.shape
+    blocking, interpret = _conv_blocking(interpret, S, C, K, x.dtype,
+                                         positions, first_channel)
+    row = {"batch": B, "positions": S, "channels": C, "taps": K,
+           "orientation": positions,
+           "path": "xla" if blocking is None else "kernel"}
+    if blocking is not None:
+        from deepspeed_tpu.ops.pallas.causal_conv import causal_conv_kernels
+        row.update(slab=blocking.slab, tile=blocking.tile)
+        y = causal_conv_kernels(x, w, segment_ids, bias, activation,
+                                blocking, first_channel, interpret)
+    else:
+        y = _causal_conv_xla(x[..., first_channel:first_channel + C], w,
+                             segment_ids)
+        if bias is not None:
+            y = y + bias.astype(x.dtype)
+        if activation is not None:
+            y = jax.nn.silu(y)
+    count_in_step(conv_calls={f"{B}x{S}x{C}x{K}x{positions}": row})
+    return y
+
+
+def _causal_conv_xla(x, w, segment_ids):
+    """The taps as shifted copies of ``x``, multiplied and added in
+    ``x``'s dtype: the fallback and the kernels' oracle."""
     K = w.shape[0]
     S = x.shape[1]
     w = w.astype(x.dtype)
@@ -109,10 +183,7 @@ def _kernel_blocking(interpret, n, C, rep, dk, dv, dt):
     blocking = gdr.chunks_per_step(n, C, rep, dk, dv, jnp.dtype(dt).itemsize)
     if interpret:
         return blocking, True
-    from deepspeed_tpu.ops.attention import _on_tpu
-    on_one_tpu = _on_tpu() and jax.device_count() == 1
-    fits = blocking.vmem_bytes <= gdr.vmem.budget()
-    return (blocking if on_one_tpu and fits else None), False
+    return (blocking if _fits_one_tpu(blocking, gdr.vmem) else None), False
 
 
 def gated_delta_rule(q, k, v, g, beta, segment_ids=None,

@@ -355,11 +355,12 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
-#: on a transposed right-hand side) and dw, the gated delta rule's two and
-#: the state-space scan's two
+#: on a transposed right-hand side) and dw, the gated delta rule's two,
+#: the state-space scan's two and the short causal convolution's two
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
-                "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd")
+                "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd",
+                "ds_conv_fwd", "ds_conv_bwd")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -704,6 +705,20 @@ def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     where it fell back to the chunked form as XLA einsums around a
     ``lax.scan``.  None where the step has no such call."""
     return _account_rows(name, "ssd_calls")
+
+
+def conv_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The short causal convolutions of the step as
+    ops/linear_attention.py ``causal_conv`` traced them: one row per shape
+    and orientation — ``batch``, ``positions``, ``channels``, ``taps``,
+    ``orientation`` (which axis of the kernels' slabs holds positions:
+    ``"sublanes"`` | ``"lanes"``) and ``path``: ``"kernel"`` where the call
+    ran as the Mosaic kernels ``ds_conv_fwd`` / ``ds_conv_bwd`` (then also
+    ``slab``, the channels one grid step takes, and ``tile``, the
+    positions one step of its inner loop takes), ``"xla"`` where it fell
+    back to shifted copies with autodiff's backward.  None where the step
+    has no such call."""
+    return _account_rows(name, "conv_calls")
 
 
 # ==================================================== where a start goes

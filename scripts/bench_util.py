@@ -212,6 +212,30 @@ def timed_chain(step_fn, state0, n, warmup=2):
     return (t_big - t_small) / (4 * n)
 
 
+def timed_unrolled(step_fn, state0, n):
+    """As :func:`timed_chain`, with the ``n`` and ``3n`` chained
+    applications written out in one jitted program instead of a
+    ``fori_loop``: a loop copies a carried buffer that a custom call (a
+    Pallas kernel) cannot update in place — 0.65 ms a step for 268 MB on a
+    v5e, as large as the call it times.  For steps whose whole output is
+    the next step's input."""
+    def run(m):
+        @jax.jit
+        def fn(state):
+            for _ in range(m):
+                state = step_fn(state)
+            return jnp.sum(state[0].astype(jnp.float32))
+        float(fn(state0))                # compile + warm
+
+        def once():
+            t0 = time.time()
+            float(fn(state0))
+            return time.time() - t0
+        return min(once() for _ in range(3))
+
+    return (run(3 * n) - run(n)) / (2 * n)
+
+
 def timed_chain_ms(step_fn, state0, n, warmup=3):
     """``timed_chain`` in milliseconds (decode_profile's historical
     unit)."""

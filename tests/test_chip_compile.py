@@ -2,8 +2,9 @@
 compiler is installed on CPU-only boxes): the training path's kernels at
 GPT-2 760M width, the grouped GEMM kernels at OLMoE-1B-7B's (their
 weight panels resident in VMEM) and at Mixtral-8x7B's, the gated delta
-rule's two at Qwen3-Next's and the state-space scan's two at Nemotron-H's
-go through Mosaic, the data-sharded flash kernel
+rule's two at Qwen3-Next's, the state-space scan's two at Nemotron-H's
+and the short causal convolution's two at both hybrids' (each in its
+orientation) go through Mosaic, the data-sharded flash kernel
 goes through the partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
@@ -126,6 +127,20 @@ def _ssd(x, dt, A, Bm, Cm, D, seg):
     return ssd.ssd_kernels(x, dt, A, Bm, Cm, D, seg, blocking)
 
 
+def _conv(positions, first=0):
+    """The causal convolution's kernels as ops/linear_attention.py calls
+    them on one TPU (the choice switched off: no TPU here), with the slab
+    the library chooses, bias and silu inside; ``x`` from its channel
+    ``first`` on, as wide as the weights."""
+    def conv(x, w, bias, seg):
+        from deepspeed_tpu.ops.pallas import causal_conv as cc
+        blocking = cc.slab_width(x.shape[1], w.shape[1], x.dtype.itemsize,
+                                 positions, first)
+        return cc.causal_conv_kernels(x, w, seg, bias, "silu", blocking,
+                                      first)
+    return conv
+
+
 def _ggemm_args(experts, rows, k, n):
     return [((rows, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
             ((rows // 128,), jnp.int32), ((1,), jnp.int32)]
@@ -170,6 +185,16 @@ _SSD_8K = [((2, 8192, 64, 64), jnp.bfloat16), ((2, 8192, 64), jnp.float32),
            ((64,), jnp.float32), ((2, 8192, 8, 128), jnp.bfloat16),
            ((2, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32),
            ((2, 8192), jnp.int32)]
+# the short causal convolution of both hybrids' mixers, packed, S 8192:
+# Qwen3-Next's q | k | v (8192 channels, positions down sublanes) and
+# Nemotron-H's x | B | C (6144 channels, positions along lanes) whole, and
+# as the models call it: v (4096 channels from 4096 on of the projection's
+# 12,288) and x (4096 from 4096 on of 10,304 = 80.5 lane tiles)
+def _conv_args(width, channels):
+    return [((2, 8192, width), jnp.bfloat16), ((4, channels), jnp.bfloat16),
+            ((channels,), jnp.bfloat16), ((2, 8192), jnp.int32)]
+
+
 _QKV = [((B, S, H, HD), jnp.bfloat16)] * 3
 _CACHE = (8, 1024, 16, 96)
 KERNEL_CASES = {
@@ -202,6 +227,16 @@ KERNEL_CASES = {
     "ds_ssd_s8192_packed_fwd": (_ssd, _SSD_8K),
     "ds_ssd_s8192_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_8K),
+    "ds_conv_sublanes_s8192_packed_fwd": (_conv("sublanes"),
+                                          _conv_args(8192, 8192)),
+    "ds_conv_sublanes_s8192_packed_part_fwd_bwd": (
+        jax.grad(_sum_sq(_conv("sublanes", 4096)), (0, 1, 2)),
+        _conv_args(12288, 4096)),
+    "ds_conv_lanes_s8192_packed_fwd": (_conv("lanes"),
+                                       _conv_args(6144, 6144)),
+    "ds_conv_lanes_s8192_packed_part_fwd_bwd": (
+        jax.grad(_sum_sq(_conv("lanes", 4096)), (0, 1, 2)),
+        _conv_args(10304, 4096)),
     "stock_flash_fwd": (_stock_flash, _QKV),
     "stock_flash_fwd_bwd": (jax.grad(_sum_sq(_stock_flash), (0, 1, 2)),
                             _QKV),
@@ -244,6 +279,12 @@ NAMED_KERNELS = {
     "ds_gdr_s8192_packed_fwd_bwd": {"ds_gdr_fwd", "ds_gdr_bwd"},
     "ds_ssd_s8192_packed_fwd": {"ds_ssd_fwd"},
     "ds_ssd_s8192_packed_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
+    "ds_conv_sublanes_s8192_packed_fwd": {"ds_conv_fwd"},
+    "ds_conv_sublanes_s8192_packed_part_fwd_bwd": {"ds_conv_fwd",
+                                                   "ds_conv_bwd"},
+    "ds_conv_lanes_s8192_packed_fwd": {"ds_conv_fwd"},
+    "ds_conv_lanes_s8192_packed_part_fwd_bwd": {"ds_conv_fwd",
+                                                "ds_conv_bwd"},
 }
 
 #: the regime each grouped kernel of a case takes (the step account's
@@ -364,7 +405,7 @@ def test_library_knows_the_chips_peaks(v5e):
 
 @pytest.mark.parametrize("script", [
     "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1",
-    "scripts/ssd_table.py"])
+    "scripts/ssd_table.py", "scripts/conv_table.py"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(

@@ -87,7 +87,8 @@ def test_fixed_names():
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
-                            "ds_ssd_fwd", "ds_ssd_bwd")
+                            "ds_ssd_fwd", "ds_ssd_bwd", "ds_conv_fwd",
+                            "ds_conv_bwd")
 
 
 # ------------------------------------------------------- the text's parser
