@@ -1,0 +1,108 @@
+"""The plain JoyAI-LLM-Flash reference against models/joyai.py at a tiny
+size, float32, on the CPU (the engine, the gradients, the departures and
+the shares' sum are tests/test_joyai.py's, on this same file), and the
+controls its two tolerances have to catch, for the main head and for the
+prediction module's."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models.joyai import joyai_model
+from references import joyai as reference
+
+TOY = dict(num_layers=3, d_model=64, num_heads=4, q_lora_rank=48,
+           kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+           v_head_dim=16, d_ff_dense=96, d_ff=32, shared_expert_d_ff=32,
+           num_experts=16, top_k=4, experts_held=4, expert_offset=4,
+           vocab_size=512, max_seq_len=128, dtype="float32")
+
+
+def _setup(scale=1.0, **overrides):
+    model = joyai_model("llm-flash", **{**TOY, **overrides})
+    params = jax.tree.map(lambda a: a * scale,
+                          model.init(jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(0)
+    gas, batch, seq = 2, 3, 48
+    ids = rng.integers(0, 512, size=(gas, batch, seq), dtype=np.int32)
+    cuts = np.sort(rng.integers(1, seq, size=(gas, batch, 3)), axis=-1)
+    cuts[0, 0] = (15, 16, 17)     # two one-token documents
+    data = {"input_ids": ids,
+            "segment_ids": (np.arange(seq)[None, None, :, None]
+                            >= cuts[:, :, None, :]).sum(-1).astype(np.int32)}
+    sizes = {k: getattr(model.config, k) for k in reference.SIZES}
+    return model, params, data, sizes
+
+
+def _model_loss(model, params, data):
+    loss = jax.jit(model.loss)
+    with jax.default_matmul_precision("highest"):
+        return np.mean([float(loss(
+            params, {k: jnp.asarray(v[g]) for k, v in data.items()}))
+            for g in range(2)])
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_reference_matches_the_model(packed):
+    model, params, data, sizes = _setup()
+    if not packed:
+        data = {"input_ids": data["input_ids"]}
+    got = reference.step_loss(params, data, sizes, chunk=1)
+    want = _model_loss(model, params, data)
+    assert abs(got - want) < 2e-5, (got, want)
+
+
+@pytest.mark.parametrize("overrides", [
+    dict(experts_held=None, expert_offset=0), dict(num_mtp_layers=0)],
+    ids=["every_expert", "module_off"])
+def test_reference_matches_the_model_otherwise_built(overrides):
+    model, params, data, sizes = _setup(**overrides)
+    got = reference.step_loss(params, data, sizes, chunk=1)
+    assert abs(got - _model_loss(model, params, data)) < 2e-5
+
+
+@pytest.mark.parametrize("head", ["main", "mtp"])
+def test_token_by_token_catches_fp8_and_not_bf16(head):
+    """The control (PERF.md section 2, PR 38) on what
+    drivers/train_steps_counted.py compares for the main head, and what
+    scripts/reference_control.py compares for the prediction module's:
+    the scored positions' losses one by one, as the root of the mean
+    squared difference, of the reference with every matrix product's
+    operands rounded to a lower precision.  bf16 is the engine's own
+    arithmetic and has to stay inside TOKEN_NLL_RMS_ATOL; the next
+    precision below, fp8 e4m3, has to land outside.  At toy size the
+    weights are scaled up until the logits matter.  The per-token losses'
+    means are step_loss's two cross-entropies."""
+    _, params, data, sizes = _setup(scale=2.5)
+    micro = {k: v[0] for k, v in data.items()}
+    per_token = {"main": reference.token_losses,
+                 "mtp": reference.mtp_token_losses}[head]
+    exact, scored = per_token(params, micro, sizes, chunk=1)
+    if head == "main":
+        other, other_scored = reference.mtp_token_losses(
+            params, micro, sizes, chunk=1)
+        mean = reference.step_loss(
+            params, {k: v[:1] for k, v in data.items()},
+            {**sizes, "aux_loss_coef": 0.0}, chunk=1)
+        assert float(exact[scored].mean()) + sizes["mtp_loss_weight"] \
+            * float(other[other_scored].mean()) \
+            == pytest.approx(mean, abs=1e-5)
+        assert other_scored.sum() < scored.sum()
+
+    def rms(dtype):
+        got, _ = per_token(params, micro, sizes, chunk=1, matmul_dtype=dtype)
+        return float(np.sqrt(np.mean(np.square(got - exact)[scored])))
+
+    bf16, fp8 = rms(jnp.bfloat16), rms(jnp.float8_e4m3fn)
+    assert bf16 < reference.TOKEN_NLL_RMS_ATOL < fp8, (bf16, fp8)
+
+
+def test_the_mean_loss_keeps_bf16_inside():
+    """LOSS_ATOL on the first step's mean loss: the bf16 control stays
+    inside it (whether fp8 lands outside is the chip's reading: PERF.md
+    section 2, PR 38)."""
+    _, params, data, sizes = _setup()
+    exact = reference.step_loss(params, data, sizes, chunk=1)
+    bf16 = reference.step_loss(params, data, sizes, chunk=1,
+                               matmul_dtype=jnp.bfloat16)
+    assert abs(bf16 - exact) < reference.LOSS_ATOL, bf16 - exact

@@ -1,0 +1,557 @@
+"""JoyAI-LLM-Flash (huggingface.co/jdopensource/JoyAI-LLM-Flash,
+``model_type: joyai_llm_flash``), whose config is key for key the
+DeepSeek-V3 layout: **latent attention** in every layer (DeepSeek-V2,
+arXiv:2405.04434 section 2.1), one leading dense layer, then layers of
+sigmoid-routed SwiGLU experts beside a shared one, and a
+**multi-token-prediction** module in the loss (DeepSeek-V3,
+arXiv:2412.19437 sections 2.1-2.2).  RMSNorm everywhere, no bias anywhere,
+untied head.
+
+- *Latent attention* (``H`` heads).  Queries: ``c_q = N(x W_dq)``
+  (``q_lora_rank`` wide), ``[q_nope | q_rope] = c_q W_uq`` per head
+  (``qk_nope_head_dim | qk_rope_head_dim``).  Keys and values: ``[c_kv |
+  k_r] = x W_dkv`` (``kv_lora_rank | qk_rope_head_dim``); ``c_kv <-
+  N(c_kv)``; ``[k_nope | v] = c_kv W_ukv`` per head (``qk_nope_head_dim |
+  v_head_dim``); ``k_r`` is ONE rotary key shared by all heads.  Rotary
+  (``rope_theta``, pairs ``(2i, 2i+1)``) on ``q_rope`` and ``k_r`` only, by
+  the position along the sequence.  ``q = [q_nope | q_rope]``, ``k =
+  [k_nope | k_r]``: the score head is ``qk_nope + qk_rope`` wide and the
+  value head ``v_head_dim`` — the flash kernels take the two widths
+  (ops/pallas/ds_flash_attention.py).  Causal softmax of ``q k^T /
+  sqrt(qk_nope + qk_rope)`` inside a document; ``o = concat_heads(P v)
+  W_o``.  This is the expanded, per-head form that training runs; the
+  absorbed form that decoding would run (scores against ``c_kv`` itself) is
+  not built.
+- *Layer 0*: ``x + MLA(N(x))``, then ``x + W_down(silu(W_gate h) * W_up
+  h)`` at ``d_ff_dense``.
+- *Layers 1..*: the same attention, then experts (moe/layer.py): ``s =
+  sigmoid(h W_r)`` in float32; the choice is the ``top_k`` largest of ``s +
+  e_score_correction_bias`` (a leaf the loss does not train); the weights
+  are ``s`` of the chosen over their sum, times ``routed_scaling_factor``;
+  SwiGLU experts at ``d_ff`` and one shared SwiGLU expert, added as it is.
+  ``experts_held`` (with ``expert_offset``) makes this chip's share of an
+  expert-parallel layer.
+- *Multi-token prediction*, depth 1 (``num_mtp_layers``): with ``h_t`` the
+  last main layer's output at position t, BEFORE the final norm, and ``E``
+  the shared embedding: ``h'_t = [N_h(h_t) ; N_e(E[x_{t+1}])] W_eh``, one
+  more block of the layers-1.. kind, a final norm of its own and the
+  SHARED head, scored against ``x_{t+2}`` where t, t+1 and t+2 are of one
+  document.  ``L = L_main + mtp_loss_weight * L_mtp + router losses``.
+
+The layer loop is **lead-then-run**: the dense block once, then one
+``lax.scan`` over the expert layers' stack (``params["blocks"]``), so that
+depth does not multiply the traced text.  The module's embedding and head
+are ``params["wte"]`` and ``params["lm_head"]`` themselves: one leaf each,
+two uses, and their gradients are the sum of both.  Each head pass is
+rematerialised on its own, so one ``[tokens, vocab]`` float32 logits array
+lives at a time.  Not built: a load-driven update of the router's bias;
+serving (the absorbed form and a cache of latents — the entry points
+raise); ZeRO-3 and parameter streaming.
+"""
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.ad_checkpoint
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.models.model import (Model, maybe_stream,
+                                        param_stream_active, qdot,
+                                        resolve_size, token_loss)
+from deepspeed_tpu.models.llama import _rms_norm, rope
+from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
+                                     init_moe_params, moe_layer,
+                                     moe_logical_specs)
+from deepspeed_tpu.ops.attention import causal_attention
+from deepspeed_tpu.telemetry.tracing import (
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_KV_LATENT,
+    SCOPE_MLP, SCOPE_MTP, SCOPE_OUT_PROJ, SCOPE_Q_LATENT, SCOPE_ROPE,
+    SCOPE_SCORES)
+
+
+@dataclass(frozen=True)
+class JoyAIConfig:
+    vocab_size: int = 129280
+    max_seq_len: int = 131072
+    #: main layers: ONE leading layer whose feed-forward is dense
+    #: (``first_k_dense_replace`` 1), then expert layers; the prediction
+    #: module's block is one more
+    num_layers: int = 40
+    d_model: int = 2048
+    num_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 32000000.0
+    #: the leading dense layer's width (``intermediate_size``)
+    d_ff_dense: int = 7168
+    #: an expert's width (``moe_intermediate_size``)
+    d_ff: int = 768
+    num_experts: int = 256
+    top_k: int = 8
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    #: the experts this chip holds (None = all): moe/layer.py MoEConfig
+    expert_offset: int = 0
+    experts_held: "int | None" = None
+    held_rows_factor: int = 2
+    shared_expert_d_ff: int = 768
+    aux_loss_coef: float = 1e-4
+    load_balance: str = "all_choices"
+    #: prediction modules behind the main model (``num_nextn_predict_layers``:
+    #: 0 = the main stack alone, 1 = as published) and the weight of the
+    #: module's loss
+    num_mtp_layers: int = 1
+    mtp_loss_weight: float = 0.3
+    norm_eps: float = 1e-6
+    dtype: str = "bfloat16"
+    remat: bool = False
+    remat_policy: str = "nothing"
+    attention_impl: str = "auto"
+
+    def __post_init__(self):
+        if self.num_layers < 2:
+            raise ValueError(
+                f"joyai: the stack is one leading dense layer and then "
+                f"expert layers (num_layers >= 2), not {self.num_layers}")
+        if self.num_mtp_layers not in (0, 1):
+            raise ValueError(
+                f"joyai: num_mtp_layers is 0 or 1 (a second prediction "
+                f"module is not built), not {self.num_mtp_layers}")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def expert_layers(self) -> int:
+        """Main layers with experts (the module's block not among them)."""
+        return self.num_layers - 1
+
+    @property
+    def moe(self) -> MoEConfig:
+        return MoEConfig(
+            d_model=self.d_model, d_ff=self.d_ff,
+            num_experts=self.num_experts, top_k=self.top_k,
+            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
+            norm_topk_prob=self.norm_topk_prob, router="sigmoid",
+            routed_scaling_factor=self.routed_scaling_factor,
+            load_balance=self.load_balance, activation="silu_glu",
+            # a held share runs through the grouped dispatch only
+            dispatch_mode="grouped",
+            expert_offset=self.expert_offset,
+            experts_held=self.experts_held,
+            held_rows_factor=self.held_rows_factor,
+            shared_expert_d_ff=self.shared_expert_d_ff)
+
+
+JOYAI_SIZES = {
+    "tiny": dict(vocab_size=256, max_seq_len=128, num_layers=3, d_model=32,
+                 num_heads=2, q_lora_rank=24, kv_lora_rank=16,
+                 qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                 d_ff_dense=64, d_ff=16, num_experts=8, top_k=2,
+                 shared_expert_d_ff=16),
+    # huggingface.co/jdopensource/JoyAI-LLM-Flash config.json: the defaults
+    # above.  48.9B parameters whole, 1.25B more with the prediction
+    # module; one chip trains the first five layers and the module with 16
+    # of each layer's 256 experts held (benchmarks/configs)
+    "llm-flash": dict(),
+}
+
+
+def _attn_params(config: JoyAIConfig, key, lead=()):
+    D, H = config.d_model, config.num_heads
+    rq, rkv = config.q_lora_rank, config.kv_lora_rank
+    nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                     config.v_head_dim)
+    norm = partial(jax.random.normal, dtype=jnp.float32)
+    k = iter(jax.random.split(key, 5))
+    std = 0.02
+    return {
+        "attn_norm": jnp.ones(lead + (D,)),
+        "w_dq": norm(next(k), lead + (D, rq)) * std,
+        "q_norm": jnp.ones(lead + (rq,)),
+        "w_uq": norm(next(k), lead + (rq, H * (nope + rot))) * std,
+        "w_dkv": norm(next(k), lead + (D, rkv + rot)) * std,
+        "kv_norm": jnp.ones(lead + (rkv,)),
+        "w_ukv": norm(next(k), lead + (rkv, H * (nope + vd))) * std,
+        "w_o": norm(next(k), lead + (H * vd, D)) * std,
+    }
+
+
+def _expert_block_params(config: JoyAIConfig, key, n=None):
+    """``n`` expert layers stacked (None: one, unstacked)."""
+    lead = () if n is None else (n,)
+    k_attn, k_moe = jax.random.split(key)
+    if n is None:
+        moe = init_moe_params(config.moe, k_moe)
+    else:
+        moe = jax.vmap(partial(init_moe_params, config.moe))(
+            jax.random.split(k_moe, n))
+    return {**_attn_params(config, k_attn, lead),
+            "mlp_norm": jnp.ones(lead + (config.d_model,)), "moe": moe}
+
+
+def init_params(config: JoyAIConfig, rng) -> dict:
+    """Seeded.  Assumed where the published config is silent: normal
+    weights of std 0.02, norm weights 1, ``e_score_correction_bias`` 0."""
+    D, V, F = config.d_model, config.vocab_size, config.d_ff_dense
+    std = 0.02
+    norm = partial(jax.random.normal, dtype=jnp.float32)
+    k = iter(jax.random.split(rng, 10))
+    params = {
+        "wte": norm(next(k), (V, D)) * std,
+        "dense": {**_attn_params(config, next(k)),
+                  "mlp_norm": jnp.ones((D,)),
+                  "w_gate": norm(next(k), (D, F)) * std,
+                  "w_up": norm(next(k), (D, F)) * std,
+                  "w_down": norm(next(k), (F, D)) * std},
+        "blocks": _expert_block_params(config, next(k),
+                                       config.expert_layers),
+        "final_norm": jnp.ones((D,)),
+        "lm_head": norm(next(k), (D, V)) * std,
+    }
+    if config.num_mtp_layers:
+        # no embedding and no head of its own: wte and lm_head above
+        params["mtp"] = {
+            "norm_h": jnp.ones((D,)), "norm_e": jnp.ones((D,)),
+            "w_eh": norm(next(k), (2 * D, D)) * std,
+            "block": _expert_block_params(config, next(k)),
+            "final_norm": jnp.ones((D,)),
+        }
+    return params
+
+
+def logical_specs(config: JoyAIConfig) -> dict:
+    def attn(lead):
+        col, row = P(*lead, None, "model"), P(*lead, "model", None)
+        return {"attn_norm": P(), "w_dq": P(), "q_norm": P(), "w_uq": col,
+                "w_dkv": P(), "kv_norm": P(), "w_ukv": col, "w_o": row}
+
+    def expert_block(lead):
+        moe = jax.tree.map(lambda spec: P(*lead, *spec),
+                           moe_logical_specs(config.moe),
+                           is_leaf=lambda s: isinstance(s, P))
+        return {**attn(lead), "mlp_norm": P(), "moe": moe}
+
+    specs = {
+        "wte": P("model", None),
+        "dense": {**attn(()), "mlp_norm": P(), "w_gate": P(None, "model"),
+                  "w_up": P(None, "model"), "w_down": P("model", None)},
+        "blocks": expert_block((None,)),
+        "final_norm": P(),
+        "lm_head": P(None, "model"),
+    }
+    if config.num_mtp_layers:
+        specs["mtp"] = {"norm_h": P(), "norm_e": P(), "w_eh": P(),
+                        "block": expert_block(()), "final_norm": P()}
+    return specs
+
+
+def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
+    """``x + MLA(N(x))``; the caller's scope is ``ds.block``."""
+    B, S, _ = x.shape
+    H, rkv = config.num_heads, config.kv_lora_rank
+    nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                     config.v_head_dim)
+    eps = config.norm_eps
+    with jax.named_scope(SCOPE_ATTN):
+        h = _rms_norm(x, layer["attn_norm"], eps)
+        with jax.named_scope(SCOPE_Q_LATENT):
+            c_q = _rms_norm(qdot(h, layer["w_dq"]), layer["q_norm"], eps)
+            q = qdot(c_q, layer["w_uq"]).reshape(B, S, H, nope + rot)
+        with jax.named_scope(SCOPE_KV_LATENT):
+            ckv = qdot(h, layer["w_dkv"])
+            c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
+            kv = qdot(c_kv, layer["w_ukv"]).reshape(B, S, H, nope + vd)
+        with jax.named_scope(SCOPE_ROPE):
+            q_rope = rope(q[..., nope:], config.rope_theta, interleaved=True)
+            k_r = rope(ckv[..., None, rkv:], config.rope_theta,
+                       interleaved=True)                  # [B, S, 1, rot]
+        with jax.named_scope(SCOPE_Q_LATENT):
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        with jax.named_scope(SCOPE_KV_LATENT):
+            # the one rotary key, a copy per head behind each head's own
+            # part: the kernels read k [B, S, H, nope + rot]
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_r, (B, S, H, rot))],
+                axis=-1)
+            v = kv[..., nope:]
+        with jax.named_scope(SCOPE_SCORES):
+            attn = causal_attention(q, k, v, impl=config.attention_impl,
+                                    segment_ids=segment_ids)
+    attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
+    with jax.named_scope(SCOPE_ATTN), jax.named_scope(SCOPE_OUT_PROJ):
+        return x + qdot(attn.reshape(B, S, H * vd), layer["w_o"])
+
+
+@jax.named_scope(SCOPE_BLOCK)
+def _dense_block(x, layer, config: JoyAIConfig, segment_ids=None):
+    x = _latent_attention(x, layer, config, segment_ids)
+    with jax.named_scope(SCOPE_MLP):
+        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
+        h = jax.nn.silu(qdot(h, layer["w_gate"])) * qdot(h, layer["w_up"])
+        return x + qdot(h, layer["w_down"])
+
+
+@jax.named_scope(SCOPE_BLOCK)
+def _expert_block(x, layer, config: JoyAIConfig, train, rng=None,
+                  segment_ids=None):
+    """-> (x, (router loss, routed rows over ``held_rows_bound``))."""
+    x = _latent_attention(x, layer, config, segment_ids)
+    with jax.named_scope(SCOPE_MLP):
+        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
+        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
+                                    rng=rng, return_stats=True)
+        return x + out, (aux.astype(jnp.float32),
+                         stats["dropped"].astype(jnp.int32))
+
+
+def _remat(fn, config: JoyAIConfig):
+    if not config.remat:
+        return fn
+    from deepspeed_tpu.models.gpt2 import remat_policy
+    return jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
+
+
+def _segments(batch):
+    return batch.get("segment_ids") if isinstance(batch, dict) else None
+
+
+def hidden_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
+                    rng=None):
+    """The main stack: -> (the last layer's output [B, S, D], before the
+    final norm; router loss summed over the expert layers; routed rows over
+    ``held_rows_bound`` summed over them, int32)."""
+    if param_stream_active():
+        raise NotImplementedError(
+            "joyai: ZeRO-3 and parameter offload gather or stream one layer "
+            "of a single stacked tree at a time; this model's layers are a "
+            "leading dense block, a stack of expert blocks and a prediction "
+            "module, and gathering at that grain is not built — use ZeRO "
+            "stage 0-2")
+    dtype = jnp.dtype(config.dtype)
+    seg = _segments(batch)
+    with jax.named_scope(SCOPE_EMBED):
+        x = params["wte"].astype(dtype)[batch["input_ids"]]
+    x = _remat(lambda x, layer: _dense_block(
+        x, maybe_stream(layer), config, segment_ids=seg), config)(
+            x, params["dense"])
+    x, (aux, over) = lax.scan(_expert_block_fn(config, train, rng, seg), x,
+                              params["blocks"])
+    return x, jnp.sum(aux), jnp.sum(over)
+
+
+def _expert_block_fn(config, train, rng, seg):
+    return _remat(lambda x, layer: _expert_block(
+        x, maybe_stream(layer), config, train=train, rng=rng,
+        segment_ids=seg), config)
+
+
+def _logits(x, norm_w, lm_head, config: JoyAIConfig):
+    x = _rms_norm(x, norm_w, config.norm_eps)
+    return x @ lm_head.astype(jnp.dtype(config.dtype))
+
+
+def forward_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
+                     rng=None):
+    """-> (the main head's logits, router loss, rows over the bound): the
+    main model alone, as a forward pass reads it."""
+    x, aux, over = hidden_with_aux(params, batch, config, train, rng)
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        return (_logits(x, params["final_norm"], params["lm_head"], config),
+                aux, over)
+
+
+def _mtp_input(params, x, batch, config: JoyAIConfig):
+    """What the module's block reads: ``[N_h(x_t) ; N_e(E[id_{t+1}])]
+    W_eh``."""
+    dtype = jnp.dtype(config.dtype)
+    mtp = params["mtp"]
+    eps = config.norm_eps
+    with jax.named_scope(SCOPE_EMBED):
+        nxt = params["wte"].astype(dtype)[
+            jnp.roll(batch["input_ids"], -1, axis=1)]
+    joined = jnp.concatenate([_rms_norm(x, mtp["norm_h"], eps),
+                              _rms_norm(nxt, mtp["norm_e"], eps)], axis=-1)
+    return qdot(joined, mtp["w_eh"])
+
+
+def mtp_hidden_with_aux(params, x, batch, config: JoyAIConfig,
+                        train: bool = True, rng=None):
+    """The prediction module up to its block's output: ``x`` is the main
+    stack's last hidden state (before the final norm); position t joins it
+    with the embedding of token t+1 (the last position's, which has none,
+    is never scored and nothing attends to it)."""
+    return _expert_block_fn(config, train, rng, _segments(batch))(
+        _mtp_input(params, x, batch, config), params["mtp"]["block"])
+
+
+def routed_rows(params, batch, config: JoyAIConfig):
+    """[expert blocks, num_experts] int32: the (token, choice) pairs each
+    block's router sends to each of ALL experts for this micro-batch, the
+    main layers' blocks in order and then the module's — what
+    ``held_rows_bound`` has to hold a share's sum of
+    (scripts/held_rows_table.py).  A diagnostic: the layers written out,
+    no scan."""
+    seg = _segments(batch)
+    moe = config.moe
+
+    def count(x, layer):
+        attended = _latent_attention(x, layer, config, seg)
+        h = _rms_norm(attended, layer["mlp_norm"], config.norm_eps)
+        from deepspeed_tpu.moe.layer import _route, _routing_logits
+        logits = _routing_logits(layer["moe"],
+                                 h.reshape(-1, config.d_model), moe)
+        chosen = _route(layer["moe"], logits, moe, True, None).expert_idx
+        return jnp.bincount(chosen.reshape(-1), length=config.num_experts)
+
+    x = params["wte"].astype(jnp.dtype(config.dtype))[batch["input_ids"]]
+    x = _dense_block(x, params["dense"], config, segment_ids=seg)
+    rows = []
+    for i in range(config.expert_layers):
+        layer = jax.tree.map(lambda a: a[i], params["blocks"])
+        rows.append(count(x, layer))
+        x, _ = _expert_block(x, layer, config, train=True, segment_ids=seg)
+    if config.num_mtp_layers:
+        rows.append(count(_mtp_input(params, x, batch, config),
+                          params["mtp"]["block"]))
+    return jnp.stack(rows)
+
+
+def mtp_targets(batch):
+    """(targets [B, S]: token t+2 at position t; scored [B, S]: where t,
+    t+1 and t+2 lie in one document and inside the sequence)."""
+    tokens = batch["input_ids"]
+    S = tokens.shape[1]
+    scored = jnp.broadcast_to(jnp.arange(S) < S - 2, tokens.shape)
+    seg = _segments(batch)
+    if seg is not None:
+        scored &= (seg == jnp.roll(seg, -1, axis=1)) \
+            & (seg == jnp.roll(seg, -2, axis=1))
+    mask = batch.get("attention_mask")
+    if mask is not None:
+        scored &= (jnp.roll(mask, -1, axis=1) != 0) \
+            & (jnp.roll(mask, -2, axis=1) != 0)
+    return jnp.roll(tokens, -2, axis=1), scored
+
+
+def _scored_nll(logits, targets):
+    logits = logits.astype(jnp.float32)
+    return jax.scipy.special.logsumexp(logits, axis=-1) \
+        - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+
+
+def mtp_token_losses(params, batch, config: JoyAIConfig):
+    """Every position's negative log likelihood of token t+2 from the
+    module's own forward pass [B, S] float32, and which are scored."""
+    x, _, _ = hidden_with_aux(params, batch, config, train=False)
+    h, _ = mtp_hidden_with_aux(params, x, batch, config, train=False)
+    targets, scored = mtp_targets(batch)
+    logits = _logits(h, params["mtp"]["final_norm"], params["lm_head"],
+                     config)
+    return _scored_nll(logits, targets), scored
+
+
+def loss_with_counts(params, batch, config: JoyAIConfig, rng=None):
+    """-> (``L_main + mtp_loss_weight * L_mtp + router losses``, {rows over
+    the bound})."""
+    x, aux, over = hidden_with_aux(params, batch, config, True, rng)
+
+    # a head pass keeps nothing but its inputs for the backward: the two
+    # passes' [tokens, vocab] float32 logits then never live together
+    @jax.checkpoint
+    def main_loss(x, norm_w, lm_head):
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            return token_loss(_logits(x, norm_w, lm_head, config), batch)
+
+    loss = main_loss(x, params["final_norm"], params["lm_head"]) + aux
+    if config.num_mtp_layers:
+        with jax.named_scope(SCOPE_MTP):
+            h, (mtp_aux, mtp_over) = mtp_hidden_with_aux(
+                params, x, batch, config, True, rng)
+
+            @jax.checkpoint
+            def mtp_loss(h, norm_w, lm_head):
+                with jax.named_scope(SCOPE_HEAD_LOSS):
+                    targets, scored = mtp_targets(batch)
+                    nll = _scored_nll(_logits(h, norm_w, lm_head, config),
+                                      targets)
+                    scored = scored.astype(jnp.float32)
+                    return jnp.sum(nll * scored) \
+                        / jnp.maximum(scored.sum(), 1.0)
+
+            loss = loss + mtp_aux + config.mtp_loss_weight * mtp_loss(
+                h, params["mtp"]["final_norm"], params["lm_head"])
+            over = over + mtp_over
+    return loss, {ROWS_OVER_BOUND: over}
+
+
+def count_params(config: JoyAIConfig) -> int:
+    import numpy as np
+    shapes = jax.eval_shape(partial(init_params, config),
+                            jax.random.PRNGKey(0))
+    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
+
+
+def _no_serving(what):
+    def refuse(*_, **__):
+        raise NotImplementedError(
+            f"joyai: {what} is not built — serving latent attention needs "
+            f"its absorbed form (scores against the cached latents "
+            f"themselves) and a paged cache of latents and rotary keys, "
+            f"and the prediction module as a self-drafting head (ROADMAP)")
+    return refuse
+
+
+def joyai_model(size: str = "llm-flash", **overrides) -> Model:
+    cfg_kwargs = resolve_size(JOYAI_SIZES, size, "joyai")
+    cfg_kwargs.update(overrides)
+    config = JoyAIConfig(**cfg_kwargs)
+    n_params = count_params(config)
+    moe = config.moe
+    # the routed experts a token's weights pass through HERE: top_k of
+    # num_experts of those held (all of them: top_k); the embedding is a
+    # lookup, and with the module the head multiplies a token twice
+    expert = 3 * config.d_model * config.d_ff
+    active = n_params - config.vocab_size * config.d_model \
+        - (config.expert_layers + config.num_mtp_layers) * expert * (
+            moe.held - config.top_k * moe.held / config.num_experts) \
+        + config.num_mtp_layers * config.d_model * config.vocab_size
+
+    def with_counts(params, batch, rng=None):
+        return loss_with_counts(params, batch, config, rng)
+
+    return Model(
+        config=config,
+        init_fn=partial(init_params, config),
+        apply_fn=lambda p, b, rng=None: forward_with_aux(
+            p, b, config, train=False, rng=rng)[0],
+        loss_fn=lambda p, b, rng=None: with_counts(p, b, rng)[0],
+        # the rows a step's expert layers left out leave the step beside
+        # its loss, as models/qwen3_next.py's; the engine counts and warns
+        loss_with_counts_fn=with_counts if moe.holds_subset else None,
+        logical_specs=logical_specs(config),
+        flops_per_token=6.0 * active,
+        meta={"name": f"joyai-{size}", "n_params": n_params,
+              "active_params": active,
+              "step_counts": {ROWS_OVER_BOUND: (
+                  "routed rows past held_rows_bound, left out of the expert "
+                  "layers: the router sent the experts held here more than "
+                  "held_rows_factor times their even share")}
+              if moe.holds_subset else {},
+              # the module's per-token losses, for a check against the
+              # plain reference's (scripts/reference_control.py)
+              "mtp_token_losses": (lambda p, b: mtp_token_losses(
+                  p, b, config)) if config.num_mtp_layers else None,
+              # every expert's routed rows, block by block
+              "routed_rows": lambda p, b: routed_rows(p, b, config)},
+        init_cache_fn=_no_serving("init_cache"),
+        prefill_fn=_no_serving("prefill"),
+        decode_fn=_no_serving("decode"),
+        verify_fn=_no_serving("verify"),
+    )

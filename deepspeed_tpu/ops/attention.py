@@ -26,9 +26,11 @@ def _kernel_needs_shard_map(q, impl: str) -> bool:
 
 
 def xla_causal_attention(q, k, v, segment_ids=None):
-    """Reference einsum attention with causal mask; [B, S, H, hd] layout.
-    fp32 softmax accumulation for bf16 inputs.  ``segment_ids`` [B, S]
-    restricts attention within packed segments."""
+    """Reference einsum attention with causal mask; [B, S, H, hd] layout
+    (``v``, and then the result, may be of another width than ``q`` and
+    ``k``; the scale is the score width's).  fp32 softmax accumulation for
+    bf16 inputs.  ``segment_ids`` [B, S] restricts attention within packed
+    segments."""
     B, S, H, hd = q.shape
     scale = hd ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -62,7 +64,7 @@ def flash_causal_attention(q, k, v, segment_ids=None, fallback=True):
     if segment_ids is not None or not prefer_stock:
         from deepspeed_tpu.ops.pallas.ds_flash_attention import \
             ds_flash_attention
-        vmem_ok = _ds_vmem_ok(q, segment_ids is not None)
+        vmem_ok = _ds_vmem_ok(q, segment_ids is not None, v)
         if not fallback and not vmem_ok:
             # explicit impl="flash" on a shape the VMEM heuristic rejects:
             # raise EAGERLY at trace time — under jit the Mosaic
@@ -117,15 +119,21 @@ def _flash_vmem_budget_mib() -> int:
     return _vmem_budget() >> 20
 
 
-def _ds_vmem_ok(q, packed=False) -> bool:
+def _ds_vmem_ok(q, packed=False, v=None) -> bool:
     """VMEM-budget check for the from-scratch kernel's whole-S staging; the
     eval_shape probe cannot see Mosaic VMEM exhaustion, so oversized shapes
-    are routed to the XLA path here (loudly, once per shape class)."""
+    are routed to the XLA path here (loudly, once per shape class).  ``v``
+    where its head width is not ``q``'s."""
     from deepspeed_tpu.ops.pallas.ds_flash_attention import vmem_fits
     key = ("vmem", q.shape[1], q.shape[3], q.dtype.itemsize, packed)
+    dv = None
+    if v is not None and v.shape[3] != q.shape[3]:
+        dv = v.shape[3]
+        key += (dv,)
     if key not in _FLASH_STATUS:
         _FLASH_STATUS[key] = vmem_fits(q.shape[1], q.shape[3],
-                                       q.dtype.itemsize, packed=packed)
+                                       q.dtype.itemsize, packed=packed,
+                                       v_head_dim=dv)
         if _FLASH_STATUS[key] is not True:
             from deepspeed_tpu.utils.logging import logger
             logger.warning(
@@ -148,23 +156,28 @@ def flash_status() -> dict:
     return dict(_FLASH_STATUS)
 
 
-def _flash_usable(q, fn=None, k=None, ds=False, packed=False) -> bool:
+def _flash_usable(q, fn=None, k=None, ds=False, packed=False,
+                  v=None) -> bool:
     """Probe the Pallas flash path once per shape class and remember the
     outcome.  A failure is logged loudly (never silently degraded — VERDICT
     round 1 flagged the silent except here) so a bench run on a slow fallback
     is visible in the logs.  ``ds=True`` marks fns that route to the
     from-scratch kernel, whose whole-S VMEM staging the eval_shape probe
-    cannot vet — those get the budget check first."""
+    cannot vet — those get the budget check first.  ``v`` where its head
+    width is not ``q``'s (only the from-scratch kernel takes that)."""
     from deepspeed_tpu.utils.logging import logger
     fn = fn or flash_causal_attention
-    kv = q if k is None else k
-    if ds and not _ds_vmem_ok(q, packed=packed):
+    kv = vv = q if k is None else k
+    if ds and not _ds_vmem_ok(q, packed=packed, v=v):
         return False
     key = (q.shape[1], q.shape[3], kv.shape[2],
            getattr(fn, "__name__", "bidirectional"))
+    if v is not None and v.shape[3] != q.shape[3]:
+        key += (v.shape[3],)
+        vv = v
     if key not in _FLASH_STATUS:
         try:
-            jax.eval_shape(fn, q, kv, kv)
+            jax.eval_shape(fn, q, kv, vv)
             _FLASH_STATUS[key] = True
             logger.info(f"attention: Pallas flash selected for S={key[0]} "
                         f"head_dim={key[1]}")
@@ -185,6 +198,9 @@ def _ds_gqa_causal(q, k, v):
 
 def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
     gqa = k.shape[2] != q.shape[2]
+    # a value head narrower than the score head: the from-scratch kernel
+    # takes the two widths, the stock wrapper one — routed as GQA is
+    two_widths = v.shape[3] != q.shape[3]
     if segment_ids is not None:
         # packed sequences: only the from-scratch kernel (GQA-native,
         # segment-masked) or the exact einsum can honor the mask
@@ -195,7 +211,7 @@ def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
             return ds_flash_attention(q, k, v, segment_ids=segment_ids,
                                       causal=True)
         if impl == "auto" and _on_tpu() and q.shape[1] >= 256 \
-                and _ds_vmem_ok(q, packed=True):
+                and _ds_vmem_ok(q, packed=True, v=v):
             try:
                 return ds_flash_attention(q, k, v,
                                           segment_ids=segment_ids,
@@ -213,22 +229,24 @@ def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
         return xla_causal_attention(q, k, v, segment_ids)
     if impl == "flash":
         # explicit request: no fallback — surface the real error
-        if gqa:
+        if gqa or two_widths:
             return _ds_gqa_causal(q, k, v)
         return flash_causal_attention(q, k, v, fallback=False)
     if impl == "auto" and _on_tpu() and q.shape[1] >= 256:
-        if gqa and _flash_usable(q, fn=_ds_gqa_causal, k=k, ds=True):
+        if (gqa or two_widths) and _flash_usable(
+                q, fn=_ds_gqa_causal, k=k, ds=True, v=v):
             # grouped-query: the from-scratch kernel reads each KV head
             # once per group instead of attending repeated copies
             return _ds_gqa_causal(q, k, v)
-        if gqa:
+        # (two widths: no other kernel takes them — the einsum below)
+        if gqa and not two_widths:
             # kernel unusable for this shape: repeat and try the tuned
             # stock wrapper before surrendering to the [S,S] einsum
             rep = q.shape[2] // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
             gqa = False
-        if _flash_usable(q):
+        if not two_widths and _flash_usable(q):
             return flash_causal_attention(q, k, v)
     if gqa:
         rep = q.shape[2] // k.shape[2]
@@ -301,9 +319,11 @@ def _local_bidirectional_attention(q, k, v, pad_mask, impl):
 def causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
     """q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd]; KV may divide
     H (GQA — the from-scratch flash kernel attends compact KV natively,
-    other paths repeat).  ``segment_ids`` [B, S] restricts attention
-    within packed segments (models thread ``batch["segment_ids"]`` here;
-    the from-scratch kernel masks natively, the einsum path exactly).
+    other paths repeat); ``v`` may be narrower than ``q`` and ``k``
+    (latent attention), and the result is then ``v``'s width.
+    ``segment_ids`` [B, S] restricts attention within packed segments
+    (models thread ``batch["segment_ids"]`` here; the from-scratch kernel
+    masks natively, the einsum path exactly).
 
     When the mesh has an active ``seq`` axis, attention runs under Ulysses
     sequence parallelism (head-scatter all-to-all; see sequence/layer.py) —

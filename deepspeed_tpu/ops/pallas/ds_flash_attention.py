@@ -8,12 +8,16 @@ as a TPU kernel rather than translated.  Algorithm: FlashAttention-2
 backward in two passes — dK/dV blocks looping over query tiles, dQ blocks
 looping over key tiles).
 
-Layouts: q [B, S, H, hd], k/v [B, S, KV, hd] (grouped-query attention:
-KV may divide H — each group of H/KV query heads reads one KV head, so
-GQA models stream KV at 1/group the HBM traffic instead of repeating
-heads).  ``segment_ids`` [B, S] int32 restricts attention to same-segment
-pairs — packed-sequence training the stock wrapper lacked (pass None for
-a single segment).  The [S, S] score matrix never materialises in HBM;
+Layouts: q [B, S, H, dk], k [B, S, KV, dk], v [B, S, KV, dv] (grouped-query
+attention: KV may divide H — each group of H/KV query heads reads one KV
+head, so GQA models stream KV at 1/group the HBM traffic instead of
+repeating heads).  The value head may be narrower than the score head
+(``dv <= dk``: latent attention scores at 128 + 64 and reads values at
+128): ``o``, ``do``, ``dv`` are then ``dv`` wide in HBM and in VMEM, ``q``,
+``k``, ``dq``, ``dk`` stay ``dk`` wide, and no operand is padded.
+``segment_ids`` [B, S] int32 restricts attention to same-segment pairs —
+packed-sequence training the stock wrapper lacked (pass None for a single
+segment).  The [S, S] score matrix never materialises in HBM;
 VMEM holds one [block_q, block_k] tile.
 """
 import functools
@@ -49,7 +53,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     n_kblocks = (_causal_kblocks(iq, block_q, block_k, seq_len)
                  if causal else seq_len // block_k)
 
@@ -113,7 +117,7 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
     segk = segk_ref[0] if has_seg else None              # [Bk, 1]
 
     dk0 = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-    dv0 = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
+    dv0 = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
     start = (ik * block_k) // block_q if causal else 0
 
     def body(j, carry):
@@ -244,19 +248,22 @@ def _vmem_budget() -> int:
 
 
 def working_set_bytes(seq_len, head_dim, itemsize, block_q=512,
-                      block_k=512, packed=False) -> int:
+                      block_k=512, packed=False, v_head_dim=None) -> int:
     """One (batch, head) grid step's VMEM working set.
 
     The kernels stage the full-sequence K/V (forward/dq) or Q/dO (dk/dv
     pass) per grid step via whole-S BlockSpecs, so the dominant term is
-    2*S*hd_padded*itemsize (the lane dim pads to a multiple of 128);
-    Pallas double-buffers the pipelined blocks, hence the factor 2 on
-    top, plus the [1, S] fp32 lse/delta rows (sublane-padded x8) and the
-    block tiles.  ``packed`` adds the dq pass's whole-S segment column,
-    whose single-lane layout pads x128."""
+    S*(hd_padded + v_hd_padded)*itemsize (the lane dim pads to a multiple
+    of 128; ``v_head_dim``, the width of v, o and do, is ``head_dim``
+    unless given); Pallas double-buffers the pipelined blocks, hence the
+    factor 2 on top, plus the [1, S] fp32 lse/delta rows (sublane-padded
+    x8) and the block tiles.  ``packed`` adds the dq pass's whole-S
+    segment column, whose single-lane layout pads x128."""
     bq, bk = _choose_blocks(seq_len, block_q, block_k)
     hd_pad = -(-head_dim // 128) * 128
-    full_kv = 2 * seq_len * hd_pad * itemsize        # K+V (or Q+dO) whole-S
+    v_pad = hd_pad if v_head_dim is None else -(-v_head_dim // 128) * 128
+    # K+V (or Q+dO) whole-S
+    full_kv = seq_len * (hd_pad + v_pad) * itemsize
     rows = 2 * 8 * seq_len * 4                       # lse+delta [1,S] fp32
     if packed:
         rows += seq_len * 128 * 4                    # dq segk [S,1] column
@@ -268,7 +275,7 @@ def working_set_bytes(seq_len, head_dim, itemsize, block_q=512,
 
 
 def vmem_fits(seq_len, head_dim, itemsize, block_q=512, block_k=512,
-              budget_bytes=None, packed=False):
+              budget_bytes=None, packed=False, v_head_dim=None):
     """Whether :func:`working_set_bytes` fits on-core.  The dispatch layer
     calls this before selecting the kernel — ``jax.eval_shape`` probes
     only shapes and would pass a 16k-fp32 sequence that Mosaic then
@@ -279,27 +286,48 @@ def vmem_fits(seq_len, head_dim, itemsize, block_q=512, block_k=512,
         budget_bytes = _vmem_budget()
     try:
         return working_set_bytes(seq_len, head_dim, itemsize, block_q,
-                                 block_k, packed) <= budget_bytes
+                                 block_k, packed, v_head_dim) <= budget_bytes
     except ValueError:
         return False
 
 
-def _compiler_kw(q, block_q, block_k, packed):
+def _vmem_limit(q, block_q, block_k, packed, v=None):
+    """The VMEM limit the three calls ask for (None: what a call is
+    granted unasked); ``v`` where its head width is not ``q``'s."""
+    return vmem.limit_for(working_set_bytes(
+        q.shape[1], q.shape[3], q.dtype.itemsize, block_q, block_k, packed,
+        None if v is None else v.shape[3]))
+
+
+def _compiler_kw(q, block_q, block_k, packed, v=None):
     """``compiler_params`` for the three calls: a raised VMEM limit where
     the working set passes what a call is granted unasked, else nothing
     (and then the call is the one it always was)."""
-    need = working_set_bytes(q.shape[1], q.shape[3], q.dtype.itemsize,
-                             block_q, block_k, packed)
-    limit = vmem.limit_for(need)
+    limit = _vmem_limit(q, block_q, block_k, packed, v)
     if limit is None:
         return {}
     from jax.experimental.pallas import tpu as pltpu
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
+def _record_call(q, k, v, block_q, block_k, packed):
+    """This call's row of the step's account
+    (``tracing.flash_calls``): shapes only, written while the
+    step is traced."""
+    from deepspeed_tpu.telemetry.tracing import count_in_step
+    B, S, H, hd = q.shape
+    row = {"batch": B, "seq_len": S, "heads": H, "kv_heads": k.shape[2],
+           "dk": hd, "dv": v.shape[3], "packed": packed,
+           "blocks": list(_choose_blocks(S, block_q, block_k)),
+           "vmem_limit_bytes": _vmem_limit(q, block_q, block_k, packed, v)}
+    count_in_step(flash_calls={
+        f"{B}x{S}x{H}x{k.shape[2]}x{hd}x{v.shape[3]}x{int(packed)}": row})
+
+
 def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
                        sm_scale=None, block_q=512, block_k=512):
-    """q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd].  KV may
+    """q [B, S, H, dk], k [B, S, KV, dk], v [B, S, KV, dv] -> [B, S, H,
+    dv], ``dv <= dk``; ``sm_scale`` defaults to ``dk ** -0.5``.  KV may
     divide H (grouped-query attention — KV streams once per group).
     ``segment_ids``: None or a [B, S] array (any integer or float dtype —
     cast to int32 here, ONCE, so the custom_vjp's float0 cotangent always
@@ -345,15 +373,20 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     # in force; True forces interpret mode (ring path off-TPU)
     _ikw = {} if interpret is None else {"interpret": interpret}
     B, S, H, hd = q.shape
-    KV = k.shape[2]
+    KV, hv = k.shape[2], v.shape[3]
     if H % KV:
         raise ValueError(f"ds_flash_attention: q heads {H} not a multiple "
                          f"of kv heads {KV}")
+    if k.shape[3] != hd or hv > hd:
+        raise ValueError(
+            f"ds_flash_attention: q and k share the score width (q {hd}, k "
+            f"{k.shape[3]}) and v may be narrower, not wider (v {hv})")
     rep = H // KV
     sm = sm_scale if sm_scale is not None else hd ** -0.5
     bq, bk = _choose_blocks(S, block_q, block_k)
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     has_seg = segment_ids is not None
+    _record_call(q, k, v, block_q, block_k, has_seg)
     # TPU-legal layouts for per-row operands (Mosaic requires the last two
     # block dims to divide (8, 128) or equal the array dims — a bare
     # [B, S] block fails): segment ids (int32, cast once in the public
@@ -369,7 +402,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, S, hd),
                      lambda b, h, i: (b, h // rep, 0, 0)),
-        pl.BlockSpec((1, 1, S, hd),
+        pl.BlockSpec((1, 1, S, hv),
                      lambda b, h, i: (b, h // rep, 0, 0)),
     ]
     if has_seg:
@@ -379,14 +412,14 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
                      pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0))]
     oT, lse = pl.pallas_call(
         kernel, grid=(B, H, S // bq), name="ds_flash_fwd", **_ikw,
-        **_compiler_kw(q, block_q, block_k, has_seg),
+        **_compiler_kw(q, block_q, block_k, has_seg, v),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, hv), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, hv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ])(*operands)
     o = jnp.transpose(oT, (0, 2, 1, 3))
@@ -411,14 +444,14 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
     chunk contributions (the ring) accumulates exactly and casts once."""
     _ikw = {} if interpret is None else {"interpret": interpret}
     B, S, H, hd = q.shape
-    KV = k.shape[2]
+    KV, hv = k.shape[2], v.shape[3]
     rep = H // KV
     sm = sm_scale if sm_scale is not None else hd ** -0.5
     bq, bk = _choose_blocks(S, block_q, block_k)
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     doT = _to_bhsd(do)
     has_seg = segment_ids is not None
-    _ckw = _compiler_kw(q, block_q, block_k, has_seg)
+    _ckw = _compiler_kw(q, block_q, block_k, has_seg, v)
     # per-q stats travel as [B, H, 1, S] ROWS (sublane-padded x8, vs the
     # x128 lane padding a [..., S, 1] column layout would cost in both
     # VMEM and HBM); the backward kernels consume them transposed
@@ -435,9 +468,9 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         pl.BlockSpec((1, 1, S, hd), lambda b, i, h: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bk, hd),
                      lambda b, i, h: (b, h // rep, i, 0)),
-        pl.BlockSpec((1, 1, bk, hd),
+        pl.BlockSpec((1, 1, bk, hv),
                      lambda b, i, h: (b, h // rep, i, 0)),
-        pl.BlockSpec((1, 1, S, hd), lambda b, i, h: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, S, hv), lambda b, i, h: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, 1, S), lambda b, i, h: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, 1, S), lambda b, i, h: (b, h, 0, 0))]
     dq_in = [qT, kT, vT, doT, lse_r, delta_r]
@@ -445,9 +478,9 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, S, hd),
                      lambda b, h, i: (b, h // rep, 0, 0)),
-        pl.BlockSpec((1, 1, S, hd),
+        pl.BlockSpec((1, 1, S, hv),
                      lambda b, h, i: (b, h // rep, 0, 0)),
-        pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, hv), lambda b, h, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, 1, S), lambda b, h, i: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, 1, S), lambda b, h, i: (b, h, 0, 0)),
     ]
@@ -471,10 +504,10 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd),
                          lambda b, i, h: (b, h // rep, i, 0)),
-            pl.BlockSpec((1, 1, bk, hd),
+            pl.BlockSpec((1, 1, bk, hv),
                          lambda b, i, h: (b, h // rep, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, KV, S, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, S, hd), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, KV, S, hv), jnp.float32)],
     )(*dkv_in)
 
     dq_kernel = functools.partial(
@@ -506,7 +539,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
 
 def chunk_fwd(q, k, v, causal, sm_scale=None, block_q=512, block_k=512,
               interpret=None):
-    """One K/V chunk's attention: -> (o [B,S,H,hd], lse [B,H,S]).
+    """One K/V chunk's attention: -> (o [B,S,H,dv], lse [B,H,S]).
     Not differentiable on its own — the ring owns the VJP."""
     o, (_, _, _, _, lse) = _fwd(q, k, v, None, causal, sm_scale, block_q,
                                 block_k, interpret=interpret)

@@ -347,12 +347,23 @@ SCOPE_OUT_PROJ = "out_proj"         # ... output projection + residual
 # ``out_proj`` as above:
 SCOPE_SSM = "ssm"                   # the norm and all of the below
 SCOPE_SCAN = "scan"                 # ... the state-space scan (SSD)
+# inside ``attn``, where the attention is latent (models/joyai.py), beside
+# ``out_proj`` as above:
+SCOPE_Q_LATENT = "q_latent"         # ... queries: down, norm, up, the join
+SCOPE_KV_LATENT = "kv_latent"       # ... keys/values: down, norm, up, k built
+SCOPE_ROPE = "rope"                 # ... rotary on q's part and the shared key
+SCOPE_SCORES = "scores"             # ... softmax(q k^T) v: the flash kernels
+# beside ``ds.block``, around a whole multi-token-prediction module
+# (models/joyai.py): its embedding, its projection, its block (whose
+# scopes are ``ds.block``'s, beneath this one), its norm, head and loss
+SCOPE_MTP = "ds.mtp"
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
                SCOPE_COMBINE, SCOPE_SHARED_EXPERT, SCOPE_LINEAR_ATTN,
                SCOPE_IN_PROJ, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
-               SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN)
+               SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN, SCOPE_Q_LATENT,
+               SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
@@ -719,6 +730,18 @@ def conv_calls(name: str = TRAIN_STEP_PROGRAM):
     back to shifted copies with autodiff's backward.  None where the step
     has no such call."""
     return _account_rows(name, "conv_calls")
+
+
+def flash_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The flash-attention calls of the step as
+    ops/pallas/ds_flash_attention.py traced them, every family's: one row
+    per shape — ``batch``, ``seq_len``, ``heads``, ``kv_heads``, ``dk``
+    (the score head's width: q and k) and ``dv`` (the value head's: v and
+    the result), ``packed`` (segment ids or not), ``blocks`` (block_q,
+    block_k) and ``vmem_limit_bytes``, the limit the three kernels ask for
+    (None: what a call is granted unasked).  None where the step has no
+    such call (the XLA einsum took its place, or there is no attention)."""
+    return _account_rows(name, "flash_calls")
 
 
 # ==================================================== where a start goes

@@ -12,8 +12,12 @@ outside in every seed.
 One JSON line per seed: the float32 loss and each dtype's distance from
 it — and, where the reference has ``token_losses``, the root of the mean
 squared difference of the first micro-batch's scored positions' losses
-(what ``TOKEN_NLL_RMS_ATOL`` limits); a last line with the extremes over the
-seeds and the limits.
+(what ``TOKEN_NLL_RMS_ATOL`` limits) — and, where it also has
+``mtp_token_losses`` (a prediction module's, which the benchmark's driver
+does not read), the same of those under ``mtp_*``, with the program's own
+forward pass (its kernels and precision, at these parameters) held to the
+float32 reference beside the controls (``program_*``); a last line with the
+extremes over the seeds and the limits.
 """
 import argparse
 import importlib
@@ -60,6 +64,13 @@ def main():
     distances = {name: [] for name in args.dtypes}
     per_token = getattr(reference, "token_losses", None)
     token_rms = {name: [] for name in args.dtypes if per_token}
+    heads = {"mtp_": (reference.mtp_token_losses,
+                      jax.jit(model.meta["mtp_token_losses"]))} \
+        if hasattr(reference, "mtp_token_losses") else {}
+    if heads:
+        from drivers.train_steps_counted import token_nll
+        heads[""] = (per_token, lambda p, b: (token_nll(model, p, b), None))
+    head_rms = {}
     for seed in args.seed:
         params = init(jax.random.PRNGKey(seed))
         stream = datagen.BatchStream(traffic, sizes["vocab_size"],
@@ -83,6 +94,22 @@ def main():
                 token_rms[name].append(float(np.sqrt(np.mean(
                     np.square(got - want)[scored]))))
                 line[name + "_token_nll_rms"] = token_rms[name][-1]
+            rms = lambda a, b: float(np.sqrt(np.mean(
+                np.square(np.asarray(a) - b)[scored])))
+            for head, (plain, program) in heads.items():
+                want, scored = plain(params, micro, sizes, chunk)
+                readings = {"program_" + head: program(
+                    params, {k: jnp.asarray(v) for k, v in micro.items()})[0]}
+                if head:
+                    readings.update({
+                        name + "_" + head: plain(
+                            params, micro, sizes, chunk,
+                            matmul_dtype=getattr(jnp, name))[0]
+                        for name in args.dtypes})
+                for key, got in readings.items():
+                    line[key + "token_nll_rms"] = rms(got, want)
+                    head_rms.setdefault(key + "token_nll_rms", []).append(
+                        line[key + "token_nll_rms"])
         print(json.dumps(line), flush=True)
     print(json.dumps({
         "LOSS_ATOL": reference.LOSS_ATOL, "seeds": len(args.seed),
@@ -90,7 +117,9 @@ def main():
            for name, d in distances.items()},
         "TOKEN_NLL_RMS_ATOL": getattr(reference, "TOKEN_NLL_RMS_ATOL", None),
         **{name + "_token_nll_rms": {"min": min(d), "max": max(d)}
-           for name, d in token_rms.items()}}), flush=True)
+           for name, d in token_rms.items()},
+        **{key: {"min": min(d), "max": max(d)}
+           for key, d in head_rms.items()}}), flush=True)
 
 
 if __name__ == "__main__":

@@ -1,0 +1,89 @@
+"""The lowered text of a toy training step's forward and backward (the
+flash kernels in Pallas' interpreter, so their bodies are in the text) for
+each family the benchmark held before the kernels took a value head of
+another width than the score head — what
+tests/test_flash_head_widths.py holds to the digests taken at the parent
+commit (tests/data/flash_step_digests.json).
+
+The text depends on the process's devices (the tests' eight virtual ones
+pin intermediate layouts), so the digests are taken in the tests' own
+environment, from the root of a checkout:
+
+    python -c "import tests.conftest, json; \
+        from tests import flash_step_texts as f; \
+        print(json.dumps({n: f.digest(n) for n in f.FAMILIES}, indent=1))"
+"""
+import functools
+import hashlib
+
+import jax
+import jax.numpy as jnp
+
+
+def _gpt2():
+    from deepspeed_tpu.models.gpt2 import gpt2_model
+    return gpt2_model(size="custom", vocab_size=128, max_seq_len=64,
+                      num_layers=2, num_heads=4, d_model=32, dtype="float32",
+                      attention_impl="flash", remat=True)
+
+
+def _olmoe():
+    from deepspeed_tpu.models.mixtral import mixtral_model
+    return mixtral_model(
+        size="olmoe-1b-7b", num_layers=2, d_model=64, num_heads=2,
+        num_kv_heads=2, d_ff=64, num_experts=4, top_k=2, vocab_size=512,
+        max_seq_len=128, moe_dispatch="grouped", remat=True,
+        attention_impl="flash")
+
+
+def _qwen3_next():
+    from deepspeed_tpu.models.qwen3_next import qwen3_next_model
+    return qwen3_next_model(
+        "80b-a3b", num_layers=4, d_model=64, num_heads=4, num_kv_heads=2,
+        head_dim=32, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=16, linear_value_head_dim=16, d_ff=32,
+        shared_expert_d_ff=32, num_experts=16, top_k=4, experts_held=4,
+        expert_offset=8, vocab_size=512, max_seq_len=128,
+        delta_rule_chunk=16, dtype="float32", remat=True,
+        attention_impl="flash")
+
+
+def _nemotron_h():
+    from deepspeed_tpu.models.nemotron_h import nemotron_h_model
+    return nemotron_h_model(
+        "3-nano-30b-a3b", num_layers=5, hybrid_override_pattern="MEM*E",
+        d_model=64, num_heads=4, num_kv_heads=2, head_dim=32,
+        mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+        chunk_size=16, d_ff=32, shared_expert_d_ff=64, num_experts=16,
+        top_k=4, experts_held=4, expert_offset=8, vocab_size=512,
+        max_seq_len=128, dtype="float32", remat=True,
+        attention_impl="flash")
+
+
+FAMILIES = {"gpt2": _gpt2, "olmoe": _olmoe, "qwen3_next": _qwen3_next,
+            "nemotron_h": _nemotron_h}
+
+
+def digest(family: str) -> str:
+    """sha256 of the lowered text of ``value_and_grad(loss)`` on a packed
+    [2, 64] batch, the Pallas calls interpreted."""
+    from jax.experimental import pallas as pl
+    from deepspeed_tpu.moe import layer as moe_layer
+    real = pl.pallas_call
+    pl.pallas_call = functools.partial(real, interpret=True)
+    # a metrics tap an earlier test of the process left installed would
+    # put its host callbacks into the text
+    tap, moe_layer._metrics_registry = moe_layer._metrics_registry, None
+    try:
+        model = FAMILIES[family]()
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
+                 "segment_ids": jnp.zeros((2, 64), jnp.int32)}
+        text = jax.jit(jax.value_and_grad(model.loss)).lower(
+            shapes, batch).as_text()
+    finally:
+        pl.pallas_call = real
+        moe_layer._metrics_registry = tap
+    assert "while" in text      # the interpreted kernels' grid loops
+    return hashlib.sha256(text.encode()).hexdigest()
+

@@ -1,8 +1,9 @@
 """Compiles for a TPU v5e that is described, not attached (the chip's
 compiler is installed on CPU-only boxes): the training path's kernels at
 GPT-2 760M width, the grouped GEMM kernels at OLMoE-1B-7B's (their
-weight panels resident in VMEM) and at Mixtral-8x7B's, the gated delta
-rule's two at Qwen3-Next's, the state-space scan's two at Nemotron-H's
+weight panels resident in VMEM) and at Mixtral-8x7B's, the flash kernels at JoyAI-LLM-Flash's two head widths (a
+192-wide score, a 128-wide value) and the grouped ones at its 768-wide
+experts, the gated delta rule's two at Qwen3-Next's, the state-space scan's two at Nemotron-H's
 and the short causal convolution's two at both hybrids' (each in its
 orientation) go through Mosaic, the data-sharded flash kernel
 goes through the partitioner, and the library knows the chip's peaks.
@@ -185,6 +186,18 @@ _SSD_8K = [((2, 8192, 64, 64), jnp.bfloat16), ((2, 8192, 64), jnp.float32),
            ((64,), jnp.float32), ((2, 8192, 8, 128), jnp.bfloat16),
            ((2, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32),
            ((2, 8192), jnp.int32)]
+# joyai-llm-flash.packed-s8192-gas2: latent attention, 32 heads (no
+# grouping), a score head of 128 + 64 = 192 = 1.5 lane tiles and a value
+# head of 128: v, o, do and dv are 128 wide in HBM, nothing padded to 192
+_QKV_MLA_8K = [((2, 8192, 32, 192), jnp.bfloat16),
+               ((2, 8192, 32, 192), jnp.bfloat16),
+               ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192), jnp.int32)]
+# ... and its five expert blocks: 16 experts held of 256, 16,384 tokens x 8
+# choices, a plan of held_rows_bound 131,072 (sixteen times the even
+# share: every routed row) + 16 * 128 rows; D 2048 -> F 768 = 6 x 128
+# (gate, up) and back (down)
+_GGEMM_W768 = _ggemm_args(16, 131072 + 16 * 128, 2048, 768)
+_GGEMM_W768_DOWN = _ggemm_args(16, 131072 + 16 * 128, 768, 2048)
 # the short causal convolution of both hybrids' mixers, packed, S 8192:
 # Qwen3-Next's q | k | v (8192 channels, positions down sublanes) and
 # Nemotron-H's x | B | C (6144 channels, positions along lanes) whole, and
@@ -219,6 +232,12 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA_8K),
     "ds_flash_gqa16_s8192_hd128_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA16_8K),
+    "ds_flash_mla_s8192_dk192_dv128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_MLA_8K),
+    "ds_ggemm_w768_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                              _GGEMM_W768),
+    "ds_ggemm_w768_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+                                   _GGEMM_W768_DOWN),
     "ds_ggemm_mixtral_streamed_fwd_bwd": (
         jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
     "ds_gdr_s8192_packed_fwd": (_gdr, _GDR_8K),
@@ -268,7 +287,12 @@ NAMED_KERNELS = {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_flash_gqa16_s8192_hd128_packed_fwd_bwd": {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
+    "ds_flash_mla_s8192_dk192_dv128_packed_fwd_bwd": {
+        "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
+    "ds_ggemm_w768_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
+    "ds_ggemm_w768_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx",
+                                   "ds_ggemm_dw"},
     "ds_ggemm_held_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_relu2_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_relu2_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx",
@@ -302,6 +326,8 @@ GGEMM_REGIMES = {
     "ds_ggemm_held_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     "ds_ggemm_relu2_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     "ds_ggemm_relu2_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_w768_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
+    "ds_ggemm_w768_down_fwd_bwd": dict.fromkeys(_ALL_THREE, "resident"),
     # (512, 1024) swapped for dx is one block over its contraction of 1024
     "ds_ggemm_mixtral_streamed_fwd_bwd": {
         "ds_ggemm_fwd": "streamed", "ds_ggemm_dx": "streamed",
@@ -323,10 +349,17 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
             *(_arg(v5e[0], shape, dtype) for shape, dtype in args))
         compiled = lowered.compile()
     assert KERNEL in compiled.as_text()
-    if "relu2" in case:
+    if "relu2" in case or "mla" in case:
         # a dim of 14.5 x 128 is one block: no padded copy of the expert
-        # stack (or of the rows) is written beside the kernels
+        # stack (or of the rows) is written beside the kernels; a value
+        # head of 128 beside a score head of 192 stays 128 wide: no padded
+        # copy of v, o, do or dv
         assert "stablehlo.pad" not in lowered.as_text()
+    if "mla" in case:
+        # the step's account of its flash calls has both widths
+        assert [(c["dk"], c["dv"], c["heads"], c["kv_heads"], c["packed"])
+                for c in tracing.flash_calls("test/compile")] \
+            == [(192, 128, 32, 32, True)]
     if case in GGEMM_REGIMES:
         calls = tracing.grouped_gemm_rows("test/compile")["calls"]
         assert {c["kernel"]: c["regime"] for c in calls} \

@@ -83,7 +83,8 @@ def test_fixed_names():
                            "ds.head_loss", "router", "dispatch", "experts",
                            "combine", "shared_expert", "linear_attn",
                            "in_proj", "conv", "delta_rule", "gate_norm",
-                           "out_proj", "ssm", "scan")
+                           "out_proj", "ssm", "scan", "q_latent",
+                           "kv_latent", "rope", "scores", "ds.mtp")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
