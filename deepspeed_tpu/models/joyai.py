@@ -418,8 +418,12 @@ def routed_rows(params, batch, config: JoyAIConfig):
         rows.append(count(x, layer))
         x, _ = _expert_block(x, layer, config, train=True, segment_ids=seg)
     if config.num_mtp_layers:
-        rows.append(count(_mtp_input(params, x, batch, config),
-                          params["mtp"]["block"]))
+        joined = _mtp_input(params, x, batch, config)
+        rows.append(count(joined, params["mtp"]["block"]))
+        # run for a registry tap to hear this block's plan as the others'
+        # (moe/layer.py ``_emit_held_plan``); with no tap nothing is left
+        _expert_block(joined, params["mtp"]["block"], config, train=True,
+                      segment_ids=seg)
     return jnp.stack(rows)
 
 

@@ -106,7 +106,10 @@ class MoEConfig:
     #: ``held_rows_bound`` is this many times the held experts' even share
     #: of the routed rows: 2 where a router spreads its tokens about
     #: evenly; a router whose experts' loads differ several-fold (sigmoid
-    #: scores over un-gated relu2 experts at initialisation) needs more
+    #: scores over un-gated relu2 experts at initialisation) needs more.
+    #: It buys safety with memory (the plan's ``[bound, ·]`` buffers), not
+    #: with time: dispatch, the grouped kernels and combine walk the rows
+    #: that are routed here and stop (``grouped_gemm.live_rows``)
     held_rows_factor: int = 2
     #: width of a shared expert every token passes through beside the
     #: routed ones (0 = none), added to their sum; ``shared_expert_gate``
@@ -308,6 +311,27 @@ def _report_router_health(entropy, load, max_frac, dead, aux, z):
                       expert=str(i))
 
 
+def _report_held_plan(live, plan_rows):
+    reg = _metrics_registry
+    if reg is None:
+        return
+    reg.set_gauge(HELD_LIVE_ROWS, float(live))
+    reg.set_gauge(HELD_PLAN_ROWS, float(plan_rows))
+
+
+def _emit_held_plan(plan):
+    """How much of a held plan is live, through the registry tap alone (as
+    the router's health: traced only when a tap is installed): per expert
+    layer that runs, :data:`HELD_LIVE_ROWS` — ``used_blocks`` tiles of
+    rows, what dispatch, the grouped kernels and combine walk — beside
+    :data:`HELD_PLAN_ROWS`, the plan's static length."""
+    if _metrics_registry is None:
+        return
+    from deepspeed_tpu.ops.pallas.grouped_gemm import live_rows
+    jax.debug.callback(_report_held_plan, live_rows(plan),
+                       jnp.int32(plan.padded_rows))
+
+
 def _emit_router_health(logits, routing, config: MoEConfig):
     """Host-callback bridge for router health, armed only with the
     registry tap (the PR 8 contract: observability overhead serving /
@@ -426,8 +450,12 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
     """The grouped formulation over the experts held here
     (``MoEConfig.experts_held``): the routing is over all experts, the
     plan over the rows whose expert is one of ours, inside a static bound
-    (``grouped_gemm.held_rows_bound``); the rows go out by one gather and
-    come back summed into their tokens.  Rows over the bound are the
+    (``grouped_gemm.held_rows_bound``); the rows go out by gathers and
+    come back summed into their tokens, and nothing here makes a pass over
+    the bound where the bound is mostly empty: every step walks the plan's
+    live prefix, a chunk at a time (a sum over a plan that is more than
+    three eighths live goes in one scatter-add: ``grouped_gemm``).
+    Rows over the bound are the
     second number of the statistics (``moe_layer(..., return_stats=True)``;
     the model hands their sum to the engine: :data:`ROWS_OVER_BOUND`)."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
@@ -440,6 +468,7 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
         plan, over = gg.make_held_group_plan(
             eids, config.expert_offset, config.held, bound)
         x_pad = gg.dispatch_held_rows(xt, plan, k)          # [Mp, D]
+    _emit_held_plan(plan)
     # shapes all: ``grouped_routed_rows`` is the EXPECTED number of held
     # rows under even routing (the true one is data)
     count_in_step(grouped_routed_rows=R * config.held // config.num_experts,
@@ -448,7 +477,8 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
                   experts_routed=config.num_experts)
     mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
     with jax.named_scope(SCOPE_EXPERTS):
-        h = _glu(mm, x_pad, params.get("w_gate"), params["w_in"], config)
+        h = _glu(mm, x_pad, params.get("w_gate"), params["w_in"], config,
+                 plan)
         y = mm(h, params["w_out"])                          # [Mp, D]
     with jax.named_scope(SCOPE_COMBINE):
         combined = gg.combine_held_rows(y, gates, plan, k)
@@ -462,6 +492,10 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
 #: (``engine.step_counts()``, the registry's ``train/step_counts``) and
 #: warns of a step in which it is not zero
 ROWS_OVER_BOUND = "moe/rows_over_bound"
+#: gauges of the registry tap (:func:`_emit_held_plan`), never step counts:
+#: the rows of a held plan's live prefix, and of the plan
+HELD_LIVE_ROWS = "moe/held_live_rows"
+HELD_PLAN_ROWS = "moe/held_plan_rows"
 
 
 def _route(params, logits, config: MoEConfig, train: bool, rng):
@@ -482,10 +516,26 @@ def _ungated(h, config: MoEConfig):
     return jax.nn.gelu(h, approximate=True)
 
 
-def _glu(mm, x, w_gate, w_in, config: MoEConfig):
+def _silu_glu(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def _glu(mm, x, w_gate, w_in, config: MoEConfig, plan=None):
+    """The experts' first half over the plan's rows ``x``.  Given a held
+    ``plan``, what XLA does between the grouped calls — the activation,
+    and in the backward pass the sum of the two cotangents of ``x`` —
+    walks the plan's live prefix as they do."""
+    if plan is None:
+        if config.activation == "silu_glu":
+            return jax.nn.silu(mm(x, w_gate)) * mm(x, w_in)
+        return _ungated(mm(x, w_in), config)
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     if config.activation == "silu_glu":
-        return jax.nn.silu(mm(x, w_gate)) * mm(x, w_in)
-    return _ungated(mm(x, w_in), config)
+        x_gate, x_in = gg.fan_out_live_rows(x, plan, 2)
+        return gg.map_live_rows(_silu_glu, plan, mm(x_gate, w_gate),
+                                mm(x_in, w_in))
+    return gg.map_live_rows(partial(_ungated, config=config), plan,
+                            mm(x, w_in))
 
 
 def gg_kernel_real() -> bool:

@@ -31,9 +31,14 @@ computation as ONE ragged GEMM over tokens sorted by expert:
    block_sparse_attention idiom), so each M-tile contracts against
    exactly its expert's ``[K, N]`` slice of the stacked ``[E, K, N]``
    weights — zero top-k slot padding, no dense ``[T, E, C]`` tensors
-   anywhere.  A tile at or past ``used_blocks`` repeats the block index
-   of the last tile that holds rows (so nothing is copied for it), does
-   no MXU work and writes zeros.
+   anywhere.  A tile at or past ``used_blocks`` — a trailing tile —
+   repeats the block index of the last tile that holds rows (so nothing
+   is copied for it) and does no MXU work.  What it leaves behind depends
+   on the plan: zeros (a full plan, :func:`make_group_plan`: fewer than
+   one tile in ten trails, and :func:`combine_rows` may gather anything),
+   or nothing at all (a held plan, ``live_only``: its output block too
+   stays on the last live tile, so the rows behind the live prefix — most
+   of such a plan — are never written, and hold whatever the buffer held).
 3. The blocks (:func:`_choose_blocks`) come from the shapes, the dtypes
    and a VMEM budget that is a constant per device kind.  **Resident**:
    ``bk = K``, one contraction per tile and no scratch accumulator, with
@@ -78,7 +83,22 @@ these kernels from the flash kernel in a compiled step: ``ds_ggemm_fwd``,
 ``ds_ggemm_dx`` (the same kernel body on a transposed right-hand side),
 ``ds_ggemm_dw`` (the three of ``KERNEL_NAMES`` that training runs),
 ``ds_ggemm_q`` (int8 weights) and ``ds_ggemm_slots`` /
-``ds_ggemm_slots_q`` (decode-sized).
+``ds_ggemm_slots_q`` (decode-sized).  ``ds_unwritten_*`` are no kernels
+but allocations: the buffers a held plan's loops write their chunks into
+(:func:`_unwritten`).
+
+A held subset of the experts (``make_held_group_plan``: expert
+parallelism's share of a layer) lays its rows out in a plan whose length
+is a static bound, several times what is routed to it.  What such a plan
+costs is what its rows cost: they are a prefix of ``used_blocks`` tiles, a
+number the step computes from the routing, and every consumer — the
+plan's own lookup, :func:`dispatch_held_rows`, the kernels' tile walk and
+their output, :func:`map_live_rows` between two grouped calls,
+:func:`combine_held_rows`, and each one's backward — walks that prefix and
+stops.  The bound costs memory, not time.  (The two sums into tokens alone
+choose in the step, from the routing: where more than three eighths of a
+plan is live its padding is the cheaper part, and one scatter-add takes
+every row of it — :func:`_sum_live_into_tokens`.)
 """
 import functools
 import os
@@ -133,10 +153,14 @@ class GroupPlan(NamedTuple):
     group_sizes: jnp.ndarray       # [E] padded rows per expert (⋅bm, ≥ bm)
     block_group_ids: jnp.ndarray   # [num_blocks] expert per M-tile (sorted)
     used_blocks: jnp.ndarray       # [1] M-tiles that hold a group; the rest
-    #                                trail behind the last one, all zeros
+    #                                trail behind the last one
     row_to_padded: jnp.ndarray     # [R] flat element -> padded row
     padded_to_row: jnp.ndarray     # [Mp] padded row -> flat element | R
     counts: jnp.ndarray            # [E] true routed counts (telemetry)
+    #: static: every consumer stops at ``used_blocks`` and the rows behind
+    #: it are never written (a held plan, whose bound is mostly unused);
+    #: False: the trailing tiles are written as zeros
+    live_only: bool = False
 
 
 def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
@@ -189,8 +213,9 @@ def _tile_group_ids(bidx, cum_blocks):
     """The expert of each M-tile ``bidx``: tile b belongs to the first
     expert whose cumulative tile count exceeds b; the tiles past the last
     group (``used_blocks`` on) clamp to E-1, which keeps the ids monotone
-    for tgmm: all-zero rows, which the kernels neither fetch nor multiply
-    — they write zeros."""
+    for tgmm: rows the kernels neither fetch nor multiply (a full plan's
+    are zeros and so is what is written for them; a held plan's are not
+    written at all)."""
     gids = jnp.sum((bidx[:, None] >= cum_blocks[None, :]).astype(jnp.int32),
                    axis=1)
     return jnp.minimum(gids, cum_blocks.shape[0] - 1).astype(jnp.int32)
@@ -321,6 +346,12 @@ def combine_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
 # ``[offset, offset + held)`` enter the plan.  How many those are is data,
 # and the plan's length is static, so it is a stated bound
 # (:func:`held_rows_bound`): rows past it are counted, never lost silently.
+# The rows sit in the plan's first ``used_blocks`` tiles — its live prefix;
+# a trailing tile holds no row, and a trailing row (one behind the prefix)
+# of any ``[Mp, ·]`` array of such a plan is neither written nor read:
+# x_pad, the kernels' results, the activation, and every cotangent.  A
+# padding row INSIDE the prefix (the rest of an expert's last tile) is
+# exact zeros, as in a full plan.
 def held_rows_bound(routed_rows: int, experts_held: int, num_experts: int,
                     block_m: Optional[int] = None, factor: int = 2) -> int:
     """``factor`` times (twice, unless the layer says otherwise:
@@ -344,9 +375,10 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     that, the groups are cut in expert order so that each expert still has
     one, and what is cut is the second result.  One stable sort lays the
     held rows out by expert; ``padded_to_row`` comes from it by
-    arithmetic, reading ``R`` on a padding row.  The plan has no
-    ``row_to_padded``: the way back sums rows into tokens
-    (:func:`combine_held_rows`), R being mostly rows held elsewhere."""
+    arithmetic and a lookup over the live prefix, reading ``R`` on a
+    padding row.  The plan has no ``row_to_padded``: the way back sums
+    rows into tokens (:func:`combine_held_rows`), R being mostly rows held
+    elsewhere.  It is ``live_only``: see the section's head."""
     R = int(expert_ids.shape[0])
     E = int(experts_held)
     bm = int(block_m or default_block_m())
@@ -374,58 +406,238 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     group_start = (cum_blocks - blocks_e) * bm
     within = (bidx * bm - group_start[gids])[:, None] \
         + jnp.arange(bm, dtype=jnp.int32)[None, :]     # [num_blocks, bm]
-    source = jnp.clip(first[gids][:, None] + within, 0, R - 1)
-    padded_to_row = jnp.where(within < kept[gids][:, None],
-                              by_expert[source], R).reshape(padded_rows)
+    source = jnp.where(within < kept[gids][:, None],
+                       first[gids][:, None] + within, R).reshape(padded_rows)
     plan = GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
-                     cum_blocks[-1:].astype(jnp.int32), None,
-                     padded_to_row, counts)
-    return plan, over
+                     cum_blocks[-1:].astype(jnp.int32), None, None, counts,
+                     live_only=True)
+    # ``by_expert[source]`` over the live prefix alone (a gather of single
+    # int32s costs by the element, not by the byte: chunks as for rows a
+    # tile's int32s wide); a row behind the prefix is a padding row
+    chunk = _live_chunk_rows(plan, 4 * bm)
+
+    def lookup(start, first, out):
+        return _put_chunk(out, jnp.take(
+            by_expert, _chunk_of(source, start, chunk), mode="fill",
+            fill_value=R), start)
+
+    padded_to_row = _over_live_chunks(
+        padded_rows, chunk, live_rows(plan), lookup,
+        jnp.full((padded_rows,), R, jnp.int32))
+    return plan._replace(padded_to_row=padded_to_row), over
 
 
-def _sum_into_tokens(rows, token_of_row, tokens):
-    """rows [Mp, D] summed into [tokens, D] by ``token_of_row`` (``tokens``
-    = no token: dropped), accumulated in float32 and rounded once."""
-    out = jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[
-        token_of_row].add(rows.astype(jnp.float32), mode="drop")
-    return out.astype(rows.dtype)
+# ---- the live prefix.  A held plan's length is its bound; its rows are a
+# prefix of ``used_blocks`` tiles, a number the step computes from the
+# routing, and everything that reads or writes a ``[Mp, ·]`` array of such
+# a plan walks that prefix alone: a loop over chunks of a static number of
+# rows whose trip count is traced.  Behind the prefix nothing is written
+# (:func:`_unwritten`), so a row there holds whatever the buffer held; what
+# reads past the prefix (the one-pass way of a sum) drops such a row by its
+# index, whatever it holds.
+#: bytes of the ``[chunk, width]`` slab one pass of such a loop moves
+_LIVE_CHUNK_BYTES = 8 << 20
 
 
-@jax.custom_vjp
-def _dispatch_held(xt, token_of_row):
-    return _token_rows(xt, token_of_row)
+def live_rows(plan: GroupPlan):
+    """[] int32: the rows of the plan's live prefix, ``used_blocks`` tiles."""
+    return plan.used_blocks[0] * plan.block_m
 
 
-_dispatch_held.defvjp(
-    lambda xt, token_of_row: (_token_rows(xt, token_of_row),
-                              (token_of_row, xt.shape[0])),
-    lambda res, g: (_sum_into_tokens(g, *res), None))
+def _live_chunk_rows(plan: GroupPlan, row_bytes: int) -> int:
+    """Rows of one chunk for arrays whose widest row is ``row_bytes`` long:
+    whole tiles, about ``_LIVE_CHUNK_BYTES``, the plan at most."""
+    bm = plan.block_m
+    rows = max(_LIVE_CHUNK_BYTES // row_bytes // bm, 1) * bm
+    return min(rows, plan.padded_rows)
 
 
-def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
-    """As :func:`dispatch_rows` for a held-subset plan: one gather of the
-    plan's rows out of ``xt`` [T, D].  Backward: the rows' cotangents
-    summed into their tokens (a token has 0 to ``top_k`` rows here)."""
-    return _dispatch_held(xt, plan.padded_to_row // top_k)
+def _unwritten(shape, dtype, after, what):
+    """The array a live-prefix loop writes its chunks into.  Where the
+    kernels run on a chip it is a buffer nobody initialised: the result of
+    a Mosaic call that writes nothing (``ds_unwritten_<what>``).  It takes
+    ``after`` — an array the loop reads — and does not touch it: a bare
+    allocation depends on nothing, so the layers' scan computes it once
+    outside itself and every loop that writes into it then copies it, and
+    the scheduler may place it long before its loop, where it only holds
+    memory.  ``what`` tells a layer's buffers of one shape apart: XLA
+    makes one call of two that are equal, and copies its result for the
+    second loop.  Where ``ragged_dot`` stands in for the kernels, which
+    multiplies every padded row, or Pallas' interpreter does: zeros."""
+    use_reference, interpret = _use_reference(None)
+    if use_reference or interpret:
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(
+        lambda after_ref, out_ref: None,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        name=f"ds_unwritten_{what}")(after)
+
+
+def _over_live_chunks(padded_rows, chunk, live, body, carry):
+    """``carry = body(start, first, carry)`` for each chunk of ``chunk``
+    rows of the live prefix (``live`` rows, traced): ``first`` is where the
+    chunk begins and ``start`` where it is read and written — the same,
+    but for a last chunk that would pass the end of the plan and is pulled
+    back inside it, over rows an earlier pass has seen."""
+    def step(c, carry):
+        first = c * chunk
+        return body(jnp.minimum(first, padded_rows - chunk), first, carry)
+
+    return jax.lax.fori_loop(0, -(-live // chunk), step, carry)
+
+
+def _seen(start, first, chunk):
+    """[chunk] bool: the rows of a last chunk pulled back inside the plan
+    (from ``start`` on) that lie before ``first``, where it begins — an
+    earlier pass has done them, and a sum must not take them twice."""
+    return start + jnp.arange(chunk, dtype=jnp.int32) < first
+
+
+def _chunk_of(x, start, chunk):
+    if chunk == x.shape[0]:
+        return x
+    return jax.lax.dynamic_slice_in_dim(x, start, chunk, axis=0)
+
+
+def _put_chunk(out, rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(out, rows, start, axis=0)
+
+
+def _token_rows_live(xt, token_of_row, live, chunk):
+    """xt [T, D] -> [Mp, D] over the live prefix: padded row p reads token
+    ``token_of_row[p]``, zeros where that is ``T`` (a padding row)."""
+    Mp = token_of_row.shape[0]
+
+    def gather(start, first, out):
+        tokens = _chunk_of(token_of_row, start, chunk)
+        return _put_chunk(out, _rows_or_zeros(xt, tokens), start)
+
+    return _over_live_chunks(
+        Mp, chunk, live, gather,
+        _unwritten((Mp,) + xt.shape[1:], xt.dtype, xt, "rows"))
+
+
+#: the live share of a plan (eighths) from which one scatter-add over the
+#: whole plan is the cheaper sum.  Measured on a v5e at 2,048-wide rows
+#: (PERF.md section 6, PR 39): XLA's one scatter over a plan sorts its
+#: indices and costs 0.068 us a plan row + 0.059 a live row; a chunk's
+#: scatter inside the loop, which does not, 0.22-0.25 us a row — so the
+#: loop wins while live rows < 0.36-0.42 of the plan's
+_ONE_PASS_SUM_EIGHTHS = 3
+
+
+def _sum_live_into_tokens(rows_at, token_of_row, tokens, like, live, chunk):
+    """The live prefix's rows (``rows_at(start, n)``: the ``[n, D]`` rows
+    from ``start`` on) summed into ``[tokens, D]`` by ``token_of_row``
+    (``tokens`` = no token: dropped, whatever the row holds): ONE float32
+    accumulator, rounded once to ``like``'s dtype.  Which way is the step's
+    choice from the routing it sees: chunk by chunk over the prefix while
+    that is the smaller part of the plan (``_ONE_PASS_SUM_EIGHTHS``), else
+    — and for a plan of one chunk — every row of the plan in one
+    scatter-add, the rows behind the prefix dropped by their index."""
+    Mp = token_of_row.shape[0]
+    zeros = functools.partial(jnp.zeros, (tokens,) + like.shape[1:],
+                              jnp.float32)
+
+    def add(start, first, acc):
+        at = _chunk_of(token_of_row, start, chunk)
+        return acc.at[jnp.where(_seen(start, first, chunk), tokens, at)].add(
+            rows_at(start, chunk).astype(jnp.float32), mode="drop")
+
+    def by_chunks():
+        return _over_live_chunks(Mp, chunk, live, add,
+                                 zeros()).astype(like.dtype)
+
+    def in_one_pass():
+        return zeros().at[token_of_row].add(
+            rows_at(0, Mp).astype(jnp.float32), mode="drop").astype(
+                like.dtype)
+
+    if chunk >= Mp:
+        return in_one_pass()
+    return jax.lax.cond(live * 8 < Mp * _ONE_PASS_SUM_EIGHTHS, by_chunks,
+                        in_one_pass)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine_held(y, gate_of_row, token_of_row, tokens):
-    return _sum_into_tokens(gate_of_row.astype(y.dtype)[:, None] * y,
-                            token_of_row, tokens)
+def _dispatch_held(xt, token_of_row, live, chunk):
+    return _token_rows_live(xt, token_of_row, live, chunk)
 
 
-def _combine_held_fwd(y, gate_of_row, token_of_row, tokens):
-    return (_combine_held(y, gate_of_row, token_of_row, tokens),
-            (y, gate_of_row, token_of_row))
+def _dispatch_held_fwd(xt, token_of_row, live, chunk):
+    return (_token_rows_live(xt, token_of_row, live, chunk),
+            (token_of_row, live, xt.shape[0]))
 
 
-def _combine_held_bwd(tokens, res, g):
-    y, gate_of_row, token_of_row = res
-    g_rows = _token_rows(g, token_of_row)
-    dgate = jnp.sum(y.astype(jnp.float32) * g_rows.astype(jnp.float32),
-                    axis=-1).astype(gate_of_row.dtype)
-    return gate_of_row.astype(y.dtype)[:, None] * g_rows, dgate, None
+def _dispatch_held_bwd(chunk, res, g):
+    token_of_row, live, tokens = res
+    return (_sum_live_into_tokens(functools.partial(_chunk_of, g),
+                                  token_of_row, tokens, g, live, chunk),
+            None, None)
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
+    """As :func:`dispatch_rows` for a held-subset plan: the rows of the
+    plan's live prefix gathered out of ``xt`` [T, D], a chunk at a time
+    (padding rows inside the prefix are exact zeros; rows behind it are not
+    written).  Backward: the live rows' cotangents summed into their tokens
+    (a token has 0 to ``top_k`` rows here)."""
+    chunk = _live_chunk_rows(plan, xt.shape[1] * xt.dtype.itemsize)
+    return _dispatch_held(xt, plan.padded_to_row // top_k, live_rows(plan),
+                          chunk)
+
+
+def _gate_and_token(gates, padded_to_row, top_k, start, chunk):
+    """Of the ``chunk`` padded rows from ``start`` on: the routed element,
+    its gate (0 on a padding row, whose element is ``R``) and its token
+    (``R // top_k``: none)."""
+    element = _chunk_of(padded_to_row, start, chunk)
+    gate = jnp.take(gates, element, mode="fill", fill_value=0)
+    return element, gate, element // top_k
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine_held(y, gates, padded_to_row, live, top_k, chunk):
+    def gated(start, n):
+        _, gate, _ = _gate_and_token(gates, padded_to_row, top_k, start, n)
+        return gate.astype(y.dtype)[:, None] * _chunk_of(y, start, n)
+
+    return _sum_live_into_tokens(gated, padded_to_row // top_k,
+                                 gates.shape[0] // top_k, y, live, chunk)
+
+
+def _combine_held_fwd(y, gates, padded_to_row, live, top_k, chunk):
+    return (_combine_held(y, gates, padded_to_row, live, top_k, chunk),
+            (y, gates, padded_to_row, live))
+
+
+def _combine_held_bwd(top_k, chunk, res, g):
+    y, gates, padded_to_row, live = res
+    routed = gates.shape[0]
+
+    def pull(start, first, carry):
+        dy, dgates = carry
+        element, gate, at = _gate_and_token(gates, padded_to_row, top_k,
+                                            start, chunk)
+        g_rows = _rows_or_zeros(g, at)
+        d = jnp.sum(_chunk_of(y, start, chunk).astype(jnp.float32)
+                    * g_rows.astype(jnp.float32), axis=-1)
+        # (a row behind the prefix that the last chunk reaches is dropped
+        # with its element, ``routed``, whatever ``y`` holds there)
+        return (_put_chunk(dy, gate.astype(y.dtype)[:, None] * g_rows, start),
+                dgates.at[jnp.where(_seen(start, first, chunk), routed,
+                                    element)].add(d, mode="drop"))
+
+    dy, dgates = _over_live_chunks(
+        y.shape[0], chunk, live, pull,
+        (_unwritten(y.shape, y.dtype, g, "dy"),
+         jnp.zeros((routed,), jnp.float32)))
+    return dy, dgates.astype(gates.dtype), None, None
 
 
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
@@ -433,15 +645,96 @@ _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 def combine_held_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
                       top_k: int):
-    """As :func:`combine_rows` for a held-subset plan: expert outputs
-    ``y`` [Mp, D], each weighted by its routed element's gate (``gates``
-    flat [T*top_k]; a padding row's is 0), summed into their tokens ->
-    [T, D].  A token none of whose choices is held here gets zeros: what
-    the absent experts would have added is left out."""
-    gate_of_row = jnp.take(gates, plan.padded_to_row, mode="fill",
-                           fill_value=0)
-    return _combine_held(y, gate_of_row, plan.padded_to_row // top_k,
-                         gates.shape[0] // top_k)
+    """As :func:`combine_rows` for a held-subset plan: the live prefix of
+    the expert outputs ``y`` [Mp, D], each row weighted by its routed
+    element's gate (``gates`` flat [T*top_k]; a padding row's is 0), summed
+    into their tokens -> [T, D], a chunk at a time into one float32
+    accumulator.  A token none of whose choices is held here gets zeros:
+    what the absent experts would have added is left out.  Backward: the
+    prefix's rows of ``dy`` from their tokens' cotangents, and each routed
+    element's ``dgate`` summed into its place."""
+    chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
+    return _combine_held(y, gates, plan.padded_to_row, live_rows(plan),
+                         top_k, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _map_live(fn, chunk, live, xs):
+    shape = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (chunk,) + x.shape[1:], x.dtype) for x in xs))
+
+    def apply(start, first, out):
+        return _put_chunk(
+            out, fn(*(_chunk_of(x, start, chunk) for x in xs)), start)
+
+    return _over_live_chunks(
+        xs[0].shape[0], chunk, live, apply,
+        _unwritten(xs[0].shape[:1] + shape.shape[1:], shape.dtype, xs[-1],
+                   "mapped"))
+
+
+def _map_live_fwd(fn, chunk, live, xs):
+    return _map_live(fn, chunk, live, xs), (live, xs)
+
+
+def _map_live_bwd(fn, chunk, res, g):
+    live, xs = res
+
+    def pull(start, first, dxs):
+        _, vjp = jax.vjp(fn, *(_chunk_of(x, start, chunk) for x in xs))
+        return tuple(_put_chunk(dx, d, start) for dx, d in
+                     zip(dxs, vjp(_chunk_of(g, start, chunk))))
+
+    return None, _over_live_chunks(
+        g.shape[0], chunk, live, pull,
+        tuple(_unwritten(x.shape, x.dtype, g, f"pulled{i}")
+              for i, x in enumerate(xs)))
+
+
+_map_live.defvjp(_map_live_fwd, _map_live_bwd)
+
+
+def map_live_rows(fn, plan: GroupPlan, *xs):
+    """``fn(*rows)`` row by row over the live prefix of the plan's
+    ``[Mp, ·]`` arrays ``xs`` -> [Mp, ·], a chunk at a time (``fn`` acts on
+    each row alone: the activation between two grouped calls).  Backward:
+    ``fn``'s own, chunk by chunk over the same prefix."""
+    widest = max(x.shape[1] * x.dtype.itemsize for x in xs)
+    return _map_live(fn, _live_chunk_rows(plan, widest), live_rows(plan), xs)
+
+
+def _sum_live(chunk, live, gs):
+    """The sum of ``gs`` [Mp, ·] over the live prefix, taken in place in
+    the first (the others' rows are added to its own, so no further
+    ``[Mp, ·]`` buffer is live while they are)."""
+    into, *others = gs
+
+    def add(start, first, acc):
+        mine = _chunk_of(acc, start, chunk)
+        total = functools.reduce(
+            jnp.add, (_chunk_of(g, start, chunk) for g in others), mine)
+        return _put_chunk(acc, jnp.where(
+            _seen(start, first, chunk)[:, None], mine, total), start)
+
+    return _over_live_chunks(into.shape[0], chunk, live, add, into)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _fan_out(ways, chunk, x, live):
+    return (x,) * ways
+
+
+_fan_out.defvjp(
+    lambda ways, chunk, x, live: ((x,) * ways, live),
+    lambda ways, chunk, live, gs: (_sum_live(chunk, live, gs), None))
+
+
+def fan_out_live_rows(x, plan: GroupPlan, ways: int):
+    """``x`` [Mp, ·] ``ways`` times, for as many grouped calls that read
+    it: the sum of their cotangents, which autodiff would take over every
+    padded row, is taken over the live prefix."""
+    chunk = _live_chunk_rows(plan, x.shape[1] * x.dtype.itemsize)
+    return _fan_out(ways, chunk, x, live_rows(plan))
 
 
 # ------------------------------------------------------------- reference
@@ -473,20 +766,25 @@ def _ref_ggemm_q(x, q, scales, plan: GroupPlan, out_dtype):
 # Grid (N/bn, m_tiles, K/bk).  Both scalar-prefetched operands reach every
 # kernel and index map: ``gid_ref`` [m_tiles] names each M-tile's expert,
 # ``used_ref`` [1] how many tiles hold a group.
-def _accumulate(i, used_ref, o_ref, acc, n_k, product):
-    """o = Σ_k product() for a tile that holds rows, zeros for one that
-    trails (no MXU work; the next grouped call and ``ds_ggemm_dw`` read
-    those rows).  Where one block spans K (``n_k`` 1) there is no scratch
-    and the product goes straight out."""
+def _accumulate(i, used_ref, o_ref, acc, n_k, product, live_only=False):
+    """o = Σ_k product() for a tile that holds rows (no MXU work for one
+    that trails).  A trailing tile is written as zeros, which the next
+    grouped call and ``ds_ggemm_dw`` read — or, ``live_only``, not at all:
+    its grid steps stay on the last live tile's output block
+    (:func:`_pallas_ggemm`), which they leave as it is and Pallas writes
+    back once, and the rows behind the prefix are nobody's.  Where one
+    block spans K (``n_k`` 1) there is no scratch and the product goes
+    straight out."""
     live = i < used_ref[0]
     if n_k == 1:
         @pl.when(live)
         def _rows():
             o_ref[:] = product().astype(o_ref.dtype)
 
-        @pl.when(jnp.logical_not(live))
-        def _trailing():
-            o_ref[:] = jnp.zeros_like(o_ref)
+        if not live_only:
+            @pl.when(jnp.logical_not(live))
+            def _trailing():
+                o_ref[:] = jnp.zeros_like(o_ref)
         return
     acc_ref, = acc
     k_idx = pl.program_id(2)
@@ -499,13 +797,15 @@ def _accumulate(i, used_ref, o_ref, acc, n_k, product):
     def _rows():
         acc_ref[:] += product()
 
-    @pl.when(k_idx == n_k - 1)
+    last = k_idx == n_k - 1
+
+    @pl.when(jnp.logical_and(last, live) if live_only else last)
     def _finalize():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
 def _ggemm_kernel(gid_ref, used_ref, x_ref, w_ref, o_ref, *acc, n_k,
-                  transpose_rhs, precision):
+                  transpose_rhs, precision, live_only=False):
     """One (j, i, k) step: x_tile @ w[g[i]]_tile in fp32."""
     contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
 
@@ -516,7 +816,8 @@ def _ggemm_kernel(gid_ref, used_ref, x_ref, w_ref, o_ref, *acc, n_k,
             x, w.astype(x.dtype), (contract, ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-    _accumulate(pl.program_id(1), used_ref, o_ref, acc, n_k, product)
+    _accumulate(pl.program_id(1), used_ref, o_ref, acc, n_k, product,
+                live_only)
 
 
 def _dequant_tile(qt, s, j, qblock, block_n, dtype):
@@ -735,12 +1036,13 @@ def _weight_block(transpose_rhs):
 
 
 def _pallas_ggemm(x, w, tiles, block_m, *, blocks, interpret, out_dtype,
-                  transpose_rhs=False, scales=None):
+                  transpose_rhs=False, scales=None, live_only=False):
     """x [Mp, K] group-padded; w [E, K, N] (or [E, N, K] with
     ``transpose_rhs``); ``tiles`` = the plan's ``(block_group_ids
     [Mp // block_m], used_blocks [1])``; ``scales`` [E, K, nb] selects
     the int8 kernel; ``blocks`` (bk, bn) or None for
-    :func:`_choose_blocks`' own."""
+    :func:`_choose_blocks`' own; ``live_only`` (float weights): the
+    result's rows behind the live prefix are not written."""
     Mp, K = x.shape
     bm = block_m
     num_blocks = Mp // bm
@@ -789,7 +1091,7 @@ def _pallas_ggemm(x, w, tiles, block_m, *, blocks, interpret, out_dtype,
     else:
         kernel = functools.partial(
             _ggemm_kernel, n_k=n_k, transpose_rhs=transpose_rhs,
-            precision=precision)
+            precision=precision, live_only=live_only)
         in_specs = [x_spec,
                     pl.BlockSpec((1, bn, bk) if transpose_rhs
                                  else (1, bk, bn),
@@ -802,8 +1104,12 @@ def _pallas_ggemm(x, w, tiles, block_m, *, blocks, interpret, out_dtype,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((bm, bn),
-                                   lambda j, i, k, g, u: (i, j)),
+            # live_only: a trailing step stays on the last live tile's
+            # block, as the row operand does, and nothing is written for it
+            out_specs=pl.BlockSpec(
+                (bm, bn),
+                (lambda j, i, k, g, u: (_live_tile(i, u), j)) if live_only
+                else (lambda j, i, k, g, u: (i, j))),
             scratch_shapes=([pltpu.VMEM((bm, bn), jnp.float32)]
                             if n_k > 1 else []),
         ),
@@ -1052,23 +1358,29 @@ def _ref_ggemm_rows(x, w, eids, out_dtype):
 # block_group_ids and used_blocks) is a primal whose cotangent is
 # symbolic-zero (int32 -> float0).  ``blocks``: (bk, bn) of the forward
 # call, or None for each kernel's own by :func:`_choose_blocks`.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret,
+                live_only):
     return _pallas_ggemm(x, w, tiles, block_m, blocks=blocks,
-                         interpret=interpret, out_dtype=x.dtype)
+                         interpret=interpret, out_dtype=x.dtype,
+                         live_only=live_only)
 
 
-def _ggemm_diff_fwd(x, w, tiles, block_m, num_experts, blocks, interpret):
-    out = _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret)
+def _ggemm_diff_fwd(x, w, tiles, block_m, num_experts, blocks, interpret,
+                    live_only):
+    out = _ggemm_diff(x, w, tiles, block_m, num_experts, blocks, interpret,
+                      live_only)
     return out, (x, w, tiles)
 
 
-def _ggemm_diff_bwd(block_m, num_experts, blocks, interpret, res, g):
+def _ggemm_diff_bwd(block_m, num_experts, blocks, interpret, live_only, res,
+                    g):
     x, w, tiles = res
     # dx: same kernel, transposed contraction against the SAME expert map
     dx = _pallas_ggemm(g.astype(x.dtype), w, tiles, block_m,
                        blocks=blocks and blocks[::-1], interpret=interpret,
-                       out_dtype=x.dtype, transpose_rhs=True)
+                       out_dtype=x.dtype, transpose_rhs=True,
+                       live_only=live_only)
     dw = _pallas_tgmm(x, g.astype(x.dtype), tiles, block_m, num_experts,
                       blocks=blocks, interpret=interpret, out_dtype=w.dtype)
     return dx, dw, None
@@ -1157,12 +1469,12 @@ def ds_ggemm(x, w, plan: GroupPlan, *, out_dtype=None, block_k=None,
         return _pallas_ggemm(x, w, tiles, plan.block_m, blocks=blocks,
                              interpret=interp,
                              out_dtype=out_dtype or x.dtype,
-                             transpose_rhs=True)
+                             transpose_rhs=True, live_only=plan.live_only)
     with _maybe_span(x, {"shape": f"{x.shape[0]}x{w.shape[1]}"
                                   f"x{w.shape[2]}",
                          "experts": int(w.shape[0]), "int8": False}):
         out = _ggemm_diff(x, w, tiles, plan.block_m, plan.num_experts,
-                          blocks, interp)
+                          blocks, interp, plan.live_only)
     if out_dtype is not None:
         out = out.astype(out_dtype)
     return out

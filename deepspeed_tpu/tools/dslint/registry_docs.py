@@ -244,6 +244,11 @@ METRICS = {
                         "counted per routing step",
     "moe/aux_loss": "weighted load-balancing aux loss gauge",
     "moe/z_loss": "router z-loss gauge",
+    "moe/held_live_rows": "rows of a held-subset plan's live prefix "
+                          "(used_blocks tiles: what dispatch, the grouped "
+                          "kernels and combine walk), per expert layer run",
+    "moe/held_plan_rows": "static length of that plan (held_rows_bound + "
+                          "one tile per held expert)",
     # --- numerics observatory (training health, ISSUE 15)
     "num/grad_norm": "last resolved global gradient norm (-1 = "
                      "non-finite)",

@@ -12,7 +12,11 @@ and, as a wider sample, for the other shares of the same routing.
         --workload <cell> --seed <n> ... [--steps 16]
 
 One JSON line per seed: per step the held share's fullest block and the
-fullest of all shares and blocks, as multiples of the even share; the
+fullest of all shares and blocks, as multiples of the even share; per step
+and block the rows of the held plan's live prefix as the expert layer
+itself reports them (``moe/held_live_rows`` beside ``moe/held_plan_rows``,
+gauges of the registry tap: the blocks in the order the device ran them)
+— what dispatch, the grouped kernels and combine walk of the plan; the
 engine's own count of rows over the bound; a last line with the extremes.
 """
 import argparse
@@ -30,6 +34,29 @@ sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
 from drivers.train_steps import build_engine, build_model    # noqa: E402
 from harness import datagen                                   # noqa: E402
 from harness.manifest import Manifest                         # noqa: E402
+
+
+class _HeldPlanTap:
+    """The registry tap of ``moe/layer.py`` as a list: every value a held
+    expert layer sets its two gauges to, in the order they arrive."""
+
+    def __init__(self):
+        self.live, self.plan = [], []
+
+    def set_gauge(self, name, value, **labels):
+        from deepspeed_tpu.moe.layer import HELD_LIVE_ROWS, HELD_PLAN_ROWS
+        if name == HELD_LIVE_ROWS:
+            self.live.append(int(value))
+        elif name == HELD_PLAN_ROWS:
+            self.plan.append(int(value))
+
+    def inc(self, name, value=1.0, **labels):
+        pass
+
+    def taken(self):
+        jax.effects_barrier()
+        live, self.live = self.live, []
+        return live
 
 
 def main():
@@ -61,6 +88,10 @@ def main():
         model = build_model(config)
         cfg = model.config.moe
         engine, _ = build_engine(config, traffic, model, seed, jax.devices())
+        # the tap is in place only while the diagnostic is traced: the
+        # engine's step, traced at its first call below, has no callback
+        from deepspeed_tpu.moe.layer import set_moe_metrics_registry
+        tap = _HeldPlanTap()
         rows = jax.jit(model.meta["routed_rows"])
         stream = datagen.BatchStream(
             traffic, model.config.vocab_size,
@@ -68,13 +99,19 @@ def main():
         even = traffic["micro_batch_per_chip"] * traffic["seq_len"] \
             * cfg.top_k * cfg.held / cfg.num_experts
         mine = cfg.expert_offset // cfg.held
-        held, fullest = [], []
+        held, fullest, live = [], [], []
         try:
             for _ in range(args.steps):
                 batch = stream.next()
                 micro = {k: jnp.asarray(np.asarray(v)[0])
                          for k, v in batch.items()}
-                shares = np.asarray(rows(engine.state["params"], micro)) \
+                set_moe_metrics_registry(tap)
+                try:
+                    routed = np.asarray(rows(engine.state["params"], micro))
+                finally:
+                    set_moe_metrics_registry(None)
+                live.append(tap.taken())
+                shares = routed \
                     .reshape(-1, cfg.num_experts // cfg.held, cfg.held) \
                     .sum(-1) / even               # [blocks, shares]
                 held.append(round(float(shares[:, mine].max()), 3))
@@ -90,6 +127,8 @@ def main():
             "even_share_rows": even, "held_rows_factor": cfg.held_rows_factor,
             "held_share_fullest_block": held,
             "any_share_fullest_block": fullest,
+            "held_plan_rows": sorted(set(tap.plan)),
+            "held_live_rows": live,
             "step_counts": engine.step_counts()}), flush=True)
         del engine
     print(json.dumps({"seeds": len(args.seed), "steps": args.steps,

@@ -12,6 +12,10 @@ environment, from the root of a checkout:
     python -c "import tests.conftest, json; \
         from tests import flash_step_texts as f; \
         print(json.dumps({n: f.digest(n) for n in f.FAMILIES}, indent=1))"
+
+tests/data/held_prefix_step_digests.json (PR 39) holds the same at that
+PR's parent commit with the grouped kernels interpreted as well, for every
+family here and in ``HELD_FAMILIES``: ``f.digest(n, grouped_kernels=True)``.
 """
 import functools
 import hashlib
@@ -60,13 +64,34 @@ def _nemotron_h():
         attention_impl="flash")
 
 
+def _joyai():
+    from deepspeed_tpu.models.joyai import joyai_model
+    return joyai_model(
+        "llm-flash", num_layers=3, d_model=64, num_heads=4, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_theta=10000.0, d_ff_dense=96, d_ff=32,
+        shared_expert_d_ff=32, num_experts=16, top_k=4, experts_held=4,
+        expert_offset=8, vocab_size=512, max_seq_len=128, dtype="float32",
+        remat=True)
+
+
 FAMILIES = {"gpt2": _gpt2, "olmoe": _olmoe, "qwen3_next": _qwen3_next,
             "nemotron_h": _nemotron_h}
+#: the families whose expert layers hold a subset of the experts — their
+#: steps run ``moe/layer.py _held_grouped_moe`` — by the family of
+#: ``FAMILIES`` or the builder; tests/test_held_live_prefix.py holds the
+#: others to the parent's text and these to having left it
+HELD_FAMILIES = {"qwen3_next": _qwen3_next, "nemotron_h": _nemotron_h,
+                 "joyai": _joyai}
 
 
-def digest(family: str) -> str:
+def digest(family: str, grouped_kernels: bool = False) -> str:
     """sha256 of the lowered text of ``value_and_grad(loss)`` on a packed
-    [2, 64] batch, the Pallas calls interpreted."""
+    [2, 64] batch, the Pallas calls interpreted; ``grouped_kernels``: the
+    ``ds_ggemm_*`` kernels too, where ``ragged_dot`` stands in for them
+    off the chip."""
+    import os
+    from unittest import mock
     from jax.experimental import pallas as pl
     from deepspeed_tpu.moe import layer as moe_layer
     real = pl.pallas_call
@@ -74,16 +99,20 @@ def digest(family: str) -> str:
     # a metrics tap an earlier test of the process left installed would
     # put its host callbacks into the text
     tap, moe_layer._metrics_registry = moe_layer._metrics_registry, None
-    try:
-        model = FAMILIES[family]()
-        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
-                 "segment_ids": jnp.zeros((2, 64), jnp.int32)}
-        text = jax.jit(jax.value_and_grad(model.loss)).lower(
-            shapes, batch).as_text()
-    finally:
-        pl.pallas_call = real
-        moe_layer._metrics_registry = tap
+    with mock.patch.dict(os.environ):
+        os.environ.pop("DS_GGEMM_INTERPRET", None)
+        if grouped_kernels:
+            os.environ["DS_GGEMM_INTERPRET"] = "1"
+        try:
+            model = {**FAMILIES, **HELD_FAMILIES}[family]()
+            shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+            batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
+                     "segment_ids": jnp.zeros((2, 64), jnp.int32)}
+            text = jax.jit(jax.value_and_grad(model.loss)).lower(
+                shapes, batch).as_text()
+        finally:
+            pl.pallas_call = real
+            moe_layer._metrics_registry = tap
     assert "while" in text      # the interpreted kernels' grid loops
     return hashlib.sha256(text.encode()).hexdigest()
 

@@ -90,13 +90,19 @@ def _decode(q, k, v, n, ks=None, vs=None):
     return decode_attention_pallas(q, k, v, n, k_scale=ks, v_scale=vs)
 
 
-def _ggemm(x, w, gids, used, blocks=None):
+def _ggemm(x, w, gids, used, blocks=None, live_only=False):
     """The differentiable grouped GEMM as moe/layer.py's training path
     calls it (ds_ggemm with the reference switched off: no TPU here),
     with the blocks the library chooses for a v5e."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     return gg._ggemm_diff(x, w, (gids, used), gg.DEFAULT_BLOCK_M,
-                          w.shape[0], blocks, False)
+                          w.shape[0], blocks, False, live_only)
+
+
+def _ggemm_held(x, w, gids, used):
+    """... over a held plan (``live_only``): a trailing tile's grid steps
+    stay on the last live tile's output block and write nothing."""
+    return _ggemm(x, w, gids, used, live_only=True)
 
 
 def _ggemm_streamed(x, w, gids, used):
@@ -220,13 +226,13 @@ KERNEL_CASES = {
                               _GGEMM_DOWN),
     "ds_ggemm_mixtral_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
                                  _GGEMM_MIXTRAL),
-    "ds_ggemm_held_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_held_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                               _GGEMM_HELD),
-    "ds_ggemm_held_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_held_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                                    _GGEMM_HELD_DOWN),
-    "ds_ggemm_relu2_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_relu2_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                                _GGEMM_RELU2),
-    "ds_ggemm_relu2_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_relu2_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                                     _GGEMM_RELU2_DOWN),
     "ds_flash_gqa_s8192_hd256_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA_8K),
@@ -234,9 +240,9 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA16_8K),
     "ds_flash_mla_s8192_dk192_dv128_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_MLA_8K),
-    "ds_ggemm_w768_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_w768_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                               _GGEMM_W768),
-    "ds_ggemm_w768_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm), (0, 1)),
+    "ds_ggemm_w768_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                                    _GGEMM_W768_DOWN),
     "ds_ggemm_mixtral_streamed_fwd_bwd": (
         jax.grad(_sum_sq(_ggemm_streamed), (0, 1)), _GGEMM_MIXTRAL),
@@ -390,6 +396,74 @@ def test_flash_vmem_budget_is_the_device_kinds(monkeypatch):
         "compiler_params"].vmem_limit_bytes
     assert flash.working_set_bytes(8192, 256, 2, packed=True) < limit \
         <= 128 << 20
+
+
+def test_a_held_layer_walks_its_live_prefix_on_a_v5e(v5e, monkeypatch):
+    """joyai-llm-flash.packed-s8192-gas2's expert layer (16 of 256 experts
+    held, ``held_rows_factor`` 16: a plan of 133,120 rows for 16,384
+    tokens), forward and backward: the loops' buffers are ``ds_unwritten_*``
+    calls, each loop updates its buffer in place (no copy of an
+    ``[Mp, ·]`` array anywhere in the compiled text), and no instruction
+    outside a loop makes a pass over the plan's rows but the kernels — and
+    the one-pass way of the two sums into tokens, which the step takes
+    only where the plan is more than three eighths live."""
+    import re
+    from deepspeed_tpu.comm.mesh import sharding_pin_scope
+    from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
+                                         moe_layer)
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    monkeypatch.setattr(gg.vmem, "device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    monkeypatch.setattr(gg, "_use_reference",
+                        lambda interpret: (False, False))
+    config = MoEConfig(d_model=2048, d_ff=768, num_experts=256, top_k=8,
+                       experts_held=16, expert_offset=32,
+                       held_rows_factor=16, router="sigmoid",
+                       dispatch_mode="grouped")
+    params = jax.tree.map(
+        lambda a: _arg(v5e[0], a.shape,
+                       jnp.bfloat16 if a.ndim == 3 else a.dtype),
+        jax.eval_shape(lambda: init_moe_params(config,
+                                               jax.random.PRNGKey(0))))
+
+    def loss(params, x):
+        out, aux = moe_layer(params, x, config, train=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+    with sharding_pin_scope(False):
+        text = jax.jit(jax.grad(loss, (0, 1))).lower(
+            params, _arg(v5e[0], (2, 8192, 2048))).compile().as_text()
+    rows = 16 * 8 * 16384 * 16 // 256 + 16 * 128
+    assert rows == 133120
+    for what in ("rows", "mapped", "dy", "pulled0", "pulled1"):
+        assert f"ds_unwritten_{what}" in text
+    # the two sums into tokens choose their way in the step: set aside the
+    # way a plan this empty never takes (``cond``'s first branch: one
+    # scatter-add over every row of the plan, as before PR 39)
+    blocks = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n.*?^\}$", text, re.M | re.S)}
+    one_pass = re.findall(r" conditional\(.*branch_computations=\{%([^,}]+)",
+                          text)
+    assert len(one_pass) == 2
+    for name in one_pass:
+        for called in re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                                 blocks[name]):
+            if called not in one_pass:
+                one_pass.append(called)
+    lines = [line for name, block in blocks.items() if name not in one_pass
+             for line in block.splitlines()]
+    whole = re.compile(rf"= (?:bf16|f32)\[{rows},(?:768|2048)\]\S* (\S+?)\(")
+    ops = {m.group(1) for line in lines
+           if " ROOT " not in line for m in [whole.search(line)] if m}
+    # the kernels and the buffers; a loop's result; its in-place update
+    assert ops <= {"custom-call", "while", "get-tuple-element", "parameter",
+                   "dynamic-update-slice", "fusion", "bitcast"}, ops
+    assert not re.search(rf" copy\(\S+\), .*\[{rows},", text)
+    fusions = [line for line in lines
+               if whole.search(line) and " fusion(" in line]
+    # a fusion that results in an [Mp, ·] array is an update in place
+    assert all("dynamic-update-slice" in line or "dynamic_update_slice"
+               in line for line in fusions), fusions[:2]
 
 
 @pytest.mark.parametrize("manual_outside", [False, True],
