@@ -1,7 +1,9 @@
 """Driver ``train_steps``: a training job through the program's normal
 entry points — ``deepspeed_tpu.initialize`` then ``engine.train_batch``
 on a fresh batch every optimizer step — measured over a window of
-``--seconds`` seconds after warm-up.
+``--seconds`` seconds after warm-up.  ``--seed`` makes the traffic (the
+documents' lengths and the token draws) and nothing else: the weights are
+the configuration's own draw, ``deployment.init_seed``.
 
 ``run_cell`` takes the configuration and the traffic as dictionaries and
 the devices as an argument, so a test rehearses it at toy size on the
@@ -17,11 +19,13 @@ import sys
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from harness import datagen, flops, trace as tr
 from harness.compile_log import CompileLog
 from harness.device import device_fields, peak_bytes
+from harness.manifest import init_seed
 from harness.spans import Spans
 
 KERNEL = "tpu_custom_call"      # how a Mosaic kernel reads in HLO text
@@ -57,13 +61,18 @@ def build_model(config):
 
 
 def build_engine(config, traffic, model, seed, devices):
+    """The engine of one cell.  Its weights are the configuration's own
+    draw, ``deployment.init_seed``; ``seed``, the run's ``--seed``, makes
+    the traffic and is not handed to the engine (the parameter stays for
+    the scripts outside the benchmark that call with it)."""
     import deepspeed_tpu
+    del seed
     engine_config = {
         **config["deployment"]["engine_config"],
         "train_micro_batch_size_per_gpu": traffic["micro_batch_per_chip"],
         "gradient_accumulation_steps":
             traffic["gradient_accumulation_steps"],
-        "seed": seed,
+        "seed": init_seed(config),
     }
     spec = config["deployment"]["mesh"]
     mesh = jax.sharding.Mesh(
@@ -85,6 +94,14 @@ def check_split(engine, n, problems):
                 and all(s.data.size * n == leaf.size for s in shards)):
             check(False, f"{jax.tree_util.keystr(path)} {leaf.shape} is "
                          f"not split {n} ways ({leaf.sharding})", problems)
+
+
+def params_sum(engine):
+    """The float32 sum of every parameter, in one program: two runs that
+    start from one draw of the weights print the same number."""
+    return float(jax.jit(lambda params: sum(
+        jnp.sum(leaf.astype(jnp.float32))
+        for leaf in jax.tree.leaves(params)))(engine.state["params"]))
 
 
 def state_bytes_per_device(engine):
@@ -172,6 +189,7 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
              keep_trace=None):
     """One run of one cell.  Returns the result object of the last line,
     its ``metrics`` as {name: value} (run.py adds the manifest's units).
+    ``seed`` is the traffic's (see ``build_engine`` for the weights').
     ``t_origin`` is where ``setup_s`` counts from (run.py: process start,
     moved on by the TPU runtime's own start-up, which comes first there);
     ``config["checks"]`` is what the cell is held to; ``layer_metrics`` is
@@ -179,6 +197,7 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
     ``keep_trace`` is a path to copy the raw ``.xplane.pb`` to (how
     tests/data's recording was made)."""
     problems = []
+    weights_seed = init_seed(config)    # a refusal before anything is built
     checks = config["checks"]
     learn = checks["learn_check"]
     phases = Phases(phases)
@@ -202,6 +221,7 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
     flops_per_token = flops.resolve(flops_function)(sizes, s_eff)
     stream = datagen.BatchStream(traffic, sizes["vocab_size"], global_micro,
                                  seed)
+    initial_params_sum = params_sum(engine)
     phases.mark("initialize_s")
     losses = []                 # device scalars, one per optimizer step
 
@@ -305,7 +325,8 @@ def run_cell(cell, config, traffic, layer_metrics, seed, seconds, trace,
     check(mark_after == 0 or mark_before < mark_after,
           f"the reference check set the memory peak ({mark_before} >= "
           f"{mark_after}); lower reference_chunk_tokens_per_chip", problems)
-    say(line="run", cell=cell, seed=seed, steps=steps, window_s=window_s,
+    say(line="run", cell=cell, seed=seed, init_seed=weights_seed,
+        initial_params_sum=initial_params_sum, steps=steps, window_s=window_s,
         tokens_per_step=tokens_per_step, s_eff=s_eff,
         flops_per_token=flops_per_token, flops_function=flops_function,
         n_params=sizes["n_params"],

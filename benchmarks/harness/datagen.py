@@ -3,7 +3,9 @@
 Documents have log-normal lengths with the short ones left out (a
 corpus filter: drawn again, not clipped); each starts with the
 end-of-text id and continues with tokens drawn Zipf(exponent) over a
-seeded permutation of the rest of the vocabulary.  Documents are
+fixed permutation of the rest of the vocabulary (which ids are the
+frequent ones is a constant of the traffic mix, never the run's seed:
+see ``BatchStream``).  Documents are
 concatenated into one stream and the stream is cut into sequences of
 ``seq_len``, as a GPT data loader does: a document that crosses the cut
 continues at the head of the next sequence.  A traffic file gives the
@@ -81,12 +83,18 @@ class BatchStream:
         self.documents = Documents(self.rng, traffic["documents"])
         tok = traffic["tokens"]
         # rank r (1-based) has weight r^-exponent; ranks map to ids by a
-        # seeded permutation of every id but the end-of-text one
+        # permutation of every id but the end-of-text one that is the same
+        # in every run, as effective_context's sample is: rank 1 is a tenth
+        # of all tokens, so which embedding rows the frequent ranks read
+        # decides which experts a sigmoid router that does not balance
+        # itself sends them to, and with a permutation from the run's seed
+        # the routed cells' rate followed --seed by 2.5% (PERF.md PR 41)
         self.eot = vocab_size - 1
         weights = np.arange(1, vocab_size, dtype=np.float64) \
             ** -float(tok["zipf_exponent"])
         self.cdf = np.cumsum(weights / weights.sum())
-        self.ids = self.rng.permutation(vocab_size - 1).astype(np.int32)
+        self.ids = np.random.default_rng(0).permutation(
+            vocab_size - 1).astype(np.int32)
         self.waits_s = []
         self._queue = queue.Queue(maxsize=PREFETCH_BATCHES)
         self._stop = threading.Event()

@@ -25,6 +25,20 @@ def load_json(path):
         return json.load(f)
 
 
+def init_seed(config):
+    """The draw of the weights a configuration is measured at: its
+    ``deployment.init_seed``, a whole number.  A run's ``--seed`` makes the
+    traffic and nothing else, so a configuration without the key is a
+    refusal to run."""
+    seed = config.get("deployment", {}).get("init_seed")
+    if isinstance(seed, bool) or not isinstance(seed, int):
+        raise SystemExit(
+            f"benchmark: configuration {config.get('name')} states no "
+            f"deployment.init_seed (a whole number: the draw of its "
+            f"weights); has {seed!r}")
+    return seed
+
+
 class Manifest:
     def __init__(self, root=ROOT):
         self.root = root
@@ -119,6 +133,11 @@ def lint(manifest):
         files.add(c["file"])
         if not os.path.isfile(os.path.join(manifest.root, c["file"])):
             bad.append(f"config {c['name']}: {c['file']} missing")
+            continue
+        try:
+            init_seed(manifest.config(c["name"]))
+        except SystemExit as refusal:
+            bad.append(str(refusal))
 
     configs = [c["name"] for c in d["configs"]]
     cells = d["workloads"]

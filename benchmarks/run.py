@@ -2,11 +2,14 @@
 
     python benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The last line of standard output is the result object; earlier lines are
-counts worth keeping.  Any failure to run — no TPU, fewer chips than the
-cell asks for, a device_kind that peaks.json does not know, a checkout
-without the program — is a non-zero exit and no result line.  A run that
-finishes with a failed check prints ``"correct": false``.
+``--seed`` is the traffic's seed: the program receives only the batches it
+makes, and the weights are the configuration's own draw (its
+``deployment.init_seed``).  The last line of standard output is the result
+object; earlier lines are counts worth keeping.  Any failure to run — no
+TPU, fewer chips than the cell asks for, a device_kind that peaks.json
+does not know, a checkout without the program — is a non-zero exit and no
+result line.  A run that finishes with a failed check prints
+``"correct": false``.
 
 Nothing here names a cell, a configuration, a traffic mix or a metric:
 the workload entry names its ``config`` and ``traffic`` (and, by its own

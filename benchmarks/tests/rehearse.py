@@ -42,9 +42,10 @@ def toy(manifest, cell_name, seq_len=64, micro=2):
 
 
 def rehearse(cell_name, root=None, seconds=1.0, trace=False, tmp=".",
-             checks=None):
+             checks=None, seed=1):
     """``checks``: keys of the configuration's ``checks`` to hold the
-    rehearsal to after all (``toy`` switches the kernel checks off)."""
+    rehearsal to after all (``toy`` switches the kernel checks off);
+    ``seed``: the run's ``--seed``, the traffic's."""
     manifest = Manifest(root) if root else Manifest()
     cell, config, traffic = toy(manifest, cell_name)
     config["checks"].update(checks or {})
@@ -53,6 +54,6 @@ def rehearse(cell_name, root=None, seconds=1.0, trace=False, tmp=".",
                for m in manifest.metrics("per_layer", cell_name)} \
         if trace else {}
     return driver.run_cell(
-        cell_name, config, traffic, metrics, seed=1, seconds=seconds,
+        cell_name, config, traffic, metrics, seed=seed, seconds=seconds,
         trace=trace, devices=jax.devices()[:cell["chips"]], peaks=TOY_PEAKS,
         t_origin=time.perf_counter(), work_dir=os.path.join(tmp, "work"))
