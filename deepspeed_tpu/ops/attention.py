@@ -25,17 +25,20 @@ def _kernel_needs_shard_map(q, impl: str) -> bool:
                                      and q.shape[1] >= 256)))
 
 
-def xla_causal_attention(q, k, v, segment_ids=None):
+def xla_causal_attention(q, k, v, segment_ids=None, window=None):
     """Reference einsum attention with causal mask; [B, S, H, hd] layout
     (``v``, and then the result, may be of another width than ``q`` and
     ``k``; the scale is the score width's).  fp32 softmax accumulation for
     bf16 inputs.  ``segment_ids`` [B, S] restricts attention within packed
-    segments."""
+    segments; ``window`` to the last ``window`` keys, the query's own
+    among them."""
     B, S, H, hd = q.shape
     scale = hd ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None, None]
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((S, S), dtype=bool), -window)
     if segment_ids is not None:
         mask = mask & (segment_ids[:, None, :, None]
                        == segment_ids[:, None, None, :])
@@ -196,37 +199,49 @@ def _ds_gqa_causal(q, k, v):
     return ds_flash_attention(q, k, v, causal=True)
 
 
-def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
+#: (block_q, block_k) of the windowed calls: with 512 x 512 a q-block of a
+#: 512-key window visits two key tiles, 1,024 keys a query; chosen on the
+#: chip at S 8192, 72 query heads to 8, window 512 (PERF.md section 6, PR 42)
+WINDOW_BLOCKS = (512, 512)
+
+
+def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
+                            window=None):
+    if window is not None and window >= q.shape[1]:
+        window = None               # every earlier key: the causal program
     gqa = k.shape[2] != q.shape[2]
     # a value head narrower than the score head: the from-scratch kernel
     # takes the two widths, the stock wrapper one — routed as GQA is
     two_widths = v.shape[3] != q.shape[3]
-    if segment_ids is not None:
-        # packed sequences: only the from-scratch kernel (GQA-native,
-        # segment-masked) or the exact einsum can honor the mask
+    if segment_ids is not None or window is not None:
+        # packed sequences, a sliding window: only the from-scratch kernel
+        # (GQA-native, segment-masked, its loops starting at the window's
+        # first tile) or the exact einsum can honor the mask
         from deepspeed_tpu.ops.pallas.ds_flash_attention import \
             ds_flash_attention
+        flash = partial(ds_flash_attention, segment_ids=segment_ids,
+                        causal=True)
+        if window is not None:
+            flash = partial(flash, window=window, block_q=WINDOW_BLOCKS[0],
+                            block_k=WINDOW_BLOCKS[1])
         if impl == "flash":
             # explicit request: no fallback — surface the real error
-            return ds_flash_attention(q, k, v, segment_ids=segment_ids,
-                                      causal=True)
+            return flash(q, k, v)
         if impl == "auto" and _on_tpu() and q.shape[1] >= 256 \
-                and _ds_vmem_ok(q, packed=True, v=v):
+                and _ds_vmem_ok(q, packed=segment_ids is not None, v=v):
             try:
-                return ds_flash_attention(q, k, v,
-                                          segment_ids=segment_ids,
-                                          causal=True)
+                return flash(q, k, v)
             except ValueError:
                 from deepspeed_tpu.utils.logging import warning_once
                 warning_once(
-                    f"packed attention: S={q.shape[1]} does not "
+                    f"packed or windowed attention: S={q.shape[1]} does not "
                     "block-decompose for the flash kernel — exact einsum "
                     "fallback (materialises [S,S] scores)")
         if gqa:
             rep = q.shape[2] // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        return xla_causal_attention(q, k, v, segment_ids)
+        return xla_causal_attention(q, k, v, segment_ids, window)
     if impl == "flash":
         # explicit request: no fallback — surface the real error
         if gqa or two_widths:
@@ -316,14 +331,17 @@ def _local_bidirectional_attention(q, k, v, pad_mask, impl):
     return xla_bidirectional_attention(q, k, v, pad_mask)
 
 
-def causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
+def causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
+                     window=None):
     """q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd]; KV may divide
     H (GQA — the from-scratch flash kernel attends compact KV natively,
     other paths repeat); ``v`` may be narrower than ``q`` and ``k``
     (latent attention), and the result is then ``v``'s width.
     ``segment_ids`` [B, S] restricts attention within packed segments
     (models thread ``batch["segment_ids"]`` here; the from-scratch kernel
-    masks natively, the einsum path exactly).
+    masks natively, the einsum path exactly).  ``window``: query i attends
+    keys j with ``i - j < window`` (None: all before it); the from-scratch
+    kernel skips the tiles outside it, the einsum path masks them.
 
     When the mesh has an active ``seq`` axis, attention runs under Ulysses
     sequence parallelism (head-scatter all-to-all; see sequence/layer.py) —
@@ -335,6 +353,11 @@ def causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
     topo = get_topology()
     sp = topo.mesh.shape[SEQ_AXIS]
     if sp > 1 and topo.sequence_parallel_impl == "ring":
+        if window is not None:
+            raise NotImplementedError(
+                "a sliding window does not compose with ring context "
+                "parallelism (the ring's chunks are whole blocks of keys) — "
+                "use sequence_parallel_impl='ulysses'")
         if segment_ids is not None:
             raise NotImplementedError(
                 "packed sequences (segment_ids) do not compose with ring "
@@ -367,8 +390,9 @@ def causal_attention(q, k, v, impl: str = "auto", segment_ids=None):
             return distributed_attention(
                 q, k, v,
                 lambda a, b, c, seg: _local_causal_attention(
-                    a, b, c, impl, seg),
+                    a, b, c, impl, seg, window),
                 segment_ids=segment_ids)
         return distributed_attention(
-            q, k, v, lambda a, b, c: _local_causal_attention(a, b, c, impl))
-    return _local_causal_attention(q, k, v, impl, segment_ids)
+            q, k, v, lambda a, b, c: _local_causal_attention(
+                a, b, c, impl, window=window))
+    return _local_causal_attention(q, k, v, impl, segment_ids, window)

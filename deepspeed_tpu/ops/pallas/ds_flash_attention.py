@@ -38,8 +38,14 @@ def _causal_kblocks(iq, block_q, block_k, seq_len):
     return jnp.minimum((iq + 1) * block_q // block_k, seq_len // block_k)
 
 
+def _window_first_kblock(iq, block_q, block_k, window):
+    """The first key block a q-block row reaches under a window: its lowest
+    query ``iq * block_q`` sees keys from ``iq * block_q - window + 1``."""
+    return jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+
+
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
-                has_seg):
+                has_seg, window=None):
     if has_seg:
         q_ref, k_ref, v_ref, segq_ref, segk_ref, o_ref, lse_ref = refs
     else:
@@ -56,6 +62,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     n_kblocks = (_causal_kblocks(iq, block_q, block_k, seq_len)
                  if causal else seq_len // block_k)
+    # a window moves the loop's START: key blocks below it are never read
+    first = (0 if window is None
+             else _window_first_kblock(iq, block_q, block_k, window))
 
     def body(j, carry):
         m, l, acc = carry
@@ -70,6 +79,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
         if causal:
             cm = q_pos >= (j * block_k + k_base)
             mask = cm if mask is None else (mask & cm)
+        if window is not None:
+            mask = mask & (q_pos - (j * block_k + k_base) < window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -83,14 +94,14 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l, acc = lax.fori_loop(0, n_kblocks, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(first, n_kblocks, body, (m0, l0, acc0))
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF)
 
 
 def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
-                has_seg):
+                has_seg, window=None):
     """Grid (B, S//block_k, H) with the Q-head dim INNERMOST: consecutive
     grid steps within one rep-group revisit the same dk/dv output block
     (index h//rep), which persists in VMEM — the kernel accumulates into
@@ -119,6 +130,12 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
     dk0 = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
     dv0 = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
     start = (ik * block_k) // block_q if causal else 0
+    stop = seq_len // block_q
+    if window is not None:
+        # the last query that sees this block's last key is window - 1
+        # past it: q blocks beyond are never read
+        stop = jnp.minimum(
+            stop, ((ik + 1) * block_k + window - 2) // block_q + 1)
 
     def body(j, carry):
         dk, dv = carry
@@ -136,6 +153,8 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
         if causal:
             cm = (j * block_q + q_base) >= k_pos
             mask = cm if mask is None else (mask & cm)
+        if window is not None:
+            mask = mask & ((j * block_q + q_base) - k_pos < window)
         p_t = jnp.exp(s_t - lse)
         if mask is not None:
             p_t = jnp.where(mask, p_t, 0.0)
@@ -150,7 +169,7 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    dk, dv = lax.fori_loop(start, seq_len // block_q, body, (dk0, dv0))
+    dk, dv = lax.fori_loop(start, stop, body, (dk0, dv0))
 
     @pl.when(ih % rep == 0)
     def _init():
@@ -164,7 +183,7 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
 
 
 def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
-               has_seg):
+               has_seg, window=None):
     """Transposed score space, like _dkv_kernel (lse/delta as [1, Bq]
     rows); the dq accumulator itself stays [Bq, hd] (contraction over the
     sublane k dim of ds_t)."""
@@ -189,6 +208,8 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     dq0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
     n_kblocks = (_causal_kblocks(iq, block_q, block_k, seq_len)
                  if causal else seq_len // block_k)
+    first = (0 if window is None
+             else _window_first_kblock(iq, block_q, block_k, window))
 
     def body(j, dq):
         k = k_ref[0, 0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
@@ -202,6 +223,8 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
         if causal:
             cm = q_pos >= (j * block_k + k_base)
             mask = cm if mask is None else (mask & cm)
+        if window is not None:
+            mask = mask & (q_pos - (j * block_k + k_base) < window)
         p_t = jnp.exp(s_t - lse)
         if mask is not None:
             p_t = jnp.where(mask, p_t, 0.0)
@@ -212,7 +235,7 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
             ds_t, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    dq = lax.fori_loop(0, n_kblocks, body, dq0)
+    dq = lax.fori_loop(first, n_kblocks, body, dq0)
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
@@ -310,25 +333,45 @@ def _compiler_kw(q, block_q, block_k, packed, v=None):
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
-def _record_call(q, k, v, block_q, block_k, packed):
+def window_k_tiles(window, block_q, block_k):
+    """Key tiles the forward and dq loops visit for a q-block far enough
+    from the sequence's start: from the tile of its first query's lowest
+    key to the tile of its last query (``block_q`` a multiple of
+    ``block_k``)."""
+    return block_q // block_k + -(-(window - 1) // block_k)
+
+
+def _record_call(q, k, v, block_q, block_k, packed, window=None):
     """This call's row of the step's account
     (``tracing.flash_calls``): shapes only, written while the
-    step is traced."""
+    step is traced.  A windowed call's row also holds its ``window`` and
+    the key tiles a q-block visits."""
     from deepspeed_tpu.telemetry.tracing import count_in_step
     B, S, H, hd = q.shape
+    bq, bk = _choose_blocks(S, block_q, block_k)
     row = {"batch": B, "seq_len": S, "heads": H, "kv_heads": k.shape[2],
            "dk": hd, "dv": v.shape[3], "packed": packed,
-           "blocks": list(_choose_blocks(S, block_q, block_k)),
+           "blocks": [bq, bk],
            "vmem_limit_bytes": _vmem_limit(q, block_q, block_k, packed, v)}
-    count_in_step(flash_calls={
-        f"{B}x{S}x{H}x{k.shape[2]}x{hd}x{v.shape[3]}x{int(packed)}": row})
+    key = f"{B}x{S}x{H}x{k.shape[2]}x{hd}x{v.shape[3]}x{int(packed)}"
+    if window is not None:
+        row.update(window=window,
+                   k_tiles_per_q_block=window_k_tiles(window, bq, bk))
+        key += f"w{window}"
+    count_in_step(flash_calls={key: row})
 
 
 def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
-                       sm_scale=None, block_q=512, block_k=512):
+                       sm_scale=None, block_q=512, block_k=512,
+                       window=None):
     """q [B, S, H, dk], k [B, S, KV, dk], v [B, S, KV, dv] -> [B, S, H,
     dv], ``dv <= dk``; ``sm_scale`` defaults to ``dk ** -0.5``.  KV may
     divide H (grouped-query attention — KV streams once per group).
+    ``window`` (causal only): query i attends keys j with ``i - j <
+    window``; the three kernels' loops start (dK/dV's: stop) at the first
+    tile the window reaches, so tiles outside it are never read, and the
+    calls are named ``ds_flash_win_*``.  None, or a window no shorter than
+    the sequence, is the causal program as it always was.
     ``segment_ids``: None or a [B, S] array (any integer or float dtype —
     cast to int32 here, ONCE, so the custom_vjp's float0 cotangent always
     matches an integer primal); packed sequences attend only within their
@@ -337,26 +380,37 @@ def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
     steps)."""
     if segment_ids is not None:
         segment_ids = segment_ids.astype(jnp.int32)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"ds_flash_attention: a window is a causal one of at least "
+                f"one key (the query's own), not window={window} with "
+                f"causal={causal}")
+        if window >= q.shape[1]:
+            window = None
     return _ds_flash(q, k, v, segment_ids, causal, sm_scale, block_q,
-                     block_k)
+                     block_k, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _ds_flash(q, k, v, segment_ids, causal, sm_scale, block_q, block_k):
-    o, _ = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _ds_flash(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
+              window):
+    o, _ = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
+                window=window)
     return o
 
 
 def _ds_flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q,
-                  block_k):
-    o, res = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k)
+                  block_k, window):
+    o, res = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
+                  window=window)
     return o, (res, segment_ids)
 
 
-def _ds_flash_bwd(causal, sm_scale, block_q, block_k, res_seg, do):
+def _ds_flash_bwd(causal, sm_scale, block_q, block_k, window, res_seg, do):
     res, segment_ids = res_seg
     dq, dk, dv = _bwd_rule(segment_ids, causal, sm_scale, block_q,
-                           block_k, res, do)
+                           block_k, res, do, window)
     if segment_ids is None:
         return dq, dk, dv, None
     import numpy as np
@@ -368,7 +422,7 @@ _ds_flash.defvjp(_ds_flash_fwd, _ds_flash_bwd)
 
 
 def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
-         interpret=None):
+         interpret=None, window=None):
     # interpret=None leaves the pallas default (and any test monkeypatch)
     # in force; True forces interpret mode (ring path off-TPU)
     _ikw = {} if interpret is None else {"interpret": interpret}
@@ -386,7 +440,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     bq, bk = _choose_blocks(S, block_q, block_k)
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     has_seg = segment_ids is not None
-    _record_call(q, k, v, block_q, block_k, has_seg)
+    _record_call(q, k, v, block_q, block_k, has_seg, window)
     # TPU-legal layouts for per-row operands (Mosaic requires the last two
     # block dims to divide (8, 128) or equal the array dims — a bare
     # [B, S] block fails): segment ids (int32, cast once in the public
@@ -396,7 +450,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     # Unpacked batches drop the segment operands entirely.
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm, causal=causal, block_q=bq, block_k=bk,
-        seq_len=S, has_seg=has_seg)
+        seq_len=S, has_seg=has_seg, window=window)
     operands = [qT, kT, vT]
     in_specs = [
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
@@ -411,7 +465,9 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
         in_specs += [pl.BlockSpec((1, bq, 1), lambda b, h, i: (b, i, 0)),
                      pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0))]
     oT, lse = pl.pallas_call(
-        kernel, grid=(B, H, S // bq), name="ds_flash_fwd", **_ikw,
+        kernel, grid=(B, H, S // bq),
+        name="ds_flash_fwd" if window is None else "ds_flash_win_fwd",
+        **_ikw,
         **_compiler_kw(q, block_q, block_k, has_seg, v),
         in_specs=in_specs,
         out_specs=[
@@ -426,17 +482,19 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     return o, (q, k, v, o, lse[..., 0])
 
 
-def _bwd_rule(segment_ids, causal, sm_scale, block_q, block_k, res, do):
+def _bwd_rule(segment_ids, causal, sm_scale, block_q, block_k, res, do,
+              window=None):
     q, k, v, o, lse = res
     doT, oT = _to_bhsd(do), _to_bhsd(o)
     delta = jnp.sum(doT.astype(jnp.float32) * oT.astype(jnp.float32),
                     axis=-1)                              # [B, H, S]
     return _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal,
-                      sm_scale, block_q, block_k)
+                      sm_scale, block_q, block_k, window=window)
 
 
 def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
-               block_q, block_k, interpret=None, keep_fp32=False):
+               block_q, block_k, interpret=None, keep_fp32=False,
+               window=None):
     """The two backward pallas calls, driven by EXPLICIT lse/delta — the
     ring-attention composition feeds the GLOBAL logsumexp and delta here
     so each K/V chunk's contribution is the exact global-softmax term.
@@ -462,7 +520,7 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
     # shared (b, h//rep, i) fp32 output block
     dkv_kernel = functools.partial(
         _dkv_kernel, sm_scale=sm, causal=causal, block_q=bq, block_k=bk,
-        seq_len=S, rep=rep, has_seg=has_seg)
+        seq_len=S, rep=rep, has_seg=has_seg, window=window)
     dkv_in = [qT, kT, vT, doT, lse_r, delta_r]
     dkv_specs = [
         pl.BlockSpec((1, 1, S, hd), lambda b, i, h: (b, h, 0, 0)),
@@ -498,8 +556,9 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         dq_specs += [pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
                      pl.BlockSpec((1, S, 1), lambda b, h, i: (b, 0, 0))]
     dkT, dvT = pl.pallas_call(
-        dkv_kernel, grid=(B, S // bk, H), name="ds_flash_bwd_dkv", **_ikw,
-        **_ckw,
+        dkv_kernel, grid=(B, S // bk, H),
+        name="ds_flash_bwd_dkv" if window is None
+        else "ds_flash_win_bwd_dkv", **_ikw, **_ckw,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd),
@@ -512,10 +571,11 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
 
     dq_kernel = functools.partial(
         _dq_kernel, sm_scale=sm, causal=causal, block_q=bq, block_k=bk,
-        seq_len=S, has_seg=has_seg)
+        seq_len=S, has_seg=has_seg, window=window)
     dqT = pl.pallas_call(
-        dq_kernel, grid=(B, H, S // bq), name="ds_flash_bwd_dq", **_ikw,
-        **_ckw,
+        dq_kernel, grid=(B, H, S // bq),
+        name="ds_flash_bwd_dq" if window is None else "ds_flash_win_bwd_dq",
+        **_ikw, **_ckw,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(

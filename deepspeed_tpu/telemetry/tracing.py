@@ -357,21 +357,34 @@ SCOPE_SCORES = "scores"             # ... softmax(q k^T) v: the flash kernels
 # (models/joyai.py): its embedding, its projection, its block (whose
 # scopes are ``ds.block``'s, beneath this one), its norm, head and loss
 SCOPE_MTP = "ds.mtp"
+# around one attention sublayer, where a stack has two kinds of them with
+# different head counts (models/laguna.py): ``attn`` and its parts lie
+# beneath, so that a reader can tell a windowed layer's time from a full
+# one's; ``ds.head_gate`` is the per-head sigmoid gate on the attention's
+# output, ``ds.lead_mlp`` the leading layer's dense feed-forward
+SCOPE_ATTN_FULL = "ds.attn_full"
+SCOPE_ATTN_SLIDING = "ds.attn_sliding"
+SCOPE_HEAD_GATE = "ds.head_gate"
+SCOPE_LEAD_MLP = "ds.lead_mlp"
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
                SCOPE_COMBINE, SCOPE_SHARED_EXPERT, SCOPE_LINEAR_ATTN,
                SCOPE_IN_PROJ, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
                SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN, SCOPE_Q_LATENT,
-               SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP)
+               SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP,
+               SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_HEAD_GATE,
+               SCOPE_LEAD_MLP)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
-#: the state-space scan's two and the short causal convolution's two
+#: the state-space scan's two and the short causal convolution's two, and
+#: the flash kernel's three where the call has a sliding window
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
                 "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd",
-                "ds_conv_fwd", "ds_conv_bwd")
+                "ds_conv_fwd", "ds_conv_bwd", "ds_flash_win_fwd",
+                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost

@@ -1,0 +1,129 @@
+"""The windowed flash kernels on the chip: per (block_q, block_k) one JSON
+line with the milliseconds of a forward call and of a forward + backward
+call (``ds_flash_win_fwd``; ``ds_flash_win_bwd_dkv`` + ``_bwd_dq``) at a
+sliding layer's shapes — by default Laguna-S-2.1's cell: one packed
+sequence of 8,192, 72 query heads to 8 KV heads of 128, a window of 512
+keys, documents drawn as the cell's traffic draws them — beside the same
+shapes under the causal mask alone (the tiles only masked: what the window
+saves) and a full layer's call (48 heads, causal).  Slope-timed
+(scripts/bench_util.py ``timed_chain``).  The blocks
+``ops/attention.py WINDOW_BLOCKS`` holds are the ones chosen from this
+table (PERF.md section 6, PR 42).
+
+    python scripts/flash_window_table.py [--seed 1] [--blocks 512x512,256x256]
+
+Fails without a TPU: a time from the CPU is not a time.
+"""
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+
+from scripts.bench_util import timed_chain
+
+DEFAULT_BLOCKS = "512x512,512x256,256x256,256x128,128x128,1024x512"
+
+
+def segments(traffic, seed):
+    from harness import datagen
+    documents = datagen.Documents(np.random.default_rng(seed),
+                                  traffic["documents"])
+    seg = np.zeros(traffic["seq_len"], np.int32)
+    at = 0
+    for i, (n, _) in enumerate(documents.row(traffic["seq_len"])):
+        seg[at:at + n] = i
+        at += n
+    return jnp.asarray(seg[None])
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--blocks", default=DEFAULT_BLOCKS)
+    ap.add_argument("--traffic", default="packed-s8192-gas4")
+    ap.add_argument("--window", type=int, default=512)
+    ap.add_argument("--heads", type=int, default=72)
+    ap.add_argument("--full-heads", type=int, default=48)
+    ap.add_argument("--kv-heads", type=int, default=8)
+    ap.add_argument("--head-dim", type=int, default=128)
+    args = ap.parse_args()
+    if jax.devices()[0].platform != "tpu":
+        sys.exit("flash_window_table: no TPU here; a kernel's time comes "
+                 "from the chip")
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import (
+        ds_flash_attention, window_k_tiles)
+    from layer_metrics.readers import window_roofline
+    from harness import datagen, device
+    with open(os.path.join(ROOT, "benchmarks", "traffic",
+                           args.traffic + ".json")) as f:
+        traffic = json.load(f)
+    S, hd = traffic["seq_len"], args.head_dim
+    seg = segments(traffic, args.seed)
+    peak = device.peaks_for(jax.devices()[0].device_kind)["bf16_flops_per_s"]
+    need = {  # required keys a query, times two, as the rooflines count them
+        "windowed": window_roofline.keys_times_two(traffic, args.window),
+        "causal": datagen.effective_context(traffic)}
+
+    def time_call(heads, window, blocks):
+        key = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+        q = jax.random.normal(key[0], (1, S, heads, hd), jnp.bfloat16)
+        k = jax.random.normal(key[1], (1, S, args.kv_heads, hd),
+                              jnp.bfloat16)
+        v = jax.random.normal(key[2], (1, S, args.kv_heads, hd),
+                              jnp.bfloat16)
+        attend = lambda q, k, v: ds_flash_attention(
+            q, k, v, segment_ids=seg, window=window, block_q=blocks[0],
+            block_k=blocks[1])
+
+        def fwd(state):
+            q, k, v = state
+            return q + 1e-3 * attend(q, k, v), k, v
+
+        def fwd_bwd(state):
+            q, k, v = state
+            dq, dk, dv = jax.grad(lambda *a: jnp.sum(
+                attend(*a).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+            return q + 1e-3 * dq, k + 1e-3 * dk, v + 1e-3 * dv
+
+        return (timed_chain(fwd, (q, k, v), 10) * 1e3,
+                timed_chain(fwd_bwd, (q, k, v), 5) * 1e3)
+
+    blocks = [tuple(int(n) for n in b.split("x"))
+              for b in args.blocks.split(",")]
+    for what, heads, window in (("windowed", args.heads, args.window),
+                                ("causal_same_heads", args.heads, None),
+                                ("full_layer", args.full_heads, None)):
+        for bq, bk in (blocks if what == "windowed" else [(512, 512)]):
+            try:
+                fwd_ms, both_ms = time_call(heads, window, (bq, bk))
+            except Exception as e:      # a block shape Mosaic refuses
+                print(json.dumps({"call": what, "blocks": [bq, bk],
+                                  "error": f"{type(e).__name__}: {e}"[:300]}),
+                      flush=True)
+                continue
+            kind = "windowed" if what == "windowed" else "causal"
+            # a forward call 4 * H hd per key, forward + backward 12
+            flops = 0.5 * S * heads * hd * need[kind]
+            print(json.dumps({
+                "call": what, "heads": heads, "kv_heads": args.kv_heads,
+                "window": window, "blocks": [bq, bk],
+                "keys_visited_per_query": None if window is None
+                else window_k_tiles(window, bq, bk) * bk,
+                "required_keys_per_query": need[kind] / 2,
+                "fwd_ms": fwd_ms, "fwd_bwd_ms": both_ms,
+                "fwd_roofline_pct": 100 * 4 * flops / peak / (fwd_ms * 1e-3),
+                "fwd_bwd_roofline_pct": 100 * 12 * flops / peak
+                / (both_ms * 1e-3),
+                "device": jax.devices()[0].device_kind}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -69,6 +69,17 @@ def _ds_flash_packed(q, k, v, seg):
     return ds_flash_attention(q, k, v, segment_ids=seg, causal=True)
 
 
+def _ds_flash_windowed(q, k, v, seg):
+    """As the dispatch calls it (ops/attention.py): a 512-key window at
+    the blocks it chose."""
+    from deepspeed_tpu.ops.attention import WINDOW_BLOCKS
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import \
+        ds_flash_attention
+    return ds_flash_attention(q, k, v, segment_ids=seg, causal=True,
+                              window=512, block_q=WINDOW_BLOCKS[0],
+                              block_k=WINDOW_BLOCKS[1])
+
+
 def _stock_flash(q, k, v):
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     return flash_attention(q, k, v, causal=True)
@@ -204,6 +215,20 @@ _QKV_MLA_8K = [((2, 8192, 32, 192), jnp.bfloat16),
 # (gate, up) and back (down)
 _GGEMM_W768 = _ggemm_args(16, 131072 + 16 * 128, 2048, 768)
 _GGEMM_W768_DOWN = _ggemm_args(16, 131072 + 16 * 128, 768, 2048)
+# Laguna-S-2.1's cell: S 8192 packed, micro-batch 1; a sliding layer's 72
+# query heads to 8 KV heads (9 to a group, window 512) and a full layer's 48
+# (6 to a group), head 128 ...
+_QKV_GQA9_8K = [((1, 8192, 72, 128), jnp.bfloat16),
+                ((1, 8192, 8, 128), jnp.bfloat16),
+                ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192), jnp.int32)]
+_QKV_GQA6_8K = [((1, 8192, 48, 128), jnp.bfloat16),
+                ((1, 8192, 8, 128), jnp.bfloat16),
+                ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192), jnp.int32)]
+# ... and its four expert layers: 8 experts held of 256, 8,192 tokens x 10
+# choices, a plan of held_rows_bound 66,560 (26 times the even share: every
+# row 8 experts can be sent) + 8 * 128 rows; D 3072 -> F 1024 and back
+_GGEMM_W1024 = _ggemm_args(8, 66560 + 8 * 128, 3072, 1024)
+_GGEMM_W1024_DOWN = _ggemm_args(8, 66560 + 8 * 128, 1024, 3072)
 # the short causal convolution of both hybrids' mixers, packed, S 8192:
 # Qwen3-Next's q | k | v (8192 channels, positions down sublanes) and
 # Nemotron-H's x | B | C (6144 channels, positions along lanes) whole, and
@@ -240,6 +265,14 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA16_8K),
     "ds_flash_mla_s8192_dk192_dv128_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_MLA_8K),
+    "ds_flash_win512_gqa9_s8192_hd128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_windowed), (0, 1, 2)), _QKV_GQA9_8K),
+    "ds_flash_gqa6_s8192_hd128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA6_8K),
+    "ds_ggemm_w1024_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
+                               _GGEMM_W1024),
+    "ds_ggemm_w1024_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
+                                    _GGEMM_W1024_DOWN),
     "ds_ggemm_w768_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                               _GGEMM_W768),
     "ds_ggemm_w768_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
@@ -294,6 +327,10 @@ NAMED_KERNELS = {
     "ds_flash_gqa16_s8192_hd128_packed_fwd_bwd": {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_flash_mla_s8192_dk192_dv128_packed_fwd_bwd": {
+        "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
+    "ds_flash_win512_gqa9_s8192_hd128_packed_fwd_bwd": {
+        "ds_flash_win_fwd", "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq"},
+    "ds_flash_gqa6_s8192_hd128_packed_fwd_bwd": {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
     "ds_ggemm_w768_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
@@ -361,6 +398,17 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
         # head of 128 beside a score head of 192 stays 128 wide: no padded
         # copy of v, o, do or dv
         assert "stablehlo.pad" not in lowered.as_text()
+    if "win512" in case:
+        # the windowed calls' row of the account: the window, the blocks
+        # the dispatch chose and the key tiles a q-block visits
+        from deepspeed_tpu.ops.attention import WINDOW_BLOCKS
+        from deepspeed_tpu.ops.pallas.ds_flash_attention import \
+            window_k_tiles
+        assert [(c["heads"], c["kv_heads"], c["window"], c["blocks"],
+                 c["k_tiles_per_q_block"])
+                for c in tracing.flash_calls("test/compile")] \
+            == [(72, 8, 512, list(WINDOW_BLOCKS),
+                 window_k_tiles(512, *WINDOW_BLOCKS))]
     if "mla" in case:
         # the step's account of its flash calls has both widths
         assert [(c["dk"], c["dv"], c["heads"], c["kv_heads"], c["packed"])
