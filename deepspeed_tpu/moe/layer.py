@@ -453,8 +453,8 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
     (``grouped_gemm.held_rows_bound``); the rows go out by gathers and
     come back summed into their tokens, and nothing here makes a pass over
     the bound where the bound is mostly empty: every step walks the plan's
-    live prefix, a chunk at a time (a sum over a plan that is more than
-    three eighths live goes in one scatter-add: ``grouped_gemm``).
+    live prefix, a chunk at a time, and the way back the tokens, a block
+    at a time (the kernel ``ds_rowsum``: ``grouped_gemm``).
     Rows over the bound are the
     second number of the statistics (``moe_layer(..., return_stats=True)``;
     the model hands their sum to the engine: :data:`ROWS_OVER_BOUND`)."""

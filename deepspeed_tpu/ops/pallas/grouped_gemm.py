@@ -83,7 +83,8 @@ these kernels from the flash kernel in a compiled step: ``ds_ggemm_fwd``,
 ``ds_ggemm_dx`` (the same kernel body on a transposed right-hand side),
 ``ds_ggemm_dw`` (the three of ``KERNEL_NAMES`` that training runs),
 ``ds_ggemm_q`` (int8 weights) and ``ds_ggemm_slots`` /
-``ds_ggemm_slots_q`` (decode-sized).  ``ds_unwritten_*`` are no kernels
+``ds_ggemm_slots_q`` (decode-sized), and ``ds_rowsum`` (a held plan's
+rows summed into their tokens).  ``ds_unwritten_*`` are no kernels
 but allocations: the buffers a held plan's loops write their chunks into
 (:func:`_unwritten`).
 
@@ -95,10 +96,12 @@ number the step computes from the routing, and every consumer — the
 plan's own lookup, :func:`dispatch_held_rows`, the kernels' tile walk and
 their output, :func:`map_live_rows` between two grouped calls,
 :func:`combine_held_rows`, and each one's backward — walks that prefix and
-stops.  The bound costs memory, not time.  (The two sums into tokens alone
-choose in the step, from the routing: where more than three eighths of a
-plan is live its padding is the cheaper part, and one scatter-add takes
-every row of it — :func:`_sum_live_into_tokens`.)
+stops.  The bound costs memory, not time.  The way back — a held plan's
+rows summed into their tokens, forward in :func:`combine_held_rows` and
+backward in :func:`dispatch_held_rows` — walks the *tokens*: the Mosaic
+kernel ``ds_rowsum`` takes a block of tokens at a time and fetches, expert
+by expert, the run of the plan's rows that are theirs
+(:func:`_sum_live_into_tokens`).
 """
 import functools
 import os
@@ -161,6 +164,12 @@ class GroupPlan(NamedTuple):
     #: it are never written (a held plan, whose bound is mostly unused);
     #: False: the trailing tiles are written as zeros
     live_only: bool = False
+    #: a held plan's (None for a whole plan, whose way back is a gather):
+    #: the held expert of each routed element, ``E`` where its expert is
+    #: held elsewhere.  An expert's rows lie in element order, so the rows
+    #: of a block of tokens are one run in each expert's group, and where
+    #: the runs begin is a count over this (:func:`_token_block_runs`)
+    group_of_element: Optional[jnp.ndarray] = None    # [R]
 
 
 def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
@@ -378,7 +387,10 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     arithmetic and a lookup over the live prefix, reading ``R`` on a
     padding row.  The plan has no ``row_to_padded``: the way back sums
     rows into tokens (:func:`combine_held_rows`), R being mostly rows held
-    elsewhere.  It is ``live_only``: see the section's head."""
+    elsewhere, a block of tokens at a time: ``group_of_element`` is what
+    that way reads of the plan beside ``padded_to_row`` (forward, recompute
+    and backward share both).  It is ``live_only``: see the section's
+    head."""
     R = int(expert_ids.shape[0])
     E = int(experts_held)
     bm = int(block_m or default_block_m())
@@ -424,7 +436,8 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     padded_to_row = _over_live_chunks(
         padded_rows, chunk, live_rows(plan), lookup,
         jnp.full((padded_rows,), R, jnp.int32))
-    return plan._replace(padded_to_row=padded_to_row), over
+    return plan._replace(padded_to_row=padded_to_row,
+                         group_of_element=key), over
 
 
 # ---- the live prefix.  A held plan's length is its bound; its rows are a
@@ -519,63 +532,250 @@ def _token_rows_live(xt, token_of_row, live, chunk):
         _unwritten((Mp,) + xt.shape[1:], xt.dtype, xt, "rows"))
 
 
-#: the live share of a plan (eighths) from which one scatter-add over the
-#: whole plan is the cheaper sum.  Measured on a v5e at 2,048-wide rows
-#: (PERF.md section 6, PR 39): XLA's one scatter over a plan sorts its
-#: indices and costs 0.068 us a plan row + 0.059 a live row; a chunk's
-#: scatter inside the loop, which does not, 0.22-0.25 us a row — so the
-#: loop wins while live rows < 0.36-0.42 of the plan's
-_ONE_PASS_SUM_EIGHTHS = 3
+# ---- the way back: a held plan's rows summed into their tokens
+#: (tokens a grid step takes, rows its stage in VMEM holds) of ``ds_rowsum``,
+#: by device kind (a substring of it; the last: any)
+_ROWSUM_BLOCKS = (("v5 lite", (512, 512)), ("", (256, 256)))
 
 
-def _sum_live_into_tokens(rows_at, token_of_row, tokens, like, live, chunk):
-    """The live prefix's rows (``rows_at(start, n)``: the ``[n, D]`` rows
-    from ``start`` on) summed into ``[tokens, D]`` by ``token_of_row``
-    (``tokens`` = no token: dropped, whatever the row holds): ONE float32
-    accumulator, rounded once to ``like``'s dtype.  Which way is the step's
-    choice from the routing it sees: chunk by chunk over the prefix while
-    that is the smaller part of the plan (``_ONE_PASS_SUM_EIGHTHS``), else
-    — and for a plan of one chunk — every row of the plan in one
-    scatter-add, the rows behind the prefix dropped by their index."""
-    Mp = token_of_row.shape[0]
-    zeros = functools.partial(jnp.zeros, (tokens,) + like.shape[1:],
-                              jnp.float32)
-
-    def add(start, first, acc):
-        at = _chunk_of(token_of_row, start, chunk)
-        return acc.at[jnp.where(_seen(start, first, chunk), tokens, at)].add(
-            rows_at(start, chunk).astype(jnp.float32), mode="drop")
-
-    def by_chunks():
-        return _over_live_chunks(Mp, chunk, live, add,
-                                 zeros()).astype(like.dtype)
-
-    def in_one_pass():
-        return zeros().at[token_of_row].add(
-            rows_at(0, Mp).astype(jnp.float32), mode="drop").astype(
-                like.dtype)
-
-    if chunk >= Mp:
-        return in_one_pass()
-    return jax.lax.cond(live * 8 < Mp * _ONE_PASS_SUM_EIGHTHS, by_chunks,
-                        in_one_pass)
+def _rowsum_copy(dtype) -> int:
+    """Rows of one copy from HBM: a tile of ``dtype`` rows in VMEM, 8 of
+    float32 and 16 of bfloat16 (a bfloat16 row shares its 32-bit words with
+    its neighbour, and Mosaic copies no slice of a tiled array that is not
+    whole tiles).  The plan's M-tile is a multiple of it, so no copy reaches
+    behind the live prefix."""
+    return 32 // jnp.dtype(dtype).itemsize
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_held(xt, token_of_row, live, chunk):
-    return _token_rows_live(xt, token_of_row, live, chunk)
+#: lanes of the int32 array that holds a row's token and gate beside it
+_ROWSUM_META_LANES = 128
 
 
-def _dispatch_held_fwd(xt, token_of_row, live, chunk):
-    return (_token_rows_live(xt, token_of_row, live, chunk),
-            (token_of_row, live, xt.shape[0]))
+class _WayBack(NamedTuple):
+    """What a sum into tokens reads of its plan."""
+    padded_to_row: jnp.ndarray       # [Mp] padded row -> element | R
+    group_of_element: jnp.ndarray    # [R] element -> held expert | E
+    group_sizes: jnp.ndarray         # [E] padded rows an expert
+    counts: jnp.ndarray              # [E] rows routed to it
 
 
-def _dispatch_held_bwd(chunk, res, g):
-    token_of_row, live, tokens = res
-    return (_sum_live_into_tokens(functools.partial(_chunk_of, g),
-                                  token_of_row, tokens, g, live, chunk),
-            None, None)
+def _way_back(plan: GroupPlan) -> _WayBack:
+    return _WayBack(plan.padded_to_row, plan.group_of_element,
+                    plan.group_sizes, plan.counts)
+
+
+def _rowsum_blocks(tokens: int):
+    """(tokens a block, rows a stage)."""
+    kind = vmem.device_kind()
+    bt, rows = next(b for sub, b in _ROWSUM_BLOCKS if sub in kind)
+    return min(bt, _round_up(tokens, 16)), rows
+
+
+def _token_block_runs(back: _WayBack, tokens, top_k, bt):
+    """(first, end) [blocks, E] int32: the rows of block ``b`` of ``bt``
+    tokens that expert ``e`` holds are the padded rows ``[first[b, e],
+    end[b, e])`` — an expert's rows lie in element order from its group's
+    start on, so a block's begin after as many rows as the blocks before it
+    were sent, and a row over the bound (behind what the group keeps) is
+    cut.  Counts and running sums over the routing: no sort, no scatter."""
+    E = back.group_sizes.shape[0]
+    nb, cap = -(-tokens // bt), bt * top_k
+    groups = jnp.pad(back.group_of_element, (0, nb * cap - tokens * top_k),
+                     constant_values=E).reshape(nb, cap)
+    sent = jnp.sum((groups[:, :, None] == jnp.arange(
+        E, dtype=jnp.int32)[None, None, :]).astype(jnp.int32), axis=1)
+    before = jnp.cumsum(sent, axis=0) - sent                   # [nb, E]
+    group_start = jnp.cumsum(back.group_sizes) - back.group_sizes
+    kept = jnp.minimum(back.counts, back.group_sizes)
+    at = lambda n: (group_start + jnp.minimum(n, kept)).astype(  # noqa: E731
+        jnp.int32)
+    return at(before), at(before + sent)
+
+
+def _rowsum_kernel(first_ref, end_ref, y_ref, meta_ref, o_ref, rows, meta,
+                   acc, sem, *, bt, stage, copy, experts, gated, precision):
+    """One block of ``bt`` tokens.  Expert by expert, the run of the plan's
+    rows that are this block's (``[first, end)`` of the tables) is fetched
+    from HBM in whole tiles of ``copy`` rows — the rows into
+    ``rows``, their tokens and gates (lanes 0 and 1 of ``meta_ref``) into
+    ``meta`` beside them, every copy of a stage in flight at once — and a
+    full stage (and the last) is added into the tokens' lines of a float32
+    accumulator by one product with the matrix that holds a row's gate (1
+    where there are none) where the row is one of this block's tokens': a
+    tile's other rows, another block's or padding, meet no token here.  The
+    products are exact, the sum float32, one rounding on the way out."""
+    b = pl.program_id(0)
+    acc[:] = jnp.zeros_like(acc)
+    token = jax.lax.broadcasted_iota(jnp.int32, (stage, bt), 1) + b * bt
+    slot = jax.lax.broadcasted_iota(jnp.int32, (stage, 1), 0)
+
+    def copies(tile, at):
+        src = pl.ds(pl.multiple_of(tile * copy, copy), copy)
+        dst = pl.ds(pl.multiple_of(at, copy), copy)
+        return (pltpu.make_async_copy(y_ref.at[src], rows.at[dst], sem.at[0]),
+                pltpu.make_async_copy(meta_ref.at[src], meta.at[dst],
+                                      sem.at[1]))
+
+    def add(filled):
+        """The stage's first ``filled`` rows into the accumulator."""
+        def wait(_, carry):
+            for one in copies(0, 0):
+                one.wait()
+            return carry
+
+        jax.lax.fori_loop(0, filled // copy, wait, 0)
+        x = rows[:]
+        x = jnp.where(slot < filled, x, jnp.zeros_like(x))   # stale: anything
+        m = meta[:]
+        mine = jnp.logical_and(m[:, 0:1] == token, slot < filled)
+        weights = jnp.where(mine, jax.lax.bitcast_convert_type(
+            m[:, 1:2], jnp.float32), 0.0) if gated else mine
+        acc[:] += jax.lax.dot_general(
+            weights.astype(x.dtype), x, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+    def run(e, filled):
+        first, end = first_ref[b * experts + e], end_ref[b * experts + e]
+        tile0 = first // copy
+        tiles = jnp.where(end > first, (end - 1) // copy + 1 - tile0, 0)
+
+        def fetch(tile, filled):
+            @pl.when(filled == stage)
+            def _full():
+                add(stage)
+
+            filled = jnp.where(filled == stage, 0, filled)
+            for one in copies(tile, filled):
+                one.start()
+            return filled + copy
+
+        return jax.lax.fori_loop(tile0, tile0 + tiles, fetch, filled)
+
+    filled = jax.lax.fori_loop(0, experts, run, 0)
+
+    @pl.when(filled > 0)
+    def _the_rest():
+        add(filled)
+
+    o_ref[:] = acc[:].astype(o_ref.dtype)
+
+
+def _pallas_rowsum(y, meta, runs, tokens, blocks, copy, gated, interpret):
+    """``y`` [Mp, D] in plan order; ``meta`` [Mp, lanes] int32: lane 0 a
+    row's token, lane 1 its gate's float32 bits (the live prefix's rows:
+    behind it nothing is read); ``runs`` of :func:`_token_block_runs`
+    -> [tokens, D]."""
+    bt, stage = blocks
+    (Mp, D), dtype = y.shape, y.dtype
+    nb, experts = runs[0].shape
+    need = (stage * D * dtype.itemsize + stage * meta.shape[1] * 4
+            + bt * D * 4 + 2 * bt * D * dtype.itemsize + 2 * bt * stage * 4)
+    limit = vmem.limit_for(need)
+    out = pl.pallas_call(
+        functools.partial(_rowsum_kernel, bt=bt, stage=stage, copy=copy,
+                          experts=experts, gated=gated,
+                          precision=_precision_for(dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nb,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec((bt, D), lambda b, first, end: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((stage, D), dtype),
+                            pltpu.VMEM((stage, meta.shape[1]), jnp.int32),
+                            pltpu.VMEM((bt, D), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((nb * bt, D), dtype),
+        interpret=interpret,
+        compiler_params=limit and pltpu.CompilerParams(
+            vmem_limit_bytes=limit),
+        name="ds_rowsum",
+    )(runs[0].reshape(-1), runs[1].reshape(-1), y, meta)
+    return out[:tokens]
+
+
+def _count_sum(tokens, width, plan_rows, blocks, path):
+    """One row of the step's own account (telemetry/tracing.py
+    ``held_row_sums``) per shape of sum."""
+    from deepspeed_tpu.telemetry.tracing import count_in_step
+    count_in_step(held_row_sums={f"{tokens}x{width}:{plan_rows}": {
+        "tokens": tokens, "width": width, "plan_rows": plan_rows,
+        "blocks": blocks, "path": path}})
+
+
+def _sum_live_into_tokens(y, gates, back: _WayBack, tokens, top_k, live,
+                          chunk):
+    """A held plan's rows ``y`` [Mp, D] — each weighted by its routed
+    element's gate (rounded to ``y``'s dtype; the product float32) where
+    there are ``gates`` (flat, [tokens * top_k]) — summed into
+    ``[tokens, D]``: ONE float32 accumulator a token, rounded once to
+    ``y``'s dtype; a token with no row here gets zeros, and a row behind
+    the live prefix (``live`` rows), whatever it holds, is not read.  On
+    one TPU (and under ``interpret``) the Mosaic kernel ``ds_rowsum``: a
+    block of tokens a grid step, whose rows — one run in each expert's
+    group (:func:`_token_block_runs`) — it fetches from ``y`` in HBM itself
+    and adds up in VMEM, each token written once; what it reads beside
+    ``y`` is an int32 array of the live rows' tokens and gates, written a
+    chunk at a time.  Elsewhere — off the chip, and on more than one
+    device, where the grouped kernels give way to ``ragged_dot`` too — the
+    reference form: one scatter-add of every row of the plan, a row with no
+    element dropped by its index.  The two differ in the order a token's
+    rows are added in, and in nothing else."""
+    Mp, D = y.shape
+    use_reference, interpret = _use_reference(None)
+    if use_reference:
+        _count_sum(tokens, D, Mp, None, "xla")
+        rows = y.astype(jnp.float32)
+        if gates is not None:
+            rows = _gate_in(y.dtype, jnp.take(
+                gates, back.padded_to_row, mode="fill",
+                fill_value=0))[:, None] * rows
+        return jnp.zeros((tokens, D), jnp.float32).at[
+            back.padded_to_row // top_k].add(rows, mode="drop").astype(
+                y.dtype)
+    blocks, copy = _rowsum_blocks(tokens), _rowsum_copy(y.dtype)
+    assert Mp % copy == 0 and blocks[1] % copy == 0, (Mp, blocks, copy)
+    _count_sum(tokens, D, Mp, blocks, "kernel")
+    lane = jnp.arange(_ROWSUM_META_LANES, dtype=jnp.int32)[None, :]
+
+    def describe(start, first, meta):
+        # (a gather of single float32s costs by the element: the live
+        # rows' alone)
+        element, gate, at = _gate_and_token(
+            gates, back.padded_to_row, top_k, start, chunk)
+        bits = jax.lax.bitcast_convert_type(_gate_in(y.dtype, gate),
+                                            jnp.int32)
+        return _put_chunk(meta, jnp.where(
+            lane == 0, at[:, None], jnp.where(lane == 1, bits[:, None], 0)),
+            start)
+
+    meta = _over_live_chunks(
+        Mp, chunk, live, describe,
+        _unwritten((Mp, _ROWSUM_META_LANES), jnp.int32, y, "meta"))
+    return _pallas_rowsum(
+        y, meta, _token_block_runs(back, tokens, top_k, blocks[0]), tokens,
+        blocks, copy, gates is not None, interpret)
+
+
+def _gate_in(dtype, gate):
+    """A gate as the rows' dtype holds it, in float32: its product with a
+    row of that dtype is then exact in float32."""
+    return gate.astype(dtype).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch_held(xt, back, live, top_k, chunk):
+    return _token_rows_live(xt, back.padded_to_row // top_k, live, chunk)
+
+
+def _dispatch_held_fwd(xt, back, live, top_k, chunk):
+    return (_dispatch_held(xt, back, live, top_k, chunk),
+            (back, live, xt.shape[0]))
+
+
+def _dispatch_held_bwd(top_k, chunk, res, g):
+    back, live, tokens = res
+    return (_sum_live_into_tokens(g, None, back, int(tokens), top_k, live,
+                                  chunk), None, None)
 
 
 _dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
@@ -588,8 +788,7 @@ def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
     written).  Backward: the live rows' cotangents summed into their tokens
     (a token has 0 to ``top_k`` rows here)."""
     chunk = _live_chunk_rows(plan, xt.shape[1] * xt.dtype.itemsize)
-    return _dispatch_held(xt, plan.padded_to_row // top_k, live_rows(plan),
-                          chunk)
+    return _dispatch_held(xt, _way_back(plan), live_rows(plan), top_k, chunk)
 
 
 def _gate_and_token(gates, padded_to_row, top_k, start, chunk):
@@ -597,23 +796,20 @@ def _gate_and_token(gates, padded_to_row, top_k, start, chunk):
     its gate (0 on a padding row, whose element is ``R``) and its token
     (``R // top_k``: none)."""
     element = _chunk_of(padded_to_row, start, chunk)
-    gate = jnp.take(gates, element, mode="fill", fill_value=0)
+    gate = jnp.ones(element.shape, jnp.float32) if gates is None \
+        else jnp.take(gates, element, mode="fill", fill_value=0)
     return element, gate, element // top_k
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _combine_held(y, gates, padded_to_row, live, top_k, chunk):
-    def gated(start, n):
-        _, gate, _ = _gate_and_token(gates, padded_to_row, top_k, start, n)
-        return gate.astype(y.dtype)[:, None] * _chunk_of(y, start, n)
-
-    return _sum_live_into_tokens(gated, padded_to_row // top_k,
-                                 gates.shape[0] // top_k, y, live, chunk)
+def _combine_held(y, gates, back, live, top_k, chunk):
+    return _sum_live_into_tokens(y, gates, back, gates.shape[0] // top_k,
+                                 top_k, live, chunk)
 
 
-def _combine_held_fwd(y, gates, padded_to_row, live, top_k, chunk):
-    return (_combine_held(y, gates, padded_to_row, live, top_k, chunk),
-            (y, gates, padded_to_row, live))
+def _combine_held_fwd(y, gates, back, live, top_k, chunk):
+    return (_combine_held(y, gates, back, live, top_k, chunk),
+            (y, gates, back.padded_to_row, live))
 
 
 def _combine_held_bwd(top_k, chunk, res, g):
@@ -654,8 +850,8 @@ def combine_held_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
     prefix's rows of ``dy`` from their tokens' cotangents, and each routed
     element's ``dgate`` summed into its place."""
     chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
-    return _combine_held(y, gates, plan.padded_to_row, live_rows(plan),
-                         top_k, chunk)
+    return _combine_held(y, gates, _way_back(plan), live_rows(plan), top_k,
+                         chunk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

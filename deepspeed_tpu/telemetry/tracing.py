@@ -378,13 +378,14 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
-#: the state-space scan's two and the short causal convolution's two, and
-#: the flash kernel's three where the call has a sliding window
+#: the state-space scan's two and the short causal convolution's two, the
+#: flash kernel's three where the call has a sliding window, and the sum of
+#: a held plan's rows into their tokens
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
                 "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd",
                 "ds_conv_fwd", "ds_conv_bwd", "ds_flash_win_fwd",
-                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq")
+                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq", "ds_rowsum")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -711,6 +712,18 @@ def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):
     fell back to the XLA chunked form.  None where the step has no such
     call."""
     return _account_rows(name, "delta_rule_calls")
+
+
+def held_row_sums(name: str = TRAIN_STEP_PROGRAM):
+    """The sums of a held plan's rows into their tokens as
+    ops/pallas/grouped_gemm.py traced them (``combine_held_rows`` forward,
+    ``dispatch_held_rows`` backward): one row per shape — ``tokens``,
+    ``width``, ``plan_rows`` and ``path``: ``"kernel"`` where the sum ran
+    as the Mosaic kernel ``ds_rowsum`` (then also ``blocks``: the tokens a
+    grid step takes and the rows its stage holds), ``"xla"`` where it fell
+    back to one scatter-add over the plan (off the chip, or on more than
+    one device).  None where the step has no such sum."""
+    return _account_rows(name, "held_row_sums")
 
 
 def _account_rows(name: str, counter: str):

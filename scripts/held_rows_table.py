@@ -18,11 +18,24 @@ itself reports them (``moe/held_live_rows`` beside ``moe/held_plan_rows``,
 gauges of the registry tap: the blocks in the order the device ran them)
 — what dispatch, the grouped kernels and combine walk of the plan; the
 engine's own count of rows over the bound; a last line with the extremes.
+
+``--sum`` times the way back alone, at the cell's shape and with no engine:
+a plan drawn at random whose held experts are sent ``--live-share`` of the
+routed rows, its rows summed into their tokens by the library's form on
+this device (the kernel ``ds_rowsum`` on one TPU), with and without gates,
+beside the two XLA forms it replaced (one scatter-add over the plan; one a
+chunk over the live prefix) and the memory's floor — microseconds a kept
+row, and the largest difference from the float32 scatter-add rounded once.
+
+    chiprun --chips 1 -- python scripts/held_rows_table.py --sum \
+        --workload <cell> --seed <n> [--live-share 0.08] [--blocks 256,512]
 """
 import argparse
+import functools
 import json
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -59,8 +72,124 @@ class _HeldPlanTap:
         return live
 
 
+def _timed(fn, *args, repeats=30, **kwargs):
+    """``repeats`` calls back to back, drained once -> milliseconds a call
+    (the first call, which compiles, apart)."""
+    jax.block_until_ready(fn(*args, **kwargs))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = fn(*args, **kwargs)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / repeats * 1e3
+
+
+def sum_table(args, config, traffic):
+    """``--sum``: one JSON line per seed (see the module's docstring)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    cfg = build_model(config).config.moe
+    T = traffic["micro_batch_per_chip"] * traffic["seq_len"]
+    k, D, E, held = cfg.top_k, cfg.d_model, cfg.num_experts, cfg.held
+    if args.rehearse:
+        os.environ["DS_GGEMM_INTERPRET"] = "1"
+    bound = gg.held_rows_bound(T * k, held, E, factor=cfg.held_rows_factor)
+    share = args.live_share or held / E
+    for seed in args.seed:
+        rng = np.random.default_rng(seed)
+        # each token's top_k distinct experts; the held ones drawn with
+        # ``share`` of the probability
+        p = np.full(E, (1 - share) / (E - held))
+        p[cfg.expert_offset:cfg.expert_offset + held] = share / held
+        eids = np.stack([rng.choice(E, k, replace=False, p=p)
+                         for _ in range(T)]).reshape(-1)
+        plan, over = gg.make_held_group_plan(
+            jnp.asarray(eids, jnp.int32), cfg.expert_offset, held, bound)
+        Mp = plan.padded_rows
+        kept = int(jnp.sum(plan.padded_to_row < T * k))
+        y = jnp.asarray(rng.standard_normal((Mp, D)), jnp.bfloat16)
+        gates = jnp.asarray(rng.uniform(0.1, 1, T * k), jnp.float32)
+        back = gg._way_back(plan)
+        chunk = gg._live_chunk_rows(plan, D * 2)
+        live = gg.live_rows(plan)
+        token_of_row = plan.padded_to_row // k
+
+        def ours(y, gates, back):
+            return gg._sum_live_into_tokens(y, gates, back, T, k, live,
+                                            chunk)
+
+        def one_pass(y, gate_of_row):
+            rows = y if gate_of_row is None else \
+                gate_of_row.astype(y.dtype)[:, None] * y
+            return jnp.zeros((T, D), jnp.float32).at[token_of_row].add(
+                rows.astype(jnp.float32), mode="drop").astype(y.dtype)
+
+        def by_chunks(y):
+            # the chunked form of PRs 39-42, kept here as the oracle is
+            def add(start, first, acc):
+                at = gg._chunk_of(token_of_row, start, chunk)
+                return acc.at[jnp.where(gg._seen(start, first, chunk), T,
+                                        at)].add(
+                    gg._chunk_of(y, start, chunk).astype(jnp.float32),
+                    mode="drop")
+            return gg._over_live_chunks(
+                Mp, chunk, live, add,
+                jnp.zeros((T, D), jnp.float32)).astype(y.dtype)
+
+        gate_of_row = jnp.take(gates, plan.padded_to_row, mode="fill",
+                               fill_value=0)
+        gated = jax.jit(ours)
+        plain = jax.jit(functools.partial(ours, gates=None))
+        the_librarys = gg._ROWSUM_BLOCKS
+        want = jax.jit(one_pass)
+        from deepspeed_tpu.telemetry import tracing
+        with tracing.step_account("sum"):
+            got_gated = gated(y, gates, back)
+        path = tracing.held_row_sums("sum")
+        got_plain = plain(y, back=back)
+
+        def diff(a, b):
+            return float(jnp.max(jnp.abs(
+                a.astype(jnp.float32) - b.astype(jnp.float32))))
+
+        line = {
+            "workload": args.workload, "seed": seed,
+            "device": jax.devices()[0].device_kind, "tokens": T, "width": D,
+            "top_k": k, "plan_rows": Mp, "live_rows": int(live),
+            "kept_rows": kept, "rows_over_bound": int(over),
+            "sums": path,
+            "max_diff_gated": diff(got_gated, want(y, gate_of_row)),
+            "max_diff_plain": diff(got_plain, want(y, None)),
+            "floor_ms": (kept + T) * D * 2 / 819e9 * 1e3}
+        # (a rehearsal makes one call each: its numbers mean nothing)
+        timed = functools.partial(_timed, repeats=1 if args.rehearse else 30)
+        ms = {"kernel_gated": timed(gated, y, gates, back),
+              "kernel_plain": timed(plain, y, back=back),
+              "xla_one_pass": timed(want, y, None),
+              "xla_by_chunks": timed(jax.jit(by_chunks), y)}
+        for blocks in args.blocks:
+            gg._ROWSUM_BLOCKS = (("", blocks),)
+            at = "@%dx%d" % blocks
+            ms["kernel_gated" + at] = timed(jax.jit(ours), y, gates,
+                                            back)
+            ms["kernel_plain" + at] = timed(jax.jit(functools.partial(
+                ours, gates=None)), y, back=back)
+        gg._ROWSUM_BLOCKS = the_librarys
+        line["ms"] = {n: round(v, 4) for n, v in ms.items()}
+        line["us_per_kept_row"] = {
+            n: round(v * 1e3 / max(kept, 1), 4) for n, v in ms.items()}
+        print(json.dumps(line), flush=True)
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--sum", action="store_true",
+                        help="time the sum into tokens alone")
+    parser.add_argument("--live-share", type=float,
+                        help="--sum: the share of the routed rows the held "
+                             "experts are sent (default: their even share)")
+    parser.add_argument("--blocks", nargs="+", default=[],
+                        type=lambda v: tuple(int(n) for n in v.split(",")),
+                        help="--sum: also time the kernel at these (tokens "
+                             "a block, rows a stage), e.g. 512,256 256,512")
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, nargs="+", required=True)
     parser.add_argument("--steps", type=int, default=16)
@@ -83,6 +212,8 @@ def main():
             args.held_rows_factor
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
+    if args.sum:
+        return sum_table(args, config, traffic)
     held_worst, any_worst = [], []
     for seed in args.seed:
         model = build_model(config)

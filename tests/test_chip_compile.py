@@ -452,14 +452,18 @@ def test_a_held_layer_walks_its_live_prefix_on_a_v5e(v5e, monkeypatch):
     tokens), forward and backward: the loops' buffers are ``ds_unwritten_*``
     calls, each loop updates its buffer in place (no copy of an
     ``[Mp, ·]`` array anywhere in the compiled text), and no instruction
-    outside a loop makes a pass over the plan's rows but the kernels — and
-    the one-pass way of the two sums into tokens, which the step takes
-    only where the plan is more than three eighths live."""
+    outside a loop makes a pass over the plan's rows but the kernels.  The
+    two sums into tokens (``combine`` forward, ``dispatch`` backward) are
+    the kernel ``ds_rowsum``, which fetches its rows from the plan itself:
+    no ``conditional`` chooses a way, no sort but the plan's one, and the
+    one scatter left under those scopes is the gates' (``dgates``: single
+    float32s)."""
     import re
     from deepspeed_tpu.comm.mesh import sharding_pin_scope
     from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
                                          moe_layer)
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    from deepspeed_tpu.telemetry import tracing
     monkeypatch.setattr(gg.vmem, "device_kind",
                         lambda: v5e[0].device_kind.lower())
     monkeypatch.setattr(gg, "_use_reference",
@@ -478,28 +482,29 @@ def test_a_held_layer_walks_its_live_prefix_on_a_v5e(v5e, monkeypatch):
         out, aux = moe_layer(params, x, config, train=True)
         return jnp.sum(out.astype(jnp.float32) ** 2) + aux
 
-    with sharding_pin_scope(False):
+    with sharding_pin_scope(False), tracing.step_account("a_held_layer"):
         text = jax.jit(jax.grad(loss, (0, 1))).lower(
             params, _arg(v5e[0], (2, 8192, 2048))).compile().as_text()
     rows = 16 * 8 * 16384 * 16 // 256 + 16 * 128
     assert rows == 133120
-    for what in ("rows", "mapped", "dy", "pulled0", "pulled1"):
+    for what in ("rows", "mapped", "dy", "pulled0", "pulled1", "meta"):
         assert f"ds_unwritten_{what}" in text
-    # the two sums into tokens choose their way in the step: set aside the
-    # way a plan this empty never takes (``cond``'s first branch: one
-    # scatter-add over every row of the plan, as before PR 39)
-    blocks = {m.group(1): m.group(0) for m in re.finditer(
-        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n.*?^\}$", text, re.M | re.S)}
-    one_pass = re.findall(r" conditional\(.*branch_computations=\{%([^,}]+)",
-                          text)
-    assert len(one_pass) == 2
-    for name in one_pass:
-        for called in re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
-                                 blocks[name]):
-            if called not in one_pass:
-                one_pass.append(called)
-    lines = [line for name, block in blocks.items() if name not in one_pass
-             for line in block.splitlines()]
+    # the way back: the kernel, twice; nothing chooses; no scatter of rows
+    sums = [row for row in tracing.parse_program_text(text).values()
+            if row["kernel"] == "ds_rowsum"]
+    assert len(sums) == 2
+    assert sorted(re.search(r"(dispatch|combine)\)*/ds_rowsum/",
+                            row["scope"]).group(1) for row in sums) \
+        == ["combine", "dispatch"], sums
+    assert " conditional(" not in text
+    assert len(re.findall(r" sort\(", text)) == 1      # the plan's own
+    scatters = re.findall(r"= (\S+) scatter\(", text)
+    assert scatters and all(re.fullmatch(r"f32\[\d+\]\S*", s)
+                            for s in scatters), scatters
+    assert tracing.held_row_sums("a_held_layer") == [{
+        "tokens": 16384, "width": 2048, "plan_rows": rows,
+        "blocks": (512, 512), "path": "kernel"}]
+    lines = text.splitlines()
     whole = re.compile(rf"= (?:bf16|f32)\[{rows},(?:768|2048)\]\S* (\S+?)\(")
     ops = {m.group(1) for line in lines
            if " ROOT " not in line for m in [whole.search(line)] if m}

@@ -166,9 +166,11 @@ def test_with_one_width_the_step_lowers_to_the_parents_text(family):
     to the text it had before the kernels took two widths (digests taken
     at the parent commit with tests/flash_step_texts.py: PERF.md section
     6, PR 38).  ``qwen3_next`` and ``nemotron_h`` were taken again at PR
-    39, which changed their expert layers on purpose (a held plan's
-    consumers walk its live prefix: tests/test_held_live_prefix.py says
-    which steps left the parent's text and why); the flash kernels' part
+    39 and at PR 44, which changed their expert layers on purpose (a held
+    plan's consumers walk its live prefix: tests/test_held_live_prefix.py
+    says which steps left the parent's text and why; the two sums into
+    tokens are one scatter-add each off the chip, with no ``cond``:
+    tests/test_held_row_sum.py); the flash kernels' part
     of them is the ``gpt2`` and ``olmoe`` digests', which PR 39 left."""
     with open(os.path.join(HERE, "data", "flash_step_digests.json")) as f:
         want = json.load(f)

@@ -92,7 +92,8 @@ def test_fixed_names():
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
                             "ds_ssd_fwd", "ds_ssd_bwd", "ds_conv_fwd",
                             "ds_conv_bwd", "ds_flash_win_fwd",
-                            "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq")
+                            "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq",
+                            "ds_rowsum")
 
 
 # ------------------------------------------------------- the text's parser
