@@ -269,11 +269,10 @@ def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
             c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
             kv = qdot(c_kv, layer["w_ukv"]).reshape(B, S, H, nope + vd)
         with jax.named_scope(SCOPE_ROPE):
-            q_rope = rope(q[..., nope:], config.rope_theta, interleaved=True)
-            k_r = rope(ckv[..., None, rkv:], config.rope_theta,
+            # q turns in place, its position-free lanes passing through
+            q = rope(q, config.rope_theta, interleaved=True, first=nope)
+            k_r = rope(jnp.expand_dims(ckv[..., rkv:], 2), config.rope_theta,
                        interleaved=True)                  # [B, S, 1, rot]
-        with jax.named_scope(SCOPE_Q_LATENT):
-            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         with jax.named_scope(SCOPE_KV_LATENT):
             # the one rotary key, a copy per head behind each head's own
             # part: the kernels read k [B, S, H, nope + rot]

@@ -12,6 +12,7 @@ from functools import partial
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -154,13 +155,20 @@ def _rms_norm(x, scale, eps):
     return (x32 * lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def rope(x, theta: float, positions=None, interleaved: bool = False):
+def rope(x, theta: float, positions=None, interleaved: bool = False,
+         first: int = 0):
     """Rotary embeddings on [B, S, H, hd].  ``interleaved=False`` pairs
     dim i with i+hd/2 (llama/NeoX split-half convention);
     ``interleaved=True`` pairs dims (2i, 2i+1) (the GPT-J rotate_every_two
     convention — same frequencies, different lane pairing, so converted
     checkpoints must match their family's layout).  ``positions``: [S]
-    (shared across batch) or [B, S] (per-row, decode)."""
+    (shared across batch) or [B, S] (per-row, decode).  ``first``
+    (interleaved only): lanes ``first..hd`` turn, at the frequencies of a
+    head ``hd - first`` wide, and the lanes before them pass through —
+    latent attention's position-free part, rotated in place."""
+    if interleaved:
+        return _rope_interleaved(x, theta, positions, first)
+    assert not first, "rope: first= is the interleaved layout's"
     B, S, H, hd = x.shape
     if positions is None:
         positions = jnp.arange(S)
@@ -174,15 +182,71 @@ def rope(x, theta: float, positions=None, interleaved: bool = False):
         cos = jnp.cos(angles)[:, :, None, :]
         sin = jnp.sin(angles)[:, :, None, :]
     xf = x.astype(jnp.float32)
-    if interleaved:
-        x1, x2 = xf[..., 0::2], xf[..., 1::2]
-        r1, r2 = x1 * cos - x2 * sin, x1 * sin + x2 * cos
-        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    else:
-        x1, x2 = jnp.split(xf, 2, axis=-1)
-        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                              axis=-1)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                          axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_interleaved(x, theta, positions, first):
+    """``x C + swap(x) Sn`` over whole heads: ``swap(x)[2i] = -x[2i+1]``,
+    ``swap(x)[2i+1] = x[2i]``.  Term for term ``x1 cos - x2 sin`` and ``x1
+    sin + x2 cos`` in float32, rounded once, with no stride along the head
+    (``x[..., 0::2]`` is a gather by index before XLA sees it, and its
+    transpose a scatter-add)."""
+    S, hd = x.shape[1], x.shape[-1]
+    assert 0 <= first < hd and (hd - first) % 2 == 0, (first, hd)
+    if positions is None:
+        positions = jnp.arange(S)
+    c, s = interleaved_tables(positions, theta, hd - first, first)
+    return _turn_pairs(x, c, s, first, False)
+
+
+def interleaved_tables(positions, theta, rot, first=0):
+    """``(C, Sn)`` float32 ``[(B,) S, 1, first + rot]`` of the interleaved
+    rotary: ``C`` is 1 on the ``first`` lanes that pass and ``cos`` (each
+    frequency twice) on the ``rot`` that turn, ``Sn`` 0 and ``sin``."""
+    freqs = theta ** (-jnp.arange(0, rot // 2) / (rot // 2))
+    angles = positions[..., None] * freqs                # [(B,) S, rot/2]
+    lanes = [(0, 0)] * (angles.ndim - 1) + [(first, 0)]
+    c = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), lanes,
+                constant_values=1.0)
+    s = jnp.pad(jnp.repeat(jnp.sin(angles), 2, axis=-1), lanes)
+    return jnp.expand_dims(c, -2), jnp.expand_dims(s, -2)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _turn_pairs(x, c, s, first, back):
+    """``x c + swap(x) s``; ``back``: with ``swap``'s transpose, the
+    rotation by the opposite angle.  ``swap`` is a product with a signed
+    permutation: one non-zero a column, so exact in ``x``'s own dtype on
+    the MXU, and the multiply-add is the product's epilogue — one pass
+    over ``x``.  (A -0.0 on a lane that passes comes back +0.0.)"""
+    hd = x.shape[-1]
+    turn = np.zeros((hd, hd), np.float32)
+    even = np.arange(first, hd, 2)
+    turn[even + 1, even], turn[even, even + 1] = -1.0, 1.0
+    swapped = jnp.einsum(
+        "bshd,de->bshe", x, jnp.asarray(turn.T if back else turn, x.dtype),
+        # bfloat16 operands are whole as they are; float32 ones must not
+        # be cut to bfloat16 on the way into the MXU
+        precision=None if x.dtype == jnp.bfloat16 else lax.Precision.HIGHEST,
+        preferred_element_type=x.dtype)
+    return (x.astype(jnp.float32) * c
+            + swapped.astype(jnp.float32) * s).astype(x.dtype)
+
+
+def _turn_pairs_fwd(x, c, s, first, back):
+    return _turn_pairs(x, c, s, first, back), (c, s)
+
+
+def _turn_pairs_bwd(first, back, tables, g):
+    # swap meets the cotangent in its own dtype, as it met x
+    c, s = tables
+    return _turn_pairs(g, c, s, first, not back), None, None
+
+
+_turn_pairs.defvjp(_turn_pairs_fwd, _turn_pairs_bwd)
 
 
 def _block_qkv(x, layer, config: LlamaConfig, positions=None, lora=None):

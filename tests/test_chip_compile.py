@@ -519,6 +519,66 @@ def test_a_held_layer_walks_its_live_prefix_on_a_v5e(v5e, monkeypatch):
                in line for line in fusions), fusions[:2]
 
 
+#: temporaries of the parent's layer (commit 1a39731, the rotary on strided
+#: lane pairs and q joined from its parts), by the same compile: a CPU count
+JOYAI_ATTENTION_TEMP_BYTES_AT_PR_44 = 2138551296
+
+
+def test_latent_attention_turns_q_in_one_pass_on_a_v5e(v5e, monkeypatch):
+    """joyai-llm-flash.packed-s8192-gas2's latent attention alone, forward
+    and backward, ``q`` ``[2, 8192, 32, 192]``: the interleaved rotary is
+    the product with the signed permutation and its epilogue, one fusion
+    over ``q`` a direction (the forward's writes what ``ds_flash_fwd``
+    reads), nothing under ``attn/rope`` gathers or scatters (the parent:
+    four gathers and two scatter-adds of float32 ``[2, 4096, 32, 64]``),
+    no float32 array of ``q``'s size under ``rope`` or ``q_latent``, and
+    the layer's temporaries are not above the parent's (1.805 GiB against
+    1.992 when this was written)."""
+    import re
+    from deepspeed_tpu.comm.mesh import sharding_pin_scope
+    from deepspeed_tpu.models import joyai
+    from deepspeed_tpu.ops.pallas import ds_flash_attention as flash
+    monkeypatch.setattr(flash.vmem, "device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    config = joyai.JoyAIConfig(num_layers=5, attention_impl="flash")
+    layer = jax.tree.map(
+        lambda a: _arg(v5e[0], a.shape,
+                       jnp.bfloat16 if a.ndim == 2 else a.dtype),
+        jax.eval_shape(lambda: joyai._attn_params(config,
+                                                  jax.random.PRNGKey(0))))
+
+    def loss(layer, x, seg):
+        with jax.named_scope("ds.block"):
+            out = joyai._latent_attention(x, layer, config, seg)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    with sharding_pin_scope(False):
+        compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+            layer, _arg(v5e[0], (2, 8192, 2048)),
+            _arg(v5e[0], (2, 8192), jnp.int32)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):].splitlines()
+    assert sum(KERNEL in line for line in entry) == 3       # the flash three
+    scoped = [(re.search(r'op_name="([^"]*)"', line), line)
+              for line in entry]
+    turning = [line for m, line in scoped
+               if m and "/attn/rope/" in m.group(1)]
+    assert turning
+    assert not [line for line in turning
+                if re.search(r'op_name="[^"]*(gather|scatter)', line)]
+    products = [line for line in turning if "kind=kOutput" in line
+                and re.search(r"= bf16\[2,(?:32,8192|8192,32),192\]", line)]
+    assert len(products) == 2, products                     # there and back
+    assert sum(line.split("=")[1].startswith(" bf16[2,32,8192,192]")
+               for line in products) == 1       # the kernels' own layout
+    whole_f32 = re.compile(r"= \(?f32\[2,(?:32,8192|8192,32),192\]")
+    assert not [line for m, line in scoped
+                if m and re.search(r"/attn/(rope|q_latent)/", m.group(1))
+                and whole_f32.search(line)]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= JOYAI_ATTENTION_TEMP_BYTES_AT_PR_44, temp
+
+
 @pytest.mark.parametrize("manual_outside", [False, True],
                          ids=["gspmd", "inside_data_manual_shard_map"])
 def test_data_sharded_flash_grad_partitions(v5e, manual_outside):
@@ -565,7 +625,7 @@ def test_library_knows_the_chips_peaks(v5e):
 
 @pytest.mark.parametrize("script", [
     "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1",
-    "scripts/ssd_table.py", "scripts/conv_table.py"])
+    "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(

@@ -563,6 +563,45 @@ def test_the_size_is_the_published_one_and_the_cut_is_the_files():
         JoyAIConfig(num_mtp_layers=2)
 
 
+def test_no_stride_and_no_join_of_q_in_the_lowered_toy_step():
+    """The lowered text of the toy step's forward and backward (tests/
+    flash_step_texts.py: ``value_and_grad(loss)``, remat on), by the scope
+    of each instruction: under ``ds.block/attn/rope`` and
+    ``.../q_latent`` no slice has a stride other than 1, nothing gathers
+    or scatters (what ``x[..., 0::2]`` is before XLA sees it, and what its
+    transpose is), and no concatenate under ``q_latent`` builds a
+    ``[B, S, H, nope + rot]`` array — ``q`` leaves its up-projection whole
+    and turns in place (PR 45).  Every pass of the block is looked at: the
+    main stack's and the module's, forward, recompute and backward."""
+    import re
+    model = toy_model()
+    H, wide = TOY["num_heads"], (TOY["qk_nope_head_dim"]
+                                 + TOY["qk_rope_head_dim"])
+    mb = micro(packed_batch())
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)), mb).as_text(
+            debug_info=True)
+    scope_of = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    seen = {"rope": 0, "q_latent": 0}
+    for line in text.splitlines():
+        m = re.search(r'= "?(?:stablehlo|chlo)\.(\w+).* loc\((#loc\d+)\)$',
+                      line)
+        scope = m and re.search(r"ds\.block/attn/(rope|q_latent)/",
+                                scope_of.get(m.group(2), ""))
+        if not scope:
+            continue
+        op, where = m.group(1), scope.group(1)
+        seen[where] += 1
+        assert op not in ("gather", "scatter", "dynamic_slice"), line
+        if op == "slice":
+            # [a:b:stride, ...]: the stride is printed where it is not 1
+            assert not re.search(r"\d+:\d+:\d+", line), line
+        if op == "concatenate" and where == "q_latent":
+            assert f"x{H}x{wide}x" not in line.split("->")[-1], line
+    # both scopes were read, each in forward, recompute and backward
+    assert seen["rope"] > 30 and seen["q_latent"] > 30, seen
+
+
 def test_scopes_and_accounts_of_a_toy_step(interpret_pallas, real_kernels):
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
