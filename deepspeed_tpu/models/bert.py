@@ -163,7 +163,7 @@ def forward(params, batch, config: BertConfig, rng=None):
     def block_fn(x, layer):
         return _block(x, maybe_stream(layer), pad_mask, config)
     if config.remat:
-        from deepspeed_tpu.models.gpt2 import remat_policy
+        from deepspeed_tpu.models.model import remat_policy
         block_fn = jax.checkpoint(block_fn,
                                   policy=remat_policy(config.remat_policy))
     # LTD token-gather would misalign the closed-over pad_mask rows

@@ -165,7 +165,7 @@ def forward(params, batch, config: BloomConfig, rng=None):
         from deepspeed_tpu.models.model import maybe_stream
         return _block(x, maybe_stream(layer), config, slopes, rng, seg)
     if config.remat:
-        from deepspeed_tpu.models.gpt2 import remat_policy
+        from deepspeed_tpu.models.model import remat_policy
         block_fn = jax.checkpoint(
             block_fn, policy=remat_policy(config.remat_policy))
     from deepspeed_tpu.models.model import scan_blocks

@@ -21,7 +21,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.model import (Model, maybe_stream, qdot,
-                                        resolve_size)
+                                        remat_policy, resolve_size)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_MLP)
@@ -195,25 +195,6 @@ def logical_specs(config: GPT2Config) -> dict:
         },
         "lnf_scale": P(), "lnf_bias": P(),
     }
-
-
-def remat_policy(name: str):
-    """Remat policies for per-layer activation checkpointing (the reference's
-    activation_checkpointing tiers become jax.checkpoint policies)."""
-    if name in (None, "nothing", "nothing_saveable"):
-        return jax.checkpoint_policies.nothing_saveable
-    if name in ("save_attn",):
-        return jax.checkpoint_policies.save_only_these_names("attn_out")
-    if name in ("dots", "dots_saveable"):
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if name in ("offload_attn",):
-        # host-offload tier: attention outputs go to pinned host DRAM instead
-        # of HBM (reference cpu_checkpointing)
-        return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=["attn_out"],
-            offload_src="device", offload_dst="pinned_host")
-    raise ValueError(f"unknown remat policy {name!r}")
 
 
 def _layer_norm(x, scale, bias, eps):

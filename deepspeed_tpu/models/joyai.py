@@ -57,13 +57,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, maybe_stream,
-                                        param_stream_active, qdot,
-                                        resolve_size, token_loss)
+from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+                                        held_share_model, layer_block,
+                                        param_count, qdot,
+                                        refuse_param_stream, resolve_size,
+                                        segment_ids_of, token_loss)
 from deepspeed_tpu.models.llama import _rms_norm, rope
 from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_layer,
-                                     moe_logical_specs)
+                                     init_moe_params, moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_KV_LATENT,
@@ -134,19 +135,9 @@ class JoyAIConfig:
 
     @property
     def moe(self) -> MoEConfig:
-        return MoEConfig(
-            d_model=self.d_model, d_ff=self.d_ff,
-            num_experts=self.num_experts, top_k=self.top_k,
-            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
-            norm_topk_prob=self.norm_topk_prob, router="sigmoid",
-            routed_scaling_factor=self.routed_scaling_factor,
-            load_balance=self.load_balance, activation="silu_glu",
-            # a held share runs through the grouped dispatch only
-            dispatch_mode="grouped",
-            expert_offset=self.expert_offset,
-            experts_held=self.experts_held,
-            held_rows_factor=self.held_rows_factor,
-            shared_expert_d_ff=self.shared_expert_d_ff)
+        # a held share runs through the grouped dispatch only
+        return MoEConfig.of(self, router="sigmoid", activation="silu_glu",
+                            dispatch_mode="grouped")
 
 
 JOYAI_SIZES = {
@@ -302,23 +293,10 @@ def _expert_block(x, layer, config: JoyAIConfig, train, rng=None,
                   segment_ids=None):
     """-> (x, (router loss, routed rows over ``held_rows_bound``))."""
     x = _latent_attention(x, layer, config, segment_ids)
-    with jax.named_scope(SCOPE_MLP):
-        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
-                                    rng=rng, return_stats=True)
-        return x + out, (aux.astype(jnp.float32),
-                         stats["dropped"].astype(jnp.int32))
-
-
-def _remat(fn, config: JoyAIConfig):
-    if not config.remat:
-        return fn
-    from deepspeed_tpu.models.gpt2 import remat_policy
-    return jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
-
-
-def _segments(batch):
-    return batch.get("segment_ids") if isinstance(batch, dict) else None
+    return expert_half(
+        x, layer["moe"], config.moe,
+        lambda x: _rms_norm(x, layer["mlp_norm"], config.norm_eps),
+        train, rng)
 
 
 def hidden_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
@@ -326,29 +304,18 @@ def hidden_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
     """The main stack: -> (the last layer's output [B, S, D], before the
     final norm; router loss summed over the expert layers; routed rows over
     ``held_rows_bound`` summed over them, int32)."""
-    if param_stream_active():
-        raise NotImplementedError(
-            "joyai: ZeRO-3 and parameter offload gather or stream one layer "
-            "of a single stacked tree at a time; this model's layers are a "
-            "leading dense block, a stack of expert blocks and a prediction "
-            "module, and gathering at that grain is not built — use ZeRO "
-            "stage 0-2")
-    dtype = jnp.dtype(config.dtype)
-    seg = _segments(batch)
-    with jax.named_scope(SCOPE_EMBED):
-        x = params["wte"].astype(dtype)[batch["input_ids"]]
-    x = _remat(lambda x, layer: _dense_block(
-        x, maybe_stream(layer), config, segment_ids=seg), config)(
-            x, params["dense"])
-    x, (aux, over) = lax.scan(_expert_block_fn(config, train, rng, seg), x,
-                              params["blocks"])
+    refuse_param_stream(
+        "joyai", "a leading dense block, a stack of expert blocks and a "
+        "prediction module")
+    seg = segment_ids_of(batch)
+    x = embed_tokens(params["wte"], batch["input_ids"],
+                     jnp.dtype(config.dtype))
+    x = layer_block(_dense_block, config, segment_ids=seg)(
+        x, params["dense"])
+    x, (aux, over) = lax.scan(
+        layer_block(_expert_block, config, train=train, rng=rng,
+                    segment_ids=seg), x, params["blocks"])
     return x, jnp.sum(aux), jnp.sum(over)
-
-
-def _expert_block_fn(config, train, rng, seg):
-    return _remat(lambda x, layer: _expert_block(
-        x, maybe_stream(layer), config, train=train, rng=rng,
-        segment_ids=seg), config)
 
 
 def _logits(x, norm_w, lm_head, config: JoyAIConfig):
@@ -386,7 +353,8 @@ def mtp_hidden_with_aux(params, x, batch, config: JoyAIConfig,
     stack's last hidden state (before the final norm); position t joins it
     with the embedding of token t+1 (the last position's, which has none,
     is never scored and nothing attends to it)."""
-    return _expert_block_fn(config, train, rng, _segments(batch))(
+    return layer_block(_expert_block, config, train=train, rng=rng,
+                       segment_ids=segment_ids_of(batch))(
         _mtp_input(params, x, batch, config), params["mtp"]["block"])
 
 
@@ -397,7 +365,7 @@ def routed_rows(params, batch, config: JoyAIConfig):
     ``held_rows_bound`` has to hold a share's sum of
     (scripts/held_rows_table.py).  A diagnostic: the layers written out,
     no scan."""
-    seg = _segments(batch)
+    seg = segment_ids_of(batch)
     moe = config.moe
 
     def count(x, layer):
@@ -432,7 +400,7 @@ def mtp_targets(batch):
     tokens = batch["input_ids"]
     S = tokens.shape[1]
     scored = jnp.broadcast_to(jnp.arange(S) < S - 2, tokens.shape)
-    seg = _segments(batch)
+    seg = segment_ids_of(batch)
     if seg is not None:
         scored &= (seg == jnp.roll(seg, -1, axis=1)) \
             & (seg == jnp.roll(seg, -2, axis=1))
@@ -495,66 +463,30 @@ def loss_with_counts(params, batch, config: JoyAIConfig, rng=None):
 
 
 def count_params(config: JoyAIConfig) -> int:
-    import numpy as np
-    shapes = jax.eval_shape(partial(init_params, config),
-                            jax.random.PRNGKey(0))
-    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
-
-
-def _no_serving(what):
-    def refuse(*_, **__):
-        raise NotImplementedError(
-            f"joyai: {what} is not built — serving latent attention needs "
-            f"its absorbed form (scores against the cached latents "
-            f"themselves) and a paged cache of latents and rotary keys, "
-            f"and the prediction module as a self-drafting head (ROADMAP)")
-    return refuse
+    return param_count(partial(init_params, config))
 
 
 def joyai_model(size: str = "llm-flash", **overrides) -> Model:
-    cfg_kwargs = resolve_size(JOYAI_SIZES, size, "joyai")
-    cfg_kwargs.update(overrides)
-    config = JoyAIConfig(**cfg_kwargs)
-    n_params = count_params(config)
-    moe = config.moe
-    # the routed experts a token's weights pass through HERE: top_k of
-    # num_experts of those held (all of them: top_k); the embedding is a
-    # lookup, and with the module the head multiplies a token twice
-    expert = 3 * config.d_model * config.d_ff
-    active = n_params - config.vocab_size * config.d_model \
-        - (config.expert_layers + config.num_mtp_layers) * expert * (
-            moe.held - config.top_k * moe.held / config.num_experts) \
-        + config.num_mtp_layers * config.d_model * config.vocab_size
-
-    def with_counts(params, batch, rng=None):
-        return loss_with_counts(params, batch, config, rng)
-
-    return Model(
-        config=config,
-        init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
-        loss_fn=lambda p, b, rng=None: with_counts(p, b, rng)[0],
-        # the rows a step's expert layers left out leave the step beside
-        # its loss, as models/qwen3_next.py's; the engine counts and warns
-        loss_with_counts_fn=with_counts if moe.holds_subset else None,
-        logical_specs=logical_specs(config),
-        flops_per_token=6.0 * active,
-        meta={"name": f"joyai-{size}", "n_params": n_params,
-              "active_params": active,
-              "step_counts": {ROWS_OVER_BOUND: (
-                  "routed rows past held_rows_bound, left out of the expert "
-                  "layers: the router sent the experts held here more than "
-                  "held_rows_factor times their even share")}
-              if moe.holds_subset else {},
-              # the module's per-token losses, for a check against the
-              # plain reference's (scripts/reference_control.py)
-              "mtp_token_losses": (lambda p, b: mtp_token_losses(
-                  p, b, config)) if config.num_mtp_layers else None,
-              # every expert's routed rows, block by block
-              "routed_rows": lambda p, b: routed_rows(p, b, config)},
-        init_cache_fn=_no_serving("init_cache"),
-        prefill_fn=_no_serving("prefill"),
-        decode_fn=_no_serving("decode"),
-        verify_fn=_no_serving("verify"),
-    )
+    config = JoyAIConfig(**{
+        **resolve_size(JOYAI_SIZES, size, "joyai"), **overrides})
+    head = config.d_model * config.vocab_size
+    return held_share_model(
+        "joyai", size, config, init_params=init_params,
+        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        loss_with_counts=loss_with_counts,
+        expert_layers=config.expert_layers + config.num_mtp_layers,
+        expert_matrices=3, lookup_params=head,
+        # with the module the head multiplies a token twice
+        reused_params=config.num_mtp_layers * head,
+        serving_needs=(
+            "serving latent attention needs its absorbed form (scores "
+            "against the cached latents themselves) and a paged cache of "
+            "latents and rotary keys, and the prediction module as a "
+            "self-drafting head"),
+        meta={
+            # the module's per-token losses, for a check against the plain
+            # reference's (scripts/reference_control.py)
+            "mtp_token_losses": (lambda p, b: mtp_token_losses(
+                p, b, config)) if config.num_mtp_layers else None,
+            # every expert's routed rows, block by block
+            "routed_rows": lambda p, b: routed_rows(p, b, config)})

@@ -52,17 +52,18 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, maybe_stream,
-                                        param_stream_active, qdot,
-                                        resolve_size, scan_layer_kinds,
+from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+                                        held_share_model, layer_block,
+                                        param_count, qdot,
+                                        refuse_param_stream, resolve_size,
+                                        scan_layer_kinds, segment_ids_of,
                                         token_loss)
 from deepspeed_tpu.models.llama import _rms_norm
 from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_layer,
-                                     moe_logical_specs)
+                                     init_moe_params, moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_ATTN, SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_BLOCK, SCOPE_EMBED,
+    SCOPE_ATTN, SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_BLOCK,
     SCOPE_HEAD_GATE, SCOPE_HEAD_LOSS, SCOPE_LEAD_MLP, SCOPE_MLP,
     SCOPE_OUT_PROJ, SCOPE_ROPE, SCOPE_SCORES)
 
@@ -157,19 +158,9 @@ class LagunaConfig:
 
     @property
     def moe(self) -> MoEConfig:
-        return MoEConfig(
-            d_model=self.d_model, d_ff=self.d_ff,
-            num_experts=self.num_experts, top_k=self.top_k,
-            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
-            norm_topk_prob=self.norm_topk_prob, router="softmax",
-            routed_scaling_factor=self.routed_scaling_factor,
-            load_balance=self.load_balance, activation="silu_glu",
-            # a held share runs through the grouped dispatch only
-            dispatch_mode="grouped",
-            expert_offset=self.expert_offset,
-            experts_held=self.experts_held,
-            held_rows_factor=self.held_rows_factor,
-            shared_expert_d_ff=self.shared_expert_d_ff)
+        # a held share runs through the grouped dispatch only
+        return MoEConfig.of(self, router="softmax", activation="silu_glu",
+                            dispatch_mode="grouped")
 
 
 LAGUNA_SIZES = {
@@ -365,29 +356,10 @@ def _expert_block(x, layer, config: LagunaConfig, kind, train, rng=None,
                   segment_ids=None):
     """-> (x, (router loss, routed rows over ``held_rows_bound``))."""
     x = _attention(x, layer, config, kind, segment_ids)
-    with jax.named_scope(SCOPE_MLP):
-        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
-                                    rng=rng, return_stats=True)
-        return x + out, (aux.astype(jnp.float32),
-                         stats["dropped"].astype(jnp.int32))
-
-
-def _remat(fn, config: LagunaConfig):
-    if not config.remat:
-        return fn
-    from deepspeed_tpu.models.gpt2 import remat_policy
-    return jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
-
-
-def _segments(batch):
-    return batch.get("segment_ids") if isinstance(batch, dict) else None
-
-
-def _expert_block_fn(config, kind, train, rng, seg):
-    return _remat(lambda x, layer: _expert_block(
-        x, maybe_stream(layer), config, kind, train=train, rng=rng,
-        segment_ids=seg), config)
+    return expert_half(
+        x, layer["moe"], config.moe,
+        lambda x: _rms_norm(x, layer["mlp_norm"], config.norm_eps),
+        train, rng)
 
 
 def hidden_with_aux(params, batch, config: LagunaConfig, train: bool = True,
@@ -395,21 +367,15 @@ def hidden_with_aux(params, batch, config: LagunaConfig, train: bool = True,
     """-> (the last layer's output [B, S, D], before the final norm; router
     loss summed over the expert layers; routed rows over
     ``held_rows_bound`` summed over them, int32)."""
-    if param_stream_active():
-        raise NotImplementedError(
-            "laguna: ZeRO-3 and parameter offload gather or stream one layer "
-            "of a single stacked tree at a time; this model's layers are a "
-            "leading dense block and two stacks (sliding, full) walked "
-            "period by period, and gathering at that grain is not built — "
-            "use ZeRO stage 0-2")
-    dtype = jnp.dtype(config.dtype)
-    seg = _segments(batch)
-    with jax.named_scope(SCOPE_EMBED):
-        x = params["wte"].astype(dtype)[batch["input_ids"]]
-    x = _remat(lambda x, layer: _lead_block(
-        x, maybe_stream(layer), config, segment_ids=seg), config)(
-            x, params["lead"])
-    block_fns = {kind: _expert_block_fn(config, kind, train, rng, seg)
+    refuse_param_stream(
+        "laguna", "a leading dense block and two stacks (sliding, full) "
+        "walked period by period")
+    seg = segment_ids_of(batch)
+    x = embed_tokens(params["wte"], batch["input_ids"],
+                     jnp.dtype(config.dtype))
+    x = layer_block(_lead_block, config, segment_ids=seg)(x, params["lead"])
+    block_fns = {kind: layer_block(_expert_block, config, kind=kind,
+                                   train=train, rng=rng, segment_ids=seg)
                  for kind in (SLIDING, FULL)}
     aux = over = 0
     if config.num_periods:
@@ -467,7 +433,7 @@ def routed_rows(params, batch, config: LagunaConfig):
     (scripts/held_rows_table.py).  A diagnostic: the layers written out,
     no scan."""
     from deepspeed_tpu.moe.layer import _route, _routing_logits
-    seg = _segments(batch)
+    seg = segment_ids_of(batch)
     moe = config.moe
     x = params["wte"].astype(jnp.dtype(config.dtype))[batch["input_ids"]]
     x = _lead_block(x, params["lead"], config, segment_ids=seg)
@@ -486,60 +452,21 @@ def routed_rows(params, batch, config: LagunaConfig):
 
 
 def count_params(config: LagunaConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, config),
-                            jax.random.PRNGKey(0))
-    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
-
-
-def _no_serving(what):
-    def refuse(*_, **__):
-        raise NotImplementedError(
-            f"laguna: {what} is not built — serving needs a cache that "
-            f"keeps sliding_window positions for the sliding layers and "
-            f"every position for the full ones, at two head counts "
-            f"(ROADMAP)")
-    return refuse
+    return param_count(partial(init_params, config))
 
 
 def laguna_model(size: str = "s-2.1", **overrides) -> Model:
-    cfg_kwargs = resolve_size(LAGUNA_SIZES, size, "laguna")
-    cfg_kwargs.update(overrides)
-    config = LagunaConfig(**cfg_kwargs)
-    n_params = count_params(config)
-    moe = config.moe
-    # the routed experts a token's weights pass through HERE: top_k of
-    # num_experts of those held (all of them: top_k); the embedding is a
-    # lookup
-    expert = 3 * config.d_model * config.d_ff
-    active = n_params - config.vocab_size * config.d_model \
-        - config.expert_layers * expert * (
-            moe.held - config.top_k * moe.held / config.num_experts)
-
-    def with_counts(params, batch, rng=None):
-        return loss_with_counts(params, batch, config, rng)
-
-    return Model(
-        config=config,
-        init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
-        loss_fn=lambda p, b, rng=None: with_counts(p, b, rng)[0],
-        # the rows a step's expert layers left out leave the step beside
-        # its loss, as models/joyai.py's; the engine counts and warns
-        loss_with_counts_fn=with_counts if moe.holds_subset else None,
-        logical_specs=logical_specs(config),
-        flops_per_token=6.0 * active,
-        meta={"name": f"laguna-{size}", "n_params": n_params,
-              "active_params": active,
-              "step_counts": {ROWS_OVER_BOUND: (
-                  "routed rows past held_rows_bound, left out of the expert "
-                  "layers: the router sent the experts held here more than "
-                  "held_rows_factor times their even share")}
-              if moe.holds_subset else {},
-              # every expert's routed rows, layer by layer
-              "routed_rows": lambda p, b: routed_rows(p, b, config)},
-        init_cache_fn=_no_serving("init_cache"),
-        prefill_fn=_no_serving("prefill"),
-        decode_fn=_no_serving("decode"),
-        verify_fn=_no_serving("verify"),
-    )
+    config = LagunaConfig(**{
+        **resolve_size(LAGUNA_SIZES, size, "laguna"), **overrides})
+    return held_share_model(
+        "laguna", size, config, init_params=init_params,
+        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        loss_with_counts=loss_with_counts,
+        expert_layers=config.expert_layers, expert_matrices=3,
+        lookup_params=config.vocab_size * config.d_model,
+        serving_needs=(
+            "serving needs a cache that keeps sliding_window positions for "
+            "the sliding layers and every position for the full ones, at "
+            "two head counts"),
+        # every expert's routed rows, layer by layer
+        meta={"routed_rows": lambda p, b: routed_rows(p, b, config)})

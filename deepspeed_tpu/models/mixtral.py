@@ -222,7 +222,7 @@ def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
         return _block(x, maybe_stream(layer), config, train=train, rng=rng,
                       segment_ids=seg)
     if config.remat:
-        from deepspeed_tpu.models.gpt2 import remat_policy
+        from deepspeed_tpu.models.model import remat_policy
         block_fn = jax.checkpoint(
             block_fn, policy=remat_policy(config.remat_policy))
     x, aux = lax.scan(block_fn, x, params["blocks"])

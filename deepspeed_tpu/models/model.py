@@ -301,6 +301,96 @@ def scan_layer_kinds(x, stacks: dict, pattern: tuple, block_fns: dict):
     return x, jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), aux)
 
 
+# -------------------------------------------------------------- the scaffold
+# What a family whose layers are of several kinds (models/qwen3_next.py,
+# nemotron_h.py, joyai.py, laguna.py) does NOT write itself: its file is its
+# config, parameters, mixers, blocks and layout, and one call of
+# ``held_share_model``.
+
+def remat_policy(name: str):
+    """Remat policies for per-layer activation checkpointing (the reference's
+    activation_checkpointing tiers become jax.checkpoint policies)."""
+    if name in (None, "nothing", "nothing_saveable"):
+        return jax.checkpoint_policies.nothing_saveable
+    if name in ("save_attn",):
+        return jax.checkpoint_policies.save_only_these_names("attn_out")
+    if name in ("dots", "dots_saveable"):
+        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    if name in ("offload_attn",):
+        # host-offload tier: attention outputs go to pinned host DRAM instead
+        # of HBM (reference cpu_checkpointing)
+        return jax.checkpoint_policies.save_and_offload_only_these_names(
+            names_which_can_be_saved=[],
+            names_which_can_be_offloaded=["attn_out"],
+            offload_src="device", offload_dst="pinned_host")
+    raise ValueError(f"unknown remat policy {name!r}")
+
+
+def layer_block(block, config, **static):
+    """``fn(x, layer)`` of one kind of layer, as a layer loop calls it:
+    ``block(x, layer, config, **static)`` with the layer's parameters
+    brought to where it computes (``maybe_stream``, inside the remat
+    boundary) and, where ``config.remat``, rematerialised by
+    ``config.remat_policy``."""
+    def fn(x, layer):
+        return block(x, maybe_stream(layer), config, **static)
+    if config.remat:
+        fn = jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
+    return fn
+
+
+def refuse_param_stream(family: str, layout: str):
+    """Where a per-layer parameter transform is on (ZeRO-3's gather,
+    parameter offload's stream) and the family's layers are ``layout``
+    and not one stacked tree: the refusal, in the family's words."""
+    if param_stream_active():
+        raise NotImplementedError(
+            f"{family}: ZeRO-3 and parameter offload gather or stream one "
+            f"layer of a single stacked tree at a time; this model's layers "
+            f"are {layout}, and gathering at that grain is not built — use "
+            f"ZeRO stage 0-2")
+
+
+def embed_tokens(wte, tokens, dtype):
+    """The embedding lookup, under the ``ds.embed`` scope."""
+    from deepspeed_tpu.telemetry.tracing import SCOPE_EMBED
+    with jax.named_scope(SCOPE_EMBED):
+        return wte.astype(dtype)[tokens]
+
+
+def segment_ids_of(batch):
+    """The documents of a packed batch ([B, S] int), or None."""
+    return batch.get("segment_ids") if isinstance(batch, dict) else None
+
+
+def expert_half(x, moe_params, moe_config, norm, train, rng=None):
+    """The expert half of a block: ``x + MoE(norm(x))`` under the ``mlp``
+    scope -> (x, (router loss float32, routed rows over
+    ``held_rows_bound`` int32)), what a layer adds to a loop's sums."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe.layer import moe_layer
+    from deepspeed_tpu.telemetry.tracing import SCOPE_MLP
+    with jax.named_scope(SCOPE_MLP):
+        out, aux, stats = moe_layer(moe_params, norm(x), moe_config,
+                                    train=train, rng=rng, return_stats=True)
+        return x + out, (aux.astype(jnp.float32),
+                         stats["dropped"].astype(jnp.int32))
+
+
+def no_experts():
+    """What a layer without experts adds to the router loss and to the
+    rows over ``held_rows_bound``."""
+    import jax.numpy as jnp
+    return jnp.float32(0.0), jnp.int32(0)
+
+
+def param_count(init_fn) -> int:
+    """Parameters ``init_fn(rng)`` would make, from shapes alone."""
+    import math
+    shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    return int(sum(math.prod(s.shape) for s in jax.tree.leaves(shapes)))
+
+
 def resolve_size(sizes: dict, size: str, family: str) -> dict:
     """Look up a size preset, refusing typos: an unknown ``size`` silently
     falling through to the dataclass defaults once shipped a 50M-param
@@ -395,6 +485,83 @@ class Model:
 
     def loss(self, params, batch, rng=None):
         return self.loss_fn(params, batch, rng)
+
+
+def held_share_model(family: str, size: str, config, *, init_params,
+                     logical_specs, forward_with_aux, expert_layers: int,
+                     expert_matrices: int, serving_needs: str,
+                     loss_with_counts=None, lookup_params: int = 0,
+                     reused_params: int = 0, meta=None) -> Model:
+    """The training-only :class:`Model` of a family whose expert layers may
+    hold a share of their experts (``config.moe``: ``experts_held`` of
+    ``num_experts``).  The family hands over ``init_params(config, rng)``,
+    ``logical_specs(config)``, ``forward_with_aux(params, batch, config,
+    train=, rng=) -> (logits, router loss, rows over the bound)`` and,
+    where its loss is more than the one head's cross-entropy and the
+    router loss, ``loss_with_counts(params, batch, config, rng) -> (loss,
+    {name: count})``.  Counted here, for ``flops_per_token = 6 * active``:
+    of ``expert_layers`` layers' experts (``expert_matrices`` matrices
+    each) a token's weights pass through ``top_k`` of ``num_experts`` of
+    those held; ``lookup_params`` are read and not multiplied (an
+    embedding), ``reused_params`` multiplied a second time (a head behind
+    a second module).  The four serving entry points raise, naming
+    ``serving_needs``; ``meta`` is the family's own beside ``name``,
+    ``n_params``, ``active_params`` and ``step_counts``."""
+    from functools import partial
+    from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
+    from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
+    moe = config.moe
+    n_params = param_count(partial(init_params, config))
+    expert = expert_matrices * moe.d_model * moe.d_ff
+    active = n_params - lookup_params + reused_params \
+        - expert_layers * expert * (
+            moe.held - moe.top_k * moe.held / moe.num_experts)
+
+    if loss_with_counts is None:
+        def loss_with_counts(params, batch, config, rng=None):
+            logits, aux, over = forward_with_aux(params, batch, config,
+                                                 train=True, rng=rng)
+            with jax.named_scope(SCOPE_HEAD_LOSS):
+                # inside a document only, where the batch is packed; aux =
+                # the weighted load-balancing loss summed over layers
+                return token_loss(logits, batch) + aux, \
+                    {ROWS_OVER_BOUND: over}
+
+    def with_counts(params, batch, rng=None):
+        return loss_with_counts(params, batch, config, rng)
+
+    def no_serving(what):
+        def refuse(*_, **__):
+            raise NotImplementedError(
+                f"{family}: {what} is not built — {serving_needs} (ROADMAP)")
+        return refuse
+
+    return Model(
+        config=config,
+        init_fn=partial(init_params, config),
+        apply_fn=lambda p, b, rng=None: forward_with_aux(
+            p, b, config, train=False, rng=rng)[0],
+        loss_fn=lambda p, b, rng=None: with_counts(p, b, rng)[0],
+        # the rows a step's expert layers left out leave the step beside
+        # its loss (no host callback: one inside the layer loop does not
+        # compile for a TPU on this jaxlib, one outside it keeps the step
+        # out of jax's compile cache); the engine counts and warns
+        loss_with_counts_fn=with_counts if moe.holds_subset else None,
+        logical_specs=logical_specs(config),
+        flops_per_token=6.0 * active,
+        meta={"name": f"{family}-{size}", "n_params": n_params,
+              "active_params": active,
+              "step_counts": {ROWS_OVER_BOUND: (
+                  "routed rows past held_rows_bound, left out of the expert "
+                  "layers: the router sent the experts held here more than "
+                  "held_rows_factor times their even share")}
+              if moe.holds_subset else {},
+              **(meta or {})},
+        init_cache_fn=no_serving("init_cache"),
+        prefill_fn=no_serving("prefill"),
+        decode_fn=no_serving("decode"),
+        verify_fn=no_serving("verify"),
+    )
 
 
 def token_loss(logits, batch):

@@ -45,21 +45,20 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, maybe_stream,
-                                        param_stream_active, qdot,
-                                        resolve_size, scan_layer_kinds,
-                                        token_loss)
+from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+                                        held_share_model, layer_block,
+                                        no_experts, param_count, qdot,
+                                        refuse_param_stream, resolve_size,
+                                        scan_layer_kinds, segment_ids_of)
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_layer,
+from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
                                      moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.ops.linear_attention import causal_conv
 from deepspeed_tpu.ops.state_space import ssd_scan
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_EMBED, SCOPE_GATE_NORM,
-    SCOPE_HEAD_LOSS, SCOPE_IN_PROJ, SCOPE_MLP, SCOPE_OUT_PROJ, SCOPE_SCAN,
-    SCOPE_SSM)
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_GATE_NORM, SCOPE_HEAD_LOSS,
+    SCOPE_IN_PROJ, SCOPE_OUT_PROJ, SCOPE_SCAN, SCOPE_SSM)
 
 SSM, EXPERTS, ATTN = "ssm", "experts", "attn"
 #: ``hybrid_override_pattern``'s characters
@@ -147,18 +146,8 @@ class NemotronHConfig:
 
     @property
     def moe(self) -> MoEConfig:
-        return MoEConfig(
-            d_model=self.d_model, d_ff=self.d_ff,
-            num_experts=self.num_experts, top_k=self.top_k,
-            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
-            norm_topk_prob=self.norm_topk_prob, router="sigmoid",
-            routed_scaling_factor=self.routed_scaling_factor,
-            load_balance=self.load_balance, activation="relu2",
-            dispatch_mode=self.moe_dispatch,
-            expert_offset=self.expert_offset,
-            experts_held=self.experts_held,
-            held_rows_factor=self.held_rows_factor,
-            shared_expert_d_ff=self.shared_expert_d_ff)
+        return MoEConfig.of(self, router="sigmoid", activation="relu2",
+                            dispatch_mode=self.moe_dispatch)
 
 
 NEMOTRON_H_SIZES = {
@@ -313,17 +302,11 @@ def _gated_norm(y, z, w, groups, eps):
         y.shape).astype(y.dtype)
 
 
-def _no_counts():
-    """What a layer without experts adds to the router loss and to the
-    rows over ``held_rows_bound``."""
-    return jnp.float32(0.0), jnp.int32(0)
-
-
 @jax.named_scope(SCOPE_BLOCK)
 def _ssm_block(x, layer, config: NemotronHConfig, train, rng=None,
                segment_ids=None):
     with jax.named_scope(SCOPE_SSM):
-        return _ssm_mixer(x, layer, config, segment_ids), _no_counts()
+        return _ssm_mixer(x, layer, config, segment_ids), no_experts()
 
 
 @jax.named_scope(SCOPE_BLOCK)
@@ -342,19 +325,15 @@ def _attn_block(x, layer, config: NemotronHConfig, train, rng=None,
     attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
     with jax.named_scope(SCOPE_ATTN):
         x = x + qdot(attn.reshape(B, S, H * hd), layer["wo"])
-    return x, _no_counts()
+    return x, no_experts()
 
 
 @jax.named_scope(SCOPE_BLOCK)
 def _experts_block(x, layer, config: NemotronHConfig, train, rng=None,
                    segment_ids=None):
-    with jax.named_scope(SCOPE_MLP):
-        h = _rms_norm(x, layer["norm"], config.norm_eps)
-        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
-                                    rng=rng, return_stats=True)
-        # beside the router loss, the rows over held_rows_bound
-        return x + out, (aux.astype(jnp.float32),
-                         stats["dropped"].astype(jnp.int32))
+    return expert_half(
+        x, layer["moe"], config.moe,
+        lambda x: _rms_norm(x, layer["norm"], config.norm_eps), train, rng)
 
 
 _BLOCKS = {SSM: _ssm_block, EXPERTS: _experts_block, ATTN: _attn_block}
@@ -365,31 +344,16 @@ def forward_with_aux(params, batch, config: NemotronHConfig,
     """-> (logits, router loss summed over layers, routed rows over
     ``held_rows_bound`` summed over layers: int32, 0 unless the experts
     held are a subset)."""
-    if param_stream_active():
-        raise NotImplementedError(
-            "nemotron-h: ZeRO-3 and parameter offload gather or stream one "
-            "layer of a single stacked tree at a time; this model's layers "
-            "are three stacks (ssm, experts, attn) walked pattern by "
-            "pattern, and gathering at that grain is not built — use ZeRO "
-            "stage 0-2")
-    tokens = batch["input_ids"]
+    refuse_param_stream(
+        "nemotron-h",
+        "three stacks (ssm, experts, attn) walked pattern by pattern")
     dtype = jnp.dtype(config.dtype)
-    with jax.named_scope(SCOPE_EMBED):
-        x = params["wte"].astype(dtype)[tokens]
-    seg = batch.get("segment_ids") if isinstance(batch, dict) else None
-
-    def block_fn(block):
-        def fn(x, layer):
-            return block(x, maybe_stream(layer), config, train=train,
-                         rng=rng, segment_ids=seg)
-        if config.remat:
-            from deepspeed_tpu.models.gpt2 import remat_policy
-            fn = jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
-        return fn
-
+    x = embed_tokens(params["wte"], batch["input_ids"], dtype)
     x, (aux, over) = scan_layer_kinds(
         x, params["blocks"], config.pattern,
-        {kind: block_fn(block) for kind, block in _BLOCKS.items()})
+        {kind: layer_block(block, config, train=train, rng=rng,
+                           segment_ids=segment_ids_of(batch))
+         for kind, block in _BLOCKS.items()})
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.norm_eps)
         logits = x @ params["lm_head"].astype(dtype)
@@ -397,63 +361,18 @@ def forward_with_aux(params, batch, config: NemotronHConfig,
 
 
 def count_params(config: NemotronHConfig) -> int:
-    import numpy as np
-    shapes = jax.eval_shape(partial(init_params, config),
-                            jax.random.PRNGKey(0))
-    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
-
-
-def _no_serving(what):
-    def refuse(*_, **__):
-        raise NotImplementedError(
-            f"nemotron-h: {what} is not built — serving a model with "
-            f"state-space layers needs a cache that holds each sequence's "
-            f"recurrent state (and convolution history) beside the "
-            f"attention layers' keys and values (ROADMAP)")
-    return refuse
+    return param_count(partial(init_params, config))
 
 
 def nemotron_h_model(size: str = "3-nano-30b-a3b", **overrides) -> Model:
-    cfg_kwargs = resolve_size(NEMOTRON_H_SIZES, size, "nemotron_h")
-    cfg_kwargs.update(overrides)
-    config = NemotronHConfig(**cfg_kwargs)
-    n_params = count_params(config)
-    moe = config.moe
-    # the routed experts a token's weights pass through HERE: top_k of
-    # num_experts of those held (all of them: top_k); the embedding is a
-    # lookup
-    expert = 2 * config.d_model * config.d_ff
-    active = n_params - config.vocab_size * config.d_model \
-        - config.layers_of(EXPERTS) * expert * (
-            moe.held - config.top_k * moe.held / config.num_experts)
-
-    def loss_with_counts(params, batch, rng=None):
-        logits, aux, over = forward_with_aux(params, batch, config,
-                                             train=True, rng=rng)
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            # inside a document only, where the batch is packed; aux = the
-            # weighted load-balancing loss summed over layers
-            return token_loss(logits, batch) + aux, {ROWS_OVER_BOUND: over}
-
-    return Model(
-        config=config,
-        init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
-        loss_fn=lambda p, b, rng=None: loss_with_counts(p, b, rng)[0],
-        # the rows a step's expert layers left out leave the step beside
-        # its loss, as models/qwen3_next.py's; the engine counts and warns
-        loss_with_counts_fn=loss_with_counts if moe.holds_subset else None,
-        logical_specs=logical_specs(config),
-        flops_per_token=6.0 * active,
-        meta={"name": f"nemotron-h-{size}", "n_params": n_params,
-              "active_params": active,
-              "step_counts": {ROWS_OVER_BOUND: (
-                  "routed rows past held_rows_bound, left out of the expert "
-                  "layers: the router sent the experts held here more than "
-                  "twice their even share")} if moe.holds_subset else {}},
-        init_cache_fn=_no_serving("init_cache"),
-        prefill_fn=_no_serving("prefill"),
-        decode_fn=_no_serving("decode"),
-        verify_fn=_no_serving("verify"),
-    )
+    config = NemotronHConfig(**{
+        **resolve_size(NEMOTRON_H_SIZES, size, "nemotron_h"), **overrides})
+    return held_share_model(
+        "nemotron-h", size, config, init_params=init_params,
+        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        expert_layers=config.layers_of(EXPERTS), expert_matrices=2,
+        lookup_params=config.vocab_size * config.d_model,
+        serving_needs=(
+            "serving a model with state-space layers needs a cache that "
+            "holds each sequence's recurrent state (and convolution "
+            "history) beside the attention layers' keys and values"))

@@ -44,21 +44,20 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, maybe_stream,
-                                        param_stream_active, qdot,
-                                        resolve_size, scan_layer_kinds,
-                                        token_loss)
+from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+                                        held_share_model, layer_block,
+                                        param_count, qdot,
+                                        refuse_param_stream, resolve_size,
+                                        scan_layer_kinds, segment_ids_of)
 from deepspeed_tpu.models.llama import _rms_norm, rope
-from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_layer,
+from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
                                      moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.ops.linear_attention import (causal_conv,
                                                 gated_delta_rule)
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_EMBED,
-    SCOPE_GATE_NORM, SCOPE_HEAD_LOSS, SCOPE_IN_PROJ, SCOPE_LINEAR_ATTN,
-    SCOPE_MLP, SCOPE_OUT_PROJ)
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
+    SCOPE_HEAD_LOSS, SCOPE_IN_PROJ, SCOPE_LINEAR_ATTN, SCOPE_OUT_PROJ)
 
 LINEAR, FULL = "linear", "full"
 
@@ -121,17 +120,9 @@ class Qwen3NextConfig:
 
     @property
     def moe(self) -> MoEConfig:
-        return MoEConfig(
-            d_model=self.d_model, d_ff=self.d_ff,
-            num_experts=self.num_experts, top_k=self.top_k,
-            aux_loss_coef=self.aux_loss_coef, z_loss_coef=0.0,
-            norm_topk_prob=self.norm_topk_prob,
-            load_balance=self.load_balance, activation="silu_glu",
-            dispatch_mode=self.moe_dispatch,
-            expert_offset=self.expert_offset,
-            experts_held=self.experts_held,
-            shared_expert_d_ff=self.shared_expert_d_ff,
-            shared_expert_gate=True)
+        return MoEConfig.of(self, activation="silu_glu",
+                            dispatch_mode=self.moe_dispatch,
+                            shared_expert_gate=True)
 
 
 QWEN3_NEXT_SIZES = {
@@ -250,12 +241,10 @@ def _partial_rope(x, config: Qwen3NextConfig):
 
 
 def _moe_finish(x, layer, config: Qwen3NextConfig, train, rng):
-    with jax.named_scope(SCOPE_MLP):
-        h = _norm(x, layer["mlp_norm"], config.rms_norm_eps)
-        out, aux, stats = moe_layer(layer["moe"], h, config.moe, train=train,
-                                    rng=rng, return_stats=True)
-        # beside the router loss, the rows over held_rows_bound
-        return x + out, (aux, stats["dropped"])
+    return expert_half(
+        x, layer["moe"], config.moe,
+        lambda x: _norm(x, layer["mlp_norm"], config.rms_norm_eps),
+        train, rng)
 
 
 def _linear_mixer(x, layer, config: Qwen3NextConfig, segment_ids):
@@ -329,31 +318,15 @@ def forward_with_aux(params, batch, config: Qwen3NextConfig,
     """-> (logits, router loss summed over layers, routed rows over
     ``held_rows_bound`` summed over layers: int32, 0 unless the experts
     held are a subset)."""
-    if param_stream_active():
-        raise NotImplementedError(
-            "qwen3-next: ZeRO-3 and parameter offload gather or stream one "
-            "layer of a single stacked tree at a time; this model's layers "
-            "are two stacks (linear, full) walked period by period, and "
-            "gathering at that grain is not built — use ZeRO stage 0-2")
-    tokens = batch["input_ids"]
+    refuse_param_stream(
+        "qwen3-next", "two stacks (linear, full) walked period by period")
     dtype = jnp.dtype(config.dtype)
-    with jax.named_scope(SCOPE_EMBED):
-        x = params["wte"].astype(dtype)[tokens]
-    seg = batch.get("segment_ids") if isinstance(batch, dict) else None
-
-    def block_fn(block):
-        def fn(x, layer):
-            return block(x, maybe_stream(layer), config, train=train,
-                         rng=rng, segment_ids=seg)
-        if config.remat:
-            from deepspeed_tpu.models.gpt2 import remat_policy
-            fn = jax.checkpoint(fn, policy=remat_policy(config.remat_policy))
-        return fn
-
-    x, aux = scan_layer_kinds(
+    x = embed_tokens(params["wte"], batch["input_ids"], dtype)
+    x, (aux, over) = scan_layer_kinds(
         x, params["blocks"], config.pattern,
-        {LINEAR: block_fn(_linear_block), FULL: block_fn(_full_block)})
-    aux, over = aux
+        {kind: layer_block(block, config, train=train, rng=rng,
+                           segment_ids=segment_ids_of(batch))
+         for kind, block in ((LINEAR, _linear_block), (FULL, _full_block))})
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _norm(x, params["final_norm"], config.rms_norm_eps)
         logits = x @ params["lm_head"].astype(dtype)
@@ -367,68 +340,22 @@ def _wq_halves(grads, config: Qwen3NextConfig):
 
 
 def count_params(config: Qwen3NextConfig) -> int:
-    import numpy as np
-    shapes = jax.eval_shape(partial(init_params, config),
-                            jax.random.PRNGKey(0))
-    return int(sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)))
-
-
-def _no_serving(what):
-    def refuse(*_, **__):
-        raise NotImplementedError(
-            f"qwen3-next: {what} is not built — serving a model with "
-            f"linear-attention layers needs a cache that holds each "
-            f"sequence's recurrent state (and convolution history) beside "
-            f"the full layers' keys and values (ROADMAP)")
-    return refuse
+    return param_count(partial(init_params, config))
 
 
 def qwen3_next_model(size: str = "80b-a3b", **overrides) -> Model:
-    cfg_kwargs = resolve_size(QWEN3_NEXT_SIZES, size, "qwen3_next")
-    cfg_kwargs.update(overrides)
-    config = Qwen3NextConfig(**cfg_kwargs)
-    n_params = count_params(config)
-    moe = config.moe
-    # the routed experts a token's weights pass through HERE: top_k of
-    # num_experts of those held (all of them: top_k)
-    expert = 3 * config.d_model * config.d_ff
-    active = n_params - config.num_layers * expert * (
-        moe.held - config.top_k * moe.held / config.num_experts)
-
-    def loss_with_counts(params, batch, rng=None):
-        logits, aux, over = forward_with_aux(params, batch, config,
-                                             train=True, rng=rng)
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            # inside a document only, where the batch is packed; aux = the
-            # weighted load-balancing loss summed over layers
-            return token_loss(logits, batch) + aux, {ROWS_OVER_BOUND: over}
-
-    return Model(
-        config=config,
-        init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
-        loss_fn=lambda p, b, rng=None: loss_with_counts(p, b, rng)[0],
-        # the rows a step's expert layers left out leave the step beside
-        # its loss (no host callback: one inside the layer loop does not
-        # compile for a TPU on this jaxlib, one outside it keeps the step
-        # out of jax's compile cache); the engine counts and warns
-        loss_with_counts_fn=loss_with_counts if moe.holds_subset else None,
-        logical_specs=logical_specs(config),
-        flops_per_token=6.0 * active,
-        meta={"name": f"qwen3-next-{size}", "n_params": n_params,
-              "active_params": active,
-              "step_counts": {ROWS_OVER_BOUND: (
-                  "routed rows past held_rows_bound, left out of the expert "
-                  "layers: the router sent the experts held here more than "
-                  "twice their even share")} if moe.holds_subset else {},
-              # parts of a leaf worth a row of their own in a gradient
-              # table (scripts/olmoe_grad_check.py)
-              "gradient_views": {
-                  "full.wq[query half]": lambda g: _wq_halves(g, config)[0],
-                  "full.wq[gate half]": lambda g: _wq_halves(g, config)[1]}},
-        init_cache_fn=_no_serving("init_cache"),
-        prefill_fn=_no_serving("prefill"),
-        decode_fn=_no_serving("decode"),
-        verify_fn=_no_serving("verify"),
-    )
+    config = Qwen3NextConfig(**{
+        **resolve_size(QWEN3_NEXT_SIZES, size, "qwen3_next"), **overrides})
+    return held_share_model(
+        "qwen3-next", size, config, init_params=init_params,
+        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        expert_layers=config.num_layers, expert_matrices=3,
+        serving_needs=(
+            "serving a model with linear-attention layers needs a cache "
+            "that holds each sequence's recurrent state (and convolution "
+            "history) beside the full layers' keys and values"),
+        # parts of a leaf worth a row of their own in a gradient table
+        # (scripts/olmoe_grad_check.py)
+        meta={"gradient_views": {
+            "full.wq[query half]": lambda g: _wq_halves(g, config)[0],
+            "full.wq[gate half]": lambda g: _wq_halves(g, config)[1]}})

@@ -34,7 +34,7 @@ no token is dropped at any imbalance.  Its parts carry the
 """
 import contextlib
 import os
-from dataclasses import dataclass, field
+from dataclasses import dataclass, fields
 from functools import partial
 from typing import Callable, Optional
 
@@ -116,6 +116,17 @@ class MoEConfig:
     #: scales it by ``sigmoid(x . w)`` per token (Qwen3-Next)
     shared_expert_d_ff: int = 0
     shared_expert_gate: bool = False
+
+    @classmethod
+    def of(cls, config, **own) -> "MoEConfig":
+        """The expert layers of a model family's ``config``: every field
+        here that ``config`` carries under the same name (widths, expert
+        and choice counts, loss coefficients, the share held), then what
+        the family fixes or names differently (``own``: the router, the
+        activation, the dispatch)."""
+        shared = {f.name: getattr(config, f.name) for f in fields(cls)
+                  if hasattr(config, f.name)}
+        return cls(**{**shared, **own})
 
     @property
     def held(self) -> int:

@@ -21,11 +21,12 @@ with ``(I + L)^-1`` taken once, in float32.  Across chunks the state is
 carried: ``v_new = U - W S``, ``o = (q exp(G)) S + (q k^T . decay) v_new``
 and ``S' = exp(G_C) S + (k exp(G_C - G))^T v_new``.
 
-One algorithm, two lowerings (:func:`_kernel_blocking` chooses by what the
-call can observe).  On one TPU, for heads that are multiples of 128 wide,
-the Mosaic kernels of ops/pallas/gated_delta_rule.py: the state stays in
-VMEM across a sequence's chunks, the inverse is taken inside the kernel
-and the backward is written by hand.  Elsewhere :func:`_chunked_xla`: the
+One algorithm, two lowerings (:func:`_kernel_blocking` chooses, by the
+rule of ``ops/pallas/vmem.lowering``).  On one TPU, for heads that are
+multiples of 128 wide, the Mosaic kernels of
+ops/pallas/gated_delta_rule.py: the state stays in VMEM across a
+sequence's chunks, the inverse is taken inside the kernel and the
+backward is written by hand.  Elsewhere :func:`_chunked_xla`: the
 inverse from one unit-lower-triangular solve, a ``lax.scan`` whose body is
 checkpointed, so that the backward pass (autodiff through the scan) keeps
 one state per chunk and recomputes the rest — the fallback and, beside
@@ -74,31 +75,14 @@ def l2norm(x, eps: float = 1e-6):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _fits_one_tpu(blocking, vmem) -> bool:
-    """What a call can observe before it takes Mosaic kernels: a TPU, one
-    device (no partitioning rule for the calls yet) and a working set
-    inside the device kind's budget."""
-    from deepspeed_tpu.ops.attention import _on_tpu
-    return (_on_tpu() and jax.device_count() == 1
-            and blocking.vmem_bytes <= vmem.budget())
-
-
 def _conv_blocking(interpret, S, C, K, dtype, positions, first):
-    """(the convolution kernels' blocking or None, interpret), chosen as
-    :func:`_kernel_blocking` chooses: the Mosaic kernels of
-    ops/pallas/causal_conv.py on a TPU with one device (no partitioning
-    rule for the call yet), for shapes they take and a working set inside
-    ``vmem.budget()``; else (None) the XLA form."""
-    from deepspeed_tpu.ops.pallas import causal_conv as kernels
-    if interpret is False or not kernels.supported(S, C, K, positions,
-                                                   first):
-        return None, False
-    blocking = kernels.slab_width(S, C, jnp.dtype(dtype).itemsize, positions,
-                                  first)
-    if interpret:
-        return blocking, True
-    return (blocking if _fits_one_tpu(blocking, kernels.vmem) else None), \
-        False
+    """(the blocking of ops/pallas/causal_conv.py's kernels, or None for
+    the XLA form; interpret), by ``vmem.lowering``'s rule."""
+    from deepspeed_tpu.ops.pallas import causal_conv as kernels, vmem
+    return vmem.lowering(
+        interpret, kernels.supported(S, C, K, positions, first),
+        lambda: kernels.slab_width(S, C, jnp.dtype(dtype).itemsize,
+                                   positions, first))
 
 
 def causal_conv(x, w, segment_ids=None, bias=None, activation=None,
@@ -170,20 +154,14 @@ def _chunked(x, n, C, Hk):
 
 
 def _kernel_blocking(interpret, n, C, rep, dk, dv, dt):
-    """(the kernels' grid blocking or None, interpret) — one algorithm,
-    two lowerings, chosen by what the call can observe: the Mosaic kernels
-    of ops/pallas/gated_delta_rule.py on a TPU with one device (no
-    partitioning rule for the call yet), for shapes they take and a working
-    set inside ``vmem.budget()``; else (None) the XLA chunked form below.
-    ``interpret=True`` runs the kernels in interpret mode wherever the
-    shapes allow."""
-    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr
-    if interpret is False or not gdr.supported(dk, dv, C, rep):
-        return None, False
-    blocking = gdr.chunks_per_step(n, C, rep, dk, dv, jnp.dtype(dt).itemsize)
-    if interpret:
-        return blocking, True
-    return (blocking if _fits_one_tpu(blocking, gdr.vmem) else None), False
+    """(the grid blocking of ops/pallas/gated_delta_rule.py's kernels, or
+    None for the XLA chunked form below; interpret), by
+    ``vmem.lowering``'s rule."""
+    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr, vmem
+    return vmem.lowering(
+        interpret, gdr.supported(dk, dv, C, rep),
+        lambda: gdr.chunks_per_step(n, C, rep, dk, dv,
+                                    jnp.dtype(dt).itemsize))
 
 
 def gated_delta_rule(q, k, v, g, beta, segment_ids=None,

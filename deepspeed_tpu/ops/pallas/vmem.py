@@ -1,6 +1,7 @@
 """The VMEM a Mosaic call's buffers may take, by device kind: one table and
 one rule for every kernel that sizes its blocks by it (``grouped_gemm``,
-``ds_flash_attention``).
+``ds_flash_attention``), and the rule by which a call with an XLA form
+beside its kernels takes one or the other (:func:`lowering`).
 
 A call that asks for nothing is granted 16 MiB; ``UNASKED`` is what its
 buffers may fill of that, a quarter left for the compiler's own scratch.
@@ -34,3 +35,23 @@ def limit_for(need_bytes: int) -> Optional[int]:
     if need_bytes <= UNASKED:
         return None
     return max(budget(), need_bytes) + HEADROOM
+
+
+def lowering(interpret, supported: bool, blocking):
+    """(the kernels' grid blocking or None, interpret) of a call that is
+    one algorithm in two lowerings (the delta rule, the state-space scan,
+    the causal convolution), chosen by what the call can observe: its
+    Mosaic kernels on a TPU with one device (no partitioning rule for
+    these calls yet), for shapes they take (``supported``) and a working
+    set (``blocking().vmem_bytes``) inside :func:`budget`; else (None) the
+    XLA form.  ``interpret`` is the caller's: True runs the kernels in
+    interpret mode wherever the shapes allow, False the XLA form."""
+    if interpret is False or not supported:
+        return None, False
+    blocking = blocking()
+    if interpret:
+        return blocking, True
+    from deepspeed_tpu.ops.attention import _on_tpu
+    fits = (_on_tpu() and jax.device_count() == 1
+            and blocking.vmem_bytes <= budget())
+    return (blocking if fits else None), False

@@ -42,17 +42,16 @@ The model writes the ``jax.named_scope`` ``scan`` around the call
 chunk length, heads and groups in the step's account
 and the lowering it took (``tracing.ssd_chunks``).
 
-One algorithm, two lowerings (:func:`_kernel_blocking` chooses by what the
-call can observe).  On one TPU, for a state size of whole lane tiles, a
-chunk of one (128) and heads of whole sublane tiles, the Mosaic kernels
-of ops/pallas/state_space.py: a group's state stays in VMEM across a
-sequence's chunks, a head's decay matrix is built in registers and never
-written to HBM, the backward is written by hand.  Elsewhere
-:func:`_chunked_xla`, einsums around a ``lax.scan`` with autodiff's
-backward — the fallback and, beside :func:`ssd_recurrent`, the kernels'
-oracle.
+One algorithm, two lowerings (:func:`_kernel_blocking` chooses, by the
+rule of ``ops/pallas/vmem.lowering``).  On one TPU, for a state size of
+whole lane tiles, a chunk of one (128) and heads of whole sublane tiles,
+the Mosaic kernels of ops/pallas/state_space.py: a group's state stays
+in VMEM across a sequence's chunks, a head's decay matrix is built in
+registers and never written to HBM, the backward is written by hand.
+Elsewhere :func:`_chunked_xla`, einsums around a ``lax.scan`` with
+autodiff's backward — the fallback and, beside :func:`ssd_recurrent`, the
+kernels' oracle.
 """
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -70,24 +69,14 @@ def _chunked(t, n, C, G):
 
 
 def _kernel_blocking(interpret, n, C, r, P, N, dtype):
-    """(the kernels' grid blocking or None, interpret) — one algorithm,
-    two lowerings, chosen by what the call can observe: the Mosaic kernels
-    of ops/pallas/state_space.py on a TPU with one device (no partitioning
-    rule for the call yet), for shapes they take and a working set inside
-    ``vmem.budget()``; else (None) the XLA chunked form below.
-    ``interpret=True`` runs the kernels in interpret mode wherever the
-    shapes allow."""
-    from deepspeed_tpu.ops.pallas import state_space as kernels
-    if interpret is False or not kernels.supported(P, N, r, C):
-        return None, False
-    blocking = kernels.chunks_per_step(n, C, r, P, N,
-                                       jnp.dtype(dtype).itemsize)
-    if interpret:
-        return blocking, True
-    from deepspeed_tpu.ops.attention import _on_tpu
-    on_one_tpu = _on_tpu() and jax.device_count() == 1
-    fits = blocking.vmem_bytes <= kernels.vmem.budget()
-    return (blocking if on_one_tpu and fits else None), False
+    """(the grid blocking of ops/pallas/state_space.py's kernels, or None
+    for the XLA chunked form below; interpret), by ``vmem.lowering``'s
+    rule."""
+    from deepspeed_tpu.ops.pallas import state_space as kernels, vmem
+    return vmem.lowering(
+        interpret, kernels.supported(P, N, r, C),
+        lambda: kernels.chunks_per_step(n, C, r, P, N,
+                                        jnp.dtype(dtype).itemsize))
 
 
 def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,

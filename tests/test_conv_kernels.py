@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops import linear_attention as la
+from deepspeed_tpu.ops import state_space as ss
 from deepspeed_tpu.ops.linear_attention import causal_conv
 from deepspeed_tpu.ops.pallas import causal_conv as kernels
 from deepspeed_tpu.telemetry import tracing
@@ -263,6 +264,47 @@ def test_more_than_one_device_takes_the_xla_form(monkeypatch):
     # a device kind with no budget of its own: the sequence does not fit
     monkeypatch.setattr(kernels.vmem, "device_kind", lambda: "tpu v4")
     assert rule() == (None, False)
+
+
+#: each op's choice at its cell's shapes (the Nemotron-H convolution and
+#: scan, Qwen3-Next's delta rule), bfloat16
+LOWERINGS = {
+    "conv": lambda asked: la._conv_blocking(
+        asked, 8192, 8192, 4, jnp.bfloat16, "sublanes", 0),
+    "delta_rule": lambda asked: la._kernel_blocking(
+        asked, 128, 64, 2, 128, 128, jnp.bfloat16),
+    "scan": lambda asked: ss._kernel_blocking(
+        asked, 64, 128, 8, 64, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", [
+    "one_tpu", "not_a_tpu", "more_than_one_device", "over_the_budget",
+    "interpret_asked_for", "xla_asked_for"])
+@pytest.mark.parametrize("op", sorted(LOWERINGS))
+def test_the_three_ops_choose_their_lowering_by_one_rule(op, case,
+                                                         monkeypatch):
+    """``vmem.lowering`` through each op's own call of it: the kernels on
+    a TPU with one device and a working set inside the budget; the XLA
+    form where any of the three fails, or is asked for; the kernels in
+    interpret mode where that is asked for, whatever the host."""
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import vmem
+    monkeypatch.setattr(vmem, "device_kind", lambda: "tpu v5 lite")
+    kernel_blocking, _ = LOWERINGS[op](True)
+    assert kernel_blocking.vmem_bytes <= vmem.budget()
+    monkeypatch.setattr(attention, "_on_tpu", lambda: case != "not_a_tpu")
+    monkeypatch.setattr(
+        jax, "device_count",
+        lambda: 4 if case == "more_than_one_device" else 1)
+    if case == "over_the_budget":
+        # no blocking fits (the convolution sizes its slab by the budget)
+        monkeypatch.setattr(vmem, "budget", lambda: 0)
+    asked = {"interpret_asked_for": True, "xla_asked_for": False}.get(case)
+    want = {"one_tpu": (kernel_blocking, False),
+            "interpret_asked_for": (kernel_blocking, True)}.get(
+                case, (None, False))
+    assert LOWERINGS[op](asked) == want
 
 
 @pytest.mark.parametrize("S,C,itemsize,positions,first,slab,tile", [

@@ -3,6 +3,7 @@ implementations)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tests.util import tiny_gpt2, random_batch
 from deepspeed_tpu.ops.attention import xla_causal_attention
@@ -111,3 +112,49 @@ def test_neox_and_bloom_native_models_train(devices8):
                 0, 256, size=(1, 8, 16), dtype=np.int32)}
             losses.append(float(engine.train_batch(batch=batch)))
         assert all(np.isfinite(losses))
+
+
+# what the parent tree's four builders computed, each with its own copy of
+# the arithmetic: (size, overrides) -> (n_params, active_params)
+_A_SHARE = dict(experts_held=4, expert_offset=2)
+HELD_SHARE_FAMILIES = {
+    "qwen3_next": ("qwen3-next", [
+        ("tiny", {}, 95216, 58352.0), ("tiny", _A_SHARE, 70640, 52208.0),
+        ("80b-a3b", {}, 79674391296, 3874929408.0)]),
+    "nemotron_h": ("nemotron-h", [
+        ("tiny", {}, 55272, 34792.0), ("tiny", _A_SHARE, 47080, 32744.0),
+        ("3-nano-30b-a3b", {}, 31577940288, 3227754816.0)]),
+    "joyai": ("joyai", [
+        ("tiny", {}, 86328, 58680.0), ("tiny", _A_SHARE, 67896, 54072.0),
+        ("llm-flash", {}, 50190491648, 3382059008.0)]),
+    "laguna": ("laguna", [
+        ("tiny", {}, 116896, 71840.0), ("tiny", _A_SHARE, 92320, 65696.0),
+        ("s-2.1", {}, 117561953280, 8140950528.0)]),
+}
+
+
+@pytest.mark.parametrize("family", sorted(HELD_SHARE_FAMILIES))
+def test_a_held_share_family_counts_and_words_as_one(family):
+    """The four families whose ``Model`` is ``held_share_model``'s: the
+    counts are the ones each computed for itself before (tiny, a share of
+    tiny, the published size), ``count_params`` is ``meta["n_params"]``,
+    and the warning for rows over the bound names ``held_rows_factor`` —
+    where nothing is left out there is nothing to count or to word."""
+    import importlib
+    from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
+    module = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    name, sizes = HELD_SHARE_FAMILIES[family]
+    for size, overrides, n_params, active in sizes:
+        model = getattr(module, family + "_model")(size, **overrides)
+        assert model.meta["name"] == f"{name}-{size}"
+        assert model.meta["n_params"] == n_params \
+            == module.count_params(model.config)
+        assert model.meta["active_params"] == active
+        assert model.flops_per_token == 6.0 * active
+        if overrides:
+            assert "held_rows_factor times" in \
+                model.meta["step_counts"][ROWS_OVER_BOUND]
+            assert model.loss_with_counts_fn is not None
+        else:
+            assert model.meta["step_counts"] == {}
+            assert model.loss_with_counts_fn is None
