@@ -303,7 +303,7 @@ def scan_layer_kinds(x, stacks: dict, pattern: tuple, block_fns: dict):
 
 # -------------------------------------------------------------- the scaffold
 # What a family whose layers are of several kinds (models/qwen3_next.py,
-# nemotron_h.py, joyai.py, laguna.py) does NOT write itself: its file is its
+# nemotron_h.py, joyai.py, laguna.py, mellum.py) does NOT write itself: its file is its
 # config, parameters, mixers, blocks and layout, and one call of
 # ``held_share_model``.
 
@@ -506,7 +506,11 @@ def held_share_model(family: str, size: str, config, *, init_params,
     embedding), ``reused_params`` multiplied a second time (a head behind
     a second module).  The four serving entry points raise, naming
     ``serving_needs``; ``meta`` is the family's own beside ``name``,
-    ``n_params``, ``active_params`` and ``step_counts``."""
+    ``n_params``, ``active_params`` and ``step_counts``.  The counts
+    always leave the step: rows are bounded where a share is held and
+    where the layers exchange rows over an ``expert`` mesh axis, which the
+    mesh decides after the model is built; where nothing is bounded the
+    count is a zero."""
     from functools import partial
     from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
     from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
@@ -546,7 +550,7 @@ def held_share_model(family: str, size: str, config, *, init_params,
         # its loss (no host callback: one inside the layer loop does not
         # compile for a TPU on this jaxlib, one outside it keeps the step
         # out of jax's compile cache); the engine counts and warns
-        loss_with_counts_fn=with_counts if moe.holds_subset else None,
+        loss_with_counts_fn=with_counts,
         logical_specs=logical_specs(config),
         flops_per_token=6.0 * active,
         meta={"name": f"{family}-{size}", "n_params": n_params,
@@ -554,8 +558,7 @@ def held_share_model(family: str, size: str, config, *, init_params,
               "step_counts": {ROWS_OVER_BOUND: (
                   "routed rows past held_rows_bound, left out of the expert "
                   "layers: the router sent the experts held here more than "
-                  "held_rows_factor times their even share")}
-              if moe.holds_subset else {},
+                  "held_rows_factor times their even share")},
               **(meta or {})},
         init_cache_fn=no_serving("init_cache"),
         prefill_fn=no_serving("prefill"),

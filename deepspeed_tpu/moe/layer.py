@@ -15,10 +15,15 @@ Two dispatch formulations (``MoEConfig.dispatch_mode``, ISSUE 8):
   FFN runs as ONE grouped GEMM over the sorted rows against the stacked
   weights (zero capacity padding), and outputs combine by gather.
   **Drop-free**: every routed token computes, regardless of
-  ``capacity_factor``.  On a multi-device ``expert`` mesh axis this
-  mode currently falls back to the einsum formulation (the pallas
-  custom call has no GSPMD rule — the qgemm precedent; a shard_map
-  tier is queued on a jax with working partial-auto shard_map).
+  ``capacity_factor``.  On an ``expert`` mesh axis wider than one the
+  experts are really spread (:func:`_exchanged_grouped_moe`): inside a
+  ``shard_map`` over every mesh axis each chip routes its own tokens
+  over all experts, sends each chosen row to the chip that holds its
+  expert (one all-to-all), runs the held plan over the rows it received,
+  sends the results back (a second) and weights and sums them where the
+  token lives.  Drop-free inside a stated bound: a chip has room for
+  ``held_rows_factor`` times the rows even routing sends it, and a row
+  past that is counted (:data:`ROWS_OVER_BOUND`), never silently lost.
 - ``auto`` — einsum when training; grouped at eval/serving when the
   kernel is real (single TPU device / interpret) or the host is
   single-device — a multi-device host where only the unsharded
@@ -46,8 +51,9 @@ from deepspeed_tpu.comm.mesh import get_topology, EXPERT_AXIS
 from deepspeed_tpu.moe.sharded_moe import (topkgating, topk_routing,
                                            GateOutput)
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_COMBINE, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_ROUTER,
-    SCOPE_SHARED_EXPERT, count_in_step)
+    SCOPE_COMBINE, SCOPE_DISPATCH, SCOPE_EXCHANGE, SCOPE_EXPERTS,
+    SCOPE_RETURN, SCOPE_ROUTER, SCOPE_SEND, SCOPE_SHARED_EXPERT,
+    count_in_step)
 
 
 @dataclass(frozen=True)
@@ -236,15 +242,17 @@ def dispatch_scope(mode: Optional[str]):
 
 def resolve_dispatch_mode(config: MoEConfig, train: bool) -> str:
     """-> "einsum" | "grouped" for this call (see set_dispatch_override).
-    A grouped request on a multi-device ``expert`` mesh axis falls back
-    to einsum (no GSPMD rule for the pallas call — qgemm precedent)."""
+    A grouped request stays grouped on an ``expert`` mesh axis of any
+    width: wider than one, the layer exchanges its rows
+    (:func:`_exchanged_grouped_moe`)."""
     env = os.environ.get("DS_MOE_DISPATCH")
     mode = env or _dispatch_override or config.dispatch_mode or "auto"
     if mode not in DISPATCH_MODES:
         raise ValueError(f"moe dispatch mode {mode!r}: choose one of "
                          f"{DISPATCH_MODES}")
     if mode == "auto":
-        if train:
+        if train or expert_axis_size() > 1:
+            # (auto never picks the exchange: it is asked for)
             mode = "einsum"
         elif gg_kernel_real() or jax.device_count() == 1:
             mode = "grouped"
@@ -257,17 +265,13 @@ def resolve_dispatch_mode(config: MoEConfig, train: bool) -> str:
             # (single-device serving programs on a multi-device host —
             # the test/bench surface)
             mode = "einsum"
-    if mode == "grouped":
-        ep = dict(get_topology().mesh.shape).get(EXPERT_AXIS, 1)
-        if ep > 1:
-            from deepspeed_tpu.utils.logging import warning_once
-            warning_once(
-                f"moe grouped dispatch: expert mesh axis is {ep}-way — "
-                "falling back to the einsum formulation (drop-free at "
-                "eval; configured capacity when training).  The "
-                "shard_map grouped tier is queued (ROADMAP item 4).")
-            mode = "einsum"
     return mode
+
+
+def expert_axis_size() -> int:
+    """Chips of the ``expert`` mesh axis (1: every chip holds every expert
+    it is told it holds, and no row leaves it)."""
+    return dict(get_topology().mesh.shape).get(EXPERT_AXIS, 1)
 
 
 # ------------------------------------------------------------- telemetry
@@ -341,6 +345,21 @@ def _emit_held_plan(plan):
     from deepspeed_tpu.ops.pallas.grouped_gemm import live_rows
     jax.debug.callback(_report_held_plan, live_rows(plan),
                        jnp.int32(plan.padded_rows))
+
+
+def _report_exchanged(sent, received):
+    reg = _metrics_registry
+    if reg is None:
+        return
+    reg.set_gauge(EXCHANGE_ROWS_SENT, float(sent))
+    reg.set_gauge(EXCHANGE_ROWS_RECEIVED, float(received))
+
+
+def _emit_exchanged(sent, received):
+    """Beside :func:`_emit_held_plan`, through the registry tap alone."""
+    if _metrics_registry is None:
+        return
+    jax.debug.callback(_report_exchanged, sent, received)
 
 
 def _emit_router_health(logits, routing, config: MoEConfig):
@@ -423,6 +442,9 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     w_in, w_out = params["w_in"], params["w_out"]
 
     R = T * k
+    if expert_axis_size() > 1:
+        return _exchanged_grouped_moe(params, xt, config, routing, eids,
+                                      gates)
     if config.holds_subset:
         return _held_grouped_moe(params, xt, config, routing, eids, gates)
     if gg_kernel_real() and not train and R <= gg.SLOT_MAX_ROWS:
@@ -497,6 +519,137 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
     return combined, aux, (jnp.sum(plan.counts) - over, over)
 
 
+def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
+                           gates):
+    """The grouped formulation with the experts spread over the ``expert``
+    mesh axis: chip ``d`` of its ``n`` holds experts ``[d * E / n, (d + 1)
+    * E / n)``.  The routing (``routing``, ``eids``, ``gates``: over all
+    experts and the whole batch) is the caller's; from there on every mesh
+    axis is manual, and each chip, for the tokens it holds (the same number
+    on every chip: rows of zero gate make it up where the tokens do not
+    split evenly, a decode step's):
+
+    1. lays its routed rows out by the chip that holds their expert
+       (``mappings.make_exchange_plan``), gathers them into the send
+       buffer, and learns from one small all-gather how many rows every
+       chip has for every other (``make_exchange_sizes``);
+    2. ``exchange/exchange_send``: one all-to-all of the rows
+       (``lax.ragged_all_to_all``: the rows there are and no padding), and
+       a small one of their experts' numbers on the chip they go to.  What
+       a chip receives from all chips is one prefix of its receive buffer,
+       whose length is a stated bound: ``held_rows_factor`` times the rows
+       a chip is sent under even routing (``grouped_gemm.held_rows_bound``)
+       — on what a chip receives in all, not on what one chip sends
+       another: one sender's skew uses the room the others leave.  A row
+       that finds no room is counted (:data:`ROWS_OVER_BOUND`), never
+       silently lost;
+    3. runs **the held plan** over what it received — a row there is a
+       token of its own with one choice: ``make_held_group_plan``,
+       ``dispatch_held_rows``, the grouped kernels, and the rows summed
+       back into the order they arrived in (``collect_held_rows``:
+       ``ds_rowsum``), all over the live prefix;
+    4. ``exchange/exchange_return``: one all-to-all of the results, each
+       row to the place it came from;
+    5. weights each row by its gate and sums a token's rows, once, in
+       float32 (``mappings.return_rows``).
+
+    Backward, every step is its own transpose by hand (an all-to-all's is
+    the all-to-all back): two all-to-alls of rows a pass.  The expert
+    weights come in as this chip's ``[E / n, ...]`` slices and their
+    gradients leave so — reduced over no chip of the ``expert`` axis.
+    Returns as :func:`_held_grouped_moe` does, the counts summed over the
+    chips."""
+    from deepspeed_tpu.moe import mappings
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    from deepspeed_tpu.utils.jax_compat import shard_map
+    topo = get_topology()
+    mesh = topo.mesh
+    n = expert_axis_size()
+    T, D = xt.shape
+    E, k, dt = config.num_experts, config.top_k, xt.dtype
+    tok_axes = tuple(topo.zero_shard_axes)
+    chips = topo.axis_size(tok_axes)
+    if config.holds_subset or E % n or mesh.shape.get("model", 1) > 1:
+        raise ValueError(
+            f"moe: the exchange over a {n}-wide expert axis spreads all "
+            f"{E} experts evenly (no held subset: {config.held} from "
+            f"{config.expert_offset}) and is not built for a model axis "
+            f"wider than one ({dict(mesh.shape)})")
+    held, t_chip = E // n, -(-T // chips)
+    # tokens that do not split evenly over the chips that hold tokens (a
+    # decode step of a small batch): rows of zeros with gates of zero make
+    # up the number, their choices spread over the experts, and are cut
+    # off again at the end
+    pad = t_chip * chips - T
+    eids, gates = eids.reshape(T, k), gates.reshape(T, k)
+    if pad:
+        xt = jnp.concatenate([xt, jnp.zeros((pad, D), dt)])
+        eids = jnp.concatenate([eids, (jnp.arange(
+            pad * k, dtype=eids.dtype) % E).reshape(pad, k)])
+        gates = jnp.concatenate([gates, jnp.zeros((pad, k), gates.dtype)])
+    R = t_chip * k
+    # n chips send a chip R rows each, 1 / n of them under even routing
+    bound = gg.held_rows_bound(n * R, held, E,
+                               factor=config.held_rows_factor)
+    row_bytes = D * jnp.dtype(dt).itemsize
+    count_in_step(
+        # of one chip; the routed rows are those it EXPECTS to receive
+        grouped_routed_rows=R,
+        grouped_padded_rows=bound + held * gg.default_block_m(),
+        held_rows_bound=bound, experts_held=held, experts_routed=E,
+        exchange_calls={f"{t_chip}x{D}:{n}": {
+            "pairs": n, "experts_held": held, "tokens": t_chip,
+            "routed_rows": R, "receive_rows": bound, "width": D,
+            "even_rows_per_pair": R // n,
+            # what one all-to-all of rows puts on a chip's links under
+            # even routing: the rows for the other chips, nothing else
+            "wire_bytes": (n - 1) * (R // n) * row_bytes,
+            "path": mappings.exchange_path()}})
+    weights = {name: params[name] for name in ("w_gate", "w_in", "w_out")
+               if name in params}
+
+    def on_chip(xt, eids, gates, weights):
+        with jax.named_scope(SCOPE_DISPATCH):
+            out = mappings.make_exchange_plan(eids.reshape(-1), held, n)
+            sizes = mappings.make_exchange_sizes(out.sizes, bound)
+            buf = mappings.send_rows(xt, out, k)            # [R, D]
+        with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
+            rows = mappings.exchange_forth(buf, sizes, bound)
+            experts = mappings.exchange_experts(out.local_expert, sizes,
+                                                bound, held)
+        with jax.named_scope(SCOPE_DISPATCH):
+            plan, over = gg.make_held_group_plan(experts, 0, held, bound)
+            x_pad = gg.dispatch_held_rows(rows, plan, 1)    # [Mp, D]
+        _emit_held_plan(plan)
+        _emit_exchanged(jnp.sum(sizes.send), jnp.sum(sizes.held))
+        mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
+        with jax.named_scope(SCOPE_EXPERTS):
+            h = _glu(mm, x_pad, weights.get("w_gate"), weights["w_in"],
+                     config, plan)
+            y = mm(h, weights["w_out"])                     # [Mp, D]
+        with jax.named_scope(SCOPE_COMBINE):
+            back = gg.collect_held_rows(y, plan, bound, 1)
+        with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_RETURN):
+            back = mappings.exchange_back(back, sizes, R)
+        with jax.named_scope(SCOPE_COMBINE):
+            combined = mappings.return_rows(back, gates.reshape(-1), out, k)
+        dropped = sizes.over + over
+        return combined, jnp.stack([jnp.int32(R) - sizes.over, dropped])[None]
+
+    tok = P(tok_axes)
+    combined, counts = shard_map(
+        on_chip, mesh=mesh,
+        in_specs=(tok, tok, tok, jax.tree.map(lambda _: P(EXPERT_AXIS),
+                                              weights)),
+        out_specs=(tok, P(tuple(mesh.axis_names))), check_vma=False)(
+            xt, eids, gates, weights)
+    counts = jnp.sum(counts, axis=0).astype(jnp.int32)
+    aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
+    if pad:
+        return combined[:T], aux, (counts[0] - pad * k, counts[1])
+    return combined, aux, (counts[0], counts[1])
+
+
 #: the name a model gives the rows over ``held_rows_bound``, summed over its
 #: layers, among its step counts (``Model.loss_with_counts_fn``): they leave
 #: the step beside the loss, and the engine adds them up under this name
@@ -507,6 +660,11 @@ ROWS_OVER_BOUND = "moe/rows_over_bound"
 #: the rows of a held plan's live prefix, and of the plan
 HELD_LIVE_ROWS = "moe/held_live_rows"
 HELD_PLAN_ROWS = "moe/held_plan_rows"
+#: and, where the layer exchanges (:func:`_exchanged_grouped_moe`): the
+#: rows a chip sent to the chips of the ``expert`` axis (itself among them)
+#: and those it received
+EXCHANGE_ROWS_SENT = "moe/exchange_rows_sent"
+EXCHANGE_ROWS_RECEIVED = "moe/exchange_rows_received"
 
 
 def _route(params, logits, config: MoEConfig, train: bool, rng):

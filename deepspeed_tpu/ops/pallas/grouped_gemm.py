@@ -791,6 +791,35 @@ def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
     return _dispatch_held(xt, _way_back(plan), live_rows(plan), top_k, chunk)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _collect_held(y, back, live, tokens, top_k, chunk):
+    return _sum_live_into_tokens(y, None, back, tokens, top_k, live, chunk)
+
+
+def _collect_held_fwd(y, back, live, tokens, top_k, chunk):
+    return _collect_held(y, back, live, tokens, top_k, chunk), (back, live)
+
+
+def _collect_held_bwd(tokens, top_k, chunk, res, g):
+    back, live = res
+    return (_token_rows_live(g, back.padded_to_row // top_k, live, chunk),
+            None, None)
+
+
+_collect_held.defvjp(_collect_held_fwd, _collect_held_bwd)
+
+
+def collect_held_rows(y: jnp.ndarray, plan: GroupPlan, tokens: int,
+                      top_k: int):
+    """:func:`dispatch_held_rows` transposed: the live prefix of ``y``
+    [Mp, D] summed into ``[tokens, D]`` with no gate (the rows an exchange
+    received, each its own token, go back to where they arrived:
+    moe/layer.py).  Backward: the gather :func:`dispatch_held_rows` is."""
+    chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
+    return _collect_held(y, _way_back(plan), live_rows(plan), int(tokens),
+                         top_k, chunk)
+
+
 def _gate_and_token(gates, padded_to_row, top_k, start, chunk):
     """Of the ``chunk`` padded rows from ``start`` on: the routed element,
     its gate (0 on a padding row, whose element is ``R``) and its token
@@ -1595,11 +1624,12 @@ def _use_reference(interpret) -> Tuple[bool, bool]:
         from deepspeed_tpu.ops.attention import _on_tpu
         if not _on_tpu():
             return True, False
-        if jax.device_count() > 1:
-            # multi-device mesh: no GSPMD rule for the pallas custom
-            # call (the qgemm precedent) — the ragged_dot reference keeps
-            # EP/TP serving correct; a shard_map tier is queued on a jax
-            # with working partial-auto shard_map (see ROADMAP item 4)
+        if not vmem.call_on_one_device():
+            # a call the partitioner would have to split: no GSPMD rule
+            # for the pallas custom call (the qgemm precedent) — the
+            # ragged_dot reference keeps EP/TP serving correct.  Inside a
+            # manual region of the mesh (the expert-parallel exchange:
+            # moe/layer.py) the call is one device's and the kernels run
             return True, False
         return False, False
     return False, bool(interpret)

@@ -37,13 +37,28 @@ def limit_for(need_bytes: int) -> Optional[int]:
     return max(budget(), need_bytes) + HEADROOM
 
 
+def call_on_one_device() -> bool:
+    """Whether the call being traced runs on one device: the process has
+    one, or the call lies in a manual region of its mesh (a ``shard_map``
+    over every axis), where each device runs the call on what it holds.
+    A Mosaic call has no partitioning rule, so anywhere else on a host of
+    several devices it takes its XLA form."""
+    if jax.device_count() == 1:
+        return True
+    from deepspeed_tpu.utils.jax_compat import get_abstract_mesh
+    mesh = get_abstract_mesh()
+    return bool(mesh.axis_names) \
+        and frozenset(mesh.manual_axes) == frozenset(mesh.axis_names)
+
+
 def lowering(interpret, supported: bool, blocking):
     """(the kernels' grid blocking or None, interpret) of a call that is
     one algorithm in two lowerings (the delta rule, the state-space scan,
     the causal convolution), chosen by what the call can observe: its
-    Mosaic kernels on a TPU with one device (no partitioning rule for
-    these calls yet), for shapes they take (``supported``) and a working
-    set (``blocking().vmem_bytes``) inside :func:`budget`; else (None) the
+    Mosaic kernels on a TPU where the call is on one device
+    (:func:`call_on_one_device`: no partitioning rule for these calls
+    yet), for shapes they take (``supported``) and a working set
+    (``blocking().vmem_bytes``) inside :func:`budget`; else (None) the
     XLA form.  ``interpret`` is the caller's: True runs the kernels in
     interpret mode wherever the shapes allow, False the XLA form."""
     if interpret is False or not supported:
@@ -52,6 +67,6 @@ def lowering(interpret, supported: bool, blocking):
     if interpret:
         return blocking, True
     from deepspeed_tpu.ops.attention import _on_tpu
-    fits = (_on_tpu() and jax.device_count() == 1
+    fits = (_on_tpu() and call_on_one_device()
             and blocking.vmem_bytes <= budget())
     return (blocking if fits else None), False

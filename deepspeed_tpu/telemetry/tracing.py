@@ -334,6 +334,14 @@ SCOPE_DISPATCH = "dispatch"         # rows by (token, choice), sort, scatter
 SCOPE_EXPERTS = "experts"           # the grouped GEMMs and the activation
 SCOPE_COMBINE = "combine"           # gather back, gate-weighted sum over k
 SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token passes through
+# beside those four, where the experts are spread over the ``expert`` mesh
+# axis (moe/layer.py ``_exchanged_grouped_moe``): the all-to-alls, and
+# nothing else — beneath ``exchange``, ``exchange_send`` (rows and their
+# experts out, between ``dispatch``'s two halves) and ``exchange_return``
+# (the experts' rows back, inside ``combine``'s)
+SCOPE_EXCHANGE = "exchange"
+SCOPE_SEND = "exchange_send"
+SCOPE_RETURN = "exchange_return"
 # inside ``ds.block``, where the mixer is a linear-attention layer
 # (models/qwen3_next.py; ``attn`` stays the full-attention layer's):
 SCOPE_LINEAR_ATTN = "linear_attn"   # LN1 and all of the below
@@ -374,7 +382,7 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN, SCOPE_Q_LATENT,
                SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP,
                SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_HEAD_GATE,
-               SCOPE_LEAD_MLP)
+               SCOPE_LEAD_MLP, SCOPE_EXCHANGE, SCOPE_SEND, SCOPE_RETURN)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
@@ -428,12 +436,14 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%([^\s,)}]+)")
 _ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)[a-z0-9]*)\[([0-9,]*)\]")
+_OPERAND = re.compile(r"\(\s*(?:[a-z0-9]+\[[0-9,]*\]\S* )?%([^\s,)]+)")
 _GROUPS_IOTA = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_LIST = re.compile(r"replica_groups=\{\{([0-9,]*)\}")
 #: HLO opcode (less ``-start`` / ``-done``) -> the cost model's family
 _COLLECTIVE_OPS = {"all-gather": "all_gather", "all-reduce": "all_reduce",
                    "reduce-scatter": "reduce_scatter",
                    "all-to-all": "all_to_all",
+                   "ragged-all-to-all": "all_to_all",
                    "collective-permute": "ppermute"}
 
 
@@ -518,6 +528,8 @@ def parse_program_text(text: str) -> Dict[str, Dict[str, Any]]:
                                        (base, _group_size(tail)))
         rows.append((name, computation, shape, opcode, base, tail))
 
+    shapes = {(computation, name): shape
+              for name, computation, shape, *_ in rows}
     fused = set()
     for _, _, _, opcode, _, tail in rows:
         if opcode == "fusion":
@@ -551,6 +563,15 @@ def parse_program_text(text: str) -> Dict[str, Dict[str, Any]]:
                 half = "-start" if "-start" in name else "-done"
         if collective is not None and half != "-start":
             payload = _shape_bytes(shape)
+            if collective == "ragged-all-to-all":
+                # the result is a buffer sized by a bound; the rows there
+                # are to send are the operand's (whichever is less: the
+                # way back sends a buffer's live rows into their places)
+                sent = _OPERAND.search(tail)
+                sent = shapes.get((computation, sent.group(1))) \
+                    if sent else None
+                if sent:
+                    payload = min(payload, _shape_bytes(sent))
             if collective == "reduce-scatter" and group:
                 payload *= group
             wire = int(round(payload * ring_wire_factor(
@@ -724,6 +745,23 @@ def held_row_sums(name: str = TRAIN_STEP_PROGRAM):
     back to one scatter-add over the plan (off the chip, or on more than
     one device).  None where the step has no such sum."""
     return _account_rows(name, "held_row_sums")
+
+
+def exchange_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The expert-parallel exchanges of the step as moe/layer.py traced
+    them: one row per shape — ``pairs`` (chips of the ``expert`` axis),
+    ``experts_held`` a chip, ``tokens`` and ``routed_rows`` of one chip
+    (the rows it sends), ``receive_rows`` (the rows it has room to receive
+    from all chips together: the held plan's bound, a row past which is
+    counted in ``moe/rows_over_bound``), ``width``, ``even_rows_per_pair``
+    (what even routing sends from one chip to another), ``wire_bytes``
+    (what one all-to-all of rows puts on a chip's links under even
+    routing) and ``path``, the collective the rows were traced to travel by
+    (``moe/mappings.py exchange_path``: ``"ragged_all_to_all"`` on a TPU —
+    the rows there are, no padding — and ``"all_to_all"`` of whole segments
+    where the backend has no ragged one).  None where the step has no
+    exchange."""
+    return _account_rows(name, "exchange_calls")
 
 
 def _account_rows(name: str, counter: str):

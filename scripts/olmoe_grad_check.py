@@ -62,8 +62,9 @@ def one_seed(workload, config, traffic, seed):
     sizes = {**config["model"], "n_params": model.meta["n_params"]}
     engine, _ = build_engine(config, traffic, model, seed, jax.devices())
     gas = traffic["gradient_accumulation_steps"]
-    first = datagen.BatchStream(traffic, sizes["vocab_size"],
-                                traffic["micro_batch_per_chip"], seed).next()
+    first = datagen.BatchStream(
+        traffic, sizes["vocab_size"], traffic["micro_batch_per_chip"]
+        * engine.topology.dp_world_size, seed).next()
     micro = {k: np.asarray(v)[0] for k, v in first.items()}
     loss = float(engine.forward(micro))
     views = model.meta.get("gradient_views")
@@ -76,6 +77,10 @@ def one_seed(workload, config, traffic, seed):
     got = jax.tree.map(lambda g: np.asarray(g.astype(jnp.float32)) * gas,
                        engine._pending_grads)
     params = jax.tree.map(np.asarray, engine.state["params"])
+    # a cell of more than one chip: the reference's parameters (and so its
+    # gradient) stay split as the engine held them, the batch as it takes it
+    shardings = jax.tree.map(lambda a: a.sharding, engine.state["params"])
+    batch_sharding = engine.batch_sharding
     # the reference's float32 gradient needs the room the engine's state
     # has — all of it: whatever of the engine is still referenced from the
     # telemetry it registered with goes too (everything kept is numpy now)
@@ -86,13 +91,19 @@ def one_seed(workload, config, traffic, seed):
     jax.clear_caches()
 
     reference = importlib.import_module("references." + config["reference"])
-    params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
+    params = jax.tree.map(
+        lambda p, s: jax.device_put(np.asarray(p, np.float32), s),
+        params, shardings)
+    ids, segments = (jax.device_put(np.asarray(micro[k]), batch_sharding)
+                     for k in ("input_ids", "segment_ids"))
 
     def grad(matmul_dtype):
+        # (a gradient lies as its parameter does: left to itself the
+        # partitioner keeps every leaf's whole on every chip of four)
         fn = jax.jit(jax.value_and_grad(lambda p: reference.micro_batch_loss(
-            p, jnp.asarray(micro["input_ids"]),
-            jnp.asarray(micro["segment_ids"]), sizes,
-            matmul_dtype=matmul_dtype, remat=True)))
+            p, ids, segments, sizes,
+            matmul_dtype=matmul_dtype, remat=True)),
+            out_shardings=(None, shardings))
         with jax.default_matmul_precision("highest"):
             value, grads = fn(params)
         return float(value), jax.tree.map(np.asarray, grads)

@@ -2,7 +2,7 @@
 ops/pallas/grouped_gemm.py (float + fused-dequant int8, forward and
 custom-VJP backward, interpret mode so the real Pallas kernels run on
 CPU), grouped-vs-einsum parity for moe/layer.py at matched drop-free
-capacity (train fwd/bwd and eval exactness), the EP-mesh fallback, and
+capacity (train fwd/bwd and eval exactness), the exchange on an EP mesh, and
 the Mixtral serving compositions (cb greedy parity incl. int8 weights /
 int8 KV, spec-decode rollback, prefix-cache COW).
 
@@ -662,11 +662,15 @@ def test_grouped_gemm_span_on_eager_call(tmp_path, monkeypatch):
     assert "moe/grouped_gemm" in names
 
 
-# ------------------------------------------------------------ EP fallback
-def test_grouped_request_on_ep_mesh_falls_back_and_matches(devices8):
-    """A grouped request on a multi-device expert axis falls back to the
-    einsum formulation (no GSPMD rule for the pallas call) and the eval
-    math is unchanged vs the single-device grouped run."""
+# ------------------------------------------------------- EP: the exchange
+def test_grouped_request_on_ep_mesh_exchanges_and_matches(devices8):
+    """A grouped request on a multi-device expert axis stays grouped: the
+    layer exchanges its rows (moe/layer.py ``_exchanged_grouped_moe``; here
+    expert 2 x data 4) and the math is unchanged vs the single-device
+    grouped run.  Tokens the chips cannot split evenly (a generation's 14
+    prompt tokens and 2 a decode step over 8 chips) are made up with rows
+    of zero gate, so ``generate`` serves as it did through the einsum, and
+    greedy tokens match exactly."""
     from deepspeed_tpu.models.mixtral import mixtral_model
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
@@ -675,24 +679,30 @@ def test_grouped_request_on_ep_mesh_falls_back_and_matches(devices8):
                       max_seq_len=64, moe_dispatch="grouped")
     params = m.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(5)
-    prompts = rng.integers(1, 200, (2, 7)).astype(np.int32)
+    batch = {"input_ids": rng.integers(1, 200, (8, 16)).astype(np.int32)}
     ref_eng = InferenceEngine(m, DeepSpeedInferenceConfig(dtype="float32"),
                               model_parameters=params)
-    ref = np.asarray(ref_eng.generate(prompts, max_new_tokens=8,
-                                      do_sample=False))
+    ref = np.asarray(jax.jit(m.apply)(ref_eng.params, batch))
+    prompts = rng.integers(1, 200, (2, 7)).astype(np.int32)
+    ref_tokens = np.asarray(ref_eng.generate(prompts, max_new_tokens=8,
+                                             do_sample=False))
     reset_topology()
     ep_eng = InferenceEngine(
         m, DeepSpeedInferenceConfig(dtype="float32", moe={"ep_size": 2}),
         model_parameters=params)
     assert dict(ep_eng.mesh.shape)["expert"] == 2
-    # the resolver sees the 2-way expert axis and falls back
     with ep_eng.mesh:
         from deepspeed_tpu.comm.mesh import get_topology
         assert dict(get_topology().mesh.shape)["expert"] == 2
-        assert resolve_dispatch_mode(m.config.moe, train=False) == "einsum"
-    got = np.asarray(ep_eng.generate(prompts, max_new_tokens=8,
-                                     do_sample=False))
-    np.testing.assert_array_equal(got, ref)
+        assert resolve_dispatch_mode(m.config.moe, train=False) == "grouped"
+        fn = jax.jit(m.apply)
+        got = np.asarray(fn(ep_eng.params, batch))
+        assert " all-to-all(" in fn.lower(
+            ep_eng.params, batch).compile().as_text()
+    np.testing.assert_allclose(got, ref, atol=2e-5)
+    got_tokens = np.asarray(ep_eng.generate(prompts, max_new_tokens=8,
+                                            do_sample=False))
+    np.testing.assert_array_equal(got_tokens, ref_tokens)
 
 
 # ------------------------------------------------------- serving parity

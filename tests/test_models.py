@@ -138,8 +138,10 @@ def test_a_held_share_family_counts_and_words_as_one(family):
     """The four families whose ``Model`` is ``held_share_model``'s: the
     counts are the ones each computed for itself before (tiny, a share of
     tiny, the published size), ``count_params`` is ``meta["n_params"]``,
-    and the warning for rows over the bound names ``held_rows_factor`` —
-    where nothing is left out there is nothing to count or to word."""
+    and the warning for rows over the bound names ``held_rows_factor``.
+    The counts leave the step whatever is held: whether rows are bounded
+    is the mesh's to say as well (an ``expert`` axis exchanges them inside
+    a bound), after the model is built."""
     import importlib
     from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
     module = importlib.import_module(f"deepspeed_tpu.models.{family}")
@@ -151,10 +153,6 @@ def test_a_held_share_family_counts_and_words_as_one(family):
             == module.count_params(model.config)
         assert model.meta["active_params"] == active
         assert model.flops_per_token == 6.0 * active
-        if overrides:
-            assert "held_rows_factor times" in \
-                model.meta["step_counts"][ROWS_OVER_BOUND]
-            assert model.loss_with_counts_fn is not None
-        else:
-            assert model.meta["step_counts"] == {}
-            assert model.loss_with_counts_fn is None
+        assert "held_rows_factor times" in \
+            model.meta["step_counts"][ROWS_OVER_BOUND]
+        assert model.loss_with_counts_fn is not None

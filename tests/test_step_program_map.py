@@ -86,7 +86,8 @@ def test_fixed_names():
                            "out_proj", "ssm", "scan", "q_latent",
                            "kv_latent", "rope", "scores", "ds.mtp",
                            "ds.attn_full", "ds.attn_sliding",
-                           "ds.head_gate", "ds.lead_mlp")
+                           "ds.head_gate", "ds.lead_mlp", "exchange",
+                           "exchange_send", "exchange_return")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
