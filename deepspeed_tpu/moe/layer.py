@@ -529,29 +529,31 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
     on every chip: rows of zero gate make it up where the tokens do not
     split evenly, a decode step's):
 
-    1. lays its routed rows out by the chip that holds their expert
-       (``mappings.make_exchange_plan``), gathers them into the send
-       buffer, and learns from one small all-gather how many rows every
-       chip has for every other (``make_exchange_sizes``);
+    1. lays its routed rows out **by expert**, over all ``E`` — a held plan
+       of its own rows (``make_held_group_plan``, ``dispatch_held_rows``) —
+       which is by chip too; learns from one small all-gather how many rows
+       every chip has for every expert, and from that table, by cumulative
+       sums, every chip's layout (``mappings.make_exchange_sizes``), its
+       own receive plan among them (``make_counted_group_plan``: no sort,
+       no look-up);
     2. ``exchange/exchange_send``: one all-to-all of the rows
-       (``lax.ragged_all_to_all``: the rows there are and no padding), and
-       a small one of their experts' numbers on the chip they go to.  What
-       a chip receives from all chips is one prefix of its receive buffer,
-       whose length is a stated bound: ``held_rows_factor`` times the rows
-       a chip is sent under even routing (``grouped_gemm.held_rows_bound``)
-       — on what a chip receives in all, not on what one chip sends
-       another: one sender's skew uses the room the others leave.  A row
-       that finds no room is counted (:data:`ROWS_OVER_BOUND`), never
-       silently lost;
-    3. runs **the held plan** over what it received — a row there is a
-       token of its own with one choice: ``make_held_group_plan``,
-       ``dispatch_held_rows``, the grouped kernels, and the rows summed
-       back into the order they arrived in (``collect_held_rows``:
-       ``ds_rowsum``), all over the live prefix;
-    4. ``exchange/exchange_return``: one all-to-all of the results, each
-       row to the place it came from;
+       (``lax.ragged_all_to_all``: the rows there are and no padding), one
+       slice a (chip, expert): a slice lands inside its expert's group of
+       the receiver's plan, behind those of the senders before, so what
+       arrives IS the group-padded array the kernels read.  A chip has room
+       for a stated bound of rows from all chips together:
+       ``held_rows_factor`` times what it is sent under even routing
+       (``grouped_gemm.held_rows_bound``) — not a bound on what one chip
+       sends another: one sender's skew uses the room the others leave.  A
+       row that finds no room is counted (:data:`ROWS_OVER_BOUND`), never
+       silently lost: the room goes to the senders in their order, and a
+       pair's last rows — those of its highest experts — are the ones cut;
+    3. runs the grouped kernels over what it received, and nothing else:
+       the receiving chip sorts, gathers and sums no row;
+    4. ``exchange/exchange_return``: one all-to-all of the results, slice
+       for slice, each row to the place it came from;
     5. weights each row by its gate and sums a token's rows, once, in
-       float32 (``mappings.return_rows``).
+       float32 (``combine_held_rows`` over its own plan: ``ds_rowsum``).
 
     Backward, every step is its own transpose by hand (an all-to-all's is
     the all-to-all back): two all-to-alls of rows a pass.  The expert
@@ -604,22 +606,22 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             # what one all-to-all of rows puts on a chip's links under
             # even routing: the rows for the other chips, nothing else
             "wire_bytes": (n - 1) * (R // n) * row_bytes,
-            "path": mappings.exchange_path()}})
+            "path": mappings.exchange_path(),
+            # a (chip, expert) is one slice of an all-to-all, and lands in
+            # its expert's group of the receiver's plan
+            "slices_per_pair": held, "receive_layout": "grouped"}})
     weights = {name: params[name] for name in ("w_gate", "w_in", "w_out")
                if name in params}
 
     def on_chip(xt, eids, gates, weights):
         with jax.named_scope(SCOPE_DISPATCH):
-            out = mappings.make_exchange_plan(eids.reshape(-1), held, n)
-            sizes = mappings.make_exchange_sizes(out.sizes, bound)
-            buf = mappings.send_rows(xt, out, k)            # [R, D]
+            # R rows hold every row a chip routes: nothing is over here
+            mine, _ = gg.make_held_group_plan(eids.reshape(-1), 0, E, R)
+            sizes = mappings.make_exchange_sizes(mine.counts, R, bound)
+            plan, over = gg.make_counted_group_plan(sizes.counts, bound)
+            buf = gg.dispatch_held_rows(xt, mine, k)        # [R + E bm, D]
         with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
-            rows = mappings.exchange_forth(buf, sizes, bound)
-            experts = mappings.exchange_experts(out.local_expert, sizes,
-                                                bound, held)
-        with jax.named_scope(SCOPE_DISPATCH):
-            plan, over = gg.make_held_group_plan(experts, 0, held, bound)
-            x_pad = gg.dispatch_held_rows(rows, plan, 1)    # [Mp, D]
+            x_pad = mappings.exchange_forth(buf, sizes, plan.padded_rows)
         _emit_held_plan(plan)
         _emit_exchanged(jnp.sum(sizes.send), jnp.sum(sizes.held))
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
@@ -627,12 +629,10 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             h = _glu(mm, x_pad, weights.get("w_gate"), weights["w_in"],
                      config, plan)
             y = mm(h, weights["w_out"])                     # [Mp, D]
-        with jax.named_scope(SCOPE_COMBINE):
-            back = gg.collect_held_rows(y, plan, bound, 1)
         with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_RETURN):
-            back = mappings.exchange_back(back, sizes, R)
+            back = mappings.exchange_back(y, sizes, mine.padded_rows)
         with jax.named_scope(SCOPE_COMBINE):
-            combined = mappings.return_rows(back, gates.reshape(-1), out, k)
+            combined = gg.combine_held_rows(back, gates.reshape(-1), mine, k)
         dropped = sizes.over + over
         return combined, jnp.stack([jnp.int32(R) - sizes.over, dropped])[None]
 

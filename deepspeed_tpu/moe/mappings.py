@@ -16,16 +16,17 @@ these entry points serve clients whose upstream activations arrive
 TP-sharded (Megatron sequence-parallel blocks).
 
 The second half of the file is the **expert-parallel exchange** of the
-grouped dispatch (moe/layer.py ``_exchanged_grouped_moe``): a chip's routed
-rows laid out by the chip that holds their expert
-(:func:`make_exchange_plan`), the rows into that order and back out of it
-by gathers alone, forward and backward (:func:`send_rows`,
-:func:`return_rows`), who sends whom how many rows and where they land
-(:func:`make_exchange_sizes`), and the all-to-all itself
-(:func:`exchange_forth`, :func:`exchange_back`: ``lax.ragged_all_to_all``
-— a chip puts on the wire the rows it has, and what it receives from all
-chips shares one buffer, so the bound is on a chip's rows and not on a
-pair's).
+grouped dispatch (moe/layer.py ``_exchanged_grouped_moe``): the all-to-all
+carries rows from one grouped layout to another.  A chip sends its routed
+rows from a held plan over ALL experts (``grouped_gemm``: sorted by expert,
+so by chip too) and the chip that holds an expert receives them at their
+place in that expert's group of ITS plan.  One small all-gather — every
+chip's rows for every expert — and cumulative sums tell every chip both
+layouts of every chip (:func:`make_exchange_sizes`), and the all-to-all
+itself moves one slice a (chip, expert) (:func:`exchange_forth`,
+:func:`exchange_back`: ``lax.ragged_all_to_all`` — a chip puts on the wire
+the rows it has, and what it receives from all chips shares one buffer, so
+the bound is on a chip's rows and not on a pair's).
 """
 import functools
 from typing import NamedTuple
@@ -73,135 +74,58 @@ def drop_tokens(x, dim: int = 0):
 
 
 # ------------------------------------------------- the expert exchange
-class ExchangePlan(NamedTuple):
-    """Where this chip's routed rows go: its ``R`` routed elements (flat,
-    token-major: ``f = t * top_k + choice``) in the order it sends them —
-    by the chip of the ``expert`` axis that holds their expert, a chip's in
-    routed order.  The two maps are each other's inverse, so rows move by
-    gathers in both directions."""
-    pairs: int                  # static: chips of the expert axis
-    by_chip: jnp.ndarray        # [R] place in the send order -> element
-    place: jnp.ndarray          # [R] element -> place in the send order
-    local_expert: jnp.ndarray   # [R] by place: the row's expert on the
-    #                             chip it goes to
-    sizes: jnp.ndarray          # [pairs] rows for each chip
-
-
-def make_exchange_plan(expert_ids: jnp.ndarray, experts_held: int,
-                       pairs: int) -> ExchangePlan:
-    """``expert_ids`` [R] over ALL experts (chip ``d`` holds experts ``[d *
-    experts_held, (d + 1) * experts_held)``) -> the plan.  Two stable
-    sorts (rows by chip, and that order sorted back by row) and a count: no
-    scatter."""
-    R = int(expert_ids.shape[0])
-    eids = expert_ids.astype(jnp.int32)
-    dest = eids // int(experts_held)
-    flat = jnp.arange(R, dtype=jnp.int32)
-    sizes = jnp.sum((dest[:, None] == jnp.arange(
-        int(pairs), dtype=jnp.int32)[None, :]).astype(jnp.int32), axis=0)
-    _, by_chip = lax.sort((dest, flat), num_keys=1, is_stable=True)
-    _, place = lax.sort((by_chip, flat), num_keys=1, is_stable=True)
-    local = _rows_at(eids, by_chip) % int(experts_held)
-    return ExchangePlan(int(pairs), by_chip, place, local, sizes)
-
-
-def _rows_at(x, idx):
-    return x.at[idx].get(mode="promise_in_bounds")
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _send(xt, by_chip, place, top_k):
-    return _rows_at(xt, by_chip // top_k)
-
-
-def _send_fwd(xt, by_chip, place, top_k):
-    return _send(xt, by_chip, place, top_k), place
-
-
-def _send_bwd(top_k, place, g):
-    # a token's top_k cotangent rows summed in float32, rounded once
-    rows = _rows_at(g, place).reshape(-1, top_k, *g.shape[1:])
-    return (jnp.sum(rows.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None)
-
-
-_send.defvjp(_send_fwd, _send_bwd)
-
-
-def send_rows(xt: jnp.ndarray, plan: ExchangePlan, top_k: int):
-    """Token-major ``xt`` [T, D] -> the send buffer [T * top_k, D]: place
-    ``p`` reads token ``by_chip[p] // top_k``.  Backward: a gather by
-    ``place``, summed over ``top_k``."""
-    return _send(xt, plan.by_chip, plan.place, top_k)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _return(back, gates, by_chip, place, top_k):
-    rows = _rows_at(back, place).astype(jnp.float32)
-    # a gate as the rows' dtype holds it: the product is exact in float32
-    g = gates.astype(back.dtype).astype(jnp.float32)
-    return jnp.sum((g[:, None] * rows).reshape(-1, top_k, back.shape[1]),
-                   axis=1).astype(back.dtype)
-
-
-def _return_fwd(back, gates, by_chip, place, top_k):
-    return (_return(back, gates, by_chip, place, top_k),
-            (back, gates, by_chip, place))
-
-
-def _return_bwd(top_k, res, g):
-    back, gates, by_chip, place = res
-    dback = (_rows_at(gates.astype(back.dtype), by_chip)[:, None]
-             * _rows_at(g, by_chip // top_k))
-    rows = _rows_at(back, place).reshape(-1, top_k, back.shape[1])
-    dgates = jnp.sum(rows.astype(jnp.float32)
-                     * g.astype(jnp.float32)[:, None, :], axis=-1)
-    return dback, dgates.reshape(gates.shape).astype(gates.dtype), None, None
-
-
-_return.defvjp(_return_fwd, _return_bwd)
-
-
-def return_rows(back: jnp.ndarray, gates: jnp.ndarray, plan: ExchangePlan,
-                top_k: int):
-    """The buffer that came back [T * top_k, D] (place for place what
-    :func:`send_rows` sent, through the experts; exact zeros where a row
-    found no room at its chip) and flat ``gates`` [T * top_k] -> [T, D]: a
-    token's ``top_k`` rows, each weighted by its gate, in ONE float32 sum
-    rounded once.  Backward: ``dback[p] = gates[by_chip[p]] *
-    dout[by_chip[p] // top_k]`` and ``dgates[f] = back[place[f]] . dout[f
-    // top_k]``."""
-    return _return(back, gates, plan.by_chip, plan.place, top_k)
-
-
 class ExchangeSizes(NamedTuple):
-    """Who sends whom how many rows, and where they lie, the same numbers
-    on every chip but ``me``'s own row and column of them.  Chip ``j``'s
-    rows for chip ``d`` start at ``send_at[d]`` of ``j``'s send buffer and
-    land at ``land_at[d]`` of ``d``'s receive buffer: behind those of the
-    chips before ``j``, so what a chip receives is one prefix of its
-    buffer.  A receive buffer holds ``bound`` rows; what would pass it is
-    cut from the end of a pair's rows and counted (``over``)."""
-    send_at: jnp.ndarray        # [pairs] in my send buffer, by chip
-    send: jnp.ndarray           # [pairs] rows I send each chip (kept)
-    land_at: jnp.ndarray        # [pairs] in chip d's receive buffer
-    held_at: jnp.ndarray        # [pairs] in my receive buffer, by sender
-    held: jnp.ndarray           # [pairs] rows each chip sends me (kept)
-    home_at: jnp.ndarray        # [pairs] in sender j's send buffer
+    """Who sends whom how many rows of which expert, and where they lie:
+    the same numbers on every chip but ``me``'s own row and column of them.
+    A slice is the rows one chip has for one expert; the arrays are
+    ``[pairs * experts_held]``, a pair's slices side by side in expert
+    order.  Sender ``j``'s slice for expert ``e`` of chip ``d`` leaves
+    ``j``'s layout where that expert's group begins (``send_at``) and lands
+    in ``d``'s layout inside the group of ``e``, behind the rows of the
+    senders before ``j`` (``land_at``).  A chip has room for ``bound`` rows
+    from all chips together, a sender's after those of the senders before
+    it; what would pass it is cut from the end of a pair's rows — its
+    highest experts' — and counted (``over``)."""
+    send_at: jnp.ndarray        # in my layout, by (chip, its expert)
+    send: jnp.ndarray           # rows I send (kept)
+    land_at: jnp.ndarray        # in chip d's layout
+    held_at: jnp.ndarray        # in my layout, by (sender, my expert)
+    held: jnp.ndarray           # rows sender j sends me (kept)
+    home_at: jnp.ndarray        # in sender j's layout
+    counts: jnp.ndarray         # [experts_held] rows my experts receive
     over: jnp.ndarray           # [] my rows that found no room
 
 
-def make_exchange_sizes(sizes: jnp.ndarray, bound: int) -> ExchangeSizes:
-    """``sizes`` [pairs]: the rows this chip has for each chip.  One small
-    all-gather (the table of every pair's count) and arithmetic."""
-    table = lax.all_gather(sizes, EXPERT_AXIS)          # [from, to]
+def make_exchange_sizes(counts: jnp.ndarray, routed: int,
+                        bound: int) -> ExchangeSizes:
+    """``counts`` [E]: the rows this chip has for each of ALL experts, laid
+    out as a held plan of ``routed`` rows over them
+    (``grouped_gemm.make_held_group_plan``); ``bound``: the rows of a
+    chip's receive plan over the experts it holds.  One small all-gather
+    (the table of every chip's count for every expert) and cumulative
+    sums: every chip's send layout and every chip's receive layout follow
+    from the table (``grouped_gemm.held_group_starts``)."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import held_group_starts
+    table = lax.all_gather(counts.astype(jnp.int32), EXPERT_AXIS)
+    n = table.shape[0]
     me = lax.axis_index(EXPERT_AXIS)
-    starts = jnp.cumsum(table, axis=1) - table          # in the sender's
-    lands = jnp.cumsum(table, axis=0) - table           # in the receiver's
-    kept = jnp.clip(jnp.int32(bound) - lands, 0, table)
-    return ExchangeSizes(starts[me], kept[me], lands[me], lands[:, me],
-                         kept[:, me], starts[:, me],
-                         jnp.sum(table[me] - kept[me]).astype(jnp.int32))
+    rows = table.reshape(n, n, -1)                      # [from, to, expert]
+    pair = jnp.sum(rows, axis=-1)
+    # a receiver's room goes to the senders in their order, a pair's to its
+    # experts in theirs
+    room = jnp.clip(jnp.int32(bound) - (jnp.cumsum(pair, axis=0) - pair), 0,
+                    pair)
+    kept = jnp.clip(room[:, :, None] - (jnp.cumsum(rows, axis=-1) - rows), 0,
+                    rows)
+    received = jnp.sum(kept, axis=0)                    # [to, expert]
+    lands = held_group_starts(received, bound)[0][None] \
+        + jnp.cumsum(kept, axis=0) - kept
+    starts = held_group_starts(table, routed)[0].reshape(rows.shape)
+    flat = lambda a: a.reshape(-1)                      # noqa: E731
+    return ExchangeSizes(flat(starts[me]), flat(kept[me]), flat(lands[me]),
+                         flat(lands[:, me]), flat(kept[:, me]),
+                         flat(starts[:, me]), received[me],
+                         jnp.sum(rows[me] - kept[me]).astype(jnp.int32))
 
 
 #: the two ways an exchange's rows travel, as ``tracing.exchange_calls``
@@ -219,76 +143,78 @@ def exchange_path() -> str:
     return RAGGED_ALL_TO_ALL if _on_tpu() else ALL_TO_ALL
 
 
-def _ragged(rows, out_rows, fill, send_at, send, land_at, held):
+def _rows_at(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+def _ragged(rows, out_rows, send_at, send, land_at, held):
     """``lax.ragged_all_to_all`` over the ``expert`` axis into a buffer of
-    ``out_rows`` rows of ``fill``: ``send[d]`` rows from ``send_at[d]`` on
-    go to chip ``d`` and land from ``land_at[d]`` on; ``held[j]`` rows
-    arrive from chip ``j``.  Where the backend has no such collective
+    ``out_rows`` rows of zeros, any whole number of slices a chip (a chip's
+    side by side): slice ``i`` is ``send[i]`` rows from ``send_at[i]`` on,
+    lands from ``land_at[i]`` on at its chip, and ``held[i]`` rows arrive
+    for it.  Where the backend has no such collective
     (:func:`exchange_path`: the CPU's test mesh) the same movement by
-    ``lax.all_to_all`` of segments as long as the whole send buffer, each
-    row then put where the ragged one would have put it."""
-    out = jnp.full((out_rows,) + rows.shape[1:], fill, rows.dtype)
+    ``lax.all_to_all``: every chip's whole buffer and where its slices lie,
+    each row of the result then looked up in the slice that covers it."""
+    out = jnp.zeros((out_rows,) + rows.shape[1:], rows.dtype)
     if exchange_path() == RAGGED_ALL_TO_ALL:
         return lax.ragged_all_to_all(rows, out, send_at, send, land_at,
                                      held, axis_name=EXPERT_AXIS)
-    length = rows.shape[0]
-    at = jnp.arange(length, dtype=jnp.int32)
-    segments = jnp.stack([
-        _rows_at(rows, jnp.minimum(send_at[d] + at, length - 1))
-        for d in range(send.shape[0])])
-    got = lax.all_to_all(segments, EXPERT_AXIS, 0, 0)   # [from, length, ..]
-    lands = lax.all_to_all(land_at, EXPERT_AXIS, 0, 0)  # in my buffer
-    at = jnp.arange(out_rows, dtype=jnp.int32)
-    for j in range(got.shape[0]):
-        i = at - lands[j]
-        taken = (i >= 0) & (i < held[j])
-        row = _rows_at(got[j], jnp.clip(i, 0, length - 1))
-        out = jnp.where(taken.reshape((-1,) + (1,) * (rows.ndim - 1)), row,
-                        out)
-    return out
+    n, length = lax.axis_size(EXPERT_AXIS), rows.shape[0]
+    to_me = lambda a: lax.all_to_all(                   # noqa: E731
+        a.reshape(n, -1), EXPERT_AXIS, 0, 0).reshape(-1)
+    got = lax.all_to_all(jnp.broadcast_to(rows, (n,) + rows.shape),
+                         EXPERT_AXIS, 0, 0)             # [from, length, ..]
+    lands, froms = to_me(land_at), to_me(send_at)
+    within = jnp.arange(out_rows, dtype=jnp.int32)[:, None] - lands[None, :]
+    covers = (within >= 0) & (within < held[None, :])   # [out_rows, slices]
+    which = jnp.argmax(covers, axis=1)
+    sender = which // (held.shape[0] // n)
+    source = sender * length + _rows_at(froms, which) \
+        + jnp.take_along_axis(within, which[:, None], axis=1)[:, 0]
+    row = _rows_at(got.reshape((n * length,) + rows.shape[1:]),
+                   jnp.clip(source, 0, n * length - 1))
+    return jnp.where(jnp.any(covers, axis=1).reshape(
+        (-1,) + (1,) * (rows.ndim - 1)), row, out)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _forth(rows, sizes: ExchangeSizes, bound):
-    return _ragged(rows, bound, 0, sizes.send_at, sizes.send, sizes.land_at,
+def _forth(rows, sizes: ExchangeSizes, out_rows):
+    return _ragged(rows, out_rows, sizes.send_at, sizes.send, sizes.land_at,
                    sizes.held)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _back(rows, sizes: ExchangeSizes, routed):
-    return _ragged(rows, routed, 0, sizes.held_at, sizes.held,
-                   sizes.home_at, sizes.send)
+def _back(rows, sizes: ExchangeSizes, out_rows):
+    return _ragged(rows, out_rows, sizes.held_at, sizes.held, sizes.home_at,
+                   sizes.send)
 
 
 # each is the other's transpose: a row's cotangent travels the way back
 _forth.defvjp(
-    lambda rows, sizes, bound: (_forth(rows, sizes, bound),
-                                (sizes, rows.shape[0])),
-    lambda bound, res, g: (_back(g, res[0], res[1]), None))
+    lambda rows, sizes, out_rows: (_forth(rows, sizes, out_rows),
+                                   (sizes, rows.shape[0])),
+    lambda out_rows, res, g: (_back(g, res[0], res[1]), None))
 _back.defvjp(
-    lambda rows, sizes, routed: (_back(rows, sizes, routed),
-                                 (sizes, rows.shape[0])),
-    lambda routed, res, g: (_forth(g, res[0], res[1]), None))
+    lambda rows, sizes, out_rows: (_back(rows, sizes, out_rows),
+                                   (sizes, rows.shape[0])),
+    lambda out_rows, res, g: (_forth(g, res[0], res[1]), None))
 
 
-def exchange_forth(rows: jnp.ndarray, sizes: ExchangeSizes, bound: int):
+def exchange_forth(rows: jnp.ndarray, sizes: ExchangeSizes, plan_rows: int):
     """One all-to-all of rows over the ``expert`` axis, inside a
-    ``shard_map`` that maps it: the send buffer ``rows`` [R, D] (by chip)
-    -> the receive buffer [bound, D], whose first ``sum(sizes.held)`` rows
-    are what arrived, by sender; the rest exact zeros."""
-    return _forth(rows, sizes, int(bound))
+    ``shard_map`` that maps it: this chip's rows ``[., D]`` in its send
+    layout (a held plan over all experts) -> ``[plan_rows, D]`` in its
+    receive layout, the group-padded array the grouped kernels read: within
+    an expert's group the rows by sender, a sender's in its routed order;
+    every other row — the groups' padding, and all behind the last group —
+    exact zeros."""
+    return _forth(rows, sizes, int(plan_rows))
 
 
-def exchange_back(rows: jnp.ndarray, sizes: ExchangeSizes, routed: int):
-    """The way back: the receive buffer's rows [bound, D], each to the
-    place of the send buffer it came from -> [routed, D]; a place whose
-    row found no room at its chip reads exact zeros."""
-    return _back(rows, sizes, int(routed))
-
-
-def exchange_experts(local_expert: jnp.ndarray, sizes: ExchangeSizes,
-                     bound: int, nobody: int):
-    """The rows' experts' numbers the way the rows go (no gradient):
-    ``nobody`` where the receive buffer holds no row."""
-    return _ragged(local_expert, int(bound), nobody, sizes.send_at,
-                   sizes.send, sizes.land_at, sizes.held)
+def exchange_back(rows: jnp.ndarray, sizes: ExchangeSizes, plan_rows: int):
+    """The way back, slice for slice: the rows ``[., D]`` of the receive
+    layout, each to the place of its sender's layout it came from ->
+    ``[plan_rows, D]``; a place whose row found no room at its chip, and a
+    padding row, reads exact zeros."""
+    return _back(rows, sizes, int(plan_rows))

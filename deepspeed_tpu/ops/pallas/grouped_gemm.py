@@ -440,6 +440,53 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
                          group_of_element=key), over
 
 
+def held_group_starts(counts: jnp.ndarray, bound_rows: int,
+                      block_m: Optional[int] = None):
+    """``counts`` [..., E]: rows for each of the ``E`` experts of a held
+    plan of ``bound_rows`` (one plan along the last axis) -> (the row at
+    which each expert's group begins, its padded rows), both [..., E], by
+    :func:`make_held_group_plan`'s rule and by cumulative sums alone: a
+    group is its rows rounded up to M-tiles, a tile at least, cut in expert
+    order where the plan's ``bound_rows + E * bm`` rows would be passed.
+    Whoever knows the counts knows the layout — a chip of an exchange, of
+    every other chip's (moe/mappings.py ``make_exchange_sizes``)."""
+    E = int(counts.shape[-1])
+    bm = int(block_m or default_block_m())
+    num_blocks = _round_up(int(bound_rows), bm) // bm + E
+    cum_blocks = jnp.minimum(
+        jnp.cumsum(jnp.maximum(-(-counts // bm), 1), axis=-1),
+        num_blocks - (E - 1 - jnp.arange(E, dtype=jnp.int32)))
+    sizes = jnp.diff(cum_blocks, prepend=0, axis=-1) * bm
+    return (cum_blocks * bm - sizes).astype(jnp.int32), sizes.astype(
+        jnp.int32)
+
+
+def make_counted_group_plan(counts: jnp.ndarray, bound_rows: int,
+                            block_m: Optional[int] = None):
+    """The held plan of rows that are in its layout already (an exchange's
+    receive buffer, every row put at its place in its expert's group by the
+    chip that sent it: moe/layer.py ``_exchanged_grouped_moe``): ``counts``
+    [E], the rows each held expert has -> (GroupPlan, rows over the bound)
+    as :func:`make_held_group_plan` gives them for the same counts, by
+    arithmetic alone (:func:`held_group_starts`) — no sort and no look-up.
+    It has neither ``padded_to_row`` nor ``group_of_element``: nothing is
+    gathered into it or summed out of it here.  It is ``live_only``."""
+    E = int(counts.shape[0])
+    bm = int(block_m or default_block_m())
+    padded_rows = _round_up(int(bound_rows), bm) + E * bm
+    num_blocks = padded_rows // bm
+    counts = counts.astype(jnp.int32)
+    starts, group_sizes = held_group_starts(counts, bound_rows, bm)
+    over = jnp.sum(counts - jnp.minimum(counts, group_sizes)).astype(
+        jnp.int32)
+    cum_blocks = (starts + group_sizes) // bm
+    gids = _tile_group_ids(jnp.arange(num_blocks, dtype=jnp.int32),
+                           cum_blocks)
+    return GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
+                     cum_blocks[-1:].astype(jnp.int32), None, None, counts,
+                     live_only=True), over
+
+
 # ---- the live prefix.  A held plan's length is its bound; its rows are a
 # prefix of ``used_blocks`` tiles, a number the step computes from the
 # routing, and everything that reads or writes a ``[Mp, ·]`` array of such
@@ -789,35 +836,6 @@ def dispatch_held_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
     (a token has 0 to ``top_k`` rows here)."""
     chunk = _live_chunk_rows(plan, xt.shape[1] * xt.dtype.itemsize)
     return _dispatch_held(xt, _way_back(plan), live_rows(plan), top_k, chunk)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _collect_held(y, back, live, tokens, top_k, chunk):
-    return _sum_live_into_tokens(y, None, back, tokens, top_k, live, chunk)
-
-
-def _collect_held_fwd(y, back, live, tokens, top_k, chunk):
-    return _collect_held(y, back, live, tokens, top_k, chunk), (back, live)
-
-
-def _collect_held_bwd(tokens, top_k, chunk, res, g):
-    back, live = res
-    return (_token_rows_live(g, back.padded_to_row // top_k, live, chunk),
-            None, None)
-
-
-_collect_held.defvjp(_collect_held_fwd, _collect_held_bwd)
-
-
-def collect_held_rows(y: jnp.ndarray, plan: GroupPlan, tokens: int,
-                      top_k: int):
-    """:func:`dispatch_held_rows` transposed: the live prefix of ``y``
-    [Mp, D] summed into ``[tokens, D]`` with no gate (the rows an exchange
-    received, each its own token, go back to where they arrived:
-    moe/layer.py).  Backward: the gather :func:`dispatch_held_rows` is."""
-    chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
-    return _collect_held(y, _way_back(plan), live_rows(plan), int(tokens),
-                         top_k, chunk)
 
 
 def _gate_and_token(gates, padded_to_row, top_k, start, chunk):

@@ -756,10 +756,14 @@ def exchange_calls(name: str = TRAIN_STEP_PROGRAM):
     counted in ``moe/rows_over_bound``), ``width``, ``even_rows_per_pair``
     (what even routing sends from one chip to another), ``wire_bytes``
     (what one all-to-all of rows puts on a chip's links under even
-    routing) and ``path``, the collective the rows were traced to travel by
+    routing), ``path``, the collective the rows were traced to travel by
     (``moe/mappings.py exchange_path``: ``"ragged_all_to_all"`` on a TPU —
-    the rows there are, no padding — and ``"all_to_all"`` of whole segments
-    where the backend has no ragged one).  None where the step has no
+    the rows there are, no padding — and ``"all_to_all"`` of whole buffers
+    where the backend has no ragged one), ``slices_per_pair`` (the slices
+    of one all-to-all that go from one chip to another: one an expert the
+    receiver holds) and ``receive_layout`` (``"grouped"``: a slice lands
+    inside its expert's group of the receiver's plan, so what arrives is
+    the array the grouped kernels read).  None where the step has no
     exchange."""
     return _account_rows(name, "exchange_calls")
 

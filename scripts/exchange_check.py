@@ -13,12 +13,16 @@ the wrong place, or an expert's gradient summed over chips, reads near 1.
     chiprun --chips 4 -- python scripts/exchange_check.py --seed <n>
 
 One JSON line per seed; exit 1 where a leaf reads more than ``--limit``
-times its control (and more than 2%).
+times its control (and more than 2%).  ``layer_ms`` is the host clock's
+median over ``--timed`` calls of the compiled layer, forward and backward
+(a layer alone, its input on the chips: no step's number).
 """
 import argparse
 import json
 import os
+import statistics
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +84,7 @@ def main():
                         metavar=("D", "F", "E", "K"))
     parser.add_argument("--factor", type=int, default=4)
     parser.add_argument("--limit", type=float, default=3.0)
+    parser.add_argument("--timed", type=int, default=5)
     args = parser.parse_args()
     D, F, E, K = args.sizes
     devices = jax.devices()[:4]
@@ -117,12 +122,18 @@ def main():
         compiled = grad(exchanged).lower(params, x).compile()
         (_, got), (dw_got, dx_got) = compiled(params, x)
         text = compiled.as_text()
+        took = []
+        for _ in range(args.timed):
+            start = time.perf_counter()
+            jax.block_until_ready(compiled(params, x))
+            took.append(1e3 * (time.perf_counter() - start))
         line = {"seed": seed, "device": devices[0].device_kind,
                 "chips": len(devices), "tokens_per_chip": args.tokens,
                 "sizes": [D, F, E, K],
                 "ragged_all_to_all": text.count(" ragged-all-to-all("),
                 "all_to_all": text.count(" all-to-all("),
                 "mosaic_calls": text.count("tpu_custom_call"),
+                "layer_ms": statistics.median(took) if took else None,
                 "leaves": {}}
         pairs = {"out": (got, low, want), "dx": (dx_got, dx_low, dx_want),
                  **{name: (dw_got[name], dw_low[name], dw_want[name])

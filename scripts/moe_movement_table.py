@@ -1,11 +1,17 @@
 """What the routed rows' movement costs on the chip, instruction by
 instruction: one traced run of a benchmark cell (``benchmarks/run.py
 --trace 1``, unchanged), then the self time of every instruction of the
-compiled step whose scope holds ``/mlp/dispatch/`` or ``/mlp/combine/``,
-joined through ``get_program_map("train/step")`` and split by phase.
+compiled step whose scope holds ``/mlp/dispatch/`` or ``/mlp/combine/``
+(inside the expert-parallel exchange's manual region:
+``/mlp/shard_map/dispatch/``, as the cells' own metrics read it), joined
+through ``get_program_map("train/step")`` and split by phase.
 
     chiprun --chips 1 -- python scripts/moe_movement_table.py --seed <n> \
         [--root <checkout>] [--out chiprun_out/<file>.json]
+    chiprun --chips 4 -- python scripts/moe_movement_table.py --seed <n> \
+        --workload mellum2-12b-a2.5b-ep4.packed-s8192-gas4-ep
+
+(a cell of four chips: the table is device 0's).
 
 ``--root`` is the checkout whose benchmark and program run (default: this
 one), so that a parent commit unpacked under ``.chip_checkout/`` is read
@@ -23,16 +29,17 @@ import runpy
 import sys
 from collections import defaultdict
 
-SCOPE = re.compile(r"/mlp/(dispatch|combine)/")
+SCOPE = re.compile(r"/mlp/(?:shard_map/)?(dispatch|combine)/")
 STEP = {"program": "train/step", "module": r"^jit_train_step\("}
 _SCATTER = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) scatter\(.*"
                       r'op_name="([^"]*)"')
 
 
-def scope_rows(dev, table, tr, step_phase, scope=SCOPE, below="/mlp/"):
+def scope_rows(dev, table, tr, step_phase, scope=SCOPE,
+               below=r"/mlp/(?:shard_map/)?"):
     """(steps traced, [row]) of one device: self time, executions, phase,
-    op_name below ``below`` and result shape of each instruction whose
-    scope path matches ``scope``."""
+    op_name below ``below`` (a pattern) and result shape of each
+    instruction whose scope path matches ``scope``."""
     steps = sum(1 for _, _, text in dev.events(tr.MODULES)
                 if re.search(STEP["module"], text))
     ns, calls, shape = defaultdict(int), defaultdict(int), {}
@@ -54,7 +61,7 @@ def scope_rows(dev, table, tr, step_phase, scope=SCOPE, below="/mlp/"):
     rows = [{"instruction": name, "phase": table[name]["phase"],
              "ms_per_step": ns[name] * 1e-6 / steps,
              "calls_per_step": calls[name] / steps,
-             "op": table[name]["scope"].split(below, 1)[1],
+             "op": re.split(below, table[name]["scope"], maxsplit=1)[1],
              "kernel": table[name].get("kernel"),
              "shape": shape[name]} for name in ns]
     rows.sort(key=lambda r: (r["phase"], -r["ms_per_step"]))

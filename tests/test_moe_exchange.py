@@ -8,7 +8,9 @@ alone with no exchange, add up to the uncut layer written plainly, and so
 does the exchanged layer); a planted skew that passes a bound is counted,
 not dropped silently; two all-to-alls of rows a pass and no capacity
 einsum in the compiled text; a one-wide ``expert`` axis never reaches the
-exchange; the plan's maps; the device gate.
+exchange; the table every chip derives its slices from, against the same
+written as loops; the receive buffer against the parent's (rows by sender,
+then sorted and gathered into the held plan); the device gate.
 
 With ``real_kernels`` the grouped kernels and ``ds_rowsum`` run in Pallas'
 interpreter inside the exchange's manual region; elsewhere their jnp forms
@@ -234,10 +236,11 @@ def test_one_senders_skew_uses_the_room_the_others_leave(monkeypatch):
 
 def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     """On a TPU the exchange is ``lax.ragged_all_to_all``; the CPU has no
-    such collective and moves the same rows by all-gathers
+    such collective and moves the same rows by ``lax.all_to_all``
     (``mappings._ragged``).  Counted here at the call: forward two of rows
-    (out and back) and one of the experts' numbers, backward the two
-    cotangents' — and nothing of the capacity formulation in the text."""
+    (out of the sender's layout, back out of the receiver's), backward the
+    two cotangents' — and nothing of the capacity formulation in the
+    text."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     calls = []
     ragged = mappings._ragged
@@ -255,8 +258,10 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     routed = tokens // 4 * K
     bound = gg.held_rows_bound(4 * routed, 2, E,
                                factor=CONFIG.held_rows_factor)
-    assert sorted(calls) == sorted([(routed, D), (routed,), (bound, D),
-                                    (bound, D), (routed, D)]), calls
+    tile = gg.default_block_m()
+    sent = -(-routed // tile) * tile + E * tile     # a plan of its own rows
+    received = bound + E // 4 * tile                # and of those it holds
+    assert sorted(calls) == sorted(2 * [(sent, D), (received, D)]), calls
     assert not re.search(rf"\[(?:{tokens}|{tokens // 4}),{E},\d+\]", text)
     (call,) = tracing.exchange_calls("toy")
     assert call["pairs"] == 4 and call["experts_held"] == 2
@@ -276,6 +281,12 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
             and row["collective"]} == {"all-to-all"}
 
 
+def _sorts(text):
+    """The operand types of every ``stablehlo.sort`` of a lowered text."""
+    return re.findall(r'"stablehlo\.sort".*?\}\) : \(([^)]*)\)', text,
+                      flags=re.S)
+
+
 def test_on_a_tpu_the_ragged_collective_is_traced_and_named(monkeypatch):
     """The same layer traced as a TPU traces it (jax 0.9.0's CPU backend
     lowers ``lax.ragged_all_to_all`` and cannot compile it: XLA:CPU's
@@ -289,10 +300,52 @@ def test_on_a_tpu_the_ragged_collective_is_traced_and_named(monkeypatch):
         text = fn.lower(*args).as_text()
     (call,) = tracing.exchange_calls("toy")
     assert call["path"] == "ragged_all_to_all"
-    # forward: rows out, their experts' numbers, rows back; backward: the
-    # two cotangents'
-    assert text.count("ragged_all_to_all") == 5
+    assert call["slices_per_pair"] == 2 == call["experts_held"]
+    assert call["receive_layout"] == "grouped"
+    # forward: rows out, rows back; backward: the two cotangents' — and
+    # nothing carries the experts' numbers: the table says where rows land
+    assert text.count("ragged_all_to_all") == 4
     assert "stablehlo.all_to_all" not in text
+
+
+def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
+    """mellum2-12b-a2.5b-ep4's expert layer as a TPU traces it (8,192
+    tokens a chip, 64 experts over four chips, top 8, a bound of three
+    times the even share: 196,608 rows): four ``ragged_all_to_all`` a layer
+    (the parent's five: one carried the experts' numbers), 16 slices a
+    pair, and the one sort left is the sender's, of its own 65,536 routed
+    elements — the parent sorted the bound, twice."""
+    monkeypatch.setattr(mappings, "exchange_path",
+                        lambda: mappings.RAGGED_ALL_TO_ALL)
+    config = MoEConfig(d_model=2304, d_ff=896, num_experts=64, top_k=8,
+                       dispatch_mode="grouped", held_rows_factor=3)
+    topo = MeshTopology(devices=jax.devices()[:4], expert_parallel_size=4)
+    set_topology(topo)
+    shapes = jax.eval_shape(lambda: init_moe_params(
+        config, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16, sharding=NamedSharding(topo.mesh, spec)),
+        shapes, moe_logical_specs(config))
+    x = jax.ShapeDtypeStruct((4, 8192, 2304), jnp.bfloat16,
+                             sharding=NamedSharding(topo.mesh, P("expert")))
+    with tracing.step_account("cell"):
+        text = jax.jit(jax.grad(lambda p, x: jnp.sum(moe_layer(
+            p, x, config)[0].astype(jnp.float32)), argnums=(0, 1))).lower(
+                params, x).as_text()
+    (call,) = tracing.exchange_calls("cell")
+    assert call["receive_rows"] == 196608 and call["routed_rows"] == 65536
+    assert call["slices_per_pair"] == 16
+    assert text.count("ragged_all_to_all") == 4
+    # 64 slices a call: 16 for each of the four chips
+    assert len(re.findall(r"ragged_all_to_all.*tensor<64xi32>, "
+                          r"tensor<64xi32>, tensor<64xi32>, tensor<64xi32>",
+                          text)) == 4
+    sorts = _sorts(text)
+    assert sorts and all(
+        re.fullmatch(r"tensor<65536xi32>(, tensor<65536xi32>)*", operands)
+        for operands in sorts), sorts
+    assert "196608xi32" not in "".join(sorts)
 
 
 def test_the_program_map_reads_a_ragged_all_to_all():
@@ -379,51 +432,173 @@ def test_what_the_exchange_cannot_split_is_refused_by_name():
                    for k, w in params.items()}, x, held)
 
 
-def test_the_plans_maps_are_each_others_inverse():
-    rng = np.random.default_rng(0)
-    eids = jnp.asarray(rng.integers(0, 8, size=40), jnp.int32)
-    plan = mappings.make_exchange_plan(eids, experts_held=2, pairs=4)
-    by_chip, place = map(np.asarray, (plan.by_chip, plan.place))
-    dest = np.asarray(eids) // 2
-    np.testing.assert_array_equal(plan.sizes, np.bincount(dest, minlength=4))
-    np.testing.assert_array_equal(place[by_chip], np.arange(40))
-    np.testing.assert_array_equal(by_chip[place], np.arange(40))
-    # by chip, a chip's rows in routed order
-    np.testing.assert_array_equal(by_chip, np.argsort(dest, kind="stable"))
-    np.testing.assert_array_equal(plan.local_expert,
-                                  np.asarray(eids)[by_chip] % 2)
-
-
-def test_who_sends_whom_and_where_it_lands():
-    """The table every chip derives from one all-gather: a sender's rows
-    for a chip lie behind its rows for the chips before; at the receiver
-    behind those of the senders before; what would pass the bound is cut
-    from the end and counted by its sender."""
+def on_four(body, *per_chip):
+    """``body`` on each of four chips of an ``expert`` axis, each argument
+    and result one row a chip."""
     from deepspeed_tpu.utils.jax_compat import shard_map
-    table = np.array([[2, 2, 2, 2], [8, 0, 0, 0], [1, 3, 0, 4],
-                      [0, 0, 4, 4]], np.int32)
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("expert",))
 
-    def body(sizes):
-        got = mappings.make_exchange_sizes(sizes.reshape(-1), bound=9)
-        return jax.tree.map(lambda a: a.reshape(1, -1), got)
+    def one(*rows):
+        out = body(*(r[0] for r in rows))
+        return jax.tree.map(lambda a: a[None], out)
 
-    got = host(shard_map(body, mesh=mesh, in_specs=P("expert"),
-                         out_specs=P("expert"), check_vma=False)(
-                             jnp.asarray(table)))
-    starts = np.cumsum(table, 1) - table
-    lands = np.cumsum(table, 0) - table
-    kept = np.clip(9 - lands, 0, table)
-    np.testing.assert_array_equal(got.send_at, starts)
-    np.testing.assert_array_equal(got.send, kept)
-    np.testing.assert_array_equal(got.land_at, lands)
-    np.testing.assert_array_equal(got.held, kept.T)
-    np.testing.assert_array_equal(got.held_at, lands.T)
-    np.testing.assert_array_equal(got.home_at, starts.T)
-    # chip 0 is sent 11 rows and has room for 9: chip 2's one row and one
-    # of chip 1's eight are cut; chip 3 is sent 10: chip 3's last is cut
-    np.testing.assert_array_equal(got.over.reshape(-1), [0, 1, 1, 1])
-    assert kept.sum(0).max() <= 9
+    return host(jax.jit(shard_map(
+        one, mesh=mesh, in_specs=P("expert"), out_specs=P("expert"),
+        check_vma=False))(*map(jnp.asarray, per_chip)))
+
+
+def layout(counts, bound_rows, bm):
+    """A held plan's groups written plainly: (start, padded size) of each
+    expert — its rows rounded up to tiles, a tile at least, cut in expert
+    order so that each later expert keeps one."""
+    blocks_left = -(-bound_rows // bm) + len(counts)
+    starts, sizes, at = [], [], 0
+    for e, c in enumerate(counts):
+        blocks = min(max(-(-c // bm), 1), blocks_left - (len(counts) - 1 - e))
+        starts.append(at)
+        sizes.append(blocks * bm)
+        at += blocks * bm
+        blocks_left -= blocks
+    return starts, sizes
+
+
+#: rows chip j has for each of 8 experts (two a chip), a bound of rows a
+#: chip receives, the M-tile
+TABLES = {
+    "skewed": (np.array([[5, 1, 0, 2, 3, 3, 1, 1], [9, 4, 1, 0, 0, 0, 1, 1],
+                         [1, 1, 6, 2, 2, 2, 1, 1], [0, 3, 3, 3, 3, 0, 2, 2]],
+                        np.int32), 32, 4),
+    # nobody routes to experts 3 and 4: a tile each all the same
+    "an_empty_expert": (np.array(
+        [[4, 4, 4, 0, 0, 2, 1, 1], [2, 6, 1, 0, 0, 5, 1, 1],
+         [8, 0, 0, 0, 0, 0, 4, 4], [3, 3, 3, 0, 0, 3, 2, 2]],
+        np.int32), 32, 4),
+    # chip 0 is sent 29 rows and has room for 12: sender 0's nine and
+    # three of sender 1's expert-0 rows; chips 2 and 3 are sent 15 and 13
+    "a_cut_at_the_bound": (np.array(
+        [[5, 4, 0, 1, 2, 1, 2, 1], [6, 4, 1, 1, 0, 0, 2, 2],
+         [3, 3, 0, 0, 4, 4, 1, 1], [2, 2, 2, 2, 2, 2, 2, 2]],
+        np.int32), 12, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TABLES))
+def test_every_slice_leaves_and_lands_where_the_table_says(case, monkeypatch):
+    """The table every chip derives from one all-gather, against the same
+    written as loops: sender ``j``'s slice for expert ``e`` leaves ``j``'s
+    layout (a held plan of its own rows over all experts) where that
+    expert's group begins, and lands in the group of ``e`` in its chip's
+    layout behind the rows of the senders before ``j``; a chip's room goes
+    to the senders in their order and a pair's to its experts in theirs,
+    what passes it is cut and counted by its sender — the parent's count
+    (a pair's rows past ``bound`` less the rows of the senders before)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    table, bound, bm = TABLES[case]
+    monkeypatch.setattr(gg, "default_block_m", lambda: bm)
+    n, E = table.shape
+    held, routed = E // n, int(table[0].sum())
+    assert (table.sum(1) == routed).all()
+    got = on_four(lambda counts: mappings.make_exchange_sizes(
+        counts, routed, bound), table)
+    kept = np.zeros((n, n, held), np.int32)
+    lands = np.zeros((n, n, held), np.int32)
+    for d in range(n):
+        room = bound
+        for j in range(n):
+            for e in range(held):
+                kept[j, d, e] = min(room, table[j, held * d + e])
+                room -= kept[j, d, e]
+        starts, sizes = layout(kept[:, d].sum(0), bound, bm)
+        for e in range(held):
+            assert kept[:, d, e].sum() <= sizes[e]
+            lands[:, d, e] = starts[e] + np.cumsum(kept[:, d, e]) \
+                - kept[:, d, e]
+    leaves = np.array([layout(table[j], routed, bm)[0]
+                       for j in range(n)]).reshape(n, n, held)
+    np.testing.assert_array_equal(got.send_at, leaves.reshape(n, -1))
+    np.testing.assert_array_equal(got.send, kept.reshape(n, -1))
+    np.testing.assert_array_equal(got.land_at, lands.reshape(n, -1))
+    by_receiver = lambda a: a.transpose(1, 0, 2).reshape(n, -1)  # noqa: E731
+    np.testing.assert_array_equal(got.held_at, by_receiver(lands))
+    np.testing.assert_array_equal(got.held, by_receiver(kept))
+    np.testing.assert_array_equal(got.home_at, by_receiver(leaves))
+    np.testing.assert_array_equal(got.counts, kept.sum(0))
+    pair = table.reshape(n, n, held).sum(-1)
+    before = np.cumsum(pair, 0) - pair
+    parents = (pair - np.clip(bound - before, 0, pair)).sum(1)
+    np.testing.assert_array_equal(got.over.reshape(-1), parents)
+    assert (parents.sum() > 0) == (case == "a_cut_at_the_bound")
+    # the receive plan from the counts is the held plan of those rows
+    for d in range(n):
+        experts = np.repeat(np.arange(held), kept[:, d].sum(0))
+        want, over = jax.jit(lambda e: gg.make_held_group_plan(
+            e, 0, held, bound))(jnp.asarray(experts, jnp.int32))
+        plan, cut = jax.jit(lambda c: gg.make_counted_group_plan(
+            c, bound))(jnp.asarray(got.counts[d]))
+        assert int(over) == int(cut) == 0
+        assert (plan.padded_rows, plan.num_blocks, plan.live_only) \
+            == (want.padded_rows, want.num_blocks, True)
+        for name in ("group_sizes", "block_group_ids", "used_blocks",
+                     "counts"):
+            np.testing.assert_array_equal(getattr(plan, name),
+                                          getattr(want, name), err_msg=name)
+
+
+@pytest.mark.parametrize("case", ["skewed", "an_empty_expert"])
+def test_the_receive_buffer_is_the_parents_held_plan_of_the_rows(
+        case, monkeypatch):
+    """What the all-to-all leaves on a chip is, bit for bit over the live
+    prefix, what the parent built there in three steps: the rows as they
+    arrived (by sender, a sender's for this chip in its routed order),
+    their experts' numbers beside them, then ``make_held_group_plan`` and
+    ``dispatch_held_rows`` over that buffer — kept here as the parent ran
+    them.  And the way back puts every row where it came from."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    table, bound, bm = TABLES[case]
+    monkeypatch.setattr(gg, "default_block_m", lambda: bm)
+    n, E = table.shape
+    held, routed, width = E // n, int(table[0].sum()), 8
+    rng = np.random.default_rng(7)
+    eids = np.stack([rng.permutation(np.repeat(np.arange(E), table[j]))
+                     for j in range(n)]).astype(np.int32)
+    rows = rng.standard_normal((n, routed, width)).astype(np.float32)
+
+    def body(eids, rows):
+        mine, _ = gg.make_held_group_plan(eids, 0, E, routed)
+        sizes = mappings.make_exchange_sizes(mine.counts, routed, bound)
+        plan, _ = gg.make_counted_group_plan(sizes.counts, bound)
+        sent = gg.dispatch_held_rows(rows, mine, 1)
+        received = mappings.exchange_forth(sent, sizes, plan.padded_rows)
+        returned = mappings.exchange_back(received, sizes, mine.padded_rows)
+        return (received, gg.live_rows(plan), returned,
+                jnp.minimum(mine.padded_to_row, routed))
+
+    @jax.jit
+    def parents(experts, arrived):
+        plan, over = gg.make_held_group_plan(experts, 0, held, bound)
+        return (gg.dispatch_held_rows(arrived, plan, 1), over,
+                gg.live_rows(plan))
+
+    received, live, returned, element = on_four(body, eids, rows)
+    set_topology(MeshTopology(devices=jax.devices()[:1]))
+    for d in range(n):
+        here = [(eids[j] // held) == d for j in range(n)]
+        arrived = np.concatenate([rows[j][here[j]] for j in range(n)])
+        experts = np.concatenate([eids[j][here[j]] % held for j in range(n)])
+        assert len(arrived) <= bound
+        pad = bound - len(arrived)
+        want, over, live_rows = parents(
+            jnp.asarray(np.concatenate([experts, np.full(pad, held)]),
+                        jnp.int32),
+            jnp.asarray(np.concatenate(
+                [arrived, np.zeros((pad, width), np.float32)])))
+        assert int(over) == 0 and int(live_rows) == live[d]
+        np.testing.assert_array_equal(received[d][:live[d]],
+                                      np.asarray(want)[:live[d]])
+        assert not received[d][live[d]:].any()
+        # back at the sender: its own rows at their places, zeros on padding
+        own = np.concatenate([rows[d], np.zeros((1, width), np.float32)])
+        np.testing.assert_array_equal(returned[d], own[element[d]])
 
 
 def test_the_device_gate_asks_where_the_call_is():
