@@ -85,9 +85,15 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+        if mask is None:
+            p = jnp.exp(s - m_new)
+        else:
+            # what the mask hid is exp(NEG_INF - m_new) = 0 with no second
+            # select over the tile, but for a row with nothing visible SO
+            # FAR (m_new still NEG_INF: a packed row whose first tiles are
+            # other documents'), where it would be exp(0): such a row
+            # subtracts 0 — a guard on one column, not on the tile
+            p = jnp.exp(s - jnp.where(m_new > NEG_INF, m_new, 0.0))
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
@@ -139,12 +145,13 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
 
     def body(j, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.dslice(j * block_q, block_q)].astype(jnp.float32)
+        qs = q_ref[0, 0, pl.dslice(j * block_q, block_q)].astype(
+            jnp.float32) * sm_scale
         do = do_ref[0, 0, pl.dslice(j * block_q, block_q)].astype(
             jnp.float32)
         lse = lse_ref[0, 0, :, pl.dslice(j * block_q, block_q)]  # [1, Bq]
         delta = delta_ref[0, 0, :, pl.dslice(j * block_q, block_q)]
-        s_t = lax.dot_general(k, q * sm_scale, (((1,), (1,)), ((), ())),
+        s_t = lax.dot_general(k, qs, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [Bk,Bq]
         mask = None
         if has_seg:
@@ -163,9 +170,9 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
             preferred_element_type=jnp.float32)
         dp_t = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta) * sm_scale
+        # the scale rides the q the scores were made with, not the tile
         dk_new = dk + lax.dot_general(
-            ds_t, q, (((1,), (0,)), ((), ())),
+            p_t * (dp_t - delta), qs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
@@ -186,26 +193,26 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
                has_seg, window=None):
     """Transposed score space, like _dkv_kernel (lse/delta as [1, Bq]
     rows); the dq accumulator itself stays [Bq, hd] (contraction over the
-    sublane k dim of ds_t)."""
+    sublane k dim of ds_t) and takes the scale once, after the loop."""
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          segq_ref, segk_ref, dq_ref) = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
     iq = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
+    qs = q_ref[0, 0].astype(jnp.float32) * sm_scale
     do = do_ref[0, 0].astype(jnp.float32)
     # rows staged whole-S (always lane-legal: S == array dim) and sliced
     # by the q-block index here — a [1, Bq] block would need bq % 128 == 0
-    qs = pl.dslice(iq * block_q, block_q)
-    lse = lse_ref[0, 0, :, qs]                           # [1, Bq]
-    delta = delta_ref[0, 0, :, qs]
+    rows = pl.dslice(iq * block_q, block_q)
+    lse = lse_ref[0, 0, :, rows]                         # [1, Bq]
+    delta = delta_ref[0, 0, :, rows]
     q_pos = iq * block_q + lax.broadcasted_iota(
         jnp.int32, (block_k, block_q), 1)
     k_base = lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-    segq = segq_ref[0, :, qs] if has_seg else None       # [1, Bq]
+    segq = segq_ref[0, :, rows] if has_seg else None     # [1, Bq]
 
-    dq0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    dq0 = jnp.zeros((block_q, qs.shape[-1]), jnp.float32)
     n_kblocks = (_causal_kblocks(iq, block_q, block_k, seq_len)
                  if causal else seq_len // block_k)
     first = (0 if window is None
@@ -214,7 +221,7 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     def body(j, dq):
         k = k_ref[0, 0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
         v = v_ref[0, 0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
-        s_t = lax.dot_general(k, q * sm_scale, (((1,), (1,)), ((), ())),
+        s_t = lax.dot_general(k, qs, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [Bk,Bq]
         mask = None
         if has_seg:
@@ -230,13 +237,12 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
             p_t = jnp.where(mask, p_t, 0.0)
         dp_t = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta) * sm_scale
         return dq + lax.dot_general(
-            ds_t, k, (((0,), (0,)), ((), ())),
+            p_t * (dp_t - delta), k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     dq = lax.fori_loop(first, n_kblocks, body, dq0)
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    dq_ref[0, 0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _to_bhsd(x):
@@ -341,19 +347,46 @@ def window_k_tiles(window, block_q, block_k):
     return block_q // block_k + -(-(window - 1) // block_k)
 
 
-def _record_call(q, k, v, block_q, block_k, packed, window=None):
+def tile_counts(seq_len, block_q, block_k, causal=True, window=None):
+    """``[interior, boundary]``: the score tiles a head's pass visits —
+    the three passes visit the same: every tile with a visible pair — by
+    what their position says of the positional mask.  An interior tile
+    lies wholly below the diagonal and, under a window, wholly inside it
+    (the mask is all true on it); a boundary tile is one the diagonal or
+    the window's edge crosses.  The kernels run one body on both: a body
+    of their own for interior tiles bought nothing on the chip (PERF.md
+    section 6, PR 49), and the counts say how many tiles that finding is
+    about."""
+    bq, bk = _choose_blocks(seq_len, block_q, block_k)
+    interior = boundary = 0
+    for i in range(seq_len // bq):
+        for j in range(seq_len // bk):
+            # the least and the greatest (query - key) over the tile
+            least, most = i * bq - (j + 1) * bk + 1, (i + 1) * bq - 1 - j * bk
+            if not causal or (least >= 0
+                              and (window is None or most < window)):
+                interior += 1
+            elif most >= 0 and (window is None or least < window):
+                boundary += 1
+    return [interior, boundary]
+
+
+def _record_call(q, k, v, block_q, block_k, packed, causal, window=None):
     """This call's row of the step's account
     (``tracing.flash_calls``): shapes only, written while the
-    step is traced.  A windowed call's row also holds its ``window`` and
-    the key tiles a q-block visits."""
+    step is traced; ``tiles`` is :func:`tile_counts`.  A windowed call's
+    row also holds its ``window`` and the key tiles a q-block visits."""
     from deepspeed_tpu.telemetry.tracing import count_in_step
     B, S, H, hd = q.shape
     bq, bk = _choose_blocks(S, block_q, block_k)
     row = {"batch": B, "seq_len": S, "heads": H, "kv_heads": k.shape[2],
            "dk": hd, "dv": v.shape[3], "packed": packed,
            "blocks": [bq, bk],
-           "vmem_limit_bytes": _vmem_limit(q, block_q, block_k, packed, v)}
+           "vmem_limit_bytes": _vmem_limit(q, block_q, block_k, packed, v),
+           "tiles": tile_counts(S, bq, bk, causal, window)}
     key = f"{B}x{S}x{H}x{k.shape[2]}x{hd}x{v.shape[3]}x{int(packed)}"
+    if not causal:
+        key += "nc"     # every tile interior: a row of its own
     if window is not None:
         row.update(window=window,
                    k_tiles_per_q_block=window_k_tiles(window, bq, bk))
@@ -440,7 +473,7 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     bq, bk = _choose_blocks(S, block_q, block_k)
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     has_seg = segment_ids is not None
-    _record_call(q, k, v, block_q, block_k, has_seg, window)
+    _record_call(q, k, v, block_q, block_k, has_seg, causal, window)
     # TPU-legal layouts for per-row operands (Mosaic requires the last two
     # block dims to divide (8, 128) or equal the array dims — a bare
     # [B, S] block fails): segment ids (int32, cast once in the public

@@ -806,8 +806,11 @@ def flash_calls(name: str = TRAIN_STEP_PROGRAM):
     per shape — ``batch``, ``seq_len``, ``heads``, ``kv_heads``, ``dk``
     (the score head's width: q and k) and ``dv`` (the value head's: v and
     the result), ``packed`` (segment ids or not), ``blocks`` (block_q,
-    block_k) and ``vmem_limit_bytes``, the limit the three kernels ask for
-    (None: what a call is granted unasked).  None where the step has no
+    block_k), ``vmem_limit_bytes``, the limit the three kernels ask for
+    (None: what a call is granted unasked), and ``tiles``: the
+    ``[interior, boundary]`` score tiles a head's pass visits — wholly
+    below the diagonal and inside the window, or crossed by one of them
+    (``ds_flash_attention.tile_counts``).  None where the step has no
     such call (the XLA einsum took its place, or there is no attention)."""
     return _account_rows(name, "flash_calls")
 

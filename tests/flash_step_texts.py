@@ -14,7 +14,8 @@ environment, from the root of a checkout:
         print(json.dumps({n: f.digest(n) for n in f.FAMILIES}, indent=1))"
 
 tests/data/held_prefix_step_digests.json (PR 39) holds the same at that
-PR's parent commit with the grouped kernels interpreted as well, for every
+PR's parent commit (``gpt2`` and ``olmoe`` taken again at PR 49 with
+flash_step_digests.json: the flash kernels' tile bodies changed) with the grouped kernels interpreted as well, for every
 family here and in ``HELD_FAMILIES``: ``f.digest(n, grouped_kernels=True)``.
 """
 import functools

@@ -392,6 +392,22 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
             *(_arg(v5e[0], shape, dtype) for shape, dtype in args))
         compiled = lowered.compile()
     assert KERNEL in compiled.as_text()
+    if case.startswith("ds_flash"):
+        # the calls ask Mosaic for what they asked before PR 49 touched
+        # the tile body (nothing at S 1024; vmem.limit_for's 96 MiB where
+        # S 8192 stages 19-29 MB) and the compile above took it; the
+        # working set is inside the budget; the account's [interior,
+        # boundary] tiles of a head
+        from deepspeed_tpu.ops.pallas import ds_flash_attention as dsf
+        for c in tracing.flash_calls("test/compile"):
+            assert c["vmem_limit_bytes"] == (
+                None if c["seq_len"] == S else 96 << 20), c
+            assert dsf.working_set_bytes(
+                c["seq_len"], c["dk"], 2, *c["blocks"], c["packed"],
+                c["dv"]) <= gg.vmem.budget()
+            assert c["tiles"] == (
+                [1, 2] if c["seq_len"] == S
+                else [0, 31] if c.get("window") else [120, 16]), c
     if "relu2" in case or "mla" in case:
         # a dim of 14.5 x 128 is one block: no padded copy of the expert
         # stack (or of the rows) is written beside the kernels; a value

@@ -141,8 +141,9 @@ def test_the_dispatch_checks_and_routes_by_both_widths(monkeypatch,
 
 
 def test_flash_calls_is_every_familys_account(interpret_pallas):
-    """One row per shape, both widths in it, the limit the calls ask for;
-    None where no flash call was traced."""
+    """One row per shape, both widths in it, the limit the calls ask for,
+    a head's [interior, boundary] tiles; None where no flash call was
+    traced."""
     assert tracing.flash_calls("test/none") is None
     q, k, v, _ = _inputs(64, 4, 2, 24, 16)
     with tracing.step_account("test/flash"):
@@ -151,13 +152,14 @@ def test_flash_calls_is_every_familys_account(interpret_pallas):
         jax.eval_shape(lambda *a: ds_flash_attention(
             *a, segment_ids=jnp.zeros((2, 64), jnp.int32)),
             q, k, k)
+    one_tile = [0, 1]       # [interior, boundary]: one tile, on the diagonal
     assert tracing.flash_calls("test/flash") == [
         {"batch": 2, "seq_len": 64, "heads": 4, "kv_heads": 2, "dk": 24,
          "dv": 16, "packed": False, "blocks": [64, 64],
-         "vmem_limit_bytes": None},
+         "vmem_limit_bytes": None, "tiles": one_tile},
         {"batch": 2, "seq_len": 64, "heads": 4, "kv_heads": 2, "dk": 24,
          "dv": 24, "packed": True, "blocks": [64, 64],
-         "vmem_limit_bytes": None}]
+         "vmem_limit_bytes": None, "tiles": one_tile}]
 
 
 @pytest.mark.parametrize("family", sorted(flash_step_texts.FAMILIES))
@@ -171,7 +173,12 @@ def test_with_one_width_the_step_lowers_to_the_parents_text(family):
     says which steps left the parent's text and why; the two sums into
     tokens are one scatter-add each off the chip, with no ``cond``:
     tests/test_held_row_sum.py); the flash kernels' part
-    of them is the ``gpt2`` and ``olmoe`` digests', which PR 39 left."""
+    of them is the ``gpt2`` and ``olmoe`` digests', which PR 39 left.
+    All four were taken again at PR 49: the forward selects once and the
+    backward kernels scale no tile, so every family's kernels' text changed
+    on purpose and the old digests went with the old bodies — which
+    tests/test_flash_tile_bodies.py keeps as its oracle, result for
+    result."""
     with open(os.path.join(HERE, "data", "flash_step_digests.json")) as f:
         want = json.load(f)
     assert flash_step_texts.digest(family) == want[family]

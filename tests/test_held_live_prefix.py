@@ -353,7 +353,11 @@ def test_a_step_with_no_held_plan_lowers_to_the_parents_text(
     ``combine_rows``, kernels that write their trailing tiles as zeros) is
     not this PR's: the toy step's lowered text — with the grouped kernels'
     bodies in it too — has the sha256 it had at PR 39's parent commit
-    (tests/flash_step_texts.py; tests/data/held_prefix_step_digests.json)."""
+    (tests/flash_step_texts.py; tests/data/held_prefix_step_digests.json),
+    taken again at PR 49, which changed the flash kernels' tile body in it
+    on purpose (tests/test_flash_tile_bodies.py holds the new one to the
+    old one's results); the held families' entries stay PR 39's parent's,
+    which the test below holds them to having left."""
     from tests import flash_step_texts
     want = _parents_digests()[
         "grouped_kernels" if grouped_kernels else "reference"][family]
