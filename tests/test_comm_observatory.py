@@ -225,7 +225,7 @@ def test_commstat_overlap_meter_classifies_threads():
     t = threading.Thread(
         target=lambda: cs.observe("all_gather", 0, 0.030))
     t.start()
-    t.join()                                      # other thread: hidden
+    t.join(timeout=30)                            # other thread: hidden
     frac = cs.step_end(0.05)
     assert frac == pytest.approx(0.75, abs=0.01)
     assert cs.summary()["overlap_fraction"] == frac

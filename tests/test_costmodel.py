@@ -570,7 +570,7 @@ def test_ledger_round_trip_via_bench_script(tmp_path):
                DS_BENCH_DIR=str(tmp_path))
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "scripts", "ckpt_bench.py")],
-        env=env, capture_output=True, text=True, timeout=560, cwd=ROOT)
+        env=env, capture_output=True, text=True, timeout=120, cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     led = str(tmp_path / "ledger.jsonl")
     recs = [json.loads(line) for line in open(led) if line.strip()]

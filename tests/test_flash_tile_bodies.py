@@ -307,14 +307,33 @@ def _hold_to_the_parent(new, old):
                                    atol=1e-5 * float(jnp.abs(b).max()))
 
 
-@pytest.mark.parametrize("rep", [1, 4], ids=["rep1", "rep4"])
-@pytest.mark.parametrize("widths", sorted(WIDTHS))
-@pytest.mark.parametrize("blocks", sorted(BLOCKS))
-@pytest.mark.parametrize("window", sorted(WINDOWS))
-@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
-def test_the_tile_body_is_the_parents_formula(packed, window, blocks, widths,
-                                              rep, interpret_pallas,
-                                              parent_kernels):
+@functools.lru_cache(maxsize=None)
+def _einsum_grads_of(packed, window, widths, rep):
+    """The XLA mask's gradients of a case: they do not depend on the
+    blocks, so the two block shapes of a case share one oracle."""
+    q, k, v, w = _inputs(S, rep, *WIDTHS[widths])
+    return _einsum_grads(q, k, v, w, _segments(S, packed), WINDOWS[window])
+
+
+def tile_body_cases(packed):
+    """The 64 cases' parameters with ``packed`` held: a file takes a half
+    of them (``tests/test_flash_tile_bodies_packed.py`` the other), so
+    that ``--dist loadfile`` gives them to two workers."""
+    def decorate(fn):
+        for name, values, ids in (
+                ("packed", [packed], ["packed" if packed else "dense"]),
+                ("window", sorted(WINDOWS), None),
+                ("blocks", sorted(BLOCKS), None),
+                ("widths", sorted(WIDTHS), None),
+                ("rep", [1, 4], ["rep1", "rep4"])):
+            fn = pytest.mark.parametrize(name, values, ids=ids)(fn)
+        return fn
+    return decorate
+
+
+def hold_the_tile_body_to_the_parent(packed, window, blocks, widths, rep,
+                                     parent_kernels):
+    wanted = _einsum_grads_of(packed, window, widths, rep)
     blocks, window = BLOCKS[blocks], WINDOWS[window]
     q, k, v, w = _inputs(S, rep, *WIDTHS[widths])
     seg = _segments(S, packed)
@@ -327,12 +346,19 @@ def test_the_tile_body_is_the_parents_formula(packed, window, blocks, widths,
     # the case holds the tiles it is here for: interior ones but under a
     # window of one block, boundary ones always
     assert boundary > 0 and (interior > 0) == (window != BQ)
-    wanted = _einsum_grads(q, k, v, w, seg, window)
     for a, b in zip(new[2], wanted):
         np.testing.assert_allclose(
             a, b, atol=2e-5 * (1 + float(jnp.abs(b).max())))
     parent_kernels()
     _hold_to_the_parent(new, _all_three(q, k, v, w, seg, blocks, window))
+
+
+@tile_body_cases(packed=False)
+def test_the_tile_body_is_the_parents_formula(packed, window, blocks, widths,
+                                              rep, interpret_pallas,
+                                              parent_kernels):
+    hold_the_tile_body_to_the_parent(packed, window, blocks, widths, rep,
+                                     parent_kernels)
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])

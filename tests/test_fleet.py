@@ -358,7 +358,7 @@ def test_scored_dispatch_never_blocks_on_wedged_replica(served):
         assert h.replica_id == other      # affinity steered it home
     finally:
         release.set()
-        t.join()
+        t.join(timeout=30)
     router.run_until_idle()
     assert h.state == "finished"
 

@@ -83,11 +83,25 @@ def _close(got, want, dtype):
         np.testing.assert_allclose(got, want, rtol=2.0 ** -7, atol=1e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-@pytest.mark.parametrize("shape", sorted(SHAPES))
-@pytest.mark.parametrize("load", sorted(ALL_LOADS))
+def sum_cases(dtype, name):
+    """The 72 cases' parameters with the rows' type held: a file takes a
+    half of them (``tests/test_held_row_sum_f32.py`` the other), so that
+    ``--dist loadfile`` gives them to two workers."""
+    def decorate(fn):
+        for arg, values, ids in (("load", sorted(ALL_LOADS), None),
+                                 ("shape", sorted(SHAPES), None),
+                                 ("dtype", [dtype], [name])):
+            fn = pytest.mark.parametrize(arg, values, ids=ids)(fn)
+        return fn
+    return decorate
+
+
+@sum_cases(jnp.bfloat16, "bf16")
 def test_the_kernel_sums_as_the_scatter_add(load, shape, dtype, kernel):
+    the_kernel_sums_as_the_scatter_add(load, shape, dtype)
+
+
+def the_kernel_sums_as_the_scatter_add(load, shape, dtype):
     factor, top_k, width = SHAPES[shape]
     rng = np.random.default_rng(11)
     plan, _ = _plan(load, factor, top_k, rng)

@@ -129,6 +129,15 @@ def one_device():
     return jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
 
 
+@functools.lru_cache(maxsize=None)
+def seeded_toy(docs=DOCS):
+    """(model, seeded weights, first micro-batch, the reference's loss
+    there), made once a process: the right side of every planted fault."""
+    model = toy_model()
+    params, mb = seeded_params(model), micro(packed_batch(docs=docs))
+    return model, params, mb, float(jitted_reference_loss(model)(params, mb))
+
+
 def _pop_biases(grads):
     return [grads["blocks"]["moe"].pop("e_score_correction_bias"),
             grads["mtp"]["block"]["moe"].pop("e_score_correction_bias")]

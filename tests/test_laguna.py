@@ -126,6 +126,15 @@ def one_device():
     return jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
 
 
+@functools.lru_cache(maxsize=None)
+def seeded_toy(docs=DOCS):
+    """(model, seeded weights, first micro-batch, the reference's loss
+    there), made once a process: the right side of every planted fault."""
+    model = toy_model()
+    params, mb = seeded_params(model), micro(packed_batch(docs=docs))
+    return model, params, mb, float(jitted_reference_loss(model)(params, mb))
+
+
 @pytest.mark.parametrize("held", ["a_share", "every_expert"])
 def test_gradients_match_the_reference(held, real_kernels):
     model = toy_model(**({} if held == "a_share" else

@@ -246,7 +246,7 @@ def test_cli_single_host_smoke(tmp_path):
         [sys.executable, "-m", "deepspeed_tpu.launcher.runner",
          "--hostfile", str(tmp_path / "missing_hostfile"),
          str(script)],
-        env=env, capture_output=True, text=True, timeout=300)
+        env=env, capture_output=True, text=True, timeout=240)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "E2E_OK" in proc.stdout
 
@@ -314,7 +314,7 @@ def test_ds_bench_runs_on_virtual_mesh():
         f"runpy.run_path({str(REPO + '/bin/ds_bench')!r},"
         " run_name='__main__')\n")
     r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=240)
+                       capture_output=True, text=True, timeout=120)
     assert "all_reduce" in r.stdout, r.stderr[-1500:]
 
 

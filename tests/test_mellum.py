@@ -22,14 +22,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.models import laguna, mellum
 from deepspeed_tpu.models.mellum import (FULL, SLIDING, MellumConfig,
                                          count_params, mellum_model)
 from deepspeed_tpu.models.model import param_stream_scope
 from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.telemetry import tracing
-from tests.util import base_config
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -119,6 +117,15 @@ def jitted_reference_loss(model, grad=False):
     """One compile where the eager form dispatches op by op."""
     fn = functools.partial(reference_loss, sizes=sizes_of(model))
     return jax.jit(jax.value_and_grad(fn) if grad else fn)
+
+
+@functools.lru_cache(maxsize=None)
+def seeded_toy():
+    """(model, seeded weights, first micro-batch, the reference's loss
+    there), made once a process: the right side of every planted fault."""
+    model = toy_model()
+    params, mb = seeded_params(model), micro(packed_batch())
+    return model, params, mb, float(jitted_reference_loss(model)(params, mb))
 
 
 def test_gradients_match_the_reference(real_kernels):
@@ -242,69 +249,6 @@ def test_a_row_over_the_bound_is_counted(monkeypatch):
     assert rows.shape == (5, 8) and (rows.sum(-1) == B * S * 3).all()
 
 
-# ------------------------------------------------------------ the engine
-def _engine(model, mesh, stage, **mesh_config):
-    engine, *_ = deepspeed_tpu.initialize(
-        model=model, config=base_config(
-            train_micro_batch_size_per_gpu=B // mesh.size,
-            gradient_accumulation_steps=GAS, seed=3,
-            zero_optimization={"stage": stage},
-            **({"mesh": mesh_config} if mesh_config else {})), mesh=mesh)
-    start = seeded_params(model)
-    engine.state["params"] = jax.tree.map(
-        lambda new, old: jax.device_put(new.astype(old.dtype), old.sharding),
-        start, engine.state["params"])
-    return engine, start
-
-
-@pytest.mark.parametrize("stage", [0, 2])
-def test_engine_first_step_loss_matches_the_reference(stage):
-    model = toy_model()
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    engine, start = _engine(model, mesh, stage)
-    batch = packed_batch()
-    want = reference.step_loss(start, batch, sizes_of(model), chunk=1)
-    got = float(engine.train_batch(batch=batch))
-    assert abs(got - want) < LOSS_TOL, (got, want)
-    assert engine.step_counts() == {"moe/rows_over_bound": 0}
-
-
-def test_engine_on_a_four_wide_expert_axis_matches_the_reference():
-    """The deployment at toy size: experts spread four ways (2 of 8 a
-    device), every expert layer through the exchange, ZeRO-2 over the same
-    four.  The first step's loss is the uncut reference's, two steps leave
-    the parameters where one device's engine leaves them, and the expert
-    leaves stay split by expert."""
-    model = toy_model()
-    four = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("expert",))
-    engine, start = _engine(model, four, 2, expert_parallel_size=4)
-    batch = packed_batch()
-    want = reference.step_loss(start, batch, sizes_of(model), chunk=1)
-    got = float(engine.train_batch(batch=batch))
-    assert abs(got - want) < LOSS_TOL, (got, want)
-    engine.train_batch(batch=packed_batch(1))
-    assert engine.step_counts() == {"moe/rows_over_bound": 0}
-    w_in = engine.state["params"]["blocks"][SLIDING]["moe"]["w_in"]
-    assert {s.data.shape for s in w_in.addressable_shards} \
-        == {(1, 3, 2, 64, 32)}
-    assert len({str(s.index) for s in w_in.addressable_shards}) == 4
-    after_four = jax.tree.map(np.asarray, engine.state["params"])
-
-    tracing.reset_programs()
-    one = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    single, _ = _engine(toy_model(), one, 2)
-    single.train_batch(batch=batch)
-    single.train_batch(batch=packed_batch(1))
-    for (path, a), b in zip(
-            jax.tree_util.tree_leaves_with_path(after_four),
-            jax.tree.leaves(single.state["params"])):
-        # Adam moves a weight whose gradient is rounding alone by +-lr a
-        # step: a handful of such elements may differ by that, no more
-        off = np.abs(a - np.asarray(b))
-        assert (off > 2e-5).mean() < 1e-3 and off.max() < 2.5e-3, \
-            (jax.tree_util.keystr(path), off.max())
-
-
 # ----------------------------------------------- what makes it this model
 def _attention_given(monkeypatch, change):
     """``causal_attention`` as the model calls it, its arguments changed."""
@@ -371,9 +315,7 @@ FAULTS = {
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_a_planted_fault_is_outside_the_tolerance(fault, monkeypatch):
     patch, overrides = FAULTS[fault]
-    right = toy_model()
-    params, mb = seeded_params(right), micro(packed_batch())
-    want = float(jitted_reference_loss(right)(params, mb))
+    _, params, mb, want = seeded_toy()
     if patch:
         patch(monkeypatch)
     model = toy_model(**overrides)
@@ -382,9 +324,7 @@ def test_a_planted_fault_is_outside_the_tolerance(fault, monkeypatch):
 
 
 def test_with_nothing_planted_the_same_comparison_holds():
-    model = toy_model()
-    params, mb = seeded_params(model), micro(packed_batch())
-    want = float(jitted_reference_loss(model)(params, mb))
+    model, params, mb, want = seeded_toy()
     assert abs(float(jax.jit(model.loss)(params, mb)) - want) < LOSS_TOL
 
 

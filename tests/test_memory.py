@@ -92,7 +92,7 @@ def test_owner_attribution_sums_to_tier_totals():
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        t.join(timeout=30)
     assert led.owner_bytes("device", "kv_pool") == 2000
     # detail rides into the snapshot
     assert snap["tiers"]["device"]["owners"]["params"]["detail"] == \

@@ -26,13 +26,16 @@ import textwrap
 import numpy as np
 import pytest
 
+from tests.util import child_env
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 WORKER = textwrap.dedent("""
     import os, sys
     pid, port, leg = int(sys.argv[1]), sys.argv[2], sys.argv[3]
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4"
+                               " --xla_backend_optimization_level=0")
     os.environ["COORDINATOR_ADDRESS"] = "127.0.0.1:" + port
     os.environ["NPROC"] = "2"
     os.environ["PROCESS_ID"] = str(pid)
@@ -118,7 +121,7 @@ def _run_two_process(leg, tmp_path):
     script = tmp_path / "worker.py"
     script.write_text(WORKER.format(repo=REPO))
     port = str(_free_port())
-    env = {k: v for k, v in os.environ.items()
+    env = {k: v for k, v in child_env().items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     procs = [subprocess.Popen(
         [sys.executable, str(script), str(pid), port, leg],
@@ -127,7 +130,7 @@ def _run_two_process(leg, tmp_path):
     outs = []
     try:
         for p in procs:
-            out, _ = p.communicate(timeout=360)
+            out, _ = p.communicate(timeout=120)
             outs.append(out)
     finally:
         for p in procs:

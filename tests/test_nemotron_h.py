@@ -10,7 +10,14 @@ experts.
 ``DS_GGEMM_INTERPRET=1`` runs the real grouped GEMM kernels in Pallas'
 interpreter.  Everything is float32 with seeded weights: the two sides
 differ only in the order of summation and in the form of the state-space
-scan (chunked here, per token there)."""
+scan (chunked here, per token there).
+
+The toy, its seeded weights, the reference's loss and gradients and the
+model's own are made once a process (``functools.lru_cache``) and every
+test reads them; a departure runs only the departed side.  The tests that
+build an engine are ``tests/test_nemotron_h_engine.py``, so that ``--dist
+loadfile`` gives the family's tests to two workers."""
+import functools
 import importlib.util
 import json
 import os
@@ -21,9 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
-from deepspeed_tpu.models import nemotron_h
-from deepspeed_tpu.models.llama import rope
 from deepspeed_tpu.models.mixtral import mixtral_model
 from deepspeed_tpu.models.model import param_stream_scope
 from deepspeed_tpu.models.nemotron_h import (NemotronHConfig, count_params,
@@ -33,7 +37,6 @@ from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.moe.layer import MoEConfig, init_moe_params
 from deepspeed_tpu.telemetry import tracing
-from tests.util import base_config
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -55,7 +58,7 @@ GAS, B, S, DOCS = 2, 2, 72, 4
 
 
 @pytest.fixture(autouse=True)
-def _real_kernels(monkeypatch):
+def real_kernels(monkeypatch):
     monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
     monkeypatch.setattr(moe_layer, "_metrics_registry", None)
     tracing.reset_programs()
@@ -122,41 +125,42 @@ def one_device():
     return jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
 
 
-@pytest.mark.parametrize("stage", [0, 2])
-def test_engine_first_step_loss_matches_the_reference(stage):
-    model = toy_model()
-    engine, *_ = deepspeed_tpu.initialize(
-        model=model, config=base_config(
-            train_micro_batch_size_per_gpu=B,
-            gradient_accumulation_steps=GAS, seed=3,
-            zero_optimization={"stage": stage}), mesh=one_device())
-    start = seeded_params(model)
-    engine.state["params"] = jax.tree.map(
-        lambda new, old: jax.device_put(new.astype(old.dtype), old.sharding),
-        start, engine.state["params"])
-    batch = packed_batch()
-    want = reference.step_loss(start, batch, sizes_of(model), chunk=1)
-    bias = lambda p: np.asarray(
-        p["blocks"]["experts"]["moe"]["e_score_correction_bias"])
-    bias_was = bias(start)
-    got = float(engine.train_batch(batch=batch))
-    assert abs(got - want) < LOSS_TOL, (got, want)
-    assert np.isfinite(float(engine.train_batch(batch=packed_batch(1))))
-    # the selection bias is a leaf the loss does not train: a gradient of
-    # exactly zero leaves it where it was
-    assert np.abs(bias_was).max() > 0
-    np.testing.assert_array_equal(bias(engine.state["params"]), bias_was)
+@functools.lru_cache(maxsize=None)
+def toy(held="a_share"):
+    """(model, seeded weights, first micro-batch, the model's jitted loss
+    and gradients) of the toy that holds a share or every expert."""
+    model = toy_model(**({} if held == "a_share" else
+                         dict(experts_held=None, expert_offset=0)))
+    return (model, seeded_params(model), micro(packed_batch()),
+            jax.jit(jax.value_and_grad(model.loss)))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_numbers(held="a_share"):
+    """The reference's loss and gradients at :func:`toy`'s weights and
+    batch."""
+    model, params, mb, _ = toy(held)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(functools.partial(
+            reference_loss, sizes=sizes_of(model))))(params, mb)
+
+
+def reference_loss_without_reset():
+    """The loss of the reference that never resets at a document's start,
+    at the same weights and batch."""
+    model, params, mb, _ = toy()
+    return jax.jit(functools.partial(reference_loss, sizes=sizes_of(model)))(
+        params, {"input_ids": mb["input_ids"]})
 
 
 @pytest.mark.parametrize("held", ["a_share", "every_expert"])
 def test_gradients_match_the_reference(held):
-    model = toy_model(**({} if held == "a_share" else
-                         dict(experts_held=None, expert_offset=0)))
-    params, mb = seeded_params(model), micro(packed_batch())
+    _, params, mb, loss_and_grads = toy(held)
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(model.loss)(params, mb)
-        want, want_grads = jax.value_and_grad(reference_loss)(
-            params, mb, sizes_of(model))
+        loss, grads = loss_and_grads(params, mb)
+    # copies down to the leaves' dict: the bias is popped, and the
+    # reference's numbers are every test's
+    want, want_grads = jax.tree.map(lambda x: x, reference_numbers(held))
     assert abs(float(loss) - float(want)) < LOSS_TOL
     bias = lambda g: g["blocks"]["experts"]["moe"].pop(
         "e_score_correction_bias")
@@ -169,103 +173,6 @@ def test_gradients_match_the_reference(held):
     # every other leaf learns
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         assert float(jnp.abs(leaf).max()) > 0, jax.tree_util.keystr(path)
-
-
-# ----------------------------------------------- what makes it this model
-def _with_moe(monkeypatch, **changes):
-    explicit = NemotronHConfig.moe.fget
-    monkeypatch.setattr(NemotronHConfig, "moe", property(
-        lambda self: replace(explicit(self), **changes)))
-
-
-def _rotary_added(monkeypatch):
-    attend = nemotron_h.causal_attention
-    monkeypatch.setattr(
-        nemotron_h, "causal_attention", lambda q, k, v, **kw: attend(
-            rope(q, 10000.0), rope(k, 10000.0), v, **kw))
-
-
-def _bias_in_the_weights(monkeypatch):
-    route = sharded_moe.topk_routing
-
-    def biased(logits, k, *args, selection_bias=None, scale=1.0, **kw):
-        routing = route(logits, k, *args, selection_bias=selection_bias,
-                        scale=scale, **kw)
-        picked = jnp.take_along_axis(
-            jax.nn.sigmoid(logits) + selection_bias, routing.expert_idx, 1)
-        return routing._replace(gate_weights=picked / jnp.sum(
-            picked, axis=1, keepdims=True) * scale)
-
-    monkeypatch.setattr(moe_layer, "topk_routing", biased)
-
-
-def _norm_before_the_gate(monkeypatch):
-    def wrong(y, z, w, groups, eps):
-        shape = y.shape[:-1] + (groups, y.shape[-1] // groups)
-        normed = nemotron_h._rms_norm(y.reshape(shape),
-                                      w.reshape(shape[-2:]), eps)
-        return normed.reshape(y.shape) * jax.nn.silu(z)
-    monkeypatch.setattr(nemotron_h, "_gated_norm", wrong)
-
-
-def _one_norm_over_all_channels(monkeypatch):
-    right = nemotron_h._gated_norm
-    monkeypatch.setattr(nemotron_h, "_gated_norm",
-                        lambda y, z, w, groups, eps: right(y, z, w, 1, eps))
-
-
-def _zeroed(name):
-    return lambda params: jax.tree_util.tree_map_with_path(
-        lambda path, w: w * 0 if path[-1].key == name else w, params)
-
-
-#: name -> (what it does to the MODEL's side: a patch, overrides of the
-#: builder, a change of the parameters the model is given).  The reference
-#: keeps the equations; the loss then has to leave the tolerance.
-DEPARTURES = {
-    "rotary_added": (_rotary_added, {}, None),
-    "softmax_for_sigmoid": (
-        lambda mp: _with_moe(mp, router="softmax"), {}, None),
-    "bias_added_to_the_weights": (_bias_in_the_weights, {}, None),
-    "no_scaling_factor": (None, dict(routed_scaling_factor=1.0), None),
-    "swiglu_for_relu2": (
-        lambda mp: _with_moe(mp, activation="silu_glu"), {}, None),
-    "norm_before_the_gate": (_norm_before_the_gate, {}, None),
-    "one_norm_over_all_channels": (_one_norm_over_all_channels, {}, None),
-    "no_skip_term": (None, {}, _zeroed("D")),
-    "no_conv_bias": (None, {}, _zeroed("conv_b")),
-    "no_document_reset": (None, {}, None),
-}
-
-
-@pytest.mark.parametrize("left_out", sorted(DEPARTURES))
-def test_a_departure_left_out_is_outside_the_tolerance(left_out,
-                                                       monkeypatch):
-    patch, overrides, change = DEPARTURES[left_out]
-    right = toy_model()
-    params, mb = seeded_params(right), micro(packed_batch())
-    if patch:
-        patch(monkeypatch)
-    model = toy_model(**overrides)
-    if left_out == "swiglu_for_relu2":
-        # its third matrices are leaves the reference does not read
-        params = seeded_params(model)
-    want = float(reference_loss(params, mb, sizes_of(right)))
-    if left_out == "no_document_reset":
-        # the model packed against the reference that never resets
-        want = float(reference.micro_batch_loss(
-            params, mb["input_ids"], None, sizes_of(right), block=36))
-    got = float(model.loss(change(params) if change else params, mb))
-    assert abs(got - want) > 50 * LOSS_TOL, (got, want)
-
-
-def test_with_nothing_left_out_the_same_comparison_holds():
-    """The control of the test above: the same parameters and batch, no
-    departure, inside the tolerance."""
-    model = toy_model()
-    params, mb = seeded_params(model), micro(packed_batch())
-    want = float(reference_loss(params, mb, sizes_of(model)))
-    assert abs(float(model.loss(params, mb)) - want) < LOSS_TOL
 
 
 # ------------------------------------------------- the router's two forms
@@ -382,12 +289,6 @@ def test_a_row_over_the_bound_is_counted(monkeypatch):
     _, counts = jax.jit(model.loss_with_counts_fn)(params, mb)
     assert int(counts["moe/rows_over_bound"]) > int(stats["dropped"])
     assert "callback" not in jax.jit(model.loss).lower(params, mb).as_text()
-    engine, *_ = deepspeed_tpu.initialize(
-        model=toy_model(), config=base_config(
-            train_micro_batch_size_per_gpu=B,
-            gradient_accumulation_steps=GAS, seed=3), mesh=one_device())
-    engine.train_batch(batch=packed_batch())
-    assert engine.step_counts()["moe/rows_over_bound"] > 0
 
 
 def test_the_held_rows_factor_sizes_the_plan():
@@ -504,61 +405,3 @@ def test_the_size_is_the_published_one_and_the_cut_is_the_files():
         NemotronHConfig(num_layers=10).num_periods
     with pytest.raises(ValueError, match="not built"):
         NemotronHConfig(num_layers=2, hybrid_override_pattern="M-").pattern
-
-
-def test_two_repeats_of_the_pattern_walk_two_stacks_deep():
-    model = toy_model(num_layers=10)
-    params, mb = seeded_params(model), micro(packed_batch())
-    assert params["blocks"]["ssm"]["w_in"].shape[:2] == (2, 2)
-    want = float(reference_loss(params, mb, sizes_of(model)))
-    assert abs(float(model.loss(params, mb)) - want) < LOSS_TOL
-
-
-def test_scopes_and_counts_of_a_toy_step():
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        engine, *_ = deepspeed_tpu.initialize(
-            model=toy_model(), config=base_config(
-                train_micro_batch_size_per_gpu=B,
-                gradient_accumulation_steps=GAS), mesh=one_device())
-        engine.train_batch(batch=packed_batch())
-        table = tracing.get_program_map("train/step")
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-    scopes = [row["scope"] or "" for row in table.values()]
-    for name in ("ds.embed", "ds.head_loss", "ds.block/attn",
-                 "ds.block/ssm/in_proj", "ds.block/ssm/conv",
-                 "ds.block/ssm/scan", "ds.block/ssm/gate_norm",
-                 "ds.block/ssm/out_proj", "ds.block/mlp/router",
-                 "ds.block/mlp/dispatch", "ds.block/mlp/experts",
-                 "ds.block/mlp/combine", "ds.block/mlp/shared_expert",
-                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"):
-        assert any(name in s for s in scopes), name
-    for phase in ("forward", "recompute", "backward"):
-        assert any(row["phase"] == phase and "/ssm/scan/" in row["scope"]
-                   for row in table.values() if row["scope"]), phase
-    # an instruction of a block is under one of the block's own scopes: a
-    # family that writes none reads ``other`` in every step.* metric
-    inside = ("/ssm/", "/attn/", "/mlp/")
-    for row in table.values():
-        if "ds.block" in (row["scope"] or ""):
-            assert row["phase"] != "other", row
-            assert any(part in row["scope"] for part in inside), row
-    assert set(tracing.STEP_SCOPES) >= {"ssm", "scan", "in_proj", "conv",
-                                        "gate_norm", "out_proj"}
-    rows = tracing.grouped_gemm_rows("train/step")
-    T, k = B * S, TOY["top_k"]
-    bound = -(-(2 * T * k * 4 // 16) // 128) * 128
-    assert rows["held_rows_bound"] == bound
-    assert rows["padded_rows_per_call"] == bound + 4 * 128
-    assert (rows["experts_held"], rows["experts_routed"]) == (4, 16)
-    assert {c["kernel"] for c in rows["calls"]} == {
-        "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"}
-    assert tracing.ssd_chunks("train/step") == [
-        {"chunks": -(-S // 16), "chunk_len": 16, "batch": B, "heads": 8,
-         "groups": 2, "head_dim": 8, "state": 16, "path": "xla"}]
-    assert tracing.delta_rule_chunks("train/step") is None

@@ -39,7 +39,7 @@ from deepspeed_tpu.telemetry.numerics import (NumericsState, group_stats,
                                               numerics_enabled,
                                               resolve_fingerprint_interval,
                                               state_fingerprint)
-from tests.util import base_config, random_batch, tiny_gpt2
+from tests.util import base_config, child_env, random_batch, tiny_gpt2
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -450,7 +450,7 @@ def test_fingerprint_resume_reproduces_stream_bitwise(tmp_path):
     out = subprocess.run(
         [sys.executable, "-c", _RESUME_CHILD.format(root=REPO),
          str(tmp_path / "ckpt")],
-        capture_output=True, text=True, timeout=540, cwd=REPO)
+        capture_output=True, text=True, timeout=120, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     doc = json.loads(out.stdout.strip().splitlines()[-1])
     assert doc["audit_ok"] is True
@@ -574,12 +574,12 @@ def test_numerics_report_render_and_errors(tmp_path, capsys):
 
 
 def test_numerics_bench_smoke_subprocess():
-    env = dict(os.environ, NUMERICS_SMOKE="1", JAX_PLATFORMS="cpu")
+    env = child_env(NUMERICS_SMOKE="1")
     env.pop("DS_NUMERICS", None)
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts",
                                       "numerics_bench.py")],
-        capture_output=True, text=True, timeout=540, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["metric"] == "numerics_overhead_fraction"
@@ -595,7 +595,7 @@ def test_ckpt_bench_detail_gains_convergence_fields():
                JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "ckpt_bench.py")],
-        capture_output=True, text=True, timeout=540, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     detail = json.loads(out.stdout.strip().splitlines()[-1])
     assert np.isfinite(detail["final_loss"])

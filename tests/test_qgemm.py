@@ -190,7 +190,7 @@ def test_qgemm_sweep_script_smoke():
     env = dict(os.environ, JAX_PLATFORMS="cpu", QGEMM_SWEEP_SMOKE="1")
     out = subprocess.run(
         [sys.executable, "scripts/qgemm_sweep.py"], env=env,
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=240,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-2000:]
     assert '"blocks"' in out.stdout

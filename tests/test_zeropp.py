@@ -2,7 +2,6 @@
 docs/_tutorials/zeropp.md): int8 block quantization, qwZ quantized weight
 gather, qgZ quantized gradient reduce-scatter, hpZ secondary shard."""
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -223,152 +222,6 @@ def test_mics_trains_to_parity(devices8):
     np.testing.assert_allclose(l_mics, l_ref, rtol=1e-4, atol=1e-4)
 
 
-# ------------------------------------------------------------------------ qgZ
-
-def test_qgz_trains_to_parity(devices8):
-    """Pure-DP mesh + zero_quantized_gradients: training through the
-    quantized grad exchange tracks the exact-reduction run (lossy but
-    convergent)."""
-    ref, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 1}))
-    qgz, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 1,
-                               "zero_quantized_gradients": True}))
-    l_ref = _train(ref, steps=4, seed=83)
-    l_qgz = _train(qgz, steps=4, seed=83)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-
-
-def test_qgz_int8_on_the_wire(devices8):
-    """The compiled step's gradient exchange must move int8 (all-to-all or
-    all-gather of s8), not fp32."""
-    engine, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 1,
-                               "zero_quantized_gradients": True}))
-    b = random_batches(1, batch_size=8, seed=2)[0]
-    batch = engine._shard_batch({"input_ids": b["input_ids"][None]},
-                                stacked=True)
-    fn = engine._get_compiled("train_step")
-    hlo = fn.lower(engine.state, batch,
-                   engine._next_rng()).compile().as_text()
-    comm_lines = [l for l in hlo.splitlines()
-                  if "all-to-all" in l or "all-gather" in l]
-    assert any("s8[" in l for l in comm_lines), comm_lines[:5]
-
-
-def test_qgz_engages_on_hybrid_tp_mesh(devices8):
-    """TP×DP mesh: the generalized tier is manual over the data axis and
-    auto over model — qgZ engages (round-2 VERDICT item 1: no more
-    single-axis pure-DP restriction) and tracks the exact-reduction run."""
-    ref, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            mesh={"model_parallel_size": 2},
-            zero_optimization={"stage": 2}))
-    engine, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            mesh={"model_parallel_size": 2},
-            zero_optimization={"stage": 2,
-                               "zero_quantized_gradients": True}))
-    assert engine._get_qgz_plan() is not None, "qgZ did not engage on TP mesh"
-    l_ref = _train(ref, steps=4, seed=3)
-    l_qgz = _train(engine, steps=4, seed=3)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-
-
-def test_qgz_falls_back_without_wide_data_axis(devices8):
-    """A mesh whose data/hpz axes are all size 1 (everything in model×seq)
-    has nothing to exchange over: qgZ must warn, return no plan, and train
-    with exact reduction."""
-    engine, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(num_heads=8), config=base_config(
-            mesh={"model_parallel_size": 4, "sequence_parallel_size": 2},
-            zero_optimization={"stage": 1,
-                               "zero_quantized_gradients": True}))
-    assert engine._get_qgz_plan() is None
-    b = random_batches(1, batch_size=8, seed=3)[0]
-    loss = engine.train_batch(batch={"input_ids": b["input_ids"][None]})
-    assert np.isfinite(float(loss))
-
-
-def test_qgz_stage3_trains_to_parity(devices8):
-    """stage-3 + zero_quantized_gradients (round-2 VERDICT item 1): the
-    per-layer gather carries a quantized-reduce-scatter VJP; training
-    tracks plain stage 3."""
-    ref, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3,
-                               "stage3_param_persistence_threshold": 0}))
-    qgz, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3,
-                               "zero_quantized_gradients": True,
-                               "stage3_param_persistence_threshold": 0}))
-    plan = qgz._get_qgz_plan()
-    assert plan is not None and plan["block_scope"] is not None
-    l_ref = _train(ref, steps=4, seed=59)
-    l_qgz = _train(qgz, steps=4, seed=59)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-
-
-def test_qgz_stage3_int8_on_the_wire(devices8):
-    """The stage-3 compiled step's gradient exchange must move s8 chunks
-    (the 'int8 asserted in the dryrun HLO' done-criterion)."""
-    engine, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3,
-                               "zero_quantized_gradients": True,
-                               "stage3_param_persistence_threshold": 0}))
-    b = random_batches(1, batch_size=8, seed=5)[0]
-    batch = engine._shard_batch({"input_ids": b["input_ids"][None]},
-                                stacked=True)
-    fn = engine._get_compiled("train_step")
-    with engine._train_scope():
-        lowered = fn.lower(engine.state, batch, engine._next_rng())
-    hlo = lowered.compile().as_text()
-    comm_lines = [l for l in hlo.splitlines()
-                  if "all-to-all" in l or "all-gather" in l]
-    assert any("s8[" in l for l in comm_lines), comm_lines[:5]
-
-
-def test_qgz_stage3_with_hpz(devices8):
-    """qgZ composes with the hpZ secondary shard: params gather over hpz
-    (wrapper), the data-axis reduction runs in the epilogue."""
-    ref, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3, "zero_hpz_partition_size": 2,
-                               "stage3_param_persistence_threshold": 0}))
-    qgz, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3, "zero_hpz_partition_size": 2,
-                               "zero_quantized_gradients": True,
-                               "stage3_param_persistence_threshold": 0}))
-    assert qgz._get_qgz_plan() is not None
-    l_ref = _train(ref, steps=3, seed=67)
-    l_qgz = _train(qgz, steps=3, seed=67)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-
-
-def test_qgz_with_qwz_combined(devices8):
-    """qwZ + qgZ together (full ZeRO++): the layer gather moves int8 both
-    ways — forward weight gather and backward gradient scatter."""
-    ref, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3,
-                               "stage3_param_persistence_threshold": 0}))
-    zpp, *_ = deepspeed_tpu.initialize(
-        model=tiny_gpt2(), config=base_config(
-            zero_optimization={"stage": 3,
-                               "zero_quantized_weights": True,
-                               "zero_quantized_gradients": True,
-                               "stage3_param_persistence_threshold": 0}))
-    l_ref = _train(ref, steps=4, seed=71)
-    l_zpp = _train(zpp, steps=4, seed=71)
-    np.testing.assert_allclose(l_zpp, l_ref, rtol=0.08, atol=0.08)
-
-
 # ------------------------------------------------- qgZ × pipeline (r3 item 4)
 
 def _pipe_cfg(gas, qgz, **extra_pipe):
@@ -391,60 +244,3 @@ def _pipe_train(engine, gas, steps, seed):
                                            dtype=np.int32)}
         out.append(float(engine.train_batch(batch=batch)))
     return out
-
-
-def test_qgz_under_pipeline_gpipe(devices8):
-    """round-3 VERDICT item 4: the quantized gradient exchange composes
-    with the scanned-GPipe pipeline (the tier's shard_map keeps the pipe
-    axis auto); parity with the dense pipeline run + int8 on the wire."""
-    from deepspeed_tpu.runtime.pipe.pipeline import pipeline_model
-    gas = 4
-    ref, *_ = deepspeed_tpu.initialize(
-        model=pipeline_model(tiny_gpt2(), num_stages=2),
-        config=_pipe_cfg(gas, qgz=False))
-    qgz, *_ = deepspeed_tpu.initialize(
-        model=pipeline_model(tiny_gpt2(), num_stages=2),
-        config=_pipe_cfg(gas, qgz=True))
-    assert qgz._get_qgz_plan() is not None, "qgZ did not engage under PP"
-    l_ref = _pipe_train(ref, gas, steps=3, seed=81)
-    l_qgz = _pipe_train(qgz, gas, steps=3, seed=81)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-    batch = qgz._shard_batch(
-        {"input_ids": np.zeros((gas, 4, 16), np.int32)}, stacked=True)
-    fn = qgz._get_compiled("train_step")
-    with qgz._train_scope():
-        hlo = fn.lower(qgz.state, batch,
-                       qgz._next_rng()).compile().as_text()
-    comm = [l for l in hlo.splitlines()
-            if "all-to-all" in l or "all-gather" in l]
-    assert any("s8[" in l for l in comm), comm[:5]
-
-
-def test_qgz_under_pipeline_chunked(devices8):
-    """Chunked GPipe (num_pipe_buffers) + qgZ: the tier scans pipeline
-    chunks and still tracks the dense run."""
-    from deepspeed_tpu.runtime.pipe.pipeline import pipeline_model
-    gas = 4
-    ref, *_ = deepspeed_tpu.initialize(
-        model=pipeline_model(tiny_gpt2(), num_stages=2),
-        config=_pipe_cfg(gas, qgz=False, num_pipe_buffers=2))
-    qgz, *_ = deepspeed_tpu.initialize(
-        model=pipeline_model(tiny_gpt2(), num_stages=2),
-        config=_pipe_cfg(gas, qgz=True, num_pipe_buffers=2))
-    assert qgz._get_qgz_plan() is not None
-    l_ref = _pipe_train(ref, gas, steps=3, seed=83)
-    l_qgz = _pipe_train(qgz, gas, steps=3, seed=83)
-    np.testing.assert_allclose(l_qgz, l_ref, rtol=0.05, atol=0.05)
-
-
-def test_qgz_1f1b_restriction_is_loadbearing(devices8):
-    """1F1B's manual interleave bypasses the exchange tier: the plan must
-    refuse (warn-and-degrade) and training must still run dense — the
-    documented restriction, asserted (round-3 VERDICT item 4)."""
-    from deepspeed_tpu.runtime.pipe.pipeline import pipeline_model
-    gas = 4
-    engine, *_ = deepspeed_tpu.initialize(
-        model=pipeline_model(tiny_gpt2(), num_stages=2),
-        config=_pipe_cfg(gas, qgz=True, schedule="1f1b"))
-    assert engine._get_qgz_plan() is None
-    assert np.isfinite(_pipe_train(engine, gas, steps=1, seed=85)[0])

@@ -1,8 +1,23 @@
 """Shared test fixtures (reference: tests/unit/simple_model.py — SimpleModel
 and random_dataloader equivalents)."""
+import os
+
 import numpy as np
 
 from deepspeed_tpu.models.gpt2 import gpt2_model
+
+
+def child_env(**extra):
+    """The environment of a child process that compiles: this process's
+    (the conftest's ``XLA_FLAGS`` are in it) with the compile cache's
+    directory, so that a child beside five busy workers does not compile
+    cold what the last run compiled.  Not for a child that restores a
+    checkpoint and trains on: under a warm cache that path corrupts the
+    heap on this jaxlib."""
+    import jax
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=jax.config.jax_compilation_cache_dir,
+                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0", **extra)
 
 
 def tiny_gpt2(**overrides):
