@@ -18,12 +18,15 @@ Two dispatch formulations (``MoEConfig.dispatch_mode``, ISSUE 8):
   ``capacity_factor``.  On an ``expert`` mesh axis wider than one the
   experts are really spread (:func:`_exchanged_grouped_moe`): inside a
   ``shard_map`` over every mesh axis each chip routes its own tokens
-  over all experts, sends each chosen row to the chip that holds its
-  expert (one all-to-all), runs the held plan over the rows it received,
-  sends the results back (a second) and weights and sums them where the
-  token lives.  Drop-free inside a stated bound: a chip has room for
-  ``held_rows_factor`` times the rows even routing sends it, and a row
-  past that is counted (:data:`ROWS_OVER_BOUND`), never silently lost.
+  over all experts, sends each chosen row — and, beside the rows, their
+  gates: a float32 a row — to the chip that holds its expert (one
+  all-to-all of rows, one of gates), runs the held plan over the rows it
+  received, each weighted by its gate between the experts' two halves,
+  sends the results back (a second all-to-all of rows) and sums them
+  where the token lives.  Drop-free inside a stated bound: a chip has
+  room for ``held_rows_factor`` times the rows even routing sends it, and
+  a row past that is counted (:data:`ROWS_OVER_BOUND`), never silently
+  lost.
 - ``auto`` — einsum when training; grouped at eval/serving when the
   kernel is real (single TPU device / interpret) or the host is
   single-device — a multi-device host where only the unsharded
@@ -531,32 +534,47 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
 
     1. lays its routed rows out **by expert**, over all ``E`` — a held plan
        of its own rows (``make_held_group_plan``, ``dispatch_held_rows``) —
-       which is by chip too; learns from one small all-gather how many rows
-       every chip has for every expert, and from that table, by cumulative
-       sums, every chip's layout (``mappings.make_exchange_sizes``), its
-       own receive plan among them (``make_counted_group_plan``: no sort,
-       no look-up);
+       which is by chip too, and their gates the same way, a float32 a row
+       and 0 on a padding row (``scatter_to_groups``); learns from one
+       small all-gather how many rows every chip has for every expert, and
+       from that table, by cumulative sums, every chip's layout
+       (``mappings.make_exchange_sizes``), its own receive plan among them
+       (``make_counted_group_plan``: no sort, no look-up);
     2. ``exchange/exchange_send``: one all-to-all of the rows
        (``lax.ragged_all_to_all``: the rows there are and no padding), one
        slice a (chip, expert): a slice lands inside its expert's group of
        the receiver's plan, behind those of the senders before, so what
-       arrives IS the group-padded array the kernels read.  A chip has room
-       for a stated bound of rows from all chips together:
-       ``held_rows_factor`` times what it is sent under even routing
+       arrives IS the group-padded array the kernels read; and a second,
+       narrow one, of the gates through the same sizes: ``[rows, 128]``
+       float32 (:data:`_GATE_LANES`: a ``[rows, 1]`` array travels as
+       wide, and is re-laid on both sides of its call: 1.9 ms a call on a
+       v5e), a row of the receive plan each.  A chip has room for a
+       stated bound of rows from all chips together: ``held_rows_factor``
+       times what it is sent under even routing
        (``grouped_gemm.held_rows_bound``) — not a bound on what one chip
        sends another: one sender's skew uses the room the others leave.  A
        row that finds no room is counted (:data:`ROWS_OVER_BOUND`), never
        silently lost: the room goes to the senders in their order, and a
        pair's last rows — those of its highest experts — are the ones cut;
     3. runs the grouped kernels over what it received, and nothing else:
-       the receiving chip sorts, gathers and sums no row;
+       the receiving chip sorts, gathers and sums no row.  **The gate is
+       applied here, where the experts are**: the live-prefix pass between
+       the two halves forms ``gate · act(...)`` in float32 and rounds once
+       (:func:`_glu`), so the output product is of weighted rows;
     4. ``exchange/exchange_return``: one all-to-all of the results, slice
        for slice, each row to the place it came from;
-    5. weights each row by its gate and sums a token's rows, once, in
-       float32 (``combine_held_rows`` over its own plan: ``ds_rowsum``).
+    5. sums a token's rows, once, in float32 (``sum_held_rows`` over its
+       own plan: ``ds_rowsum`` with no gates).
 
-    Backward, every step is its own transpose by hand (an all-to-all's is
-    the all-to-all back): two all-to-alls of rows a pass.  The expert
+    Backward, every step is its own transpose (an all-to-all's is the
+    all-to-all back; the sum's is step 1's gather): two all-to-alls of rows
+    and one of gates' cotangents a pass.  No row that came back is a
+    residual of anything — a gate's cotangent is the row sum of ``dh · act``
+    in the pass that forms the halves' cotangents, on the expert's chip, and
+    travels home through the narrow exchange's transpose — so a
+    rematerialised layer's recompute ends at the activation: no output
+    product, no return, no sum.  Five all-to-alls of rows a layer-pass
+    (forward 2, recompute 1, backward 2) and three of gates.  The expert
     weights come in as this chip's ``[E / n, ...]`` slices and their
     gradients leave so — reduced over no chip of the ``expert`` axis.
     Returns as :func:`_held_grouped_moe` does, the counts summed over the
@@ -609,30 +627,47 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             "path": mappings.exchange_path(),
             # a (chip, expert) is one slice of an all-to-all, and lands in
             # its expert's group of the receiver's plan
-            "slices_per_pair": held, "receive_layout": "grouped"}})
+            "slices_per_pair": held, "receive_layout": "grouped",
+            # what a rematerialised layer runs of them: the recompute
+            # needs the rows it received and their gates, and nothing
+            # that came back
+            "row_calls_per_pass": {"forward": 2, "recompute": 1,
+                                   "backward": 2},
+            "gate_calls_per_pass": {"forward": 1, "recompute": 1,
+                                    "backward": 1}}})
     weights = {name: params[name] for name in ("w_gate", "w_in", "w_out")
                if name in params}
 
     def on_chip(xt, eids, gates, weights):
         with jax.named_scope(SCOPE_DISPATCH):
             # R rows hold every row a chip routes: nothing is over here
-            mine, _ = gg.make_held_group_plan(eids.reshape(-1), 0, E, R)
+            mine, _ = gg.make_held_group_plan(eids.reshape(-1), 0, E, R,
+                                              row_to_padded=True)
             sizes = mappings.make_exchange_sizes(mine.counts, R, bound)
             plan, over = gg.make_counted_group_plan(sizes.counts, bound)
             buf = gg.dispatch_held_rows(xt, mine, k)        # [R + E bm, D]
+            # a gate a row, as wide as the narrowest row the chip moves
+            # whole (a ``[rows, 1]`` array is padded to it anyway, and then
+            # re-laid on either side of its all-to-all)
+            gate_buf = jnp.broadcast_to(gg.scatter_to_groups(
+                gates.reshape(-1).astype(jnp.float32), mine)[:, None],
+                (mine.padded_rows, _GATE_LANES))
         with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
             x_pad = mappings.exchange_forth(buf, sizes, plan.padded_rows)
+            row_gate = mappings.exchange_forth(gate_buf, sizes,
+                                               plan.padded_rows)
         _emit_held_plan(plan)
         _emit_exchanged(jnp.sum(sizes.send), jnp.sum(sizes.held))
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
             h = _glu(mm, x_pad, weights.get("w_gate"), weights["w_in"],
-                     config, plan)
+                     config, plan, row_gate)
             y = mm(h, weights["w_out"])                     # [Mp, D]
         with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_RETURN):
             back = mappings.exchange_back(y, sizes, mine.padded_rows)
         with jax.named_scope(SCOPE_COMBINE):
-            combined = gg.combine_held_rows(back, gates.reshape(-1), mine, k)
+            combined = _after(gg.sum_held_rows(back, mine, k),
+                              jax.lax.stop_gradient(h[0, 0]))
         dropped = sizes.over + over
         return combined, jnp.stack([jnp.int32(R) - sizes.over, dropped])[None]
 
@@ -689,11 +724,54 @@ def _silu_glu(gate, up):
     return jax.nn.silu(gate) * up
 
 
-def _glu(mm, x, w_gate, w_in, config: MoEConfig, plan=None):
+#: lanes of the float32 array that carries a routed row's gate to its
+#: expert's chip: a row of 128 float32 is the narrowest the chip's
+#: all-to-all moves as it lies
+_GATE_LANES = 128
+
+
+def _row_weighted(fn):
+    """``fn`` of a plan's rows in float32, each row times its weight (lane
+    0 of the last operand, ``[rows, lanes]`` float32), rounded once to the
+    rows' dtype."""
+    def weighted(*operands):
+        *xs, weight = operands
+        h = fn(*(x.astype(jnp.float32) for x in xs))
+        return (weight[:, :1] * h).astype(xs[0].dtype)
+    return weighted
+
+
+@jax.custom_vjp
+def _after(x, token):
+    """``x``, whose cotangent waits for ``token`` — a value of the
+    rematerialised forward, here an element of the activation.  Nothing of
+    the exchanged layer's backward pass depends on its recompute any more,
+    and a scheduler left free starts it first: the ``[Mp, D]`` cotangent
+    of the returned rows is then live all through the recompute's own
+    exchange (the described compile of the cell's loss and gradient:
+    1.2 GiB more; the step on the chips: 0.32).  The compiler expands an
+    ``optimization_barrier`` before it schedules, so the wait is
+    arithmetic it cannot fold: the cotangent plus zero times the token,
+    made finite first."""
+    return x
+
+
+def _after_bwd(token, g):
+    wait = 0.0 * jnp.clip(jnp.nan_to_num(token.astype(jnp.float32)), -1, 1)
+    return g + wait.astype(g.dtype), jnp.zeros_like(token)
+
+
+_after.defvjp(lambda x, token: (x, token), _after_bwd)
+
+
+def _glu(mm, x, w_gate, w_in, config: MoEConfig, plan=None, row_gate=None):
     """The experts' first half over the plan's rows ``x``.  Given a held
     ``plan``, what XLA does between the grouped calls — the activation,
     and in the backward pass the sum of the two cotangents of ``x`` —
-    walks the plan's live prefix as they do."""
+    walks the plan's live prefix as they do; given a ``row_gate`` too
+    (``[Mp, lanes]`` float32, lane 0 a routed row's gate where its expert
+    is: :func:`_exchanged_grouped_moe`) the same pass weights each row by
+    it, and its backward forms the gates' cotangent beside the halves'."""
     if plan is None:
         if config.activation == "silu_glu":
             return jax.nn.silu(mm(x, w_gate)) * mm(x, w_in)
@@ -701,10 +779,12 @@ def _glu(mm, x, w_gate, w_in, config: MoEConfig, plan=None):
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     if config.activation == "silu_glu":
         x_gate, x_in = gg.fan_out_live_rows(x, plan, 2)
-        return gg.map_live_rows(_silu_glu, plan, mm(x_gate, w_gate),
-                                mm(x_in, w_in))
-    return gg.map_live_rows(partial(_ungated, config=config), plan,
-                            mm(x, w_in))
+        fn, halves = _silu_glu, (mm(x_gate, w_gate), mm(x_in, w_in))
+    else:
+        fn, halves = partial(_ungated, config=config), (mm(x, w_in),)
+    if row_gate is None:
+        return gg.map_live_rows(fn, plan, *halves)
+    return gg.map_live_rows(_row_weighted(fn), plan, *halves, row_gate)
 
 
 def gg_kernel_real() -> bool:

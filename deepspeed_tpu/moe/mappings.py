@@ -26,7 +26,16 @@ layouts of every chip (:func:`make_exchange_sizes`), and the all-to-all
 itself moves one slice a (chip, expert) (:func:`exchange_forth`,
 :func:`exchange_back`: ``lax.ragged_all_to_all`` — a chip puts on the wire
 the rows it has, and what it receives from all chips shares one buffer, so
-the bound is on a chip's rows and not on a pair's).
+the bound is on a chip's rows and not on a pair's).  A "row" is whatever
+lies behind the first axis: the layer sends its activations ``[rows, D]``
+and, through the same sizes, their gates, a few float32 lanes a row — the
+narrow exchange, so that a routed row is weighted where its expert is and
+the way back carries rows its sender only sums.  Each direction is the
+other's transpose (``custom_vjp``), for rows and gates alike: a gate's
+cotangent, formed on the expert's chip, comes home by
+:func:`exchange_back`'s movement.  What a rematerialised layer runs of
+them: forward out, out (gates) and back; recompute out and out (gates) —
+nothing that came back is a residual; backward the three transposes.
 """
 import functools
 from typing import NamedTuple

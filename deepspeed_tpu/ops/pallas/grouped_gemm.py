@@ -375,7 +375,8 @@ def held_rows_bound(routed_rows: int, experts_held: int, num_experts: int,
 
 def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
                          experts_held: int, bound_rows: int,
-                         block_m: Optional[int] = None):
+                         block_m: Optional[int] = None,
+                         row_to_padded: bool = False):
     """``expert_ids`` [R] over ALL experts -> (GroupPlan over the
     ``experts_held`` experts from ``expert_offset`` on, rows over the
     bound [] int32).  The padded row count is ``bound_rows + held * bm``
@@ -389,8 +390,12 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     rows into tokens (:func:`combine_held_rows`), R being mostly rows held
     elsewhere, a block of tokens at a time: ``group_of_element`` is what
     that way reads of the plan beside ``padded_to_row`` (forward, recompute
-    and backward share both).  It is ``live_only``: see the section's
-    head."""
+    and backward share both).  Asked for (``row_to_padded``: a sender's
+    plan of its own rows over all experts, whose per-row scalars go out by
+    :func:`scatter_to_groups` and whose cotangents come home by a gather),
+    it has one all the same — the plan's sort turned round by a second of
+    the same ``R`` elements, ``padded_rows`` (out of range) for an element
+    that has no row here.  It is ``live_only``: see the section's head."""
     R = int(expert_ids.shape[0])
     E = int(experts_held)
     bm = int(block_m or default_block_m())
@@ -408,21 +413,34 @@ def make_held_group_plan(expert_ids: jnp.ndarray, expert_offset: int,
     group_sizes = blocks_e * bm
     kept = jnp.minimum(counts, group_sizes)
     over = jnp.sum(counts - kept).astype(jnp.int32)
-    _, by_expert = jax.lax.sort((key, jnp.arange(R, dtype=jnp.int32)),
-                                num_keys=1, is_stable=True)
+    element = jnp.arange(R, dtype=jnp.int32)
+    by_key, by_expert = jax.lax.sort((key, element), num_keys=1,
+                                     is_stable=True)
     bidx = jnp.arange(num_blocks, dtype=jnp.int32)
     gids = _tile_group_ids(bidx, cum_blocks)
     # padded row p of expert g is its ``p - group_start[g]``-th routed row
     # where it has that many (a tile past the last group reads beyond)
     first = jnp.cumsum(counts) - counts                # in ``by_expert``
     group_start = (cum_blocks - blocks_e) * bm
+    to_padded = None
+    if row_to_padded:
+        # the same the other way: the j-th element in expert order lies
+        # ``j - first`` rows into its expert's group, where the group keeps
+        # that many (the experts' tables by a comparison and a sum: a
+        # look-up of single int32s costs by the element)
+        of = by_key[:, None] == experts[None, :]       # [R, E]
+        pick = lambda table: jnp.sum(                  # noqa: E731
+            jnp.where(of, table[None, :], 0), axis=1)
+        place = jnp.where(element < pick(first + kept),
+                          element + pick(group_start - first), padded_rows)
+        _, to_padded = jax.lax.sort((by_expert, place), num_keys=1)
     within = (bidx * bm - group_start[gids])[:, None] \
         + jnp.arange(bm, dtype=jnp.int32)[None, :]     # [num_blocks, bm]
     source = jnp.where(within < kept[gids][:, None],
                        first[gids][:, None] + within, R).reshape(padded_rows)
     plan = GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
-                     cum_blocks[-1:].astype(jnp.int32), None, None, counts,
-                     live_only=True)
+                     cum_blocks[-1:].astype(jnp.int32), to_padded, None,
+                     counts, live_only=True)
     # ``by_expert[source]`` over the live prefix alone (a gather of single
     # int32s costs by the element, not by the byte: chunks as for rows a
     # tile's int32s wide); a row behind the prefix is a padding row
@@ -899,6 +917,33 @@ def combine_held_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
     chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
     return _combine_held(y, gates, _way_back(plan), live_rows(plan), top_k,
                          chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sum_held(y, back, live, top_k, chunk):
+    return _sum_live_into_tokens(y, None, back, back.group_of_element.shape[0]
+                                 // top_k, top_k, live, chunk)
+
+
+# :func:`_dispatch_held`'s transpose, and that its own: the cotangent of a
+# token's sum is every one of its rows', so nothing of ``y`` is kept
+_sum_held.defvjp(
+    lambda y, back, live, top_k, chunk: (
+        _sum_held(y, back, live, top_k, chunk), (back, live)),
+    lambda top_k, chunk, res, g: (
+        _dispatch_held(g, *res, top_k, chunk), None, None))
+
+
+def sum_held_rows(y: jnp.ndarray, plan: GroupPlan, top_k: int):
+    """The way back of rows that carry their weight already (an exchange's
+    returned rows, gated where their experts are: moe/layer.py
+    ``_exchanged_grouped_moe``): the live prefix of ``y`` [Mp, D] summed
+    into its tokens -> [T, D], one float32 accumulator a token, as
+    :func:`combine_held_rows` with every gate 1.  Backward:
+    :func:`dispatch_held_rows`' forward over the tokens' cotangents — a
+    gather, and no row of ``y`` is a residual."""
+    chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
+    return _sum_held(y, _way_back(plan), live_rows(plan), top_k, chunk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

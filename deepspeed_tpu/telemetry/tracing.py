@@ -761,10 +761,18 @@ def exchange_calls(name: str = TRAIN_STEP_PROGRAM):
     the rows there are, no padding — and ``"all_to_all"`` of whole buffers
     where the backend has no ragged one), ``slices_per_pair`` (the slices
     of one all-to-all that go from one chip to another: one an expert the
-    receiver holds) and ``receive_layout`` (``"grouped"``: a slice lands
+    receiver holds), ``receive_layout`` (``"grouped"``: a slice lands
     inside its expert's group of the receiver's plan, so what arrives is
-    the array the grouped kernels read).  None where the step has no
-    exchange."""
+    the array the grouped kernels read), and what a layer and micro-batch
+    runs of them by phase where the layer is rematerialised:
+    ``row_calls_per_pass`` (``{"forward": 2, "recompute": 1, "backward":
+    2}`` — all-to-alls of ``width``-wide rows; the recompute has no return:
+    a row is weighted by its gate on its expert's chip, so no row that
+    came back is a residual) and ``gate_calls_per_pass`` (one a phase: the
+    gates out beside the rows, ``[rows, 1]`` float32, and their cotangent
+    home).  Statements of the layer's code, held to the compiled text by
+    tests/test_chip_compile.py and tests/test_moe_exchange.py — not counts
+    read off an executable.  None where the step has no exchange."""
     return _account_rows(name, "exchange_calls")
 
 

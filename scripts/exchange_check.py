@@ -5,10 +5,14 @@ layer at a cell's widths through ``moe/layer.py _exchanged_grouped_moe``
 all-to-alls there are ``lax.all_to_all`` of whole segments) against the
 layer written plainly in float32 on the same devices: every token through
 every expert, weight 0 where it was not chosen.  Output, ``dx``, every
-``dw`` and the router's gradient as ``|a - b|_2 / |b|_2``, beside the
-plain layer with its products' operands rounded to bf16 against itself
-(the noise a bf16 program cannot be under).  A cotangent that came back to
-the wrong place, or an expert's gradient summed over chips, reads near 1.
+``dw``, the router's gradient and the gates' own (``gates``: the gradient
+by a ``[tokens, experts]`` array of zeros added to the chosen weights —
+in the exchanged layer what the experts' chips form from ``dh`` and the
+activation and send home through the narrow exchange's transpose) as
+``|a - b|_2 / |b|_2``, beside the plain layer with its products' operands
+rounded to bf16 against itself (the noise a bf16 program cannot be under).
+A cotangent that came back to the wrong place, or an expert's gradient
+summed over chips, reads near 1.
 
     chiprun --chips 4 -- python scripts/exchange_check.py --seed <n>
 
@@ -33,13 +37,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from deepspeed_tpu.comm.mesh import MeshTopology, set_topology   # noqa: E402
+from deepspeed_tpu.moe import layer as moe_layer_module          # noqa: E402
 from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,  # noqa: E402
                                      moe_layer, moe_logical_specs)
 
 
-def plain_layer(params, x, config, matmul_dtype=None):
+def plain_layer(params, x, nudge, config, matmul_dtype=None):
     """[B, S, D] -> [B, S, D], float32: softmax over all experts, the
-    ``top_k`` largest renormalised, every token through every expert."""
+    ``top_k`` largest renormalised (plus ``nudge`` [B, S, E] where
+    chosen), every token through every expert."""
     f32 = lambda a: a.astype(jnp.float32)
     mm = jnp.matmul if matmul_dtype is None else (
         lambda a, b: jnp.matmul(f32(a.astype(matmul_dtype)),
@@ -47,8 +53,10 @@ def plain_layer(params, x, config, matmul_dtype=None):
     m = f32(x).reshape(-1, x.shape[-1])
     probs = jax.nn.softmax(mm(m, f32(params["router"])), axis=-1)
     _, chosen = jax.lax.top_k(probs, config.top_k)
-    picked = probs * jax.nn.one_hot(chosen, config.num_experts).sum(1)
-    weights = picked / picked.sum(-1, keepdims=True)
+    sent = jax.nn.one_hot(chosen, config.num_experts).sum(1)
+    picked = probs * sent
+    weights = picked / picked.sum(-1, keepdims=True) \
+        + sent * f32(nudge).reshape(sent.shape)
 
     def one_expert(out, expert):
         w_gate, w_in, w_out, weight = expert
@@ -61,9 +69,28 @@ def plain_layer(params, x, config, matmul_dtype=None):
     return out.reshape(x.shape)
 
 
+def exchanged_layer(params, x, nudge, config):
+    """The library's layer with ``nudge`` added to the gates its router
+    chose, between the routing and the dispatch."""
+    route = moe_layer_module._route
+
+    def nudged(*args, **kwargs):
+        routing = route(*args, **kwargs)
+        return routing._replace(gate_weights=routing.gate_weights
+                                + jnp.take_along_axis(
+                                    nudge.reshape(-1, nudge.shape[-1]),
+                                    routing.expert_idx, axis=1))
+
+    moe_layer_module._route = nudged
+    try:
+        return moe_layer(params, x, config, train=True)[0]
+    finally:
+        moe_layer_module._route = route
+
+
 def weighted(layer):
-    def loss(params, x):
-        out = layer(params, x)
+    def loss(params, x, nudge):
+        out = layer(params, x, nudge)
         w = jnp.cos(jnp.arange(out.shape[-1], dtype=jnp.float32))
         return jnp.sum(out.astype(jnp.float32) * w) / out.shape[0], out
     return loss
@@ -106,26 +133,25 @@ def main():
         x = jax.device_put(jax.random.normal(
             keys[1], (len(devices), args.tokens, D), jnp.bfloat16), rows)
 
-        def exchanged(params, x):
-            out, _, stats = moe_layer(params, x, config, train=True,
-                                      return_stats=True)
-            return out
-
+        nudge = jax.device_put(jnp.zeros(x.shape[:2] + (E,), jnp.float32),
+                               rows)
         grad = lambda layer: jax.jit(jax.value_and_grad(
-            weighted(layer), argnums=(0, 1), has_aux=True))
+            weighted(layer), argnums=(0, 1, 2), has_aux=True))
         with jax.default_matmul_precision("highest"):
-            (_, want), (dw_want, dx_want) = grad(
-                lambda p, x: plain_layer(p, x, config))(params, x)
-            (_, low), (dw_low, dx_low) = grad(
-                lambda p, x: plain_layer(p, x, config, jnp.bfloat16))(
-                    params, x)
-        compiled = grad(exchanged).lower(params, x).compile()
-        (_, got), (dw_got, dx_got) = compiled(params, x)
+            (_, want), (dw_want, dx_want, dg_want) = grad(
+                lambda p, x, n: plain_layer(p, x, n, config))(
+                    params, x, nudge)
+            (_, low), (dw_low, dx_low, dg_low) = grad(
+                lambda p, x, n: plain_layer(p, x, n, config, jnp.bfloat16))(
+                    params, x, nudge)
+        compiled = grad(lambda p, x, n: exchanged_layer(
+            p, x, n, config)).lower(params, x, nudge).compile()
+        (_, got), (dw_got, dx_got, dg_got) = compiled(params, x, nudge)
         text = compiled.as_text()
         took = []
         for _ in range(args.timed):
             start = time.perf_counter()
-            jax.block_until_ready(compiled(params, x))
+            jax.block_until_ready(compiled(params, x, nudge))
             took.append(1e3 * (time.perf_counter() - start))
         line = {"seed": seed, "device": devices[0].device_kind,
                 "chips": len(devices), "tokens_per_chip": args.tokens,
@@ -136,6 +162,7 @@ def main():
                 "layer_ms": statistics.median(took) if took else None,
                 "leaves": {}}
         pairs = {"out": (got, low, want), "dx": (dx_got, dx_low, dx_want),
+                 "gates": (dg_got, dg_low, dg_want),
                  **{name: (dw_got[name], dw_low[name], dw_want[name])
                     for name in sorted(dw_want)}}
         for name, (a, c, b) in pairs.items():

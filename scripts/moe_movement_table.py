@@ -19,7 +19,10 @@ with the same code.  Standard output: the cell's own lines, then the table
 (ms per optimizer step, executions per step, phase, the tail of the
 instruction's op_name, result shape), the sum by phase, and every
 ``scatter`` instruction of the executable's text — fused computations
-included — that sits under one of the two scopes.
+included — that sits under one of the two scopes; where the cell
+exchanges its rows, the same table of every instruction under
+``/mlp/shard_map/exchange/`` (a call's collective beside the copies and
+fills around it: ``exchange_rows`` of ``--out``).
 """
 import argparse
 import json
@@ -30,6 +33,9 @@ import sys
 from collections import defaultdict
 
 SCOPE = re.compile(r"/mlp/(?:shard_map/)?(dispatch|combine)/")
+#: the expert-parallel exchange's own scope: its collectives, and the
+#: re-tiling copies and zero fills the compiler puts around each
+EXCHANGE = re.compile(r"/mlp/(?:shard_map/)?exchange/")
 STEP = {"program": "train/step", "module": r"^jit_train_step\("}
 _SCATTER = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) scatter\(.*"
                       r'op_name="([^"]*)"')
@@ -120,18 +126,26 @@ def traced_cell(args):
             tr, step_phase, texts[-1] if texts else None)
 
 
-def main():
-    args = cell_arguments(__doc__, "olmoe-1b-7b.packed-s4096-gas8")
-    dev, table, tr, step_phase, text = traced_cell(args)
-    steps, rows = scope_rows(dev, table, tr, step_phase)
+def print_rows(rows):
+    """The table; -> ms a step by phase and the scope's first name."""
     sums = defaultdict(float)
     for r in rows:
         sums[f'{r["phase"]}/{r["op"].split("/", 1)[0]}'] += r["ms_per_step"]
         print(f'{r["phase"]:9s} {r["ms_per_step"]:9.3f} ms '
               f'{r["calls_per_step"]:6.1f}x  {r["instruction"]:28s} '
               f'{r["shape"]:32s} {r["op"][-110:]}')
-    sums = dict(sorted(sums.items()), all=sum(sums.values()))
+    return dict(sorted(sums.items()), all=sum(sums.values()))
+
+
+def main():
+    args = cell_arguments(__doc__, "olmoe-1b-7b.packed-s4096-gas8")
+    dev, table, tr, step_phase, text = traced_cell(args)
+    steps, rows = scope_rows(dev, table, tr, step_phase)
+    sums = print_rows(rows)
     print(json.dumps({"steps_traced": steps, "ms_per_step": sums}))
+    _, exchange_rows = scope_rows(dev, table, tr, step_phase, scope=EXCHANGE)
+    if exchange_rows:
+        print(json.dumps({"exchange_ms_per_step": print_rows(exchange_rows)}))
     scatters = scatters_in(text) if text else None
     print(json.dumps({"scatters_under_dispatch_or_combine":
                       None if scatters is None else len(scatters),
@@ -140,8 +154,8 @@ def main():
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
             json.dump({"rows": rows, "ms_per_step": sums,
-                       "steps_traced": steps, "scatters": scatters}, f,
-                      indent=1)
+                       "steps_traced": steps, "scatters": scatters,
+                       "exchange_rows": exchange_rows}, f, indent=1)
 
 
 if __name__ == "__main__":

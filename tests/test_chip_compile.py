@@ -629,6 +629,123 @@ def test_data_sharded_flash_grad_partitions(v5e, manual_outside):
     assert all(f"bf16[{B},{H},{S},{HD}]" in l for l in calls), calls[0][:200]
 
 
+def test_a_rematerialised_exchange_returns_no_rows_on_a_v5e(v5e, monkeypatch):
+    """mellum2-12b-a2.5b-ep4's expert layer over the four described chips
+    (8,192 tokens a chip, 64 experts, top 8, a bound of three times the
+    even share), its gradient under ``jax.checkpoint`` as the model's
+    rematerialised block runs it: the gate is applied where the experts
+    are, so no row that came back is a residual and the recompute ends at
+    the activation.  Counted in the compiled text: **five** row-wide
+    ``ragged-all-to-all`` (forward 2, recompute 1, backward 2 — the
+    parent's six), three narrow ones (the gates out in forward and
+    recompute, their cotangent home), eleven grouped Mosaic calls (three
+    forward, two in the recompute, six backward — the parent's twelve) and
+    two ``ds_rowsum`` (the forward's sum, the dispatch's transpose): a
+    later edit that keeps a returned row brings the sixth back, and fails
+    here."""
+    import math
+    import re
+    from deepspeed_tpu.comm.mesh import MeshTopology, set_topology
+    from deepspeed_tpu.moe import mappings
+    from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
+                                         moe_layer, moe_logical_specs)
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    from deepspeed_tpu.telemetry import tracing
+    monkeypatch.setattr(gg.vmem, "device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    monkeypatch.setattr(gg, "_use_reference",
+                        lambda interpret: (False, False))
+    monkeypatch.setattr(mappings, "exchange_path",
+                        lambda: mappings.RAGGED_ALL_TO_ALL)
+    D = 2304
+    config = MoEConfig(d_model=D, d_ff=896, num_experts=64, top_k=8,
+                       dispatch_mode="grouped", held_rows_factor=3)
+    topo = MeshTopology(devices=v5e, expert_parallel_size=4)
+    set_topology(topo)
+    params = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim == 3 else a.dtype,
+            sharding=NamedSharding(topo.mesh, spec)),
+        jax.eval_shape(lambda: init_moe_params(config,
+                                               jax.random.PRNGKey(0))),
+        moe_logical_specs(config))
+    x = jax.ShapeDtypeStruct((4, 8192, D), jnp.bfloat16,
+                             sharding=NamedSharding(topo.mesh, P("expert")))
+
+    @jax.checkpoint
+    def block(params, x):
+        out, aux = moe_layer(params, x, config, train=True)
+        return x + out, aux
+
+    def loss(params, x):
+        out, aux = block(params, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+    try:
+        text = jax.jit(jax.grad(loss, (0, 1))).lower(
+            params, x).compile().as_text()
+    finally:
+        from deepspeed_tpu.comm.mesh import reset_topology
+        reset_topology()
+    # (the compiler re-tiles a row: bf16[rows, 2, 1152], f32[rows, 1, 128])
+    exchanged = [(dtype, math.prod(map(int, dims.split(",")[1:])))
+                 for dtype, dims in re.findall(
+                     r"= (\w+)\[([\d,]+)\]\S* ragged-all-to-all\(", text)]
+    assert sorted(exchanged) == 5 * [("bf16", D)] + 3 * [("f32", 128)], \
+        exchanged
+    kernels = [row["kernel"] for row in
+               tracing.parse_program_text(text).values() if row["kernel"]]
+    grouped = [k for k in kernels if k.startswith("ds_ggemm")]
+    assert len(grouped) == 11, sorted(kernels)
+    assert kernels.count("ds_rowsum") == 2, sorted(kernels)
+
+
+def test_the_exchanged_layers_backward_waits_for_its_recompute(v5e,
+                                                               monkeypatch):
+    """mellum2-12b-a2.5b-ep4's loss and gradient (four layers, all 64
+    experts over the four described chips, one micro-batch): no row that
+    came back is a residual, so nothing of an expert layer's backward pass
+    depends on its recompute, and a scheduler left free runs the
+    cotangents' exchange first and keeps ``dy`` — ``[Mp, D]``, 0.87 GiB —
+    live through the recompute's own: temporaries 6,051 MiB by this count
+    (the step's peak on the chips + 2.6%, PERF §6 PR 51).  ``moe/layer.py
+    _after`` ties the backward to the recompute's end by arithmetic the
+    compiler cannot fold: 5,023 MiB (the parent's program 4,847).  A
+    compiler that learns to fold the tie, or an edit that drops it, fails
+    here and nowhere else."""
+    from deepspeed_tpu.comm.mesh import (MeshTopology, reset_topology,
+                                         set_topology)
+    from deepspeed_tpu.models.mellum import mellum_model
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import vmem
+    monkeypatch.setattr(vmem, "device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    topo = MeshTopology(devices=v5e, expert_parallel_size=4)
+    set_topology(topo)
+    try:
+        model = mellum_model(size="12b-a2.5b", num_layers=4,
+                             dtype="bfloat16", remat=True,
+                             held_rows_factor=3)
+        params = jax.tree.map(
+            lambda a, spec: jax.ShapeDtypeStruct(
+                a.shape, jnp.bfloat16 if a.ndim >= 2 else a.dtype,
+                sharding=NamedSharding(topo.mesh, spec or P())),
+            jax.eval_shape(model.init_fn, jax.random.PRNGKey(0)),
+            model.logical_specs)
+        tokens = jax.ShapeDtypeStruct(
+            (4, 8192), jnp.int32, sharding=NamedSharding(topo.mesh,
+                                                         P("expert")))
+        memory = jax.jit(jax.value_and_grad(
+            model.loss_with_counts_fn, has_aux=True)).lower(
+                params, {"input_ids": tokens, "segment_ids": tokens}
+            ).compile().memory_analysis()
+    finally:
+        reset_topology()
+    assert memory.temp_size_in_bytes < 5400 * 2 ** 20, \
+        memory.temp_size_in_bytes / 2 ** 20
+
+
 def test_library_knows_the_chips_peaks(v5e):
     from deepspeed_tpu.telemetry.mfu import peak_flops_per_device
     from deepspeed_tpu.telemetry.roofline import (hbm_bytes_per_s,

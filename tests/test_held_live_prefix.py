@@ -204,6 +204,122 @@ def test_the_activation_and_the_fan_out_over_the_prefix(load, chunk_bytes):
                                    np.asarray(d_want)[prefix], rtol=1e-6)
 
 
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_a_plan_asked_for_the_way_from_element_to_row(load):
+    """``make_held_group_plan(..., row_to_padded=True)``: every other field
+    is the plan's without it, ``row_to_padded`` is ``padded_to_row`` turned
+    round — ``padded_rows``, out of range, for an element held elsewhere
+    or over the bound — and a scalar a row goes out by
+    ``scatter_to_groups`` (0 on a padding row) with a gather for its
+    transpose."""
+    rng = np.random.default_rng(5)
+    R = T * K
+    eids = jnp.asarray(LOADS[load](rng, R, E_ALL, OFF, HELD), jnp.int32)
+    bound = gg.held_rows_bound(R, HELD, E_ALL, BM, factor=2)
+    plain, over = gg.make_held_group_plan(eids, OFF, HELD, bound, block_m=BM)
+    plan, over_too = gg.make_held_group_plan(eids, OFF, HELD, bound,
+                                             block_m=BM, row_to_padded=True)
+    assert plain.row_to_padded is None and int(over) == int(over_too)
+    for name, field in plain._asdict().items():
+        if name != "row_to_padded":
+            np.testing.assert_array_equal(getattr(plan, name), field, name)
+    to_row = np.asarray(plan.padded_to_row)
+    want = np.full(R, plan.padded_rows)
+    rows = np.flatnonzero(to_row < R)
+    want[to_row[rows]] = rows
+    np.testing.assert_array_equal(plan.row_to_padded, want)
+    held = np.isin(np.asarray(eids), np.arange(OFF, OFF + HELD))
+    assert (want < plan.padded_rows).sum() == held.sum() - int(over)
+    if load == "no_held_row" or int(over):
+        return
+    gates = jnp.asarray(rng.random(R), jnp.float32)
+    out, pull = jax.vjp(lambda g: gg.scatter_to_groups(g, plan), gates)
+    np.testing.assert_array_equal(
+        out, np.where(to_row < R, np.append(gates, 0)[to_row], 0))
+    g = jnp.asarray(rng.random(plan.padded_rows), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(pull(g)[0])[held],
+        np.asarray(g)[np.minimum(want, plan.padded_rows - 1)][held])
+
+
+@pytest.mark.parametrize("load", ["even_share", "twice",
+                                  "every_routed_row_held"])
+def test_the_sum_with_no_gates_and_its_transpose(load, chunk_bytes):
+    """``sum_held_rows`` is ``combine_held_rows`` with every gate 1, and
+    its transpose ``dispatch_held_rows``' forward over the tokens'
+    cotangents — bit for bit, and no row of ``y`` is kept for it."""
+    chunk_bytes(3 * BM * 32)
+    rng = np.random.default_rng(9)
+    plan, _ = _plan(load, 4, rng)
+    Mp, live = plan.padded_rows, int(gg.live_rows(plan))
+    prefix = (np.arange(Mp) < live)[:, None]
+    y = jnp.where(prefix, _bf16(rng, Mp, D), jnp.nan)
+    got, pull = jax.vjp(lambda y: gg.sum_held_rows(y, plan, K), y)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(gg.combine_held_rows(
+            y, jnp.ones((T * K,), jnp.float32), plan, K), np.float32))
+    g = _bf16(rng, T, D)
+    np.testing.assert_array_equal(
+        np.asarray(pull(g)[0], np.float32)[prefix[:, 0]],
+        np.asarray(gg.dispatch_held_rows(g, plan, K),
+                   np.float32)[prefix[:, 0]])
+    residuals = jax.make_jaxpr(
+        lambda y: jax.vjp(lambda y: gg.sum_held_rows(y, plan, K), y)[1])(y)
+    assert not any(v.aval.shape == (Mp, D) for v in residuals.jaxpr.outvars)
+
+
+@pytest.mark.parametrize("lanes", [1, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("load", ["even_share", "every_routed_row_held"])
+def test_a_weight_a_row_beside_the_two_halves(load, dtype, lanes,
+                                              chunk_bytes):
+    """``map_live_rows`` over two ``[Mp, F]`` arrays and a ``[Mp, 1]``
+    float32 one (and the same over 128 lanes, lane 0 the weight, as an
+    exchange delivers it) — a routed row's gate where its expert is
+    (moe/layer.py ``_glu``'s third operand) — against ``weight * silu(a) *
+    b`` written plainly in float32 and rounded once: forward over the live
+    prefix, and all three cotangents, the weight's — a row sum over ``F``,
+    in lane 0 alone — among them.  With every routed row held the prefix is longer than sixteen
+    chunks of three tiles and the plan shorter than seventeen: the last
+    chunk is pulled back over rows the sixteenth has done."""
+    chunk_bytes(3 * BM * max(D, lanes) * 4)
+    rng = np.random.default_rng(12)
+    plan, _ = _plan(load, 16, rng)
+    Mp, live = plan.padded_rows, int(gg.live_rows(plan))
+    chunk = 3 * BM
+    assert (-(-live // chunk) * chunk > Mp) \
+        == (load == "every_routed_row_held")
+    prefix = np.arange(Mp) < live
+    a, b, g = (jnp.asarray(rng.standard_normal((Mp, D)), dtype)
+               for _ in range(3))
+    w = jnp.asarray(rng.random((Mp, lanes)), jnp.float32)
+
+    def ours(a, b, w):
+        return gg.map_live_rows(moe._row_weighted(moe._silu_glu), plan,
+                                a, b, w)
+
+    def one_pass(a, b, w):
+        f32 = lambda x: x.astype(jnp.float32)            # noqa: E731
+        return (w[:, :1] * (jax.nn.silu(f32(a)) * f32(b))).astype(dtype)
+
+    got, pull = jax.vjp(ours, a, b, w)
+    want, pull_want = jax.vjp(one_pass, a, b, w)
+    assert got.dtype == dtype and got.shape == (Mp, D)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[prefix],
+                                  np.asarray(want, np.float32)[prefix])
+    got_d = pull(jnp.where(prefix[:, None], g, jnp.nan))
+    want_d = pull_want(g)
+    assert [d.shape for d in got_d] == [(Mp, D), (Mp, D), (Mp, lanes)]
+    assert [d.dtype for d in got_d] == [dtype, dtype, jnp.float32]
+    for d, d_want in zip(got_d, want_d):
+        d, d_want = (np.asarray(x, np.float32)[prefix] for x in (d, d_want))
+        assert np.isfinite(d).all()
+        np.testing.assert_allclose(
+            d, d_want, rtol=1e-5 if dtype == jnp.float32 else 2.0 ** -7,
+            atol=1e-6)
+
+
 @pytest.mark.parametrize("blocks", [None, (8, 128)],
                          ids=["resident", "streamed"])
 @pytest.mark.parametrize("transpose_rhs", [False, True], ids=["fwd", "dx"])

@@ -6,8 +6,12 @@ the router's gradient; the guide's sum-of-shares test (the four held
 shares' partial results, each from ``experts_held`` / ``expert_offset``
 alone with no exchange, add up to the uncut layer written plainly, and so
 does the exchanged layer); a planted skew that passes a bound is counted,
-not dropped silently; two all-to-alls of rows a pass and no capacity
-einsum in the compiled text; a one-wide ``expert`` axis never reaches the
+not dropped silently, and the rows that were kept give every gradient
+theirs; the gates' own gradient — formed where the experts are, sent home
+by the narrow exchange's transpose — against ``<dcombined, y>`` written
+plainly; two all-to-alls of rows and one of gates a pass, five and three
+under rematerialisation, and no capacity einsum in the compiled text; a
+one-wide ``expert`` axis never reaches the
 exchange; the table every chip derives its slices from, against the same
 written as loops; the receive buffer against the parent's (rows by sender,
 then sorted and gathered into the held plan); the device gate.
@@ -35,6 +39,8 @@ from deepspeed_tpu.telemetry import tracing
 
 D, F, E, K = 32, 16, 8, 2
 B, S = 8, 8
+#: float32 lanes of the array that carries the gates beside the rows
+LANES = moe_layer_module._GATE_LANES
 CONFIG = MoEConfig(d_model=D, d_ff=F, num_experts=E, top_k=K,
                    dispatch_mode="grouped", aux_loss_coef=1e-2,
                    load_balance="all_choices", held_rows_factor=4)
@@ -238,9 +244,10 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     """On a TPU the exchange is ``lax.ragged_all_to_all``; the CPU has no
     such collective and moves the same rows by ``lax.all_to_all``
     (``mappings._ragged``).  Counted here at the call: forward two of rows
-    (out of the sender's layout, back out of the receiver's), backward the
-    two cotangents' — and nothing of the capacity formulation in the
-    text."""
+    (out of the sender's layout, back out of the receiver's) and the gates
+    beside the rows out, a float32 a row over 128 lanes; backward the three
+    cotangents'
+    — and nothing of the capacity formulation in the text."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     calls = []
     ragged = mappings._ragged
@@ -261,13 +268,19 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     tile = gg.default_block_m()
     sent = -(-routed // tile) * tile + E * tile     # a plan of its own rows
     received = bound + E // 4 * tile                # and of those it holds
-    assert sorted(calls) == sorted(2 * [(sent, D), (received, D)]), calls
+    assert sorted(calls) == sorted(2 * [(sent, D), (received, D)]
+                                   + [(sent, LANES), (received, LANES)]), \
+        calls
     assert not re.search(rf"\[(?:{tokens}|{tokens // 4}),{E},\d+\]", text)
     (call,) = tracing.exchange_calls("toy")
     assert call["pairs"] == 4 and call["experts_held"] == 2
     assert call["tokens"] == tokens // 4 and call["routed_rows"] == routed
     assert call["receive_rows"] == bound
     assert call["wire_bytes"] == 3 * (routed // 4) * D * 4
+    assert call["row_calls_per_pass"] == {"forward": 2, "recompute": 1,
+                                          "backward": 2}
+    assert call["gate_calls_per_pass"] == {"forward": 1, "recompute": 1,
+                                           "backward": 1}
     # the account names the collective that was traced: here the stand-in
     assert call["path"] == mappings.exchange_path() == "all_to_all"
     table = tracing.parse_program_text(text)
@@ -279,6 +292,179 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     assert {row["collective"] for row in table.values()
             if "/exchange/" in (row["scope"] or "")
             and row["collective"]} == {"all-to-all"}
+
+
+def _output_alone(config):
+    """As :func:`weighted` without the router's loss: what the rows that
+    came back, and nothing else, give every leaf."""
+    def loss(params, x):
+        out, _, stats = moe_layer(params, x, config, train=True,
+                                  return_stats=True)
+        w = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+            out.shape)
+        return jnp.sum(out * w), (out, stats)
+    return loss
+
+
+def _unequal_gates():
+    """A router so sharp that a token's first gate is most of its weight."""
+    params, x = params_and_x(router_scale=60.0)
+    probs = jax.nn.softmax(x.reshape(-1, D) @ params["router"], axis=-1)
+    top = jax.lax.top_k(probs, K)[0]
+    assert float(jnp.mean(top[:, 0] / top.sum(-1))) > 0.8
+    want = on_one_device(CONFIG, params, x)
+    fn, args = four_wide(CONFIG, params, x)
+    return want, fn(*args), B * S * K, 0
+
+
+def _zero_gate_padding_tokens():
+    """Three tokens over four chips: the fourth chip's is a row of zeros
+    whose gates are zero, sent, multiplied and summed like any other."""
+    from deepspeed_tpu.comm.mesh import sharding_pin_scope
+    params, whole = params_and_x()
+    x = whole[:1, :3]
+    want = on_one_device(CONFIG, params, x)
+    fn, (placed, _) = four_wide(CONFIG, params, whole)
+    with sharding_pin_scope(False):
+        return want, fn(placed, x), 3 * K, 0
+
+
+def _rows_over_a_tight_bound():
+    """Every token chooses chip 0's two experts, the first with 0.62 of
+    its weight: at factor 1 chip 0 has room for its own 16 tokens' rows and
+    nobody else's.  The other 48 tokens' rows are counted, and add nothing
+    to the output or to any gradient — the gates' among them: what is
+    left is the plain layer over the first 16 tokens."""
+    config = replace(CONFIG, held_rows_factor=1, aux_loss_coef=0.0)
+    params, x = params_and_x(config)
+    params["router"] = jnp.zeros((D, E)).at[:, 0].set(0.05).at[:, 1].set(
+        0.03)
+    x = jnp.abs(x)
+    kept = (jnp.arange(B * S) < 16).reshape(B, S, 1)
+
+    def plainly(params, x):
+        out = plain_layer(params, x, config) * kept
+        w = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+            out.shape)
+        return jnp.sum(out * w), (out, {"dropped": 48 * K})
+
+    set_topology(MeshTopology(devices=jax.devices()[:1]))
+    want = jax.jit(jax.value_and_grad(plainly, argnums=(0, 1),
+                                      has_aux=True))(params, x)
+    fn, args = four_wide(config, params, x)
+    return want, jax.jit(jax.value_and_grad(
+        _output_alone(config), argnums=(0, 1), has_aux=True))(*args), \
+        16 * K, 48 * K
+
+
+@pytest.mark.parametrize("case", [_unequal_gates, _zero_gate_padding_tokens,
+                                  _rows_over_a_tight_bound],
+                         ids=lambda case: case.__name__.lstrip("_"))
+def test_every_gradient_with_the_gate_applied_at_the_experts(case,
+                                                             monkeypatch):
+    """The exchanged layer against the layer on one device (and, where
+    rows are cut, against the plain layer over the tokens that were kept):
+    output, counts, and every gradient by name — the router's, which
+    reaches it through the gates alone, and ``w_out``'s, whose product now
+    takes weighted rows."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    monkeypatch.setattr(gg, "default_block_m", lambda: 8)
+    ((want, (want_out, _)), want_grads), ((got, (out, stats)), grads), \
+        dispatched, dropped = host(case())
+    assert int(stats["dispatched"]) == dispatched
+    assert int(stats["dropped"]) == dropped
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    np.testing.assert_allclose(out, want_out, atol=2e-5 * np.abs(
+        want_out).max())
+    for name in ("router", "w_gate", "w_in", "w_out"):
+        scale = np.abs(want_grads[0][name]).max()
+        assert scale > 0, name
+        np.testing.assert_allclose(grads[0][name], want_grads[0][name],
+                                   atol=2e-5 * scale, err_msg=name)
+    np.testing.assert_allclose(grads[1], want_grads[1],
+                               atol=2e-5 * np.abs(want_grads[1]).max())
+
+
+def test_the_gates_gradient_is_the_returned_row_dot_its_tokens_cotangent(
+        monkeypatch):
+    """``dgates[t, j] = <dcombined[t], y[t, j]>`` with ``y`` the un-gated
+    output of token ``t``'s ``j``-th expert, written plainly in float32 —
+    against the exchanged layer's, which never forms that dot: the
+    expert's chip sums ``dh · silu(gate) · up`` over the 16 hidden columns
+    and the narrow exchange's transpose carries the number home.  Read as
+    the gradient by an array of zeros added to the chosen gates."""
+    params, x = params_and_x()
+    route = moe_layer_module._route
+
+    def loss(params, x, nudge):
+        def nudged(*args, **kwargs):
+            routing = route(*args, **kwargs)
+            return routing._replace(gate_weights=routing.gate_weights
+                                    + nudge.reshape(-1, K))
+        monkeypatch.setattr(moe_layer_module, "_route", nudged)
+        out = moe_layer(params, x, CONFIG, train=True)[0]
+        monkeypatch.setattr(moe_layer_module, "_route", route)
+        w = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+            out.shape)
+        return jnp.sum(out * w)
+
+    _, (placed, xs) = four_wide(CONFIG, params, x)
+    got = np.asarray(jax.jit(jax.grad(loss, argnums=2))(
+        placed, xs, jax.device_put(jnp.zeros((B, S, K)), xs.sharding))
+    ).reshape(-1, K)
+    h = x.reshape(-1, D)
+    chosen = np.asarray(route(params, h @ params["router"], CONFIG, True,
+                              None).expert_idx)
+    every = jnp.einsum(
+        "tef,efd->ted",
+        jax.nn.silu(jnp.einsum("td,edf->tef", h, params["w_gate"]))
+        * jnp.einsum("td,edf->tef", h, params["w_in"]), params["w_out"])
+    y = np.take_along_axis(np.asarray(every), chosen[:, :, None], axis=1)
+    dcombined = np.cos(np.arange(h.size, dtype=np.float32)).reshape(h.shape)
+    want = np.einsum("td,tkd->tk", dcombined, y)
+    assert np.abs(want).min() > 0
+    np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
+
+
+def test_a_rematerialised_layer_runs_five_row_exchanges_and_three_of_gates():
+    """The layer's gradient under ``jax.checkpoint`` as the CPU compiles
+    it, the exchange by its stand-in (a call of ``mappings._ragged`` is one
+    ``all-to-all`` of whole buffers, ``[1, rows, width]`` a chip, beside
+    two of int32 offsets): five of rows — forward 2, recompute 1, backward
+    2 — and three of gates.  The recompute returns nothing: no residual of
+    the backward pass is a row that came back.  (The same count in the
+    text a v5e's compiler writes: tests/test_chip_compile.py.)"""
+    params, x = params_and_x()
+    _, (placed, xs) = four_wide(CONFIG, params, x)
+
+    @jax.checkpoint
+    def block(params, x):
+        with jax.named_scope(tracing.SCOPE_BLOCK):
+            out, aux = moe_layer(params, x, CONFIG, train=True)
+        return x + out, aux
+
+    def loss(params, x):
+        out, aux = block(params, x)
+        return jnp.sum(out ** 2) + 2.0 * aux
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        placed, xs).compile().as_text()
+    widths = sorted(int(w) for w in re.findall(
+        r"= \(f32\[1,\d+,(\d+)\]\S*, .* all-to-all\(", text))
+    assert widths == 5 * [D] + 3 * [LANES], widths
+    phases = sorted(tracing.phase_of(op_name) for op_name in re.findall(
+        rf'= \(f32\[1,\d+,{D}\]\S*, .* all-to-all\(.*op_name="([^"]*)"', text))
+    assert phases == 2 * ["backward"] + 2 * ["forward"] + ["recompute"], \
+        phases
+
+
+def _ragged_widths(text):
+    """(element type, row width) of every ``ragged_all_to_all`` of a
+    lowered text, by its operand; rows first."""
+    found = re.findall(r"@ragged_all_to_all\(.*?: \(tensor<\d+x(\d+)x(\w+)>",
+                       text)
+    return sorted(((dtype, int(width)) for width, dtype in found),
+                  key=lambda found: -found[1])
 
 
 def _sorts(text):
@@ -302,19 +488,25 @@ def test_on_a_tpu_the_ragged_collective_is_traced_and_named(monkeypatch):
     assert call["path"] == "ragged_all_to_all"
     assert call["slices_per_pair"] == 2 == call["experts_held"]
     assert call["receive_layout"] == "grouped"
-    # forward: rows out, rows back; backward: the two cotangents' — and
-    # nothing carries the experts' numbers: the table says where rows land
-    assert text.count("ragged_all_to_all") == 4
+    # forward: rows out, rows back; backward: the two cotangents' — the
+    # gates out beside the rows, their cotangent home — and nothing
+    # carries the experts' numbers: the table says where rows land
+    assert sorted(_ragged_widths(text)) == sorted(
+        4 * [("f32", D)] + 2 * [("f32", LANES)])
     assert "stablehlo.all_to_all" not in text
 
 
 def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
     """mellum2-12b-a2.5b-ep4's expert layer as a TPU traces it (8,192
     tokens a chip, 64 experts over four chips, top 8, a bound of three
-    times the even share: 196,608 rows): four ``ragged_all_to_all`` a layer
-    (the parent's five: one carried the experts' numbers), 16 slices a
-    pair, and the one sort left is the sender's, of its own 65,536 routed
-    elements — the parent sorted the bound, twice."""
+    times the even share: 196,608 rows): of rows **three**
+    ``ragged_all_to_all`` a layer where there were four — the gradient
+    alone is asked for here, and no residual of the backward pass is a row
+    that came back any more, so the forward's return is dead code before
+    XLA sees it — and two of gates; 16 slices a pair; and the sorts are
+    the sender's, of its own 65,536 routed elements (the plan's, and the
+    same turned round for the gates' way home) — the receiver sorts
+    nothing."""
     monkeypatch.setattr(mappings, "exchange_path",
                         lambda: mappings.RAGGED_ALL_TO_ALL)
     config = MoEConfig(d_model=2304, d_ff=896, num_experts=64, top_k=8,
@@ -336,11 +528,12 @@ def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
     (call,) = tracing.exchange_calls("cell")
     assert call["receive_rows"] == 196608 and call["routed_rows"] == 65536
     assert call["slices_per_pair"] == 16
-    assert text.count("ragged_all_to_all") == 4
+    assert _ragged_widths(text) == 3 * [("bf16", 2304)] \
+        + 2 * [("f32", LANES)]
     # 64 slices a call: 16 for each of the four chips
     assert len(re.findall(r"ragged_all_to_all.*tensor<64xi32>, "
                           r"tensor<64xi32>, tensor<64xi32>, tensor<64xi32>",
-                          text)) == 4
+                          text)) == 5
     sorts = _sorts(text)
     assert sorts and all(
         re.fullmatch(r"tensor<65536xi32>(, tensor<65536xi32>)*", operands)
