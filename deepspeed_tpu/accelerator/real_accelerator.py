@@ -34,9 +34,12 @@ class Accelerator:
     def device_count(self) -> int:
         return len(self.devices())
 
+    def local_devices(self):
+        return [d for d in jax.local_devices()
+                if d.platform == self._platform] or jax.local_devices()
+
     def local_device_count(self) -> int:
-        return len([d for d in jax.local_devices()
-                    if d.platform == self._platform]) or jax.local_device_count()
+        return len(self.local_devices())
 
     def current_device(self):
         return self.devices()[0]
@@ -64,7 +67,9 @@ class Accelerator:
 
     # ----- memory -----------------------------------------------------------
     def memory_stats(self, device_index: int = 0) -> dict:
-        dev = self.devices()[device_index]
+        """Of the ``device_index``-th LOCAL device: a process reads the
+        allocator of its own devices only."""
+        dev = self.local_devices()[device_index]
         stats = getattr(dev, "memory_stats", lambda: None)()
         return stats or {}
 

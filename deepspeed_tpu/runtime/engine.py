@@ -41,6 +41,10 @@ from deepspeed_tpu.runtime.fp16.loss_scaler import (
     create_loss_scaler, update_scale)
 from deepspeed_tpu.runtime.step_programs import tree_cast as _tree_cast
 from deepspeed_tpu.telemetry import tracing
+from deepspeed_tpu.telemetry.memory import (
+    attribute_params, fullest_device_bytes, get_memory_ledger,
+    live_temporaries, memory_enabled, program_memory,
+    set_memory_config_default)
 from deepspeed_tpu.telemetry.tracing import (
     TRAIN_STEP_PROGRAM, register_program)
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -217,8 +221,6 @@ class DeepSpeedEngine:
         # memory-ledger process default (ISSUE 14): installed BEFORE
         # the offload tiers construct their swappers, so an init-time
         # master/moment swap-out already honors telemetry.memory: false
-        from deepspeed_tpu.telemetry.memory import \
-            set_memory_config_default
         set_memory_config_default(self._config.telemetry_config.memory)
 
         # ---- ZeRO sharding policy -------------------------------------------
@@ -784,20 +786,25 @@ class DeepSpeedEngine:
                 self.telemetry_registry,
                 port=tcfg.metrics_port).start()
         # memory observatory (ISSUE 14): attribute the engine's big
-        # owners into the tiered ledger once (param/optimizer byte
-        # sizes never change); per-step publication + the HBM-fraction
-        # anomaly feed ride _record_step_telemetry.  The opt-in
-        # compiled activation analysis (DS_MEM_COMPILED=1 — one extra
-        # XLA compile) lands lazily beside the first-step cost report.
-        from deepspeed_tpu.telemetry.memory import memory_enabled
+        # owners into the tiered ledger once (the state's byte sizes
+        # never change); per-step publication + the HBM-fraction
+        # anomaly feed ride _record_step_telemetry.  The device tier is
+        # ONE chip's: each owner's shards on the fullest local device,
+        # counted where the state has just been placed (arithmetic over
+        # shard shapes, no device read); the step's gradients and
+        # workspace join it when somebody asks for the step's account
+        # (_maybe_register_program_map).
         self._mem_on = tcfg.enabled and memory_enabled(tcfg.memory)
-        self._mem_compiled_done = False
         self._program_map_registered = False
+        self._state_bytes = None
         if self._mem_on:
             try:
                 from deepspeed_tpu.telemetry.iostat import get_iostat
-                from deepspeed_tpu.telemetry.memory import (
-                    attribute_params, get_memory_ledger, tree_bytes)
+                self._state_bytes = fullest_device_bytes(
+                    params=self.state["params"],
+                    optimizer=self.state["opt_state"],
+                    state_other={k: v for k, v in self.state.items()
+                                 if k not in ("params", "opt_state")})
                 # swap I/O observations land in this engine's registry
                 # and feed its anomaly detector (a collapsing NVMe read
                 # rate raises anomaly/mem_swap_read before the offload
@@ -805,10 +812,12 @@ class DeepSpeedEngine:
                 get_iostat().attach(registry=self.telemetry_registry,
                                     anomaly=self.anomaly)
                 led = get_memory_ledger()
-                attribute_params(led, self.state["params"])
-                opt_bytes = tree_bytes(self.state.get("opt_state"))
-                if opt_bytes:
-                    led.set_bytes("device", "optimizer", opt_bytes)
+                attribute_params(led, self.state["params"],
+                                 nbytes=self._state_bytes["params"])
+                for owner in ("optimizer", "state_other"):
+                    if self._state_bytes[owner]:
+                        led.set_bytes("device", owner,
+                                      self._state_bytes[owner])
                 if self.host_optimizer is not None:
                     led.set_bytes("host", "optimizer",
                                   self.host_optimizer.host_dram_bytes,
@@ -2060,7 +2069,6 @@ class DeepSpeedEngine:
             # after its dispatch: the device works while the host walks
             # the step once more, and jit's trace is the program's first
             self._maybe_cost_report(batch, rng)
-            self._maybe_memory_report(batch, rng)
             self._maybe_register_program_map(batch)
         self._finish_step(metrics)
         # syncing on the loss every step stalls the async dispatch
@@ -2449,23 +2457,62 @@ class DeepSpeedEngine:
 
     def _maybe_register_program_map(self, batch):
         """On the first fused dispatch, publish the step under
-        ``"train/step"`` for ``get_program_map`` (telemetry/tracing.py): a
-        thunk and the batch's abstract signature, nothing more — the
-        executable's text is fetched and parsed when someone first asks.
+        ``"train/step"`` for ``get_program_map`` (telemetry/tracing.py)
+        and ``step_memory`` (telemetry/memory.py): two thunks and the
+        batch's abstract signature, nothing more — the executable is
+        fetched when someone first ASKS for either (a peek —
+        ``/debug/memory``, a post-mortem bundle — fetches nothing) and
+        dropped.  Every load leaves its six numbers behind, so the map
+        and then the account are ONE load; the text (tens of MB) is
+        handed to its asker and not kept, so the account first and the
+        map later are two.  The registry calls one thunk at a time.
         The table holds the engine weakly."""
         if self._program_map_registered:
             return
         self._program_map_registered = True
         signature = jax.tree.map(_abstract_placed, batch)
         alive = weakref.ref(self)
+        kept = {}               # "program": the first load's numbers
 
-        def step_text():
+        def load():
             engine = alive()
             if engine is None:
                 return None
+            executable = engine._compile_train_step(signature)
+            kept.setdefault("program", program_memory(executable))
+            return executable
+
+        def step_text():
             with tracing.setup_span(tracing.SPAN_PROGRAM_TEXT):
-                return engine._compile_train_step(signature).as_text()
-        register_program(TRAIN_STEP_PROGRAM, step_text)
+                executable = load()
+                return None if executable is None else executable.as_text()
+
+        def step_bytes():
+            engine = alive()
+            if engine is None or engine._state_bytes is None:
+                return None     # the engine is gone, or its ledger is off
+            with tracing.setup_span(tracing.SPAN_MEMORY_COMPILED):
+                if "program" not in kept:
+                    load()
+                program = kept.get("program")
+            if program is None:
+                return None     # a backend with no memory analysis
+            gradients = tracing.gradient_bytes(TRAIN_STEP_PROGRAM)
+            temporaries = live_temporaries(program, gradients)
+            # whoever makes the account feeds the ledger's device tier
+            # (a reader of the ledger only ever peeks at it)
+            led = get_memory_ledger()
+            if gradients is not None:
+                led.set_bytes("device", "gradients", gradients)
+            if temporaries is not None:
+                led.set_bytes("device", "workspace",
+                              temporaries - gradients, **program)
+            return {
+                "state": engine._state_bytes,
+                "batch": fullest_device_bytes(batch=signature)["batch"],
+                "program": program, "gradients": gradients,
+                "temporaries": temporaries}
+        register_program(TRAIN_STEP_PROGRAM, step_text, step_bytes)
 
     def compile_train_step(self, batch):
         """The fused step ``train_batch`` runs for ``batch`` (leaves lead
@@ -2484,34 +2531,6 @@ class DeepSpeedEngine:
             return fn.lower(
                 jax.tree.map(_abstract, self.state, self.state_shardings),
                 signature, _abstract(self._rng)).compile()
-
-    def _maybe_memory_report(self, batch, rng):
-        """Opt-in activation-peak accounting (ISSUE 14): compile the
-        fused train step once more and read the backend's
-        ``memory_analysis()`` (temp = the activation/workspace peak)
-        into the ledger's ``activations`` owner.  Costs a FULL XLA
-        compile, so it only runs under ``DS_MEM_COMPILED=1``; backends
-        without the analysis quietly skip."""
-        if self._mem_compiled_done:
-            return
-        self._mem_compiled_done = True
-        if not (self._mem_on and os.environ.get(
-                "DS_MEM_COMPILED", "").strip() in ("1", "true", "on")):
-            return
-        try:
-            from deepspeed_tpu.telemetry.memory import (
-                compiled_memory_stats, get_memory_ledger)
-            with tracing.setup_span(tracing.SPAN_MEMORY_COMPILED), \
-                    self._train_scope(), self._ltd_scope(), self._aq_scope():
-                stats = compiled_memory_stats(
-                    self._step_program("train_step"), self.state, batch, rng)
-            if stats:
-                get_memory_ledger().set_bytes(
-                    "device", "activations",
-                    stats.get("temp_size_in_bytes", 0), **stats)
-        except Exception as e:          # noqa: BLE001 — best-effort
-            from deepspeed_tpu.utils.logging import logger
-            logger.debug(f"memory ledger: compiled analysis failed: {e}")
 
     def _postmortem_dir(self) -> str:
         """Training-side bundle placement (the preemption.py rules):
@@ -2612,7 +2631,6 @@ class DeepSpeedEngine:
         if self._mem_on:
             # memory observatory (ISSUE 14): mem/* gauges + the HBM
             # used-fraction anomaly feed (a leak flags before the OOM)
-            from deepspeed_tpu.telemetry.memory import get_memory_ledger
             get_memory_ledger().publish_and_feed(reg, self.anomaly,
                                                  corr=corr)
         tokens = self.train_batch_size() * max(self._last_seq_len, 0)

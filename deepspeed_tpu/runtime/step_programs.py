@@ -11,17 +11,19 @@ maps each name ``engine._get_compiled`` knows to its builder.
 """
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.runtime.fp16.loss_scaler import has_overflow, update_scale
 from deepspeed_tpu.telemetry.numerics import group_stats, inject_nonfinite
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
-    step_account)
+    count_in_step, step_account)
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -115,9 +117,17 @@ def micro_grads(ctx, params, batch, rng, scale, n=None, compress_step=None,
 
 def as_grads(ctx, grads):
     """Gradient storage: the accumulation dtype, laid out by the ZeRO
-    policy (stage 2's reduce-scatter is this constraint)."""
-    return ctx.zero_policy.constrain_grads(
-        tree_cast(grads, ctx.grad_dtype), ctx.grad_specs)
+    policy (stage 2's reduce-scatter is this constraint).  Inside a
+    step's account the tree states its bytes on one device
+    (``tracing.gradient_bytes``): shapes, nothing in the program."""
+    grads = tree_cast(grads, ctx.grad_dtype)
+    mesh = ctx.zero_policy.mesh
+    count_in_step(gradient_bytes_per_device=sum(
+        math.prod(NamedSharding(mesh, spec).shard_shape(g.shape))
+        * g.dtype.itemsize
+        for g, spec in zip(jax.tree.leaves(grads), jax.tree.leaves(
+            ctx.grad_specs, is_leaf=lambda x: isinstance(x, PartitionSpec)))))
+    return ctx.zero_policy.constrain_grads(grads, ctx.grad_specs)
 
 
 def accumulate(ctx, grads_acc, grads):

@@ -45,9 +45,10 @@ from deepspeed_tpu.telemetry.roofline import (      # noqa: F401
     hbm_bytes_per_s, ici_bytes_per_s, observe_achieved, perf_table,
     publish_report)
 from deepspeed_tpu.telemetry.memory import (        # noqa: F401
-    MEM_ENV, MemoryLedger, attribute_params, compiled_memory_stats,
+    MEM_ENV, MemoryLedger, attribute_params, device_bytes,
     device_memory_stats, get_memory_ledger, hbm_used_fraction,
-    memory_enabled, reset_memory_ledger, tree_bytes)
+    memory_enabled, peek_step_memory, reset_memory_ledger, step_memory,
+    tree_bytes)
 from deepspeed_tpu.telemetry.iostat import (        # noqa: F401
     IoStat, NVME_GBPS_ENV, get_iostat, nvme_bytes_per_s, reset_iostat)
 from deepspeed_tpu.telemetry.numerics import (      # noqa: F401

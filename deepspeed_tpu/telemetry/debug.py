@@ -108,14 +108,20 @@ def flightrec_payload(recorder, query: Optional[Dict[str, str]] = None
 def memory_payload(query: Optional[Dict[str, str]] = None
                    ) -> Dict[str, Any]:
     """The ``/debug/memory`` body: ledger snapshot (tiers × owners with
-    watermarks + failure ring + device stats) and the swap I/O summary.
+    watermarks + failure ring + device stats), the train step's own
+    account of one chip's bytes under ``"step"`` (``peek_step_memory``:
+    None until somebody has asked ``step_memory()`` for it — this body
+    never starts the load of the step's executable that the first asker
+    pays) and the swap I/O summary.
     ``?tier=<name>`` filters the tier table.  Reads the EXISTING iostat
-    (peek, never create/install): a read-only debug GET must not
-    mutate global state, and an aio import failure must not 500 the
-    endpoint the ledger half can still answer."""
+    and account (peek, never create/install): a read-only debug GET
+    must not mutate global state, and an aio import failure must not
+    500 the endpoint the ledger half can still answer."""
     from deepspeed_tpu.telemetry.iostat import peek_iostat
-    from deepspeed_tpu.telemetry.memory import get_memory_ledger
+    from deepspeed_tpu.telemetry.memory import (get_memory_ledger,
+                                                peek_step_memory)
     payload = get_memory_ledger().snapshot()
+    payload["step"] = peek_step_memory()
     io = peek_iostat()
     payload["swap"] = io.summary() if io is not None else {"ops": {}}
     want = (query or {}).get("tier")

@@ -8,9 +8,12 @@ bytes per **tier** (``device`` HBM via the accelerator abstraction's
 ``memory_stats``, ``host`` pinned/DRAM copies, ``nvme`` swap files)
 and per **owner** within a tier (model params — split dtype/quantized
 via the costmodel ``param_stream_bytes`` walk — optimizer state, the
-KV block pool, the prefix-cache retained set, the spec draft pool,
-activation peaks from compiled-program ``memory_analysis()`` where the
-backend supports it).
+KV block pool, the prefix-cache retained set, the spec draft pool).  A
+training engine's device tier is **one chip's**: each owner's bytes on
+the fullest local device, counted from the arrays' own shards, with the
+step's summed gradient tree and the rest of its program's temporaries
+(``gradients``, ``workspace``) from :func:`step_memory` once somebody has
+asked for that account.
 
 Three read surfaces, one source of truth:
 
@@ -36,12 +39,12 @@ import collections
 import os
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
+
+from deepspeed_tpu.telemetry.tracing import (TRAIN_STEP_PROGRAM,
+                                             get_program_memory)
 
 MEM_ENV = "DS_MEM_LEDGER"
-#: opt-in compiled-program activation analysis (one extra XLA compile
-#: of the train step — too heavy to pay by default)
-MEM_COMPILED_ENV = "DS_MEM_COMPILED"
 
 #: the ledger's tier vocabulary; owners within a tier are free-form
 TIERS = ("device", "host", "nvme")
@@ -90,15 +93,57 @@ def device_memory_stats(device_index: int = 0) -> Dict[str, int]:
         return {}
 
 
+def local_device_stats() -> List[Dict[str, int]]:
+    """The memory stats of every local device that reports any, through
+    the accelerator abstraction (``[]`` on the CPU).  An accelerator
+    that does not say how many local devices it has is asked for its
+    first alone."""
+    try:
+        from deepspeed_tpu.accelerator import get_accelerator
+        acc = get_accelerator()
+        count = getattr(acc, "local_device_count", lambda: 1)()
+        every = [dict(acc.memory_stats(i) or {}) for i in range(count)]
+    except Exception:           # no backend at all (early import, tests)
+        return []
+    return [stats for stats in every if stats]
+
+
+def used_bytes(stats: Dict[str, int]) -> int:
+    """What a device holds now: the buffers the runtime keeps
+    (``bytes_in_use``: state, batches) plus what its programs have
+    reserved for their temporaries (``bytes_reserved`` — on a TPU these
+    are NOT inside ``bytes_in_use``); the first alone where the backend
+    reports no second."""
+    return int(stats.get("bytes_in_use", 0) or 0) \
+        + int(stats.get("bytes_reserved", 0) or 0)
+
+
+def peak_bytes(stats: Dict[str, int]) -> int:
+    """The allocator's own high-watermark of :func:`used_bytes`
+    (``peak_bytes_in_use`` + ``peak_bytes_reserved``, what
+    benchmarks/harness/device.py reads as ``peak_hbm_gib``); no lower
+    than what is held now."""
+    return max(int(stats.get("peak_bytes_in_use", 0) or 0)
+               + int(stats.get("peak_bytes_reserved", 0) or 0),
+               used_bytes(stats))
+
+
+def fullest_device_stats(by=used_bytes) -> Dict[str, int]:
+    """The stats of the local device that reads highest ``by`` (``{}``
+    where no device reports any)."""
+    return max(local_device_stats(), key=by, default={})
+
+
 def hbm_used_fraction(stats: Optional[Dict[str, int]] = None
                       ) -> Optional[float]:
-    """bytes_in_use / bytes_limit, or None when either is unknown —
+    """:func:`used_bytes` / bytes_limit (of the fullest local device
+    where no stats are handed in), or None when the limit is unknown —
     no fictitious fractions on backends without memory stats."""
-    s = device_memory_stats() if stats is None else stats
+    s = fullest_device_stats() if stats is None else stats
     limit = s.get("bytes_limit") or 0
     if not limit:
         return None
-    return float(s.get("bytes_in_use", 0)) / float(limit)
+    return float(used_bytes(s)) / float(limit)
 
 
 class MemoryLedger:
@@ -119,7 +164,7 @@ class MemoryLedger:
         self._owner_peak: Dict[tuple, float] = {}
         #: tier -> high-watermark of the tier TOTAL
         self._tier_peak: Dict[str, float] = {}
-        #: device-stats watermark (bytes_in_use peak; observe_device)
+        #: device-stats watermark (peak_bytes over observe_device's reads)
         self._hbm_peak = 0.0
         self._failures: collections.deque = collections.deque(
             maxlen=max(int(max_failures), 1))
@@ -166,16 +211,17 @@ class MemoryLedger:
         return v
 
     def observe_device(self) -> Dict[str, int]:
-        """Sample the accelerator's memory stats, tracking the
-        bytes_in_use high-watermark; returns the stats (``{}`` on
-        backends without them)."""
-        stats = device_memory_stats()
-        used = float(stats.get("bytes_in_use", 0) or 0)
-        if used:
+        """Sample every local device's memory stats, tracking the
+        high-watermark of :func:`peak_bytes` over all of them; returns
+        the stats of the device that holds most now (``{}`` on backends
+        without them)."""
+        every = local_device_stats()
+        peak = float(max(map(peak_bytes, every), default=0))
+        if peak:
             with self._lock:
-                if used > self._hbm_peak:
-                    self._hbm_peak = used
-        return stats
+                if peak > self._hbm_peak:
+                    self._hbm_peak = peak
+        return max(every, key=used_bytes, default={})
 
     def record_alloc_failure(self, site: str, flightrec=None,
                              **detail) -> Dict[str, Any]:
@@ -233,7 +279,7 @@ class MemoryLedger:
         # read-only device probe: no ledger lock, no peak mutation —
         # the /debug/memory reader must not touch ANY lock a wedged
         # writer could be holding
-        stats = device_memory_stats()
+        stats = fullest_device_stats()
         tiers: Dict[str, Any] = {}
         for t in TIERS:
             rows = {}
@@ -263,8 +309,9 @@ class MemoryLedger:
             frac = hbm_used_fraction(stats)
             if frac is not None:
                 dev["used_fraction"] = round(frac, 4)
+            dev["used_bytes"] = used_bytes(stats)
             dev["watermark_bytes"] = int(max(self._hbm_peak,
-                                             dev.get("bytes_in_use", 0)))
+                                             peak_bytes(stats)))
             out["device_stats"] = dev
         return out
 
@@ -289,7 +336,7 @@ class MemoryLedger:
         stats = self.observe_device()
         if stats:
             registry.set_gauge("mem/hbm_used_bytes",
-                               float(stats.get("bytes_in_use", 0)))
+                               float(used_bytes(stats)))
             if stats.get("bytes_limit"):
                 registry.set_gauge("mem/hbm_limit_bytes",
                                    float(stats["bytes_limit"]))
@@ -327,15 +374,17 @@ class MemoryLedger:
 # -------------------------------------------------- owner attribution
 def attribute_params(ledger: MemoryLedger, params, *,
                      tier: str = "device", owner: str = "params",
-                     stream: Optional[Dict[str, int]] = None
-                     ) -> Dict[str, int]:
+                     stream: Optional[Dict[str, int]] = None,
+                     nbytes: Optional[int] = None) -> Dict[str, int]:
     """Attribute a model's parameter bytes into the ledger, split
     dtype/quantized via the costmodel ``param_stream_bytes`` walk (the
     SAME math serve_bench/decode_profile floors use, so the ledger and
     the perf observatory can never disagree about param bytes).
     ``stream`` short-circuits the walk when the caller already holds a
     ``param_stream_bytes`` result (the serving scheduler's cost
-    stream)."""
+    stream).  ``nbytes``: the row's bytes where they are not the
+    walk's total (a training engine's are one chip's share of it,
+    :func:`device_bytes`; the split stays as the row's detail)."""
     if stream is None:
         from deepspeed_tpu.telemetry.costmodel import param_stream_bytes
         stream = param_stream_bytes(params)
@@ -343,7 +392,7 @@ def attribute_params(ledger: MemoryLedger, params, *,
              + stream.get("expert_int8_bytes", 0)
              + stream.get("plain_bytes", 0))
     ledger.set_bytes(
-        tier, owner, total,
+        tier, owner, total if nbytes is None else nbytes,
         dense_int8_bytes=int(stream.get("dense_int8_bytes", 0)),
         expert_int8_bytes=int(stream.get("expert_int8_bytes", 0)),
         plain_bytes=int(stream.get("plain_bytes", 0)))
@@ -364,27 +413,154 @@ def tree_bytes(tree) -> int:
     return total
 
 
-def compiled_memory_stats(fn, *args) -> Optional[Dict[str, int]]:
-    """Activation-peak accounting from a compiled program's
-    ``memory_analysis()`` (argument/output/temp/generated-code bytes)
-    where the backend supports it; None where it doesn't.  Costs a full
-    XLA compile — callers gate it (``DS_MEM_COMPILED=1``)."""
+def device_bytes(tree) -> Dict[int, int]:
+    """``{device id: bytes}`` of a pytree's array leaves on each local
+    device, from every leaf's own sharding: the shard of its shape that
+    the device holds (a replicated leaf counts whole on each).
+    Arithmetic over shapes — no device read, no sync — so abstract
+    leaves (``ShapeDtypeStruct`` with a sharding) count as arrays do.
+    Leaves kept in pinned host memory and leaves that are no arrays
+    are left out."""
     import jax
-    try:
-        compiled = jax.jit(fn).lower(*args).compile()
-        mem = compiled.memory_analysis()
-        if mem is None:
-            return None
-        out = {}
-        for k in ("argument_size_in_bytes", "output_size_in_bytes",
-                  "temp_size_in_bytes", "alias_size_in_bytes",
-                  "generated_code_size_in_bytes"):
-            v = getattr(mem, k, None)
-            if v is not None:
-                out[k] = int(v)
-        return out or None
-    except Exception:
+    import numpy as np
+    per: Dict[int, int] = {}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is None or getattr(sharding, "memory_kind",
+                                       None) == "pinned_host":
+            continue
+        try:
+            itemsize = int(np.dtype(leaf.dtype).itemsize)
+        except TypeError:       # a typed key array: no numpy dtype
+            continue
+        shards = sharding.addressable_devices_indices_map(leaf.shape)
+        for device, index in shards.items():
+            held = itemsize
+            for dim, part in zip(leaf.shape, index):
+                held *= len(range(*part.indices(dim)))
+            per[device.id] = per.get(device.id, 0) + held
+    return per
+
+
+def fullest_device_bytes(**owners) -> Dict[str, int]:
+    """Each owner's :func:`device_bytes` on the ONE local device that
+    holds most of them all together, so the owners add up to what a
+    real device holds (all zeros where nothing is on a device)."""
+    per = {owner: device_bytes(tree) for owner, tree in owners.items()}
+    devices = {d for held in per.values() for d in held}
+    fullest = max(devices, default=None, key=lambda d: sum(
+        held.get(d, 0) for held in per.values()))
+    return {owner: held.get(fullest, 0) for owner, held in per.items()}
+
+
+def step_memory(name: str = TRAIN_STEP_PROGRAM,
+                create: bool = True) -> Optional[Dict[str, Any]]:
+    """Where one chip's memory goes while the step registered under
+    ``name`` runs: the step's own account of its bytes, per device, or
+    None where no step has run.  Made on request (nothing a step pays:
+    the first asker pays the engine's ``memory_thunk`` — one load of
+    the executable that runs, a full compile where no persistent cache
+    holds it — and should be the thread that trains);
+    :func:`peek_step_memory` is for a reader that must start nothing::
+
+      state       {"params", "optimizer", "state_other"}: the engine's
+                  state on its fullest device, counted from the arrays'
+                  shards where they were placed (:func:`device_bytes`)
+      batch       one step's batch, the same way
+      program     {"argument", "output", "alias", "temp",
+                  "generated_code", "peak"}: ``memory_analysis()`` of
+                  the executable that runs, which XLA states per device
+      gradients   the summed gradient tree as the step lays it out
+                  (``tracing.gradient_bytes``: counted by
+                  runtime/step_programs.py while the step is traced);
+                  one of the temporaries.  None: none was counted
+      temporaries the program's temporaries that are live when it is at
+                  its fullest (:func:`live_temporaries`): program.peak
+                  - argument - (output - alias), XLA's own peak less
+                  what is no temporary — what a TPU's runtime reserves
+                  for the program (``peak_bytes_reserved``).  ONE
+                  definition: None where that peak is not known to
+                  cover temporaries (the CPU backend's does not), never
+                  ``program.temp``, the sum of the temporary
+                  allocations, which reads higher (+ 0.85 GiB in a 760M
+                  step) and is there to be read beside it
+      workspace   temporaries - gradients: activations kept for the
+                  backward, a micro-batch's gradients in flight, the
+                  kernels' and collectives' buffers.  None without
+                  ``temporaries``
+      expected_peak   state + batch + (output - alias) + temporaries
+                  + generated_code.  None without ``temporaries``
+      layout_padding  argument - (state + batch): XLA's word for the
+                  same arrays as the executable lays them out, less
+                  the count by shards (the RNG key, tiles' padding)
+      allocator   {"peak_bytes_in_use", "peak_bytes_reserved",
+                  "bytes_limit"} of the local device whose peak is
+                  highest; None where the backend reports none (CPU)
+      unaccounted the allocator's peak - expected_peak (None without
+                  either): the one term that measures a reading
+                  against a count
+    """
+    counted = get_program_memory(name, create=create)
+    if counted is None:
         return None
+    program, gradients = counted["program"], counted["gradients"]
+    held = sum(counted["state"].values()) + counted["batch"]
+    temporaries = counted["temporaries"]
+    account = dict(
+        counted, workspace=None, expected_peak=None,
+        layout_padding=program["argument"] - held,
+        allocator=None, unaccounted=None)
+    if temporaries is not None:
+        account["workspace"] = temporaries - gradients
+        account["expected_peak"] = held + program["output"] \
+            - program["alias"] + temporaries + program["generated_code"]
+    stats = fullest_device_stats(by=peak_bytes)
+    if stats:
+        account["allocator"] = {
+            key: int(stats.get(key, 0) or 0) for key in
+            ("peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")}
+        if temporaries is not None:
+            account["unaccounted"] = peak_bytes(stats) \
+                - account["expected_peak"]
+    return account
+
+
+def peek_step_memory(name: str = TRAIN_STEP_PROGRAM
+                     ) -> Optional[Dict[str, Any]]:
+    """:func:`step_memory` where somebody has asked for it already, else
+    None: no thunk is called, nothing is compiled or loaded and no
+    ledger row is written — what ``/debug/memory`` and a post-mortem
+    bundle show (a read-only GET and a crashing process start no load
+    of the step's executable), as ``peek_iostat`` is for the swap
+    I/O."""
+    return step_memory(name, create=False)
+
+
+def live_temporaries(program: Dict[str, int],
+                     gradients: Optional[int]) -> Optional[int]:
+    """:func:`step_memory`'s ``temporaries`` of a program's six numbers:
+    XLA's peak less the arguments and the outputs that alias none.
+    None where that peak cannot be held to cover temporaries: it leaves
+    less than ``gradients``, the tree the step certainly holds among
+    them (the CPU backend's peak is its arguments' and some hundred
+    bytes), or no such tree was counted to hold it against."""
+    live = program["peak"] - program["argument"] \
+        - (program["output"] - program["alias"])
+    if gradients is None or live < gradients:
+        return None
+    return live
+
+
+def program_memory(executable) -> Optional[Dict[str, int]]:
+    """``memory_analysis()`` of a compiled program as :func:`step_memory`
+    keeps it, or None where the backend has no such analysis."""
+    analysis = executable.memory_analysis()
+    if analysis is None:
+        return None
+    sizes = {key: int(getattr(analysis, f"{key}_size_in_bytes"))
+             for key in ("argument", "output", "alias", "temp",
+                         "generated_code")}
+    return dict(sizes, peak=int(analysis.peak_memory_in_bytes))
 
 
 # ------------------------------------------------- process-wide ledger

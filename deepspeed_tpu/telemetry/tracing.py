@@ -584,18 +584,56 @@ def parse_program_text(text: str) -> Dict[str, Dict[str, Any]]:
 
 # -- process-wide table by program name, beside costmodel.get_report
 _PROGRAM_LOCK = threading.Lock()
-_PROGRAM_THUNKS: Dict[str, Callable[[], Optional[str]]] = {}
-_PROGRAM_MAPS: Dict[str, Dict[str, Dict[str, Any]]] = {}
+#: held while a thunk runs (a load of an executable: seconds), so two
+#: askers at once make one load and a registrant's thunks may share what
+#: they keep; never held with _PROGRAM_LOCK wanted by a peek
+_PROGRAM_ASK_LOCK = threading.RLock()
+#: name -> {"text": thunk, "memory": thunk or None}
+_PROGRAM_THUNKS: Dict[str, Dict[str, Optional[Callable[[], Any]]]] = {}
+#: name -> what the thunks gave when first asked, by the same keys (the
+#: text as its parsed map)
+_PROGRAM_FACTS: Dict[str, Dict[str, Any]] = {}
 
 
-def register_program(name: str, text_thunk: Callable[[], Optional[str]]):
+def register_program(name: str, text_thunk: Callable[[], Optional[str]],
+                     memory_thunk: Optional[Callable[[], Any]] = None):
     """Publish a program under ``name``.  ``text_thunk()`` returns the
-    HLO text of the executable that runs (or None if it can no longer be
-    had); it is NOT called here — only by the first
-    :func:`get_program_map` that asks."""
+    HLO text of the executable that runs, ``memory_thunk()`` what is
+    counted of its bytes per device (telemetry/memory.py
+    ``step_memory`` says which keys); either returns None if it can no
+    longer be had.  Neither is called here — only by the first
+    :func:`get_program_map` / :func:`get_program_memory` that asks."""
     with _PROGRAM_LOCK:
-        _PROGRAM_THUNKS[name] = text_thunk
-        _PROGRAM_MAPS.pop(name, None)
+        _PROGRAM_THUNKS[name] = {"text": text_thunk, "memory": memory_thunk}
+        _PROGRAM_FACTS.pop(name, None)
+
+
+def _program_fact(name: str, kind: str, reduce=lambda raw: raw,
+                  create: bool = True):
+    """``reduce`` of what the ``kind`` thunk of ``name`` returns, asked
+    once and kept; None where there is no such program or thunk, or the
+    thunk has nothing to give any more.  ``create=False`` peeks: what
+    an earlier asker was given — no thunk is called and no lock taken
+    (a debug reader beside a wedged writer)."""
+    if not create:
+        return _PROGRAM_FACTS.get(name, {}).get(kind)
+    with _PROGRAM_ASK_LOCK:
+        with _PROGRAM_LOCK:
+            facts = _PROGRAM_FACTS.get(name, {})
+            if kind in facts:
+                return facts[kind]
+            thunks = _PROGRAM_THUNKS.get(name)
+        thunk = thunks and thunks[kind]
+        if thunk is None:
+            return None
+        raw = thunk()
+        if raw is None:
+            return None
+        fact = reduce(raw)
+        with _PROGRAM_LOCK:
+            if _PROGRAM_THUNKS.get(name) is thunks:
+                _PROGRAM_FACTS.setdefault(name, {})[kind] = fact
+        return fact
 
 
 def get_program_map(name: str = TRAIN_STEP_PROGRAM):
@@ -605,20 +643,27 @@ def get_program_map(name: str = TRAIN_STEP_PROGRAM):
     pays for the executable's text (with the persistent compile cache a
     load, ~2 s for a 760M step) and the parse; later calls return the
     same table."""
+    return _program_fact(name, "text", parse_program_text)
+
+
+def get_program_text(name: str = TRAIN_STEP_PROGRAM) -> Optional[str]:
+    """The HLO text of the program registered under ``name`` as its
+    ``text_thunk`` gives it now (a load of the executable every call:
+    only the parsed map is kept), or None.  For a script that wants the
+    text itself; :func:`get_program_map` is what the readers use."""
     with _PROGRAM_LOCK:
-        if name in _PROGRAM_MAPS:
-            return _PROGRAM_MAPS[name]
-        thunk = _PROGRAM_THUNKS.get(name)
-    if thunk is None:
-        return None
-    text = thunk()
-    if text is None:
-        return None
-    table = parse_program_text(text)
-    with _PROGRAM_LOCK:
-        if _PROGRAM_THUNKS.get(name) is thunk:
-            _PROGRAM_MAPS[name] = table
-    return table
+        thunks = _PROGRAM_THUNKS.get(name)
+    with _PROGRAM_ASK_LOCK:
+        return thunks["text"]() if thunks else None
+
+
+def get_program_memory(name: str = TRAIN_STEP_PROGRAM, create: bool = True):
+    """What the program registered under ``name`` counts of its bytes
+    per device, as its ``memory_thunk`` gave it when first asked (the
+    engine's: telemetry/memory.py ``step_memory`` reads it), or None.
+    ``create=False``: only what an earlier asker already made — a load
+    of the step's executable is not a reader's to start."""
+    return _program_fact(name, "memory", create=create)
 
 
 #: an instruction inside a layer loop of the step: a ``while`` body below
@@ -823,6 +868,16 @@ def flash_calls(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "flash_calls")
 
 
+def gradient_bytes(name: str = TRAIN_STEP_PROGRAM):
+    """Bytes one device holds of the step's summed gradient tree — the
+    accumulator ``accumulated_grads`` carries over the micro-batches and
+    ``apply_grads`` reads — as runtime/step_programs.py ``as_grads``
+    traced it: every leaf in the accumulation dtype, the shard the ZeRO
+    policy's layout gives a device.  None where no step with such a
+    tree was traced."""
+    return _STEP_COUNTERS.get(name, {}).get("gradient_bytes_per_device")
+
+
 # ==================================================== where a start goes
 #: Fixed names of the host spans the program opens at its own boundaries
 #: before (and around) its first steps — part of the program's interface,
@@ -836,7 +891,7 @@ SPAN_INIT_OPTIMIZER = "engine/init/optimizer"   # ... optimizer and its state
 SPAN_TRAIN_STEP = "train/step"              # one train_batch call, and in it
 SPAN_FUSED_STEP = "train/fused_step"        # ... the fused program's call
 SPAN_COST_ANALYZE = "costmodel/analyze"     # the cost report's jaxpr walk
-SPAN_MEMORY_COMPILED = "memory/compiled"    # DS_MEM_COMPILED's extra compile
+SPAN_MEMORY_COMPILED = "memory/compiled"    # step_memory's first asker
 SPAN_PROGRAM_TEXT = "program_map/text"      # get_program_map's first asker
 SPAN_COMPILE_AOT = "compile/aot"            # compile_train_step: lower + compile
 SETUP_SPANS = (SPAN_ENGINE_INIT, SPAN_INIT_SHARDINGS, SPAN_INIT_PARAMS,
@@ -1234,7 +1289,7 @@ def reset_programs():
     global _SETUP
     with _PROGRAM_LOCK:
         _PROGRAM_THUNKS.clear()
-        _PROGRAM_MAPS.clear()
+        _PROGRAM_FACTS.clear()
         account, _SETUP = _SETUP, None
     if account is not None:
         account.unlisten()

@@ -56,9 +56,6 @@ ENV_VARS = {
     "DS_KV_TIERING": "0/1 disables/forces tiered KV spill "
                      "(host-RAM/NVMe cold tiers; wins over "
                      "serving.kv_tiering.enabled)",
-    "DS_MEM_COMPILED": "1 arms the one-time compiled-program "
-                       "memory_analysis activation-peak probe (a full "
-                       "extra XLA compile of the train step)",
     "DS_MEM_LEDGER": "0/1 disables/forces the tiered memory ledger "
                      "taps (wins over telemetry.memory)",
     "DS_MOE_DISPATCH": "MoE expert-dispatch override: auto/einsum/"
@@ -181,16 +178,19 @@ METRICS = {
                              "fully hidden behind compute)",
     # --- memory observatory (tiered ledger + OOM forensics, ISSUE 14)
     "mem/owner_bytes": "live bytes per owner, labeled by tier+owner "
-                       "(params/optimizer/kv_pool/prefix_cache/...)",
+                       "(params/optimizer/kv_pool/prefix_cache/...; a "
+                       "training engine's device tier is one chip's: "
+                       "its fullest local device)",
     "mem/tier_bytes": "live bytes per tier (device/host/nvme)",
     "mem/tier_watermark_bytes": "high-watermark of a tier's total, "
                                 "labeled by tier",
-    "mem/hbm_used_bytes": "device bytes_in_use via the accelerator "
-                          "abstraction (absent on CPU)",
-    "mem/hbm_limit_bytes": "device bytes_limit (absent on CPU)",
-    "mem/hbm_used_fraction": "bytes_in_use/bytes_limit gauge (the "
-                             "anomaly/mem_hbm leak feed; absent on "
-                             "CPU)",
+    "mem/hbm_used_bytes": "in use + reserved (bytes_in_use + "
+                          "bytes_reserved), fullest local device, via "
+                          "the accelerator abstraction (absent on CPU)",
+    "mem/hbm_limit_bytes": "that device's bytes_limit (absent on CPU)",
+    "mem/hbm_used_fraction": "in use + reserved over bytes_limit, "
+                             "fullest local device (the anomaly/mem_hbm "
+                             "leak feed; absent on CPU)",
     "mem/alloc_failures": "allocation failures snapshotted into the "
                           "OOM forensics ring",
     # --- offload I/O (swap bandwidth telemetry, ISSUE 14)

@@ -47,7 +47,8 @@ def render(payload: dict) -> str:
         frac = dev.get("used_fraction")
         lines.append(
             "device HBM: "
-            f"{fmt_bytes(dev.get('bytes_in_use', 0))} in use"
+            f"{fmt_bytes(dev.get('used_bytes', dev.get('bytes_in_use', 0)))}"
+            " in use + reserved"
             + (f" / {fmt_bytes(dev['bytes_limit'])} limit"
                if dev.get("bytes_limit") else "")
             + (f" ({frac:.1%})" if frac is not None else "")
@@ -55,6 +56,24 @@ def render(payload: dict) -> str:
                if dev.get("watermark_bytes") else ""))
     else:
         lines.append("device HBM: no backend memory stats (CPU)")
+
+    step = payload.get("step")
+    if step:
+        # the train step's own account of one chip (step_memory)
+        held = sum(step["state"].values()) + step["batch"]
+        lines.append(
+            f"train step, one chip: state + batch {fmt_bytes(held)}"
+            + (f", gradients {fmt_bytes(step['gradients'])}"
+               if step["gradients"] is not None else "")
+            + (f", temporaries {fmt_bytes(step['temporaries'])} live at "
+               f"the program's peak (gradients among them), expected "
+               f"peak {fmt_bytes(step['expected_peak'])}"
+               if step["temporaries"] is not None else
+               f", temporaries not known (this backend's peak does not "
+               f"cover them; their allocations sum to "
+               f"{fmt_bytes(step['program']['temp'])})")
+            + (f", unaccounted {step['unaccounted'] / 2 ** 20:+.1f} MiB"
+               if step["unaccounted"] is not None else ""))
 
     tiers = payload.get("tiers", {})
     if not tiers:

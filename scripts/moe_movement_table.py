@@ -112,8 +112,7 @@ def traced_cell(args):
 
     def keep_text(*a, **k):
         from deepspeed_tpu.telemetry import tracing
-        thunk = tracing._PROGRAM_THUNKS.get(STEP["program"])
-        texts.append(thunk() if thunk else None)
+        texts.append(tracing.get_program_text(STEP["program"]))
         return start_trace(*a, **k)
     jax.profiler.start_trace = keep_text
     sys.argv = [os.path.join(bench, "run.py"), "--workload", args.workload,

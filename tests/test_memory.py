@@ -35,7 +35,6 @@ from deepspeed_tpu.telemetry import (FlightRecorder, IoStat, MemoryLedger,
                                      memory_payload, reset_iostat,
                                      reset_memory_ledger, tree_bytes)
 from deepspeed_tpu.telemetry.memory import (attribute_params,
-                                            compiled_memory_stats,
                                             device_memory_stats,
                                             hbm_used_fraction)
 from tests.util import tiny_gpt2
@@ -190,19 +189,6 @@ def test_attribute_params_matches_costmodel(served):
     assert led.owner_bytes("device", "params") == want
     detail = led.snapshot()["tiers"]["device"]["owners"]["params"]["detail"]
     assert detail["plain_bytes"] == stream["plain_bytes"]
-
-
-def test_compiled_memory_stats_helper():
-    import jax.numpy as jnp
-
-    def f(x):
-        return jnp.dot(x, x.T).sum()
-
-    stats = compiled_memory_stats(f, np.ones((8, 8), np.float32))
-    if stats is None:
-        pytest.skip("backend lacks compiled memory_analysis")
-    assert stats["argument_size_in_bytes"] >= 8 * 8 * 4
-    assert "temp_size_in_bytes" in stats
 
 
 # --------------------------------------------------------------- iostat
