@@ -651,18 +651,23 @@ class DeepSpeedEngine:
             min_loss_scale=f.min_loss_scale,
             consecutive_hysteresis=f.consecutive_hysteresis)
 
-        self.state: Dict[str, Any] = {
-            "params": params,
-            "opt_state": opt_state,
-            "step": jnp.int32(0),
-            "scaler": scaler,
-        }
         self.state_shardings = {
             "params": self._nonblock_shardings,
             "opt_state": self.opt_shardings,
             "step": NamedSharding(self.mesh, P()),
             "scaler": jax.tree.map(lambda _: NamedSharding(self.mesh, P()),
                                    scaler),
+        }
+        # the counter and the scaler are born where every step returns them:
+        # left as host scalars, the second call's arguments would differ
+        # from the first's in their placement alone, and jit would build
+        # the whole step a second time for it
+        self.state: Dict[str, Any] = {
+            "params": params,
+            "opt_state": opt_state,
+            "step": jax.device_put(jnp.int32(0),
+                                   self.state_shardings["step"]),
+            "scaler": jax.device_put(scaler, self.state_shardings["scaler"]),
         }
 
         # 1-bit optimizer error-feedback buffers (reference zoadam.py /
@@ -2155,17 +2160,25 @@ class DeepSpeedEngine:
         self.state["params"] = new_params
         # overflow steps don't advance the schedule/bias-correction step
         # (reference skip semantics; matches step_programs.apply_grads)
-        self.state["step"] = self.state["step"] + jnp.where(
-            overflow, jnp.int32(0), jnp.int32(1))
+        self._write_placed("step", self.state["step"] + jnp.where(
+            overflow, jnp.int32(0), jnp.int32(1)))
         if fp16:
-            self.state["scaler"] = update_scale(
-                scaler, overflow, self.scaler_config)
+            self._write_placed("scaler", update_scale(
+                scaler, overflow, self.scaler_config))
         return {
             "loss": loss,
             "grad_norm": grad_norm,
             "overflow": overflow,
             "loss_scale": self.state["scaler"].cur_scale,
         }
+
+    def _write_placed(self, key, value):
+        """The host-side writers of the counter and the scaler (the offload
+        tiers' epilogues) leave them where the step programs return them:
+        the jitted program that takes ``self.state`` next then finds the
+        placement it was compiled for, whatever placement jax inferred
+        for the eager arithmetic that made the new value."""
+        self.state[key] = jax.device_put(value, self.state_shardings[key])
 
     def _reload_layer(self, i: int):
         """Authoritative rebuild of layer ``i``'s compute-dtype shard from
@@ -2237,7 +2250,7 @@ class DeepSpeedEngine:
             nonblock_new = {k: v for k, v in new_tree.items() if k != bk}
             self.state["params"] = jax.device_put(nonblock_new,
                                                   self._nonblock_shardings)
-            self.state["step"] = self.state["step"] + 1
+            self._write_placed("step", self.state["step"] + 1)
         store.publish(self.telemetry_registry)
         return {
             "loss": loss if loss is not None else jnp.float32(0.0),
@@ -2262,10 +2275,10 @@ class DeepSpeedEngine:
         if not overflow:
             self.state["params"] = jax.device_put(new_params,
                                                   self.param_shardings)
-            self.state["step"] = self.state["step"] + 1
+            self._write_placed("step", self.state["step"] + 1)
         if fp16:
-            self.state["scaler"] = update_scale(
-                scaler, jnp.bool_(overflow), self.scaler_config)
+            self._write_placed("scaler", update_scale(
+                scaler, jnp.bool_(overflow), self.scaler_config))
         return {
             "loss": loss if loss is not None else jnp.float32(0.0),
             "grad_norm": jnp.float32(grad_norm),
