@@ -206,12 +206,12 @@ WINDOW_BLOCKS = (512, 512)
 
 
 def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
-                            window=None):
+                            window=None, kv_of=None):
     if window is not None and window >= q.shape[1]:
         window = None               # every earlier key: the causal program
     gqa = k.shape[2] != q.shape[2]
-    # a value head narrower than the score head: the from-scratch kernel
-    # takes the two widths, the stock wrapper one — routed as GQA is
+    # a value head narrower or wider than the score head: the from-scratch
+    # kernel takes the two widths, the stock wrapper one — routed as GQA is
     two_widths = v.shape[3] != q.shape[3]
     if segment_ids is not None or window is not None:
         # packed sequences, a sliding window: only the from-scratch kernel
@@ -220,7 +220,7 @@ def _local_causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
         from deepspeed_tpu.ops.pallas.ds_flash_attention import \
             ds_flash_attention
         flash = partial(ds_flash_attention, segment_ids=segment_ids,
-                        causal=True)
+                        causal=True, kv_of=kv_of)
         if window is not None:
             flash = partial(flash, window=window, block_q=WINDOW_BLOCKS[0],
                             block_k=WINDOW_BLOCKS[1])
@@ -332,11 +332,14 @@ def _local_bidirectional_attention(q, k, v, pad_mask, impl):
 
 
 def causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
-                     window=None):
+                     window=None, kv_of=None):
     """q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd]; KV may divide
     H (GQA — the from-scratch flash kernel attends compact KV natively,
     other paths repeat); ``v`` may be narrower than ``q`` and ``k``
-    (latent attention), and the result is then ``v``'s width.
+    (latent attention) or wider (differential attention's pair of value
+    heads), and the result is then ``v``'s width.  ``kv_of``: the layer
+    whose keys and values these are where it is not the caller's own, for
+    the flash calls' account (packed or windowed calls on one device).
     ``segment_ids`` [B, S] restricts attention within packed segments
     (models thread ``batch["segment_ids"]`` here; the from-scratch kernel
     masks natively, the einsum path exactly).  ``window``: query i attends
@@ -390,9 +393,9 @@ def causal_attention(q, k, v, impl: str = "auto", segment_ids=None,
             return distributed_attention(
                 q, k, v,
                 lambda a, b, c, seg: _local_causal_attention(
-                    a, b, c, impl, seg, window),
+                    a, b, c, impl, seg, window, kv_of),
                 segment_ids=segment_ids)
         return distributed_attention(
             q, k, v, lambda a, b, c: _local_causal_attention(
-                a, b, c, impl, window=window))
-    return _local_causal_attention(q, k, v, impl, segment_ids, window)
+                a, b, c, impl, window=window, kv_of=kv_of))
+    return _local_causal_attention(q, k, v, impl, segment_ids, window, kv_of)

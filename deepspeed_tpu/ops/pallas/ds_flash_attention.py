@@ -11,10 +11,12 @@ looping over key tiles).
 Layouts: q [B, S, H, dk], k [B, S, KV, dk], v [B, S, KV, dv] (grouped-query
 attention: KV may divide H — each group of H/KV query heads reads one KV
 head, so GQA models stream KV at 1/group the HBM traffic instead of
-repeating heads).  The value head may be narrower than the score head
-(``dv <= dk``: latent attention scores at 128 + 64 and reads values at
-128): ``o``, ``do``, ``dv`` are then ``dv`` wide in HBM and in VMEM, ``q``,
-``k``, ``dq``, ``dk`` stay ``dk`` wide, and no operand is padded.
+repeating heads).  The value head may be of another width than the score
+head, narrower (latent attention scores at 128 + 64 and reads values at
+128) or wider (differential attention scores at 64 and reads a pair of
+value heads, 128): ``o``, ``do``, ``dv`` are then ``dv`` wide in HBM and in
+VMEM, ``q``, ``k``, ``dq``, ``dk`` stay ``dk`` wide, and no operand is
+padded.
 ``segment_ids`` [B, S] int32 restricts attention to same-segment pairs —
 packed-sequence training the stock wrapper lacked (pass None for a single
 segment).  The [S, S] score matrix never materialises in HBM;
@@ -299,7 +301,8 @@ def working_set_bytes(seq_len, head_dim, itemsize, block_q=512,
         # whole-S [1, S] int32 segment rows staged by the fwd/dkv/dq
         # passes (x8 sublane pad) — small next to the column term
         rows += 8 * seq_len * 4
-    tiles = (bq + bk) * hd_pad * (itemsize + 2 * 4)  # in tiles + fp32 acc
+    # in tiles + fp32 acc, at the wider of the two widths
+    tiles = (bq + bk) * max(hd_pad, v_pad) * (itemsize + 2 * 4)
     return 2 * (full_kv + rows) + tiles
 
 
@@ -371,11 +374,14 @@ def tile_counts(seq_len, block_q, block_k, causal=True, window=None):
     return [interior, boundary]
 
 
-def _record_call(q, k, v, block_q, block_k, packed, causal, window=None):
+def _record_call(q, k, v, block_q, block_k, packed, causal, window=None,
+                 kv_of=None):
     """This call's row of the step's account
     (``tracing.flash_calls``): shapes only, written while the
     step is traced; ``tiles`` is :func:`tile_counts`.  A windowed call's
-    row also holds its ``window`` and the key tiles a q-block visits."""
+    row also holds its ``window`` and the key tiles a q-block visits; a
+    call whose keys and values are another layer's, that layer
+    (``kv_of``)."""
     from deepspeed_tpu.telemetry.tracing import count_in_step
     B, S, H, hd = q.shape
     bq, bk = _choose_blocks(S, block_q, block_k)
@@ -391,14 +397,17 @@ def _record_call(q, k, v, block_q, block_k, packed, causal, window=None):
         row.update(window=window,
                    k_tiles_per_q_block=window_k_tiles(window, bq, bk))
         key += f"w{window}"
+    if kv_of is not None:
+        row.update(kv_of=kv_of)
+        key += f"kv{kv_of}"
     count_in_step(flash_calls={key: row})
 
 
 def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
                        sm_scale=None, block_q=512, block_k=512,
-                       window=None):
+                       window=None, kv_of=None):
     """q [B, S, H, dk], k [B, S, KV, dk], v [B, S, KV, dv] -> [B, S, H,
-    dv], ``dv <= dk``; ``sm_scale`` defaults to ``dk ** -0.5``.  KV may
+    dv], ``dv`` any width; ``sm_scale`` defaults to ``dk ** -0.5``.  KV may
     divide H (grouped-query attention — KV streams once per group).
     ``window`` (causal only): query i attends keys j with ``i - j <
     window``; the three kernels' loops start (dK/dV's: stop) at the first
@@ -410,7 +419,8 @@ def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
     matches an integer primal); packed sequences attend only within their
     own segment (non-differentiable — a proper custom_vjp argument, NOT a
     closure capture: closed-over tracers break under jit/scan train
-    steps)."""
+    steps).  ``kv_of``: the layer whose keys and values these are, where
+    it is not the caller's own — for the account's row only."""
     if segment_ids is not None:
         segment_ids = segment_ids.astype(jnp.int32)
     if window is not None:
@@ -422,25 +432,26 @@ def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
         if window >= q.shape[1]:
             window = None
     return _ds_flash(q, k, v, segment_ids, causal, sm_scale, block_q,
-                     block_k, window)
+                     block_k, window, kv_of)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _ds_flash(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
-              window):
+              window, kv_of):
     o, _ = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
-                window=window)
+                window=window, kv_of=kv_of)
     return o
 
 
 def _ds_flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q,
-                  block_k, window):
+                  block_k, window, kv_of):
     o, res = _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
-                  window=window)
+                  window=window, kv_of=kv_of)
     return o, (res, segment_ids)
 
 
-def _ds_flash_bwd(causal, sm_scale, block_q, block_k, window, res_seg, do):
+def _ds_flash_bwd(causal, sm_scale, block_q, block_k, window, kv_of,
+                  res_seg, do):
     res, segment_ids = res_seg
     dq, dk, dv = _bwd_rule(segment_ids, causal, sm_scale, block_q,
                            block_k, res, do, window)
@@ -455,7 +466,7 @@ _ds_flash.defvjp(_ds_flash_fwd, _ds_flash_bwd)
 
 
 def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
-         interpret=None, window=None):
+         interpret=None, window=None, kv_of=None):
     # interpret=None leaves the pallas default (and any test monkeypatch)
     # in force; True forces interpret mode (ring path off-TPU)
     _ikw = {} if interpret is None else {"interpret": interpret}
@@ -464,16 +475,16 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
     if H % KV:
         raise ValueError(f"ds_flash_attention: q heads {H} not a multiple "
                          f"of kv heads {KV}")
-    if k.shape[3] != hd or hv > hd:
+    if k.shape[3] != hd:
         raise ValueError(
             f"ds_flash_attention: q and k share the score width (q {hd}, k "
-            f"{k.shape[3]}) and v may be narrower, not wider (v {hv})")
+            f"{k.shape[3]}); only v may be of another (v {hv})")
     rep = H // KV
     sm = sm_scale if sm_scale is not None else hd ** -0.5
     bq, bk = _choose_blocks(S, block_q, block_k)
     qT, kT, vT = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     has_seg = segment_ids is not None
-    _record_call(q, k, v, block_q, block_k, has_seg, causal, window)
+    _record_call(q, k, v, block_q, block_k, has_seg, causal, window, kv_of)
     # TPU-legal layouts for per-row operands (Mosaic requires the last two
     # block dims to divide (8, 128) or equal the array dims — a bare
     # [B, S] block fails): segment ids (int32, cast once in the public

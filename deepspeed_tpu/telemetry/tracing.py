@@ -374,6 +374,19 @@ SCOPE_ATTN_FULL = "ds.attn_full"
 SCOPE_ATTN_SLIDING = "ds.attn_sliding"
 SCOPE_HEAD_GATE = "ds.head_gate"
 SCOPE_LEAD_MLP = "ds.lead_mlp"
+# inside ``ds.block``, where the mixer is a Mamba-1 layer, a gated memory
+# unit or differential attention (models/phi4flash.py).  ``mamba`` holds
+# the norm, ``in_proj``, ``conv``, ``scan`` (the selective scan), ``gate``
+# and ``out_proj``; ``gmu`` the whole unit; ``diff_attn`` the norm,
+# ``qkv``, ``flash`` (the two maps' flash calls), ``combine`` (the lambda
+# combine and its norm) and ``out_proj`` — the windowed, the full and the
+# cross layers alike: the flash calls' account tells them apart
+SCOPE_MAMBA = "mamba"
+SCOPE_GATE = "gate"
+SCOPE_GMU = "gmu"
+SCOPE_DIFF_ATTN = "diff_attn"
+SCOPE_QKV = "qkv"
+SCOPE_FLASH = "flash"
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
@@ -382,18 +395,21 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN, SCOPE_Q_LATENT,
                SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP,
                SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_HEAD_GATE,
-               SCOPE_LEAD_MLP, SCOPE_EXCHANGE, SCOPE_SEND, SCOPE_RETURN)
+               SCOPE_LEAD_MLP, SCOPE_EXCHANGE, SCOPE_SEND, SCOPE_RETURN,
+               SCOPE_MAMBA, SCOPE_GATE, SCOPE_GMU, SCOPE_DIFF_ATTN,
+               SCOPE_QKV, SCOPE_FLASH)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
 #: the state-space scan's two and the short causal convolution's two, the
-#: flash kernel's three where the call has a sliding window, and the sum of
-#: a held plan's rows into their tokens
+#: flash kernel's three where the call has a sliding window, the sum of a
+#: held plan's rows into their tokens, and the selective scan's two
 KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
                 "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
                 "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd",
                 "ds_conv_fwd", "ds_conv_bwd", "ds_flash_win_fwd",
-                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq", "ds_rowsum")
+                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq", "ds_rowsum",
+                "ds_sscan_fwd", "ds_sscan_bwd")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -839,6 +855,19 @@ def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "ssd_calls")
 
 
+def selective_scan_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The selective scans (Mamba-1) of the step as ops/selective_scan.py
+    traced them: one row per shape and, where the caller names it, per
+    ``layer`` — ``batch``, ``positions``, ``channels``, ``state``,
+    ``chunk`` and ``path``: ``"kernel"`` where the call ran as the Mosaic
+    kernels ``ds_sscan_fwd`` / ``ds_sscan_bwd`` (then also
+    ``channels_per_step`` and ``chunks_per_step``, the channels and chunks
+    one grid step takes), ``"xla"`` where it fell back to the chunked form
+    (an associative scan a chunk inside a ``lax.scan``).  None where the
+    step has no such call."""
+    return _account_rows(name, "selective_scan_calls")
+
+
 def conv_calls(name: str = TRAIN_STEP_PROGRAM):
     """The short causal convolutions of the step as
     ops/linear_attention.py ``causal_conv`` traced them: one row per shape
@@ -863,8 +892,11 @@ def flash_calls(name: str = TRAIN_STEP_PROGRAM):
     (None: what a call is granted unasked), and ``tiles``: the
     ``[interior, boundary]`` score tiles a head's pass visits — wholly
     below the diagonal and inside the window, or crossed by one of them
-    (``ds_flash_attention.tile_counts``).  None where the step has no
-    such call (the XLA einsum took its place, or there is no attention)."""
+    (``ds_flash_attention.tile_counts``); a windowed call's row also has
+    its ``window``, and a call whose keys and values are another layer's
+    has that layer under ``kv_of`` (and a row of its own).  None where the
+    step has no such call (the XLA einsum took its place, or there is no
+    attention)."""
     return _account_rows(name, "flash_calls")
 
 

@@ -5,7 +5,9 @@ weight panels resident in VMEM) and at Mixtral-8x7B's, the flash kernels at JoyA
 192-wide score, a 128-wide value) and the grouped ones at its 768-wide
 experts, the gated delta rule's two at Qwen3-Next's, the state-space scan's two at Nemotron-H's
 and the short causal convolution's two at both hybrids' (each in its
-orientation) go through Mosaic, the data-sharded flash kernel
+orientation), the selective scan's two and the flash kernels at a 64-wide
+score and a 128-wide value head at Phi-4-mini-flash's (one packed sequence
+of 16,384) go through Mosaic, the data-sharded flash kernel
 goes through the partitioner, and the library knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
@@ -145,6 +147,15 @@ def _ssd(x, dt, A, Bm, Cm, D, seg):
     return ssd.ssd_kernels(x, dt, A, Bm, Cm, D, seg, blocking)
 
 
+def _sscan(u, dt, A, Bm, Cm, D, bias, first):
+    """The selective scan's kernels as ops/selective_scan.py calls them on
+    one TPU (the choice switched off: no TPU here), with the blocking the
+    library chooses."""
+    from deepspeed_tpu.ops.pallas import selective_scan as sscan
+    blocking = sscan.blocking(u.shape[2], A.shape[1], 128, u.dtype.itemsize)
+    return sscan.sscan_kernels(u, dt, A, Bm, Cm, D, bias, first, blocking)
+
+
 def _conv(positions, first=0):
     """The causal convolution's kernels as ops/linear_attention.py calls
     them on one TPU (the choice switched off: no TPU here), with the slab
@@ -221,6 +232,18 @@ _GGEMM_W768_DOWN = _ggemm_args(16, 131072 + 16 * 128, 768, 2048)
 _QKV_GQA9_8K = [((1, 8192, 72, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192), jnp.int32)]
+# phi-4-mini-flash-reasoning.packed-s16384-traces: one map of a differential
+# layer — 20 query heads to 10, a score head of 64 (half a lane tile) and a
+# value head of 128: wider values than keys — and a Mamba-1 layer's scan:
+# 5,120 channels, 16 states
+_QKV_DIFF_16K = [((1, 16384, 20, 64), jnp.bfloat16),
+                 ((1, 16384, 10, 64), jnp.bfloat16),
+                 ((1, 16384, 10, 128), jnp.bfloat16), ((1, 16384), jnp.int32)]
+_SSCAN_16K = [((1, 16384, 5120), jnp.bfloat16),
+              ((1, 16384, 5120), jnp.bfloat16), ((5120, 16), jnp.float32),
+              ((1, 16384, 16), jnp.bfloat16), ((1, 16384, 16), jnp.bfloat16),
+              ((5120,), jnp.float32), ((5120,), jnp.float32),
+              ((1, 16384), jnp.bool_)]
 _QKV_GQA6_8K = [((1, 8192, 48, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192), jnp.int32)]
@@ -269,6 +292,13 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_windowed), (0, 1, 2)), _QKV_GQA9_8K),
     "ds_flash_gqa6_s8192_hd128_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_GQA6_8K),
+    "ds_flash_diff_s16384_dk64_dv128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_DIFF_16K),
+    "ds_flash_win512_diff_s16384_dk64_dv128_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_ds_flash_windowed), (0, 1, 2)), _QKV_DIFF_16K),
+    "ds_sscan_s16384_packed_fwd": (_sscan, _SSCAN_16K),
+    "ds_sscan_s16384_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_sscan), (0, 1, 2, 3, 4, 5, 6)), _SSCAN_16K),
     "ds_ggemm_w1024_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
                                _GGEMM_W1024),
     "ds_ggemm_w1024_down_fwd_bwd": (jax.grad(_sum_sq(_ggemm_held), (0, 1)),
@@ -332,6 +362,12 @@ NAMED_KERNELS = {
         "ds_flash_win_fwd", "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq"},
     "ds_flash_gqa6_s8192_hd128_packed_fwd_bwd": {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
+    "ds_flash_diff_s16384_dk64_dv128_packed_fwd_bwd": {
+        "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
+    "ds_flash_win512_diff_s16384_dk64_dv128_packed_fwd_bwd": {
+        "ds_flash_win_fwd", "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq"},
+    "ds_sscan_s16384_packed_fwd": {"ds_sscan_fwd"},
+    "ds_sscan_s16384_packed_fwd_bwd": {"ds_sscan_fwd", "ds_sscan_bwd"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
     "ds_ggemm_w768_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_ggemm_w768_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx",
@@ -405,9 +441,10 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
             assert dsf.working_set_bytes(
                 c["seq_len"], c["dk"], 2, *c["blocks"], c["packed"],
                 c["dv"]) <= gg.vmem.budget()
-            assert c["tiles"] == (
-                [1, 2] if c["seq_len"] == S
-                else [0, 31] if c.get("window") else [120, 16]), c
+            assert c["tiles"] == {
+                (S, None): [1, 2], (8192, None): [120, 16],
+                (8192, 512): [0, 31], (16384, None): [496, 32],
+                (16384, 512): [0, 63]}[c["seq_len"], c.get("window")], c
     if "relu2" in case or "mla" in case:
         # a dim of 14.5 x 128 is one block: no padded copy of the expert
         # stack (or of the rows) is written beside the kernels; a value
@@ -423,13 +460,21 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
         assert [(c["heads"], c["kv_heads"], c["window"], c["blocks"],
                  c["k_tiles_per_q_block"])
                 for c in tracing.flash_calls("test/compile")] \
-            == [(72, 8, 512, list(WINDOW_BLOCKS),
-                 window_k_tiles(512, *WINDOW_BLOCKS))]
-    if "mla" in case:
-        # the step's account of its flash calls has both widths
+            == [((20, 10) if "diff" in case else (72, 8))
+                + (512, list(WINDOW_BLOCKS),
+                   window_k_tiles(512, *WINDOW_BLOCKS))]
+    if "mla" in case or "diff" in case:
+        # the step's account of its flash calls has both widths, the value
+        # head's narrower or wider than the score head's
         assert [(c["dk"], c["dv"], c["heads"], c["kv_heads"], c["packed"])
                 for c in tracing.flash_calls("test/compile")] \
-            == [(192, 128, 32, 32, True)]
+            == [(192, 128, 32, 32, True) if "mla" in case
+                else (64, 128, 20, 10, True)]
+    if "sscan" in case:
+        from deepspeed_tpu.ops.pallas import selective_scan as sscan
+        assert sscan.blocking(5120, 16, 128, 2).channels == 512
+        assert sscan.blocking(5120, 16, 128, 2).vmem_bytes \
+            <= gg.vmem.UNASKED
     if case in GGEMM_REGIMES:
         calls = tracing.grouped_gemm_rows("test/compile")["calls"]
         assert {c["kernel"]: c["regime"] for c in calls} \

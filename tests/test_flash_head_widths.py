@@ -1,5 +1,6 @@
 """The flash kernels with a value head of another width than the score
-head (latent attention: q, k 128 + 64 wide, v 128): forward and all three
+head (latent attention: q, k 128 + 64 wide, v 128; differential attention:
+q, k 64 wide, v a pair of heads, 128): forward and all three
 gradients in Pallas' interpreter against the XLA einsum, the sizing
 functions and the dispatch taking both widths, the step's account of its
 flash calls — and, where the two widths are one, every family the
@@ -29,6 +30,8 @@ SHAPES = {
     "toy_24_16_gqa": (64, 4, 2, 24, 16, 32),
     "mla_192_128": (32, 2, 2, 192, 128, 16),
     "mla_192_128_gqa": (32, 2, 1, 192, 128, 16),
+    "toy_16_24_wider": (64, 4, 2, 16, 24, 32),
+    "diff_64_128_gqa": (32, 4, 2, 64, 128, 16),
 }
 
 
@@ -87,12 +90,35 @@ def test_the_scale_is_the_score_heads(interpret_pallas):
     assert float(jnp.abs(out - other).max()) > 1e-3
 
 
-def test_a_wider_value_head_and_unlike_score_heads_are_refused():
+def test_unlike_score_heads_are_refused_and_a_wider_value_head_is_not():
     q, k, v, _ = _inputs(32, 2, 2, 16, 24)
-    with pytest.raises(ValueError, match="narrower, not wider"):
-        ds_flash_attention(q, k, v)
     with pytest.raises(ValueError, match="share the score width"):
         ds_flash_attention(q, k[..., :8], v[..., :8])
+    assert jax.eval_shape(ds_flash_attention, q, k, v).shape \
+        == (2, 32, 2, 24)
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["causal", "window_24"])
+def test_wider_values_packed_as_differential_attention_calls_them(
+        window, interpret_pallas):
+    """Score width 64, value width 128, two query heads to a key head,
+    packed — under a window and without: one of the two maps of a
+    differential layer (models/phi4flash.py)."""
+    S, H, KV, dk, dv, block = 64, 4, 2, 64, 128, 16
+    q, k, v, w = _inputs(S, H, KV, dk, dv, seed=3)
+    seg = _segments(S, True)
+    rep = H // KV
+    flash = lambda q, k, v: ds_flash_attention(
+        q, k, v, segment_ids=seg, window=window, block_q=block,
+        block_k=block)
+    einsum = lambda q, k, v: xla_causal_attention(
+        q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2), seg,
+        window)
+    np.testing.assert_allclose(flash(q, k, v), einsum(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(einsum(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.abs(b).max()))
 
 
 def test_the_working_set_counts_both_widths():
@@ -108,6 +134,12 @@ def test_the_working_set_counts_both_widths():
     assert dsf.vmem_fits(8192, 192, 2, budget_bytes=two, packed=True,
                          v_head_dim=128)
     assert not dsf.vmem_fits(8192, 192, 2, budget_bytes=two, packed=True)
+    # wider values than keys: 64 / 128 stages, and tiles, as 128 / 128 does
+    # (64 pads to a lane tile); 64 / 256 more than that
+    wider = dsf.working_set_bytes(16384, 64, 2, packed=True, v_head_dim=128)
+    assert wider == dsf.working_set_bytes(16384, 128, 2, packed=True)
+    assert dsf.working_set_bytes(16384, 64, 2, packed=True,
+                                 v_head_dim=256) > wider
 
 
 def test_the_dispatch_checks_and_routes_by_both_widths(monkeypatch,

@@ -87,14 +87,15 @@ def test_fixed_names():
                            "kv_latent", "rope", "scores", "ds.mtp",
                            "ds.attn_full", "ds.attn_sliding",
                            "ds.head_gate", "ds.lead_mlp", "exchange",
-                           "exchange_send", "exchange_return")
+                           "exchange_send", "exchange_return", "mamba",
+                           "gate", "gmu", "diff_attn", "qkv", "flash")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
                             "ds_ssd_fwd", "ds_ssd_bwd", "ds_conv_fwd",
                             "ds_conv_bwd", "ds_flash_win_fwd",
                             "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq",
-                            "ds_rowsum")
+                            "ds_rowsum", "ds_sscan_fwd", "ds_sscan_bwd")
 
 
 # ------------------------------------------------------- the text's parser
