@@ -118,7 +118,9 @@ class MoEConfig:
     #: scores over un-gated relu2 experts at initialisation) needs more.
     #: It buys safety with memory (the plan's ``[bound, ·]`` buffers), not
     #: with time: dispatch, the grouped kernels and combine walk the rows
-    #: that are routed here and stop (``grouped_gemm.live_rows``)
+    #: that are routed here and stop (``grouped_gemm.live_rows``), and an
+    #: exchange's receive buffer is initialised a tile a held expert
+    #: (``grouped_gemm.zeroed_padding``), whatever the bound
     held_rows_factor: int = 2
     #: width of a shared expert every token passes through beside the
     #: routed ones (0 = none), added to their sum; ``shared_expert_gate``
@@ -544,7 +546,10 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
        (``lax.ragged_all_to_all``: the rows there are and no padding), one
        slice a (chip, expert): a slice lands inside its expert's group of
        the receiver's plan, behind those of the senders before, so what
-       arrives IS the group-padded array the kernels read; and a second,
+       arrives IS the group-padded array the kernels read — in a buffer
+       nobody filled: one small kernel zeroes each group's last tile first
+       (its padding rows: ``grouped_gemm.zeroed_padding``), and behind the
+       live prefix nothing is written; and a second,
        narrow one, of the gates through the same sizes: ``[rows, 128]``
        float32 (:data:`_GATE_LANES`: a ``[rows, 1]`` array travels as
        wide, and is re-laid on both sides of its call: 1.9 ms a call on a
@@ -620,6 +625,11 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
         exchange_calls={f"{t_chip}x{D}:{n}": {
             "pairs": n, "experts_held": held, "tokens": t_chip,
             "routed_rows": R, "receive_rows": bound, "width": D,
+            # what a call writes of its receive buffer before the rows
+            # arrive: the last tile of each held expert's group, where the
+            # group's padding rows lie — behind the live prefix nothing
+            "receive_fill": "padding_tiles",
+            "zeroed_rows_per_call": held * gg.default_block_m(),
             "even_rows_per_pair": R // n,
             # what one all-to-all of rows puts on a chip's links under
             # even routing: the rows for the other chips, nothing else
@@ -655,7 +665,7 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
         with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
             x_pad = mappings.exchange_forth(buf, sizes, plan.padded_rows)
             row_gate = mappings.exchange_forth(gate_buf, sizes,
-                                               plan.padded_rows)
+                                               plan.padded_rows, "gates")
         _emit_held_plan(plan)
         _emit_exchanged(jnp.sum(sizes.send), jnp.sum(sizes.held))
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)

@@ -36,6 +36,14 @@ cotangent, formed on the expert's chip, comes home by
 :func:`exchange_back`'s movement.  What a rematerialised layer runs of
 them: forward out, out (gates) and back; recompute out and out (gates) —
 nothing that came back is a residual; backward the three transposes.
+What a call lands in depends on its side.  Out (:func:`_forth`: rows,
+gates, and the cotangents of what came back), the buffer is the
+receiver's bound, several times what arrives: it is born unwritten, the
+last tile of each held expert's group is zeroed (the group's padding rows,
+which the grouped kernels multiply), and behind the plan's live prefix
+nothing is written, as nothing there is read.  Home (:func:`_back`), zeros:
+a sender's layout is nearly all live, and a place whose row found no room
+reads exact zero.
 """
 import functools
 from typing import NamedTuple
@@ -152,24 +160,44 @@ def exchange_path() -> str:
     return RAGGED_ALL_TO_ALL if _on_tpu() else ALL_TO_ALL
 
 
+def _as_sent(rows):
+    """The shape behind the first axis in which the chip's collective moves
+    a row of ``rows`` [., width]: ``lax.ragged_all_to_all`` copies rows
+    from any offset to any offset, so the compiler gives it arrays whose
+    every row is whole tiles of its own — ``[packing, width / packing]``,
+    ``packing`` the elements a 32-bit word holds (bf16 ``[rows, 2304]``
+    travels as ``[rows, 2, 1152]`` in tiles of 2 x 128, float32 ``[rows,
+    128]`` as ``[rows, 1, 128]``) — and re-tiles, a pass over the array,
+    what comes in another.  A ``broadcast`` is born in any tiling; a Mosaic
+    call's result in its shape's, so the buffer a kernel makes for the
+    collective to land in is asked for in this shape (:func:`_forth`).
+    Off the chip, and for a width that is no whole tiles: as it is."""
+    packing = 4 // rows.dtype.itemsize
+    if exchange_path() != RAGGED_ALL_TO_ALL or rows.ndim != 2 \
+            or rows.shape[1] % (128 * packing):
+        return rows.shape[1:]
+    return (packing, rows.shape[1] // packing)
+
+
 def _rows_at(x, idx):
     return x.at[idx].get(mode="promise_in_bounds")
 
 
-def _ragged(rows, out_rows, send_at, send, land_at, held):
-    """``lax.ragged_all_to_all`` over the ``expert`` axis into a buffer of
-    ``out_rows`` rows of zeros, any whole number of slices a chip (a chip's
-    side by side): slice ``i`` is ``send[i]`` rows from ``send_at[i]`` on,
-    lands from ``land_at[i]`` on at its chip, and ``held[i]`` rows arrive
-    for it.  Where the backend has no such collective
-    (:func:`exchange_path`: the CPU's test mesh) the same movement by
-    ``lax.all_to_all``: every chip's whole buffer and where its slices lie,
-    each row of the result then looked up in the slice that covers it."""
-    out = jnp.zeros((out_rows,) + rows.shape[1:], rows.dtype)
+def _ragged(rows, out, send_at, send, land_at, held):
+    """``lax.ragged_all_to_all`` over the ``expert`` axis into ``out``, any
+    whole number of slices a chip (a chip's side by side): slice ``i`` is
+    ``send[i]`` rows from ``send_at[i]`` on, lands from ``land_at[i]`` on at
+    its chip, and ``held[i]`` rows arrive for it; a row of ``out`` that no
+    slice covers keeps what it held.  Where the backend has no such
+    collective (:func:`exchange_path`: the CPU's test mesh) the same
+    movement by ``lax.all_to_all``: every chip's whole buffer and where its
+    slices lie, each row of the result then looked up in the slice that
+    covers it."""
     if exchange_path() == RAGGED_ALL_TO_ALL:
         return lax.ragged_all_to_all(rows, out, send_at, send, land_at,
                                      held, axis_name=EXPERT_AXIS)
-    n, length = lax.axis_size(EXPERT_AXIS), rows.shape[0]
+    n, length, out_rows = lax.axis_size(EXPERT_AXIS), rows.shape[0], \
+        out.shape[0]
     to_me = lambda a: lax.all_to_all(                   # noqa: E731
         a.reshape(n, -1), EXPERT_AXIS, 0, 0).reshape(-1)
     got = lax.all_to_all(jnp.broadcast_to(rows, (n,) + rows.shape),
@@ -187,38 +215,53 @@ def _ragged(rows, out_rows, send_at, send, land_at, held):
         (-1,) + (1,) * (rows.ndim - 1)), row, out)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _forth(rows, sizes: ExchangeSizes, out_rows):
-    return _ragged(rows, out_rows, sizes.send_at, sizes.send, sizes.land_at,
-                   sizes.held)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _forth(rows, sizes: ExchangeSizes, out_rows, what):
+    """Out, into a receive plan's buffer — the bound, mostly behind the live
+    prefix: born unwritten, its groups' padding tiles zeroed
+    (``grouped_gemm.zeroed_padding``; ``what`` names the buffer's use, as
+    there), then the rows that arrive."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import zeroed_padding
+    # the buffer waits for the rows as they leave, not as they came: the
+    # array they came in would else be live beside its re-tiled self
+    sent = rows.reshape(rows.shape[:1] + _as_sent(rows))
+    out = zeroed_padding(sizes.counts, (out_rows,) + sent.shape[1:],
+                         rows.dtype, sent, what)
+    return _ragged(sent, out, sizes.send_at, sizes.send, sizes.land_at,
+                   sizes.held).reshape((out_rows,) + rows.shape[1:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _back(rows, sizes: ExchangeSizes, out_rows):
-    return _ragged(rows, out_rows, sizes.held_at, sizes.held, sizes.home_at,
-                   sizes.send)
+    """Home, into zeros: a sender's layout is nearly all live, and a place
+    whose row found no room at its chip has to read exact zero."""
+    return _ragged(rows, jnp.zeros((out_rows,) + rows.shape[1:], rows.dtype),
+                   sizes.held_at, sizes.held, sizes.home_at, sizes.send)
 
 
 # each is the other's transpose: a row's cotangent travels the way back
 _forth.defvjp(
-    lambda rows, sizes, out_rows: (_forth(rows, sizes, out_rows),
-                                   (sizes, rows.shape[0])),
-    lambda out_rows, res, g: (_back(g, res[0], res[1]), None))
+    lambda rows, sizes, out_rows, what: (_forth(rows, sizes, out_rows, what),
+                                         (sizes, rows.shape[0])),
+    lambda out_rows, what, res, g: (_back(g, res[0], res[1]), None))
 _back.defvjp(
     lambda rows, sizes, out_rows: (_back(rows, sizes, out_rows),
                                    (sizes, rows.shape[0])),
-    lambda out_rows, res, g: (_forth(g, res[0], res[1]), None))
+    lambda out_rows, res, g: (_forth(g, res[0], res[1], "cotangents"), None))
 
 
-def exchange_forth(rows: jnp.ndarray, sizes: ExchangeSizes, plan_rows: int):
+def exchange_forth(rows: jnp.ndarray, sizes: ExchangeSizes, plan_rows: int,
+                   what: str = "rows"):
     """One all-to-all of rows over the ``expert`` axis, inside a
     ``shard_map`` that maps it: this chip's rows ``[., D]`` in its send
     layout (a held plan over all experts) -> ``[plan_rows, D]`` in its
     receive layout, the group-padded array the grouped kernels read: within
-    an expert's group the rows by sender, a sender's in its routed order;
-    every other row — the groups' padding, and all behind the last group —
-    exact zeros."""
-    return _forth(rows, sizes, int(plan_rows))
+    an expert's group the rows by sender, a sender's in its routed order,
+    and the group's padding exact zeros; behind the last group — the plan's
+    live prefix, ``grouped_gemm.live_rows`` — nothing is written, as in any
+    ``[plan_rows, ·]`` array of a held plan.  ``what`` names the buffer
+    among a layer's of one shape (``grouped_gemm.zeroed_padding``)."""
+    return _forth(rows, sizes, int(plan_rows), what)
 
 
 def exchange_back(rows: jnp.ndarray, sizes: ExchangeSizes, plan_rows: int):

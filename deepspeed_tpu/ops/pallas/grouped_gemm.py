@@ -86,7 +86,9 @@ these kernels from the flash kernel in a compiled step: ``ds_ggemm_fwd``,
 ``ds_ggemm_slots_q`` (decode-sized), and ``ds_rowsum`` (a held plan's
 rows summed into their tokens).  ``ds_unwritten_*`` are no kernels
 but allocations: the buffers a held plan's loops write their chunks into
-(:func:`_unwritten`).
+(:func:`_unwritten`); ``ds_zeroed_padding_*`` is such an allocation with
+zeros in the last tile of each group and nothing written elsewhere: the
+buffer an exchange's rows land in (:func:`zeroed_padding`).
 
 A held subset of the experts (``make_held_group_plan``: expert
 parallelism's share of a layer) lays its rows out in a plan whose length
@@ -551,6 +553,81 @@ def _unwritten(shape, dtype, after, what):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         name=f"ds_unwritten_{what}")(after)
+
+
+def _padding_tiles(counts, padded_rows, block_m):
+    """[E] int32: the last M-tile of each group of the held plan of
+    ``padded_rows`` rows that ``counts`` [E] lay out
+    (:func:`held_group_starts`) — the tiles that hold the groups' padding
+    rows, one a group (a group has a tile at least, so no two are one), all
+    inside the live prefix."""
+    E = int(counts.shape[0])
+    starts, sizes = held_group_starts(counts, padded_rows - E * block_m,
+                                      block_m)
+    return (starts + sizes) // block_m - 1
+
+
+def _zero_tiles(buf, tiles, block_m):
+    """``buf`` [Mp, ·] with its M-tiles ``tiles`` zeros: the XLA form."""
+    zeros = jnp.zeros((block_m,) + buf.shape[1:], buf.dtype)
+    return jax.lax.fori_loop(
+        0, tiles.shape[0],
+        lambda e, out: _put_chunk(out, zeros, tiles[e] * block_m), buf)
+
+
+def _pallas_zeroed_tiles(tiles, block_m, shape, dtype, after, what,
+                         interpret=False):
+    """An unwritten ``shape`` = [Mp, ·] buffer (:func:`_unwritten`, and
+    ``after`` and ``what`` as there) but for its M-tiles ``tiles``, which
+    are zeros: a tile of zeros copied over each, HBM to HBM, so the kernel
+    touches no element and takes the buffer in whatever tiling its shape
+    has."""
+    n = int(tiles.shape[0])
+
+    def kernel(tiles_ref, zeros_ref, after_ref, out_ref, sems):
+        def copy(e):
+            start = pl.multiple_of(tiles_ref[e] * block_m, block_m)
+            return pltpu.make_async_copy(
+                zeros_ref, out_ref.at[pl.ds(start, block_m)], sems.at[e])
+
+        jax.lax.fori_loop(0, n, lambda e, _: copy(e).start(), None)
+        jax.lax.fori_loop(0, n, lambda e, _: copy(e).wait(), None)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((n,))]),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        interpret=interpret,
+        name=f"ds_zeroed_padding_{what}")(
+            tiles.astype(jnp.int32),
+            jnp.zeros((block_m,) + shape[1:], dtype), after)
+
+
+def zeroed_padding(counts, shape, dtype, after, what):
+    """The ``shape`` = [Mp, ·] buffer that rows put at their places in
+    the groups of a held plan land in (an exchange's receive buffer:
+    moe/mappings.py ``_forth``; ``counts`` [E] as
+    :func:`make_counted_group_plan` takes them, ``Mp`` its padded rows):
+    **exact zeros in the groups' padding rows, and nothing written
+    elsewhere** — a group's padding is the rest of its last tile, which is
+    zeroed whole here and whose live rows whoever lands the rows writes
+    afterwards; every other row either arrives or lies behind the live
+    prefix, where nothing reads.  On a chip one Mosaic call
+    (``ds_zeroed_padding_<what>``) is the allocation and the zeros, ``E``
+    copies of a tile; ``after`` and ``what`` as :func:`_unwritten` takes
+    them, and for its reasons.  Off the chip :func:`_unwritten`'s zeros
+    with the same tiles zeroed over them."""
+    bm = default_block_m()
+    tiles = _padding_tiles(counts, shape[0], bm)
+    use_reference, interpret = _use_reference(None)
+    if use_reference or interpret:
+        return _zero_tiles(_unwritten(shape, dtype, after, what), tiles, bm)
+    return _pallas_zeroed_tiles(tiles, bm, shape, dtype, after, what)
 
 
 def _over_live_chunks(padded_rows, chunk, live, body, carry):

@@ -22,7 +22,10 @@ instruction's op_name, result shape), the sum by phase, and every
 included — that sits under one of the two scopes; where the cell
 exchanges its rows, the same table of every instruction under
 ``/mlp/shard_map/exchange/`` (a call's collective beside the copies and
-fills around it: ``exchange_rows`` of ``--out``).
+fills around it: ``exchange_rows`` of ``--out``), and of every instruction
+under no ``ds.*`` scope at all that takes half a millisecond a step — what
+``step.unattributed_ms_per_step`` is made of, the compiler's re-tiling
+``copy`` of what a collective returned among it (``unattributed_rows``).
 """
 import argparse
 import json
@@ -45,7 +48,8 @@ def scope_rows(dev, table, tr, step_phase, scope=SCOPE,
                below=r"/mlp/(?:shard_map/)?"):
     """(steps traced, [row]) of one device: self time, executions, phase,
     op_name below ``below`` (a pattern) and result shape of each
-    instruction whose scope path matches ``scope``."""
+    instruction whose scope path matches ``scope`` — or, with no ``scope``,
+    whose phase is ``other``: no ``ds.*`` scope names it."""
     steps = sum(1 for _, _, text in dev.events(tr.MODULES)
                 if re.search(STEP["module"], text))
     ns, calls, shape = defaultdict(int), defaultdict(int), {}
@@ -53,7 +57,11 @@ def scope_rows(dev, table, tr, step_phase, scope=SCOPE,
     def moves(text):
         name = step_phase.instruction(text)
         row = table.get(name)
-        return name if row and scope.search(row["scope"] or "") else None
+        if not row:
+            return None
+        named = row["phase"] == "other" if scope is None \
+            else scope.search(row["scope"] or "")
+        return name if named else None
 
     for s, e, text in step_phase.in_step(dev, dev.segments(), STEP):
         name = moves(text)
@@ -67,7 +75,8 @@ def scope_rows(dev, table, tr, step_phase, scope=SCOPE,
     rows = [{"instruction": name, "phase": table[name]["phase"],
              "ms_per_step": ns[name] * 1e-6 / steps,
              "calls_per_step": calls[name] / steps,
-             "op": re.split(below, table[name]["scope"], maxsplit=1)[1],
+             "op": re.split(below, table[name]["scope"] or "",
+                            maxsplit=1)[-1],
              "kernel": table[name].get("kernel"),
              "shape": shape[name]} for name in ns]
     rows.sort(key=lambda r: (r["phase"], -r["ms_per_step"]))
@@ -145,6 +154,10 @@ def main():
     _, exchange_rows = scope_rows(dev, table, tr, step_phase, scope=EXCHANGE)
     if exchange_rows:
         print(json.dumps({"exchange_ms_per_step": print_rows(exchange_rows)}))
+    unattributed_rows = [r for r in scope_rows(
+        dev, table, tr, step_phase, scope=None)[1] if r["ms_per_step"] >= 0.5]
+    print(json.dumps({"unattributed_ms_per_step":
+                      print_rows(unattributed_rows)}))
     scatters = scatters_in(text) if text else None
     print(json.dumps({"scatters_under_dispatch_or_combine":
                       None if scatters is None else len(scatters),
@@ -154,7 +167,8 @@ def main():
         with open(args.out, "w") as f:
             json.dump({"rows": rows, "ms_per_step": sums,
                        "steps_traced": steps, "scatters": scatters,
-                       "exchange_rows": exchange_rows}, f, indent=1)
+                       "exchange_rows": exchange_rows,
+                       "unattributed_rows": unattributed_rows}, f, indent=1)
 
 
 if __name__ == "__main__":

@@ -757,12 +757,28 @@ def test_the_exchanged_layers_backward_waits_for_its_recompute(v5e,
     _after`` ties the backward to the recompute's end by arithmetic the
     compiler cannot fold: 5,023 MiB (the parent's program 4,847).  A
     compiler that learns to fold the tie, or an edit that drops it, fails
-    here and nowhere else."""
+    here and nowhere else.
+
+    The same text holds how an exchange's buffers are born (two layer
+    bodies, the loop's and the full layer's; a rematerialised pass lands
+    three row-wide calls and two narrow ones in receive-sized buffers and
+    brings two row-wide and one narrow home): every ``ragged-all-to-all``
+    whose result is the bound's ``[198656, ·]`` lands in the result of a
+    ``ds_zeroed_padding_<what>`` call, as it lies — no ``broadcast``, and
+    no ``copy`` or ``reshape`` of the 0.92 GB between the two (the kernel
+    makes the buffer in the shape the collective moves it in,
+    ``mappings._as_sent``) — and every call home still lands in zeros.  The
+    buffer waits for the rows *as they leave* (``mappings._forth``): tied
+    to the array they came in, that array stays live beside its re-tiled
+    self and the step's peak reads 0.51 GiB more (8,202 MiB here for
+    7,678)."""
+    import re
     from deepspeed_tpu.comm.mesh import (MeshTopology, reset_topology,
                                          set_topology)
     from deepspeed_tpu.models.mellum import mellum_model
     from deepspeed_tpu.ops import attention
     from deepspeed_tpu.ops.pallas import vmem
+    from deepspeed_tpu.telemetry import tracing
     monkeypatch.setattr(vmem, "device_kind",
                         lambda: v5e[0].device_kind.lower())
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
@@ -781,14 +797,34 @@ def test_the_exchanged_layers_backward_waits_for_its_recompute(v5e,
         tokens = jax.ShapeDtypeStruct(
             (4, 8192), jnp.int32, sharding=NamedSharding(topo.mesh,
                                                          P("expert")))
-        memory = jax.jit(jax.value_and_grad(
+        compiled = jax.jit(jax.value_and_grad(
             model.loss_with_counts_fn, has_aux=True)).lower(
                 params, {"input_ids": tokens, "segment_ids": tokens}
-            ).compile().memory_analysis()
+            ).compile()
     finally:
         reset_topology()
+    memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 5400 * 2 ** 20, \
         memory.temp_size_in_bytes / 2 ** 20
+    assert memory.peak_memory_in_bytes < 7800 * 2 ** 20, \
+        memory.peak_memory_in_bytes / 2 ** 20
+    text = compiled.as_text()
+    kernel_of = {name: row["kernel"] for name, row in
+                 tracing.parse_program_text(text).items() if row["kernel"]}
+    opcode = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = \S+ ([\w-]+)\(", text,
+                             flags=re.M))
+    landed = {"receive": [], "home": []}
+    for rows, buffer in re.findall(
+            r"= \w+\[(\d+),[\d,]+\]\S* ragged-all-to-all\(%\S+, %([\w.-]+),",
+            text):
+        landed["receive" if rows == "198656" else "home"].append(
+            kernel_of.get(buffer, opcode[buffer]))
+    assert sorted(landed["receive"]) == \
+        2 * ["ds_zeroed_padding_cotangents"] \
+        + 4 * ["ds_zeroed_padding_gates"] + 4 * ["ds_zeroed_padding_rows"]
+    assert landed["home"] == 6 * ["broadcast"]
+    assert sum(kernel.startswith("ds_zeroed_padding")
+               for kernel in kernel_of.values()) == 10
 
 
 def test_library_knows_the_chips_peaks(v5e):

@@ -19,6 +19,7 @@ then sorted and gathered into the held plan); the device gate.
 With ``real_kernels`` the grouped kernels and ``ds_rowsum`` run in Pallas'
 interpreter inside the exchange's manual region; elsewhere their jnp forms
 stand in."""
+import math
 import re
 from dataclasses import replace
 
@@ -276,6 +277,9 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     assert call["pairs"] == 4 and call["experts_held"] == 2
     assert call["tokens"] == tokens // 4 and call["routed_rows"] == routed
     assert call["receive_rows"] == bound
+    # what a call initialises of its receive buffer: a tile a held expert
+    assert call["receive_fill"] == "padding_tiles"
+    assert call["zeroed_rows_per_call"] == E // 4 * tile
     assert call["wire_bytes"] == 3 * (routed // 4) * D * 4
     assert call["row_calls_per_pass"] == {"forward": 2, "recompute": 1,
                                           "backward": 2}
@@ -460,11 +464,13 @@ def test_a_rematerialised_layer_runs_five_row_exchanges_and_three_of_gates():
 
 def _ragged_widths(text):
     """(element type, row width) of every ``ragged_all_to_all`` of a
-    lowered text, by its operand; rows first."""
-    found = re.findall(r"@ragged_all_to_all\(.*?: \(tensor<\d+x(\d+)x(\w+)>",
-                       text)
-    return sorted(((dtype, int(width)) for width, dtype in found),
-                  key=lambda found: -found[1])
+    lowered text, by its operand; rows first.  A row into a receive buffer
+    travels in the collective's own shape (``mappings._as_sent``: bf16
+    ``[., 2, width / 2]``, float32 ``[., 1, width]``): its elements."""
+    found = re.findall(
+        r"@ragged_all_to_all\(.*?: \(tensor<\d+x((?:\d+x)+)([a-z]\w*)>", text)
+    return sorted(((dtype, math.prod(map(int, dims.split("x")[:-1])))
+                   for dims, dtype in found), key=lambda found: -found[1])
 
 
 def _sorts(text):
@@ -527,6 +533,8 @@ def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
                 params, x).as_text()
     (call,) = tracing.exchange_calls("cell")
     assert call["receive_rows"] == 196608 and call["routed_rows"] == 65536
+    assert call["receive_fill"] == "padding_tiles"
+    assert call["zeroed_rows_per_call"] == 2048
     assert call["slices_per_pair"] == 16
     assert _ragged_widths(text) == 3 * [("bf16", 2304)] \
         + 2 * [("f32", LANES)]
@@ -737,18 +745,24 @@ def test_every_slice_leaves_and_lands_where_the_table_says(case, monkeypatch):
                                           getattr(want, name), err_msg=name)
 
 
+@pytest.mark.parametrize("buffer", ["zeros", "nan"])
 @pytest.mark.parametrize("case", ["skewed", "an_empty_expert"])
 def test_the_receive_buffer_is_the_parents_held_plan_of_the_rows(
-        case, monkeypatch):
-    """What the all-to-all leaves on a chip is, bit for bit over the live
-    prefix, what the parent built there in three steps: the rows as they
-    arrived (by sender, a sender's for this chip in its routed order),
-    their experts' numbers beside them, then ``make_held_group_plan`` and
-    ``dispatch_held_rows`` over that buffer — kept here as the parent ran
-    them.  And the way back puts every row where it came from."""
+        case, buffer, monkeypatch):
+    """What the all-to-all leaves on a chip is, bit for bit **over the live
+    prefix** — the groups' padding rows among it, exact zeros — what the
+    parent built there in three steps: the rows as they arrived (by sender,
+    a sender's for this chip in its routed order), their experts' numbers
+    beside them, then ``make_held_group_plan`` and ``dispatch_held_rows``
+    over that buffer — kept here as the parent ran them.  Behind the prefix
+    the buffer is nobody's (``nan``: born of NaN here, as a chip's is born
+    of whatever its memory held) and is not compared.  And the way back
+    puts every row where it came from, into zeros."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     table, bound, bm = TABLES[case]
     monkeypatch.setattr(gg, "default_block_m", lambda: bm)
+    if buffer == "nan":
+        monkeypatch.setattr(gg, "_unwritten", _born_of_nan)
     n, E = table.shape
     held, routed, width = E // n, int(table[0].sum()), 8
     rng = np.random.default_rng(7)
@@ -788,10 +802,166 @@ def test_the_receive_buffer_is_the_parents_held_plan_of_the_rows(
         assert int(over) == 0 and int(live_rows) == live[d]
         np.testing.assert_array_equal(received[d][:live[d]],
                                       np.asarray(want)[:live[d]])
-        assert not received[d][live[d]:].any()
+        behind = received[d][live[d]:]
+        assert np.isnan(behind).all() if buffer == "nan" else not behind.any()
         # back at the sender: its own rows at their places, zeros on padding
         own = np.concatenate([rows[d], np.zeros((1, width), np.float32)])
         np.testing.assert_array_equal(returned[d], own[element[d]])
+
+
+def _born_of_nan(shape, dtype, after, what):
+    """``grouped_gemm._unwritten`` as a chip has it, at its worst: a buffer
+    that holds what nobody wrote."""
+    return jnp.full(shape, jnp.nan, dtype)
+
+
+def _poisoned_receive_buffers(monkeypatch, seen):
+    """``grouped_gemm.zeroed_padding`` with NaN where its buffer is zeros
+    off the chip — its alone: the live-prefix loops keep theirs."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    zeroed_padding = gg.zeroed_padding
+
+    def poisoned(counts, shape, dtype, after, what):
+        seen.append((what, shape))
+        with monkeypatch.context() as only_here:
+            only_here.setattr(gg, "_unwritten", _born_of_nan)
+            return zeroed_padding(counts, shape, dtype, after, what)
+
+    monkeypatch.setattr(gg, "zeroed_padding", poisoned)
+
+
+#: token -> its two experts (of 8, two a chip; the M-tile is 8 rows), by the
+#: routing each case plants; tokens a chip
+ROUTINGS = {
+    # 12 rows an expert, 3 from every chip: a tile and a half, 4 padding rows
+    "even": (lambda t: (t % 8, (t + 3) % 8), 12),
+    # chip 0's tokens choose chip 0's experts, nobody else does
+    "one_senders_skew": (lambda t: (0, 1) if t < 12 else (
+        2 + t % 6, 2 + (t + 1) % 6), 12),
+    # nobody chooses expert 3: its one tile is padding from end to end
+    "an_expert_with_no_rows": (lambda t: (
+        (0, 1, 2, 4, 5, 6, 7)[t % 7], (0, 1, 2, 4, 5, 6, 7)[(t + 2) % 7]), 12),
+    # 16 rows an expert: two tiles to the last row, no padding row at all
+    "a_full_last_tile": (lambda t: (t % 8, (t + 1) % 8), 16),
+}
+
+
+def _planted(case, monkeypatch):
+    """The exchanged layer's (params, x) under ``ROUTINGS[case]``, the
+    grouped kernels interpreted: the router's weights stay its own, its
+    choices are the table's."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    choose, per_chip = ROUTINGS[case]
+    monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
+    monkeypatch.setattr(gg, "default_block_m", lambda: 8)
+    params, x = params_and_x()
+    x = x[:, :per_chip // 2]                    # [8, ., D]: two rows a chip
+    chosen = jnp.asarray([choose(t) for t in range(4 * per_chip)], jnp.int32)
+    route = moe_layer_module._route
+    monkeypatch.setattr(
+        moe_layer_module, "_route", lambda *a, **k: route(*a, **k)._replace(
+            expert_idx=chosen))
+    return params, x
+
+
+@pytest.mark.parametrize("case", sorted(ROUTINGS))
+def test_a_poisoned_receive_buffer_changes_nothing(case, monkeypatch):
+    """On a chip the buffer an exchange lands in is born unwritten, and all
+    it is given before the rows arrive is zeros in its groups' last tiles
+    (``grouped_gemm.zeroed_padding``).  Here, on the stand-in path with the
+    grouped kernels interpreted: NaN where that buffer is zeros off the
+    chip — rows, gates and cotangents — and the layer's output, its counts
+    and every gradient are the clean run's bit for bit."""
+    params, x = _planted(case, monkeypatch)
+    fn, args = four_wide(CONFIG, params, x)
+    clean = host(fn(*args))
+    seen = []
+    _poisoned_receive_buffers(monkeypatch, seen)
+    fn, args = four_wide(CONFIG, params, x)     # traced anew
+    dirty = host(fn(*args))
+    assert sorted({what for what, _ in seen}) == ["cotangents", "gates",
+                                                  "rows"], seen
+    (_, (_, stats)), _ = clean
+    rows = args[1].shape[0] * args[1].shape[1] * K
+    assert int(stats["dispatched"]) == rows and int(stats["dropped"]) == 0
+    for a, b in zip(jax.tree.leaves(clean), jax.tree.leaves(dirty)):
+        assert np.isfinite(b).all()
+        np.testing.assert_array_equal(a, b)
+
+
+def test_padding_tiles_left_unzeroed_poison_the_weights_gradient(
+        monkeypatch):
+    """That the test above can fail: the same poisoned buffer with its
+    padding tiles left as they were born — ``ds_ggemm_dw`` sums over a
+    group's padding rows, and ``w_in``'s gradient is not finite."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    _poisoned_receive_buffers(monkeypatch, [])
+    monkeypatch.setattr(gg, "_zero_tiles", lambda buf, tiles, block_m: buf)
+    fn, args = four_wide(CONFIG, *_planted("even", monkeypatch))
+    _, (dparams, _) = host(fn(*args))
+    assert not np.isfinite(dparams["w_in"]).all()
+
+
+@pytest.mark.parametrize("case", sorted(TABLES))
+def test_the_padding_tiles_are_each_groups_last(case):
+    """``grouped_gemm._padding_tiles`` against the layout written as loops:
+    the tile that ends each group — every padding row of the live prefix
+    lies in one of them, and none of them behind it."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    table, bound, bm = TABLES[case]
+    held = table.shape[1] // table.shape[0]
+    for d in range(table.shape[0]):
+        counts = np.minimum(table[:, held * d:held * (d + 1)].sum(0), bound)
+        starts, sizes = layout(counts, bound, bm)
+        padded_rows = -(-bound // bm) * bm + held * bm
+        tiles = np.asarray(gg._padding_tiles(jnp.asarray(counts),
+                                             padded_rows, bm))
+        np.testing.assert_array_equal(
+            tiles, [(s + z) // bm - 1 for s, z in zip(starts, sizes)])
+        padding = np.ones(padded_rows, bool)
+        for s, c in zip(starts, counts):
+            padding[s:s + c] = False
+        live = starts[-1] + sizes[-1]
+        covered = np.zeros(padded_rows, bool)
+        for t in tiles:
+            covered[t * bm:(t + 1) * bm] = True
+        assert not (padding[:live] & ~covered[:live]).any()
+        assert not covered[live:].any()
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((96, 256), jnp.bfloat16), ((96, 2, 128), jnp.bfloat16),
+    ((96, 128), jnp.float32)], ids=["rows", "rows_as_sent", "gates"])
+def test_the_kernel_zeroes_its_tiles_and_writes_nothing_else(shape, dtype):
+    """``ds_zeroed_padding_<what>`` in Pallas' interpreter, whose
+    uninitialised results are NaN: the padding tiles exact zeros, every
+    other row as it was born — in the buffer's own shape and in the shape
+    the chip's collective moves it in (``mappings._as_sent``)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    bm = 8
+    counts = jnp.asarray([5, 0, 8, 17], jnp.int32)
+    tiles = gg._padding_tiles(counts, shape[0], bm)
+    np.testing.assert_array_equal(tiles, [0, 1, 2, 5])
+    out = np.asarray(gg._pallas_zeroed_tiles(
+        tiles, bm, shape, dtype, jnp.ones((3,)), "test",
+        interpret=True).astype(jnp.float32)).reshape(shape[0] // bm, -1)
+    for tile, rows in enumerate(out):
+        assert (rows == 0).all() if tile in (0, 1, 2, 5) \
+            else np.isnan(rows).all(), tile
+
+
+def test_a_row_travels_as_whole_tiles_of_its_own(monkeypatch):
+    """``mappings._as_sent``: on the chip a bf16 row of 2,304 is
+    ``[2, 1152]`` and a float32 row of 128 ``[1, 128]``; a width that is no
+    whole tiles, and any row off the chip, is as it is."""
+    rows = lambda width, dtype: jnp.zeros((8, width), dtype)  # noqa: E731
+    assert mappings._as_sent(rows(2304, jnp.bfloat16)) == (2304,)
+    monkeypatch.setattr(mappings, "exchange_path",
+                        lambda: mappings.RAGGED_ALL_TO_ALL)
+    assert mappings._as_sent(rows(2304, jnp.bfloat16)) == (2, 1152)
+    assert mappings._as_sent(rows(LANES, jnp.float32)) == (1, LANES)
+    assert mappings._as_sent(rows(D, jnp.float32)) == (D,)
+    assert mappings._as_sent(rows(128, jnp.bfloat16)) == (128,)
 
 
 def test_the_device_gate_asks_where_the_call_is():
