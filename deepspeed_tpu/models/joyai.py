@@ -217,34 +217,55 @@ def init_params(config: JoyAIConfig, rng) -> dict:
     return params
 
 
+def attn_specs(lead=()):
+    col, row = P(*lead, None, "model"), P(*lead, "model", None)
+    return {"attn_norm": P(), "w_dq": P(), "q_norm": P(), "w_uq": col,
+            "w_dkv": P(), "kv_norm": P(), "w_ukv": col, "w_o": row}
+
+
+def dense_mlp_specs(lead=()):
+    return {"mlp_norm": P(), "w_gate": P(*lead, None, "model"),
+            "w_up": P(*lead, None, "model"),
+            "w_down": P(*lead, "model", None)}
+
+
+def expert_block_specs(config, lead=()):
+    moe = jax.tree.map(lambda spec: P(*lead, *spec),
+                       moe_logical_specs(config.moe),
+                       is_leaf=lambda s: isinstance(s, P))
+    return {**attn_specs(lead), "mlp_norm": P(), "moe": moe}
+
+
 def logical_specs(config: JoyAIConfig) -> dict:
-    def attn(lead):
-        col, row = P(*lead, None, "model"), P(*lead, "model", None)
-        return {"attn_norm": P(), "w_dq": P(), "q_norm": P(), "w_uq": col,
-                "w_dkv": P(), "kv_norm": P(), "w_ukv": col, "w_o": row}
-
-    def expert_block(lead):
-        moe = jax.tree.map(lambda spec: P(*lead, *spec),
-                           moe_logical_specs(config.moe),
-                           is_leaf=lambda s: isinstance(s, P))
-        return {**attn(lead), "mlp_norm": P(), "moe": moe}
-
     specs = {
         "wte": P("model", None),
-        "dense": {**attn(()), "mlp_norm": P(), "w_gate": P(None, "model"),
-                  "w_up": P(None, "model"), "w_down": P("model", None)},
-        "blocks": expert_block((None,)),
+        "dense": {**attn_specs(), **dense_mlp_specs()},
+        "blocks": expert_block_specs(config, (None,)),
         "final_norm": P(),
         "lm_head": P(None, "model"),
     }
     if config.num_mtp_layers:
         specs["mtp"] = {"norm_h": P(), "norm_e": P(), "w_eh": P(),
-                        "block": expert_block(()), "final_norm": P()}
+                        "block": expert_block_specs(config),
+                        "final_norm": P()}
     return specs
 
 
-def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
-    """``x + MLA(N(x))``; the caller's scope is ``ds.block``."""
+def _rotary(q, k_r, config):
+    """q [B, S, H, nope + rot] turned in place on its last ``rot`` lanes,
+    its position-free lanes passing through; the one shared key k_r [B, S,
+    1, rot] whole."""
+    return (rope(q, config.rope_theta, interleaved=True,
+                 first=config.qk_nope_head_dim),
+            rope(k_r, config.rope_theta, interleaved=True))
+
+
+def latent_attention(x, layer, config, segment_ids, rotary=_rotary):
+    """``MLA(N(x))``, the branch alone: whoever calls owns the residual
+    (the block below adds ``x``; models/xing.py writes it into its
+    streams).  ``config`` is any with this file's attention sizes;
+    ``rotary(q, k_r, config)`` turns both (a family with scaled
+    frequencies brings its own)."""
     B, S, _ = x.shape
     H, rkv = config.num_heads, config.kv_lora_rank
     nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
@@ -260,10 +281,7 @@ def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
             c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
             kv = qdot(c_kv, layer["w_ukv"]).reshape(B, S, H, nope + vd)
         with jax.named_scope(SCOPE_ROPE):
-            # q turns in place, its position-free lanes passing through
-            q = rope(q, config.rope_theta, interleaved=True, first=nope)
-            k_r = rope(jnp.expand_dims(ckv[..., rkv:], 2), config.rope_theta,
-                       interleaved=True)                  # [B, S, 1, rot]
+            q, k_r = rotary(q, jnp.expand_dims(ckv[..., rkv:], 2), config)
         with jax.named_scope(SCOPE_KV_LATENT):
             # the one rotary key, a copy per head behind each head's own
             # part: the kernels read k [B, S, H, nope + rot]
@@ -276,16 +294,31 @@ def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
                                     segment_ids=segment_ids)
     attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
     with jax.named_scope(SCOPE_ATTN), jax.named_scope(SCOPE_OUT_PROJ):
-        return x + qdot(attn.reshape(B, S, H * vd), layer["w_o"])
+        return qdot(attn.reshape(B, S, H * vd), layer["w_o"])
+
+
+def _latent_attention(x, layer, config: JoyAIConfig, segment_ids):
+    """``x + MLA(N(x))``; the caller's scope is ``ds.block``."""
+    out = latent_attention(x, layer, config, segment_ids)
+    with jax.named_scope(SCOPE_ATTN), jax.named_scope(SCOPE_OUT_PROJ):
+        return x + out
+
+
+def dense_mlp(x, layer, config):
+    """``W_down(silu(W_gate h) * W_up h)``, ``h = N(x)``: the leading
+    layer's feed-forward branch alone."""
+    with jax.named_scope(SCOPE_MLP):
+        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
+        h = jax.nn.silu(qdot(h, layer["w_gate"])) * qdot(h, layer["w_up"])
+        return qdot(h, layer["w_down"])
 
 
 @jax.named_scope(SCOPE_BLOCK)
 def _dense_block(x, layer, config: JoyAIConfig, segment_ids=None):
     x = _latent_attention(x, layer, config, segment_ids)
+    out = dense_mlp(x, layer, config)
     with jax.named_scope(SCOPE_MLP):
-        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        h = jax.nn.silu(qdot(h, layer["w_gate"])) * qdot(h, layer["w_up"])
-        return x + qdot(h, layer["w_down"])
+        return x + out
 
 
 @jax.named_scope(SCOPE_BLOCK)
@@ -323,17 +356,19 @@ def _logits(x, norm_w, lm_head, config: JoyAIConfig):
     return x @ lm_head.astype(jnp.dtype(config.dtype))
 
 
-def forward_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
-                     rng=None):
+def forward_with_aux(params, batch, config, train: bool = True, rng=None,
+                     stack=None):
     """-> (the main head's logits, router loss, rows over the bound): the
-    main model alone, as a forward pass reads it."""
-    x, aux, over = hidden_with_aux(params, batch, config, train, rng)
+    main model alone, as a forward pass reads it.  ``stack``: as
+    :func:`loss_with_counts`."""
+    hidden, _ = stack or (hidden_with_aux, mtp_hidden_with_aux)
+    x, aux, over = hidden(params, batch, config, train, rng)
     with jax.named_scope(SCOPE_HEAD_LOSS):
         return (_logits(x, params["final_norm"], params["lm_head"], config),
                 aux, over)
 
 
-def _mtp_input(params, x, batch, config: JoyAIConfig):
+def mtp_input(params, x, batch, config):
     """What the module's block reads: ``[N_h(x_t) ; N_e(E[id_{t+1}])]
     W_eh``."""
     dtype = jnp.dtype(config.dtype)
@@ -355,7 +390,7 @@ def mtp_hidden_with_aux(params, x, batch, config: JoyAIConfig,
     is never scored and nothing attends to it)."""
     return layer_block(_expert_block, config, train=train, rng=rng,
                        segment_ids=segment_ids_of(batch))(
-        _mtp_input(params, x, batch, config), params["mtp"]["block"])
+        mtp_input(params, x, batch, config), params["mtp"]["block"])
 
 
 def routed_rows(params, batch, config: JoyAIConfig):
@@ -385,7 +420,7 @@ def routed_rows(params, batch, config: JoyAIConfig):
         rows.append(count(x, layer))
         x, _ = _expert_block(x, layer, config, train=True, segment_ids=seg)
     if config.num_mtp_layers:
-        joined = _mtp_input(params, x, batch, config)
+        joined = mtp_input(params, x, batch, config)
         rows.append(count(joined, params["mtp"]["block"]))
         # run for a registry tap to hear this block's plan as the others'
         # (moe/layer.py ``_emit_held_plan``); with no tap nothing is left
@@ -417,21 +452,27 @@ def _scored_nll(logits, targets):
         - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
 
 
-def mtp_token_losses(params, batch, config: JoyAIConfig):
+def mtp_token_losses(params, batch, config, stack=None):
     """Every position's negative log likelihood of token t+2 from the
-    module's own forward pass [B, S] float32, and which are scored."""
-    x, _, _ = hidden_with_aux(params, batch, config, train=False)
-    h, _ = mtp_hidden_with_aux(params, x, batch, config, train=False)
+    module's own forward pass [B, S] float32, and which are scored.
+    ``stack``: as :func:`loss_with_counts`."""
+    hidden, mtp_hidden = stack or (hidden_with_aux, mtp_hidden_with_aux)
+    x, _, _ = hidden(params, batch, config, train=False)
+    h, _ = mtp_hidden(params, x, batch, config, train=False)
     targets, scored = mtp_targets(batch)
     logits = _logits(h, params["mtp"]["final_norm"], params["lm_head"],
                      config)
     return _scored_nll(logits, targets), scored
 
 
-def loss_with_counts(params, batch, config: JoyAIConfig, rng=None):
+def loss_with_counts(params, batch, config, rng=None, stack=None):
     """-> (``L_main + mtp_loss_weight * L_mtp + router losses``, {rows over
-    the bound})."""
-    x, aux, over = hidden_with_aux(params, batch, config, True, rng)
+    the bound}).  ``stack``: the (``hidden_with_aux``,
+    ``mtp_hidden_with_aux``) of a family whose layers differ and whose
+    heads, module and losses are these (models/xing.py); None: this
+    file's."""
+    hidden, mtp_hidden = stack or (hidden_with_aux, mtp_hidden_with_aux)
+    x, aux, over = hidden(params, batch, config, True, rng)
 
     # a head pass keeps nothing but its inputs for the backward: the two
     # passes' [tokens, vocab] float32 logits then never live together
@@ -443,7 +484,7 @@ def loss_with_counts(params, batch, config: JoyAIConfig, rng=None):
     loss = main_loss(x, params["final_norm"], params["lm_head"]) + aux
     if config.num_mtp_layers:
         with jax.named_scope(SCOPE_MTP):
-            h, (mtp_aux, mtp_over) = mtp_hidden_with_aux(
+            h, (mtp_aux, mtp_over) = mtp_hidden(
                 params, x, batch, config, True, rng)
 
             @jax.checkpoint

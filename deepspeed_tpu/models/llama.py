@@ -202,11 +202,14 @@ def _rope_interleaved(x, theta, positions, first):
     return _turn_pairs(x, c, s, first, False)
 
 
-def interleaved_tables(positions, theta, rot, first=0):
+def interleaved_tables(positions, theta, rot, first=0, inv_freq=None):
     """``(C, Sn)`` float32 ``[(B,) S, 1, first + rot]`` of the interleaved
     rotary: ``C`` is 1 on the ``first`` lanes that pass and ``cos`` (each
-    frequency twice) on the ``rot`` that turn, ``Sn`` 0 and ``sin``."""
-    freqs = theta ** (-jnp.arange(0, rot // 2) / (rot // 2))
+    frequency twice) on the ``rot`` that turn, ``Sn`` 0 and ``sin``.
+    ``inv_freq`` [rot / 2]: the frequencies, where they are not
+    ``theta``'s own (a scaled context)."""
+    freqs = theta ** (-jnp.arange(0, rot // 2) / (rot // 2)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None] * freqs                # [(B,) S, rot/2]
     lanes = [(0, 0)] * (angles.ndim - 1) + [(first, 0)]
     c = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), lanes,

@@ -363,18 +363,28 @@ def segment_ids_of(batch):
     return batch.get("segment_ids") if isinstance(batch, dict) else None
 
 
-def expert_half(x, moe_params, moe_config, norm, train, rng=None):
-    """The expert half of a block: ``x + MoE(norm(x))`` under the ``mlp``
-    scope -> (x, (router loss float32, routed rows over
-    ``held_rows_bound`` int32)), what a layer adds to a loop's sums."""
+def expert_branch(x, moe_params, moe_config, norm, train, rng=None):
+    """The expert half of a block without its residual: ``MoE(norm(x))``
+    under the ``mlp`` scope -> (the branch, (router loss float32, routed
+    rows over ``held_rows_bound`` int32)), the pair being what a layer
+    adds to a loop's sums."""
     import jax.numpy as jnp
     from deepspeed_tpu.moe.layer import moe_layer
     from deepspeed_tpu.telemetry.tracing import SCOPE_MLP
     with jax.named_scope(SCOPE_MLP):
         out, aux, stats = moe_layer(moe_params, norm(x), moe_config,
                                     train=train, rng=rng, return_stats=True)
-        return x + out, (aux.astype(jnp.float32),
-                         stats["dropped"].astype(jnp.int32))
+        return out, (aux.astype(jnp.float32),
+                     stats["dropped"].astype(jnp.int32))
+
+
+def expert_half(x, moe_params, moe_config, norm, train, rng=None):
+    """The expert half of a block: ``x + MoE(norm(x))`` -> (x, what
+    :func:`expert_branch` returns beside the branch)."""
+    from deepspeed_tpu.telemetry.tracing import SCOPE_MLP
+    out, sums = expert_branch(x, moe_params, moe_config, norm, train, rng)
+    with jax.named_scope(SCOPE_MLP):
+        return x + out, sums
 
 
 def no_experts():

@@ -387,6 +387,15 @@ SCOPE_GMU = "gmu"
 SCOPE_DIFF_ATTN = "diff_attn"
 SCOPE_QKV = "qkv"
 SCOPE_FLASH = "flash"
+# inside ``attn`` and ``mlp``, where the residual is several streams
+# mixed by hyper-connections (ops/hyper_connection.py, models/xing.py):
+# ``hc/coeff`` the flattened norm, the projection, the sigmoids and the
+# Sinkhorn sweeps; ``hc/read`` the streams summed into the sublayer's
+# input; ``hc/write`` the streams mixed and the sublayer's output added
+SCOPE_HC = "hc"
+SCOPE_HC_COEFF = "coeff"
+SCOPE_HC_READ = "read"
+SCOPE_HC_WRITE = "write"
 STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
                SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
@@ -397,7 +406,8 @@ STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
                SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_HEAD_GATE,
                SCOPE_LEAD_MLP, SCOPE_EXCHANGE, SCOPE_SEND, SCOPE_RETURN,
                SCOPE_MAMBA, SCOPE_GATE, SCOPE_GMU, SCOPE_DIFF_ATTN,
-               SCOPE_QKV, SCOPE_FLASH)
+               SCOPE_QKV, SCOPE_FLASH, SCOPE_HC, SCOPE_HC_COEFF,
+               SCOPE_HC_READ, SCOPE_HC_WRITE)
 #: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
 #: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
 #: on a transposed right-hand side) and dw, the gated delta rule's two,
@@ -866,6 +876,18 @@ def selective_scan_calls(name: str = TRAIN_STEP_PROGRAM):
     (an associative scan a chunk inside a ``lax.scan``).  None where the
     step has no such call."""
     return _account_rows(name, "selective_scan_calls")
+
+
+def hc_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The hyper-connected sublayers of the step as
+    ops/hyper_connection.py ``hc_coefficients`` traced them: one row per
+    call site — ``site``, ``tokens`` (of a micro-batch), ``streams``,
+    ``width`` and ``calls_per_pass``, how often one forward pass of a
+    micro-batch runs it (the layer loop's length).  Their sum is the
+    sublayer calls of a pass; a step makes it once a micro-batch forward,
+    again in the recompute, and once backward.  None where the step has
+    no such call."""
+    return _account_rows(name, "hc_calls")
 
 
 def conv_calls(name: str = TRAIN_STEP_PROGRAM):

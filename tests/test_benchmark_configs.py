@@ -16,7 +16,7 @@ FILES = sorted(os.path.basename(p)
 
 
 def test_the_benchmark_has_its_configurations():
-    assert len(FILES) >= 9
+    assert len(FILES) >= 10
     with open(os.path.join(os.path.dirname(CONFIGS), "..",
                            "BENCHMARK.json")) as f:
         named = {os.path.basename(c["file"])

@@ -88,7 +88,8 @@ def test_fixed_names():
                            "ds.attn_full", "ds.attn_sliding",
                            "ds.head_gate", "ds.lead_mlp", "exchange",
                            "exchange_send", "exchange_return", "mamba",
-                           "gate", "gmu", "diff_attn", "qkv", "flash")
+                           "gate", "gmu", "diff_attn", "qkv", "flash", "hc",
+                           "coeff", "read", "write")
     assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
                             "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
                             "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
