@@ -21,7 +21,12 @@ untied head.
   sqrt(qk_nope + qk_rope)`` inside a document; ``o = concat_heads(P v)
   W_o``.  This is the expanded, per-head form that training runs; the
   absorbed form that decoding would run (scores against ``c_kv`` itself) is
-  not built.
+  not built.  ``k`` and ``v`` are never assembled: ``k = [c_kv | k_r] W_k``
+  is one product whose operand ``W_k`` holds each head's key columns of
+  ``W_ukv`` and, under them, an identity that carries ``k_r`` into every
+  head's last lanes (exact: a value times one plus zeros), and ``v`` is
+  the product with the value columns; their cotangents go back through
+  the same products (``_key_value_weights``).  ``W_ukv`` stays one leaf.
 - *Layer 0*: ``x + MLA(N(x))``, then ``x + W_down(silu(W_gate h) * W_up
   h)`` at ``d_ff_dense``.
 - *Layers 1..*: the same attention, then experts (moe/layer.py): ``s =
@@ -260,6 +265,28 @@ def _rotary(q, k_r, config):
             rope(k_r, config.rope_theta, interleaved=True))
 
 
+def _key_value_weights(w_ukv, config, dtype):
+    """The ``w_ukv`` leaf ``[rkv, H (nope + vd)]`` as the two operands
+    that write the kernels' ``k`` and ``v``: ``W_k`` ``[rkv + rot, H, nope
+    + rot]`` — each head's key columns with ``rot`` zero columns behind
+    them, and under those ``rot`` rows of zeros and one identity, the same
+    for every head, which carry the shared rotary key into each head's
+    last lanes — and ``W_v`` ``[rkv, H, vd]``, each head's value columns.
+    Built while tracing, so the leaf, its gradient and the checkpoint stay
+    as they are; the constant blocks' gradient is dropped by the
+    transpose."""
+    H, rkv = config.num_heads, config.kv_lora_rank
+    nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                     config.v_head_dim)
+    w = w_ukv.astype(dtype).reshape(rkv, H, nope + vd)
+    carry = jnp.broadcast_to(jnp.eye(rot, dtype=dtype)[:, None],
+                             (rot, H, rot))
+    w_k = jnp.concatenate([
+        jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rot))),
+        jnp.pad(carry, ((0, 0), (0, 0), (nope, 0)))], axis=0)
+    return w_k, w[..., nope:]
+
+
 def latent_attention(x, layer, config, segment_ids, rotary=_rotary):
     """``MLA(N(x))``, the branch alone: whoever calls owns the residual
     (the block below adds ``x``; models/xing.py writes it into its
@@ -279,16 +306,20 @@ def latent_attention(x, layer, config, segment_ids, rotary=_rotary):
         with jax.named_scope(SCOPE_KV_LATENT):
             ckv = qdot(h, layer["w_dkv"])
             c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
-            kv = qdot(c_kv, layer["w_ukv"]).reshape(B, S, H, nope + vd)
         with jax.named_scope(SCOPE_ROPE):
             q, k_r = rotary(q, jnp.expand_dims(ckv[..., rkv:], 2), config)
         with jax.named_scope(SCOPE_KV_LATENT):
-            # the one rotary key, a copy per head behind each head's own
-            # part: the kernels read k [B, S, H, nope + rot]
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_r, (B, S, H, rot))],
-                axis=-1)
-            v = kv[..., nope:]
+            w_k, w_v = _key_value_weights(layer["w_ukv"], config,
+                                          c_kv.dtype)
+            # one product writes k [B, S, H, nope + rot] as the kernels
+            # read it: a bf16 value times one plus exact zeros, summed in
+            # float32 and rounded once, is that value, so every head's
+            # last ``rot`` lanes are the one rotary key to the bit
+            k = jnp.einsum(
+                "bsc,chd->bshd",
+                jnp.concatenate([c_kv, k_r.reshape(B, S, rot)], axis=-1),
+                w_k)
+            v = jnp.einsum("bsc,chd->bshd", c_kv, w_v)
         with jax.named_scope(SCOPE_SCORES):
             attn = causal_attention(q, k, v, impl=config.attention_impl,
                                     segment_ids=segment_ids)

@@ -19,8 +19,11 @@ equations and their source), with YaRN-scaled rotary frequencies.
 - *The sublayers* are JoyAI's own branch functions, imported:
   ``joyai.latent_attention``, ``joyai.dense_mlp``, ``model.expert_branch``
   — the residual add is the caller's there, and here the caller writes the
-  branch into the streams.  The heads, the prediction module's input and
-  both losses are ``joyai.loss_with_counts`` over this file's stack.
+  branch into the streams.  The attention's ``k`` and ``v`` leave two
+  products of ``[c_kv | k_r]`` as the kernels read them, under this file's
+  rotary as under JoyAI's (models/joyai.py ``_key_value_weights``).  The
+  heads, the prediction module's input and both losses are
+  ``joyai.loss_with_counts`` over this file's stack.
 - *Rotary*: YaRN (``rope_factor`` over ``original_max_position_embeddings``
   between ``beta_fast`` and ``beta_slow`` turns; models/laguna.py
   ``yarn_inv_freq``) at every length, pairs ``(2i, 2i+1)``; the softmax

@@ -358,7 +358,9 @@ SCOPE_SCAN = "scan"                 # ... the state-space scan (SSD)
 # inside ``attn``, where the attention is latent (models/joyai.py), beside
 # ``out_proj`` as above:
 SCOPE_Q_LATENT = "q_latent"         # ... queries: down, norm, up, the join
-SCOPE_KV_LATENT = "kv_latent"       # ... keys/values: down, norm, up, k built
+SCOPE_KV_LATENT = "kv_latent"       # ... keys/values: down, norm, and two
+#     products up: k whole (the shared rotary key in every head's last
+#     lanes) and v, each as the kernels read it
 SCOPE_ROPE = "rope"                 # ... rotary on q's part and the shared key
 SCOPE_SCORES = "scores"             # ... softmax(q k^T) v: the flash kernels
 # beside ``ds.block``, around a whole multi-token-prediction module

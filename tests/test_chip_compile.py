@@ -585,22 +585,16 @@ def test_a_held_layer_walks_its_live_prefix_on_a_v5e(v5e, monkeypatch):
 JOYAI_ATTENTION_TEMP_BYTES_AT_PR_44 = 2138551296
 
 
-def test_latent_attention_turns_q_in_one_pass_on_a_v5e(v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def latent_attention_on_a_v5e(v5e):
     """joyai-llm-flash.packed-s8192-gas2's latent attention alone, forward
-    and backward, ``q`` ``[2, 8192, 32, 192]``: the interleaved rotary is
-    the product with the signed permutation and its epilogue, one fusion
-    over ``q`` a direction (the forward's writes what ``ds_flash_fwd``
-    reads), nothing under ``attn/rope`` gathers or scatters (the parent:
-    four gathers and two scatter-adds of float32 ``[2, 4096, 32, 64]``),
-    no float32 array of ``q``'s size under ``rope`` or ``q_latent``, and
-    the layer's temporaries are not above the parent's (1.805 GiB against
-    1.992 when this was written)."""
+    and backward, ``x`` ``[2, 8192, 2048]`` packed, compiled once for the
+    two tests below: (the compiled program, its entry computation's lines
+    each with its ``op_name``)."""
     import re
     from deepspeed_tpu.comm.mesh import sharding_pin_scope
     from deepspeed_tpu.models import joyai
     from deepspeed_tpu.ops.pallas import ds_flash_attention as flash
-    monkeypatch.setattr(flash.vmem, "device_kind",
-                        lambda: v5e[0].device_kind.lower())
     config = joyai.JoyAIConfig(num_layers=5, attention_impl="flash")
     layer = jax.tree.map(
         lambda a: _arg(v5e[0], a.shape,
@@ -613,17 +607,35 @@ def test_latent_attention_turns_q_in_one_pass_on_a_v5e(v5e, monkeypatch):
             out = joyai._latent_attention(x, layer, config, seg)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    with sharding_pin_scope(False):
+    with pytest.MonkeyPatch.context() as patch, sharding_pin_scope(False):
+        patch.setattr(flash.vmem, "device_kind",
+                      lambda: v5e[0].device_kind.lower())
         compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
             layer, _arg(v5e[0], (2, 8192, 2048)),
             _arg(v5e[0], (2, 8192), jnp.int32)).compile()
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):].splitlines()
     assert sum(KERNEL in line for line in entry) == 3       # the flash three
-    scoped = [(re.search(r'op_name="([^"]*)"', line), line)
-              for line in entry]
-    turning = [line for m, line in scoped
-               if m and "/attn/rope/" in m.group(1)]
+    scoped = []
+    for line in entry:
+        m = re.search(r'op_name="([^"]*)"', line)
+        scoped.append((m.group(1) if m else "", line))
+    return compiled, scoped
+
+
+def test_latent_attention_turns_q_in_one_pass_on_a_v5e(
+        latent_attention_on_a_v5e):
+    """``q`` ``[2, 8192, 32, 192]``: the interleaved rotary is
+    the product with the signed permutation and its epilogue, one fusion
+    over ``q`` a direction (the forward's writes what ``ds_flash_fwd``
+    reads), nothing under ``attn/rope`` gathers or scatters (the parent:
+    four gathers and two scatter-adds of float32 ``[2, 4096, 32, 64]``),
+    no float32 array of ``q``'s size under ``rope`` or ``q_latent``, and
+    the layer's temporaries are not above the parent's (1.805 GiB against
+    1.992 when this was written)."""
+    import re
+    compiled, scoped = latent_attention_on_a_v5e
+    turning = [line for scope, line in scoped if "/attn/rope/" in scope]
     assert turning
     assert not [line for line in turning
                 if re.search(r'op_name="[^"]*(gather|scatter)', line)]
@@ -633,9 +645,55 @@ def test_latent_attention_turns_q_in_one_pass_on_a_v5e(v5e, monkeypatch):
     assert sum(line.split("=")[1].startswith(" bf16[2,32,8192,192]")
                for line in products) == 1       # the kernels' own layout
     whole_f32 = re.compile(r"= \(?f32\[2,(?:32,8192|8192,32),192\]")
-    assert not [line for m, line in scoped
-                if m and re.search(r"/attn/(rope|q_latent)/", m.group(1))
+    assert not [line for scope, line in scoped
+                if re.search(r"/attn/(rope|q_latent)/", scope)
                 and whole_f32.search(line)]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= JOYAI_ATTENTION_TEMP_BYTES_AT_PR_44, temp
+
+
+def test_latent_attention_writes_k_and_v_where_the_kernels_read_them(
+        latent_attention_on_a_v5e):
+    """The same compile (PR 57): ``k`` leaves one product —
+    ``[c_kv | k_r] [W_uk 0 ; 0 I]`` — as ``bf16[2,32,8192,192]`` in the
+    kernels' own layout and ``v`` its own as ``bf16[2,32,8192,128]``, so
+    nothing stands between them and ``ds_flash_fwd``; the kernels' float32
+    ``dk`` / ``dv`` are the operands of the products that take them back.
+    Under ``attn/kv_latent`` and ``attn/scores`` the only instructions
+    that write 60 MB or more are the three kernels, products, and the one
+    copy that turns the kernels' output ``[2, 32, 8192, 128]`` for the
+    output projection (the parent had it too; beside it: a broadcast of
+    ``k_r``, the slices of ``kv``, the join of ``k``, the casts of ``dk``
+    and ``dv``, a split, a pad-and-add of ``[2, 8192, 32, 256]`` and its
+    copy).  The layer's temporaries stay under the constant above
+    (1,949,622,272 when this was written; the parent's 1,938,547,712)."""
+    import math
+    import re
+    compiled, scoped = latent_attention_on_a_v5e
+    size = {"bf16": 2, "f32": 4, "s32": 4}
+    result = re.compile(r"= \(?(\w+)\[([\d,]+)\]")
+
+    def written(line):
+        m = result.search(line)
+        return m and size.get(m.group(1), 0) * math.prod(
+            map(int, m.group(2).split(",")))
+
+    here = [line for scope, line in scoped
+            if re.search(r"/attn/(kv_latent|scores)/", scope)]
+    born = [line for scope, line in scoped if "/attn/kv_latent/" in scope
+            and re.search(r"= bf16\[2,32,8192,(?:192|128)\]", line)]
+    assert sorted(line.split(" = ")[1].split(":")[0] for line in born) == [
+        "bf16[2,32,8192,128]{3,2,1,0", "bf16[2,32,8192,192]{3,2,1,0"], born
+    assert all(" fusion(" in line and "kind=kOutput" in line
+               for line in born), born
+    large = [line for line in here if (written(line) or 0) >= 60e6
+             and not re.search(r" (get-tuple-element|bitcast)\(", line)]
+    others = [line for line in large if KERNEL not in line
+              and not (" fusion(" in line and "kind=kOutput" in line)]
+    assert len(others) == 1 and re.search(
+        r"= bf16\[2,8192,32,128\]\S* copy\(.*attn/scores/transpose",
+        others[0]), others
+    assert sum(KERNEL in line for line in large) == 3, large
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= JOYAI_ATTENTION_TEMP_BYTES_AT_PR_44, temp
 
@@ -839,7 +897,9 @@ def test_library_knows_the_chips_peaks(v5e):
 
 @pytest.mark.parametrize("script", [
     "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1",
-    "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py"])
+    "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py",
+    "scripts/latent_attention_table.py --seed 1",
+    "scripts/latent_attention_table.py --bits"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(

@@ -362,6 +362,156 @@ def test_the_size_is_the_published_one_and_the_cut_is_the_files():
         JoyAIConfig(num_mtp_layers=2)
 
 
+def _assembled_latent_attention(x, layer, config, segment_ids):
+    """``joyai.latent_attention`` as it stood before PR 57, the oracle of
+    the test below: ``kv = c_kv W_ukv`` whole, ``k`` joined from each
+    head's own lanes and a copy a head of the one rotary key, ``v``
+    sliced."""
+    from deepspeed_tpu.models.llama import _rms_norm
+    from deepspeed_tpu.models.model import qdot
+    B, S, _ = x.shape
+    H, rkv = config.num_heads, config.kv_lora_rank
+    nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                     config.v_head_dim)
+    eps = config.norm_eps
+    h = _rms_norm(x, layer["attn_norm"], eps)
+    c_q = _rms_norm(qdot(h, layer["w_dq"]), layer["q_norm"], eps)
+    q = qdot(c_q, layer["w_uq"]).reshape(B, S, H, nope + rot)
+    ckv = qdot(h, layer["w_dkv"])
+    c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
+    kv = qdot(c_kv, layer["w_ukv"]).reshape(B, S, H, nope + vd)
+    q, k_r = joyai._rotary(q, jnp.expand_dims(ckv[..., rkv:], 2), config)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r, (B, S, H, rot))], axis=-1)
+    attn = joyai.causal_attention(q, k, kv[..., nope:],
+                                  impl=config.attention_impl,
+                                  segment_ids=segment_ids)
+    return qdot(attn.reshape(B, S, H * vd), layer["w_o"])
+
+
+def handed_to_the_kernels(fn, x, layer, config, segment_ids):
+    """-> (``fn``'s output, the ``k`` and the ``v`` it handed
+    ``joyai.causal_attention``); scripts/latent_attention_table.py asks
+    the same on the chip."""
+    handed = []
+    attention = joyai.causal_attention
+
+    def noting(q, k, v, **kwargs):
+        handed.append((k, v))
+        return attention(q, k, v, **kwargs)
+
+    joyai.causal_attention = noting
+    try:
+        out = fn(x, layer, config, segment_ids)
+    finally:
+        joyai.causal_attention = attention
+    return (out, *handed[-1])
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["whole", "packed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_k_and_v_leave_their_products_as_the_assembled_ones(dtype, packed):
+    """``k = [c_kv | k_r] [W_uk 0 ; 0 I]`` and ``v = c_kv W_uv`` (PR 57)
+    against the assembled form: a value times one plus exact zeros is that
+    value, so in bfloat16 — what the configurations run — the kernels are
+    handed the same to the bit, and the layer's output is the same; in
+    float32 the rotary lanes and ``v`` are, and each head's own lanes are
+    to the last place (this CPU's dot sums 40 terms in another order than
+    32: 1.3e-7 of the largest, measured).  The cotangents go back through
+    the products' transposes (the sum of ``dk_r`` over the heads in the
+    accumulator, no pad-and-add), so the gradients agree to rounding."""
+    exact = dtype == "bfloat16"
+    config = JoyAIConfig(**{k: v for k, v in TOY.items()
+                            if k not in ("dtype", "remat")})
+    dt = jnp.dtype(dtype)
+    # wide scores and norm weights away from their start, as seeded_params
+    layer = joyai._attn_params(config, jax.random.PRNGKey(3))
+    for i, (name, w) in enumerate(layer.items()):
+        if w.ndim == 1:
+            layer[name] = w + 0.3 * jax.random.normal(jax.random.PRNGKey(i),
+                                                      w.shape)
+        else:
+            layer[name] = (w * (1.0 if name == "w_o" else 12.0)).astype(dt)
+    x = jax.random.normal(jax.random.PRNGKey(4),
+                          (B, S, TOY["d_model"])).astype(dt)
+    seg = micro(packed_batch())["segment_ids"] if packed else None
+    forms = (joyai.latent_attention, _assembled_latent_attention)
+    (out, k, v), (want, want_k, want_v) = (
+        [np.asarray(a) for a in handed_to_the_kernels(fn, x, layer, config,
+                                                      seg)] for fn in forms)
+    H, nope, rot, vd = (TOY["num_heads"], TOY["qk_nope_head_dim"],
+                        TOY["qk_rope_head_dim"], TOY["v_head_dim"])
+    assert k.dtype == want_k.dtype == dt and v.dtype == want_v.dtype == dt
+    assert k.shape == (B, S, H, nope + rot) and v.shape == (B, S, H, vd)
+    np.testing.assert_array_equal(k[..., nope:], want_k[..., nope:])
+    np.testing.assert_array_equal(v, want_v)
+    if exact:
+        np.testing.assert_array_equal(k, want_k)
+        np.testing.assert_array_equal(out, want)
+    else:
+        assert np.abs(k - want_k).max() < 4e-7 * np.abs(want_k).max()
+        assert np.abs(out - want).max() < 1e-5 * np.abs(want).max()
+
+    def loss(fn, layer, x):
+        return jnp.sum(fn(x, layer, config, seg).astype(jnp.float32) ** 2)
+
+    got, ref = (jax.jit(jax.value_and_grad(functools.partial(loss, fn),
+                                           (0, 1)))(layer, x)
+                for fn in forms)
+    assert abs(float(got[0]) - float(ref[0])) <= (
+        0 if exact else LOSS_TOL * float(ref[0]))
+    worst = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                           - b.astype(jnp.float32)))
+                           / jnp.max(jnp.abs(b.astype(jnp.float32)))),
+        got[1], ref[1])
+    # bfloat16: one rounding of a cotangent is 2 ** -8 of it
+    assert max(jax.tree.leaves(worst)) < (2 ** -6 if exact else GRAD_TOL), \
+        worst
+
+
+@pytest.mark.parametrize("family", ["joyai", "xing"])
+def test_no_array_of_ks_size_is_joined_spread_or_padded_in_the_lowered_toy_step(
+        family):
+    """Beside the test of ``q`` below, the same lowered text by scope:
+    under ``ds.block/attn/kv_latent`` and ``.../scores`` no
+    ``concatenate``, ``broadcast_in_dim`` or ``pad`` results in a ``[B, S,
+    H, ·]`` array — before PR 57 ``k`` was a concatenate of each head's
+    lanes and a broadcast of the shared rotary key, and the backward
+    rebuilt ``dkv`` by a pad and an add — in any pass of the block:
+    forward, recompute and backward, the main stack's and the module's.
+    models/xing.py runs the same function under its own rotary."""
+    import re
+    if family == "joyai":
+        model = toy_model()
+    else:
+        from tests.test_xing import toy_model as xing_toy_model
+        model = xing_toy_model()
+    H = TOY["num_heads"]
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        micro(packed_batch())).as_text(debug_info=True)
+    scope_of = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    whole = re.compile(rf"tensor<{B}x{S}x{H}x\d+x\w+>")
+    seen = {"kv_latent": 0, "scores": 0}
+    products = 0
+    for line in text.splitlines():
+        m = re.search(r'= "?(?:stablehlo|chlo)\.(\w+).* loc\((#loc\d+)\)$',
+                      line)
+        scope = m and re.search(r"ds\.block/attn/(kv_latent|scores)/",
+                                scope_of.get(m.group(2), ""))
+        if not scope:
+            continue
+        op, result = m.group(1), line.split("->")[-1]
+        seen[scope.group(1)] += 1
+        if whole.search(result):
+            assert op not in ("concatenate", "broadcast_in_dim", "pad"), line
+            products += op == "dot_general"
+    # k and v leave a product each in every forward pass and recompute
+    assert products >= 10, products
+    assert seen["kv_latent"] > 100 and seen["scores"] > 100, seen
+
+
 def test_no_stride_and_no_join_of_q_in_the_lowered_toy_step():
     """The lowered text of the toy step's forward and backward (tests/
     flash_step_texts.py: ``value_and_grad(loss)``, remat on), by the scope

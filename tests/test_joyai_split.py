@@ -20,8 +20,11 @@ from tests.test_joyai import (  # noqa: F401 (the fixtures come by name)
 from tests.util import base_config
 
 #: float32 bits of the first step's loss, and the step's kernels by name
-#: with the instructions that carry each, at the parent of PR 56
-LOSS_BITS = 1097189736      # 14.361671447753906
+#: with the instructions that carry each, at the parent of PR 56 — the
+#: loss one place above that (…736, 14.361671447753906) since PR 57: ``k``
+#: leaves a product of ``[c_kv | k_r]``, and this CPU's float32 dot sums
+#: its 40 terms, eight of them exact zeros, in another order than 32
+LOSS_BITS = 1097189737      # 14.361672401428223
 KERNELS = {"ds_flash_fwd": 255, "ds_flash_bwd_dkv": 198,
            "ds_flash_bwd_dq": 117, "ds_ggemm_fwd": 221, "ds_ggemm_dx": 108,
            "ds_ggemm_dw": 160, "ds_rowsum": 312}
