@@ -34,7 +34,6 @@ from bench import train_config, train_flops_per_token
 from deepspeed_tpu.models.gpt2 import gpt2_model
 from deepspeed_tpu.ops.attention import flash_status
 from deepspeed_tpu.telemetry import tracing
-from deepspeed_tpu.telemetry.costmodel import get_report
 from deepspeed_tpu.telemetry.mfu import peak_flops_per_device
 from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
@@ -86,21 +85,23 @@ def release(engine):
             leaf.delete()
 
 
-def pallas_call_sites(engine):
-    """Kernel launch sites in the engine's train step, from the cost
-    report its first step registers; a dead cost model fails here."""
-    check(engine._step_cost_ok, "cost model did not analyse the train step")
-    return get_report("train/step").pallas_launches
+def pallas_call_sites():
+    """Kernel launch sites in the train step of the engine that stepped
+    last, from its cost report, which is made here, for the asking; a
+    dead cost model fails here."""
+    report = tracing.get_program_cost()
+    check(report is not None, "cost model did not analyse the train step")
+    return report.pallas_launches
 
 
-def require_flash(engine):
+def require_flash():
     """The auto ladder chose a Pallas kernel for every shape class it met
     and the step's jaxpr holds kernel launches — anything else means the
     [S,S] einsum stood in."""
     status = flash_status()
     check(status and all(v is True for v in status.values()),
           f"flash kernel not selected: {status}")
-    sites = pallas_call_sites(engine)
+    sites = pallas_call_sites()
     check(sites > 0, "no pallas_call in the train step's jaxpr")
     return sites
 
@@ -146,10 +147,10 @@ def phase_kernel_vs_reference(size="760m", num_layers=2, seq=1024, micro=12,
                              model.config.vocab_size)
         losses[impl] = float(engine.train_batch(batch=batch))
         if impl == "auto":
-            sites = require_flash(engine)
+            sites = require_flash()
             _, calls = kernel_calls(engine, batch, mosaic)
         else:
-            check(pallas_call_sites(engine) == 0,
+            check(pallas_call_sites() == 0,
                   "the einsum step holds pallas_call sites")
         release(engine)
     check(all(math.isfinite(v) for v in losses.values()), f"{losses}")
@@ -177,8 +178,11 @@ def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
                        **widths)
     cfg = model.config
     t_start = t0 = time.perf_counter()
+    # the engine waits for the device where it prints: at the last timed
+    # step, where this loop waits anyway, its rate gauges are written
     engine, *_ = deepspeed_tpu.initialize(
-        model=model, config=train_config(micro, zero_stage=2))
+        model=model, config={**train_config(micro, zero_stage=2),
+                             "steps_per_print": warmup + steps})
     global_batch = micro * engine.topology.dp_world_size
     batch = seeded_batch(0, global_batch, seq, cfg.vocab_size)
     init_s = time.perf_counter() - t0
@@ -188,7 +192,7 @@ def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
     jax.block_until_ready(losses)
     warmup_s = time.perf_counter() - t0
     setup_compiles, compile_s = backend_compiles(since=t_start)
-    kernel_sites = require_flash(engine)
+    kernel_sites = require_flash()
 
     t_timed = t0 = time.perf_counter()
     timed = [engine.train_batch(batch=batch) for _ in range(steps)]
@@ -201,6 +205,10 @@ def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
     tokens_per_s = global_batch * seq / step_s
     n = len(jax.devices())
     mfu = tokens_per_s / n * train_flops_per_token(model, seq) / peak
+    # the engine's own MFU is 6N over a window that began at its first
+    # step, compile and all: above the timed steps' it divided by a
+    # dispatch time
+    gauge = engine.telemetry_registry.get_gauge("train/mfu")
     say(phase="trainer", device=device_fields(),
         model={"name": model.meta["name"], "n_params": model.meta["n_params"],
                "num_layers": cfg.num_layers, "d_model": cfg.d_model,
@@ -208,7 +216,7 @@ def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
         setup={"init_s": init_s, "warmup_s": warmup_s,
                "compile_s": compile_s, "compiles": setup_compiles},
         step_s=step_s, tokens_per_s_per_chip=tokens_per_s / n, mfu=mfu,
-        peak_flops_per_chip=peak, timed_steps=steps,
+        engine_mfu_gauge=gauge, peak_flops_per_chip=peak, timed_steps=steps,
         timed_compiles=timed_compiles, pallas_call_sites=kernel_sites,
         kernel_calls=len(calls), losses=losses)
     check(all(math.isfinite(v) for v in losses), f"non-finite loss: {losses}")
@@ -217,9 +225,10 @@ def phase_trainer(size="760m", seq=1024, micro=12, warmup=2,
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
     check(timed_compiles == 0,
           f"{timed_compiles} compilations inside the timed steps")
-    check(engine._peak_flops and
-          engine.telemetry_registry.get_gauge("train/mfu") is not None,
+    check(engine._peak_flops and gauge is not None,
           "engine published no MFU from the library's peak table")
+    check(0 < gauge <= mfu, f"engine's train/mfu {gauge} against {mfu} "
+                            f"over the timed steps")
     release(engine)
 
 
@@ -261,7 +270,7 @@ def phase_zero3_four_chips(size="760m", num_layers=6, seq=1024, micro=12,
         return engine, b, losses
 
     engine, batch, sharded = run(devices, 1)
-    kernel_sites = require_flash(engine)
+    kernel_sites = require_flash()
     threshold = engine._config.zero_config.param_persistence_threshold
     for path, leaf in jax.tree_util.tree_leaves_with_path(
             engine.state["params"]):

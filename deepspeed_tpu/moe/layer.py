@@ -38,7 +38,7 @@ moe_dispatch="grouped")``): forward, ``dx`` and ``dw`` then run as the
 named ``ds_ggemm_*`` kernels under ``lax.scan`` + ``jax.checkpoint`` and
 no token is dropped at any imbalance.  Its parts carry the
 ``jax.named_scope``s ``router`` / ``dispatch`` / ``experts`` /
-``combine`` (telemetry/tracing.py ``STEP_SCOPES``).
+``combine`` (telemetry/tracing.py ``SCOPE_ROUTER`` ... ``SCOPE_COMBINE``).
 """
 import contextlib
 import os

@@ -54,9 +54,9 @@ the caller names; elsewhere :func:`_causal_conv_xla`, shifted copies with
 autodiff's backward.
 
 Their parts of a step carry the ``jax.named_scope``s ``conv`` and
-``delta_rule`` (telemetry/tracing.py ``STEP_SCOPES``), written by the
-model, and each call leaves its shape and the lowering it took (``path``,
-with the kernels' grid blocking) in the step's account
+``delta_rule`` (telemetry/tracing.py ``SCOPE_CONV``, ``SCOPE_DELTA_RULE``),
+written by the model, and each call leaves its shape and the lowering it
+took (``path``, with the kernels' grid blocking) in the step's account
 (``tracing.delta_rule_chunks``, ``tracing.conv_calls``).
 """
 import jax

@@ -81,7 +81,7 @@ Every ``pl.pallas_call`` here has a ``name=``, which is how the
 step-program map (``get_program_map`` of telemetry/tracing.py) tells
 these kernels from the flash kernel in a compiled step: ``ds_ggemm_fwd``,
 ``ds_ggemm_dx`` (the same kernel body on a transposed right-hand side),
-``ds_ggemm_dw`` (the three of ``KERNEL_NAMES`` that training runs),
+``ds_ggemm_dw`` (the three that training runs),
 ``ds_ggemm_q`` (int8 weights) and ``ds_ggemm_slots`` /
 ``ds_ggemm_slots_q`` (decode-sized), and ``ds_rowsum`` (a held plan's
 rows summed into their tokens).  ``ds_unwritten_*`` are no kernels

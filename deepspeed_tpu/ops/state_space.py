@@ -38,7 +38,7 @@ divide the sequence the tail is padded with tokens of step 0.
 chunked form is tested against, and what a decode step would run.
 
 The model writes the ``jax.named_scope`` ``scan`` around the call
-(telemetry/tracing.py ``STEP_SCOPES``); each call leaves its chunk count,
+(telemetry/tracing.py ``SCOPE_SCAN``); each call leaves its chunk count,
 chunk length, heads and groups in the step's account
 and the lowering it took (``tracing.ssd_chunks``).
 

@@ -480,9 +480,10 @@ class TelemetryConfig(DeepSpeedConfigModel):
     anomaly_threshold: float = 5.0
     #: detector window (recent step latencies the median/MAD run over)
     anomaly_window: int = 64
-    #: compiled-program cost model (ISSUE 13): one-time jaxpr analysis
-    #: of the fused train step (FLOPs/bytes/launches -> perf/* gauges,
-    #: /debug/perf, post-mortem perf.json).  DS_PERF_COSTMODEL env wins.
+    #: compiled-program cost model (ISSUE 13).  The trainer no longer
+    #: reads it: the fused train step's report is made for whoever asks
+    #: (telemetry/tracing.py ``get_program_cost``); the serving
+    #: scheduler's eager analysis follows DS_PERF_COSTMODEL alone.
     costmodel: bool = True
     #: tiered memory ledger (ISSUE 14): per-step byte attribution by
     #: tier/owner (mem/* gauges, /debug/memory, post-mortem

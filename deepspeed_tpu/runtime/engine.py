@@ -738,7 +738,10 @@ class DeepSpeedEngine:
         self.timers = SynchronizedWallClockTimer()
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_batch_size(),
-            steps_per_output=self._config.steps_per_print)
+            steps_per_output=self._config.steps_per_print,
+            sync_every_step=self._config.wall_clock_breakdown)
+        # tokens dispatched since the timer last waited for the device
+        self._tokens_since_sync = 0
         self.monitor = self._build_monitor()
         self.last_metrics: Dict[str, float] = {}
 
@@ -778,12 +781,6 @@ class DeepSpeedEngine:
             # constructed before the recorder existed: late-bind so
             # param/swap_fail + param/degraded events land in the ring
             self.param_store.flightrec = self.flightrec
-        # perf observatory (ISSUE 13): one-time cost analysis of the
-        # fused train-step program (perf/* gauges + span annotation).
-        # _step_cost_ok flips only when a report actually registered —
-        # a disabled/failed analysis must not leak perf gauges
-        self._step_cost_done = False
-        self._step_cost_ok = False
         self.metrics_server = None
         if tcfg.metrics_port is not None and jax.process_index() == 0:
             from deepspeed_tpu.telemetry import MetricsServer
@@ -1890,21 +1887,10 @@ class DeepSpeedEngine:
         latency, tokens/s, and MFU land in the metrics registry."""
         step = self.global_steps + 1
         t0 = time.perf_counter()
-        span_args = {"step": step}
-        if self._step_cost_ok and self.tracer.enabled:
-            # cost annotation (ISSUE 13): once the step program's
-            # CostReport exists, every train/step span of the armed
-            # tracer carries it (a profiler-only span has no arguments)
-            from deepspeed_tpu.telemetry.costmodel import get_report
-            rep = get_report("train/step")
-            if rep is not None:
-                span_args.update(cost_flops=rep.flops,
-                                 cost_hbm_bytes=rep.hbm_bytes,
-                                 cost_pallas_launches=rep.pallas_launches)
         with tracing.setup_span(tracing.SPAN_TRAIN_STEP,
                                 step=self.global_steps, tracer=self.tracer,
                                 cat="train", corr=f"train-step-{step}",
-                                args=span_args):
+                                args={"step": step}):
             loss = self._train_batch_impl(data_iter=data_iter, batch=batch)
             # still inside the train/step span so an anomaly instant
             # lands between this step's B/E pair (the serve side keeps
@@ -1921,12 +1907,10 @@ class DeepSpeedEngine:
             # straggling link would, while /debug/comm keeps answering
             comm_corr = f"train-step-{self.global_steps + 1}"
             self._commstat.step_begin()
-            wire = 0
-            if self._step_cost_ok:
-                from deepspeed_tpu.telemetry.costmodel import get_report
-                rep = get_report("train/step")
-                if rep is not None:
-                    wire = rep.comm_wire_bytes()
+            # the step's wire bytes, once somebody has asked for its
+            # cost report (tracing.get_program_cost); a drill starts none
+            rep = tracing.get_program_cost(create=False)
+            wire = rep.comm_wire_bytes() if rep is not None else 0
             with self.tracer.span("comm/step_window", cat="comm",
                                   corr=comm_corr,
                                   args={"wire_bytes": wire}):
@@ -2070,10 +2054,6 @@ class DeepSpeedEngine:
                     self._train_scope(), self._ltd_scope(), \
                     self._aq_scope():
                 self.state, metrics = fn(self.state, batch, rng)
-            # the observers of the first step read shapes only, and come
-            # after its dispatch: the device works while the host walks
-            # the step once more, and jit's trace is the program's first
-            self._maybe_cost_report(batch, rng)
             self._maybe_register_program_map(batch)
         self._finish_step(metrics)
         # syncing on the loss every step stalls the async dispatch
@@ -2438,43 +2418,14 @@ class DeepSpeedEngine:
             msg += f" grad_norm={float(metrics.get('grad_norm', 0.0)):.3f}"
             log_dist(msg, ranks=[0])
 
-    def _maybe_cost_report(self, batch, rng):
-        """One-time jaxpr cost analysis of the fused train step
-        (ISSUE 13): dot FLOPs, boundary HBM bytes (state read+written +
-        batch — the step streams its whole state), pallas launch sites,
-        and collective bytes, registered as the ``train/step`` program
-        and published as ``perf/*`` gauges.  One extra host-side trace,
-        once per engine; never raises into the step."""
-        if self._step_cost_done:
-            return
-        self._step_cost_done = True
-        tcfg = self._config.telemetry_config
-        from deepspeed_tpu.telemetry.costmodel import costmodel_enabled
-        if not (tcfg.enabled and costmodel_enabled(tcfg.costmodel)):
-            return
-        try:
-            from deepspeed_tpu.telemetry.costmodel import analyze_fn
-            from deepspeed_tpu.telemetry.roofline import publish_report
-            with tracing.setup_span(tracing.SPAN_COST_ANALYZE), \
-                    self._train_scope(), self._ltd_scope(), self._aq_scope():
-                report = analyze_fn(
-                    self._step_program("train_step"), self.state, batch, rng,
-                    name="train/step",
-                    detail={"tokens_per_step": self.train_batch_size()
-                            * max(self._last_seq_len or 0, 0)})
-            publish_report(self.telemetry_registry, report)
-            self._step_cost_ok = True
-        except Exception as e:          # noqa: BLE001 — best-effort
-            from deepspeed_tpu.utils.logging import logger
-            logger.warning(f"costmodel: train/step analysis failed: {e}")
-
     def _maybe_register_program_map(self, batch):
         """On the first fused dispatch, publish the step under
-        ``"train/step"`` for ``get_program_map`` (telemetry/tracing.py)
-        and ``step_memory`` (telemetry/memory.py): two thunks and the
-        batch's abstract signature, nothing more — the executable is
-        fetched when someone first ASKS for either (a peek —
-        ``/debug/memory``, a post-mortem bundle — fetches nothing) and
+        ``"train/step"`` for ``get_program_map`` (telemetry/tracing.py),
+        ``step_memory`` (telemetry/memory.py) and ``get_program_cost``:
+        three thunks and the batch's abstract signature, nothing more —
+        the executable is fetched, or the step traced once more for its
+        cost report, when someone first ASKS (a peek — ``/debug/memory``,
+        ``/debug/perf``, a post-mortem bundle — starts nothing) and
         dropped.  Every load leaves its six numbers behind, so the map
         and then the account are ONE load; the text (tens of MB) is
         handed to its asker and not kept, so the account first and the
@@ -2484,6 +2435,7 @@ class DeepSpeedEngine:
             return
         self._program_map_registered = True
         signature = jax.tree.map(_abstract_placed, batch)
+        tokens = self.train_batch_size() * max(self._last_seq_len, 0)
         alive = weakref.ref(self)
         kept = {}               # "program": the first load's numbers
 
@@ -2525,7 +2477,29 @@ class DeepSpeedEngine:
                 "batch": fullest_device_bytes(batch=signature)["batch"],
                 "program": program, "gradients": gradients,
                 "temporaries": temporaries}
-        register_program(TRAIN_STEP_PROGRAM, step_text, step_bytes)
+
+        def step_cost():
+            """Dot FLOPs, boundary HBM bytes (state read + written +
+            batch: the step streams its whole state), pallas launch
+            sites and collective bytes of the step, from shapes alone;
+            whoever asks also publishes the static ``perf/*`` gauges."""
+            engine = alive()
+            if engine is None:
+                return None
+            from deepspeed_tpu.telemetry.costmodel import analyze_fn
+            from deepspeed_tpu.telemetry.roofline import publish_report
+            set_topology(engine.topology)
+            with tracing.setup_span(tracing.SPAN_COST_ANALYZE), \
+                    engine._train_scope(), engine._ltd_scope(), \
+                    engine._aq_scope():
+                report = analyze_fn(
+                    engine._step_program("train_step"),
+                    *engine._abstract_step_args(signature),
+                    name=TRAIN_STEP_PROGRAM,
+                    detail={"tokens_per_step": tokens})
+            publish_report(engine.telemetry_registry, report)
+            return report
+        register_program(TRAIN_STEP_PROGRAM, step_text, step_bytes, step_cost)
 
     def compile_train_step(self, batch):
         """The fused step ``train_batch`` runs for ``batch`` (leaves lead
@@ -2541,9 +2515,13 @@ class DeepSpeedEngine:
         fn = self._get_compiled("train_step")
         with tracing.setup_span(tracing.SPAN_COMPILE_AOT), \
                 self._train_scope(), self._ltd_scope(), self._aq_scope():
-            return fn.lower(
-                jax.tree.map(_abstract, self.state, self.state_shardings),
-                signature, _abstract(self._rng)).compile()
+            return fn.lower(*self._abstract_step_args(signature)).compile()
+
+    def _abstract_step_args(self, signature):
+        """The fused step's arguments as shapes: nothing is read from
+        the device or donated."""
+        return (jax.tree.map(_abstract, self.state, self.state_shardings),
+                signature, _abstract(self._rng))
 
     def _postmortem_dir(self) -> str:
         """Training-side bundle placement (the preemption.py rules):
@@ -2611,13 +2589,16 @@ class DeepSpeedEngine:
         return g % max(len(self._num_groups), 1)
 
     def _record_step_telemetry(self, duration_s: float):
-        """Per-step registry update + monitor bridge (ISSUE 4): step
-        latency histogram, tokens/s, and the MFU gauge — model FLOPs
-        (``flops_per_token × tokens``, the Megatron 6N convention the
-        in-tree models declare) over wall clock against the local
-        devices' peak.  Wall clock is dispatch-side (unsynced) between
-        bridge boundaries, exactly like ThroughputTimer — the bridge
-        step's sync closes the window."""
+        """Per-step registry update + monitor bridge (ISSUE 4).
+        ``duration_s`` is what the ``train_batch`` call took to return —
+        a dispatch time on an asynchronous device: it feeds the latency
+        histogram, the flight recorder and the anomaly detector, which
+        watch for a wedged host step.  The rates (tokens/s, model FLOP/s
+        as ``flops_per_token × tokens``, the Megatron 6N convention the
+        in-tree models declare, and MFU against the local devices' peak)
+        are written only on a step whose ``tput_timer.stop`` has just
+        waited for the device: the tokens dispatched since the previous
+        such stop over the timer's window between the two."""
         tcfg = self._config.telemetry_config
         if not tcfg.enabled:
             return
@@ -2636,28 +2617,27 @@ class DeepSpeedEngine:
             # close the per-step collective window (ISSUE 19): publishes
             # comm/overlap_fraction and the comm/step flight event
             self._commstat.step_end(duration_s, corr=corr)
-        if self._step_cost_ok:
-            # achieved-vs-floor for the fused step program (ISSUE 13);
-            # floors only resolve where the device rate tables do
-            from deepspeed_tpu.telemetry.roofline import observe_achieved
-            observe_achieved(reg, "train/step", duration_s)
         if self._mem_on:
             # memory observatory (ISSUE 14): mem/* gauges + the HBM
             # used-fraction anomaly feed (a leak flags before the OOM)
             get_memory_ledger().publish_and_feed(reg, self.anomaly,
                                                  corr=corr)
-        tokens = self.train_batch_size() * max(self._last_seq_len, 0)
-        if tokens and duration_s > 0:
-            reg.set_gauge("train/tokens_per_s", tokens / duration_s)
-        fpt = getattr(self.model, "flops_per_token", None) or 0.0
-        if fpt and tokens and duration_s > 0:
-            flops = fpt * tokens
-            reg.set_gauge("train/model_flops_per_s", flops / duration_s)
-            if self._peak_flops:
-                from deepspeed_tpu.telemetry import mfu as _mfu
-                val = _mfu(flops, duration_s, self._peak_flops)
-                if val is not None:
-                    reg.set_gauge("train/mfu", val)
+        self._tokens_since_sync += (self.train_batch_size()
+                                    * max(self._last_seq_len, 0))
+        window_s = self.tput_timer.synced_window_s
+        if window_s:
+            tokens, self._tokens_since_sync = self._tokens_since_sync, 0
+            flops = tokens * (getattr(self.model, "flops_per_token", None)
+                              or 0.0)
+            if tokens:
+                reg.set_gauge("train/tokens_per_s", tokens / window_s)
+            if flops:
+                reg.set_gauge("train/model_flops_per_s", flops / window_s)
+                if self._peak_flops:
+                    from deepspeed_tpu.telemetry import mfu as _mfu
+                    val = _mfu(flops, window_s, self._peak_flops)
+                    if val is not None:
+                        reg.set_gauge("train/mfu", val)
         if (self.monitor is not None and self.monitor.enabled
                 and tcfg.monitor_interval
                 and self.global_steps % tcfg.monitor_interval == 0):

@@ -32,8 +32,10 @@ Reports register into a process-wide table (plain dict writes — the
 ``/debug/perf`` reader never takes any scheduler lock) so the metrics
 surfaces, post-mortem bundles, and ``scripts/perf_report.py`` all read
 one source of truth.  Analysis costs one extra trace per program
-family; ``DS_PERF_COSTMODEL=0`` (or ``telemetry.costmodel: false``)
-disables it.
+family: the serving scheduler pays it at a family's first execution
+(``DS_PERF_COSTMODEL=0`` switches that off); the engine's train step
+is analysed when somebody asks (telemetry/tracing.py
+``get_program_cost``), and :func:`get_report` only ever peeks.
 """
 import math
 import os

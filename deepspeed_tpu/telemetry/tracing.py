@@ -39,9 +39,9 @@ When no trace path is armed, every hook routes through
 whose other hooks do nothing.
 
 **Inside the compiled step** the host cannot see, so the program names
-its own parts: ``jax.named_scope``s with the fixed names of
-:data:`STEP_SCOPES` and Pallas kernels with those of
-:data:`KERNEL_NAMES`.  Scopes are HLO metadata only — no runtime cost,
+its own parts: ``jax.named_scope``s with the fixed names of the
+``SCOPE_*`` constants below and Pallas kernels with a ``ds_*`` ``name=``
+of their own.  Scopes are HLO metadata only — no runtime cost,
 no change to what XLA fuses.  A device trace does not carry that
 metadata (an ``XLA Ops`` event is the instruction's text without
 ``metadata={...}``), but it does carry the **instruction name**, and so
@@ -398,30 +398,6 @@ SCOPE_HC = "hc"
 SCOPE_HC_COEFF = "coeff"
 SCOPE_HC_READ = "read"
 SCOPE_HC_WRITE = "write"
-STEP_SCOPES = (SCOPE_FWD_BWD, SCOPE_ACCUMULATE, SCOPE_OPTIMIZER,
-               SCOPE_EMBED, SCOPE_BLOCK, SCOPE_ATTN, SCOPE_MLP,
-               SCOPE_HEAD_LOSS, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
-               SCOPE_COMBINE, SCOPE_SHARED_EXPERT, SCOPE_LINEAR_ATTN,
-               SCOPE_IN_PROJ, SCOPE_CONV, SCOPE_DELTA_RULE, SCOPE_GATE_NORM,
-               SCOPE_OUT_PROJ, SCOPE_SSM, SCOPE_SCAN, SCOPE_Q_LATENT,
-               SCOPE_KV_LATENT, SCOPE_ROPE, SCOPE_SCORES, SCOPE_MTP,
-               SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_HEAD_GATE,
-               SCOPE_LEAD_MLP, SCOPE_EXCHANGE, SCOPE_SEND, SCOPE_RETURN,
-               SCOPE_MAMBA, SCOPE_GATE, SCOPE_GMU, SCOPE_DIFF_ATTN,
-               SCOPE_QKV, SCOPE_FLASH, SCOPE_HC, SCOPE_HC_COEFF,
-               SCOPE_HC_READ, SCOPE_HC_WRITE)
-#: ``name=`` of each ``pl.pallas_call`` of the training path: the flash
-#: kernel's three, and the grouped GEMM's forward, dx (the forward kernel
-#: on a transposed right-hand side) and dw, the gated delta rule's two,
-#: the state-space scan's two and the short causal convolution's two, the
-#: flash kernel's three where the call has a sliding window, the sum of a
-#: held plan's rows into their tokens, and the selective scan's two
-KERNEL_NAMES = ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq",
-                "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw",
-                "ds_gdr_fwd", "ds_gdr_bwd", "ds_ssd_fwd", "ds_ssd_bwd",
-                "ds_conv_fwd", "ds_conv_bwd", "ds_flash_win_fwd",
-                "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq", "ds_rowsum",
-                "ds_sscan_fwd", "ds_sscan_bwd")
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -616,7 +592,7 @@ _PROGRAM_LOCK = threading.Lock()
 #: askers at once make one load and a registrant's thunks may share what
 #: they keep; never held with _PROGRAM_LOCK wanted by a peek
 _PROGRAM_ASK_LOCK = threading.RLock()
-#: name -> {"text": thunk, "memory": thunk or None}
+#: name -> {"text": thunk, "memory": thunk or None, "cost": thunk or None}
 _PROGRAM_THUNKS: Dict[str, Dict[str, Optional[Callable[[], Any]]]] = {}
 #: name -> what the thunks gave when first asked, by the same keys (the
 #: text as its parsed map)
@@ -624,15 +600,19 @@ _PROGRAM_FACTS: Dict[str, Dict[str, Any]] = {}
 
 
 def register_program(name: str, text_thunk: Callable[[], Optional[str]],
-                     memory_thunk: Optional[Callable[[], Any]] = None):
+                     memory_thunk: Optional[Callable[[], Any]] = None,
+                     cost_thunk: Optional[Callable[[], Any]] = None):
     """Publish a program under ``name``.  ``text_thunk()`` returns the
     HLO text of the executable that runs, ``memory_thunk()`` what is
     counted of its bytes per device (telemetry/memory.py
-    ``step_memory`` says which keys); either returns None if it can no
-    longer be had.  Neither is called here — only by the first
-    :func:`get_program_map` / :func:`get_program_memory` that asks."""
+    ``step_memory`` says which keys), ``cost_thunk()`` its
+    telemetry/costmodel.py ``CostReport``; each returns None if it can
+    no longer be had.  None is called here — only by the first
+    :func:`get_program_map` / :func:`get_program_memory` /
+    :func:`get_program_cost` that asks."""
     with _PROGRAM_LOCK:
-        _PROGRAM_THUNKS[name] = {"text": text_thunk, "memory": memory_thunk}
+        _PROGRAM_THUNKS[name] = {"text": text_thunk, "memory": memory_thunk,
+                                 "cost": cost_thunk}
         _PROGRAM_FACTS.pop(name, None)
 
 
@@ -692,6 +672,16 @@ def get_program_memory(name: str = TRAIN_STEP_PROGRAM, create: bool = True):
     ``create=False``: only what an earlier asker already made — a load
     of the step's executable is not a reader's to start."""
     return _program_fact(name, "memory", create=create)
+
+
+def get_program_cost(name: str = TRAIN_STEP_PROGRAM, create: bool = True):
+    """The ``CostReport`` of the program registered under ``name``, as
+    its ``cost_thunk`` made it when first asked (the engine's: one more
+    trace of the step from shapes alone and a walk of its jaxpr, handed
+    to telemetry/roofline.py ``publish_report`` — the ``perf/*`` gauges
+    and ``costmodel.get_report`` have it from then on), or None.
+    ``create=False``: only what an earlier asker already made."""
+    return _program_fact(name, "cost", create=create)
 
 
 #: an instruction inside a layer loop of the step: a ``while`` body below
@@ -937,8 +927,8 @@ def gradient_bytes(name: str = TRAIN_STEP_PROGRAM):
 # ==================================================== where a start goes
 #: Fixed names of the host spans the program opens at its own boundaries
 #: before (and around) its first steps — part of the program's interface,
-#: beside :data:`STEP_SCOPES` and :data:`KERNEL_NAMES`: readers of the
-#: set-up account, of a profiler session (``ds/engine/init`` ...) and of
+#: beside the ``SCOPE_*`` names above: readers of the set-up account,
+#: of a profiler session (``ds/engine/init`` ...) and of
 #: the ``DS_TRACE`` file key on them.
 SPAN_ENGINE_INIT = "engine/init"            # DeepSpeedEngine.__init__, and in it
 SPAN_INIT_SHARDINGS = "engine/init/shardings"   # ... ZeRO policy, specs

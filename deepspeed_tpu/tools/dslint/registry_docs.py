@@ -76,8 +76,8 @@ ENV_VARS = {
                                 "resident_layers; ISSUE 17)",
     "DS_PEAK_FLOPS": "per-device peak FLOPs for MFU math (wins over "
                      "telemetry.peak_flops)",
-    "DS_PERF_COSTMODEL": "0/1 disables/forces compiled-program cost "
-                         "analysis (wins over telemetry.costmodel)",
+    "DS_PERF_COSTMODEL": "0/1 disables/forces the serving scheduler's "
+                         "compiled-program cost analysis",
     "DS_QGEMM": "0 disables the fused-dequant int8 qgemm kernel "
                 "(per-layer dequant fallback)",
     "DS_QGEMM_BLOCKS": "qgemm (bm,bk,bn) block-shape override "
@@ -110,10 +110,16 @@ METRICS = {
                          "held_rows_bound); each step in which one is "
                          "not zero is warned of",
     "train/steps": "train_batch iterations completed",
-    "train/step_latency_s": "per-step wall-clock histogram",
-    "train/tokens_per_s": "training token throughput gauge",
-    "train/model_flops_per_s": "achieved model FLOP/s gauge",
-    "train/mfu": "model FLOPs utilization vs device peak",
+    "train/step_latency_s": "histogram of what a train_batch call took "
+                            "to return (a dispatch time on an async "
+                            "device)",
+    "train/tokens_per_s": "training token throughput over the last "
+                          "synced window (steps_per_print boundary, or "
+                          "every step under wall_clock_breakdown)",
+    "train/model_flops_per_s": "achieved model FLOP/s over the same "
+                               "synced window",
+    "train/mfu": "model FLOPs utilization vs device peak over the same "
+                 "synced window",
     "train/profiled_flops_per_s": "flops-profiler measured FLOP/s",
     "train/profiled_mfu": "flops-profiler measured MFU",
     # --- compiles, from jax's own events (telemetry/tracing.py)

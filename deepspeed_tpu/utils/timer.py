@@ -119,17 +119,25 @@ class ThroughputTimer:
     """samples/sec + tokens/sec aggregation across steps."""
 
     def __init__(self, batch_size: int, start_step: int = 2,
-                 steps_per_output: Optional[int] = None, monitor_memory: bool = False):
+                 steps_per_output: Optional[int] = None, monitor_memory: bool = False,
+                 sync_every_step: bool = False):
         self.batch_size = max(batch_size, 1)
         self.start_step = start_step
         self.steps_per_output = steps_per_output
         self.monitor_memory = monitor_memory
+        #: wait for the device at every stop, not at report boundaries alone
+        self.sync_every_step = sync_every_step
         self.epoch_count = 0
         self.global_step_count = 0
         self.total_elapsed_time = 0.0
-        self.step_elapsed_time = 0.0
         self.started = False
         self.start_time = 0.0
+        #: the window between two stops that waited for the device (the
+        #: first opens at the first start): where it opened, and the
+        #: seconds it took if the LAST stop closed one — None after a stop
+        #: that waited for nothing, whose own duration is a dispatch time
+        self._window_start = None
+        self.synced_window_s = None
 
     def update_epoch_count(self):
         self.epoch_count += 1
@@ -137,6 +145,8 @@ class ThroughputTimer:
     def start(self):
         self.started = True
         self.start_time = time.time()
+        if self._window_start is None:
+            self._window_start = self.start_time
 
     def stop(self, global_step: bool = True, report_speed: bool = True, sync_obj=None):
         if not self.started:
@@ -148,19 +158,23 @@ class ThroughputTimer:
         # serialise the async dispatch pipeline.  Between reports the
         # wall-clock durations still sum correctly because the boundary sync
         # closes the window.
-        if will_report:
+        synced = bool((will_report or self.sync_every_step)
+                      and sync_obj is not None)
+        if synced:
             _sync(sync_obj)
-        duration = time.time() - self.start_time
+        now = time.time()
+        duration = now - self.start_time
+        self.synced_window_s = now - self._window_start if synced else None
+        if synced:
+            self._window_start = now
         if global_step:
             self.global_step_count += 1
         if self.global_step_count > self.start_step:
             self.total_elapsed_time += duration
-            self.step_elapsed_time += duration
             if will_report:
                 log_dist(
                     f"step={self.global_step_count}, "
                     f"samples/sec={self.avg_samples_per_sec():.2f}", ranks=[0])
-                self.step_elapsed_time = 0.0
 
     def avg_samples_per_sec(self) -> float:
         counted = self.global_step_count - self.start_step

@@ -17,6 +17,7 @@ from deepspeed_tpu.ops import state_space as ss
 from deepspeed_tpu.ops.linear_attention import causal_conv
 from deepspeed_tpu.ops.pallas import causal_conv as kernels
 from deepspeed_tpu.telemetry import tracing
+from tests.util import kernel_names
 
 B, S, C = 2, 512, 256          # two tiles of positions, two channel groups
 F32_TOL = 1e-5                 # max |a - b| / max |b|; measured <= 1e-6
@@ -338,7 +339,8 @@ def test_the_account_says_which_lowering_ran():
     assert tracing.conv_calls("test/conv") == [
         {**row, "orientation": "lanes", "tile": 512},
         {**row, "orientation": "sublanes", "tile": 128}]
-    assert {"ds_conv_fwd", "ds_conv_bwd"} <= set(tracing.KERNEL_NAMES)
+    assert {"ds_conv_fwd", "ds_conv_bwd"} <= kernel_names(
+        _conv(None, "silu", "lanes", True), x, w, b)
 
 
 def test_an_activation_the_op_does_not_know():

@@ -3,6 +3,7 @@ the read and the write against the equations spelled out token by token in
 numpy; what the Sinkhorn sweeps reach after 20 and after 1; the clamp and
 ``hc_eps`` each on a case built to show them; the start; the dtypes; the
 step's account."""
+import re
 from dataclasses import replace
 
 import jax
@@ -15,6 +16,7 @@ from deepspeed_tpu.ops.hyper_connection import (HyperConnection, exit_sum,
                                                 hc_write, init_hc_params,
                                                 replicate, sinkhorn)
 from deepspeed_tpu.telemetry import tracing
+from tests.util import scope_parts
 
 HC = HyperConnection()
 T, C = 6, 16
@@ -272,4 +274,5 @@ def test_the_scopes_are_the_ones_the_readers_key_on():
     text = jax.jit(f).lower(x).as_text(debug_info=True)
     for scope in ("hc/coeff", "hc/read", "hc/write"):
         assert scope in text, scope
-    assert {"hc", "coeff", "read", "write"} <= set(tracing.STEP_SCOPES)
+    assert {"hc", "coeff", "read", "write"} <= scope_parts(
+        re.findall(r'"([^"]*\bhc/[^"]*)"', text))

@@ -16,7 +16,7 @@ from deepspeed_tpu.telemetry import tracing
 from tests.test_nemotron_h import (  # noqa: F401 (the fixture comes by name)
     B, GAS, LOSS_TOL, S, TOY, one_device, packed_batch, real_kernels,
     reference, sizes_of, toy, toy_model)
-from tests.util import base_config
+from tests.util import base_config, scope_parts
 
 
 @functools.lru_cache(maxsize=None)
@@ -105,8 +105,8 @@ def test_scopes_and_counts_of_a_toy_step():
         if "ds.block" in (row["scope"] or ""):
             assert row["phase"] != "other", row
             assert any(part in row["scope"] for part in inside), row
-    assert set(tracing.STEP_SCOPES) >= {"ssm", "scan", "in_proj", "conv",
-                                        "gate_norm", "out_proj"}
+    assert scope_parts(scopes) >= {"ssm", "scan", "in_proj", "conv",
+                                   "gate_norm", "out_proj"}
     rows = tracing.grouped_gemm_rows("train/step")
     T, k = B * S, TOY["top_k"]
     bound = -(-(2 * T * k * 4 // 16) // 128) * 128

@@ -23,7 +23,7 @@ from deepspeed_tpu.models.mixtral import MIXTRAL_SIZES, mixtral_model
 from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.moe.sharded_moe import topk_routing
 from deepspeed_tpu.telemetry import tracing
-from tests.util import base_config
+from tests.util import base_config, scope_parts
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -227,10 +227,8 @@ def test_scopes_kernel_names_and_row_counts_of_a_toy_step():
                  "ds.block/mlp/experts", "ds.block/mlp/combine",
                  "ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"):
         assert any(name in s for s in scopes), name
-    assert {"router", "dispatch", "experts", "combine"} \
-        <= set(tracing.STEP_SCOPES)
-    assert {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"} \
-        <= set(tracing.KERNEL_NAMES)
+    assert {"router", "dispatch", "experts", "combine", "ds_ggemm_fwd",
+            "ds_ggemm_dx", "ds_ggemm_dw"} <= scope_parts(scopes)
     for phase in ("forward", "recompute", "backward"):
         assert any(row["phase"] == phase and "/experts/" in row["scope"]
                    for row in table.values() if row["scope"]), phase

@@ -12,7 +12,7 @@ from deepspeed_tpu.telemetry import tracing
 from tests.test_qwen3_next import (  # noqa: F401 (the fixtures come by name)
     B, GAS, S, TOY, micro, one_device, packed_batch, real_kernels,
     toy_model)
-from tests.util import base_config
+from tests.util import base_config, scope_parts
 
 
 def _counting_engine():
@@ -89,7 +89,7 @@ def test_scopes_and_counts_of_a_toy_step():
         assert any(row["phase"] == phase
                    and "/linear_attn/delta_rule/" in row["scope"]
                    for row in table.values() if row["scope"]), phase
-    assert set(tracing.STEP_SCOPES) >= {
+    assert scope_parts(scopes) >= {
         "linear_attn", "in_proj", "conv", "delta_rule", "gate_norm",
         "out_proj", "shared_expert"}
     rows = tracing.grouped_gemm_rows("train/step")

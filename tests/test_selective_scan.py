@@ -10,6 +10,7 @@ import pytest
 from deepspeed_tpu.ops.selective_scan import (selective_scan,
                                               selective_scan_recurrent)
 from deepspeed_tpu.telemetry import tracing
+from tests.util import kernel_names
 
 B, S, D, N, CHUNK = 2, 256, 128, 16, 128
 VALUE_TOL = 2e-5        # max |a - b| / max |b|; measured <= 1e-6
@@ -146,8 +147,10 @@ def test_the_account_has_a_row_a_call():
     assert xla["path"] == "xla" and xla["layer"] == 2 \
         and "channels_per_step" not in xla
     assert tracing.selective_scan_calls("test/none") is None
-    assert {"ds_sscan_fwd", "ds_sscan_bwd"} <= set(tracing.KERNEL_NAMES)
-    assert "mamba" in tracing.STEP_SCOPES and "scan" in tracing.STEP_SCOPES
+    assert {"ds_sscan_fwd", "ds_sscan_bwd"} <= kernel_names(
+        _call(selective_scan, None, interpret=True), *args)
+    # the scopes the model puts the call under (models/phi4flash.py)
+    assert (tracing.SCOPE_MAMBA, tracing.SCOPE_SCAN) == ("mamba", "scan")
 
 
 def test_shapes_the_kernels_do_not_take_fall_back():

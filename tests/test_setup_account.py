@@ -100,16 +100,16 @@ def test_a_start_by_program_stage_and_cause(fresh_compiles):
     assert len(rows_of(account, stage="lower", step=0)) == 1
     backend = [r for r in rows_of(account, step=0) if r["stage"] in BACKEND]
     assert [r["stage"] for r in backend] == ["compile"]   # the cache is off
-    # ... and once more for the cost report, from a fresh closure that
-    # jit's tracing cache cannot know: ROADMAP S5 (a) as a count
-    again = rows_of(account, stage="trace", retrace=True,
-                    cause=tracing.SPAN_COST_ANALYZE)
-    assert len(again) == 1 and not again[0]["recompile"]
-    assert again[0]["start"] > first[0]["end"]
-    # nothing but the cost report traced the step again: the second call
-    # found the program the first one compiled (S5 (d))
-    assert rows_of(account, stage="trace", retrace=True) == again
+    # ... and never again: the second call found the program the first
+    # one compiled (S5 (d)), and nobody has asked for the cost report
+    assert rows_of(account, stage="trace", retrace=True) == []
     assert [r for r in rows_of(account) if r["step"] >= 1] == []
+    # whoever asks pays for the one more trace, from a fresh closure that
+    # jit's tracing cache cannot know (what S5 (a) counted in every start)
+    assert tracing.get_program_cost() is not None
+    again = rows_of(tracing.setup_account(), stage="trace", retrace=True)
+    assert [r["cause"] for r in again] == [tracing.SPAN_COST_ANALYZE]
+    assert not again[0]["recompile"]
     for row in account["rows"]:
         assert row["stage"] in tracing.STAGES
         assert row["end"] >= row["start"] and row["self_s"] >= -1e-9
@@ -130,12 +130,12 @@ def test_the_programs_own_spans(fresh_compiles):
     for fused in (s for s in spans if s["name"] == tracing.SPAN_FUSED_STEP):
         assert by_id[fused["parent"]]["name"] == tracing.SPAN_TRAIN_STEP
         assert by_id[fused["parent"]]["step"] == fused["step"]
-    analyze, = [s for s in spans if s["name"] == tracing.SPAN_COST_ANALYZE]
-    # after the dispatch of the first step, inside its train/step
-    assert by_id[analyze["parent"]] is steps[0]
-    fused0 = next(s for s in spans if s["parent"] == steps[0]["id"]
-                  and s["name"] == tracing.SPAN_FUSED_STEP)
-    assert analyze["start"] >= fused0["end"]
+    # an observer's span is its asker's: none is opened by a start
+    assert not [s for s in spans if s["name"] in tracing.OBSERVER_SPANS]
+    tracing.get_program_cost()
+    analyze, = [s for s in tracing.setup_account()["spans"]
+                if s["name"] == tracing.SPAN_COST_ANALYZE]
+    assert analyze["parent"] is None and analyze["start"] >= steps[-1]["end"]
     for span in spans:
         parent = by_id.get(span["parent"])
         if parent is not None:
@@ -409,6 +409,7 @@ def test_the_trace_file_holds_spans_and_rows(tmp_path, monkeypatch,
     try:
         engine, batch = started(steps=2)
         engine.compile_train_step(batch)
+        tracing.get_program_cost()
         get_tracer().flush()
     finally:
         monkeypatch.delenv(TRACE_ENV)

@@ -16,6 +16,7 @@ from deepspeed_tpu.ops import state_space as ss
 from deepspeed_tpu.ops.pallas import state_space as kernels
 from deepspeed_tpu.ops.state_space import ssd_recurrent, ssd_scan
 from deepspeed_tpu.telemetry import tracing
+from tests.util import kernel_names
 
 B, S, H, P, G, N, CHUNK = 2, 512, 4, 64, 2, 128, 128
 BLOCK = 2               # chunks a grid step walks here: two blocks
@@ -239,7 +240,7 @@ def test_the_account_says_which_lowering_ran():
         {"chunks": S // CHUNK, "chunk_len": CHUNK, "batch": B, "heads": H,
          "groups": G, "head_dim": P, "state": N, "path": "kernel",
          "heads_per_step": H // G, "chunks_per_step": 4}]
-    assert {"ds_ssd_fwd", "ds_ssd_bwd"} <= set(tracing.KERNEL_NAMES)
+    assert {"ds_ssd_fwd", "ds_ssd_bwd"} <= kernel_names(_kernel(seg), *args)
 
 
 def test_a_toy_engines_step_runs_the_kernels_and_says_so(monkeypatch):

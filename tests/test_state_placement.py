@@ -116,11 +116,11 @@ def test_every_leaf_of_the_state_is_born_placed(name, devices8):
 
 
 def built_once_and_nothing_later(start):
-    # the first call's trace, lowering and executable, the cost report's
-    # walk of the step (S5 (a)), and nothing at step 1 or later
+    # the first call's trace, lowering and executable, and nothing at
+    # step 1 or later (the cost report's walk is its asker's)
     assert start.rows_after_first == []
     assert start.recompiled == 0
-    assert start.stages.count("trace") == 2
+    assert start.stages.count("trace") == 1
     assert start.stages.count("lower") == 1
     assert sum(start.stages.count(s) for s in ("compile", "cache_load")) == 1
 

@@ -10,12 +10,12 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.telemetry import reset_tracer
+from deepspeed_tpu.telemetry import reset_tracer, tracing
 from deepspeed_tpu.telemetry.costmodel import ring_wire_factor
 from deepspeed_tpu.telemetry.tracing import (
-    KERNEL_NAMES, NULL_TRACER, PHASES, STEP_SCOPES, SpanTracer,
-    count_in_step, get_program_map, grouped_gemm_rows, parse_program_text,
-    phase_of, register_program, reset_programs, step_account)
+    NULL_TRACER, PHASES, SpanTracer, count_in_step, get_program_map,
+    grouped_gemm_rows, parse_program_text, phase_of, register_program,
+    reset_programs, step_account)
 from tests.util import base_config, random_batch, tiny_gpt2
 
 
@@ -78,25 +78,22 @@ def test_phase_of(case):
 
 
 def test_fixed_names():
-    assert STEP_SCOPES == ("ds.fwd_bwd", "ds.accumulate", "ds.optimizer",
-                           "ds.embed", "ds.block", "attn", "mlp",
-                           "ds.head_loss", "router", "dispatch", "experts",
-                           "combine", "shared_expert", "linear_attn",
-                           "in_proj", "conv", "delta_rule", "gate_norm",
-                           "out_proj", "ssm", "scan", "q_latent",
-                           "kv_latent", "rope", "scores", "ds.mtp",
-                           "ds.attn_full", "ds.attn_sliding",
-                           "ds.head_gate", "ds.lead_mlp", "exchange",
-                           "exchange_send", "exchange_return", "mamba",
-                           "gate", "gmu", "diff_attn", "qkv", "flash", "hc",
-                           "coeff", "read", "write")
-    assert KERNEL_NAMES == ("ds_flash_fwd", "ds_flash_bwd_dkv",
-                            "ds_flash_bwd_dq", "ds_ggemm_fwd", "ds_ggemm_dx",
-                            "ds_ggemm_dw", "ds_gdr_fwd", "ds_gdr_bwd",
-                            "ds_ssd_fwd", "ds_ssd_bwd", "ds_conv_fwd",
-                            "ds_conv_bwd", "ds_flash_win_fwd",
-                            "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq",
-                            "ds_rowsum", "ds_sscan_fwd", "ds_sscan_bwd")
+    """What readers key on by name: the phases, the program's name and
+    the host spans of a start.  A scope or a kernel is read off an
+    instruction's ``op_name`` and is listed nowhere."""
+    assert PHASES == ("forward", "recompute", "backward", "optimizer",
+                      "accumulate", "other")
+    assert tracing.TRAIN_STEP_PROGRAM == "train/step"
+    assert tracing.SETUP_SPANS == (
+        "engine/init", "engine/init/shardings", "engine/init/params",
+        "engine/init/optimizer", "train/step", "train/fused_step",
+        "costmodel/analyze", "memory/compiled", "program_map/text",
+        "compile/aot")
+    assert tracing.OBSERVER_SPANS == (
+        "costmodel/analyze", "memory/compiled", "program_map/text",
+        "compile/aot")
+    assert not hasattr(tracing, "STEP_SCOPES")
+    assert not hasattr(tracing, "KERNEL_NAMES")
 
 
 # ------------------------------------------------------- the text's parser

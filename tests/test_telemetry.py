@@ -407,6 +407,9 @@ def test_chaos_session_trace_and_metrics(tmp_path, monkeypatch, served):
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=tiny_gpt2(),
         config=base_config(
+            # the rate gauges are written where the engine waits for the
+            # device: a print boundary inside the five steps
+            steps_per_print=4,
             telemetry={"metrics_port": 0},
             resilience={"faults": "train.step:stall=0@2"}))
     for i in range(5):

@@ -1,6 +1,7 @@
 """Shared test fixtures (reference: tests/unit/simple_model.py — SimpleModel
 and random_dataloader equivalents)."""
 import os
+import re
 
 import numpy as np
 
@@ -47,3 +48,22 @@ def base_config(**overrides):
     }
     cfg.update(overrides)
     return cfg
+
+
+def scope_parts(scopes):
+    """Every name between the slashes and the parentheses of scope paths
+    (the ``scope`` column of ``tracing.get_program_map``, ``op_name``s):
+    ``transpose(jvp(ds.block))/mlp/experts`` -> ds.block, mlp, experts."""
+    return {part for scope in scopes if scope
+            for part in re.split(r"[/()]+", scope) if part}
+
+
+def kernel_names(fn, *args):
+    """The ``ds_*`` names in the jaxpr of ``fn`` and of its gradient by
+    its first argument: the ``name=`` of each ``pl.pallas_call`` they
+    trace to, which is what an instruction's ``op_name`` carries."""
+    import jax
+    import jax.numpy as jnp
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32))))(*args))
+    return set(re.findall(r"\bds_[a-z_]+", text))
