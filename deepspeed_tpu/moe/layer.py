@@ -425,13 +425,21 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     [T·k] routed (token, choice) pairs by expert into a group-padded
     layout, run the expert FFN as grouped GEMMs over it
     (ops/pallas/grouped_gemm.py — zero capacity padding, no [T, E, C]
-    tensors), and combine each token's k outputs by gate weighting.  The
-    rows move by gathers only, through the plan's two index maps: one
-    from the token-major ``xt`` straight into the padded layout
-    (``dispatch_rows``: no [T·k, D] copy), one back out of the experts'
-    output (``combine_rows``), and their hand-written backward passes are
-    gathers too — no scatter is traced here.  Returns (combined [T, D],
-    aux scalar, (dispatched, dropped))."""
+    tensors), and sum each token's k outputs.  The rows move by gathers
+    only, through the plan's two index maps: one from the token-major
+    ``xt`` straight into the padded layout (``dispatch_rows``: no
+    [T·k, D] copy), one back out of the experts' output (``sum_rows``),
+    each the other's backward — no scatter is traced here.  **A row is
+    weighted by its gate where its expert is**: the gates ride the plan's
+    sort into plan order (a float32 a row, 0 on a padding row; their
+    cotangent is sorted home) and the activation between the two products
+    forms ``gate · act(...)`` in float32 and rounds once (:func:`_glu`),
+    so the way back is un-gated and keeps no row: a rematerialised layer's
+    recompute ends at the activation — no output product, no sum — and a
+    gate's cotangent is the row sum of ``dh · act`` in the pass that forms
+    the halves' cotangents.  (The decode-sized branch, which has no plan,
+    weights its rows on the way back.)  Returns (combined [T, D], aux
+    scalar, (dispatched, dropped))."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     T, D = xt.shape
     E, k = config.num_experts, config.top_k
@@ -468,7 +476,8 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
                 (gates.astype(dt)[:, None] * y).reshape(T, k, D), axis=1)
     else:
         with jax.named_scope(SCOPE_DISPATCH):
-            plan = gg.make_group_plan(eids, E)
+            plan = gg.make_group_plan(eids, E,
+                                      gates=gates.astype(jnp.float32))
             x_pad = gg.dispatch_rows(xt, plan, k)       # [Mp, D]
         # both are shapes: the step's own account of what its grouped
         # calls compute (rows past the routed ones are zeros)
@@ -476,10 +485,11 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
                       grouped_padded_rows=plan.padded_rows)
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
-            h = _glu(mm, x_pad, w_gate, w_in, config)
+            h = _glu(mm, x_pad, w_gate, w_in, config,
+                     row_gate=plan.gates[:, None])
             y = mm(h, w_out)                            # [Mp, D]
         with jax.named_scope(SCOPE_COMBINE):
-            combined = gg.combine_rows(y, gates, plan, k)
+            combined = gg.sum_rows(y, plan, k)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
     return combined, aux, (jnp.int32(R), jnp.int32(0))
 
@@ -778,23 +788,26 @@ def _glu(mm, x, w_gate, w_in, config: MoEConfig, plan=None, row_gate=None):
     """The experts' first half over the plan's rows ``x``.  Given a held
     ``plan``, what XLA does between the grouped calls — the activation,
     and in the backward pass the sum of the two cotangents of ``x`` —
-    walks the plan's live prefix as they do; given a ``row_gate`` too
+    walks the plan's live prefix as they do; given a ``row_gate``
     (``[Mp, lanes]`` float32, lane 0 a routed row's gate where its expert
-    is: :func:`_exchanged_grouped_moe`) the same pass weights each row by
-    it, and its backward forms the gates' cotangent beside the halves'."""
+    is: :func:`_grouped_moe`'s full plan, :func:`_exchanged_grouped_moe`)
+    the activation's pass weights each row by it, and its backward forms
+    the gates' cotangent beside the halves'."""
+    glu = config.activation == "silu_glu"
+    fn = _silu_glu if glu else partial(_ungated, config=config)
+    gated = ()
+    if row_gate is not None:
+        fn, gated = _row_weighted(fn), (row_gate,)
     if plan is None:
-        if config.activation == "silu_glu":
-            return jax.nn.silu(mm(x, w_gate)) * mm(x, w_in)
-        return _ungated(mm(x, w_in), config)
+        halves = (mm(x, w_gate), mm(x, w_in)) if glu else (mm(x, w_in),)
+        return fn(*halves, *gated)
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
-    if config.activation == "silu_glu":
+    if glu:
         x_gate, x_in = gg.fan_out_live_rows(x, plan, 2)
-        fn, halves = _silu_glu, (mm(x_gate, w_gate), mm(x_in, w_in))
+        halves = (mm(x_gate, w_gate), mm(x_in, w_in))
     else:
-        fn, halves = partial(_ungated, config=config), (mm(x, w_in),)
-    if row_gate is None:
-        return gg.map_live_rows(fn, plan, *halves)
-    return gg.map_live_rows(_row_weighted(fn), plan, *halves, row_gate)
+        halves = (mm(x, w_in),)
+    return gg.map_live_rows(fn, plan, *halves, *gated)
 
 
 def gg_kernel_real() -> bool:

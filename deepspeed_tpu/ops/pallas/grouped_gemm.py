@@ -20,11 +20,12 @@ computation as ONE ragged GEMM over tokens sorted by expert:
    (about one in ten at 512 rows an expert), which the kernels neither
    fetch nor multiply.  Rows move through the two maps by gathers only —
    :func:`dispatch_rows` from the token-major activations straight into
-   the padded layout, :func:`combine_rows` back out with the
-   gate-weighted sum, each with a hand-written backward that gathers
-   through the other map (autodiff would write a scatter-add, which costs
-   3× a gather of the same rows on a v5e) — and the plan itself holds no
-   scatter either.
+   the padded layout, :func:`sum_rows` back out, a token's rows summed
+   as they are (the layer weights a row by its gate where its expert is,
+   between the two products: the way back knows no gate and keeps no
+   row), each the other's transpose and so the other's backward
+   (autodiff would write a scatter-add, which costs 3× a gather of the
+   same rows on a v5e) — and the plan itself holds no scatter either.
 2. :func:`ds_ggemm` — one Pallas kernel over grid ``(N/bn, m_tiles,
    K/bk)``, N outermost and K innermost: the M-grid walks group
    boundaries via the scalar-prefetched ``block_group_ids`` (the
@@ -35,7 +36,7 @@ computation as ONE ragged GEMM over tokens sorted by expert:
    repeats the block index of the last tile that holds rows (so nothing
    is copied for it) and does no MXU work.  What it leaves behind depends
    on the plan: zeros (a full plan, :func:`make_group_plan`: fewer than
-   one tile in ten trails, and :func:`combine_rows` may gather anything),
+   one tile in ten trails, and :func:`sum_rows` may gather anything),
    or nothing at all (a held plan, ``live_only``: its output block too
    stays on the last live tile, so the rows behind the live prefix — most
    of such a plan — are never written, and hold whatever the buffer held).
@@ -149,7 +150,7 @@ class GroupPlan(NamedTuple):
     ``padded_to_row[p]`` is its inverse: the flat element that sits in
     padded row ``p``, ``R`` (out of range) on a padding row.  Rows move
     through the pair by gathers alone, in both directions and in both
-    backward passes (:func:`dispatch_rows`, :func:`combine_rows`).
+    backward passes (:func:`dispatch_rows`, :func:`sum_rows`).
     """
     block_m: int                   # static M-tile the layout is padded to
     padded_rows: int               # static padded row count (Mp)
@@ -172,10 +173,40 @@ class GroupPlan(NamedTuple):
     #: of a block of tokens are one run in each expert's group, and where
     #: the runs begin is a count over this (:func:`_token_block_runs`)
     group_of_element: Optional[jnp.ndarray] = None    # [R]
+    #: a whole plan's, where it was made with them: the routed elements'
+    #: gates in plan order, 0 on a padding row — they rode the plan's sort
+    #: (:func:`_sorted_with`), and their cotangent is sorted home
+    gates: Optional[jnp.ndarray] = None               # [Mp] float32
+
+
+@jax.custom_vjp
+def _sorted_with(keys, index, riders):
+    """(``index``, ``riders``) in the stable order of ``keys`` (all [n]):
+    the plan's sort with a float32 a row riding it, where a gather of
+    single float32s by the sorted index costs by the element (0.29 ms for
+    40,960 on a v5e against 0.06 for the whole sort).  Backward: the
+    riders' cotangent sorted back by the index — exact where the index is
+    a permutation; entries whose index repeats get one another's."""
+    _, index, riders = jax.lax.sort((keys, index, riders), num_keys=1,
+                                    is_stable=True)
+    return index, riders
+
+
+def _sorted_with_fwd(keys, index, riders):
+    out = _sorted_with(keys, index, riders)
+    return out, out[0]
+
+
+def _sorted_with_bwd(index, g):
+    return None, None, jax.lax.sort((index, g[1]), num_keys=1)[1]
+
+
+_sorted_with.defvjp(_sorted_with_fwd, _sorted_with_bwd)
 
 
 def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
-                    block_m: Optional[int] = None) -> GroupPlan:
+                    block_m: Optional[int] = None,
+                    gates: Optional[jnp.ndarray] = None) -> GroupPlan:
     """``expert_ids`` [R] int32 (R static, e.g. T·top_k) -> GroupPlan.
 
     All outputs have static shapes; values are data-dependent.  No
@@ -187,6 +218,9 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
     within an expert (determinism + the exact addition order the parity
     tests pin down) and puts an expert's padding after its tokens.
     ``row_to_padded`` is the same permutation sorted back by element.
+    ``gates`` [R] (the routed elements', float32; differentiable) ride
+    the sort as a second payload and leave it as the plan's ``gates``: in
+    plan order, zeros on padding rows.
     """
     R = int(expert_ids.shape[0])
     E = int(num_experts)
@@ -206,9 +240,13 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
         (jnp.arange(padded_rows - R, dtype=jnp.int32)[:, None]
          >= pad_end[None, :]).astype(jnp.int32), axis=1)
     flat = jnp.arange(padded_rows, dtype=jnp.int32)
-    _, padded_to_row = jax.lax.sort(
-        (jnp.concatenate([eids, pad_eids]), jnp.minimum(flat, R)),
-        num_keys=1, is_stable=True)
+    keys, element = jnp.concatenate([eids, pad_eids]), jnp.minimum(flat, R)
+    if gates is None:
+        _, padded_to_row = jax.lax.sort((keys, element), num_keys=1,
+                                        is_stable=True)
+    else:
+        padded_to_row, gates = _sorted_with(
+            keys, element, jnp.pad(gates, (0, padded_rows - R)))
     _, padded_of = jax.lax.sort((padded_to_row, flat), num_keys=1,
                                 is_stable=True)
     row_to_padded = padded_of[:R]
@@ -217,7 +255,7 @@ def make_group_plan(expert_ids: jnp.ndarray, num_experts: int,
                            cum_blocks)
     return GroupPlan(bm, padded_rows, num_blocks, E, group_sizes, gids,
                      cum_blocks[-1:].astype(jnp.int32), row_to_padded,
-                     padded_to_row, counts)
+                     padded_to_row, counts, gates=gates)
 
 
 def _tile_group_ids(bidx, cum_blocks):
@@ -256,7 +294,9 @@ def _from_groups(padded, padded_to_row, row_to_padded):
 
 
 def _keeping_maps(move):
-    return lambda x, *maps: (move(x, *maps), maps)
+    """``move``'s forward rule: the plan's two maps (the two arguments
+    behind ``x``) are all it keeps."""
+    return lambda x, *rest: (move(x, *rest), rest[:2])
 
 
 # each is the other's transpose
@@ -291,18 +331,18 @@ def _dispatch(xt, padded_to_row, row_to_padded, top_k):
     return _token_rows(xt, padded_to_row // top_k)
 
 
-def _dispatch_fwd(xt, padded_to_row, row_to_padded, top_k):
-    return _dispatch(xt, padded_to_row, row_to_padded, top_k), row_to_padded
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum(y, padded_to_row, row_to_padded, top_k):
+    # a token's top_k rows summed in float32, rounded once
+    rows = _rows(y, row_to_padded).reshape(-1, top_k, *y.shape[1:])
+    return jnp.sum(rows.astype(jnp.float32), axis=1).astype(y.dtype)
 
 
-def _dispatch_bwd(top_k, row_to_padded, g):
-    # a token's top_k cotangent rows summed in float32, rounded once
-    rows = _rows(g, row_to_padded).reshape(-1, top_k, *g.shape[1:])
-    return (jnp.sum(rows.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None)
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+# each is the other's transpose
+_dispatch.defvjp(_keeping_maps(_dispatch),
+                 lambda top_k, maps, g: (_sum(g, *maps, top_k), None, None))
+_sum.defvjp(_keeping_maps(_sum),
+            lambda top_k, maps, g: (_dispatch(g, *maps, top_k), None, None))
 
 
 def dispatch_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
@@ -310,44 +350,18 @@ def dispatch_rows(xt: jnp.ndarray, plan: GroupPlan, top_k: int):
     (padded row ``p`` reads token ``padded_to_row[p] // top_k``; padding
     rows are exact zeros, which ``ds_ggemm_dw`` relies on).  Equals
     ``scatter_to_groups(repeat(xt, top_k), plan)`` without the [T·k, D]
-    copy; backward: gather by ``row_to_padded``, sum over ``top_k``."""
+    copy; backward: :func:`sum_rows` of the cotangent."""
     return _dispatch(xt, plan.padded_to_row, plan.row_to_padded, top_k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(y, gates, padded_to_row, row_to_padded, top_k):
-    rows = _rows(y, row_to_padded)
-    return jnp.sum((gates.astype(y.dtype)[:, None] * rows).reshape(
-        -1, top_k, y.shape[1]), axis=1)
-
-
-def _combine_fwd(y, gates, padded_to_row, row_to_padded, top_k):
-    return (_combine(y, gates, padded_to_row, row_to_padded, top_k),
-            (y, gates, padded_to_row, row_to_padded))
-
-
-def _combine_bwd(top_k, res, g):
-    y, gates, padded_to_row, row_to_padded = res
-    dy = (_rows_or_zeros(gates.astype(y.dtype), padded_to_row)[:, None]
-          * _token_rows(g, padded_to_row // top_k))
-    rows = _rows(y, row_to_padded).reshape(-1, top_k, y.shape[1])
-    dgates = jnp.sum(rows.astype(jnp.float32)
-                     * g.astype(jnp.float32)[:, None, :], axis=-1)
-    return dy, dgates.reshape(gates.shape).astype(gates.dtype), None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
-
-
-def combine_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
-                 top_k: int):
-    """Group-padded expert outputs ``y`` [Mp, D] and flat ``gates``
-    [T·top_k] -> [T, D]: each token's ``top_k`` rows, gathered by
-    ``row_to_padded``, weighted in ``y``'s dtype and summed.  Backward:
-    ``dy[p] = gates[padded_to_row[p]] · dout[padded_to_row[p] // top_k]``
-    (zeros on padding rows) and ``dgates[f] = y[row_to_padded[f]] ·
-    dout[f // top_k]`` accumulated in float32."""
-    return _combine(y, gates, plan.padded_to_row, plan.row_to_padded, top_k)
+def sum_rows(y: jnp.ndarray, plan: GroupPlan, top_k: int):
+    """The way back of rows that carry their weight already (gated where
+    their experts are: moe/layer.py ``_grouped_moe``): group-padded ``y``
+    [Mp, D] -> [T, D], each token's ``top_k`` rows gathered by
+    ``row_to_padded`` and summed in float32, rounded once.  Backward:
+    :func:`dispatch_rows` of the tokens' cotangents (zeros on padding
+    rows) — no row of ``y`` is a residual."""
+    return _sum(y, plan.padded_to_row, plan.row_to_padded, top_k)
 
 
 # ------------------------------------------------------ a held subset
@@ -983,7 +997,7 @@ _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 def combine_held_rows(y: jnp.ndarray, gates: jnp.ndarray, plan: GroupPlan,
                       top_k: int):
-    """As :func:`combine_rows` for a held-subset plan: the live prefix of
+    """The gated way back of a held-subset plan: the live prefix of
     the expert outputs ``y`` [Mp, D], each row weighted by its routed
     element's gate (``gates`` flat [T*top_k]; a padding row's is 0), summed
     into their tokens -> [T, D], a chunk at a time into one float32

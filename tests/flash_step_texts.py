@@ -15,8 +15,11 @@ environment, from the root of a checkout:
 
 tests/data/held_prefix_step_digests.json (PR 39) holds the same at that
 PR's parent commit (``gpt2`` and ``olmoe`` taken again at PR 49 with
-flash_step_digests.json: the flash kernels' tile bodies changed) with the grouped kernels interpreted as well, for every
-family here and in ``HELD_FAMILIES``: ``f.digest(n, grouped_kernels=True)``.
+flash_step_digests.json: the flash kernels' tile bodies changed; ``olmoe``
+again at PR 59, in both files: the full plan weights a row where its
+expert is and its way back is un-gated) with the grouped kernels
+interpreted as well, for every family here and in ``HELD_FAMILIES``:
+``f.digest(n, grouped_kernels=True)``.
 """
 import functools
 import hashlib

@@ -210,7 +210,10 @@ def test_with_one_width_the_step_lowers_to_the_parents_text(family):
     backward kernels scale no tile, so every family's kernels' text changed
     on purpose and the old digests went with the old bodies — which
     tests/test_flash_tile_bodies.py keeps as its oracle, result for
-    result."""
+    result.  ``olmoe`` was taken again at PR 59, which changed its expert
+    layer on purpose (a row is weighted where its expert is and the way
+    back is ``sum_rows``: tests/test_grouped_gemm.py holds it to the dense
+    per-expert reference); the three others stood."""
     with open(os.path.join(HERE, "data", "flash_step_digests.json")) as f:
         want = json.load(f)
     assert flash_step_texts.digest(family) == want[family]

@@ -17,6 +17,7 @@ The load-bearing contracts:
 - serving: grouped and einsum dispatch produce token-identical greedy
   outputs (eval capacity is drop-free by MixtralConfig default).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -122,10 +123,21 @@ def _scatter_dispatch(xt, r2p, k, padded_rows):
     return jnp.zeros((padded_rows, xt.shape[1]), xt.dtype).at[r2p].set(rows)
 
 
-def _take_combine(y, gates, r2p, k):
-    out_rows = jnp.take(y, r2p, axis=0)
-    return jnp.sum((gates.astype(y.dtype)[:, None] * out_rows).reshape(
-        -1, k, y.shape[1]), axis=1)
+def _take_sum(y, r2p, k):
+    """take -> sum over a token's rows in float32, rounded once."""
+    out_rows = jnp.take(y, r2p, axis=0).astype(jnp.float32)
+    return jnp.sum(out_rows.reshape(-1, k, y.shape[1]), axis=1).astype(
+        y.dtype)
+
+
+def _experts_dense(xt, w1, w2, eids, gates, k):
+    """The dense per-expert reference of a layer of two matrices: every
+    routed element through its own expert's pair, weighted by its gate
+    behind the activation, a token's ``k`` results summed."""
+    rows = jnp.repeat(xt, k, axis=0)
+    h = jnp.einsum("rd,rdf->rf", rows, w1[eids])
+    y = jnp.einsum("rf,rfd->rd", gates[:, None] * jax.nn.gelu(h), w2[eids])
+    return jnp.sum(y.reshape(-1, k, xt.shape[1]), axis=1)
 
 
 ROW_MOVEMENT_CASES = {
@@ -141,10 +153,13 @@ ROW_MOVEMENT_CASES = {
 
 @pytest.mark.parametrize("case", sorted(ROW_MOVEMENT_CASES))
 def test_rows_move_by_gathers_as_the_scatter_formulation_did(case):
-    """dispatch_rows / combine_rows (gathers through the plan's two maps,
-    hand-written backward) against take -> scatter -> ... -> take under
+    """dispatch_rows / sum_rows (gathers through the plan's two maps, each
+    the other's backward) against take -> scatter -> ... -> take under
     plain autodiff: the same layout, the same forward to the bit, the
-    same gradients, and no scatter in the traced gradient."""
+    same gradients, and no scatter in the traced gradient; and, the gates
+    multiplied into the activation between the two products in plan order
+    (they ride the plan's sort), values and gradients — the gates' among
+    them — of the dense per-expert reference."""
     T, k, E, bm, how = ROW_MOVEMENT_CASES[case]
     rng = np.random.default_rng(sorted(ROW_MOVEMENT_CASES).index(case))
     R, D = T * k, 6
@@ -167,35 +182,71 @@ def test_rows_move_by_gathers_as_the_scatter_formulation_did(case):
             gg.dispatch_rows(xt.astype(dt), plan, k),
             _scatter_dispatch(xt.astype(dt), r2p, k, plan.padded_rows))
         np.testing.assert_array_equal(
-            gg.combine_rows(y.astype(dt), gates, plan, k),
-            _take_combine(y.astype(dt), gates, r2p, k))
+            gg.sum_rows(y.astype(dt), plan, k),
+            _take_sum(y.astype(dt), r2p, k))
     np.testing.assert_array_equal(
         gg.scatter_to_groups(jnp.repeat(xt, k, axis=0), plan),
         gg.dispatch_rows(xt, plan, k))
 
-    def new(xt_, y_, gates_):
+    def new(xt_, y_):
         return (jnp.sum(gg.dispatch_rows(xt_, plan, k) * c_pad)
-                + jnp.sum(gg.combine_rows(y_, gates_, plan, k) * c_out))
+                + jnp.sum(gg.sum_rows(y_, plan, k) * c_out))
 
-    def old(xt_, y_, gates_):
+    def old(xt_, y_):
         return (jnp.sum(_scatter_dispatch(xt_, r2p, k, plan.padded_rows)
                         * c_pad)
-                + jnp.sum(_take_combine(y_, gates_, r2p, k) * c_out))
+                + jnp.sum(_take_sum(y_, r2p, k) * c_out))
 
-    got = jax.grad(new, argnums=(0, 1, 2))(xt, y, gates)
-    want = jax.grad(old, argnums=(0, 1, 2))(xt, y, gates)
-    for name, a, b in zip(("d xt", "d y", "d gates"), got, want):
+    got = jax.grad(new, argnums=(0, 1))(xt, y)
+    want = jax.grad(old, argnums=(0, 1))(xt, y)
+    for name, a, b in zip(("d xt", "d y"), got, want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
     # a padding row's cotangent is an exact zero, as the scatter's was
     pad = np.setdiff1d(np.arange(plan.padded_rows), np.asarray(r2p))
     assert not np.asarray(got[1])[pad].any()
 
-    def scatters(fn):
+    # the layer's own order: out by dispatch_rows, the gate in the
+    # activation where the expert is, back by the un-gated sum
+    F = 5
+    w1 = jnp.asarray(rng.standard_normal((E, D, F)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((E, F, D)), jnp.float32)
+
+    def through_the_plan(xt_, w1_, w2_, gates_):
+        gated = gg.make_group_plan(eids, E, block_m=bm, gates=gates_)
+        mm = functools.partial(gg.ds_ggemm, plan=gated)
+        h = mm(gg.dispatch_rows(xt_, gated, k), w1_)
+        return gg.sum_rows(
+            mm(gated.gates[:, None] * jax.nn.gelu(h), w2_), gated, k)
+
+    def dense(xt_, w1_, w2_, gates_):
+        return _experts_dense(xt_, w1_, w2_, eids, gates_, k)
+
+    # the same plan, and the gates where a gather would have put them
+    gated = gg.make_group_plan(eids, E, block_m=bm, gates=gates)
+    for got, want in zip(gated[:-1], plan[:-1]):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(gated.gates,
+                                  gg.scatter_to_groups(gates, plan))
+    assert not np.asarray(gated.gates)[pad].any()
+    np.testing.assert_allclose(through_the_plan(xt, w1, w2, gates),
+                               dense(xt, w1, w2, gates), rtol=2e-5,
+                               atol=2e-5)
+    args = (xt, w1, w2, gates)
+    got = jax.grad(lambda *a: jnp.sum(through_the_plan(*a) * c_out),
+                   argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * c_out),
+                    argnums=(0, 1, 2, 3))(*args)
+    for name, a, b in zip(("d xt", "d w1", "d w2", "d gates"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
+
+    def scatters(fn, *operands):
         from deepspeed_tpu.telemetry.costmodel import primitive_names
-        return [n for n in primitive_names(jax.make_jaxpr(
-            jax.grad(fn, argnums=(0, 1, 2)))(xt, y, gates))
+        return [n for n in primitive_names(jax.make_jaxpr(jax.grad(
+            fn, argnums=tuple(range(len(operands)))))(*operands))
             if "scatter" in n]
-    assert scatters(old) and not scatters(new)
+    assert scatters(old, xt, y) and not scatters(new, xt, y)
+    assert not scatters(
+        lambda *a: jnp.sum(through_the_plan(*a) * c_out), *args)
 
 
 def _accounted(fn, *args):
@@ -462,6 +513,59 @@ def _layer_setup(E=4, k=2, T=(2, 8), D=16, F=32, activation="silu_glu",
     params = init_moe_params(cfg, jax.random.PRNGKey(seed))
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (*T, D))
     return cfg, params, x
+
+
+def _equations(jaxpr):
+    """Every equation of a traced program, sub-jaxprs included."""
+    from deepspeed_tpu.telemetry.costmodel import _sub_jaxprs
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("activation", ["silu_glu", "gelu"])
+def test_a_rematerialised_full_plan_layer_multiplies_no_output_matrix_twice(
+        monkeypatch, activation):
+    """The gradient of two rematerialised full-plan layers, as traced with
+    the real kernels: a row is weighted where its expert is, so the way
+    back keeps no row of ``y`` — the recompute ends at the activation (a
+    layer runs its first-half products twice and ``h @ w_out`` once: five
+    ``ds_ggemm_fwd`` with a gate matrix, three without) and the backward
+    gathers ``[R, D]`` rows out of a padded array once (the dispatch's
+    cotangent), where it also gathered ``y`` for the gates' row-dot."""
+    from collections import Counter
+    from deepspeed_tpu.moe.layer import moe_layer
+    monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
+    layers, k, D = 2, 2, 16
+    cfg, params, x = _layer_setup(k=k, D=D, F=32, activation=activation)
+    R = x.shape[0] * x.shape[1] * k
+
+    def loss(params, x):
+        for _ in range(layers):
+            out, aux = jax.checkpoint(
+                lambda p, h: moe_layer(p, h, cfg, train=True))(params, x)
+            x = x + out
+        return jnp.sum(x) + aux
+
+    with dispatch_scope("grouped"):
+        traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+    kernels = Counter(
+        e.params["name"]
+        for e in _equations(traced) if e.primitive.name == "pallas_call")
+    first_half = 2 if activation == "silu_glu" else 1
+    assert kernels == {"ds_ggemm_fwd": layers * (2 * first_half + 1),
+                       "ds_ggemm_dx": layers * (first_half + 1),
+                       "ds_ggemm_dw": layers * (first_half + 1)}, kernels
+    # rows gathered out of a padded [Mp, D] array into flat routed order:
+    # the forward sum's and the dispatch's cotangent's, and no third
+    row_gathers = [e for e in _equations(traced)
+                   if e.primitive.name == "gather"
+                   and e.outvars[0].aval.shape == (R, D)
+                   and e.invars[0].aval.shape[0] > R]
+    assert len(row_gathers) == 2 * layers, row_gathers
+    assert not [e for e in _equations(traced)
+                if "scatter" in e.primitive.name]
 
 
 # ------------------------------------------------------- serving parity

@@ -466,13 +466,18 @@ def test_a_step_with_no_held_plan_lowers_to_the_parents_text(
         family, grouped_kernels):
     """GPT-2 never reaches the expert layer and OLMoE's full plan
     (``_grouped_moe``: ``make_group_plan``, ``dispatch_rows``,
-    ``combine_rows``, kernels that write their trailing tiles as zeros) is
+    ``sum_rows``, kernels that write their trailing tiles as zeros) is
     not this PR's: the toy step's lowered text — with the grouped kernels'
     bodies in it too — has the sha256 it had at PR 39's parent commit
     (tests/flash_step_texts.py; tests/data/held_prefix_step_digests.json),
     taken again at PR 49, which changed the flash kernels' tile body in it
     on purpose (tests/test_flash_tile_bodies.py holds the new one to the
-    old one's results); the held families' entries stay PR 39's parent's,
+    old one's results), and OLMoE's at PR 59, which moved the gate to the
+    experts' activation in the full plan alone (tests/test_grouped_gemm.py
+    holds it to the dense reference and counts its kernels; the held
+    families' text, kernels interpreted or not, stood at that PR: the same
+    digests at parent and change); the held families' entries stay PR 39's
+    parent's,
     which the test below holds them to having left."""
     from tests import flash_step_texts
     want = _parents_digests()[
