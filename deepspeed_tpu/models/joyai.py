@@ -72,9 +72,9 @@ from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
                                      init_moe_params, moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
-    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_KV_LATENT,
-    SCOPE_MLP, SCOPE_MTP, SCOPE_OUT_PROJ, SCOPE_Q_LATENT, SCOPE_ROPE,
-    SCOPE_SCORES)
+    SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_IN_PROJ,
+    SCOPE_KV_LATENT, SCOPE_MLP, SCOPE_MTP, SCOPE_OUT_PROJ, SCOPE_Q_LATENT,
+    SCOPE_ROPE, SCOPE_SCORES)
 
 
 @dataclass(frozen=True)
@@ -292,7 +292,9 @@ def latent_attention(x, layer, config, segment_ids, rotary=_rotary):
     (the block below adds ``x``; models/xing.py writes it into its
     streams).  ``config`` is any with this file's attention sizes;
     ``rotary(q, k_r, config)`` turns both (a family with scaled
-    frequencies brings its own)."""
+    frequencies brings its own; None: nothing turns, the shared key part
+    is kept as it is — models/kimi_linear.py).  A layer without ``w_dq``
+    has no query latent: ``q = h W_q`` (``w_q``), one matrix."""
     B, S, _ = x.shape
     H, rkv = config.num_heads, config.kv_lora_rank
     nope, rot, vd = (config.qk_nope_head_dim, config.qk_rope_head_dim,
@@ -300,14 +302,21 @@ def latent_attention(x, layer, config, segment_ids, rotary=_rotary):
     eps = config.norm_eps
     with jax.named_scope(SCOPE_ATTN):
         h = _rms_norm(x, layer["attn_norm"], eps)
-        with jax.named_scope(SCOPE_Q_LATENT):
-            c_q = _rms_norm(qdot(h, layer["w_dq"]), layer["q_norm"], eps)
-            q = qdot(c_q, layer["w_uq"]).reshape(B, S, H, nope + rot)
+        if "w_dq" in layer:
+            with jax.named_scope(SCOPE_Q_LATENT):
+                c_q = _rms_norm(qdot(h, layer["w_dq"]), layer["q_norm"], eps)
+                q = qdot(c_q, layer["w_uq"])
+        else:
+            with jax.named_scope(SCOPE_IN_PROJ):
+                q = qdot(h, layer["w_q"])
+        q = q.reshape(B, S, H, nope + rot)
         with jax.named_scope(SCOPE_KV_LATENT):
             ckv = qdot(h, layer["w_dkv"])
             c_kv = _rms_norm(ckv[..., :rkv], layer["kv_norm"], eps)
-        with jax.named_scope(SCOPE_ROPE):
-            q, k_r = rotary(q, jnp.expand_dims(ckv[..., rkv:], 2), config)
+        k_r = jnp.expand_dims(ckv[..., rkv:], 2)
+        if rotary is not None:
+            with jax.named_scope(SCOPE_ROPE):
+                q, k_r = rotary(q, k_r, config)
         with jax.named_scope(SCOPE_KV_LATENT):
             w_k, w_v = _key_value_weights(layer["w_ukv"], config,
                                           c_kv.dtype)

@@ -21,8 +21,26 @@ with ``(I + L)^-1`` taken once, in float32.  Across chunks the state is
 carried: ``v_new = U - W S``, ``o = (q exp(G)) S + (q k^T . decay) v_new``
 and ``S' = exp(G_C) S + (k exp(G_C - G))^T v_new``.
 
-One algorithm, two lowerings (:func:`_kernel_blocking` chooses, by the
-rule of ``ops/pallas/vmem.lowering``).  On one TPU, for heads that are
+**A decay a key channel** (Kimi Team 2025, "Kimi Linear", arXiv:2510.26692,
+KDA: ``g_t`` a vector of ``dk`` entries, ``S <- Diag(exp(g_t)) S_{t-1}``).
+``g`` of rank four asks for it; the shape decides, nothing else does.  The
+same transform holds with the decay inside the contraction, ``A[i, j] =
+sum_c a_ic k_jc exp(G_ic - G_jc)`` for ``a`` = ``k`` (``L = beta A``,
+strictly lower) and ``a`` = ``q``; the factors on the rows carry over
+(``W = T (beta k . exp(G))``, ``(q . exp(G)) S``, ``S' = Diag(exp(G_C)) S +
+(k . exp(G_C - G))^T v_new``: every exponent <= 0).  ``A`` does not
+factor into one product without ``exp(-G_j)``, which overflows, so it is
+made at a second level (Yang et al. 2023, GLA, arXiv:2312.06635 section
+4): sub-blocks of ``SUB_BLOCK`` positions; for a pair ``I > J`` both
+sides are taken against ``G`` at ``I``'s first row — ``(a_i . exp(G_i -
+G_ref)) . (k_j . exp(G_ref - G_j))``, both exponents <= 0, one product —
+and the diagonal sub-blocks element by element, ``[SUB_BLOCK, SUB_BLOCK,
+dk]``, a chunk at a time so that the array is never whole
+(:func:`_chunked_xla_channel`).
+
+One algorithm, two lowerings for the decay a head (:func:`_kernel_blocking`
+chooses, by the rule of ``ops/pallas/vmem.lowering``); the decay a channel
+has the XLA form alone.  On one TPU, for heads that are
 multiples of 128 wide, the Mosaic kernels of
 ops/pallas/gated_delta_rule.py: the state stays in VMEM across a
 sequence's chunks, the inverse is taken inside the kernel and the
@@ -66,6 +84,7 @@ from jax import lax
 from deepspeed_tpu.telemetry.tracing import count_in_step
 
 DEFAULT_CHUNK = 64
+SUB_BLOCK = 8           # positions of a chunk's second level (decay a channel)
 _HIGHEST = lax.Precision.HIGHEST      # the oracle's products
 
 
@@ -175,8 +194,10 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     itself, which the kernels do on the tiles they hold), ``v`` [B, S,
     Hv, dv] with ``Hv`` a multiple of
     ``Hk`` (key head ``h`` serves value heads ``h*Hv/Hk ..``), ``g`` (log
-    decay, <= 0) and ``beta`` (write strength) [B, S, Hv], ``segment_ids``
-    [B, S] int or None.  Returns ``o`` [B, S, Hv, dv] in ``v``'s dtype.
+    decay, <= 0) [B, S, Hv] — one a head — or [B, S, Hv, dk] — one a key
+    channel: the XLA form alone — and ``beta`` (write strength) [B, S, Hv],
+    ``segment_ids`` [B, S] int or None.  Returns ``o`` [B, S, Hv, dv] in
+    ``v``'s dtype.
     Matrix products take their operands in ``v``'s dtype (the model's:
     bfloat16 in a bf16 step, float32 in a float32 one) and accumulate in
     float32; the decays, the inverse of ``I + L`` and the carried state are
@@ -190,8 +211,12 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     n = -(-S // C)
     pad = n * C - S
     f32 = lambda a: a.astype(jnp.float32)
-    blocking, interpret = _kernel_blocking(interpret, n, C, Hv // Hk, dk, dv,
-                                           dt)
+    by_channel = g.ndim == 4
+    if by_channel and interpret:
+        raise ValueError("gated_delta_rule: a decay a key channel has no "
+                         "kernels to interpret")
+    blocking, interpret = (None, False) if by_channel else _kernel_blocking(
+        interpret, n, C, Hv // Hk, dk, dv, dt)
     if l2norm_scales is not None and blocking is None:
         q, k = (l2norm(t) * s for t, s in zip((q, k), l2norm_scales))
     if l2norm_scales is None or blocking is None:
@@ -207,9 +232,11 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
         q, k, v, g, beta = (tail(t) for t in (q, k, v, g, beta))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
     row = {"chunks": n, "chunk_len": C, "batch": B, "heads": Hv,
-           "dk": dk, "dv": dv,
+           "dk": dk, "dv": dv, "decay": "channel" if by_channel else "head",
            "path": "xla" if blocking is None else "kernel"}
-    if blocking is not None:
+    if by_channel:
+        o = _chunked_xla_channel(q, k, v, g, beta, seg, n, C)
+    elif blocking is not None:
         from deepspeed_tpu.ops.pallas.gated_delta_rule import \
             gated_delta_rule_kernels
         row.update(heads_per_step=blocking.heads,
@@ -289,10 +316,143 @@ def _chunked_xla(q, k, v, g, beta, seg, n, C):
     return jnp.moveaxis(o, 4, 2).reshape(B, n * C, Hv, dv)
 
 
+def _fit(n, want):
+    """The largest divisor of ``n`` that is at most ``want``."""
+    return max(d for d in range(1, min(n, want) + 1) if n % d == 0)
+
+
+def _unit_lower_inverse(L):
+    """``(I + L)^-1`` for ``L`` [..., C, C] strictly lower triangular, in
+    float32, by halves: with the inverses ``A'``, ``D'`` of two
+    neighbouring diagonal blocks, ``[[A, 0], [B, D]]^-1 = [[A', 0], [-D' B
+    A', D']]`` — ``log2 C`` levels of two batched products each, from the
+    1 x 1 blocks up, as accurate as forward substitution.  (The triangular
+    solve's lowering walks the diagonal blocks one after another, 22 ms a
+    layer-call of 8,192 chunk-heads on a v5e; the finite series ``sum_k
+    (-L)^k`` is fast and loses every digit to cancellation once
+    neighbouring keys are alike: PERF.md section 6, PR 60.)"""
+    C = L.shape[-1]
+    P = 1 << (C - 1).bit_length()
+    lead = L.shape[:-2]
+    if P != C:      # beside an identity: the inverse's corner is the same
+        L = jnp.pad(L, [(0, 0)] * len(lead) + [(0, P - C)] * 2)
+    dot = lambda a, b: jnp.einsum("...ij,...jk->...ik", a, b,
+                                  precision=_HIGHEST)
+    inverse = jnp.ones(lead + (P, 1, 1), jnp.float32)    # the 1 x 1 blocks'
+    s = 1
+    while s < P:
+        n = P // (2 * s)
+        # of each pair of blocks, the one under the diagonal: rows of the
+        # pair's second half, columns of its first
+        below = L.reshape(lead + (n, 2, s, n, 2, s))[..., :, 1, :, :, 0, :]
+        below = jnp.sum(jnp.where(jnp.eye(n, dtype=bool)[:, None, :, None],
+                                  below, 0.0), axis=-2)  # [..., n, s, s]
+        first, second = inverse[..., 0::2, :, :], inverse[..., 1::2, :, :]
+        inverse = jnp.concatenate([
+            jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
+            jnp.concatenate([-dot(dot(second, below), first), second],
+                            axis=-1)], axis=-2)          # [..., n, 2s, 2s]
+        s *= 2
+    return inverse[..., 0, :C, :C]
+
+
+def _chunked_xla_channel(q, k, v, g, beta, seg, n, C):
+    """:func:`_chunked_xla` for ``g`` [B, n * C, Hv, dk], a decay a key
+    channel (the module docstring has the equations).  One ``lax.scan``
+    over chunks carries the state, its body checkpointed: what needs no
+    state — ``A``, the inverse, ``W``, ``U`` and the decayed rows — is
+    made inside it, for the one chunk, so that the element-wise diagonals
+    and everything else of a chunk's size live for one step (on a v5e the
+    rule's value and gradient at 16,384 tokens x 32 heads read the faster
+    the fewer chunks' local work a step batched: 198, 148 and 95 ms at 32,
+    8 and 1 under one inverse — PERF.md section 6, PR 60)."""
+    B, _, Hk, dk = q.shape
+    Hv, dv = v.shape[2], v.shape[3]
+    dt = v.dtype
+    f32 = lambda a: a.astype(jnp.float32)
+    rep = Hv // Hk
+    sb = _fit(C, SUB_BLOCK)
+    nb = C // sb
+    dot = lambda spec, a, b: jnp.einsum(
+        spec, a, b, preferred_element_type=jnp.float32)
+
+    # g = key head, r = the value heads it serves, i/j = positions, c = a
+    # key channel, I/J = sub-blocks; every array leads with its chunk
+    qc, kc = (_chunked(t, n, C, Hk) for t in (q, k))         # [n,B,g,1,C,dk]
+    vc = _chunked(v, n, C, Hk)                               # [n,B,g,r,C,dv]
+    gc = _chunked(g, n, C, Hk)                               # [n,B,g,r,C,dk]
+    bc = _chunked(beta, n, C, Hk)                            # [n,B,g,r,C]
+    sc = seg.reshape(B, n, C).transpose(1, 0, 2)             # [n, B, C]
+    # the document the previous chunk ended in (chunk 0: no state yet)
+    prev = jnp.concatenate([sc[:1, :, 0], sc[:-1, :, -1]], axis=0)  # [n, B]
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    earlier = jnp.arange(C)[None, :] < (jnp.arange(nb) * sb)[:, None]  # I, j
+    within = jnp.tril(jnp.ones((sb, sb), bool))              # i >= j
+    on_diagonal = jnp.eye(nb, dtype=bool)[:, None, :, None]  # I, i, J, j
+    heads = lambda a: a[:, None, None]                       # over g and r
+    blocks = lambda a: a.reshape(a.shape[:-2] + (nb, sb, a.shape[-1]))
+
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        q_c, k_c, v_c, g_c, b_c, s_c, prev_c = xs
+        G = jnp.cumsum(g_c, axis=-2)                         # [B,g,r,C,dk]
+        Gs = blocks(G)                                       # ...,I,i,c
+        ref = Gs[..., :1, :]                                 # at I's first row
+        # rows against their sub-block's first, keys against every later
+        # sub-block's first: both exponents <= 0, zero where not earlier
+        k_ref = (f32(k_c)[..., None, :, :] * jnp.exp(jnp.where(
+            earlier[..., None], ref - G[..., None, :, :], -jnp.inf))
+        ).astype(dt)                                         # ...,I,j,c
+        rows = jnp.exp(Gs - ref)
+        # the diagonal sub-blocks, element by element: k_j exp(G_i - G_j)
+        k_in = f32(blocks(jnp.broadcast_to(k_c, G.shape)))[..., None, :, :] \
+            * jnp.exp(jnp.where(within[..., None],
+                                Gs[..., :, None, :] - Gs[..., None, :, :],
+                                -jnp.inf))                   # ...,I,i,j,c
+        # sum_c a_ic k_jc exp(G_ic - G_jc) for a = k and a = q at once (one
+        # reduction reads the diagonal's exponentials): [2, .., C, C]
+        a_s = blocks(jnp.stack([jnp.broadcast_to(f32(a), G.shape)
+                                for a in (k_c, q_c)]))
+        off = dot("abgrIic,bgrIjc->abgrIij", (a_s * rows).astype(dt), k_ref)
+        diag = jnp.sum(a_s[..., None, :] * k_in, axis=-1)    # ...,I,i,j
+        A = jnp.where(on_diagonal, diag[..., None, :],
+                      off.reshape(off.shape[:-1] + (nb, sb)))
+        A = A.reshape(A.shape[:-4] + (C, C))
+        same = heads(s_c[:, :, None] == s_c[:, None, :])     # [B,1,1,C,C]
+        kk = jnp.where(same & strict, A[0], 0.0)
+        attn = jnp.where(same & lower, A[1], 0.0).astype(dt)
+        # T = (I + L)^-1 once, in float32; W and U are products with it
+        T = _unit_lower_inverse(b_c[..., None] * kk).astype(dt)
+        from_state = jnp.where(heads(s_c == prev_c[:, None])[..., None],
+                               jnp.exp(G), 0.0)              # [B,g,r,C,dk]
+        to_end = jnp.exp(jnp.where(heads(s_c == s_c[:, -1:])[..., None],
+                                   G[..., -1:, :] - G, -jnp.inf))
+        W = dot("bgrij,bgrjc->bgric", T,
+                (b_c[..., None] * from_state * f32(k_c)).astype(dt))
+        U = dot("bgrij,bgrjd->bgrid", T,
+                (b_c[..., None] * f32(v_c)).astype(dt))
+        # ... and what does: the new values, the output, the state's step
+        held = state.astype(dt)                              # [B,g,r,dk,dv]
+        v_new = (U - dot("bgrck,bgrkv->bgrcv", W.astype(dt), held)
+                 ).astype(dt)
+        o = dot("bgrck,bgrkv->bgrcv", (from_state * f32(q_c)).astype(dt),
+                held) + dot("bgrij,bgrjv->bgriv", attn, v_new)
+        state = state * from_state[..., -1, :, None] + dot(
+            "bgrck,bgrcv->bgrkv", (to_end * f32(k_c)).astype(dt), v_new)
+        return state, o.astype(dt)
+
+    state0 = jnp.zeros((B, Hk, rep, dk, dv), jnp.float32)
+    _, o = lax.scan(one_chunk, state0, (qc, kc, vc, gc, bc, sc, prev))
+    o = jnp.moveaxis(o, 0, 1)                                # [B,n,g,r,C,dv]
+    return jnp.moveaxis(o, 4, 2).reshape(B, n * C, Hv, dv)
+
+
 def gated_delta_rule_recurrent(q, k, v, g, beta, segment_ids=None):
     """The same by the literal per-token recurrence (a ``lax.scan`` over
     tokens): the oracle the chunked form is tested against, and what a
-    decode step would run.  Same arguments and result."""
+    decode step would run.  Same arguments (``g`` of either rank) and
+    result."""
     B, S, Hk, dk = q.shape
     Hv, dv = v.shape[2], v.shape[3]
     rep = Hv // Hk
@@ -305,8 +465,10 @@ def gated_delta_rule_recurrent(q, k, v, g, beta, segment_ids=None):
 
     def token(state, xs):
         q_t, k_t, v_t, g_t, b_t, first_t = xs
-        keep = jnp.where(first_t[:, None], 0.0, jnp.exp(g_t))    # [B, Hv]
-        state = state * keep[..., None, None]
+        if g_t.ndim == 2:       # [B, Hv]: one decay for a head's channels
+            g_t = g_t[..., None]
+        keep = jnp.where(first_t[:, None, None], 0.0, jnp.exp(g_t))
+        state = state * keep[..., None]                 # [B, Hv, dk | 1, 1]
         read = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=_HIGHEST)
         state = state + k_t[..., :, None] \
             * (b_t[..., None] * (v_t - read))[..., None, :]

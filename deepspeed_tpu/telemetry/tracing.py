@@ -350,6 +350,9 @@ SCOPE_CONV = "conv"                 # ... the short causal convolution + silu
 SCOPE_DELTA_RULE = "delta_rule"     # ... l2-norm, the gated delta rule
 SCOPE_GATE_NORM = "gate_norm"       # ... per-head RMSNorm, silu(z) gate
 SCOPE_OUT_PROJ = "out_proj"         # ... output projection + residual
+# ... and, where the decay (a vector a head) and the output's gate each
+# come through a low-rank pair of matrices (models/kimi_linear.py): both
+SCOPE_LOW_RANK_GATE = "low_rank_gate"
 # inside ``ds.block``, where the mixer is a state-space layer
 # (models/nemotron_h.py), over ``in_proj`` / ``conv`` / ``gate_norm`` /
 # ``out_proj`` as above:

@@ -20,6 +20,13 @@ again at PR 59, in both files: the full plan weights a row where its
 expert is and its way back is un-gated) with the grouped kernels
 interpreted as well, for every family here and in ``HELD_FAMILIES``:
 ``f.digest(n, grouped_kernels=True)``.
+
+tests/data/kda_neighbour_step_digests.json (PR 60) holds, at that PR's
+parent commit, the two families whose ``latent_attention`` learnt to do
+without a query latent and without rotary (``NEIGHBOUR_FAMILIES``:
+``f.digest(n)``) and the gated delta rule's own call with a decay a head,
+as its XLA form and as its kernels interpreted (``f.delta_rule_digest``),
+from before the rule took a decay a key channel.
 """
 import functools
 import hashlib
@@ -79,6 +86,18 @@ def _joyai():
         remat=True)
 
 
+def _xing():
+    from deepspeed_tpu.models.xing import xing_model
+    return xing_model(
+        "4.0-29b-a4b", num_layers=3, num_dense_layers=1, d_model=64,
+        num_heads=4, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_factor=8.0,
+        original_max_position_embeddings=16, d_ff_dense=96, d_ff=32,
+        shared_expert_d_ff=32, num_experts=16, top_k=4, experts_held=4,
+        expert_offset=8, vocab_size=512, max_seq_len=128, dtype="float32",
+        remat=True)
+
+
 FAMILIES = {"gpt2": _gpt2, "olmoe": _olmoe, "qwen3_next": _qwen3_next,
             "nemotron_h": _nemotron_h}
 #: the families whose expert layers hold a subset of the experts — their
@@ -87,6 +106,30 @@ FAMILIES = {"gpt2": _gpt2, "olmoe": _olmoe, "qwen3_next": _qwen3_next,
 #: others to the parent's text and these to having left it
 HELD_FAMILIES = {"qwen3_next": _qwen3_next, "nemotron_h": _nemotron_h,
                  "joyai": _joyai}
+#: the families that run ``joyai.latent_attention`` (with a query latent
+#: and rotary: the form they had before models/kimi_linear.py's)
+NEIGHBOUR_FAMILIES = {"joyai": _joyai, "xing": _xing}
+
+
+def delta_rule_digest(interpret: bool) -> str:
+    """sha256 of the lowered text of the gated delta rule's value and
+    gradient with a decay a head ([2, 128] packed, two key heads of 128
+    serving four value heads): ``interpret`` False its XLA form, True its
+    Mosaic kernels in Pallas' interpreter."""
+    from deepspeed_tpu.ops.linear_attention import gated_delta_rule
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    B, S, Hk, Hv, d = 2, 128, 2, 4, 128
+
+    def loss(q, k, v, g, beta, seg):
+        return jnp.sum(gated_delta_rule(
+            q, k, v, g, beta, seg, chunk=64, interpret=interpret,
+            l2norm_scales=(d ** -0.5, 1.0)))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        shape(B, S, Hk, d), shape(B, S, Hk, d), shape(B, S, Hv, d),
+        shape(B, S, Hv), shape(B, S, Hv),
+        jax.ShapeDtypeStruct((B, S), jnp.int32)).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def digest(family: str, grouped_kernels: bool = False) -> str:
@@ -108,7 +151,8 @@ def digest(family: str, grouped_kernels: bool = False) -> str:
         if grouped_kernels:
             os.environ["DS_GGEMM_INTERPRET"] = "1"
         try:
-            model = {**FAMILIES, **HELD_FAMILIES}[family]()
+            model = {**FAMILIES, **HELD_FAMILIES,
+                     **NEIGHBOUR_FAMILIES}[family]()
             shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
             batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
                      "segment_ids": jnp.zeros((2, 64), jnp.int32)}

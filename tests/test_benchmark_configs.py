@@ -16,7 +16,8 @@ FILES = sorted(os.path.basename(p)
 
 
 def test_the_benchmark_has_its_configurations():
-    assert len(FILES) >= 10
+    assert len(FILES) >= 11
+    assert "kimi-linear-48b-a3b.json" in FILES
     with open(os.path.join(os.path.dirname(CONFIGS), "..",
                            "BENCHMARK.json")) as f:
         named = {os.path.basename(c["file"])

@@ -93,13 +93,14 @@ def test_the_step_account_holds_the_chunks():
         gated_delta_rule(*_inputs(), chunk=16)
     assert tracing.delta_rule_chunks("test/delta") == [
         {"chunks": 4, "chunk_len": 16, "batch": B, "heads": HV,
-         "dk": DK, "dv": DV, "path": "xla"}]
+         "dk": DK, "dv": DV, "decay": "head", "path": "xla"}]
     assert tracing.delta_rule_chunks("test/none") is None
     with tracing.step_account("test/delta"):
         gated_delta_rule(*_wide_inputs(256), interpret=True)
     assert tracing.delta_rule_chunks("test/delta") == [
         {"chunks": 4, "chunk_len": 64, "batch": B, "heads": HV,
-         "dk": 128, "dv": 128, "path": "kernel", "heads_per_step": 2,
+         "dk": 128, "dv": 128, "decay": "head", "path": "kernel",
+         "heads_per_step": 2,
          "chunks_per_step": 4}]
 
 

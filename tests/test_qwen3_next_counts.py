@@ -101,4 +101,4 @@ def test_scopes_and_counts_of_a_toy_step():
     assert (rows["experts_held"], rows["experts_routed"]) == (4, 16)
     assert tracing.delta_rule_chunks("train/step") == [
         {"chunks": -(-S // 16), "chunk_len": 16, "batch": B, "heads": 4,
-         "dk": 16, "dv": 16, "path": "xla"}]
+         "dk": 16, "dv": 16, "decay": "head", "path": "xla"}]
