@@ -38,17 +38,21 @@ and the diagonal sub-blocks element by element, ``[SUB_BLOCK, SUB_BLOCK,
 dk]``, a chunk at a time so that the array is never whole
 (:func:`_chunked_xla_channel`).
 
-One algorithm, two lowerings for the decay a head (:func:`_kernel_blocking`
-chooses, by the rule of ``ops/pallas/vmem.lowering``); the decay a channel
-has the XLA form alone.  On one TPU, for heads that are
-multiples of 128 wide, the Mosaic kernels of
-ops/pallas/gated_delta_rule.py: the state stays in VMEM across a
-sequence's chunks, the inverse is taken inside the kernel and the
-backward is written by hand.  Elsewhere :func:`_chunked_xla`: the
-inverse from one unit-lower-triangular solve, a ``lax.scan`` whose body is
-checkpointed, so that the backward pass (autodiff through the scan) keeps
-one state per chunk and recomputes the rest — the fallback and, beside
-:func:`gated_delta_rule_recurrent`, the kernels' oracle.
+One algorithm, two lowerings for either decay (:func:`_kernel_blocking`
+chooses, by the rule of ``ops/pallas/vmem.lowering``: the shape of ``g``
+and what the call can observe, nothing else).  On one TPU, for heads that
+are multiples of 128 wide, the Mosaic kernels of
+ops/pallas/gated_delta_rule.py (a decay a head: ``ds_gdr_*``) and of
+ops/pallas/kda.py (a decay a channel, one value head a key head:
+``ds_kda_*`` — the levels above the sub-blocks by halves, the sub-blocks'
+diagonals in registers): the state stays in VMEM across a sequence's
+chunks, the inverse is taken inside the kernel and the backward is written
+by hand.  Elsewhere :func:`_chunked_xla` and :func:`_chunked_xla_channel`:
+the inverse from one unit-lower-triangular solve (a channel: by halves), a
+``lax.scan`` whose body is checkpointed, so that the backward pass
+(autodiff through the scan) keeps one state per chunk and recomputes the
+rest — the fallbacks and, beside :func:`gated_delta_rule_recurrent`, the
+kernels' oracles.
 
 **Packed documents.**  With ``segment_ids`` a token sees only its own
 document: at a document's first token the state is zero, exactly as if
@@ -172,15 +176,17 @@ def _chunked(x, n, C, Hk):
     return jnp.moveaxis(jnp.moveaxis(x, 2, 4), 1, 0)
 
 
-def _kernel_blocking(interpret, n, C, rep, dk, dv, dt):
-    """(the grid blocking of ops/pallas/gated_delta_rule.py's kernels, or
-    None for the XLA chunked form below; interpret), by
-    ``vmem.lowering``'s rule."""
-    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr, vmem
+def _kernel_blocking(interpret, n, C, rep, dk, dv, dt, by_channel=False):
+    """(the grid blocking of the rule's kernels — ops/pallas/kda.py's for a
+    decay a key channel, else ops/pallas/gated_delta_rule.py's — or None
+    for the XLA chunked forms below; interpret), by ``vmem.lowering``'s
+    rule."""
+    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr, kda, vmem
+    kernels = kda if by_channel else gdr
     return vmem.lowering(
-        interpret, gdr.supported(dk, dv, C, rep),
-        lambda: gdr.chunks_per_step(n, C, rep, dk, dv,
-                                    jnp.dtype(dt).itemsize))
+        interpret, kernels.supported(dk, dv, C, rep),
+        lambda: kernels.chunks_per_step(n, C, rep, dk, dv,
+                                        jnp.dtype(dt).itemsize))
 
 
 def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
@@ -195,7 +201,7 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     Hv, dv] with ``Hv`` a multiple of
     ``Hk`` (key head ``h`` serves value heads ``h*Hv/Hk ..``), ``g`` (log
     decay, <= 0) [B, S, Hv] — one a head — or [B, S, Hv, dk] — one a key
-    channel: the XLA form alone — and ``beta`` (write strength) [B, S, Hv],
+    channel — and ``beta`` (write strength) [B, S, Hv],
     ``segment_ids`` [B, S] int or None.  Returns ``o`` [B, S, Hv, dv] in
     ``v``'s dtype.
     Matrix products take their operands in ``v``'s dtype (the model's:
@@ -203,7 +209,8 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     float32; the decays, the inverse of ``I + L`` and the carried state are
     float32.  Differentiable in all five.  ``interpret``: None chooses
     the lowering (:func:`_kernel_blocking`), True runs the kernels in
-    interpret mode, False the XLA form."""
+    interpret mode (the XLA form for a shape they refuse), False the XLA
+    form."""
     B, S, Hk, dk = q.shape
     Hv, dv = v.shape[2], v.shape[3]
     dt = v.dtype
@@ -212,11 +219,8 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     pad = n * C - S
     f32 = lambda a: a.astype(jnp.float32)
     by_channel = g.ndim == 4
-    if by_channel and interpret:
-        raise ValueError("gated_delta_rule: a decay a key channel has no "
-                         "kernels to interpret")
-    blocking, interpret = (None, False) if by_channel else _kernel_blocking(
-        interpret, n, C, Hv // Hk, dk, dv, dt)
+    blocking, interpret = _kernel_blocking(interpret, n, C, Hv // Hk, dk, dv,
+                                           dt, by_channel)
     if l2norm_scales is not None and blocking is None:
         q, k = (l2norm(t) * s for t, s in zip((q, k), l2norm_scales))
     if l2norm_scales is None or blocking is None:
@@ -234,17 +238,18 @@ def gated_delta_rule(q, k, v, g, beta, segment_ids=None,
     row = {"chunks": n, "chunk_len": C, "batch": B, "heads": Hv,
            "dk": dk, "dv": dv, "decay": "channel" if by_channel else "head",
            "path": "xla" if blocking is None else "kernel"}
-    if by_channel:
-        o = _chunked_xla_channel(q, k, v, g, beta, seg, n, C)
-    elif blocking is not None:
+    if blocking is not None:
         from deepspeed_tpu.ops.pallas.gated_delta_rule import \
             gated_delta_rule_kernels
+        from deepspeed_tpu.ops.pallas.kda import kda_kernels
         row.update(heads_per_step=blocking.heads,
                    chunks_per_step=blocking.chunks)
-        o = gated_delta_rule_kernels(q, k, v, g, beta, seg, blocking,
-                                     l2norm_scales, interpret)
+        kernels = kda_kernels if by_channel else gated_delta_rule_kernels
+        o = kernels(q, k, v, g, beta, seg, blocking, l2norm_scales,
+                    interpret)
     else:
-        o = _chunked_xla(q, k, v, g, beta, seg, n, C)
+        xla = _chunked_xla_channel if by_channel else _chunked_xla
+        o = xla(q, k, v, g, beta, seg, n, C)
     count_in_step(delta_rule_calls={f"{B}x{n * C}x{Hv}x{dk}x{dv}": row})
     return o[:, :S]
 

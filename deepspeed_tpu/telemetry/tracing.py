@@ -792,9 +792,10 @@ def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
 def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):
     """The gated-delta-rule calls of the step as ops/linear_attention.py
     traced them: one row per shape — ``chunks`` and ``chunk_len`` of the
-    scan, ``batch``, ``heads``, ``dk``, ``dv`` and ``path``: ``"kernel"``
-    where the call ran as the Mosaic kernels ``ds_gdr_fwd`` /
-    ``ds_gdr_bwd`` (then also ``heads_per_step`` and ``chunks_per_step``,
+    scan, ``batch``, ``heads``, ``dk``, ``dv``, ``decay`` (``"head"`` or
+    ``"channel"``) and ``path``: ``"kernel"`` where the call ran as the
+    Mosaic kernels ``ds_gdr_fwd`` / ``ds_gdr_bwd`` (a decay a channel:
+    ``ds_kda_fwd`` / ``ds_kda_bwd``; then also ``heads_per_step`` and ``chunks_per_step``,
     the value heads and chunks one grid step takes), ``"xla"`` where it
     fell back to the XLA chunked form.  None where the step has no such
     call."""

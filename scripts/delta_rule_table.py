@@ -4,7 +4,8 @@ unchanged), then the self time of every instruction of the compiled step
 under ``/linear_attn/delta_rule/``, joined through
 ``get_program_map("train/step")`` and sorted into the parts of
 ``ops/linear_attention.py`` — by kernel name where the instruction is one
-of ``ops/pallas/gated_delta_rule.py``'s calls, else by what its op_name
+of ``ops/pallas/gated_delta_rule.py``'s or ``ops/pallas/kda.py``'s calls,
+else by what its op_name
 holds.
 
     chiprun --chips 1 -- python scripts/delta_rule_table.py --seed <n> \
@@ -30,7 +31,7 @@ SCOPE = re.compile(r"/linear_attn/delta_rule/")
 #: the backward of a part runs sits under ``transpose(`` and is told
 #: apart by the row's phase
 PARTS = (
-    ("kernel", re.compile(r"ds_gdr_\w+")),
+    ("kernel", re.compile(r"ds_(?:gdr|kda)_\w+")),
     ("scan body", re.compile(r"while|scan|checkpoint")),
     ("solve", re.compile(r"triangular_solve")),
     ("kk / qk", re.compile(r"nbgid,nbgjd->nbgij")),
@@ -40,8 +41,9 @@ PARTS = (
         r"|broadcast_in_dim)$")),
     ("l2-norms and layout", re.compile(r".")),
 )
+# (a ``while`` returns a tuple, whose type has spaces in it)
 _LEFT = re.compile(r'(InvertDiagBlocksLowerTriangular|triangular-solve'
-                   r'|= \S+ while\().*op_name="[^"]*/linear_attn/'
+                   r'|[\])] while\().*op_name="[^"]*/linear_attn/'
                    r'delta_rule/')
 
 

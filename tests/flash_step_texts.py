@@ -116,6 +116,7 @@ def delta_rule_digest(interpret: bool) -> str:
     gradient with a decay a head ([2, 128] packed, two key heads of 128
     serving four value heads): ``interpret`` False its XLA form, True its
     Mosaic kernels in Pallas' interpreter."""
+    jax.clear_caches()      # as digest() below: from no earlier trace
     from deepspeed_tpu.ops.linear_attention import gated_delta_rule
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     B, S, Hk, Hv, d = 2, 128, 2, 4, 128
@@ -143,6 +144,9 @@ def digest(family: str, grouped_kernels: bool = False) -> str:
     from deepspeed_tpu.moe import layer as moe_layer
     real = pl.pallas_call
     pl.pallas_call = functools.partial(real, interpret=True)
+    # what the process traced before decides which inner jitted functions
+    # of the step share one traced body, and so the text: start from none
+    jax.clear_caches()
     # a metrics tap an earlier test of the process left installed would
     # put its host callbacks into the text
     tap, moe_layer._metrics_registry = moe_layer._metrics_registry, None

@@ -3,7 +3,8 @@ compiler is installed on CPU-only boxes): the training path's kernels at
 GPT-2 760M width, the grouped GEMM kernels at OLMoE-1B-7B's (their
 weight panels resident in VMEM) and at Mixtral-8x7B's, the flash kernels at JoyAI-LLM-Flash's two head widths (a
 192-wide score, a 128-wide value) and the grouped ones at its 768-wide
-experts, the gated delta rule's two at Qwen3-Next's, the state-space scan's two at Nemotron-H's
+experts, the gated delta rule's two at Qwen3-Next's and its two with a
+decay a key channel at Kimi-Linear's (one packed sequence of 16,384), the state-space scan's two at Nemotron-H's
 and the short causal convolution's two at both hybrids' (each in its
 orientation), the selective scan's two and the flash kernels at a 64-wide
 score and a 128-wide value head at Phi-4-mini-flash's (one packed sequence
@@ -136,6 +137,16 @@ def _gdr(q, k, v, g, beta, seg):
                                         scales=(dk ** -0.5, 1.0))
 
 
+def _kda(q, k, v, g, beta, seg):
+    """The same rule with a decay a key channel (``g`` of rank four):
+    ops/pallas/kda.py's kernels, with the blocking the library chooses."""
+    from deepspeed_tpu.ops.pallas import kda
+    (B, S, H, dk), dv = q.shape, v.shape[3]
+    blocking = kda.chunks_per_step(S // 64, 64, 1, dk, dv, v.dtype.itemsize)
+    return kda.kda_kernels(q, k, v, g, beta, seg, blocking,
+                           scales=(dk ** -0.5, 1.0))
+
+
 def _ssd(x, dt, A, Bm, Cm, D, seg):
     """The state-space scan's kernels as ops/state_space.py calls them on
     one TPU (the choice switched off: no TPU here), with the blocking the
@@ -198,6 +209,11 @@ _QKV_GQA_8K = [((2, 8192, 16, 256), jnp.bfloat16),
 _GDR_8K = [((2, 8192, 16, 128), jnp.bfloat16)] * 2 + [
     ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192, 32), jnp.float32),
     ((2, 8192, 32), jnp.float32), ((2, 8192), jnp.int32)]
+# kimi-linear-48b-a3b.packed-s16384-traces' six delta-rule layers: 32 heads
+# of 128, a decay a key channel, 256 chunks of 64 of one packed sequence
+_KDA_16K = [((1, 16384, 32, 128), jnp.bfloat16)] * 3 + [
+    ((1, 16384, 32, 128), jnp.float32), ((1, 16384, 32), jnp.float32),
+    ((1, 16384), jnp.int32)]
 # nemotron-3-nano-30b-a3b.packed-s8192-gas2: 8 experts held of 128, 16,384
 # tokens x 6 choices, a plan of held_rows_bound 24,576 (four times the even
 # share) + 8 * 128 rows; D 2688 -> F 1856 = 14.5 x 128 and back: one block
@@ -312,6 +328,9 @@ KERNEL_CASES = {
     "ds_gdr_s8192_packed_fwd": (_gdr, _GDR_8K),
     "ds_gdr_s8192_packed_fwd_bwd": (
         jax.grad(_sum_sq(_gdr), (0, 1, 2, 3, 4)), _GDR_8K),
+    "ds_kda_s16384_packed_fwd": (_kda, _KDA_16K),
+    "ds_kda_s16384_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_kda), (0, 1, 2, 3, 4)), _KDA_16K),
     "ds_ssd_s8192_packed_fwd": (_ssd, _SSD_8K),
     "ds_ssd_s8192_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_8K),
@@ -380,6 +399,8 @@ NAMED_KERNELS = {
     "ds_ggemm_down_fwd_bwd": {"ds_ggemm_fwd", "ds_ggemm_dx", "ds_ggemm_dw"},
     "ds_gdr_s8192_packed_fwd": {"ds_gdr_fwd"},
     "ds_gdr_s8192_packed_fwd_bwd": {"ds_gdr_fwd", "ds_gdr_bwd"},
+    "ds_kda_s16384_packed_fwd": {"ds_kda_fwd"},
+    "ds_kda_s16384_packed_fwd_bwd": {"ds_kda_fwd", "ds_kda_bwd"},
     "ds_ssd_s8192_packed_fwd": {"ds_ssd_fwd"},
     "ds_ssd_s8192_packed_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
     "ds_conv_sublanes_s8192_packed_fwd": {"ds_conv_fwd"},
@@ -897,6 +918,7 @@ def test_library_knows_the_chips_peaks(v5e):
 
 @pytest.mark.parametrize("script", [
     "chip_smoke.py", "bench.py", "scripts/delta_rule_table.py --seed 1",
+    "scripts/kda_rule_bench.py",
     "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py",
     "scripts/latent_attention_table.py --seed 1",
     "scripts/latent_attention_table.py --bits"])

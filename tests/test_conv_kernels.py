@@ -268,12 +268,15 @@ def test_more_than_one_device_takes_the_xla_form(monkeypatch):
 
 
 #: each op's choice at its cell's shapes (the Nemotron-H convolution and
-#: scan, Qwen3-Next's delta rule), bfloat16
+#: scan, Qwen3-Next's and Kimi-Linear's delta rules), bfloat16
 LOWERINGS = {
     "conv": lambda asked: la._conv_blocking(
         asked, 8192, 8192, 4, jnp.bfloat16, "sublanes", 0),
     "delta_rule": lambda asked: la._kernel_blocking(
         asked, 128, 64, 2, 128, 128, jnp.bfloat16),
+    # ... and Kimi-Linear's, a decay a key channel
+    "delta_rule_by_channel": lambda asked: la._kernel_blocking(
+        asked, 256, 64, 1, 128, 128, jnp.bfloat16, True),
     "scan": lambda asked: ss._kernel_blocking(
         asked, 64, 128, 8, 64, 128, jnp.bfloat16),
 }

@@ -179,6 +179,10 @@ def test_the_account_says_which_decay_and_the_shape_alone_chooses():
         gated_delta_rule(q, k, v, g[..., 0], beta, seg, chunk=chunk)
     (row,) = tracing.delta_rule_chunks("test/kda")
     assert row["decay"] == "head"
-    # no kernels for it, and no switch that could ask for them
-    with pytest.raises(ValueError, match="key channel"):
+    # heads 8 wide are no shape of ops/pallas/kda.py's kernels: asked for
+    # or not, the XLA form (tests/test_kda_kernels.py has the shapes they
+    # take)
+    with tracing.step_account("test/kda"):
         gated_delta_rule(q, k, v, g, beta, seg, chunk=chunk, interpret=True)
+    (row,) = tracing.delta_rule_chunks("test/kda")
+    assert (row["decay"], row["path"]) == ("channel", "xla")
