@@ -61,7 +61,17 @@ def causal_attention_flops(tokens, sizes, s_eff, passes):
     is QK^T and PV = 4*S*D per token; a backward call is dQ, dK, dV and dP
     = 8*S*D; the causal mask halves both.  ``passes`` lists the calls,
     e.g. ["fwd", "fwd", "bwd"] under full remat, where the forward kernel
-    runs twice."""
+    runs twice.
+
+    What it assumes: EVERY one of ``num_layers`` layers attends, at
+    ``num_heads * head_dim = d_model``, with one width for scores and
+    values and no window.  A family with layers of another kind, a latent
+    width, two head counts, a window or a score narrower than its value
+    brings its own count in ``required_ops/<file>.py`` and lists its own
+    pair of rooflines; ``manifest.lint`` refuses a metric that names this
+    function for a cell whose configuration needed such a file for its
+    ``mfu_pct`` (read 5.9 x high in one such cell and 3 x low in another
+    while it was listed for all)."""
     per_call = {"fwd": 4.0, "bwd": 8.0}
     return 0.5 * sum(per_call[p] for p in passes) \
         * tokens * sizes["num_layers"] * sizes["d_model"] * s_eff
@@ -72,9 +82,12 @@ def grouped_ffn_flops(tokens, sizes, s_eff, passes):
     for a grouped GEMM kernel's roofline: each token goes through
     ``top_k`` SwiGLU experts, three D x F matrices each, so a forward
     call is 2*top_k*3*D*F = 6*top_k*D*F per token per layer, and a
-    backward call (dx and dw of each) twice that.  Rows a kernel pads a
-    group with are not required work.  ``s_eff`` plays no part: the
-    signature is the one the roofline readers call."""
-    per_call = {"fwd": 6.0, "bwd": 12.0}
+    backward call (dx and dw of each) twice that.  ``"gate_up"`` is a
+    forward pass that stops before the output matrix, 4*top_k*D*F: all a
+    recompute runs where the backward needs no output of the down product
+    (a row weighted by its gate before it).  Rows a kernel pads a group
+    with are not required work.  ``s_eff`` plays no part: the signature
+    is the one the roofline readers call."""
+    per_call = {"fwd": 6.0, "gate_up": 4.0, "bwd": 12.0}
     return sum(per_call[p] for p in passes) * tokens * sizes["num_layers"] \
         * sizes["top_k"] * sizes["d_model"] * sizes["d_ff"]

@@ -4,7 +4,8 @@ Nothing here knows a cell, a configuration, a traffic mix or a metric by
 name: a workload entry names its ``config`` and ``traffic``, and every file
 is found from those names (``cells/<workload>.json``, where a cell has one,
 from the workload's own).  ``lint`` holds the manifest to the limits the
-driver checks before any run (names, units, four-chip share, files).
+driver checks before any run (names, units, four-chip share, files), and
+a share of a peak to the cells its count of operations was written for.
 """
 import json
 import os
@@ -115,7 +116,9 @@ def lint(manifest):
     if not (isinstance(d["run_seconds"], int) and 1 <= d["run_seconds"] <= 51):
         bad.append("run_seconds: whole number 1..51")
 
-    files = set()
+    # own_count: the configurations that compute their mfu_pct with a
+    # function of required_ops/ ("flops": {"train": "<file>:<function>"})
+    files, own_count = set(), {}
     for c in d["configs"]:
         if set(c) != {"name", "source", "file", "reduced", "why"}:
             bad.append(f"config {c.get('name')}: keys {sorted(c)}")
@@ -134,10 +137,14 @@ def lint(manifest):
         if not os.path.isfile(os.path.join(manifest.root, c["file"])):
             bad.append(f"config {c['name']}: {c['file']} missing")
             continue
+        config = manifest.config(c["name"])
         try:
-            init_seed(manifest.config(c["name"]))
+            init_seed(config)
         except SystemExit as refusal:
             bad.append(str(refusal))
+        train = config.get("flops", {}).get("train", "")
+        if ":" in train:
+            own_count[c["name"]] = train
 
     configs = [c["name"] for c in d["configs"]]
     cells = d["workloads"]
@@ -198,6 +205,24 @@ def lint(manifest):
                 if not os.path.isfile(manifest.path(
                         "layer_metrics", m["name"] + ".json")):
                     bad.append(f"{m['name']}: layer_metrics file missing")
+                    continue
+                if ("roofline" in m["name"] or "mfu" in m["name"]) \
+                        and not m.get("workloads"):
+                    bad.append(f"{m['name']}: no workloads list: a share "
+                               f"of a peak is counted for named cells")
+                counted_by = manifest.layer_metric(m["name"])["params"].get(
+                    "flops")
+                if counted_by is None or ":" in counted_by:
+                    continue        # no count, or a family's own
+                for w in cells:
+                    own = own_count.get(w["config"])
+                    if own and w["name"] in m.get("workloads", cell_names):
+                        bad.append(
+                            f"{m['name']}: {counted_by} of harness/flops.py "
+                            f"is listed for {w['name']}, whose configuration "
+                            f"counts its step with {own}: a family that "
+                            f"needed its own count for the step needs it "
+                            f"for the kernel too")
     if "setup_s" not in e2e:
         bad.append("end_to_end lacks setup_s")
     if len(set(names)) != len(names):

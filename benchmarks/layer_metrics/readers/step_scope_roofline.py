@@ -2,9 +2,9 @@
 the bound: the larger of the two floors of the required operations over
 the device time of the instructions traced under the scope.  As
 step_op_time chooses the instructions (by the scope path the step-program
-map gives each), as kernel_roofline prices them — but the function named
-under ``ops`` returns (FLOPs, bytes), and the floor is the larger of FLOPs
-/ bf16 peak and bytes / HBM bandwidth.  What runs under the scope may be
+map gives each), as step_kernel_roofline prices them — but the function
+named under ``ops`` returns (FLOPs, bytes), and the floor is the larger of
+FLOPs / bf16 peak and bytes / HBM bandwidth.  What runs under the scope may be
 XLA fusions or a Mosaic kernel: the floor does not know.
 params:
   program, module: as step_phase

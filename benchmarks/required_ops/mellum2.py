@@ -92,9 +92,11 @@ def swiglu_ffn_flops(tokens, sizes, s_eff, passes):
     and, summed over the chips, as many arrive, so a chip's required work
     is that of ITS OWN tokens' rows — ``top_k`` experts a token a layer,
     three D x F matrices each, a forward call 2 * 3 * D * F per row and a
-    backward call twice that.  Rows of padding (a group's last tile, the
-    bound's empty part) are not required."""
-    per_call = {"fwd": 6.0, "bwd": 12.0}
+    backward call twice that; ``"gate_up"`` is the recompute of an
+    exchanged layer, which multiplies no output matrix: 2 * 2 * D * F.
+    Rows of padding (a group's last tile, the bound's empty part) are not
+    required."""
+    per_call = {"fwd": 6.0, "gate_up": 4.0, "bwd": 12.0}
     return sum(per_call[p] for p in passes) * tokens * sizes["num_layers"] \
         * sizes["top_k"] * sizes["d_model"] * sizes["d_ff"]
 

@@ -35,7 +35,14 @@ def test_a_full_line_passes(cell, traced):
 
 
 def test_every_listed_metric_and_no_other():
-    one_chip, four_chip = CELLS[0], CELLS[-1]
+    # the cell that gathers its parameters, by the metric's own list, and
+    # one that does not: no place in the manifest's order is any cell's
+    gathers = next(m for m in MANIFEST.data["per_layer"]
+                   if m["name"] == "comm.gather_gbps_per_chip")["workloads"]
+    four_chip = gathers[0]
+    one_chip = next(c for c in CELLS if c not in gathers)
+    assert MANIFEST.workload(four_chip)["chips"] == 4
+    assert MANIFEST.workload(one_chip)["chips"] == 1
     good = json.loads(line(four_chip, True))
     assert "comm.gather_gbps_per_chip" in good["metrics"]
     assert "comm.gather_gbps_per_chip" not in \

@@ -163,9 +163,8 @@ def test_the_metrics_are_the_new_cells_alone():
             assert m["moves"] == "tokens_per_s_per_chip"
     assert set(METRICS) <= {
         m["name"] for m in manifest.metrics("per_layer", CELL)}
-    assert len(manifest.data["per_layer"]) == 128      # the contract's most
-    names = [w["name"] for w in manifest.data["workloads"]]
-    assert names.index(CELL) == 11 and manifest.workload(CELL)["chips"] == 1
+    assert len(manifest.data["per_layer"]) <= 128      # the contract's most
+    assert manifest.workload(CELL)["chips"] == 1
     config = manifest.config("kimi-linear-48b-a3b")
     assert config["reference"] == "kimi_linear"
     assert config["flops"]["train"] == "kimi_linear:train_flops_per_token"

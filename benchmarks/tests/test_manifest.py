@@ -5,6 +5,8 @@ import json
 import os
 import shutil
 
+import pytest
+
 from harness.manifest import Manifest, ROOT, lint
 from rehearse import rehearse
 
@@ -18,10 +20,67 @@ def test_lint_catches_what_the_driver_refuses():
     manifest.data = copy.deepcopy(manifest.data)
     manifest.data["workloads"][0]["name"] = "has space"
     manifest.data["end_to_end"][0]["unit"] = "tokens per second"
-    manifest.data["workloads"][1]["chips"] = 4        # 2 of 3 on four chips
+    # one four-chip cell more than the quarter of the cells there are
+    cells = manifest.data["workloads"]
+    one_chip = [w for w in cells if w["chips"] == 1]
+    four_chip = len(cells) - len(one_chip)
+    for w in one_chip[:max(1, len(cells) // 4) + 1 - four_chip]:
+        w["chips"] = 4
     complaints = " | ".join(lint(manifest))
     assert "bad name" in complaints and "bad unit" in complaints
     assert "over 25%" in complaints
+
+
+FLASH = "attention.flash_fwd_roofline"
+
+
+def edited_tree(tmp_path, edit):
+    """A checkout in a temporary tree: the benchmark's files as they are (a
+    link) under a BENCHMARK.json whose per-layer entries ``edit`` has
+    changed, handed to it by name."""
+    data = copy.deepcopy(Manifest().data)
+    edit({m["name"]: m for m in data["per_layer"]})
+    os.symlink(os.path.join(ROOT, "benchmarks"),
+               str(tmp_path / "benchmarks"))
+    with open(str(tmp_path / "BENCHMARK.json"), "w") as f:
+        json.dump(data, f)
+    return Manifest(str(tmp_path))
+
+
+def test_a_roofline_without_a_list_is_refused(tmp_path):
+    shares = [m for m in Manifest().data["per_layer"]
+              if "roofline" in m["name"] or "mfu" in m["name"]]
+    assert FLASH in [m["name"] for m in shares]
+    assert all(m.get("workloads") for m in shares)
+    complaints = lint(edited_tree(
+        tmp_path, lambda entries: entries[FLASH].pop("workloads")))
+    assert any(c.startswith(FLASH + ": no workloads list: a share of a peak "
+                            "is counted for named cells")
+               for c in complaints)
+
+
+@pytest.mark.parametrize("cell", [w["name"]
+                                  for w in Manifest().data["workloads"]])
+def test_a_bare_count_is_listed_only_where_the_step_has_none_of_its_own(
+        cell, tmp_path):
+    """``causal_attention_flops`` counts every layer at d_model: a cell
+    whose configuration brought a ``required_ops`` file for its mfu_pct
+    cannot list a roofline counted by it; the others can."""
+    manifest = Manifest()
+    own = manifest.cell(cell)[1].get("flops", {}).get("train", "")
+    listed = cell in next(m for m in manifest.data["per_layer"]
+                          if m["name"] == FLASH)["workloads"]
+    assert listed == (":" not in own)
+    complaints = lint(edited_tree(
+        tmp_path, lambda entries: entries[FLASH].update(workloads=[cell])))
+    if listed:
+        assert complaints == []
+    else:
+        assert len(complaints) == 1 and complaints[0].startswith(
+            f"{FLASH}: causal_attention_flops of harness/flops.py is "
+            f"listed for {cell}, whose configuration counts its step with "
+            f"{own}")
+        assert "needs it for the kernel too" in complaints[0]
 
 
 def test_a_new_cell_is_files_only(tmp_path):

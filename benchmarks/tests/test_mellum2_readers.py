@@ -168,7 +168,10 @@ def test_known_answers_on_hand_made_events(program, monkeypatch):  # noqa: F811
         == pytest.approx(share(ops.full_layer_attention_flops,
                                ["bwd"], 600))
     assert value("moe.ep_ggemm_fwd_roofline", ctx) \
-        == pytest.approx(share(ops.swiglu_ffn_flops, ["fwd", "fwd"], 300))
+        == pytest.approx(share(ops.swiglu_ffn_flops, ["fwd", "gate_up"], 300))
+    # five forward products a layer-pass where two whole passes are six
+    assert share(ops.swiglu_ffn_flops, ["fwd", "gate_up"], 300) * 6 \
+        == pytest.approx(share(ops.swiglu_ffn_flops, ["fwd", "fwd"], 300) * 5)
     assert value("moe.ep_ggemm_bwd_roofline", ctx) \
         == pytest.approx(share(ops.swiglu_ffn_flops, ["bwd"], 400))
     from deepspeed_tpu.telemetry import tracing
@@ -209,8 +212,6 @@ def test_the_metrics_are_the_new_cells_alone():
             assert m["moves"] == "tokens_per_s_per_chip"
     assert set(METRICS) <= {
         m["name"] for m in manifest.metrics("per_layer", CELL)}
-    names = [w["name"] for w in manifest.data["workloads"]]
-    assert names.index(CELL) == 8 == len(names) - 1
     cell, config, mix = manifest.cell(CELL)
     assert cell["chips"] == 4
     assert config["reference"] == "mellum2"

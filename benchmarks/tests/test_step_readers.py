@@ -13,7 +13,8 @@ import pytest
 from harness import flops, trace as tr
 from harness.manifest import Manifest
 from layer_metrics.readers import step_phase
-from test_trace_reduction import KERNEL, RECORDED, hlo
+from test_trace_reduction import (KERNEL, MOSAIC, RECORDED, hlo,
+                                  neither_mosaic_nor_collective, op_ms)
 
 PHASES = ("forward", "recompute", "backward", "optimizer")
 STEP_METRICS = [f"step.{p}_ms_per_step" for p in PHASES] \
@@ -198,7 +199,8 @@ def test_phases_sum_to_the_self_total_on_the_recording(recorded, program):
     ctx = context(recorded, steps=4)
     total = sum(value(m, ctx) for m in STEP_METRICS)
     assert value("step.unattributed_ms_per_step", ctx) == 0.0
-    # the same ops as model.xla + attention.kernel, less what ran outside
+    # the same ops as every Mosaic call + every op that is neither one nor
+    # a collective (device_op_time on the text alone), less what ran outside
     # the step's module (two small programs per step on device 0).  The
     # five are each the worst device's, so their sum may pass one device's
     # total: compare per device
@@ -219,8 +221,7 @@ def test_phases_sum_to_the_self_total_on_the_recording(recorded, program):
                          if not exclude.search(t))
         assert 0 <= everywhere - ns < 0.02 * everywhere
     assert worst_total * 1e-6 / 4 <= total <= 1.05 * worst_total * 1e-6 / 4
-    both = value("model.xla_ms_per_step", ctx) \
-        + value("attention.kernel_ms_per_step", ctx)
+    both = op_ms(ctx, neither_mosaic_nor_collective()) + op_ms(ctx, MOSAIC)
     assert total == pytest.approx(both, rel=0.05)
 
 
@@ -233,9 +234,9 @@ def test_kernels_and_gathers_on_the_recording(recorded, program):
         / (value("attention.flash_fwd_roofline", ctx) / 100)
     bwd_ms = need(["bwd"]) / 197e12 * 1e3 \
         / (value("attention.flash_bwd_roofline", ctx) / 100)
-    # named apart, the kernels still add up to "any Mosaic call"
-    assert fwd_ms + bwd_ms == pytest.approx(
-        value("attention.kernel_ms_per_step", ctx), rel=0.02)
+    # named apart, the kernels add up to "any Mosaic call" on a recording
+    # whose only Mosaic kernel is the flash one
+    assert fwd_ms + bwd_ms == pytest.approx(op_ms(ctx, MOSAIC), rel=0.02)
     assert fwd_ms > 0 and bwd_ms > 0
     gathers = value("comm.param_gather_exposed_ms_per_step", ctx)
     assert 0 < gathers <= value("comm.exposed_ms_per_step", ctx)
