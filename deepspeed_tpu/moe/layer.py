@@ -18,15 +18,17 @@ Two dispatch formulations (``MoEConfig.dispatch_mode``, ISSUE 8):
   ``capacity_factor``.  On an ``expert`` mesh axis wider than one the
   experts are really spread (:func:`_exchanged_grouped_moe`): inside a
   ``shard_map`` over every mesh axis each chip routes its own tokens
-  over all experts, sends each chosen row — and, beside the rows, their
-  gates: a float32 a row — to the chip that holds its expert (one
-  all-to-all of rows, one of gates), runs the held plan over the rows it
-  received, each weighted by its gate between the experts' two halves,
-  sends the results back (a second all-to-all of rows) and sums them
-  where the token lives.  Drop-free inside a stated bound: a chip has
-  room for ``held_rows_factor`` times the rows even routing sends it, and
-  a row past that is counted (:data:`ROWS_OVER_BOUND`), never silently
-  lost.
+  over all experts, sends a token's row once to each chip that holds an
+  expert it chose — and, beside the rows, a float32 pair a (token,
+  expert): its gate and where its token's row lands (one all-to-all of
+  rows, one of lanes) — expands what landed into the held plan's groups,
+  runs the plan, each row weighted by its gate between the experts' two
+  halves, sums a token's results on that chip, sends the sums back (a
+  second all-to-all of rows) and adds them up where the token lives.
+  Drop-free inside a stated bound: a chip has room for
+  ``held_rows_factor`` times the (token, expert) rows even routing sends
+  it, and a row past that is counted (:data:`ROWS_OVER_BOUND`), never
+  silently lost.
 - ``auto`` — einsum when training; grouped at eval/serving when the
   kernel is real (single TPU device / interpret) or the host is
   single-device — a multi-device host where only the unsharded
@@ -44,7 +46,7 @@ import contextlib
 import os
 from dataclasses import dataclass, fields
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -352,19 +354,25 @@ def _emit_held_plan(plan):
                        jnp.int32(plan.padded_rows))
 
 
-def _report_exchanged(sent, received):
+def _report_exchanged(sent, received, wire_share):
     reg = _metrics_registry
     if reg is None:
         return
     reg.set_gauge(EXCHANGE_ROWS_SENT, float(sent))
     reg.set_gauge(EXCHANGE_ROWS_RECEIVED, float(received))
+    reg.set_gauge(EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW, float(wire_share))
 
 
-def _emit_exchanged(sent, received):
-    """Beside :func:`_emit_held_plan`, through the registry tap alone."""
+def _emit_exchanged(sizes, routed):
+    """Beside :func:`_emit_held_plan`, through the registry tap alone: the
+    (token, chip) rows this chip sent and received, and of those it sent
+    the ones that left it — the wire's — a routed (token, expert) row."""
     if _metrics_registry is None:
         return
-    jax.debug.callback(_report_exchanged, sent, received)
+    sent = jnp.sum(sizes.rows.send)
+    wire = sent - sizes.rows.send[jax.lax.axis_index(EXPERT_AXIS)]
+    jax.debug.callback(_report_exchanged, sent, jnp.sum(sizes.rows.held),
+                       wire.astype(jnp.float32) / routed)
 
 
 def _emit_router_health(logits, routing, config: MoEConfig):
@@ -540,60 +548,79 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
     mesh axis: chip ``d`` of its ``n`` holds experts ``[d * E / n, (d + 1)
     * E / n)``.  The routing (``routing``, ``eids``, ``gates``: over all
     experts and the whole batch) is the caller's; from there on every mesh
-    axis is manual, and each chip, for the tokens it holds (the same number
-    on every chip: rows of zero gate make it up where the tokens do not
-    split evenly, a decode step's):
+    axis is manual, and each chip, for the ``t`` tokens it holds (the same
+    number on every chip: rows of zero gate make it up where the tokens do
+    not split evenly, a decode step's).  **A row on the wire is a (token,
+    destination chip)**: a token's row crosses to a chip once, whatever
+    number of that chip's experts it chose — at most ``n`` rows a token,
+    one of them its own chip's, where a row a (token, expert) sent ``k``
+    (:func:`_rows_to_experts` is steps 1 to 4):
 
-    1. lays its routed rows out **by expert**, over all ``E`` — a held plan
-       of its own rows (``make_held_group_plan``, ``dispatch_held_rows``) —
-       which is by chip too, and their gates the same way, a float32 a row
-       and 0 on a padding row (``scatter_to_groups``); learns from one
-       small all-gather how many rows every chip has for every expert, and
-       from that table, by cumulative sums, every chip's layout
-       (``mappings.make_exchange_sizes``), its own receive plan among them
-       (``make_counted_group_plan``: no sort, no look-up);
+    1. lays its tokens' rows out **by chip** — for each chip the tokens
+       that chose at least one of its experts, in token order: a held plan
+       of the ``t * n`` (token, chip) elements over the ``n`` chips
+       (``make_held_group_plan``, ``dispatch_held_rows``), ``n * t`` rows
+       at most, a bound that is also the worst case — and, **by expert**
+       over all ``E`` (a held plan of its ``t * k`` routed elements), a
+       float32 pair a routed row: its gate and the place its token's row
+       lands in at the expert's chip (``scatter_to_groups``); learns from
+       one small all-gather how many rows every chip has for every expert
+       and for every chip, and from that table, by cumulative sums, every
+       chip's layouts (``mappings.make_exchange_sizes``), its own receive
+       plan among them (``make_counted_group_plan``: no sort, no look-up);
     2. ``exchange/exchange_send``: one all-to-all of the rows
        (``lax.ragged_all_to_all``: the rows there are and no padding), one
-       slice a (chip, expert): a slice lands inside its expert's group of
-       the receiver's plan, behind those of the senders before, so what
-       arrives IS the group-padded array the kernels read — in a buffer
-       nobody filled: one small kernel zeroes each group's last tile first
-       (its padding rows: ``grouped_gemm.zeroed_padding``), and behind the
-       live prefix nothing is written; and a second,
-       narrow one, of the gates through the same sizes: ``[rows, 128]``
-       float32 (:data:`_GATE_LANES`: a ``[rows, 1]`` array travels as
-       wide, and is re-laid on both sides of its call: 1.9 ms a call on a
-       v5e), a row of the receive plan each.  A chip has room for a
-       stated bound of rows from all chips together: ``held_rows_factor``
-       times what it is sent under even routing
-       (``grouped_gemm.held_rows_bound``) — not a bound on what one chip
-       sends another: one sender's skew uses the room the others leave.  A
-       row that finds no room is counted (:data:`ROWS_OVER_BOUND`), never
-       silently lost: the room goes to the senders in their order, and a
-       pair's last rows — those of its highest experts — are the ones cut;
-    3. runs the grouped kernels over what it received, and nothing else:
-       the receiving chip sorts, gathers and sums no row.  **The gate is
-       applied here, where the experts are**: the live-prefix pass between
-       the two halves forms ``gate · act(...)`` in float32 and rounds once
+       slice a pair of chips, into a landing buffer ``[n * t, D]`` nobody
+       filled — sender ``j``'s rows from ``j * t`` on, a slot no other
+       sender reaches, so no row can find the wire or the buffer full;
+       and a second, narrow one, **the lanes**: ``[rows, 128]`` float32
+       (:data:`_GATE_LANES`: a ``[rows, 2]`` array travels as wide, and is
+       re-laid on both sides of its call), one slice a (chip, expert), a
+       row a (token, expert) — lane 0 its gate, lane 1 its landed place
+       from 1 on, so a gate of exact 0.0 is still a chosen expert — which
+       lands inside its expert's group of the receiver's plan, behind the
+       rows of the senders before: what arrives IS a group-padded array,
+       in a buffer one small kernel zeroed the groups' last tiles of
+       (their padding rows: ``grouped_gemm.zeroed_padding``).  A chip's
+       plan has room for a stated bound of (token, expert) rows from all
+       chips together: ``held_rows_factor`` times what it is sent under
+       even routing (``grouped_gemm.held_rows_bound``) — not a bound on
+       what one chip sends another: one sender's skew uses the room the
+       others leave.  A row that finds no room is counted
+       (:data:`ROWS_OVER_BOUND`), never silently lost: the room goes to the
+       senders in their order, and a pair's last rows — those of its
+       highest experts — are the ones cut;
+    3. **expands the landed rows where the experts are**: the plan's rows
+       are gathered out of the landing buffer by the places the lanes
+       brought (``gather_landed_rows``, over the plan's live prefix; a
+       padding row reads zeros) — the receiving chip sorts nothing;
+    4. runs the grouped kernels over the plan.  **The gate is applied
+       here, where the experts are**: the live-prefix pass between the two
+       halves forms ``gate · act(...)`` in float32 and rounds once
        (:func:`_glu`), so the output product is of weighted rows;
-    4. ``exchange/exchange_return``: one all-to-all of the results, slice
+    5. **sums where the experts are**: a token's weighted output rows on
+       this chip are summed by landed row, in float32, rounded once
+       (``sum_into_landed_rows``: ``ds_rowsum`` with the landed rows for
+       its tokens) — ``[n * t, D]``, a row a (token, sender);
+    6. ``exchange/exchange_return``: one all-to-all of those sums, slice
        for slice, each row to the place it came from;
-    5. sums a token's rows, once, in float32 (``sum_held_rows`` over its
-       own plan: ``ds_rowsum`` with no gates).
+    7. sums a token's at most ``n`` rows, once, in float32
+       (``sum_held_rows`` over its plan by chip: ``ds_rowsum`` with no
+       gates).
 
     Backward, every step is its own transpose (an all-to-all's is the
-    all-to-all back; the sum's is step 1's gather): two all-to-alls of rows
-    and one of gates' cotangents a pass.  No row that came back is a
+    all-to-all back; a sum's is the gather it undoes): two all-to-alls of
+    rows and one of lanes' cotangents a pass.  No row that came back is a
     residual of anything — a gate's cotangent is the row sum of ``dh · act``
     in the pass that forms the halves' cotangents, on the expert's chip, and
     travels home through the narrow exchange's transpose — so a
     rematerialised layer's recompute ends at the activation: no output
     product, no return, no sum.  Five all-to-alls of rows a layer-pass
-    (forward 2, recompute 1, backward 2) and three of gates.  The expert
+    (forward 2, recompute 1, backward 2) and three of lanes.  The expert
     weights come in as this chip's ``[E / n, ...]`` slices and their
     gradients leave so — reduced over no chip of the ``expert`` axis.
-    Returns as :func:`_held_grouped_moe` does, the counts summed over the
-    chips."""
+    Returns as :func:`_held_grouped_moe` does, the counts — of (token,
+    expert) rows — summed over the chips."""
     from deepspeed_tpu.moe import mappings
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     from deepspeed_tpu.utils.jax_compat import shard_map
@@ -623,9 +650,16 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             pad * k, dtype=eids.dtype) % E).reshape(pad, k)])
         gates = jnp.concatenate([gates, jnp.zeros((pad, k), gates.dtype)])
     R = t_chip * k
-    # n chips send a chip R rows each, 1 / n of them under even routing
+    # n chips send a chip R (token, expert) rows each, 1 / n of them under
+    # even routing
     bound = gg.held_rows_bound(n * R, held, E,
                                factor=config.held_rows_factor)
+    # the landing buffer: a row a (token, sender), a slot of t_chip a sender
+    landed_rows = n * t_chip
+    if landed_rows >= 1 << 24:
+        raise ValueError(
+            f"moe: a landed row's place travels as a float32, exact below "
+            f"2**24 ({landed_rows} rows land on a chip)")
     row_bytes = D * jnp.dtype(dt).itemsize
     count_in_step(
         # of one chip; the routed rows are those it EXPECTS to receive
@@ -635,21 +669,26 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
         exchange_calls={f"{t_chip}x{D}:{n}": {
             "pairs": n, "experts_held": held, "tokens": t_chip,
             "routed_rows": R, "receive_rows": bound, "width": D,
-            # what a call writes of its receive buffer before the rows
-            # arrive: the last tile of each held expert's group, where the
-            # group's padding rows lie — behind the live prefix nothing
+            # what a row on the wire is: a token's, once a chip it has an
+            # expert on — never more than the tokens a chip holds to each
+            # other chip, whatever the routing, so the wire is never full
+            "row_unit": "token_chip", "landed_rows": landed_rows,
+            "wire_rows_bound": (n - 1) * t_chip,
+            "wire_bytes": (n - 1) * t_chip * row_bytes,
+            # what a call writes of a plan-sized receive buffer (the
+            # lanes') before its rows arrive: the last tile of each held
+            # expert's group, where the group's padding rows lie — behind
+            # the live prefix nothing; of the rows' landing buffer nothing
             "receive_fill": "padding_tiles",
             "zeroed_rows_per_call": held * gg.default_block_m(),
             "even_rows_per_pair": R // n,
-            # what one all-to-all of rows puts on a chip's links under
-            # even routing: the rows for the other chips, nothing else
-            "wire_bytes": (n - 1) * (R // n) * row_bytes,
             "path": mappings.exchange_path(),
-            # a (chip, expert) is one slice of an all-to-all, and lands in
-            # its expert's group of the receiver's plan
+            # of the lanes: a (chip, expert) is one slice of the narrow
+            # all-to-all, and lands in its expert's group of the receiver's
+            # plan; of the rows one slice a pair
             "slices_per_pair": held, "receive_layout": "grouped",
             # what a rematerialised layer runs of them: the recompute
-            # needs the rows it received and their gates, and nothing
+            # needs the rows it received and their lanes, and nothing
             # that came back
             "row_calls_per_pass": {"forward": 2, "recompute": 1,
                                    "backward": 2},
@@ -659,36 +698,25 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
                if name in params}
 
     def on_chip(xt, eids, gates, weights):
-        with jax.named_scope(SCOPE_DISPATCH):
-            # R rows hold every row a chip routes: nothing is over here
-            mine, _ = gg.make_held_group_plan(eids.reshape(-1), 0, E, R,
-                                              row_to_padded=True)
-            sizes = mappings.make_exchange_sizes(mine.counts, R, bound)
-            plan, over = gg.make_counted_group_plan(sizes.counts, bound)
-            buf = gg.dispatch_held_rows(xt, mine, k)        # [R + E bm, D]
-            # a gate a row, as wide as the narrowest row the chip moves
-            # whole (a ``[rows, 1]`` array is padded to it anyway, and then
-            # re-laid on either side of its all-to-all)
-            gate_buf = jnp.broadcast_to(gg.scatter_to_groups(
-                gates.reshape(-1).astype(jnp.float32), mine)[:, None],
-                (mine.padded_rows, _GATE_LANES))
-        with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
-            x_pad = mappings.exchange_forth(buf, sizes, plan.padded_rows)
-            row_gate = mappings.exchange_forth(gate_buf, sizes,
-                                               plan.padded_rows, "gates")
+        out = _rows_to_experts(xt, eids, gates, E, bound)
+        plan, by_chip, sizes = out.plan, out.by_chip, out.sizes
         _emit_held_plan(plan)
-        _emit_exchanged(jnp.sum(sizes.send), jnp.sum(sizes.held))
+        _emit_exchanged(sizes, R)
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
-            h = _glu(mm, x_pad, weights.get("w_gate"), weights["w_in"],
-                     config, plan, row_gate)
+            h = _glu(mm, out.x_pad, weights.get("w_gate"), weights["w_in"],
+                     config, plan, out.lanes)
             y = mm(h, weights["w_out"])                     # [Mp, D]
-        with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_RETURN):
-            back = mappings.exchange_back(y, sizes, mine.padded_rows)
         with jax.named_scope(SCOPE_COMBINE):
-            combined = _after(gg.sum_held_rows(back, mine, k),
+            summed = gg.sum_into_landed_rows(
+                y, plan, out.source, (sizes.first, sizes.end), landed_rows)
+        with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_RETURN):
+            back = mappings.exchange_back(summed, sizes.rows,
+                                          by_chip.padded_rows)
+        with jax.named_scope(SCOPE_COMBINE):
+            combined = _after(gg.sum_held_rows(back, by_chip, n),
                               jax.lax.stop_gradient(h[0, 0]))
-        dropped = sizes.over + over
+        dropped = sizes.over + out.over
         return combined, jnp.stack([jnp.int32(R) - sizes.over, dropped])[None]
 
     tok = P(tok_axes)
@@ -703,6 +731,70 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
     if pad:
         return combined[:T], aux, (counts[0] - pad * k, counts[1])
     return combined, aux, (counts[0], counts[1])
+
+
+class _AtTheExperts(NamedTuple):
+    """What :func:`_rows_to_experts` leaves on a chip."""
+    x_pad: jnp.ndarray          # [Mp, D] the receive plan's rows
+    lanes: jnp.ndarray          # [Mp, lanes] float32: gate, landed place
+    source: jnp.ndarray         # [Mp] the landed row behind a plan row
+    plan: object                # the receive plan (``GroupPlan``)
+    by_chip: object             # the rows' send layout (``GroupPlan``)
+    sizes: object               # ``mappings.ExchangeSizes``
+    over: jnp.ndarray           # [] rows the receive plan itself cut
+
+
+def _rows_to_experts(xt, eids, gates, num_experts, bound) -> _AtTheExperts:
+    """The way out of :func:`_exchanged_grouped_moe` (its steps 1 to 4), on
+    one chip of the ``expert`` axis inside the ``shard_map``: this chip's
+    tokens ``xt`` [t, D] with their choices ``eids`` / ``gates`` [t, k] ->
+    the rows its experts multiply, in its receive plan of ``bound`` rows."""
+    from deepspeed_tpu.moe import mappings
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    n = jax.lax.axis_size(EXPERT_AXIS)
+    me = jax.lax.axis_index(EXPERT_AXIS)
+    (t_chip, k), E = eids.shape, int(num_experts)
+    held, R, landed_rows = E // n, t_chip * k, n * t_chip
+    with jax.named_scope(SCOPE_DISPATCH):
+        chosen = jnp.any(eids[:, :, None] == jnp.arange(E, dtype=eids.dtype),
+                         axis=1)                            # [t, E]
+        sizes = mappings.make_exchange_sizes(
+            chosen, R, bound, gg.landed_block_rows(landed_rows))
+        plan, over = gg.make_counted_group_plan(sizes.counts, bound)
+        # the rows' send layout: a token's row once a chip it chose an
+        # expert of — a held plan of (token, chip) elements over the chips,
+        # every one of which finds room
+        by_chip, _ = gg.make_held_group_plan(jnp.where(
+            sizes.to_chip, jnp.arange(n, dtype=jnp.int32), n).reshape(-1),
+            0, n, landed_rows)
+        buf = gg.dispatch_held_rows(xt, by_chip, n)         # [n t + n bm, D]
+        # the lanes': a row a routed element, by expert (R rows hold every
+        # row a chip routes: nothing is over here).  Where an element's
+        # row lands at its expert's chip: its token's place among those
+        # its chip sends there, in this sender's slot — from 1 on, 0 being
+        # no row (a padding row's)
+        mine, _ = gg.make_held_group_plan(eids.reshape(-1), 0, E, R,
+                                          row_to_padded=True)
+        lands = 1 + me * t_chip + jnp.sum(jnp.where(
+            (eids // held)[:, :, None] == jnp.arange(n, dtype=eids.dtype),
+            sizes.at[:, None, :], 0), axis=-1)              # [t, k]
+        # a gate and a place a row, as wide as the narrowest row the chip
+        # moves whole (a ``[rows, 2]`` array is padded to it anyway, and
+        # then re-laid on either side of its all-to-all)
+        lane_buf = jnp.pad(gg.scatter_to_groups(jnp.stack(
+            [gates.astype(jnp.float32), jax.lax.stop_gradient(
+                lands.astype(jnp.float32))], axis=-1).reshape(R, 2), mine),
+            ((0, 0), (0, _GATE_LANES - 2)))
+    with jax.named_scope(SCOPE_EXCHANGE), jax.named_scope(SCOPE_SEND):
+        landed = mappings.exchange_forth(buf, sizes.rows, landed_rows)
+        lanes = mappings.exchange_forth(lane_buf, sizes.lanes,
+                                        plan.padded_rows, "gates",
+                                        counts=sizes.counts)
+    with jax.named_scope(SCOPE_DISPATCH):
+        source = _landed_source(lanes, plan, landed_rows)
+        x_pad = gg.gather_landed_rows(landed, plan, source,
+                                      (sizes.first, sizes.end))
+    return _AtTheExperts(x_pad, lanes, source, plan, by_chip, sizes, over)
 
 
 #: the name a model gives the rows over ``held_rows_bound``, summed over its
@@ -720,6 +812,9 @@ HELD_PLAN_ROWS = "moe/held_plan_rows"
 #: and those it received
 EXCHANGE_ROWS_SENT = "moe/exchange_rows_sent"
 EXCHANGE_ROWS_RECEIVED = "moe/exchange_rows_received"
+#: and what of the sent rows crossed to another chip, a routed (token,
+#: expert) row: a token's row crosses to a chip once, so (n - 1) / k at most
+EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW = "moe/exchange_wire_rows_per_routed_row"
 
 
 def _route(params, logits, config: MoEConfig, train: bool, rng):
@@ -748,6 +843,19 @@ def _silu_glu(gate, up):
 #: expert's chip: a row of 128 float32 is the narrowest the chip's
 #: all-to-all moves as it lies
 _GATE_LANES = 128
+
+
+def _landed_source(lanes, plan, landed_rows):
+    """[Mp] int32: the landed row behind each row of the receive plan, as
+    lane 1 of the lanes that arrived says it — from 1 on, 0 on a padding
+    row (its tile was zeroed) — and ``landed_rows``, which reads zeros and
+    sums into nothing, on a padding row and behind the live prefix, where
+    the lanes hold whatever the buffer held."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import live_rows
+    place = jax.lax.stop_gradient(lanes[:, 1])
+    row = jnp.arange(plan.padded_rows, dtype=jnp.int32)
+    there = (row < live_rows(plan)) & (place >= 1) & (place <= landed_rows)
+    return jnp.where(there, place, landed_rows + 1).astype(jnp.int32) - 1
 
 
 def _row_weighted(fn):

@@ -104,7 +104,10 @@ rows summed into their tokens, forward in :func:`combine_held_rows` and
 backward in :func:`dispatch_held_rows` — walks the *tokens*: the Mosaic
 kernel ``ds_rowsum`` takes a block of tokens at a time and fetches, expert
 by expert, the run of the plan's rows that are theirs
-(:func:`_sum_live_into_tokens`).
+(:func:`_sum_live_into_tokens`).  An expert-parallel exchange's receive
+plan reads each landed row once an expert the token chose
+(:func:`gather_landed_rows`) and its way back is the same kernel with the
+landed rows for its tokens (:func:`sum_into_landed_rows`).
 """
 import functools
 import os
@@ -635,7 +638,11 @@ def zeroed_padding(counts, shape, dtype, after, what):
     (``ds_zeroed_padding_<what>``) is the allocation and the zeros, ``E``
     copies of a tile; ``after`` and ``what`` as :func:`_unwritten` takes
     them, and for its reasons.  Off the chip :func:`_unwritten`'s zeros
-    with the same tiles zeroed over them."""
+    with the same tiles zeroed over them.  With no ``counts`` the buffer
+    has no groups — an exchange's landing buffer, read by row and only
+    where a row landed: :func:`_unwritten` as it is."""
+    if counts is None:
+        return _unwritten(shape, dtype, after, what)
     bm = default_block_m()
     tiles = _padding_tiles(counts, shape[0], bm)
     use_reference, interpret = _use_reference(None)
@@ -1035,6 +1042,99 @@ def sum_held_rows(y: jnp.ndarray, plan: GroupPlan, top_k: int):
     gather, and no row of ``y`` is a residual."""
     chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
     return _sum_held(y, _way_back(plan), live_rows(plan), top_k, chunk)
+
+
+# ---- rows that landed once and are read by several experts.  An exchange
+# brings a token's row to a chip once (moe/layer.py
+# ``_exchanged_grouped_moe``): the landing buffer ``[L, D]`` holds a row a
+# (token, sender), and the chip's plan reads it once for each of its experts
+# the token chose.  ``source`` [Mp] says which: the landed row behind each
+# plan row, ``L`` on a padding row and behind the live prefix.  Out of the
+# buffer a gather over the live prefix; back into it — the experts' results
+# summed by landed row, a cotangent's transpose of the gather — ``ds_rowsum``
+# with the landed rows for its tokens: an expert's rows lie in landed order,
+# so a block of landed rows has one run in each group (``runs``: whoever
+# knows the senders' counts knows them, ``mappings.make_exchange_sizes``).
+def landed_block_rows(landed_rows: int) -> int:
+    """Landed rows a block of :func:`sum_into_landed_rows`: what the runs
+    of its plan are counted by."""
+    return _rowsum_blocks(int(landed_rows))[0]
+
+
+def _sum_live_into_landed(y, source, runs, landed_rows, live, chunk):
+    """:func:`_sum_live_into_tokens` for rows whose token is given a plan
+    row (``source``) and whose runs are given: ``y`` [Mp, D] summed by
+    ``source`` into ``[landed_rows, D]``, float32, rounded once."""
+    Mp, D = y.shape
+    use_reference, interpret = _use_reference(None)
+    if use_reference:
+        _count_sum(landed_rows, D, Mp, None, "xla")
+        return jnp.zeros((landed_rows, D), jnp.float32).at[source].add(
+            y.astype(jnp.float32), mode="drop").astype(y.dtype)
+    blocks, copy = _rowsum_blocks(landed_rows), _rowsum_copy(y.dtype)
+    assert Mp % copy == 0 and blocks[1] % copy == 0, (Mp, blocks, copy)
+    _count_sum(landed_rows, D, Mp, blocks, "kernel")
+    lane = jnp.arange(_ROWSUM_META_LANES, dtype=jnp.int32)[None, :]
+
+    def describe(start, first, meta):
+        at = _chunk_of(source, start, chunk)
+        return _put_chunk(meta, jnp.where(lane == 0, at[:, None], 0), start)
+
+    meta = _over_live_chunks(
+        Mp, chunk, live, describe,
+        _unwritten((Mp, _ROWSUM_META_LANES), jnp.int32, y, "meta"))
+    return _pallas_rowsum(y, meta, runs, landed_rows, blocks, copy, False,
+                          interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gather_landed(landed, source, runs, live, landed_rows, chunk):
+    return _token_rows_live(landed, source, live, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _sum_landed(y, source, runs, live, landed_rows, chunk):
+    return _sum_live_into_landed(y, source, runs, landed_rows, live, chunk)
+
+
+def _keeping_source(move):
+    """``move``'s forward rule: what says which landed row a plan row reads
+    (the three arguments behind the rows) is all it keeps."""
+    return lambda x, *rest: (move(x, *rest), rest[:3])
+
+
+# each is the other's transpose, and neither keeps a row
+_gather_landed.defvjp(
+    _keeping_source(_gather_landed),
+    lambda landed_rows, chunk, res, g: (
+        _sum_landed(g, *res, landed_rows, chunk), None, None, None))
+_sum_landed.defvjp(
+    _keeping_source(_sum_landed),
+    lambda landed_rows, chunk, res, g: (
+        _gather_landed(g, *res, landed_rows, chunk), None, None, None))
+
+
+def gather_landed_rows(landed: jnp.ndarray, plan: GroupPlan, source, runs):
+    """``landed`` [L, D] -> the plan's ``[Mp, D]``: plan row ``p`` of the
+    live prefix reads landed row ``source[p]``, exact zeros where that is
+    ``L`` (a group's padding); rows behind the prefix are not written.
+    Backward: :func:`sum_into_landed_rows` of the cotangent."""
+    chunk = _live_chunk_rows(plan, landed.shape[1] * landed.dtype.itemsize)
+    return _gather_landed(landed, source, runs, live_rows(plan),
+                          landed.shape[0], chunk)
+
+
+def sum_into_landed_rows(y: jnp.ndarray, plan: GroupPlan, source, runs,
+                         landed_rows: int):
+    """The plan's live rows ``y`` [Mp, D] summed by ``source`` ->
+    ``[landed_rows, D]``: one float32 accumulator a landed row, rounded
+    once (``ds_rowsum`` on one TPU; ``runs`` = (first, end), each [blocks
+    of :func:`landed_block_rows`, E]); a landed row no plan row reads gets
+    zeros.  Backward: :func:`gather_landed_rows` of the cotangent — no row
+    of ``y`` is a residual."""
+    chunk = _live_chunk_rows(plan, y.shape[1] * y.dtype.itemsize)
+    return _sum_landed(y, source, runs, live_rows(plan), int(landed_rows),
+                       chunk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

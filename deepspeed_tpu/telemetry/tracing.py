@@ -818,26 +818,36 @@ def exchange_calls(name: str = TRAIN_STEP_PROGRAM):
     """The expert-parallel exchanges of the step as moe/layer.py traced
     them: one row per shape — ``pairs`` (chips of the ``expert`` axis),
     ``experts_held`` a chip, ``tokens`` and ``routed_rows`` of one chip
-    (the rows it sends), ``receive_rows`` (the rows it has room to receive
-    from all chips together: the held plan's bound, a row past which is
-    counted in ``moe/rows_over_bound``), ``width``, ``even_rows_per_pair``
-    (what even routing sends from one chip to another), ``wire_bytes``
-    (what one all-to-all of rows puts on a chip's links under even
-    routing), ``path``, the collective the rows were traced to travel by
-    (``moe/mappings.py exchange_path``: ``"ragged_all_to_all"`` on a TPU —
-    the rows there are, no padding — and ``"all_to_all"`` of whole buffers
-    where the backend has no ragged one), ``slices_per_pair`` (the slices
-    of one all-to-all that go from one chip to another: one an expert the
-    receiver holds), ``receive_layout`` (``"grouped"``: a slice lands
-    inside its expert's group of the receiver's plan, so what arrives is
-    the array the grouped kernels read), and what a layer and micro-batch
-    runs of them by phase where the layer is rematerialised:
+    (its (token, expert) rows), ``receive_rows`` (the (token, expert) rows
+    its plan has room for from all chips together: the held plan's bound,
+    a row past which is counted in ``moe/rows_over_bound``), ``width``,
+    ``row_unit`` (``"token_chip"``: what a row on the wire is — a token's
+    row crosses to a chip once, whatever number of that chip's experts it
+    chose), ``landed_rows`` (the landing buffer: ``pairs * tokens``, a
+    slot of ``tokens`` a sender), ``wire_rows_bound`` (``(pairs - 1) *
+    tokens``: the most one all-to-all of rows can put on a chip's links,
+    whatever the routing) and ``wire_bytes`` (that many rows' bytes),
+    ``even_rows_per_pair`` (the (token, expert) rows even routing routes
+    from one chip to another), ``path``, the collective the rows were
+    traced to travel by (``moe/mappings.py exchange_path``:
+    ``"ragged_all_to_all"`` on a TPU — the rows there are, no padding —
+    and ``"all_to_all"`` of whole buffers where the backend has no ragged
+    one), ``slices_per_pair`` (the slices of the narrow all-to-all that go
+    from one chip to another: one an expert the receiver holds; the rows'
+    has one a pair), ``receive_layout`` (``"grouped"``: a slice of the
+    lanes lands inside its expert's group of the receiver's plan, and the
+    rows are gathered into the same groups from where they landed),
+    ``receive_fill`` / ``zeroed_rows_per_call`` (what a narrow call writes
+    of its plan-sized buffer before its rows arrive; the rows' landing
+    buffer is not written at all), and what a layer and micro-batch runs
+    of them by phase where the layer is rematerialised:
     ``row_calls_per_pass`` (``{"forward": 2, "recompute": 1, "backward":
     2}`` — all-to-alls of ``width``-wide rows; the recompute has no return:
     a row is weighted by its gate on its expert's chip, so no row that
     came back is a residual) and ``gate_calls_per_pass`` (one a phase: the
-    gates out beside the rows, ``[rows, 1]`` float32, and their cotangent
-    home).  Statements of the layer's code, held to the compiled text by
+    lanes out beside the rows — ``[rows, 128]`` float32, a gate and a
+    landed place a (token, expert) — and their cotangent home).
+    Statements of the layer's code, held to the compiled text by
     tests/test_chip_compile.py and tests/test_moe_exchange.py — not counts
     read off an executable.  None where the step has no exchange."""
     return _account_rows(name, "exchange_calls")

@@ -255,11 +255,15 @@ METRICS = {
                           "kernels and combine walk), per expert layer run",
     "moe/held_plan_rows": "static length of that plan (held_rows_bound + "
                           "one tile per held expert)",
-    "moe/exchange_rows_sent": "rows one chip sent to the chips of the "
-                              "expert axis (itself among them) in an "
-                              "exchanged expert layer run",
-    "moe/exchange_rows_received": "rows that chip received from them (the "
-                                  "held plan's live rows there)",
+    "moe/exchange_rows_sent": "(token, chip) rows one chip sent to the "
+                              "chips of the expert axis (itself among "
+                              "them) in an exchanged expert layer run",
+    "moe/exchange_rows_received": "(token, sender) rows that chip received "
+                                  "from them (what landed there)",
+    "moe/exchange_wire_rows_per_routed_row": "of the rows it sent, those "
+                                             "that crossed to another chip, "
+                                             "a routed (token, expert) row: "
+                                             "a token crosses to a chip once",
     # --- numerics observatory (training health, ISSUE 15)
     "num/grad_norm": "last resolved global gradient norm (-1 = "
                      "non-finite)",

@@ -267,20 +267,29 @@ def test_two_row_all_to_alls_a_pass_and_no_capacity_einsum(monkeypatch):
     bound = gg.held_rows_bound(4 * routed, 2, E,
                                factor=CONFIG.held_rows_factor)
     tile = gg.default_block_m()
-    sent = -(-routed // tile) * tile + E * tile     # a plan of its own rows
-    received = bound + E // 4 * tile                # and of those it holds
-    assert sorted(calls) == sorted(2 * [(sent, D), (received, D)]
-                                   + [(sent, LANES), (received, LANES)]), \
-        calls
+    # the rows leave a plan of (token, chip) elements over the four chips
+    # and land a row a (token, sender); the lanes leave a plan of the
+    # routed elements over all experts and land in the plan of those held
+    landed = tokens
+    sent = -(-landed // tile) * tile + 4 * tile
+    lanes_sent = -(-routed // tile) * tile + E * tile
+    received = bound + E // 4 * tile
+    assert sorted(calls) == sorted(2 * [(sent, D), (landed, D)]
+                                   + [(lanes_sent, LANES),
+                                      (received, LANES)]), calls
     assert not re.search(rf"\[(?:{tokens}|{tokens // 4}),{E},\d+\]", text)
     (call,) = tracing.exchange_calls("toy")
     assert call["pairs"] == 4 and call["experts_held"] == 2
     assert call["tokens"] == tokens // 4 and call["routed_rows"] == routed
     assert call["receive_rows"] == bound
-    # what a call initialises of its receive buffer: a tile a held expert
+    # what a row on the wire is, and how many a call can put there at most
+    assert call["row_unit"] == "token_chip"
+    assert call["landed_rows"] == landed
+    assert call["wire_rows_bound"] == 3 * (tokens // 4)
+    assert call["wire_bytes"] == 3 * (tokens // 4) * D * 4
+    # what a call initialises of a plan-sized buffer: a tile a held expert
     assert call["receive_fill"] == "padding_tiles"
     assert call["zeroed_rows_per_call"] == E // 4 * tile
-    assert call["wire_bytes"] == 3 * (routed // 4) * D * 4
     assert call["row_calls_per_pass"] == {"forward": 2, "recompute": 1,
                                           "backward": 2}
     assert call["gate_calls_per_pass"] == {"forward": 1, "recompute": 1,
@@ -430,6 +439,181 @@ def test_the_gates_gradient_is_the_returned_row_dot_its_tokens_cotangent(
     np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
 
 
+class _Gauges:
+    """A registry tap that keeps every value a gauge was set to."""
+    def __init__(self):
+        self.seen = {}
+
+    def set_gauge(self, name, value, **labels):
+        self.seen.setdefault(name, []).append(value)
+
+    def inc(self, name, value=1.0, **labels):
+        pass
+
+
+def _planted_choices(monkeypatch, choose, gate=None):
+    """The layer's (params, x) with every token's two experts planted:
+    ``choose(token)`` -> its pair, ``gate(token)`` -> its gates or None for
+    the router's own."""
+    params, x = params_and_x()
+    chosen = jnp.asarray([choose(t) for t in range(B * S)], jnp.int32)
+    route = moe_layer_module._route
+
+    def planted(*a, **k):
+        routing = route(*a, **k)._replace(expert_idx=chosen)
+        if gate is None:
+            return routing
+        mask, value = (jnp.asarray([gate(t)[i] for t in range(B * S)])
+                       for i in range(2))
+        return routing._replace(gate_weights=jnp.where(
+            mask, value.astype(jnp.float32), routing.gate_weights))
+
+    monkeypatch.setattr(moe_layer_module, "_route", planted)
+    return params, x
+
+
+@pytest.mark.parametrize("case, choose, crossing", [
+    # a token's two experts are one chip's, the next chip's: it crosses once
+    ("both_on_the_next_chip",
+     lambda t: (2 * ((t // 16 + 1) % 4), 2 * ((t // 16 + 1) % 4) + 1), 64),
+    # one expert at home, one on the next chip
+    ("one_at_home", lambda t: (2 * (t // 16), 2 * ((t // 16 + 1) % 4)), 64),
+    # both at home: nothing on the wire
+    ("both_at_home", lambda t: (2 * (t // 16), 2 * (t // 16) + 1), 0),
+    # two other chips
+    ("two_other_chips",
+     lambda t: (2 * ((t // 16 + 1) % 4), 2 * ((t // 16 + 2) % 4) + 1), 128),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_a_token_crosses_to_a_chip_once(case, choose, crossing, monkeypatch):
+    """The rows on the wire are the distinct (token, other chip) pairs —
+    ``moe/exchange_wire_rows_per_routed_row`` times the routed rows, summed
+    over the four chips — whatever number of a chip's experts a token
+    chose, and the layer is the one-device layer all the same."""
+    params, x = _planted_choices(monkeypatch, choose)
+    (want, (want_out, _)), want_grads = host(on_one_device(CONFIG, params, x))
+    gauges = _Gauges()
+    monkeypatch.setattr(moe_layer_module, "_metrics_registry", gauges)
+    fn, args = four_wide(CONFIG, params, x)
+    (got, (out, stats)), grads = host(fn(*args))
+    jax.effects_barrier()
+    shares = gauges.seen[moe_layer_module.EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW]
+    # forward alone sets them: one value a chip
+    assert len(shares) == 4
+    routed = B * S // 4 * K
+    assert round(sum(shares) * routed) == crossing
+    assert all(share * routed <= 3 * (B * S // 4) for share in shares)
+    assert sum(gauges.seen[moe_layer_module.EXCHANGE_ROWS_SENT]) \
+        == sum(gauges.seen[moe_layer_module.EXCHANGE_ROWS_RECEIVED]) \
+        == crossing + (64 if "at_home" in case else 0)
+    assert int(stats["dispatched"]) == B * S * K and not int(stats["dropped"])
+    np.testing.assert_allclose(out, want_out, atol=1e-5 * np.abs(
+        want_out).max())
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, atol=1e-5 * np.abs(w).max())
+
+
+def test_every_token_to_every_chip_fills_the_wire_and_no_more(monkeypatch):
+    """Top 4 of 8 experts, one on each chip: every token crosses to the
+    three other chips, which is all a wire can be asked to carry — the
+    bound the step's account states — where a row a (token, expert) would
+    have put the same three on it, and top 8 of 8 no more."""
+    for k in (4, 8):
+        config = replace(CONFIG, top_k=k)
+        params, x = params_and_x(config)
+        chosen = jnp.asarray([[(2 * c + t) % E if k == 4 else c
+                               for c in range(k)] for t in range(B * S)],
+                             jnp.int32)
+        route = moe_layer_module._route
+        monkeypatch.setattr(
+            moe_layer_module, "_route",
+            lambda *a, _route=route, **kw: _route(*a, **kw)._replace(
+                expert_idx=chosen))
+        want = host(on_one_device(config, params, x))
+        gauges = _Gauges()
+        monkeypatch.setattr(moe_layer_module, "_metrics_registry", gauges)
+        fn, args = four_wide(config, params, x)
+        with tracing.step_account("toy"):
+            got = host(fn(*args))
+        jax.effects_barrier()
+        monkeypatch.setattr(moe_layer_module, "_route", route)
+        monkeypatch.setattr(moe_layer_module, "_metrics_registry", None)
+        (call,) = tracing.exchange_calls("toy")
+        tracing.reset_programs()
+        shares = gauges.seen[
+            moe_layer_module.EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW]
+        wire = [round(share * (B * S // 4) * k) for share in shares]
+        assert wire == 4 * [call["wire_rows_bound"]] == 4 * [3 * 16]
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, w, rtol=2e-4,
+                                       atol=2e-5 * np.abs(w).max())
+
+
+def test_a_gate_of_exact_zero_is_still_a_chosen_expert(monkeypatch):
+    """Every third token's first gate is exactly 0.0 (and a padding
+    token's gates all are): the row is sent, multiplied and counted like
+    any other — the place a row landed in travels beside its gate, not in
+    it — and the gate's own gradient is the row's dot with its token's
+    cotangent, not zero."""
+    zeroed = lambda t: ((t % 3 == 0, False), (0.0, 0.0))    # noqa: E731
+    params, x = _planted_choices(
+        monkeypatch, lambda t: (t % 8, (t + 3) % 8), zeroed)
+    (want, (want_out, want_stats)), want_grads = host(
+        on_one_device(CONFIG, params, x))
+    fn, args = four_wide(CONFIG, params, x)
+    (got, (out, stats)), grads = host(fn(*args))
+    assert int(stats["dispatched"]) == B * S * K \
+        == int(want_stats["dispatched"])
+    assert int(stats["dropped"]) == 0
+    np.testing.assert_allclose(out, want_out, atol=1e-5 * np.abs(
+        want_out).max())
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, atol=1e-5 * np.abs(w).max())
+    # the gates' own gradient, by an array of zeros added to them
+    route = moe_layer_module._route
+
+    def loss(params, x, nudge):
+        monkeypatch.setattr(
+            moe_layer_module, "_route", lambda *a, **k: (
+                lambda r: r._replace(gate_weights=r.gate_weights
+                                     + nudge.reshape(-1, K)))(route(*a, **k)))
+        out = moe_layer(params, x, CONFIG, train=True)[0]
+        monkeypatch.setattr(moe_layer_module, "_route", route)
+        return jnp.sum(out * jnp.cos(jnp.arange(
+            out.size, dtype=jnp.float32)).reshape(out.shape))
+
+    nudge = jnp.zeros((B, S, K))
+    set_topology(MeshTopology(devices=jax.devices()[:1]))
+    want_dgates = np.asarray(jax.jit(jax.grad(loss, argnums=2))(
+        params, x, nudge)).reshape(-1, K)
+    _, (placed, xs) = four_wide(CONFIG, params, x)
+    dgates = np.asarray(jax.jit(jax.grad(loss, argnums=2))(
+        placed, xs, jax.device_put(nudge, xs.sharding))).reshape(-1, K)
+    assert np.abs(want_dgates[::3, 0]).min() > 0
+    np.testing.assert_allclose(dgates, want_dgates,
+                               atol=2e-5 * np.abs(want_dgates).max())
+
+
+@pytest.mark.parametrize("config", [
+    replace(CONFIG, top_k=1), replace(CONFIG, num_experts=4),
+    replace(CONFIG, num_experts=4, top_k=1)],
+    ids=["top_1", "an_expert_a_chip", "top_1_an_expert_a_chip"])
+def test_top_one_and_one_expert_a_chip_run(config, real_kernels):
+    """The same code where a token has one row anyway (``k = 1``) and
+    where a chip is an expert (``E / n = 1``): what it sent before."""
+    params, x = params_and_x(config)
+    (want, (want_out, want_stats)), want_grads = host(
+        on_one_device(config, params, x))
+    fn, args = four_wide(config, params, x)
+    (got, (out, stats)), grads = host(fn(*args))
+    assert int(stats["dispatched"]) == B * S * config.top_k
+    assert int(stats["dropped"]) == 0
+    np.testing.assert_allclose(out, want_out, atol=1e-5 * np.abs(
+        want_out).max())
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, atol=1e-5 * max(np.abs(w).max(),
+                                                          1e-30))
+
+
 def test_a_rematerialised_layer_runs_five_row_exchanges_and_three_of_gates():
     """The layer's gradient under ``jax.checkpoint`` as the CPU compiles
     it, the exchange by its stand-in (a call of ``mappings._ragged`` is one
@@ -509,10 +693,9 @@ def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
     ``ragged_all_to_all`` a layer where there were four — the gradient
     alone is asked for here, and no residual of the backward pass is a row
     that came back any more, so the forward's return is dead code before
-    XLA sees it — and two of gates; 16 slices a pair; and the sorts are
-    the sender's, of its own 65,536 routed elements (the plan's, and the
-    same turned round for the gates' way home) — the receiver sorts
-    nothing."""
+    XLA sees it — each of a row a (token, chip), 4 slices — and two of
+    lanes, 16 slices a pair; and the sorts are the sender's — the receiver
+    sorts nothing."""
     monkeypatch.setattr(mappings, "exchange_path",
                         lambda: mappings.RAGGED_ALL_TO_ALL)
     config = MoEConfig(d_model=2304, d_ff=896, num_experts=64, top_k=8,
@@ -538,13 +721,28 @@ def test_at_the_cells_shapes_no_sort_is_as_long_as_the_bound(monkeypatch):
     assert call["slices_per_pair"] == 16
     assert _ragged_widths(text) == 3 * [("bf16", 2304)] \
         + 2 * [("f32", LANES)]
-    # 64 slices a call: 16 for each of the four chips
-    assert len(re.findall(r"ragged_all_to_all.*tensor<64xi32>, "
-                          r"tensor<64xi32>, tensor<64xi32>, tensor<64xi32>",
-                          text)) == 5
+    assert call["row_unit"] == "token_chip"
+    assert call["landed_rows"] == 32768
+    assert call["wire_rows_bound"] == 3 * 8192
+    assert call["wire_bytes"] == 3 * 8192 * 2304 * 2
+    # a narrow call has 64 slices, 16 for each of the four chips; a
+    # row-wide one four, one a pair
+    slices = lambda n: len(re.findall(                      # noqa: E731
+        r"ragged_all_to_all.*" + ", ".join(4 * [f"tensor<{n}xi32>"]), text))
+    assert (slices(64), slices(4)) == (2, 3)
+    # no operand of a row-wide call is longer than a row a (token, chip)
+    # and a tile a chip: 33,280 rows where the parent sent 73,728
+    operands = [int(rows) for rows in re.findall(
+        r"@ragged_all_to_all\(.*?: \(tensor<(\d+)x(?:2x1152|2304)xbf16>",
+        text)]
+    assert len(operands) == 3 and max(operands) <= 32768 + 4 * 128, operands
     sorts = _sorts(text)
+    # the sender's alone: of its 32,768 (token, chip) elements and of its
+    # 65,536 routed ones (the plan's, and the same turned round for the
+    # gates' way home) — the receiver sorts nothing
     assert sorts and all(
-        re.fullmatch(r"tensor<65536xi32>(, tensor<65536xi32>)*", operands)
+        re.fullmatch(r"tensor<(65536|32768)xi32>"
+                     r"(, tensor<(65536|32768)xi32>)*", operands)
         for operands in sorts), sorts
     assert "196608xi32" not in "".join(sorts)
 
@@ -683,26 +881,45 @@ TABLES = {
 }
 
 
+def _choices(table, tokens, seed=5):
+    """[chips, tokens, E] bool: for each chip, tokens that chose expert
+    ``e`` ``table[chip, e]`` times in all, whichever they are."""
+    rng = np.random.default_rng(seed)
+    chosen = np.zeros(table.shape[:1] + (tokens,) + table.shape[1:], bool)
+    for j, counts in enumerate(table):
+        for e, count in enumerate(counts):
+            chosen[j, rng.permutation(tokens)[:count], e] = True
+    return chosen
+
+
 @pytest.mark.parametrize("case", sorted(TABLES))
 def test_every_slice_leaves_and_lands_where_the_table_says(case, monkeypatch):
     """The table every chip derives from one all-gather, against the same
-    written as loops: sender ``j``'s slice for expert ``e`` leaves ``j``'s
-    layout (a held plan of its own rows over all experts) where that
-    expert's group begins, and lands in the group of ``e`` in its chip's
-    layout behind the rows of the senders before ``j``; a chip's room goes
-    to the senders in their order and a pair's to its experts in theirs,
-    what passes it is cut and counted by its sender — the parent's count
-    (a pair's rows past ``bound`` less the rows of the senders before)."""
+    written as loops.  **The lanes**, a row a (token, expert): sender
+    ``j``'s slice for expert ``e`` leaves ``j``'s layout (a held plan of its
+    own routed elements over all experts) where that expert's group begins,
+    and lands in the group of ``e`` in its chip's layout behind the rows of
+    the senders before ``j``; a chip's room goes to the senders in their
+    order and a pair's to its experts in theirs, what passes it is cut and
+    counted by its sender — the parent's count (a pair's rows past
+    ``bound`` less the rows of the senders before).  **The rows**, one a
+    (token, chip): a pair's slice is the tokens that chose any expert of
+    the chip, once each — so what leaves a chip is the count of distinct
+    (token, other chip) pairs, never more than three times its tokens —
+    and lands in its sender's slot.  **The runs**: the plan rows of an
+    expert whose landed row lies in a block of the landing buffer."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     table, bound, bm = TABLES[case]
     monkeypatch.setattr(gg, "default_block_m", lambda: bm)
     n, E = table.shape
-    held, routed = E // n, int(table[0].sum())
+    held, routed, tokens, block = E // n, int(table[0].sum()), 12, 8
     assert (table.sum(1) == routed).all()
-    got = on_four(lambda counts: mappings.make_exchange_sizes(
-        counts, routed, bound), table)
+    chosen = _choices(table, tokens)
+    got = on_four(lambda chosen: mappings.make_exchange_sizes(
+        chosen, routed, bound, block), chosen)
     kept = np.zeros((n, n, held), np.int32)
     lands = np.zeros((n, n, held), np.int32)
+    groups = np.zeros((n, held), np.int32)
     for d in range(n):
         room = bound
         for j in range(n):
@@ -710,25 +927,60 @@ def test_every_slice_leaves_and_lands_where_the_table_says(case, monkeypatch):
                 kept[j, d, e] = min(room, table[j, held * d + e])
                 room -= kept[j, d, e]
         starts, sizes = layout(kept[:, d].sum(0), bound, bm)
+        groups[d] = starts
         for e in range(held):
             assert kept[:, d, e].sum() <= sizes[e]
             lands[:, d, e] = starts[e] + np.cumsum(kept[:, d, e]) \
                 - kept[:, d, e]
     leaves = np.array([layout(table[j], routed, bm)[0]
                        for j in range(n)]).reshape(n, n, held)
-    np.testing.assert_array_equal(got.send_at, leaves.reshape(n, -1))
-    np.testing.assert_array_equal(got.send, kept.reshape(n, -1))
-    np.testing.assert_array_equal(got.land_at, lands.reshape(n, -1))
+    np.testing.assert_array_equal(got.lanes.send_at, leaves.reshape(n, -1))
+    np.testing.assert_array_equal(got.lanes.send, kept.reshape(n, -1))
+    np.testing.assert_array_equal(got.lanes.land_at, lands.reshape(n, -1))
     by_receiver = lambda a: a.transpose(1, 0, 2).reshape(n, -1)  # noqa: E731
-    np.testing.assert_array_equal(got.held_at, by_receiver(lands))
-    np.testing.assert_array_equal(got.held, by_receiver(kept))
-    np.testing.assert_array_equal(got.home_at, by_receiver(leaves))
+    np.testing.assert_array_equal(got.lanes.held_at, by_receiver(lands))
+    np.testing.assert_array_equal(got.lanes.held, by_receiver(kept))
+    np.testing.assert_array_equal(got.lanes.home_at, by_receiver(leaves))
     np.testing.assert_array_equal(got.counts, kept.sum(0))
     pair = table.reshape(n, n, held).sum(-1)
     before = np.cumsum(pair, 0) - pair
     parents = (pair - np.clip(bound - before, 0, pair)).sum(1)
     np.testing.assert_array_equal(got.over.reshape(-1), parents)
     assert (parents.sum() > 0) == (case == "a_cut_at_the_bound")
+    # the rows: a token once a chip, in its sender's slot
+    to_chip = chosen.reshape(n, tokens, n, held).any(-1)   # [from, token, to]
+    crossing = to_chip.sum(1)                              # [from, to]
+    assert (crossing < table.reshape(n, n, held).sum(-1)).any()
+    leaving = np.array([layout(crossing[j], n * tokens, bm)[0]
+                        for j in range(n)])
+    np.testing.assert_array_equal(got.rows.send_at, leaving)
+    np.testing.assert_array_equal(got.rows.send, crossing)
+    np.testing.assert_array_equal(
+        got.rows.land_at, np.arange(n)[:, None] * tokens + np.zeros(n, int))
+    np.testing.assert_array_equal(
+        got.rows.held_at, np.zeros((n, 1), int) + np.arange(n) * tokens)
+    np.testing.assert_array_equal(got.rows.held, crossing.T)
+    np.testing.assert_array_equal(got.rows.home_at, leaving.T)
+    wire = crossing.sum(1) - np.diag(crossing)
+    assert (wire <= (n - 1) * tokens).all()
+    assert wire.sum() == sum(to_chip[j, :, d].sum() for j in range(n)
+                             for d in range(n) if d != j)
+    # the runs: an expert's plan rows by the block their landed row is in
+    blocks = -(-n * tokens // block)
+    for d in range(n):
+        for e in range(held):
+            where = []                  # landed rows of the group, in order
+            for j in range(n):
+                at = np.cumsum(to_chip[j, :, d]) - to_chip[j, :, d]
+                mine = at[chosen[j, :, held * d + e]][:kept[j, d, e]]
+                where += list(j * tokens + mine)
+            assert where == sorted(where)
+            upto = [int(np.sum(np.array(where, int) < b * block))
+                    for b in range(blocks + 1)]
+            np.testing.assert_array_equal(got.first[d][:, e],
+                                          groups[d, e] + np.array(upto[:-1]))
+            np.testing.assert_array_equal(got.end[d][:, e],
+                                          groups[d, e] + np.array(upto[1:]))
     # the receive plan from the counts is the held plan of those rows
     for d in range(n):
         experts = np.repeat(np.arange(held), kept[:, d].sum(0))
@@ -745,56 +997,77 @@ def test_every_slice_leaves_and_lands_where_the_table_says(case, monkeypatch):
                                           getattr(want, name), err_msg=name)
 
 
+#: tokens a chip whose two choices are drawn by these weights of the 8
+#: experts (two a chip): a chip's receive plan has room for 40 rows, the
+#: M-tile is 4
+DRAWS = {
+    "skewed": np.array([9, 4, 1, 2, 3, 3, 1, 1], float),
+    # nobody routes to experts 3 and 4: a tile each all the same
+    "an_empty_expert": np.array([4, 4, 4, 0, 0, 2, 1, 1], float),
+}
+
+
 @pytest.mark.parametrize("buffer", ["zeros", "nan"])
 @pytest.mark.parametrize("case", ["skewed", "an_empty_expert"])
 def test_the_receive_buffer_is_the_parents_held_plan_of_the_rows(
         case, buffer, monkeypatch):
-    """What the all-to-all leaves on a chip is, bit for bit **over the live
-    prefix** — the groups' padding rows among it, exact zeros — what the
-    parent built there in three steps: the rows as they arrived (by sender,
-    a sender's for this chip in its routed order), their experts' numbers
+    """What the way out leaves on a chip (``_rows_to_experts``: a row a
+    (token, chip) landed, then gathered into the plan by the places the
+    lanes brought) is, bit for bit **over the live prefix** — the groups'
+    padding rows among it, exact zeros — what the parent built there in
+    three steps: a row a (token, expert) as it arrived (by sender, a
+    sender's for this chip in its routed order), their experts' numbers
     beside them, then ``make_held_group_plan`` and ``dispatch_held_rows``
     over that buffer — kept here as the parent ran them.  Behind the prefix
     the buffer is nobody's (``nan``: born of NaN here, as a chip's is born
-    of whatever its memory held) and is not compared.  And the way back
-    puts every row where it came from, into zeros."""
+    of whatever its memory held) and is not compared.  And the way back —
+    the plan's rows summed by landed row, home, summed by token — gives
+    every token its own row once for each of its choices."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
-    table, bound, bm = TABLES[case]
+    bound, bm, tokens, width, n = 40, 4, 8, 8, 4
     monkeypatch.setattr(gg, "default_block_m", lambda: bm)
     if buffer == "nan":
         monkeypatch.setattr(gg, "_unwritten", _born_of_nan)
-    n, E = table.shape
-    held, routed, width = E // n, int(table[0].sum()), 8
+    held = E // n
     rng = np.random.default_rng(7)
-    eids = np.stack([rng.permutation(np.repeat(np.arange(E), table[j]))
-                     for j in range(n)]).astype(np.int32)
-    rows = rng.standard_normal((n, routed, width)).astype(np.float32)
+    weights = DRAWS[case] / DRAWS[case].sum()
+    eids = np.stack([[rng.choice(E, K, replace=False, p=weights)
+                      for _ in range(tokens)] for _ in range(n)]).astype(
+                          np.int32)                         # [n, tokens, K]
+    rows = rng.standard_normal((n, tokens, width)).astype(np.float32)
+    # a token whose two experts are one chip's crosses to it once
+    assert (eids[..., 0] // held == eids[..., 1] // held).any()
 
     def body(eids, rows):
-        mine, _ = gg.make_held_group_plan(eids, 0, E, routed)
-        sizes = mappings.make_exchange_sizes(mine.counts, routed, bound)
-        plan, _ = gg.make_counted_group_plan(sizes.counts, bound)
-        sent = gg.dispatch_held_rows(rows, mine, 1)
-        received = mappings.exchange_forth(sent, sizes, plan.padded_rows)
-        returned = mappings.exchange_back(received, sizes, mine.padded_rows)
-        return (received, gg.live_rows(plan), returned,
-                jnp.minimum(mine.padded_to_row, routed))
+        out = moe_layer_module._rows_to_experts(
+            rows, eids, jnp.ones(eids.shape, jnp.float32), E, bound)
+        runs = (out.sizes.first, out.sizes.end)
+        summed = gg.sum_into_landed_rows(out.x_pad, out.plan, out.source,
+                                         runs, n * tokens)
+        home = mappings.exchange_back(summed, out.sizes.rows,
+                                      out.by_chip.padded_rows)
+        return (out.x_pad, gg.live_rows(out.plan),
+                gg.sum_held_rows(home, out.by_chip, n),
+                jnp.sum(out.sizes.rows.send), out.lanes[:, 0])
 
     @jax.jit
     def parents(experts, arrived):
         plan, over = gg.make_held_group_plan(experts, 0, held, bound)
         return (gg.dispatch_held_rows(arrived, plan, 1), over,
-                gg.live_rows(plan))
+                gg.live_rows(plan), gg.dispatch_held_rows(
+                    jnp.ones((arrived.shape[0], 1)), plan, 1)[:, 0])
 
-    received, live, returned, element = on_four(body, eids, rows)
+    received, live, returned, sent, gates = on_four(body, eids, rows)
     set_topology(MeshTopology(devices=jax.devices()[:1]))
     for d in range(n):
-        here = [(eids[j] // held) == d for j in range(n)]
-        arrived = np.concatenate([rows[j][here[j]] for j in range(n)])
-        experts = np.concatenate([eids[j][here[j]] % held for j in range(n)])
+        here = [(eids[j] // held).reshape(-1) == d for j in range(n)]
+        arrived = np.concatenate([np.repeat(rows[j], K, axis=0)[here[j]]
+                                  for j in range(n)])
+        experts = np.concatenate([eids[j].reshape(-1)[here[j]] % held
+                                  for j in range(n)])
         assert len(arrived) <= bound
         pad = bound - len(arrived)
-        want, over, live_rows = parents(
+        want, over, live_rows, ones = parents(
             jnp.asarray(np.concatenate([experts, np.full(pad, held)]),
                         jnp.int32),
             jnp.asarray(np.concatenate(
@@ -802,11 +1075,18 @@ def test_the_receive_buffer_is_the_parents_held_plan_of_the_rows(
         assert int(over) == 0 and int(live_rows) == live[d]
         np.testing.assert_array_equal(received[d][:live[d]],
                                       np.asarray(want)[:live[d]])
-        behind = received[d][live[d]:]
+        # a gate a row that arrived, zero on a group's padding rows
+        np.testing.assert_array_equal(gates[d][:live[d]],
+                                      np.asarray(ones)[:live[d]])
+        # (the lanes land as the parent's rows did, in a buffer of the
+        # plan's length that the collective alone writes)
+        behind = gates[d][live[d]:]
         assert np.isnan(behind).all() if buffer == "nan" else not behind.any()
-        # back at the sender: its own rows at their places, zeros on padding
-        own = np.concatenate([rows[d], np.zeros((1, width), np.float32)])
-        np.testing.assert_array_equal(returned[d], own[element[d]])
+        # fewer rows left the chip than it routed: distinct (token, chip)
+        pairs = {(t, c) for t in range(tokens) for c in eids[d, t] // held}
+        assert sent[d] == len(pairs) < tokens * K
+        # back at the sender: a token's row once a choice, summed
+        np.testing.assert_allclose(returned[d], K * rows[d], rtol=1e-6)
 
 
 def _born_of_nan(shape, dtype, after, what):
