@@ -14,10 +14,15 @@ paths, selected by ``impl``:
   multiplied, so cost scales with layout density — the long-sequence path.
 """
 import random
+from dataclasses import dataclass
 from typing import List, Optional
 
+import jax
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
+
+from deepspeed_tpu.telemetry.tracing import count_in_step
 
 NEG_INF = -1e30
 
@@ -286,3 +291,352 @@ class SparseSelfAttention:
         return sparse_self_attention(query, key, value,
                                      self.sparsity_config, causal=causal,
                                      impl=self.impl)
+
+
+# ------------------------------------------------ blocks a query picks itself
+# InfLLM-V2 (MiniCPM4: arXiv:2506.07900; arXiv:2509.24663), the trainable
+# sparse attention of models/minicpm_sala.py.  Above, a layout is a numpy
+# constant of the configuration; here it is data: every query scores pooled
+# keys, the scores are max-pooled to key blocks, and the ``topk`` highest
+# blocks (with the first and the nearest always among them) are the keys the
+# query's softmax runs over.  The choice is per query token and per
+# key/value head (the query heads of a group vote with the sum of their
+# softmaxes), has no parameter and carries no gradient.
+@dataclass(frozen=True)
+class BlockSelection:
+    """The selection's seven numbers (positions are counted from a
+    document's first token)."""
+    #: keys of one block: positions ``[block_size * b, block_size * (b+1))``
+    block_size: int = 64
+    #: a pooled key is the mean of ``kernel_size`` keys, one every
+    #: ``kernel_stride`` positions while the window lies inside the document
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    #: blocks a query keeps (a query with no more causal blocks keeps all)
+    topk: int = 64
+    #: the first ``init_blocks`` blocks and the blocks of the last
+    #: ``window_size`` positions up to the query's own are always kept
+    init_blocks: int = 1
+    window_size: int = 2048
+    #: every query of a document shorter than this keeps every causal block
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        if self.kernel_size % self.kernel_stride \
+                or self.block_size % self.kernel_stride \
+                or self.window_size % self.block_size:
+            raise ValueError(
+                f"block selection: kernel_size {self.kernel_size} and "
+                f"block_size {self.block_size} must be multiples of "
+                f"kernel_stride {self.kernel_stride}, window_size "
+                f"{self.window_size} of block_size")
+        if self.init_blocks + self.local_blocks > self.topk:
+            raise ValueError(
+                f"block selection: {self.init_blocks} first and "
+                f"{self.local_blocks} nearest blocks are always kept, more "
+                f"than topk {self.topk}")
+
+    @property
+    def local_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+    @property
+    def windows_per_block(self) -> int:
+        return self.block_size // self.kernel_stride
+
+    @property
+    def reach(self) -> int:
+        """Pooling windows that start before a block and end inside it."""
+        return self.kernel_size // self.kernel_stride - 1
+
+
+def _documents(segment_ids, S):
+    """(first position, one past the last position) of each token's
+    document, [b, S] int32 each, from ``segment_ids`` [b, S] whose
+    documents are runs."""
+    idx = jnp.arange(S, dtype=jnp.int32)
+    changes = segment_ids[:, 1:] != segment_ids[:, :-1]
+    edge = jnp.ones((segment_ids.shape[0], 1), bool)
+    first = jnp.concatenate([edge, changes], axis=1)
+    last = jnp.concatenate([changes, edge], axis=1)
+    start = lax.cummax(jnp.where(first, idx, 0), axis=1)
+    end = lax.cummin(jnp.where(last, idx + 1, S), axis=1, reverse=True)
+    return start, end
+
+
+def _shifted(x, n, axis):
+    """``x[i + n]`` along ``axis``, zero where ``i + n`` is outside."""
+    if n == 0:
+        return x
+    size = x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (max(-n, 0), max(n, 0))
+    return lax.slice_in_dim(jnp.pad(x, pad), max(n, 0), max(n, 0) + size,
+                            axis=axis)
+
+
+def _sliding_sum(x, width, axis=1):
+    """``sum(x[i : i + width])`` at every ``i`` along ``axis`` (zeros past
+    the end), by doubling: log2(width) shifted sums."""
+    out, offset, span = None, 0, 1
+    while width:
+        if width & 1:
+            part = _shifted(x, offset, axis)
+            out = part if out is None else out + part
+            offset += span
+        x = x + _shifted(x, span, axis)
+        span, width = 2 * span, width >> 1
+    return out
+
+
+def _geometry(segment_ids, S, sel: BlockSelection):
+    """What the selection and the attention both need of a packed row,
+    [b, S] int32 each: a token's position in its document, its document's
+    length, and ``c0``, the *column* of its document's block 0.  A column
+    is a block index shifted so that one static axis of ``S / block_size``
+    columns holds every document's blocks side by side: block ``b`` of a
+    document that starts at ``a`` is column ``b + a // block_size``
+    (two documents may share a column at their boundary; the document
+    itself tells them apart)."""
+    start, end = _documents(segment_ids, S)
+    idx = jnp.arange(S, dtype=jnp.int32)
+    slot0 = start // sel.kernel_stride
+    return {"pos": idx - start, "len": end - start, "start": start,
+            "end": end, "c0": slot0 // sel.windows_per_block,
+            "lane": slot0 % sel.windows_per_block}
+
+
+def select_blocks(q, k, segment_ids=None, sel: BlockSelection = None,
+                  query_chunk: int = 512):
+    """The key blocks each query keeps: ``(blocks [b, G, S, topk] int32,
+    count [b, G, S] int32)`` — ``blocks`` the kept blocks' indices inside
+    the query's document, ascending, ``-1`` where a query has fewer causal
+    blocks than ``topk``; ``count`` how many are kept.  ``q`` [b, S, H,
+    hd], ``k`` [b, S, G, hd] (``H`` a multiple of ``G``), after any norm
+    and rotation the attention itself would see.  No gradient flows.
+
+    Per document, positions from its first token (``sel``'s numbers):
+
+    1. pooled keys ``K_j = mean(k[stride * j : stride * j + kernel])``
+       while the window lies inside the document;
+    2. ``p[h, t, :] = softmax_j(q[h, t] . K_j / sqrt(hd))`` over the
+       windows that end at or before ``t``; ``a[g, t, j]`` its sum over the
+       query heads of key/value head ``g``;
+    3. block ``b``'s score is the maximum of ``a`` over the windows that
+       touch it;
+    4. the first ``init_blocks`` blocks and the ``window_size /
+       block_size`` blocks that end with the query's own score +inf; the
+       ``topk`` highest causal blocks are kept, the lower index on a tie.
+
+    Scores, softmax and top-k are float32 (the products at
+    ``Precision.HIGHEST``).  ``dense_len`` is not applied here: the
+    choice is made for every query, and :func:`selected_attention` keeps
+    every causal block of a short document whatever was chosen.
+
+    The lowering works on static axes: pooled keys live in ``S / stride``
+    *slots* (window ``j`` of a document that starts at ``a`` is slot ``a
+    // stride + j``; documents never share a slot), blocks in ``S /
+    block_size`` columns (:func:`_geometry`).  ``S`` must be a multiple
+    of ``block_size``."""
+    sel = sel or BlockSelection()
+    b, S, H, hd = q.shape
+    G = k.shape[2]
+    st, ks, per = sel.kernel_stride, sel.kernel_size, sel.windows_per_block
+    if S % sel.block_size:
+        raise ValueError(f"select_blocks: S {S} is not a multiple of "
+                         f"block_size {sel.block_size}")
+    U, C = S // st, S // sel.block_size
+    K = min(sel.topk, C)
+    seg = (jnp.zeros((b, S), jnp.int32) if segment_ids is None
+           else segment_ids.astype(jnp.int32))
+    q, k = lax.stop_gradient((q, k))
+    geo = _geometry(seg, S, sel)
+
+    # -- step 1: a slot's window, read at the last position its start can be
+    probe = jnp.arange(U, dtype=jnp.int32) * st + (st - 1)
+    at = lambda t: t[:, probe]                               # [b, U]
+    w_start = probe - (st - 1) + at(geo["start"]) % st
+    w_ok = (w_start >= at(geo["start"])) & (w_start + ks <= at(geo["end"]))
+    w_seg = at(seg)
+    sums = _sliding_sum(k.astype(jnp.float32), ks)           # [b, S, G, hd]
+    pooled = jnp.take_along_axis(
+        sums, w_start[:, :, None, None], axis=1) / ks        # [b, U, G, hd]
+
+    Qs = query_chunk if S % query_chunk == 0 else S
+    col = jnp.arange(C, dtype=jnp.int32)
+
+    def some_queries(xs):
+        qc, t, seg_q, pos, c0, lane = xs
+        # -- step 2
+        s = jnp.einsum("bqgrd,bugd->bgrqu",
+                       qc.astype(jnp.float32).reshape(b, Qs, G, H // G, hd),
+                       pooled, precision=lax.Precision.HIGHEST) * hd ** -0.5
+        seen = (w_ok[:, None] & (w_seg[:, None] == seg_q[:, :, None])
+                & (w_start[:, None] + (ks - 1) <= t[None, :, None]))
+        seen = seen[:, None, None]                           # [b,1,1,Qs,U]
+        top = jnp.max(jnp.where(seen, s, -jnp.inf), axis=-1, keepdims=True)
+        e = jnp.where(seen, jnp.exp(s - jnp.where(jnp.isfinite(top), top,
+                                                  0.0)), 0.0)
+        den = jnp.sum(e, axis=-1, keepdims=True)
+        a = jnp.sum(e / jnp.where(den > 0, den, 1.0), axis=2)  # [b,G,Qs,U]
+        # -- step 3: the windows reach .. per - 1 around a block's first,
+        # then the slots that are a block's first for this query's document
+        best = a
+        for o in range(-sel.reach, per):
+            if o:
+                best = jnp.maximum(best, _shifted(a, o, 3))
+        best = best.reshape(b, G, Qs, C, per)
+        mine = jnp.arange(per) == lane[:, None, :, None, None]
+        score = jnp.max(jnp.where(mine, best, 0.0), axis=-1)  # [b,G,Qs,C]
+        # -- step 4
+        own = (c0 + pos // sel.block_size)[:, None, :, None]
+        first = c0[:, None, :, None]
+        causal = (col >= first) & (col <= own)
+        forced = (col < first + sel.init_blocks) \
+            | (col > own - sel.local_blocks)
+        score = jnp.where(causal, jnp.where(forced, jnp.inf, score),
+                          -jnp.inf)
+        value, column = lax.top_k(score, K)
+        kept = value > -jnp.inf
+        block = jnp.sort(jnp.where(kept, column - first, C), axis=-1)
+        return (jnp.where(block < C, block, -1),
+                jnp.sum(kept, axis=-1, dtype=jnp.int32))
+
+    by_chunk = lambda t: jnp.moveaxis(
+        t.reshape((b, S // Qs, Qs) + t.shape[2:]), 1, 0)
+    blocks, count = lax.map(some_queries, (
+        by_chunk(q), jnp.arange(S, dtype=jnp.int32).reshape(-1, Qs),
+        by_chunk(seg), by_chunk(geo["pos"]), by_chunk(geo["c0"]),
+        by_chunk(geo["lane"])))
+    blocks = jnp.moveaxis(blocks, 0, 2).reshape(b, G, S, K)
+    count = jnp.moveaxis(count, 0, 2).reshape(b, G, S)
+    if K < sel.topk:
+        blocks = jnp.pad(blocks, ((0, 0),) * 3 + ((0, sel.topk - K),),
+                         constant_values=-1)
+    return blocks, count
+
+
+def _spans(S, query_chunk, key_spans):
+    """(queries scored at a time, spans the sequence is walked in): the
+    caller's where they divide ``S``, else one span of one chunk."""
+    if S % (query_chunk * key_spans):
+        return S, 1
+    return query_chunk, key_spans
+
+
+def visited_keys_per_query(S, query_chunk=128, key_spans=4):
+    """Keys :func:`selected_attention` multiplies a query by, a mean over a
+    sequence's queries: the queries of span ``i`` of ``n`` see the keys
+    ``[0, (i + 1) S / n)`` under their mask, whatever was selected."""
+    _, n = _spans(S, query_chunk, key_spans)
+    return S * (n + 1) / (2.0 * n)
+
+
+def selected_attention(q, k, v, blocks, segment_ids=None,
+                       sel: BlockSelection = None, query_chunk: int = 128,
+                       key_spans: int = 4):
+    """Softmax attention of each query over the keys ``s <= t`` of its own
+    document's kept blocks: ``q`` [b, S, H, hd], ``k``, ``v`` [b, S, G,
+    hd], ``blocks`` [b, G, S, topk] as :func:`select_blocks` returns them
+    -> [b, S, H, hd] in ``q``'s dtype.  Every causal block of a document
+    shorter than ``sel.dense_len`` is kept whatever ``blocks`` says.
+    Differentiable in ``q``, ``k`` and ``v``; products take their operands
+    in ``q``'s dtype and accumulate in float32, the softmax is float32.
+
+    The lowering (``masked_chunks``): all the keys a query could see, under
+    a per-(token, block) mask — ``query_chunk`` queries at a time against
+    every key of their span, the sequence walked in ``key_spans`` spans of
+    growing key length (so a query is multiplied by
+    :func:`visited_keys_per_query` keys, not by ``S``), each chunk
+    rematerialised for its gradient.  The mask of a chunk is one small
+    product: the kept columns of its queries [G * chunk, columns] against
+    which column each key lies in [columns, keys].  Nothing is skipped for
+    being unselected: the step's device time does not depend on the data.
+    A kernel that visits only the kept blocks is not built (ROADMAP)."""
+    sel = sel or BlockSelection()
+    b, S, H, hd = q.shape
+    G = k.shape[2]
+    dtype = q.dtype
+    C = -(-S // sel.block_size)
+    seg = (jnp.zeros((b, S), jnp.int32) if segment_ids is None
+           else segment_ids.astype(jnp.int32))
+    geo = _geometry(seg, S, sel)
+    key_col = geo["c0"] + geo["pos"] // sel.block_size          # [b, S]
+    dense = geo["len"] < sel.dense_len
+    col = jnp.arange(C, dtype=jnp.int32)
+    Qc, n_spans = _spans(S, query_chunk, key_spans)
+    count_in_step(sparse_attention_calls={f"{b}x{S}x{H}x{G}x{hd}": {
+        "batch": b, "seq_len": S, "heads": H, "kv_heads": G, "head_dim": hd,
+        **{f"sparse/{f}": getattr(sel, f)
+           for f in sel.__dataclass_fields__},
+        "sparse/visited_keys_per_query": visited_keys_per_query(
+            S, query_chunk, key_spans),
+        "query_chunk": Qc, "key_spans": n_spans,
+        "lowering": "masked_chunks"}})
+    # float32 outside the chunks' loop: its transpose sums their cotangents
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    by_chunk = lambda t, n: jnp.moveaxis(
+        t.reshape(t.shape[:n] + (-1, Qc) + t.shape[n + 1:]), n, 0)
+
+    def span(first, last):
+        """Queries ``[first, last)`` over the keys ``[0, last)``."""
+        in_col = (key_col[:, :last, None] == col).astype(dtype)  # [b,Sk,C]
+        seg_k, at = seg[:, :last], jnp.arange(last, dtype=jnp.int32)
+
+        @jax.checkpoint
+        def some_queries(xs):
+            qc, blk, t, seg_q, c0, dense_q = xs
+            column = jnp.where(blk >= 0, blk + c0[:, None, :, None], -1)
+            kept = jnp.any(column[..., None] == col, axis=-2) \
+                | dense_q[:, None, :, None]                      # [b,G,Qc,C]
+            seen = jnp.einsum("bgqc,bkc->bgqk", kept.astype(dtype),
+                              in_col) > 0.5
+            seen &= (seg_q[:, None, :, None] == seg_k[:, None, None, :]) \
+                & (t[None, None, :, None] >= at)
+            s = jnp.einsum("bqgrd,bkgd->bgrqk",
+                           qc.reshape(b, Qc, G, H // G, hd),
+                           k32[:, :last].astype(dtype),
+                           preferred_element_type=jnp.float32) * hd ** -0.5
+            p = jax.nn.softmax(jnp.where(seen[:, :, None], s, -jnp.inf),
+                               axis=-1)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(dtype),
+                           v32[:, :last].astype(dtype),
+                           preferred_element_type=jnp.float32)
+            return o.reshape(b, Qc, H, hd).astype(dtype)
+
+        cut = lambda t, axis: lax.slice_in_dim(t, first, last, axis=axis)
+        out = lax.map(some_queries, (
+            by_chunk(cut(q, 1), 1), by_chunk(cut(blocks, 2), 2),
+            jnp.arange(first, last, dtype=jnp.int32).reshape(-1, Qc),
+            by_chunk(cut(seg, 1), 1), by_chunk(cut(geo["c0"], 1), 1),
+            by_chunk(cut(dense, 1), 1)))
+        return jnp.moveaxis(out, 0, 1).reshape(b, last - first, H, hd)
+
+    width = S // n_spans
+    return jnp.concatenate([span(i * width, (i + 1) * width)
+                            for i in range(n_spans)], axis=1)
+
+
+def selection_counts(blocks, count, segment_ids=None,
+                     sel: BlockSelection = None):
+    """What a selection adds up to, from its data (a diagnostic, outside
+    the step): ``sparse/selected_blocks_per_query`` (the blocks a query
+    attends over, a document under ``dense_len`` keeping all its causal
+    ones), ``sparse/required_keys_per_query`` (the keys ``s <= t`` inside
+    them: what step 5 of the equations multiplies), both means over
+    queries and key/value heads, and ``sparse/dense_documents``, the share
+    of queries whose document is under ``dense_len``."""
+    sel = sel or BlockSelection()
+    b, G, S, _ = blocks.shape
+    seg = (jnp.zeros((b, S), jnp.int32) if segment_ids is None
+           else segment_ids.astype(jnp.int32))
+    geo = _geometry(seg, S, sel)
+    own = geo["pos"] // sel.block_size
+    dense = geo["len"] < sel.dense_len
+    kept = jnp.where(dense[:, None], own[:, None] + 1, count)
+    # every kept block is whole but the query's own
+    keys = (kept - 1) * sel.block_size \
+        + (geo["pos"] % sel.block_size + 1)[:, None]
+    return {"sparse/selected_blocks_per_query": jnp.mean(kept * 1.0),
+            "sparse/required_keys_per_query": jnp.mean(keys * 1.0),
+            "sparse/dense_documents": jnp.mean(dense * 1.0)}

@@ -51,6 +51,11 @@ registers and never written to HBM, the backward is written by hand.
 Elsewhere :func:`_chunked_xla`, einsums around a ``lax.scan`` with
 autodiff's backward — the fallback and, beside :func:`ssd_recurrent`, the
 kernels' oracle.
+
+:func:`lightning_attention` (models/minicpm_sala.py) is the same recurrence
+with nothing selective left in it — a step of 1, a constant decay a head,
+``B = k``, ``C = q``, one group a head — and calls :func:`ssd_scan`: the
+two families share one function and, on a TPU, one pair of kernels.
 """
 import jax.numpy as jnp
 from jax import lax
@@ -210,3 +215,35 @@ def ssd_recurrent(x, dt, A, B, C, D=None, segment_ids=None):
     if D is not None:
         y = y + f32(D)[:, None] * f32(x)
     return y.astype(x.dtype)
+
+
+def lightning_slopes(heads: int):
+    """The decay rates of Lightning attention, one a head: ``s_h = 2^(-8 h
+    / heads)``, h = 1..heads (ALiBi's slopes, as Lightning Attention-2,
+    arXiv:2401.04658, builds them); a head's state decays by ``exp(-s_h)``
+    a token."""
+    return 2.0 ** (-8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32)
+                   / heads)
+
+
+def lightning_attention(q, k, v, slopes, segment_ids=None,
+                        chunk: int = DEFAULT_CHUNK, interpret=None):
+    """Lightning attention (Qin et al. 2024, arXiv:2401.04658): linear
+    attention with a constant decay a head and no gate.  Per head, with a
+    float32 state ``S`` [dk, dv], zero at a document's first token:
+
+        S_t = exp(-slopes[h]) S_{t-1} + k_t^T v_t        o_t = q_t S_t
+
+    ``q``, ``k`` [b, S, H, dk] (any scale already in ``q``), ``v`` [b, S,
+    H, dv], ``slopes`` [H] > 0 -> ``o`` [b, S, H, dv] in ``v``'s dtype.
+    This IS the recurrence at the top of this file with a step of 1, ``A =
+    -slopes``, ``B = k``, ``C = q`` and one group a head, so it runs
+    through :func:`ssd_scan` — its Mosaic kernels on one TPU, the XLA form
+    elsewhere — and adds nothing of its own: the step's account holds the
+    call as the row of ``tracing.ssd_chunks()`` whose ``groups`` equal its
+    ``heads`` (``chunks`` is the count of Lightning chunks).  The slopes
+    are constants: no gradient flows to them."""
+    b, S, H, _ = q.shape
+    return ssd_scan(v, jnp.ones((b, S, H), jnp.float32),
+                    -lax.stop_gradient(slopes.astype(jnp.float32)), k, q,
+                    None, segment_ids, chunk=chunk, interpret=interpret)

@@ -152,10 +152,13 @@ class MemoryLedger:
 
     Writers (``set_bytes``/``add_bytes``/``record_alloc_failure``) take
     the ledger lock; every read path copies dicts under the GIL — no
-    reader can deadlock on a wedged writer."""
+    reader can deadlock on a wedged writer.  The lock is reentrant: a
+    finalizer that writes the ledger (``SwapEngine.__del__`` closes and
+    accounts) runs wherever the cyclic collector does, which may be this
+    thread inside a write."""
 
     def __init__(self, max_failures: int = DEFAULT_MAX_FAILURES):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         #: (tier, owner) -> live bytes
         self._owners: Dict[tuple, float] = {}
         #: (tier, owner) -> caller-supplied detail dict
@@ -181,7 +184,8 @@ class MemoryLedger:
             self._detail[key] = dict(detail)
         if v > self._owner_peak.get(key, 0.0):
             self._owner_peak[key] = v
-        total = sum(b for (t, _), b in self._owners.items()
+        # a copy: a finalizer's write may add an owner while this sums
+        total = sum(b for (t, _), b in list(self._owners.items())
                     if t == tier)
         if total > self._tier_peak.get(tier, 0.0):
             self._tier_peak[tier] = total

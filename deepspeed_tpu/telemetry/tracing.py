@@ -401,6 +401,20 @@ SCOPE_HC = "hc"
 SCOPE_HC_COEFF = "coeff"
 SCOPE_HC_READ = "read"
 SCOPE_HC_WRITE = "write"
+# inside ``ds.block``, where the mixer is attention over the key blocks
+# each query picks for itself, or Lightning linear attention
+# (models/minicpm_sala.py).  ``sparse_attn`` holds the norm, ``qkv`` (the
+# three products and the norms a head of q and k), ``select`` (pooled
+# keys, scores, the max-pool to blocks and the top-k: no gradient),
+# ``attend`` (softmax attention over the kept blocks' keys) and
+# ``out_proj`` (the output gate, the product, the residual);
+# ``lightning`` holds ``in_proj``, ``rope``, ``scan`` (the recurrence:
+# ops/state_space.py ``lightning_attention``), ``gate_norm`` and
+# ``out_proj``
+SCOPE_SPARSE_ATTN = "sparse_attn"
+SCOPE_SELECT = "select"
+SCOPE_ATTEND = "attend"
+SCOPE_LIGHTNING = "lightning"
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -867,8 +881,28 @@ def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     ``ds_ssd_bwd`` (then also ``heads_per_step`` and ``chunks_per_step``,
     the heads — one group's — and chunks one grid step takes), ``"xla"``
     where it fell back to the chunked form as XLA einsums around a
-    ``lax.scan``.  None where the step has no such call."""
+    ``lax.scan``.  A row whose ``groups`` equal its ``heads`` is a
+    Lightning-attention call (``lightning_attention``: one group a head),
+    and its ``chunks`` the count of Lightning chunks.  None where the step
+    has no such call."""
     return _account_rows(name, "ssd_calls")
+
+
+def sparse_attention_calls(name: str = TRAIN_STEP_PROGRAM):
+    """The selected-block attention calls of the step as
+    ops/sparse_attention.py traced them: one row per shape — ``batch``,
+    ``seq_len``, ``heads``, ``kv_heads``, ``head_dim``, the selection's
+    seven numbers, ``sparse/visited_keys_per_query`` (the keys the
+    lowering multiplies a query by, a mean over the sequence's queries:
+    static, whatever was selected), ``query_chunk`` and ``key_spans`` (the
+    queries scored at a time, and in how many spans of growing key length
+    the sequence is walked) and ``lowering`` (``"masked_chunks"``: every
+    key of a span under a per-(token, block) mask).  What depends on the
+    data — the blocks kept, the keys required, the queries of documents
+    under ``dense_len`` — is no shape and is not here:
+    ``ops.sparse_attention.selection_counts``.  None where the step has
+    no such call."""
+    return _account_rows(name, "sparse_attention_calls")
 
 
 def selective_scan_calls(name: str = TRAIN_STEP_PROGRAM):

@@ -127,6 +127,19 @@ def _reset_topology():
     reset_topology()
 
 
+@pytest.fixture(autouse=True)
+def _no_leaked_moe_tap():
+    """A serving scheduler installs its registry as the expert layers'
+    routing tap when it is built — a global of moe/layer.py that nobody
+    takes out (ROADMAP D28) — and a train step traced while one is
+    installed holds host callbacks and is traced again at every call.  No
+    test starts with, or leaves behind, another test's tap."""
+    from deepspeed_tpu.moe.layer import set_moe_metrics_registry
+    set_moe_metrics_registry(None)
+    yield
+    set_moe_metrics_registry(None)
+
+
 @pytest.fixture
 def interpret_pallas(monkeypatch):
     """Run every Pallas kernel in interpret mode (the CPU has no Mosaic)."""

@@ -230,6 +230,14 @@ _SSD_8K = [((2, 8192, 64, 64), jnp.bfloat16), ((2, 8192, 64), jnp.float32),
            ((64,), jnp.float32), ((2, 8192, 8, 128), jnp.bfloat16),
            ((2, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32),
            ((2, 8192), jnp.int32)]
+# minicpm-sala.packed-s16384-longdocs: Lightning attention is the same scan
+# at one group a head — 32 heads of 128 with a state of 128 (v, a step of
+# 1, the slopes, k, q)
+_SSD_LIGHTNING_16K = [
+    ((1, 16384, 32, 128), jnp.bfloat16), ((1, 16384, 32), jnp.float32),
+    ((32,), jnp.float32), ((1, 16384, 32, 128), jnp.bfloat16),
+    ((1, 16384, 32, 128), jnp.bfloat16), ((32,), jnp.float32),
+    ((1, 16384), jnp.int32)]
 # joyai-llm-flash.packed-s8192-gas2: latent attention, 32 heads (no
 # grouping), a score head of 128 + 64 = 192 = 1.5 lane tiles and a value
 # head of 128: v, o, do and dv are 128 wide in HBM, nothing padded to 192
@@ -334,6 +342,8 @@ KERNEL_CASES = {
     "ds_ssd_s8192_packed_fwd": (_ssd, _SSD_8K),
     "ds_ssd_s8192_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_8K),
+    "ds_ssd_s16384_one_head_a_group_fwd_bwd": (
+        jax.grad(_sum_sq(_ssd), (0, 3, 4)), _SSD_LIGHTNING_16K),
     "ds_conv_sublanes_s8192_packed_fwd": (_conv("sublanes"),
                                           _conv_args(8192, 8192)),
     "ds_conv_sublanes_s8192_packed_part_fwd_bwd": (
@@ -403,6 +413,7 @@ NAMED_KERNELS = {
     "ds_kda_s16384_packed_fwd_bwd": {"ds_kda_fwd", "ds_kda_bwd"},
     "ds_ssd_s8192_packed_fwd": {"ds_ssd_fwd"},
     "ds_ssd_s8192_packed_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
+    "ds_ssd_s16384_one_head_a_group_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
     "ds_conv_sublanes_s8192_packed_fwd": {"ds_conv_fwd"},
     "ds_conv_sublanes_s8192_packed_part_fwd_bwd": {"ds_conv_fwd",
                                                    "ds_conv_bwd"},

@@ -53,8 +53,7 @@ GAS, B, S, DOCS = 2, 2, 48, 4
 
 
 @pytest.fixture(autouse=True)
-def _isolation(monkeypatch):
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
+def _isolation():
     tracing.reset_programs()
     yield
     tracing.reset_programs()

@@ -22,7 +22,6 @@ import pytest
 from deepspeed_tpu.models import joyai, kimi_linear
 from deepspeed_tpu.models.kimi_linear import (KDA, MLA, KimiLinearConfig,
                                               kimi_linear_model)
-from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.telemetry import tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -45,8 +44,7 @@ GAS, B, S, DOCS = 2, 2, 48, 4
 
 
 @pytest.fixture(autouse=True)
-def _isolation(monkeypatch):
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
+def _isolation():
     tracing.reset_programs()
     yield
     tracing.reset_programs()

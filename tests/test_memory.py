@@ -117,6 +117,34 @@ def test_watermark_monotonicity():
     assert led.snapshot()["tiers"]["device"]["watermark_bytes"] == 1200
 
 
+def test_a_finalizer_may_write_the_ledger_inside_a_write():
+    """``SwapEngine.__del__`` closes and accounts, and the cyclic
+    collector runs it wherever an allocation tips it over — on this
+    thread inside ``set_bytes``, where a plain lock deadlocked (the
+    whole suite hung in tests/test_offload.py, PR 64)."""
+    led = MemoryLedger()
+
+    class Engine:
+        def __del__(self):
+            led.set_bytes("nvme", "gone", 0)
+
+    store = led._store_locked
+
+    def collecting(key, v, detail):
+        if key[1] == "live":
+            Engine()                    # dropped at once: finalized here
+        return store(key, v, detail)
+
+    led._store_locked = collecting
+    writer = threading.Thread(
+        target=lambda: led.set_bytes("host", "live", 5), daemon=True)
+    writer.start()
+    writer.join(timeout=10)
+    assert not writer.is_alive(), "the ledger's write waits for itself"
+    assert led._owners[("host", "live")] == 5
+    assert led._owners[("nvme", "gone")] == 0
+
+
 def test_alloc_failure_snapshot_ring_and_flightrec():
     led = MemoryLedger(max_failures=4)
     fr = FlightRecorder(64)

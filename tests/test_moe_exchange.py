@@ -48,8 +48,7 @@ CONFIG = MoEConfig(d_model=D, d_ff=F, num_experts=E, top_k=K,
 
 
 @pytest.fixture(autouse=True)
-def _isolation(monkeypatch):
-    monkeypatch.setattr(moe_layer_module, "_metrics_registry", None)
+def _isolation():
     tracing.reset_programs()
     yield
     reset_topology()

@@ -60,7 +60,6 @@ GAS, B, S, DOCS = 2, 2, 72, 4
 @pytest.fixture(autouse=True)
 def real_kernels(monkeypatch):
     monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
     tracing.reset_programs()
     yield
     tracing.reset_programs()

@@ -50,9 +50,6 @@ GAS, B, S, DOCS = 2, 2, 64, 3
 @pytest.fixture(autouse=True)
 def _real_kernels(monkeypatch):
     monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
-    # a scheduler built by an earlier test of this worker leaves its
-    # registry installed, and the router-health callback with it
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
     tracing.reset_programs()
     yield
     tracing.reset_programs()

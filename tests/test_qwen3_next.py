@@ -29,7 +29,6 @@ from deepspeed_tpu.models.mixtral import mixtral_model
 from deepspeed_tpu.models.model import param_stream_scope
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig, count_params,
                                              qwen3_next_model)
-from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.telemetry import tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,7 +53,6 @@ GAS, B, S, DOCS = 2, 2, 72, 4
 @pytest.fixture(autouse=True)
 def real_kernels(monkeypatch):
     monkeypatch.setenv("DS_GGEMM_INTERPRET", "1")
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
     tracing.reset_programs()
     yield
     tracing.reset_programs()

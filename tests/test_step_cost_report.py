@@ -14,7 +14,6 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models.mixtral import mixtral_model
-from deepspeed_tpu.moe import layer as moe_layer
 from deepspeed_tpu.resilience.postmortem import write_postmortem
 from deepspeed_tpu.telemetry import MetricsRegistry, costmodel, tracing
 from deepspeed_tpu.telemetry.debug import perf_payload
@@ -30,12 +29,6 @@ def nothing_asked(monkeypatch):
     # rates resolve on the CPU, so a floor (and a ratio to it) could
     monkeypatch.setenv("DS_PEAK_FLOPS", "1e12")
     monkeypatch.setenv("DS_HBM_GBPS", "819")
-    # a routing tap an earlier file of this worker left installed (a
-    # serving scheduler wires its registry in and nobody takes it out)
-    # puts host callbacks into the expert layers, and a step that holds
-    # one is traced again at every call: the [moe] cases then count three
-    # traces of a step where one ran (the driver's six-worker run of PR 59)
-    monkeypatch.setattr(moe_layer, "_metrics_registry", None)
     tracing.reset_programs()
     costmodel.reset_reports()
     yield
