@@ -72,7 +72,9 @@ activations are 0.5 GiB each.
 
 Not built: serving (a recurrent state a Lightning layer beside a key/value
 cache and a cache of pooled keys and selections for the sparse layers — the
-entry points raise); a kernel that visits only the kept blocks; ZeRO-3 and
+entry points raise); a kernel that visits only the kept blocks (the attend
+stage's kernels, ops/pallas/selected_attention.py, mask them on the tile and
+leave out what causality does); ZeRO-3 and
 parameter streaming (no stacked subtree); tensor parallelism (every leaf
 is replicated over ``model``).
 """
@@ -132,8 +134,9 @@ class MiniCPMSALAConfig:
     init_blocks: int = 1
     window_size: int = 2048
     dense_len: int = 8192
-    #: the attend stage's lowering: queries scored at a time, and in how
-    #: many spans of growing key length a sequence is walked
+    #: the attend stage's XLA form (where its kernels do not run): queries
+    #: scored at a time, and in how many spans of growing key length a
+    #: sequence is walked
     attend_query_chunk: int = 128
     attend_key_spans: int = 4
     # the Lightning layers

@@ -532,9 +532,43 @@ def visited_keys_per_query(S, query_chunk=128, key_spans=4):
     return S * (n + 1) / (2.0 * n)
 
 
+def _kept_columns(blk, c0, dense_q, col):
+    """1 at the columns ``col`` a query keeps, [b, G, queries, columns]
+    bool: its kept blocks ``blk`` [b, G, queries, topk] shifted by its
+    document's column 0, or every column where its document is short."""
+    column = jnp.where(blk >= 0, blk + c0[:, None, :, None], -1)
+    return jnp.any(column[..., None] == col, axis=-2) \
+        | dense_q[:, None, :, None]
+
+
+def mask_operands(blocks, seg, sel: BlockSelection, dtype):
+    """What the kernels' mask is made of, once a call: which column each
+    key lies in [b, S, C] and the columns each query keeps [b, G, C, S]
+    (``dense_len`` folded in as rows of ones), zeros and ones in
+    ``dtype``, and the first position of each query's document [b, 1, S]
+    int32."""
+    S = seg.shape[1]
+    geo = _geometry(seg, S, sel)
+    col = jnp.arange(S // sel.block_size, dtype=jnp.int32)
+    key_col = geo["c0"] + geo["pos"] // sel.block_size
+    kept = _kept_columns(blocks, geo["c0"], geo["len"] < sel.dense_len, col)
+    return ((key_col[..., None] == col).astype(dtype),
+            jnp.swapaxes(kept, 2, 3).astype(dtype), geo["start"][:, None, :])
+
+
+def _attend_blocking(interpret, S, R, hd, sel, dtype):
+    """(the tiles of ops/pallas/selected_attention.py's kernels, or None for
+    the XLA form; interpret), by ``vmem.lowering``'s rule."""
+    from deepspeed_tpu.ops.pallas import selected_attention as kernels, vmem
+    return vmem.lowering(
+        interpret, kernels.supported(S, hd, sel.block_size, interpret),
+        lambda: kernels.blocking(S, R, hd, sel.block_size,
+                                 jnp.dtype(dtype).itemsize))
+
+
 def selected_attention(q, k, v, blocks, segment_ids=None,
                        sel: BlockSelection = None, query_chunk: int = 128,
-                       key_spans: int = 4):
+                       key_spans: int = 4, interpret=None):
     """Softmax attention of each query over the keys ``s <= t`` of its own
     document's kept blocks: ``q`` [b, S, H, hd], ``k``, ``v`` [b, S, G,
     hd], ``blocks`` [b, G, S, topk] as :func:`select_blocks` returns them
@@ -543,36 +577,64 @@ def selected_attention(q, k, v, blocks, segment_ids=None,
     Differentiable in ``q``, ``k`` and ``v``; products take their operands
     in ``q``'s dtype and accumulate in float32, the softmax is float32.
 
-    The lowering (``masked_chunks``): all the keys a query could see, under
-    a per-(token, block) mask — ``query_chunk`` queries at a time against
-    every key of their span, the sequence walked in ``key_spans`` spans of
-    growing key length (so a query is multiplied by
-    :func:`visited_keys_per_query` keys, not by ``S``), each chunk
-    rematerialised for its gradient.  The mask of a chunk is one small
-    product: the kept columns of its queries [G * chunk, columns] against
-    which column each key lies in [columns, keys].  Nothing is skipped for
-    being unselected: the step's device time does not depend on the data.
-    A kernel that visits only the kept blocks is not built (ROADMAP)."""
+    One algorithm, two lowerings (:func:`_attend_blocking` chooses, from
+    what the call observes; ``interpret``: None chooses, True runs the
+    kernels in interpret mode, False the XLA form).  Both multiply a query
+    by all the keys it could see, under a per-(token, block) mask — which
+    column each key lies in against the columns each query keeps: one
+    small product of zeros and ones — and skip nothing for being
+    unselected, so the step's device time does not depend on the data.
+
+    * ``mosaic_tiles`` — on one TPU, for a head width of 128 and an ``S``
+      the tiles divide: the kernels of ops/pallas/selected_attention.py.
+      Online-softmax tiles with the scores, the mask and the softmax in
+      VMEM, a key/value head's query heads sharing a tile's mask, the
+      backward by hand from the saved log-sum-exp rows; a query is
+      multiplied by ``(S + tile) / 2`` keys.
+    * ``masked_chunks`` — elsewhere: ``query_chunk`` queries at a time
+      against every key of their span, the sequence walked in
+      ``key_spans`` spans of growing key length (so a query is multiplied
+      by :func:`visited_keys_per_query` keys, not by ``S``), each chunk
+      rematerialised for its gradient; the scores go through HBM.
+
+    Not built: a kernel that also leaves out a (query tile, key block) no
+    query of the tile kept — its trip counts would be data (ROADMAP) — and
+    one that gathers a query's kept keys."""
     sel = sel or BlockSelection()
     b, S, H, hd = q.shape
     G = k.shape[2]
     dtype = q.dtype
-    C = -(-S // sel.block_size)
     seg = (jnp.zeros((b, S), jnp.int32) if segment_ids is None
            else segment_ids.astype(jnp.int32))
+    row = {"batch": b, "seq_len": S, "heads": H, "kv_heads": G,
+           "head_dim": hd,
+           **{f"sparse/{f}": getattr(sel, f)
+              for f in sel.__dataclass_fields__}}
+    account = lambda **how: count_in_step(sparse_attention_calls={
+        f"{b}x{S}x{H}x{G}x{hd}": {**row, **how}})
+
+    tiles, interpret = _attend_blocking(interpret, S, H // G, hd, sel, dtype)
+    if tiles is not None:
+        from deepspeed_tpu.ops.pallas import selected_attention as kernels
+        from deepspeed_tpu.ops.pallas.vmem import limit_for
+        bq, bk = tiles.block_q, tiles.block_k
+        account(lowering="mosaic_tiles", blocks=[bq, bk],
+                tiles=kernels.visited_tiles(S, bq, bk),
+                vmem_limit_bytes=limit_for(tiles.vmem_bytes),
+                **{"sparse/visited_keys_per_query":
+                   kernels.visited_keys_per_query(S, bq, bk)})
+        return kernels.selected_attention_kernels(
+            q, k, v, *mask_operands(blocks, seg, sel, dtype), tiles,
+            interpret)
+
     geo = _geometry(seg, S, sel)
     key_col = geo["c0"] + geo["pos"] // sel.block_size          # [b, S]
     dense = geo["len"] < sel.dense_len
-    col = jnp.arange(C, dtype=jnp.int32)
+    col = jnp.arange(-(-S // sel.block_size), dtype=jnp.int32)
     Qc, n_spans = _spans(S, query_chunk, key_spans)
-    count_in_step(sparse_attention_calls={f"{b}x{S}x{H}x{G}x{hd}": {
-        "batch": b, "seq_len": S, "heads": H, "kv_heads": G, "head_dim": hd,
-        **{f"sparse/{f}": getattr(sel, f)
-           for f in sel.__dataclass_fields__},
-        "sparse/visited_keys_per_query": visited_keys_per_query(
-            S, query_chunk, key_spans),
-        "query_chunk": Qc, "key_spans": n_spans,
-        "lowering": "masked_chunks"}})
+    account(lowering="masked_chunks", query_chunk=Qc, key_spans=n_spans,
+            **{"sparse/visited_keys_per_query": visited_keys_per_query(
+                S, query_chunk, key_spans)})
     # float32 outside the chunks' loop: its transpose sums their cotangents
     k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
     by_chunk = lambda t, n: jnp.moveaxis(
@@ -586,11 +648,10 @@ def selected_attention(q, k, v, blocks, segment_ids=None,
         @jax.checkpoint
         def some_queries(xs):
             qc, blk, t, seg_q, c0, dense_q = xs
-            column = jnp.where(blk >= 0, blk + c0[:, None, :, None], -1)
-            kept = jnp.any(column[..., None] == col, axis=-2) \
-                | dense_q[:, None, :, None]                      # [b,G,Qc,C]
-            seen = jnp.einsum("bgqc,bkc->bgqk", kept.astype(dtype),
-                              in_col) > 0.5
+            seen = jnp.einsum(
+                "bgqc,bkc->bgqk",
+                _kept_columns(blk, c0, dense_q, col).astype(dtype),
+                in_col) > 0.5
             seen &= (seg_q[:, None, :, None] == seg_k[:, None, None, :]) \
                 & (t[None, None, :, None] >= at)
             s = jnp.einsum("bqgrd,bkgd->bgrqk",

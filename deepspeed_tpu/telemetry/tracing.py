@@ -892,12 +892,18 @@ def sparse_attention_calls(name: str = TRAIN_STEP_PROGRAM):
     """The selected-block attention calls of the step as
     ops/sparse_attention.py traced them: one row per shape — ``batch``,
     ``seq_len``, ``heads``, ``kv_heads``, ``head_dim``, the selection's
-    seven numbers, ``sparse/visited_keys_per_query`` (the keys the
-    lowering multiplies a query by, a mean over the sequence's queries:
-    static, whatever was selected), ``query_chunk`` and ``key_spans`` (the
+    seven numbers, ``lowering`` and ``sparse/visited_keys_per_query`` (the
+    keys that lowering multiplies a query by, a mean over the sequence's
+    queries: static, whatever was selected).  ``lowering`` is
+    ``"mosaic_tiles"`` where the call ran as the kernels of
+    ops/pallas/selected_attention.py — then also ``blocks`` (the queries
+    and keys of a tile), ``tiles`` (the (query tile, key tile) pairs one
+    pass of one key/value head visits: every pair with a causal pair in
+    it) and ``vmem_limit_bytes`` (what the three calls ask Mosaic for) —
+    or ``"masked_chunks"``, every key of a span under a per-(token, block)
+    mask as XLA einsums — then ``query_chunk`` and ``key_spans`` (the
     queries scored at a time, and in how many spans of growing key length
-    the sequence is walked) and ``lowering`` (``"masked_chunks"``: every
-    key of a span under a per-(token, block) mask).  What depends on the
+    the sequence is walked).  What depends on the
     data — the blocks kept, the keys required, the queries of documents
     under ``dense_len`` — is no shape and is not here:
     ``ops.sparse_attention.selection_counts``.  None where the step has

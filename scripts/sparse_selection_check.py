@@ -33,7 +33,9 @@ them) and that seed's first micro-batch, one JSON line:
 * ``attend_rel``: ``selected_attention`` given the REFERENCE's selection
   against the reference's step 5 on the same bfloat16 q, k and v — the
   largest difference over the largest value; no block left out can hide in
-  it.  Held to ``--attend-tol``.
+  it.  Held to ``--attend-tol``.  The stage runs through the lowering the
+  engine takes here (``ops/sparse_attention.py``'s rule: the Mosaic kernels
+  on one TPU, the XLA form elsewhere), and ``attend_lowering`` says which.
 * the data-dependent counts no static account can hold
   (``ops.sparse_attention.selection_counts``).
 * with ``--plant``: what the benchmark's token-by-token check
@@ -167,6 +169,7 @@ def main():
     from deepspeed_tpu.ops.sparse_attention import (select_blocks,
                                                     selected_attention,
                                                     selection_counts)
+    from deepspeed_tpu.telemetry import tracing
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     reference = importlib.import_module("references." + config["reference"])
@@ -206,7 +209,7 @@ def main():
 
     chunk = max(1, config["checks"]["reference_chunk_tokens_per_chip"]
                 // traffic["seq_len"])
-    ok = True
+    ok, lowering = True, None
     for seed in args.seed:
         params = init(jax.random.PRNGKey(seed))
         stream = datagen.BatchStream(traffic, sizes["vocab_size"],
@@ -253,9 +256,17 @@ def main():
                     params, micro, sizes, matmul_dtype=getattr(jnp, name))
                 line.update({f"{name}_{key}": value for key, value
                              in shares(control, want).items()})
-            line["attend_rel"] = float(attend_both(
-                q, k, v, jnp.asarray(want),
-                jnp.asarray(micro["segment_ids"])))
+            with tracing.step_account("check/attend"):
+                line["attend_rel"] = float(attend_both(
+                    q, k, v, jnp.asarray(want),
+                    jnp.asarray(micro["segment_ids"])))
+            # the row is written where the call is traced: the first seed
+            rows = tracing.sparse_attention_calls("check/attend")
+            if rows:
+                lowering = {key: rows[0][key] for key in (
+                    "lowering", "blocks", "tiles",
+                    "sparse/visited_keys_per_query") if key in rows[0]}
+            line["attend_lowering"] = lowering
             line["SELECTION_AGREEMENT_MIN"] = \
                 reference.SELECTION_AGREEMENT_MIN
             line["ok"] = (line["ok"] and line["agreement"]

@@ -6,7 +6,9 @@ weight panels resident in VMEM) and at Mixtral-8x7B's, the flash kernels at JoyA
 experts, the gated delta rule's two at Qwen3-Next's and its two with a
 decay a key channel at Kimi-Linear's (one packed sequence of 16,384), the state-space scan's two at Nemotron-H's
 and the short causal convolution's two at both hybrids' (each in its
-orientation), the selective scan's two and the flash kernels at a 64-wide
+orientation), the selected-block attention's three at MiniCPM-SALA's (32
+query heads to 2, 64 kept blocks a token, one packed sequence of 16,384),
+the selective scan's two and the flash kernels at a 64-wide
 score and a 128-wide value head at Phi-4-mini-flash's (one packed sequence
 of 16,384) go through Mosaic, the data-sharded flash kernel
 goes through the partitioner, and the library knows the chip's peaks.
@@ -158,6 +160,20 @@ def _ssd(x, dt, A, Bm, Cm, D, seg):
     return ssd.ssd_kernels(x, dt, A, Bm, Cm, D, seg, blocking)
 
 
+def _sel(q, k, v, blocks, seg):
+    """The sparse layer's attend stage as ops/sparse_attention.py calls it
+    on one TPU (the choice switched off: no TPU here): the mask's operands
+    made from ``blocks`` and the three kernels, at the device kind's
+    tiles."""
+    from deepspeed_tpu.ops import sparse_attention as sa
+    from deepspeed_tpu.ops.pallas import selected_attention as sel
+    (B, S, H, hd), G = q.shape, k.shape[2]
+    chosen = sa.BlockSelection()
+    tiles = sel.blocking(S, H // G, hd, chosen.block_size, q.dtype.itemsize)
+    return sel.selected_attention_kernels(
+        q, k, v, *sa.mask_operands(blocks, seg, chosen, q.dtype), tiles)
+
+
 def _sscan(u, dt, A, Bm, Cm, D, bias, first):
     """The selective scan's kernels as ops/selective_scan.py calls them on
     one TPU (the choice switched off: no TPU here), with the blocking the
@@ -268,6 +284,12 @@ _SSCAN_16K = [((1, 16384, 5120), jnp.bfloat16),
               ((1, 16384, 16), jnp.bfloat16), ((1, 16384, 16), jnp.bfloat16),
               ((5120,), jnp.float32), ((5120,), jnp.float32),
               ((1, 16384), jnp.bool_)]
+# minicpm-sala.packed-s16384-longdocs: a sparse layer's 32 query heads to 2
+# key/value heads of 128, each (token, key/value head) keeping 64 blocks
+_SEL_16K = [((1, 16384, 32, 128), jnp.bfloat16),
+            ((1, 16384, 2, 128), jnp.bfloat16),
+            ((1, 16384, 2, 128), jnp.bfloat16),
+            ((1, 2, 16384, 64), jnp.int32), ((1, 16384), jnp.int32)]
 _QKV_GQA6_8K = [((1, 8192, 48, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16),
                 ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192), jnp.int32)]
@@ -320,6 +342,9 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ds_flash_packed), (0, 1, 2)), _QKV_DIFF_16K),
     "ds_flash_win512_diff_s16384_dk64_dv128_packed_fwd_bwd": (
         jax.grad(_sum_sq(_ds_flash_windowed), (0, 1, 2)), _QKV_DIFF_16K),
+    "ds_sel_s16384_r16_packed_fwd": (_sel, _SEL_16K),
+    "ds_sel_s16384_r16_packed_fwd_bwd": (
+        jax.grad(_sum_sq(_sel), (0, 1, 2)), _SEL_16K),
     "ds_sscan_s16384_packed_fwd": (_sscan, _SSCAN_16K),
     "ds_sscan_s16384_packed_fwd_bwd": (
         jax.grad(_sum_sq(_sscan), (0, 1, 2, 3, 4, 5, 6)), _SSCAN_16K),
@@ -395,6 +420,9 @@ NAMED_KERNELS = {
         "ds_flash_fwd", "ds_flash_bwd_dkv", "ds_flash_bwd_dq"},
     "ds_flash_win512_diff_s16384_dk64_dv128_packed_fwd_bwd": {
         "ds_flash_win_fwd", "ds_flash_win_bwd_dkv", "ds_flash_win_bwd_dq"},
+    "ds_sel_s16384_r16_packed_fwd": {"ds_sel_fwd"},
+    "ds_sel_s16384_r16_packed_fwd_bwd": {"ds_sel_fwd", "ds_sel_bwd_dq",
+                                         "ds_sel_bwd_dkv"},
     "ds_sscan_s16384_packed_fwd": {"ds_sscan_fwd"},
     "ds_sscan_s16384_packed_fwd_bwd": {"ds_sscan_fwd", "ds_sscan_bwd"},
     "ds_ggemm_fwd": {"ds_ggemm_fwd"},
@@ -502,6 +530,18 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
                 for c in tracing.flash_calls("test/compile")] \
             == [(192, 128, 32, 32, True) if "mla" in case
                 else (64, 128, 20, 10, True)]
+    if "ds_sel" in case:
+        # 16 heads' tiles of 512 tokens pass what a call is granted unasked:
+        # the three calls ask for vmem.limit_for's 96 MiB, Mosaic took it,
+        # and the working set is inside the budget
+        from deepspeed_tpu.ops.pallas import selected_attention as sel
+        tiles = sel.blocking(16384, 16, 128, 64, 2)
+        assert tiles[:2] == (512, 512)
+        assert gg.vmem.UNASKED < tiles.vmem_bytes <= gg.vmem.budget()
+        assert gg.vmem.limit_for(tiles.vmem_bytes) == 96 << 20
+        assert compiled.as_text().count(
+            f'"memory_space":"1","offset":"0","size":"{96 << 20}"') \
+            == len(NAMED_KERNELS[case])
     if "sscan" in case:
         from deepspeed_tpu.ops.pallas import selective_scan as sscan
         assert sscan.blocking(5120, 16, 128, 2).channels == 512

@@ -8,6 +8,7 @@ a layer without a scope of the layer's own.  A file of its own so that
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.telemetry import tracing
@@ -76,8 +77,20 @@ def test_a_layer_saves_its_input_and_its_mixers_output():
     assert f"tensor<{cfg.mlp_token_tile}x{cfg.d_ff}xf32>" in text
 
 
-def test_scopes_and_accounts_of_a_toy_step():
+@pytest.mark.parametrize("lowering", ["masked_chunks", "mosaic_tiles"])
+def test_scopes_and_accounts_of_a_toy_step(lowering, monkeypatch):
+    """On the CPU the attend stage is the XLA form; its twin runs the
+    stage's kernels (interpret mode, tiles of 32 x 16) through the same
+    engine, so that the scopes and the account read as they do on the
+    chip."""
     from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.ops import sparse_attention as sa
+    from deepspeed_tpu.ops.pallas import selected_attention as kernels
+    if lowering == "mosaic_tiles":
+        rule = sa._attend_blocking
+        monkeypatch.setattr(sa, "_attend_blocking",
+                            lambda interpret, *a: rule(True, *a))
+        monkeypatch.setattr(kernels, "TILES", (32, 16))
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -112,11 +125,22 @@ def test_scopes_and_accounts_of_a_toy_step():
             if mixer in scope:
                 assert any(p in scope for p in inside), row
     sparse, = tracing.sparse_attention_calls("train/step")
-    assert sparse["lowering"] == "masked_chunks"
+    assert sparse["lowering"] == lowering
     assert (sparse["sparse/topk"], sparse["sparse/block_size"],
             sparse["sparse/dense_len"]) == (4, 4, 32)
-    assert sparse["sparse/visited_keys_per_query"] == S * 3 / 4   # 2 spans
-    assert (sparse["query_chunk"], sparse["key_spans"]) == (16, 2)
+    if lowering == "masked_chunks":
+        assert sparse["sparse/visited_keys_per_query"] == S * 3 / 4  # 2 spans
+        assert (sparse["query_chunk"], sparse["key_spans"]) == (16, 2)
+    else:
+        # every tile pair with a causal (query, key) in it, the kernels'
+        # three calls under the stage's scope in each of their phases
+        assert sparse["blocks"] == [32, 16]
+        assert sparse["tiles"] == kernels.visited_tiles(S, 32, 16)
+        assert sparse["sparse/visited_keys_per_query"] == (S + 32) / 2
+        for call, phases_ in (("ds_sel_fwd", {"forward", "recompute"}),
+                              ("ds_sel_bwd_dq", {"backward"}),
+                              ("ds_sel_bwd_dkv", {"backward"})):
+            assert phases("/sparse_attn/attend/" + call) == phases_, call
     # the Lightning calls are the scan's row with one group a head
     scan, = tracing.ssd_chunks("train/step")
     assert (scan["groups"], scan["heads"], scan["path"]) == (4, 4, "xla")
