@@ -254,11 +254,20 @@ def logical_specs(config: NemotronHConfig) -> dict:
     }
 
 
-def _ssm_mixer(x, layer, config: NemotronHConfig, segment_ids):
+def ssm_branch(x, layer, config, segment_ids, heads=None):
+    """``Mamba2(N(x))``, the branch alone: whoever calls owns the residual
+    (the block below adds ``x``; models/granite_hybrid.py scales the branch
+    first).  ``config`` is any with this file's Mamba-2 sizes; ``heads``
+    are the heads BUILT here (None: all ``mamba_num_heads``) — a
+    tensor-parallel share builds ``z``, ``x``, ``dt`` and what follows them
+    for its own heads and ``B``, ``C`` whole, and its gated norm runs over
+    the channels it holds.  The caller's scope is ``ssm``."""
     B, S, _ = x.shape
-    Hm, Pd = config.mamba_num_heads, config.mamba_head_dim
+    Hm = config.mamba_num_heads if heads is None else heads
+    Pd = config.mamba_head_dim
     G, N = config.n_groups, config.ssm_state_size
-    d_in, conv_ch = config.d_inner, config.conv_channels
+    d_in = Hm * Pd
+    conv_ch = d_in + 2 * G * N
     f32 = lambda a: a.astype(jnp.float32)
     with jax.named_scope(SCOPE_IN_PROJ):
         h = _rms_norm(x, layer["norm"], config.norm_eps)
@@ -287,7 +296,7 @@ def _ssm_mixer(x, layer, config: NemotronHConfig, segment_ids):
         y = _gated_norm(y.reshape(B, S, d_in), z, layer["gate_norm"], G,
                         config.norm_eps)
     with jax.named_scope(SCOPE_OUT_PROJ):
-        return x + qdot(y, layer["w_out"])
+        return qdot(y, layer["w_out"])
 
 
 def _gated_norm(y, z, w, groups, eps):
@@ -306,17 +315,28 @@ def _gated_norm(y, z, w, groups, eps):
 def _ssm_block(x, layer, config: NemotronHConfig, train, rng=None,
                segment_ids=None):
     with jax.named_scope(SCOPE_SSM):
-        return _ssm_mixer(x, layer, config, segment_ids), no_experts()
+        out = ssm_branch(x, layer, config, segment_ids)
+        with jax.named_scope(SCOPE_OUT_PROJ):
+            return x + out, no_experts()
 
 
-@jax.named_scope(SCOPE_BLOCK)
-def _attn_block(x, layer, config: NemotronHConfig, train, rng=None,
-                segment_ids=None):
+def attention_branch(x, layer, config, segment_ids, heads=None,
+                     q_scale=None):
+    """``Attention(N(x))``, the branch alone, its scopes (``attn``) its
+    own.  ``config`` is any with this file's attention sizes; ``heads`` =
+    (query, key/value) heads BUILT here (None: all of both); ``q_scale``
+    multiplies ``q`` in float32 before its one rounding: a family whose
+    scores are not scaled by ``1 / sqrt(head_dim)`` folds the ratio in
+    there (``causal_attention`` takes no scale)."""
     B, S, _ = x.shape
-    H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
+    H, KV = heads or (config.num_heads, config.num_kv_heads)
+    hd = config.head_dim
     with jax.named_scope(SCOPE_ATTN):
         h = _rms_norm(x, layer["norm"], config.norm_eps)
-        q = qdot(h, layer["wq"]).reshape(B, S, H, hd)
+        q = qdot(h, layer["wq"])
+        if q_scale is not None:
+            q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
+        q = q.reshape(B, S, H, hd)
         k = qdot(h, layer["wk"]).reshape(B, S, KV, hd)
         v = qdot(h, layer["wv"]).reshape(B, S, KV, hd)
         # no rotary embedding: the family has no position embedding
@@ -324,8 +344,15 @@ def _attn_block(x, layer, config: NemotronHConfig, train, rng=None,
                                 segment_ids=segment_ids)
     attn = jax.ad_checkpoint.checkpoint_name(attn, "attn_out")
     with jax.named_scope(SCOPE_ATTN):
-        x = x + qdot(attn.reshape(B, S, H * hd), layer["wo"])
-    return x, no_experts()
+        return qdot(attn.reshape(B, S, H * hd), layer["wo"])
+
+
+@jax.named_scope(SCOPE_BLOCK)
+def _attn_block(x, layer, config: NemotronHConfig, train, rng=None,
+                segment_ids=None):
+    out = attention_branch(x, layer, config, segment_ids)
+    with jax.named_scope(SCOPE_ATTN):
+        return x + out, no_experts()
 
 
 @jax.named_scope(SCOPE_BLOCK)

@@ -84,6 +84,17 @@ def _kernel_blocking(interpret, n, C, r, P, N, dtype):
                                         jnp.dtype(dtype).itemsize))
 
 
+def _chunk_alone_refused(C, r, P, N) -> dict:
+    """``{"why": "chunk 256"}`` where the kernels refuse a call's shapes for
+    its chunk alone — they would take the same call at their own chunk — so
+    that the step's account says why a published chunk size runs as XLA;
+    else nothing."""
+    from deepspeed_tpu.ops.pallas import state_space as kernels
+    alone = not kernels.supported(P, N, r, C) \
+        and kernels.supported(P, N, r, kernels.LANES)
+    return {"why": f"chunk {C}"} if alone else {}
+
+
 def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
              chunk: int = DEFAULT_CHUNK, interpret=None):
     """The recurrence of the module docstring for every head at once.
@@ -117,7 +128,8 @@ def ssd_scan(x, dt, A, B, C, D=None, segment_ids=None,
     B, C = B.astype(dtype), C.astype(dtype)
     row = {"chunks": n, "chunk_len": Cn, "batch": b, "heads": H, "groups": G,
            "head_dim": P, "state": N,
-           "path": "xla" if blocking is None else "kernel"}
+           "path": "xla" if blocking is None else "kernel",
+           **_chunk_alone_refused(Cn, H // G, P, N)}
     if blocking is not None:
         from deepspeed_tpu.ops.pallas.state_space import ssd_kernels
         row.update(heads_per_step=blocking.heads,

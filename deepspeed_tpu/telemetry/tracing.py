@@ -881,7 +881,8 @@ def ssd_chunks(name: str = TRAIN_STEP_PROGRAM):
     ``ds_ssd_bwd`` (then also ``heads_per_step`` and ``chunks_per_step``,
     the heads — one group's — and chunks one grid step takes), ``"xla"``
     where it fell back to the chunked form as XLA einsums around a
-    ``lax.scan``.  A row whose ``groups`` equal its ``heads`` is a
+    ``lax.scan`` (with ``why``, ``"chunk 256"``, where the kernels would
+    have taken the call at their own chunk of 128).  A row whose ``groups`` equal its ``heads`` is a
     Lightning-attention call (``lightning_attention``: one group a head),
     and its ``chunks`` the count of Lightning chunks.  None where the step
     has no such call."""

@@ -254,6 +254,13 @@ _SSD_LIGHTNING_16K = [
     ((32,), jnp.float32), ((1, 16384, 32, 128), jnp.bfloat16),
     ((1, 16384, 32, 128), jnp.bfloat16), ((32,), jnp.float32),
     ((1, 16384), jnp.int32)]
+# granite-4.0-h-small.packed-s4096-gas1: a chip's 16 of 128 Mamba-2 heads
+# over the ONE group's B and C (r = 16 heads a group, twice Nemotron-H's 8)
+_SSD_ONE_GROUP_4K = [
+    ((1, 4096, 16, 64), jnp.bfloat16), ((1, 4096, 16), jnp.float32),
+    ((16,), jnp.float32), ((1, 4096, 1, 128), jnp.bfloat16),
+    ((1, 4096, 1, 128), jnp.bfloat16), ((16,), jnp.float32),
+    ((1, 4096), jnp.int32)]
 # joyai-llm-flash.packed-s8192-gas2: latent attention, 32 heads (no
 # grouping), a score head of 128 + 64 = 192 = 1.5 lane tiles and a value
 # head of 128: v, o, do and dv are 128 wide in HBM, nothing padded to 192
@@ -369,6 +376,8 @@ KERNEL_CASES = {
         jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_8K),
     "ds_ssd_s16384_one_head_a_group_fwd_bwd": (
         jax.grad(_sum_sq(_ssd), (0, 3, 4)), _SSD_LIGHTNING_16K),
+    "ds_ssd_s4096_sixteen_heads_a_group_fwd_bwd": (
+        jax.grad(_sum_sq(_ssd), (0, 1, 2, 3, 4, 5)), _SSD_ONE_GROUP_4K),
     "ds_conv_sublanes_s8192_packed_fwd": (_conv("sublanes"),
                                           _conv_args(8192, 8192)),
     "ds_conv_sublanes_s8192_packed_part_fwd_bwd": (
@@ -442,6 +451,8 @@ NAMED_KERNELS = {
     "ds_ssd_s8192_packed_fwd": {"ds_ssd_fwd"},
     "ds_ssd_s8192_packed_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
     "ds_ssd_s16384_one_head_a_group_fwd_bwd": {"ds_ssd_fwd", "ds_ssd_bwd"},
+    "ds_ssd_s4096_sixteen_heads_a_group_fwd_bwd": {"ds_ssd_fwd",
+                                                   "ds_ssd_bwd"},
     "ds_conv_sublanes_s8192_packed_fwd": {"ds_conv_fwd"},
     "ds_conv_sublanes_s8192_packed_part_fwd_bwd": {"ds_conv_fwd",
                                                    "ds_conv_bwd"},

@@ -243,6 +243,20 @@ def test_the_account_says_which_lowering_ran():
     assert {"ds_ssd_fwd", "ds_ssd_bwd"} <= kernel_names(_kernel(seg), *args)
 
 
+@pytest.mark.parametrize("chunk, why", [(256, {"why": "chunk 256"}),
+                                        (CHUNK, {})])
+def test_the_account_says_when_the_chunk_alone_sent_a_call_to_xla(chunk, why):
+    """Shapes the kernels take at their own chunk of 128, asked for at a
+    published 256: the XLA form as before, and the row carries the
+    reason; at 128, left to the rule (no TPU here: XLA too), no reason."""
+    args, seg = _inputs(), _segments("one_document")
+    with tracing.step_account("test/ssd"):
+        jax.eval_shape(lambda *a: ssd_scan(*a, seg, chunk=chunk), *args)
+    assert tracing.ssd_chunks("test/ssd") == [
+        {"chunks": S // chunk, "chunk_len": chunk, "batch": B, "heads": H,
+         "groups": G, "head_dim": P, "state": N, "path": "xla", **why}]
+
+
 def test_a_toy_engines_step_runs_the_kernels_and_says_so(monkeypatch):
     """Nemotron-H at toy depth with a mixer the kernels take (heads of 64,
     state 128, chunk 128), the choice steered to interpret mode as it
