@@ -2,10 +2,20 @@
 (reference: engine.py:1233 ``_configure_basic_optimizer`` — FusedAdam,
 DeepSpeedCPUAdam, FusedLamb, OnebitAdam, ...).
 
-On TPU, "fused" is what XLA does to any optax update under jit, so FusedAdam and
-Adam share an implementation; DeepSpeedCPUAdam (ZeRO-Offload's host-side SIMD
-optimizer, csrc/adam/cpu_adam_impl.cpp) maps to the host-offload execution tier
-selected by the engine, not a different math.
+FusedAdam and Adam share an implementation: "fused" is what XLA does to an
+optax update under jit — which reads a leaf's operands once where it can
+finish the update in one fusion, and two to five times where the step's norms
+or a gradient handed over in pieces pull it apart (PERF.md section 3).  The
+mixed-precision AdamW (runtime/bf16_optimizer.py ``mp_adamw``: any
+``bf16.master_weights_dtype`` / ``optimizer_states_dtype``) keeps it at one
+for the leaves where that happens: standing alone (no clipping, no
+trainable_mask, no fp16) it updates every stacked leaf of three or more axes
+behind an ``optimization_barrier`` with the step's sums in the same
+expression — one fusion, in place — and leaves matrices, vectors and every
+composed transform to XLA whole.
+DeepSpeedCPUAdam (ZeRO-Offload's host-side SIMD optimizer,
+csrc/adam/cpu_adam_impl.cpp) maps to the host-offload execution tier selected
+by the engine, not a different math.
 """
 from typing import Optional
 

@@ -20,7 +20,8 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.runtime.fp16.loss_scaler import has_overflow, update_scale
-from deepspeed_tpu.telemetry.numerics import group_stats, inject_nonfinite
+from deepspeed_tpu.telemetry.numerics import (
+    group_stats, group_stats_of, inject_nonfinite)
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
     count_in_step, step_account)
@@ -159,30 +160,25 @@ def accumulated_grads(ctx, params, batches, rng, scale, compress_step=None):
     return grads, loss_sum, jax.tree.map(lambda c: jnp.sum(c, axis=0), counts)
 
 
-@jax.named_scope(SCOPE_OPTIMIZER)
-def apply_grads(ctx, state, grads, nf_group=None):
-    """Shared epilogue: unscale, overflow check, update, skip-on-overflow.
-    ``nf_group``: the ``train.nonfinite`` chaos fault's leaf group, set only
-    by the builder of a ``train_step@nf<g>`` variant."""
-    fp16 = ctx.fp16
-    params, opt_state, scaler = (state["params"], state["opt_state"],
-                                 state["scaler"])
-    scale = loss_scale(ctx, state)
-    if nf_group is not None and ctx.num_leaf_group is not None:
-        # NaN-poison the chosen leaf group's gradient at TRACE time — a
-        # dedicated step variant per injected group, so the healthy
-        # compiled step is untouched (ISSUE 15)
-        grads = inject_nonfinite(grads, ctx.num_leaf_group, nf_group)
+def _numerics_on(ctx):
+    return ctx.num_leaf_group is not None and bool(ctx.num_groups)
+
+
+def _optax_update(ctx, grads, opt_state, params, scale):
+    """Unscale, norms, overflow, ``update`` + ``apply_updates``: the path
+    of every transform but a lone ``mp_adamw`` without fp16.  -> (new
+    params, new optimizer state, overflow, the gradient's norm, the
+    numerics tier's group stats and update ratio or None)."""
     grads = jax.tree.map(lambda g: g / scale, grads)
     grad_norm = global_norm(grads)
     num_stats = None
-    if ctx.num_leaf_group is not None and ctx.num_groups:
+    if _numerics_on(ctx):
         # in-graph numerics stats (ISSUE 15): per-group grad norms
         # + the non-finite provenance bitmap, device-resident until
         # the bank resolves (no host sync here)
         num_stats = group_stats(grads, ctx.num_leaf_group,
                                 len(ctx.num_groups))
-    if fp16:
+    if ctx.fp16:
         overflow = has_overflow(grads)
         safe_grads = jax.tree.map(
             lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
@@ -202,6 +198,49 @@ def apply_grads(ctx, state, grads, nf_group=None):
         update_ratio = jnp.where(
             overflow, jnp.float32(0.0),
             unorm / jnp.maximum(pnorm, jnp.float32(1e-12)))
+    return new_params, new_opt, overflow, grad_norm, num_stats, update_ratio
+
+
+def _in_place_update(ctx, in_place, grads, opt_state, params):
+    """As :func:`_optax_update` for an optimizer that offers
+    ``update_in_place`` (runtime/bf16_optimizer.py, no fp16: the scale is
+    1.0 and nothing overflows): it writes the parameters itself — a
+    stacked leaf in one pass over its operands — and hands back a sum of
+    squares and a non-finite count a leaf, from which the same norms are
+    formed."""
+    new_params, new_opt, sums = in_place(grads, opt_state, params)
+    norm = lambda squares: jnp.sqrt(sum(squares))
+    num_stats = update_ratio = None
+    if _numerics_on(ctx):
+        num_stats = group_stats_of(sums.grad_sq, sums.nonfinite,
+                                   ctx.num_leaf_group, len(ctx.num_groups))
+        update_ratio = norm(sums.update_sq) / jnp.maximum(
+            norm(sums.param_sq), jnp.float32(1e-12))
+    return (new_params, new_opt, jnp.bool_(False), norm(sums.grad_sq),
+            num_stats, update_ratio)
+
+
+@jax.named_scope(SCOPE_OPTIMIZER)
+def apply_grads(ctx, state, grads, nf_group=None):
+    """Shared epilogue: unscale, overflow check, update, skip-on-overflow.
+    ``nf_group``: the ``train.nonfinite`` chaos fault's leaf group, set only
+    by the builder of a ``train_step@nf<g>`` variant."""
+    fp16 = ctx.fp16
+    params, opt_state, scaler = (state["params"], state["opt_state"],
+                                 state["scaler"])
+    scale = loss_scale(ctx, state)
+    if nf_group is not None and ctx.num_leaf_group is not None:
+        # NaN-poison the chosen leaf group's gradient at TRACE time — a
+        # dedicated step variant per injected group, so the healthy
+        # compiled step is untouched (ISSUE 15)
+        grads = inject_nonfinite(grads, ctx.num_leaf_group, nf_group)
+    in_place = None if fp16 else getattr(ctx.optimizer, "update_in_place",
+                                         None)
+    (new_params, new_opt, overflow, grad_norm, num_stats,
+     update_ratio) = (_optax_update(ctx, grads, opt_state, params, scale)
+                      if in_place is None else
+                      _in_place_update(ctx, in_place, grads, opt_state,
+                                       params))
     if fp16:
         new_params = jax.tree.map(
             lambda old, new: jnp.where(overflow, old, new),

@@ -126,13 +126,25 @@ def group_stats(grads, leaf_group_index: Sequence[int], num_groups: int):
     leaves = jax.tree_util.tree_leaves(grads)
     if len(leaves) != len(leaf_group_index):
         return None
+    return group_stats_of(
+        [jnp.sum(jnp.square(leaf.astype(jnp.float32))) for leaf in leaves],
+        [jnp.sum(jnp.logical_not(jnp.isfinite(leaf))).astype(jnp.int32)
+         for leaf in leaves], leaf_group_index, num_groups)
+
+
+def group_stats_of(grad_sq, nonfinite, leaf_group_index: Sequence[int],
+                   num_groups: int):
+    """:func:`group_stats` from each leaf's sum of squares (float32) and
+    non-finite count (int32), however they were formed (the fused
+    optimizer's kernel leaves them beside the update)."""
+    import jax.numpy as jnp
+    if len(grad_sq) != len(leaf_group_index):
+        return None
     sq = jnp.zeros((num_groups,), jnp.float32)
     nf = jnp.zeros((num_groups,), jnp.int32)
-    for leaf, g in zip(leaves, leaf_group_index):
-        x = leaf.astype(jnp.float32)
-        sq = sq.at[g].add(jnp.sum(x * x))
-        nf = nf.at[g].add(
-            jnp.sum(jnp.logical_not(jnp.isfinite(leaf))).astype(jnp.int32))
+    for leaf_sq, leaf_nf, g in zip(grad_sq, nonfinite, leaf_group_index):
+        sq = sq.at[g].add(leaf_sq)
+        nf = nf.at[g].add(leaf_nf)
     return jnp.sqrt(sq), nf
 
 

@@ -867,6 +867,19 @@ def exchange_calls(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "exchange_calls")
 
 
+def optimizer_fused(name: str = TRAIN_STEP_PROGRAM):
+    """Which parameter leaves the step's AdamW updates in one isolated
+    pass, as runtime/bf16_optimizer.py ``update_in_place`` traced it:
+    ``{"leaves", "param_bytes"}`` of those taken behind an
+    ``optimization_barrier`` (a stacked leaf of three or more axes) and
+    ``{"xla_leaves", "xla_param_bytes"}`` of those left for XLA to fuse
+    with what makes their gradient (matrices, vectors, the embedding
+    table).  Bytes are the parameters' own, over all devices.  None where
+    the step took the optax entry (a composed transform, fp16, another
+    optimizer)."""
+    return _STEP_COUNTERS.get(name, {}).get("optimizer_fused")
+
+
 def _account_rows(name: str, counter: str):
     """The rows a dict counter of the step's account holds, by key."""
     calls = _STEP_COUNTERS.get(name, {}).get(counter)

@@ -48,21 +48,33 @@ def test_bf16_moments_track_fp32():
     np.testing.assert_allclose(lo["w"], hi["w"], rtol=0.02, atol=2e-4)
 
 
-def test_kahan_master_accumulates_tiny_updates():
+@pytest.mark.parametrize("entry,shape", [
+    ("optax", (128,)), ("in_place", (128,)), ("in_place", (2, 16, 128))],
+    ids=["optax", "in_place", "in_place_stacked"])
+def test_kahan_master_accumulates_tiny_updates(entry, shape):
     """THE bf16-master failure mode: per-step updates below bf16 resolution
-    silently vanish without compensation.  Kahan must accumulate them."""
-    p0 = jnp.full((128,), 1.0, jnp.bfloat16)
+    silently vanish without compensation.  Kahan must accumulate them —
+    through ``update`` + ``apply_updates`` and through ``update_in_place``,
+    a vector left to XLA and a stacked leaf behind its barrier."""
+    p0 = jnp.full(shape, 1.0, jnp.bfloat16)
     # constant gradient -> adam steps converge to -lr (sign(g) like);
     # pick lr so each step (~1e-4) is far below bf16 ulp at 1.0 (~7.8e-3)
-    g = {"w": jnp.full((128,), 1e-3, jnp.float32)}
+    g = {"w": jnp.full(shape, 1e-3, jnp.float32)}
     steps = 200
 
     tx = mp_adamw(1e-4, master_dtype="bfloat16")
     params = {"w": p0}
     state = tx.init(params)
+
+    @jax.jit
+    def step(state, params):
+        if entry == "optax":
+            updates, state = tx.update(g, state, params)
+            return optax.apply_updates(params, updates), state
+        return tx.update_in_place(g, state, params)[:2]
+
     for _ in range(steps):
-        updates, state = tx.update(g, state, params)
-        params = optax.apply_updates(params, updates)
+        params, state = step(state, params)
     moved = float(np.mean(np.asarray(params["w"], np.float32)))
 
     # plain bf16 adam (no compensation): the same trajectory stalls at 1.0
@@ -77,7 +89,7 @@ def test_kahan_master_accumulates_tiny_updates():
 
     # fp32 oracle
     otx = optax.adam(1e-4)
-    ow = jnp.full((128,), 1.0, jnp.float32)
+    ow = jnp.full(shape, 1.0, jnp.float32)
     ostate = otx.init({"w": ow})
     for _ in range(steps):
         upd, ostate = otx.update(g, ostate)

@@ -10,8 +10,10 @@ orientation), the selected-block attention's three at MiniCPM-SALA's (32
 query heads to 2, 64 kept blocks a token, one packed sequence of 16,384),
 the selective scan's two and the flash kernels at a 64-wide
 score and a 128-wide value head at Phi-4-mini-flash's (one packed sequence
-of 16,384) go through Mosaic, the data-sharded flash kernel
-goes through the partitioner, and the library knows the chip's peaks.
+of 16,384) go through Mosaic, AdamW's update of Granite's and Nemotron-H's
+stacked expert leaves is one fusion over the donated state, the
+data-sharded flash kernel goes through the partitioner, and the library
+knows the chip's peaks.
 
 A compile that passes is not a chip run — it says nothing about results
 or times.  ``chip_smoke.py`` is the run."""
@@ -570,6 +572,119 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
         assert NAMED_KERNELS[case] <= named, named
 
 
+def _passes_over(compiled, shape):
+    """The entry computation's fusions and copies that read or write a
+    bf16 array of ``shape``: (name, arrays read, arrays written,
+    op_name) each."""
+    import re
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    leaf = "bf16[%s]" % ",".join(map(str, shape))
+    typed = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) [\w-]+\(", line)
+        if m:
+            typed[m.group(1)] = m.group(2)
+    passes = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) (?:fusion|copy)\((.*?)\)",
+                     line)
+        if not m:
+            continue
+        reads = sum(typed.get(name, "").startswith(leaf)
+                    for name in re.findall(r"%([\w.\-]+)", m.group(3)))
+        writes = m.group(2).count(leaf)
+        if reads or writes:
+            op = re.search(r'op_name="([^"]*)', line)
+            passes.append((m.group(1), reads, writes,
+                           op.group(1) if op else ""))
+    return passes
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 9, 9, 4096, 768),       # granite-4.0-h-small: nine layers' nine
+                                # held experts, gate / up
+    (1, 4, 8, 2688, 1856),      # nemotron-3-nano: the chip keeps the 2688
+                                # minor (no padded lanes)
+    (1, 9, 4096, 2320),         # a Mamba layer's W_in = [z|xBC|dt]
+], ids=["granite_experts", "nemotron_h_experts", "granite_w_in"])
+def test_a_stacked_leafs_update_is_one_fusion_in_place_on_a_v5e(v5e, shape):
+    """``mp_adamw.update_in_place`` at the cells' stacked leaves, compiled
+    for the chip: ONE fusion reads the leaf's five operands and writes its
+    four results and the four sums; the donated state is updated where it
+    lies (no array copied, nothing of a leaf's size reserved beside it)."""
+    from deepspeed_tpu.runtime.bf16_optimizer import mp_adamw
+    tx = mp_adamw(1e-4, weight_decay=0.1, mu_dtype="bfloat16",
+                  nu_dtype="bfloat16", master_dtype="bfloat16")
+    params = {"w": _arg(v5e[0], shape)}
+    state = jax.tree.map(lambda x: _arg(v5e[0], x.shape, x.dtype),
+                         jax.eval_shape(tx.init, params))
+
+    def step(params, state, grads):
+        return tx.update_in_place(grads, state, params)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, state, params).compile()
+    assert [row[1:3] for row in _passes_over(compiled, shape)] == [(5, 4)]
+    leaf_bytes = 2 * int(jnp.prod(jnp.asarray(shape)))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * leaf_bytes
+    assert memory.temp_size_in_bytes < leaf_bytes // 8
+
+
+@pytest.mark.parametrize("entry,passes", [("optax", 5), ("in_place", 2)])
+def test_a_gradient_handed_over_in_pieces_is_joined_once_on_a_v5e(
+        v5e, entry, passes):
+    """A layer loop that unrolls a period (``w[0, i]`` for every ``i`` in
+    one body) hands a stacked leaf's gradient over as pieces.  Left free,
+    XLA duplicates their join into every consumer and the update falls
+    into four fusions over the operands (the Granite cell before PR 67);
+    behind ``update_in_place``'s barrier the pieces are joined once and
+    the update is one fusion."""
+    import optax
+    from deepspeed_tpu.runtime.bf16_optimizer import mp_adamw
+    from deepspeed_tpu.runtime.step_programs import global_norm
+    from deepspeed_tpu.telemetry.numerics import group_stats
+    layers, experts, d, f, tokens = 9, 4, 1024, 512, 2048
+    shape = (1, layers, experts, d, f)
+    tx = mp_adamw(1e-4, weight_decay=0.1, mu_dtype="bfloat16",
+                  nu_dtype="bfloat16", master_dtype="bfloat16")
+
+    def loss(params, x):
+        for i in range(layers):
+            y = jnp.einsum("td,edf->etf", x, params["w"][0, i])
+            x = x + jnp.einsum("etf,efd->td", jax.nn.silu(y),
+                               params["w2"][0, i])
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    def step(params, state, x):
+        grads = jax.grad(loss)(params, x)
+        if entry == "in_place":
+            return tx.update_in_place(grads, state, params)
+        # what ``apply_grads`` asks for beside the optax entry
+        updates, state = tx.update(grads, state, params)
+        return (optax.apply_updates(params, updates), state,
+                (global_norm(grads), group_stats(grads, [0, 1], 2),
+                 global_norm(updates), global_norm(params)))
+
+    params = {"w": _arg(v5e[0], shape),
+              "w2": _arg(v5e[0], (1, layers, experts, f, d))}
+    state = jax.tree.map(lambda x: _arg(v5e[0], x.shape, x.dtype),
+                         jax.eval_shape(tx.init, params))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, state, _arg(v5e[0], (tokens, d))).compile()
+    # the model's own matmuls read ``w`` too; so do the copies in and out
+    # that the toy's einsum costs (it wants another layout than the
+    # entry's), on both entries
+    fusions = [row for row in _passes_over(compiled, shape)
+               if "dot_general" not in row[3] and "copy" not in row[0]]
+    assert len(fusions) == passes, fusions
+    if entry == "in_place":
+        # the join writes the gradient once; the update reads the five
+        # operands and writes four
+        assert sorted(row[1:3] for row in fusions) == [(0, 1), (5, 4)]
+
+
 def test_flash_vmem_budget_is_the_device_kinds(monkeypatch):
     """S 8192 at head width 256, packed, stages 29 MB: over what Mosaic
     grants unasked, inside a v5e's budget — and only then do the calls ask
@@ -1006,7 +1121,8 @@ def test_library_knows_the_chips_peaks(v5e):
     "scripts/kda_rule_bench.py",
     "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py",
     "scripts/latent_attention_table.py --seed 1",
-    "scripts/latent_attention_table.py --bits"])
+    "scripts/latent_attention_table.py --bits",
+    "scripts/optimizer_table.py --seed 1"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(
