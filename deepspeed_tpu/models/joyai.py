@@ -68,8 +68,8 @@ from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
                                         refuse_param_stream, resolve_size,
                                         segment_ids_of, token_loss)
 from deepspeed_tpu.models.llama import _rms_norm, rope
-from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_logical_specs)
+from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
+                                     moe_logical_specs, named_sums)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_BLOCK, SCOPE_EMBED, SCOPE_HEAD_LOSS, SCOPE_IN_PROJ,
@@ -388,7 +388,7 @@ def hidden_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
     x, (aux, over) = lax.scan(
         layer_block(_expert_block, config, train=train, rng=rng,
                     segment_ids=seg), x, params["blocks"])
-    return x, jnp.sum(aux), jnp.sum(over)
+    return x, jnp.sum(aux), jnp.sum(over, 0)
 
 
 def _logits(x, norm_w, lm_head, config: JoyAIConfig):
@@ -540,7 +540,7 @@ def loss_with_counts(params, batch, config, rng=None, stack=None):
             loss = loss + mtp_aux + config.mtp_loss_weight * mtp_loss(
                 h, params["mtp"]["final_norm"], params["lm_head"])
             over = over + mtp_over
-    return loss, {ROWS_OVER_BOUND: over}
+    return loss, named_sums(over)
 
 
 def count_params(config: JoyAIConfig) -> int:

@@ -59,8 +59,8 @@ from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
                                         scan_layer_kinds, segment_ids_of,
                                         token_loss)
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.moe.layer import (ROWS_OVER_BOUND, MoEConfig,
-                                     init_moe_params, moe_logical_specs)
+from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
+                                     moe_logical_specs, named_sums)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_BLOCK,
@@ -384,7 +384,7 @@ def hidden_with_aux(params, batch, config: LagunaConfig, train: bool = True,
     if config.tail_layers:
         x, (tail_aux, tail_over) = lax.scan(block_fns[SLIDING], x,
                                             params["tail"])
-        aux, over = aux + jnp.sum(tail_aux), over + jnp.sum(tail_over)
+        aux, over = aux + jnp.sum(tail_aux), over + jnp.sum(tail_over, 0)
     return x, aux, over
 
 
@@ -403,11 +403,12 @@ def forward_with_aux(params, batch, config: LagunaConfig, train: bool = True,
 
 
 def loss_with_counts(params, batch, config: LagunaConfig, rng=None):
-    """-> (cross-entropy + router losses, {rows over the bound})."""
+    """-> (cross-entropy + router losses, {rows over the bound, and the
+    step's load: ``moe/layer.py named_sums``})."""
     logits, aux, over = forward_with_aux(params, batch, config, True, rng)
     with jax.named_scope(SCOPE_HEAD_LOSS):
         loss = token_loss(logits, batch)
-    return loss + aux, {ROWS_OVER_BOUND: over}
+    return loss + aux, named_sums(over)
 
 
 def layers_in_order(params, config: LagunaConfig):

@@ -274,7 +274,7 @@ def hidden_with_aux(params, batch, config: MellumConfig, train: bool = True,
         # the step no longer fits the chip)
         def fn(x, layers):
             x, (aux, over) = lax.scan(block_fns[kind], x, layers)
-            return x, (jnp.sum(aux), jnp.sum(over))
+            return x, (jnp.sum(aux), jnp.sum(over, 0))
         return fn
 
     aux = over = 0
@@ -287,7 +287,7 @@ def hidden_with_aux(params, batch, config: MellumConfig, train: bool = True,
     if config.tail_layers:
         x, (tail_aux, tail_over) = lax.scan(block_fns[SLIDING], x,
                                             params["tail"])
-        aux, over = aux + jnp.sum(tail_aux), over + jnp.sum(tail_over)
+        aux, over = aux + jnp.sum(tail_aux), over + jnp.sum(tail_over, 0)
     return x, aux, over
 
 
@@ -386,11 +386,12 @@ head_nll_sum.defvjp(_head_nll_fwd, _head_nll_bwd)
 
 
 def loss_with_counts(params, batch, config: MellumConfig, rng=None):
-    """-> (cross-entropy + router losses, {rows over a bound}).  The
+    """-> (cross-entropy + router losses, {rows over a bound, and the
+    step's load: ``moe/layer.py named_sums``}).  The
     cross-entropy is ``models.model.token_loss``'s — position t against
     token t + 1, inside a document, not where ``attention_mask`` is 0 —
     through :func:`head_nll_sum`."""
-    from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
+    from deepspeed_tpu.moe.layer import named_sums
     x, aux, over = hidden_with_aux(params, batch, config, True, rng)
     with jax.named_scope(SCOPE_HEAD_LOSS):
         h = _rms_norm(x, params["final_norm"], config.norm_eps)
@@ -407,7 +408,7 @@ def loss_with_counts(params, batch, config: MellumConfig, rng=None):
             h, params["lm_head"].astype(h.dtype), jnp.roll(ids, -1, axis=1),
             scored)
         loss = total / jnp.maximum(jnp.sum(scored), 1.0)
-    return loss + aux, {ROWS_OVER_BOUND: over}
+    return loss + aux, named_sums(over)
 
 
 def layers_in_order(params, config: MellumConfig):

@@ -19,7 +19,8 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.model import Model, qdot, resolve_size, token_loss
 from deepspeed_tpu.models.llama import _rms_norm, rope
-from deepspeed_tpu.moe.layer import MoEConfig, moe_layer
+from deepspeed_tpu.moe.layer import (STEP_LOAD, MoEConfig, layer_sums,
+                                     moe_layer)
 from deepspeed_tpu.moe.sharded_moe import topkgating
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
@@ -184,14 +185,17 @@ def _qkv(x, layer, config: MixtralConfig, positions=None):
 
 def _moe_finish(x, attn_flat, layer, config: MixtralConfig, train: bool,
                 rng=None):
-    """Attention output projection + residual + routed-expert FFN."""
+    """Attention output projection + residual + routed-expert FFN ->
+    (x, (router loss, the layer's int32 sums: ``moe/layer.py
+    layer_sums``))."""
     with jax.named_scope(SCOPE_ATTN):
         x = x + qdot(attn_flat, layer["wo"])
     with jax.named_scope(SCOPE_MLP):
         h = _rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
-        moe_out, aux = moe_layer(layer["moe"], h, config.moe, train=train,
-                                 rng=rng)
-        return x + moe_out, aux
+        moe_out, aux, stats = moe_layer(layer["moe"], h, config.moe,
+                                        train=train, rng=rng,
+                                        return_stats=True)
+        return x + moe_out, (aux, layer_sums(stats))
 
 
 @jax.named_scope(SCOPE_BLOCK)
@@ -211,6 +215,8 @@ def _block(carry, layer, config: MixtralConfig, train: bool, rng=None,
 
 def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
                      rng=None):
+    """-> (logits, router loss summed over layers, the layers' int32 sums
+    added up: ``moe/layer.py layer_sums``)."""
     tokens = batch["input_ids"]
     dtype = jnp.dtype(config.dtype)
     with jax.named_scope(SCOPE_EMBED):
@@ -225,10 +231,11 @@ def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
         from deepspeed_tpu.models.model import remat_policy
         block_fn = jax.checkpoint(
             block_fn, policy=remat_policy(config.remat_policy))
-    x, aux = lax.scan(block_fn, x, params["blocks"])
+    x, (aux, sums) = lax.scan(block_fn, x, params["blocks"])
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.rms_norm_eps)
-        return x @ params["lm_head"].astype(dtype), jnp.sum(aux)
+        return (x @ params["lm_head"].astype(dtype), jnp.sum(aux),
+                jnp.sum(sums, 0))
 
 
 # --------------------------------------------------------------------- decode
@@ -328,19 +335,25 @@ def mixtral_model(size: str = "8x7b", **overrides) -> Model:
     active = n_params - (1 - config.top_k / config.num_experts) * (
         3 * config.num_layers * config.num_experts * config.d_model * config.d_ff)
 
-    def loss_fn(params, batch, rng=None):
-        logits, aux = forward_with_aux(params, batch, config, train=True, rng=rng)
+    def loss_with_load(params, batch, rng=None):
+        logits, aux, sums = forward_with_aux(params, batch, config,
+                                             train=True, rng=rng)
         with jax.named_scope(SCOPE_HEAD_LOSS):
             # inside a document only, where the batch is packed; aux = the
             # weighted router losses summed over layers (moe/layer.py)
-            return token_loss(logits, batch) + aux
+            return token_loss(logits, batch) + aux, \
+                dict(zip(STEP_LOAD, sums[1:]))
 
     return Model(
         config=config,
         init_fn=partial(init_params, config),
         apply_fn=lambda p, b, rng=None: forward_with_aux(
             p, b, config, train=False, rng=rng)[0],
-        loss_fn=loss_fn,
+        loss_fn=lambda p, b, rng=None: loss_with_load(p, b, rng)[0],
+        # nothing is left out of this loss (no bound; an einsum's capacity
+        # drops are the reference's semantics), so no ``step_counts``: what
+        # leaves the step beside it is its load (``engine.step_load()``)
+        loss_with_counts_fn=loss_with_load,
         logical_specs=logical_specs(config),
         flops_per_token=6.0 * active,
         meta={"name": f"mixtral-{size}", "n_params": n_params,

@@ -365,17 +365,17 @@ def segment_ids_of(batch):
 
 def expert_branch(x, moe_params, moe_config, norm, train, rng=None):
     """The expert half of a block without its residual: ``MoE(norm(x))``
-    under the ``mlp`` scope -> (the branch, (router loss float32, routed
-    rows over ``held_rows_bound`` int32)), the pair being what a layer
-    adds to a loop's sums."""
+    under the ``mlp`` scope -> (the branch, (router loss float32, the
+    layer's int32 sums: routed rows over ``held_rows_bound``, then its load
+    — ``moe/layer.py layer_sums``)), the pair being what a layer adds to a
+    loop's sums."""
     import jax.numpy as jnp
-    from deepspeed_tpu.moe.layer import moe_layer
+    from deepspeed_tpu.moe.layer import layer_sums, moe_layer
     from deepspeed_tpu.telemetry.tracing import SCOPE_MLP
     with jax.named_scope(SCOPE_MLP):
         out, aux, stats = moe_layer(moe_params, norm(x), moe_config,
                                     train=train, rng=rng, return_stats=True)
-        return out, (aux.astype(jnp.float32),
-                     stats["dropped"].astype(jnp.int32))
+        return out, (aux.astype(jnp.float32), layer_sums(stats))
 
 
 def expert_half(x, moe_params, moe_config, norm, train, rng=None):
@@ -389,9 +389,10 @@ def expert_half(x, moe_params, moe_config, norm, train, rng=None):
 
 def no_experts():
     """What a layer without experts adds to the router loss and to the
-    rows over ``held_rows_bound``."""
+    expert layers' sums (:func:`expert_branch`)."""
     import jax.numpy as jnp
-    return jnp.float32(0.0), jnp.int32(0)
+    from deepspeed_tpu.moe.layer import STEP_SUMS
+    return jnp.float32(0.0), jnp.zeros(len(STEP_SUMS), jnp.int32)
 
 
 def param_count(init_fn) -> int:
@@ -443,7 +444,8 @@ class Model:
     #: (``metrics["counts"]``); the engine adds them up under their names
     #: (``engine.step_counts()``, the registry's ``train/step_counts``) and
     #: warns of one that is not zero; ``meta["step_counts"]`` = {name: what
-    #: it counts} words the warning.
+    #: it counts} says which names are such counts and words the warning;
+    #: any other name is the step's load (``engine.step_load()``).
     loss_with_counts_fn: Optional[Callable] = None
     #: pytree of jax.sharding.PartitionSpec (or None) matching params — the
     #: tensor-parallel ("model" axis) layout. ZeRO axes are layered on top.
@@ -506,7 +508,7 @@ def held_share_model(family: str, size: str, config, *, init_params,
     hold a share of their experts (``config.moe``: ``experts_held`` of
     ``num_experts``).  The family hands over ``init_params(config, rng)``,
     ``logical_specs(config)``, ``forward_with_aux(params, batch, config,
-    train=, rng=) -> (logits, router loss, rows over the bound)`` and,
+    train=, rng=) -> (logits, router loss, the expert layers' sums)`` and,
     where its loss is more than the one head's cross-entropy and the
     router loss, ``loss_with_counts(params, batch, config, rng) -> (loss,
     {name: count})``.  Counted here, for ``flops_per_token = 6 * active``:
@@ -516,13 +518,14 @@ def held_share_model(family: str, size: str, config, *, init_params,
     embedding), ``reused_params`` multiplied a second time (a head behind
     a second module).  The four serving entry points raise, naming
     ``serving_needs``; ``meta`` is the family's own beside ``name``,
-    ``n_params``, ``active_params`` and ``step_counts``.  The counts
+    ``n_params``, ``active_params`` and ``step_counts`` (which of the sums
+    the loss left out: the rest is ``engine.step_load()``).  The counts
     always leave the step: rows are bounded where a share is held and
     where the layers exchange rows over an ``expert`` mesh axis, which the
     mesh decides after the model is built; where nothing is bounded the
     count is a zero."""
     from functools import partial
-    from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND
+    from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND, named_sums
     from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
     moe = config.moe
     n_params = param_count(partial(init_params, config))
@@ -538,8 +541,7 @@ def held_share_model(family: str, size: str, config, *, init_params,
             with jax.named_scope(SCOPE_HEAD_LOSS):
                 # inside a document only, where the batch is packed; aux =
                 # the weighted load-balancing loss summed over layers
-                return token_loss(logits, batch) + aux, \
-                    {ROWS_OVER_BOUND: over}
+                return token_loss(logits, batch) + aux, named_sums(over)
 
     def with_counts(params, batch, rng=None):
         return loss_with_counts(params, batch, config, rng)

@@ -350,7 +350,7 @@ def hidden_with_aux(params, batch, config: XingConfig, train: bool = True,
         layer_block(_expert_block, config, train=train, rng=rng,
                     segment_ids=seg, calls=config.expert_layers),
         x, params["blocks"])
-    return _exit_sum(x, config.hc_mult), jnp.sum(aux), jnp.sum(over)
+    return _exit_sum(x, config.hc_mult), jnp.sum(aux), jnp.sum(over, 0)
 
 
 def mtp_hidden_with_aux(params, x, batch, config: XingConfig,

@@ -341,38 +341,38 @@ def _report_held_plan(live, plan_rows):
     reg.set_gauge(HELD_PLAN_ROWS, float(plan_rows))
 
 
-def _emit_held_plan(plan):
+def _emit_held_plan(load):
     """How much of a held plan is live, through the registry tap alone (as
-    the router's health: traced only when a tap is installed): per expert
-    layer that runs, :data:`HELD_LIVE_ROWS` — ``used_blocks`` tiles of
-    rows, what dispatch, the grouped kernels and combine walk — beside
+    the router's health: traced only when a tap is installed; a train step
+    returns the same record, :func:`step_load`, beside its loss): per
+    expert layer that runs, :data:`HELD_LIVE_ROWS` — ``used_blocks`` tiles
+    of rows, what dispatch, the grouped kernels and combine walk — beside
     :data:`HELD_PLAN_ROWS`, the plan's static length."""
     if _metrics_registry is None:
         return
-    from deepspeed_tpu.ops.pallas.grouped_gemm import live_rows
-    jax.debug.callback(_report_held_plan, live_rows(plan),
-                       jnp.int32(plan.padded_rows))
+    jax.debug.callback(_report_held_plan, load[HELD_LIVE_ROWS],
+                       load[HELD_PLAN_ROWS])
 
 
-def _report_exchanged(sent, received, wire_share):
+def _report_exchanged(sent, received, wire, routed):
     reg = _metrics_registry
     if reg is None:
         return
     reg.set_gauge(EXCHANGE_ROWS_SENT, float(sent))
     reg.set_gauge(EXCHANGE_ROWS_RECEIVED, float(received))
-    reg.set_gauge(EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW, float(wire_share))
+    reg.set_gauge(EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW,
+                  float(wire) / float(routed))
 
 
-def _emit_exchanged(sizes, routed):
+def _emit_exchanged(load, routed):
     """Beside :func:`_emit_held_plan`, through the registry tap alone: the
     (token, chip) rows this chip sent and received, and of those it sent
     the ones that left it — the wire's — a routed (token, expert) row."""
     if _metrics_registry is None:
         return
-    sent = jnp.sum(sizes.rows.send)
-    wire = sent - sizes.rows.send[jax.lax.axis_index(EXPERT_AXIS)]
-    jax.debug.callback(_report_exchanged, sent, jnp.sum(sizes.rows.held),
-                       wire.astype(jnp.float32) / routed)
+    jax.debug.callback(_report_exchanged, load[EXCHANGE_ROWS_SENT],
+                       load[EXCHANGE_ROWS_RECEIVED],
+                       load[EXCHANGE_WIRE_ROWS], jnp.int32(routed))
 
 
 def _emit_router_health(logits, routing, config: MoEConfig):
@@ -447,7 +447,7 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     gate's cotangent is the row sum of ``dh · act`` in the pass that forms
     the halves' cotangents.  (The decode-sized branch, which has no plan,
     weights its rows on the way back.)  Returns (combined [T, D], aux
-    scalar, (dispatched, dropped))."""
+    scalar, (dispatched, dropped, the call's :func:`step_load`))."""
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     T, D = xt.shape
     E, k = config.num_experts, config.top_k
@@ -462,7 +462,7 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
     w_gate = params.get("w_gate")
     w_in, w_out = params["w_in"], params["w_out"]
 
-    R = T * k
+    R, load = T * k, {}
     if expert_axis_size() > 1:
         return _exchanged_grouped_moe(params, xt, config, routing, eids,
                                       gates)
@@ -491,6 +491,7 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
         # calls compute (rows past the routed ones are zeros)
         count_in_step(grouped_routed_rows=R,
                       grouped_padded_rows=plan.padded_rows)
+        load = step_load(plan, R, E)
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
             h = _glu(mm, x_pad, w_gate, w_in, config,
@@ -499,7 +500,7 @@ def _grouped_moe(params, xt, config: MoEConfig, train: bool, rng):
         with jax.named_scope(SCOPE_COMBINE):
             combined = gg.sum_rows(y, plan, k)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
-    return combined, aux, (jnp.int32(R), jnp.int32(0))
+    return combined, aux, (jnp.int32(R), jnp.int32(0), load)
 
 
 def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
@@ -524,9 +525,11 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
         plan, over = gg.make_held_group_plan(
             eids, config.expert_offset, config.held, bound)
         x_pad = gg.dispatch_held_rows(xt, plan, k)          # [Mp, D]
-    _emit_held_plan(plan)
+    load = step_load(plan, R, config.num_experts)
+    _emit_held_plan(load)
     # shapes all: ``grouped_routed_rows`` is the EXPECTED number of held
-    # rows under even routing (the true one is data)
+    # rows under even routing (``moe/even_rows``); the true one is data and
+    # leaves the step beside it: ``moe/routed_rows`` (:func:`step_load`)
     count_in_step(grouped_routed_rows=R * config.held // config.num_experts,
                   grouped_padded_rows=plan.padded_rows,
                   held_rows_bound=bound, experts_held=config.held,
@@ -539,7 +542,7 @@ def _held_grouped_moe(params, xt, config: MoEConfig, routing, eids, gates):
     with jax.named_scope(SCOPE_COMBINE):
         combined = gg.combine_held_rows(y, gates, plan, k)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
-    return combined, aux, (jnp.sum(plan.counts) - over, over)
+    return combined, aux, (load[ROUTED_ROWS] - over, over, load)
 
 
 def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
@@ -620,7 +623,7 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
     weights come in as this chip's ``[E / n, ...]`` slices and their
     gradients leave so — reduced over no chip of the ``expert`` axis.
     Returns as :func:`_held_grouped_moe` does, the counts — of (token,
-    expert) rows — summed over the chips."""
+    expert) rows — and the load summed over the chips."""
     from deepspeed_tpu.moe import mappings
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     from deepspeed_tpu.utils.jax_compat import shard_map
@@ -700,8 +703,9 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
     def on_chip(xt, eids, gates, weights):
         out = _rows_to_experts(xt, eids, gates, E, bound)
         plan, by_chip, sizes = out.plan, out.by_chip, out.sizes
-        _emit_held_plan(plan)
-        _emit_exchanged(sizes, R)
+        load = step_load(plan, n * R, E, sizes)
+        _emit_held_plan(load)
+        _emit_exchanged(load, R)
         mm = partial(gg.ds_ggemm, plan=plan, out_dtype=dt)
         with jax.named_scope(SCOPE_EXPERTS):
             h = _glu(mm, out.x_pad, weights.get("w_gate"), weights["w_in"],
@@ -717,7 +721,9 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             combined = _after(gg.sum_held_rows(back, by_chip, n),
                               jax.lax.stop_gradient(h[0, 0]))
         dropped = sizes.over + out.over
-        return combined, jnp.stack([jnp.int32(R) - sizes.over, dropped])[None]
+        return combined, jnp.stack(
+            [jnp.int32(R) - sizes.over, dropped,
+             *(load[name] for name in STEP_LOAD)])[None]
 
     tok = P(tok_axes)
     combined, counts = shard_map(
@@ -728,9 +734,10 @@ def _exchanged_grouped_moe(params, xt, config: MoEConfig, routing, eids,
             xt, eids, gates, weights)
     counts = jnp.sum(counts, axis=0).astype(jnp.int32)
     aux = routing.l_aux * config.aux_loss_coef + routing.router_z_loss
+    load = dict(zip(STEP_LOAD, counts[2:]))
     if pad:
-        return combined[:T], aux, (counts[0] - pad * k, counts[1])
-    return combined, aux, (counts[0], counts[1])
+        return combined[:T], aux, (counts[0] - pad * k, counts[1], load)
+    return combined, aux, (counts[0], counts[1], load)
 
 
 class _AtTheExperts(NamedTuple):
@@ -815,6 +822,76 @@ EXCHANGE_ROWS_RECEIVED = "moe/exchange_rows_received"
 #: and what of the sent rows crossed to another chip, a routed (token,
 #: expert) row: a token's row crosses to a chip once, so (n - 1) / k at most
 EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW = "moe/exchange_wire_rows_per_routed_row"
+#: the rest of a routed step's load (:func:`step_load`), which leaves a
+#: train step as the five above and :data:`ROWS_OVER_BOUND` do: the (token,
+#: choice) rows sent to the experts held here before any bound, what that
+#: is under even routing, one expert's even share, the fullest held
+#: expert's rows; the sent rows that left their chip; and, the same on
+#: every chip of an exchange and so counted once: the rows the fullest
+#: chip's plan received, and the mean over the chips
+ROUTED_ROWS = "moe/routed_rows"
+EVEN_ROWS = "moe/even_rows"
+EVEN_EXPERT_ROWS = "moe/even_expert_rows"
+FULLEST_EXPERT_ROWS = "moe/fullest_expert_rows"
+EXCHANGE_WIRE_ROWS = "moe/exchange_wire_rows"
+FULLEST_CHIP_ROWS = "moe/fullest_chip_rows"
+MEAN_CHIP_ROWS = "moe/mean_chip_rows"
+#: the load facts in the order they ride, and the int32 sums one expert
+#: layer-call hands the layer loop of its model (:func:`layer_sums`)
+STEP_LOAD = (ROUTED_ROWS, EVEN_ROWS, EVEN_EXPERT_ROWS, FULLEST_EXPERT_ROWS,
+             HELD_LIVE_ROWS, HELD_PLAN_ROWS, EXCHANGE_ROWS_SENT,
+             EXCHANGE_ROWS_RECEIVED, EXCHANGE_WIRE_ROWS, FULLEST_CHIP_ROWS,
+             MEAN_CHIP_ROWS)
+STEP_SUMS = (ROWS_OVER_BOUND,) + STEP_LOAD
+
+
+def step_load(plan, routed: int, num_experts: int, sizes=None) -> dict:
+    """What the router did to one expert layer-call, {name: int32}: of the
+    ``routed`` (token, choice) rows routed over all ``num_experts`` by
+    everyone who sends to ``plan`` — the plan of the experts held here, the
+    receive plan of an exchange whose ``sizes`` (``ExchangeSizes``) then
+    add the wire's.  The ONE record of both sinks: a train step returns it
+    (``moe_layer(return_stats=True)["load"]``, summed over layers,
+    micro-batches and chips where :data:`ROWS_OVER_BOUND` is, to
+    ``engine.step_load()``), the registry tap reads its gauges from it by
+    callback.  Every value a sum's term: ratios are made on the host."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import live_rows
+    load = {ROUTED_ROWS: jnp.sum(plan.counts),
+            EVEN_ROWS: routed * plan.num_experts // num_experts,
+            EVEN_EXPERT_ROWS: (routed + num_experts // 2) // num_experts,
+            FULLEST_EXPERT_ROWS: jnp.max(plan.counts),
+            HELD_LIVE_ROWS: live_rows(plan),
+            HELD_PLAN_ROWS: plan.padded_rows}
+    if sizes is not None:
+        me = jax.lax.axis_index(EXPERT_AXIS)
+        sent = jnp.sum(sizes.rows.send)
+        # the table is every chip's: its two numbers leave from one
+        once = (me == 0).astype(jnp.int32)
+        load.update({
+            # a plan's counts are what its senders kept inside the bound
+            ROUTED_ROWS: load[ROUTED_ROWS] + sizes.over,
+            EXCHANGE_ROWS_SENT: sent,
+            EXCHANGE_ROWS_RECEIVED: jnp.sum(sizes.rows.held),
+            EXCHANGE_WIRE_ROWS: sent - sizes.rows.send[me],
+            FULLEST_CHIP_ROWS: once * jnp.max(sizes.chip_rows),
+            MEAN_CHIP_ROWS: once * (jnp.sum(sizes.chip_rows)
+                                    // sizes.chip_rows.shape[0])})
+    return {name: jnp.asarray(v, jnp.int32) for name, v in load.items()}
+
+
+def layer_sums(stats: dict):
+    """[len(:data:`STEP_SUMS`)] int32 of one layer-call's ``stats``
+    (``moe_layer(return_stats=True)``): the rows it left out, then its
+    load, 0 where its path has no such fact — what a model's layer loop
+    adds up, and :func:`named_sums` names again."""
+    return jnp.stack([stats["dropped"].astype(jnp.int32)] + [
+        stats["load"].get(name, jnp.int32(0)) for name in STEP_LOAD])
+
+
+def named_sums(sums) -> dict:
+    """{name: int32 scalar} of :func:`layer_sums` added up: what a model's
+    ``loss_with_counts_fn`` returns beside its loss."""
+    return dict(zip(STEP_SUMS, sums))
 
 
 def _route(params, logits, config: MoEConfig, train: bool, rng):
@@ -940,7 +1017,8 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
     """x: [B, S, D] -> (out [B, S, D], aux_loss scalar), and with
     ``return_stats`` a third: ``{"dispatched", "dropped"}``, int32 counts
     of routed rows computed and left out (einsum: past capacity; a held
-    subset: past ``held_rows_bound``).
+    subset: past ``held_rows_bound``), and ``"load"``: what the call's
+    path has of :func:`step_load`.
 
     einsum mode: the reference's MOELayer.forward (sharded_moe.py:477)
     step-for-step, with einsum dispatch in place of explicit
@@ -962,13 +1040,13 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
     xt = wsc(x.reshape(T, D), tok_sh)
     mode = resolve_dispatch_mode(config, train)
     if mode == "grouped":
-        combined, aux, (n_disp, n_drop) = _grouped_moe(
+        combined, aux, (n_disp, n_drop, load) = _grouped_moe(
             params, xt, config, train, rng)
         _emit_routing_stats(n_disp, n_drop)
         moe_out = wsc(combined, tok_sh).reshape(B, S, D)
         out = _finish_residual(params, x, moe_out, aux, config)
-        return out + ({"dispatched": n_disp, "dropped": n_drop},) \
-            if return_stats else out
+        return out + ({"dispatched": n_disp, "dropped": n_drop,
+                       "load": load},) if return_stats else out
     if config.holds_subset:
         raise ValueError(
             f"moe: a held subset of the experts ({config.held} of "
@@ -1007,8 +1085,18 @@ def moe_layer(params: dict, x: jnp.ndarray, config: MoEConfig,
     aux = gate.l_aux * config.aux_loss_coef + gate.router_z_loss
     moe_out = combined.reshape(B, S, D)
     out = _finish_residual(params, x, moe_out, aux, config)
-    return out + ({"dispatched": kept, "dropped": n_drop},) \
-        if return_stats else out
+    if not return_stats:
+        return out
+    # no plan: the rows routed, the fullest expert's, and the slots the
+    # capacity tensors hold (the even numbers are the routed ones')
+    R, E = T * config.top_k, config.num_experts
+    fullest = jnp.max(jnp.sum(
+        routing.expert_idx.reshape(-1, 1) == jnp.arange(E), axis=0))
+    load = {ROUTED_ROWS: R, EVEN_ROWS: R, EVEN_EXPERT_ROWS: (R + E // 2) // E,
+            FULLEST_EXPERT_ROWS: fullest,
+            HELD_PLAN_ROWS: E * dispatch_m.shape[-1]}
+    return out + ({"dispatched": kept, "dropped": n_drop, "load": {
+        name: jnp.asarray(v, jnp.int32) for name, v in load.items()}},)
 
 
 def _finish_residual(params, x, moe_out, aux, config: MoEConfig):

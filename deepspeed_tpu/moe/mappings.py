@@ -139,6 +139,9 @@ class ExchangeSizes(NamedTuple):
     #: chip, and the place it has in that pair's slice (its tokens' order)
     to_chip: jnp.ndarray
     at: jnp.ndarray
+    #: [pairs], the same on every chip: the (token, expert) rows each
+    #: chip's plan receives from all chips together (the table's sums)
+    chip_rows: jnp.ndarray
 
 
 def make_exchange_sizes(chosen: jnp.ndarray, routed: int, bound: int,
@@ -206,7 +209,8 @@ def make_exchange_sizes(chosen: jnp.ndarray, routed: int, bound: int,
                              ends[:-1]]).astype(jnp.int32)
     return ExchangeSizes(wide, lanes, received[me],
                          jnp.sum(rows[me] - kept[me]).astype(jnp.int32),
-                         first, ends, to_chip, at)
+                         first, ends, to_chip, at,
+                         jnp.sum(received, axis=-1).astype(jnp.int32))
 
 
 #: the two ways an exchange's rows travel, as ``tracing.exchange_calls``

@@ -734,6 +734,10 @@ class DeepSpeedEngine:
         # first, until they are ready; and their sums so far
         self._pending_counts = collections.deque()
         self._step_counts = {}
+        # what came beside them and is not a count of what the loss left
+        # out: the steps' load (step_load())
+        self._step_load = {"steps": 0, "totals": {},
+                           "last": collections.deque(maxlen=64)}
         self.micro_steps = 0
         self.timers = SynchronizedWallClockTimer()
         self.tput_timer = ThroughputTimer(
@@ -1113,26 +1117,50 @@ class DeepSpeedEngine:
         self._resolve_step_counts(wait=True)
         return dict(self._step_counts)
 
+    def step_load(self) -> dict:
+        """What the router did to the steps run, from the sums that leave
+        the fused step beside the counts (``moe/layer.py step_load``: every
+        name a sum over a step's expert layer-calls, micro-batches and
+        chips; a fact no layer of the step made reads 0 and is left out):
+        ``{"steps": resolved steps, "totals": {name: sum over them},
+        "last": [{name: value} a step, newest last, at most 64]}``.  The
+        last resolved step's are the registry's gauges of those names.
+        Never a count: nothing here warns.  Waits for the steps in
+        flight."""
+        self._resolve_step_counts(wait=True)
+        load = self._step_load
+        return {"steps": load["steps"], "totals": dict(load["totals"]),
+                "last": [dict(step) for step in load["last"]]}
+
     def _resolve_step_counts(self, wait: bool):
         """Add up the banked counts — only those whose step has ended
         unless ``wait`` — and warn of each that is not zero: the model
-        left that much out of the step's loss."""
+        left that much out of the step's loss.  A name the model does not
+        state among its ``step_counts`` is the step's load."""
         said = self.model.meta.get("step_counts", {})
         while self._pending_counts:
             step, counts = self._pending_counts[0]
             if not wait and not all(c.is_ready() for c in counts.values()):
                 return
             self._pending_counts.popleft()
-            for name, value in jax.device_get(counts).items():
-                value = int(value)
+            counts = {n: int(v) for n, v in jax.device_get(counts).items()}
+            load = {n: v for n, v in counts.items() if n not in said and v}
+            if load:
+                self._step_load["steps"] += 1
+                self._step_load["last"].append(load)
+            totals = self._step_load["totals"]
+            for name, value in load.items():
+                totals[name] = totals.get(name, 0) + value
+                self.telemetry_registry.set_gauge(name, float(value))
+            for name in said:
+                value = counts.get(name, 0)
                 self._step_counts[name] = \
                     self._step_counts.get(name, 0) + value
                 if value:
                     self.telemetry_registry.inc("train/step_counts",
                                                 float(value), count=name)
                     logger.warning(f"train step {step}: {name} = {value}"
-                                   + (f" ({said[name]})"
-                                      if name in said else ""))
+                                   f" ({said[name]})")
 
     def _build_monitor(self):
         try:
@@ -1651,7 +1679,7 @@ class DeepSpeedEngine:
                 self._step_ctx, qgz_fn=self._qgz_grad_fn(),
                 plan=self._get_qgz_plan(), nf_group=nf_group)
         if (name in ("grad", "grad_step", "grad_micro")
-                and self.model.loss_with_counts_fn is not None):
+                and self.model.meta.get("step_counts")):
             from deepspeed_tpu.utils.logging import warning_once
             warning_once(
                 f"{name}: only the fused train step returns the model's "
@@ -2499,7 +2527,9 @@ class DeepSpeedEngine:
                     detail={"tokens_per_step": tokens})
             publish_report(engine.telemetry_registry, report)
             return report
-        register_program(TRAIN_STEP_PROGRAM, step_text, step_bytes, step_cost)
+        register_program(
+            TRAIN_STEP_PROGRAM, step_text, step_bytes, step_cost,
+            lambda: alive() and alive().step_load())
 
     def compile_train_step(self, batch):
         """The fused step ``train_batch`` runs for ``batch`` (leaves lead

@@ -609,7 +609,7 @@ _PROGRAM_LOCK = threading.Lock()
 #: askers at once make one load and a registrant's thunks may share what
 #: they keep; never held with _PROGRAM_LOCK wanted by a peek
 _PROGRAM_ASK_LOCK = threading.RLock()
-#: name -> {"text": thunk, "memory": thunk or None, "cost": thunk or None}
+#: name -> {"text": thunk, "memory" / "cost" / "load": thunk or None}
 _PROGRAM_THUNKS: Dict[str, Dict[str, Optional[Callable[[], Any]]]] = {}
 #: name -> what the thunks gave when first asked, by the same keys (the
 #: text as its parsed map)
@@ -618,18 +618,20 @@ _PROGRAM_FACTS: Dict[str, Dict[str, Any]] = {}
 
 def register_program(name: str, text_thunk: Callable[[], Optional[str]],
                      memory_thunk: Optional[Callable[[], Any]] = None,
-                     cost_thunk: Optional[Callable[[], Any]] = None):
+                     cost_thunk: Optional[Callable[[], Any]] = None,
+                     load_thunk: Optional[Callable[[], Any]] = None):
     """Publish a program under ``name``.  ``text_thunk()`` returns the
     HLO text of the executable that runs, ``memory_thunk()`` what is
     counted of its bytes per device (telemetry/memory.py
     ``step_memory`` says which keys), ``cost_thunk()`` its
-    telemetry/costmodel.py ``CostReport``; each returns None if it can
+    telemetry/costmodel.py ``CostReport``, ``load_thunk()`` the load of
+    the steps it has run (:func:`step_load`); each returns None if it can
     no longer be had.  None is called here — only by the first
     :func:`get_program_map` / :func:`get_program_memory` /
-    :func:`get_program_cost` that asks."""
+    :func:`get_program_cost` that asks, and by every :func:`step_load`."""
     with _PROGRAM_LOCK:
         _PROGRAM_THUNKS[name] = {"text": text_thunk, "memory": memory_thunk,
-                                 "cost": cost_thunk}
+                                 "cost": cost_thunk, "load": load_thunk}
         _PROGRAM_FACTS.pop(name, None)
 
 
@@ -801,6 +803,18 @@ def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
         rows["calls"] = [account["grouped_calls"][key]
                          for key in sorted(account["grouped_calls"])]
     return rows
+
+
+def step_load(name: str = TRAIN_STEP_PROGRAM):
+    """Beside :func:`grouped_gemm_rows` and :func:`exchange_calls`, which
+    are shapes: what the router did to the steps the program ``name`` has
+    run, as its registrant has it NOW (the engine's ``step_load()``:
+    ``{"steps", "totals", "last"}`` of the ``moe/*`` sums that leave the
+    step beside its loss — data, no callback; it waits for the steps in
+    flight).  None where no such program is registered, or it is gone."""
+    with _PROGRAM_LOCK:
+        thunk = _PROGRAM_THUNKS.get(name, {}).get("load")
+    return thunk() if thunk else None
 
 
 def delta_rule_chunks(name: str = TRAIN_STEP_PROGRAM):

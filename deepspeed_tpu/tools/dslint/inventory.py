@@ -173,6 +173,21 @@ class Inventory:
         """Collect every registry use in one module (public so tests can
         feed synthetic snippets through the same extraction)."""
         consts = _module_str_constants(tree)
+        # names that reach a registry by a variable, declared where they
+        # are made: registry_docs.METRIC_NAME_TUPLES
+        from . import registry_docs
+        for node in getattr(tree, "body", []):
+            if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                    and isinstance(node.targets[0], ast.Name) \
+                    and isinstance(node.value, ast.Tuple) \
+                    and registry_docs.METRIC_NAME_TUPLES.get(rel) \
+                    == node.targets[0].id:
+                for el in node.value.elts:
+                    name = consts.get(el.id) if isinstance(el, ast.Name) \
+                        else None
+                    if name:
+                        _add(self.metrics_emitted,
+                             Ref(name, rel, node.lineno))
         # local aliases of the serving counter/gauge dicts — the repo
         # idiom `c = self.metrics.counters; c["x"] += 1`
         aliases = {"counters": "counters", "gauges": "gauges"}

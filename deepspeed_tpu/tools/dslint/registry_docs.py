@@ -100,6 +100,11 @@ ENV_VARS = {
                 "over telemetry.trace)",
 }
 
+#: module -> its module-level tuple of metric names that reach a registry
+#: by a variable: the sums a train step returns beside its loss, which the
+#: engine gauges under the names its model gave them (``step_load()``)
+METRIC_NAME_TUPLES = {"deepspeed_tpu/moe/layer.py": "STEP_LOAD"}
+
 #: metric name (as exposed on /metrics, after the ServingMetrics
 #: ``serving/`` prefix normalization) -> one-line description
 METRICS = {
@@ -250,20 +255,57 @@ METRICS = {
                         "counted per routing step",
     "moe/aux_loss": "weighted load-balancing aux loss gauge",
     "moe/z_loss": "router z-loss gauge",
+    # the router's load: in a train step from the step's own outputs (sums
+    # over its expert layer-calls, micro-batches and chips, gauged by the
+    # engine when the step has ended: engine.step_load()); the five the
+    # registry tap sets (held_*, exchange_rows_*, *_per_routed_row) on a
+    # forward with a tap by callback, per expert layer run
     "moe/held_live_rows": "rows of a held-subset plan's live prefix "
                           "(used_blocks tiles: what dispatch, the grouped "
-                          "kernels and combine walk), per expert layer run",
+                          "kernels and combine walk), per expert layer run "
+                          "(in a train step: from the step's own outputs, "
+                          "summed over the step; on a forward with a tap: "
+                          "by callback)",
     "moe/held_plan_rows": "static length of that plan (held_rows_bound + "
-                          "one tile per held expert)",
+                          "one tile per held expert); in a train step "
+                          "summed as moe/held_live_rows is",
     "moe/exchange_rows_sent": "(token, chip) rows one chip sent to the "
                               "chips of the expert axis (itself among "
-                              "them) in an exchanged expert layer run",
+                              "them) in an exchanged expert layer run (in "
+                              "a train step: from the step's own outputs, "
+                              "summed over the step and the chips; on a "
+                              "forward with a tap: by callback)",
     "moe/exchange_rows_received": "(token, sender) rows that chip received "
-                                  "from them (what landed there)",
+                                  "from them (what landed there); in a "
+                                  "train step summed as "
+                                  "moe/exchange_rows_sent is",
     "moe/exchange_wire_rows_per_routed_row": "of the rows it sent, those "
                                              "that crossed to another chip, "
                                              "a routed (token, expert) row: "
-                                             "a token crosses to a chip once",
+                                             "a token crosses to a chip once "
+                                             "(the tap's, by callback; a "
+                                             "train step: moe/exchange_"
+                                             "wire_rows / moe/routed_rows)",
+    "moe/routed_rows": "(token, choice) rows a train step's routers sent "
+                       "to the experts held here, before any bound, summed "
+                       "over its expert layer-calls, micro-batches and "
+                       "chips (from the step's own outputs)",
+    "moe/even_rows": "what moe/routed_rows is under even routing: a "
+                     "constant of the shapes, summed the same way",
+    "moe/even_expert_rows": "one expert's even share of a layer-call's "
+                            "routed rows (rows / num_experts), summed as "
+                            "moe/routed_rows is",
+    "moe/fullest_expert_rows": "rows of the fullest held expert of a "
+                               "layer-call (max of the plan's counts), "
+                               "summed as moe/routed_rows is",
+    "moe/exchange_wire_rows": "of moe/exchange_rows_sent, the rows that "
+                              "left their chip, summed the same way",
+    "moe/fullest_chip_rows": "(token, expert) rows the fullest chip's plan "
+                             "received in an exchanged layer-call, summed "
+                             "over layer-calls and micro-batches, once an "
+                             "expert axis (every chip computes the same)",
+    "moe/mean_chip_rows": "the mean over the chips where "
+                          "moe/fullest_chip_rows is the maximum",
     # --- numerics observatory (training health, ISSUE 15)
     "num/grad_norm": "last resolved global gradient norm (-1 = "
                      "non-finite)",

@@ -13,11 +13,13 @@ and, as a wider sample, for the other shares of the same routing.
 
 One JSON line per seed: per step the held share's fullest block and the
 fullest of all shares and blocks, as multiples of the even share; per step
-and block the rows of the held plan's live prefix as the expert layer
-itself reports them (``moe/held_live_rows`` beside ``moe/held_plan_rows``,
-gauges of the registry tap: the blocks in the order the device ran them)
-— what dispatch, the grouped kernels and combine walk of the plan; the
-engine's own count of rows over the bound; a last line with the extremes.
+the load of the very step that trained (``engine.step_load()``: sums over
+the step's blocks and micro-batches that leave it beside the loss) — the
+rows of the held plans' live prefixes (``moe/held_live_rows`` beside
+``moe/held_plan_rows``: what dispatch, the grouped kernels and combine
+walk of the plan), the rows routed here over their even number and the
+fullest held expert's over one expert's even share; the engine's own count
+of rows over the bound; a last line with the extremes.
 
 ``--sum`` times the way back alone, at the cell's shape and with no engine:
 a plan drawn at random whose held experts are sent ``--live-share`` of the
@@ -47,29 +49,6 @@ sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
 from drivers.train_steps import build_engine, build_model    # noqa: E402
 from harness import datagen                                   # noqa: E402
 from harness.manifest import Manifest                         # noqa: E402
-
-
-class _HeldPlanTap:
-    """The registry tap of ``moe/layer.py`` as a list: every value a held
-    expert layer sets its two gauges to, in the order they arrive."""
-
-    def __init__(self):
-        self.live, self.plan = [], []
-
-    def set_gauge(self, name, value, **labels):
-        from deepspeed_tpu.moe.layer import HELD_LIVE_ROWS, HELD_PLAN_ROWS
-        if name == HELD_LIVE_ROWS:
-            self.live.append(int(value))
-        elif name == HELD_PLAN_ROWS:
-            self.plan.append(int(value))
-
-    def inc(self, name, value=1.0, **labels):
-        pass
-
-    def taken(self):
-        jax.effects_barrier()
-        live, self.live = self.live, []
-        return live
 
 
 def _timed(fn, *args, repeats=30, **kwargs):
@@ -219,10 +198,8 @@ def main():
         model = build_model(config)
         cfg = model.config.moe
         engine, _ = build_engine(config, traffic, model, seed, jax.devices())
-        # the tap is in place only while the diagnostic is traced: the
-        # engine's step, traced at its first call below, has no callback
-        from deepspeed_tpu.moe.layer import set_moe_metrics_registry
-        tap = _HeldPlanTap()
+        # the all-experts sample is a second program: the step knows the
+        # share it holds only
         rows = jax.jit(model.meta["routed_rows"])
         stream = datagen.BatchStream(
             traffic, model.config.vocab_size,
@@ -230,18 +207,13 @@ def main():
         even = traffic["micro_batch_per_chip"] * traffic["seq_len"] \
             * cfg.top_k * cfg.held / cfg.num_experts
         mine = cfg.expert_offset // cfg.held
-        held, fullest, live = [], [], []
+        held, fullest = [], []
         try:
             for _ in range(args.steps):
                 batch = stream.next()
                 micro = {k: jnp.asarray(np.asarray(v)[0])
                          for k, v in batch.items()}
-                set_moe_metrics_registry(tap)
-                try:
-                    routed = np.asarray(rows(engine.state["params"], micro))
-                finally:
-                    set_moe_metrics_registry(None)
-                live.append(tap.taken())
+                routed = np.asarray(rows(engine.state["params"], micro))
                 shares = routed \
                     .reshape(-1, cfg.num_experts // cfg.held, cfg.held) \
                     .sum(-1) / even               # [blocks, shares]
@@ -252,14 +224,21 @@ def main():
             stream.close()
         held_worst.append(max(held))
         any_worst.append(max(fullest))
+        from deepspeed_tpu.moe import layer as moe
+        steps = engine.step_load()["last"]      # the newest 64 at most
+        ratio = lambda above, below: [          # noqa: E731
+            round(step[above] / step[below], 4) for step in steps]
         print(json.dumps({
             "workload": args.workload, "seed": seed,
             "device": jax.devices()[0].device_kind,
             "even_share_rows": even, "held_rows_factor": cfg.held_rows_factor,
             "held_share_fullest_block": held,
             "any_share_fullest_block": fullest,
-            "held_plan_rows": sorted(set(tap.plan)),
-            "held_live_rows": live,
+            "held_plan_rows": sorted({s[moe.HELD_PLAN_ROWS] for s in steps}),
+            "held_live_rows": [s[moe.HELD_LIVE_ROWS] for s in steps],
+            "routed_over_even_rows": ratio(moe.ROUTED_ROWS, moe.EVEN_ROWS),
+            "fullest_expert_over_even": ratio(moe.FULLEST_EXPERT_ROWS,
+                                              moe.EVEN_EXPERT_ROWS),
             "step_counts": engine.step_counts()}), flush=True)
         del engine
     print(json.dumps({"seeds": len(args.seed), "steps": args.steps,

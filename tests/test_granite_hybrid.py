@@ -255,7 +255,7 @@ def test_the_expert_shares_add_up_to_the_uncut_sublayer():
             y, (aux, over) = jax.jit(functools.partial(
                 gh._fed, config=share.config, train=True, rng=None))(
                     x, mine)
-            assert int(over) == 0
+            assert int(over[0]) == 0    # the rows over; then the load
             assert float(aux) == pytest.approx(
                 sizes["aux_loss_coef"] * float(balance), rel=1e-5)
             branch = (y - x) / share.config.residual_multiplier

@@ -45,6 +45,9 @@ def test_three_engine_steps_from_the_references_loss_downwards():
     assert losses[2] < losses[1] < losses[0] - 0.02, losses
     # a plan of every routed row: nothing is left out of the loss
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
 
 
 def test_the_engine_counts_rows_over_the_bound_in_every_layer(monkeypatch):

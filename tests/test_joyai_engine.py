@@ -62,6 +62,9 @@ def test_engine_first_step_loss_matches_the_reference(stage):
     assert np.abs(bias_was).max() > 0
     np.testing.assert_array_equal(bias(engine.state["params"]), bias_was)
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
 
 
 # ----------------------------------------------- what makes it this model
@@ -158,7 +161,7 @@ def _dense_layer_as_an_expert_layer(monkeypatch):
                          segment_ids=seg)
         x, _ = fn(x, lead)
         x, (aux, over) = jax.lax.scan(fn, x, params["blocks"])
-        return x, jnp.sum(aux), jnp.sum(over)
+        return x, jnp.sum(aux), jnp.sum(over, 0)
 
     monkeypatch.setattr(joyai, "hidden_with_aux", hidden)
 

@@ -53,6 +53,9 @@ def test_engine_first_step_loss_matches_the_reference(stage):
     if stage == 2:      # a second step on the state the first one left
         assert np.isfinite(float(engine.train_batch(batch=packed_batch(1))))
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
 
 
 # ----------------------------------------------- what makes it this model

@@ -42,6 +42,9 @@ def test_engine_first_step_loss_matches_the_reference(stage):
     got = float(engine.train_batch(batch=batch))
     assert abs(got - want) < LOSS_TOL, (got, want)
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
 
 
 def test_engine_on_a_four_wide_expert_axis_matches_the_reference():
@@ -59,6 +62,9 @@ def test_engine_on_a_four_wide_expert_axis_matches_the_reference():
     assert abs(got - want) < LOSS_TOL, (got, want)
     engine.train_batch(batch=packed_batch(1))
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
     w_in = engine.state["params"]["blocks"][SLIDING]["moe"]["w_in"]
     assert {s.data.shape for s in w_in.addressable_shards} \
         == {(1, 3, 2, 64, 32)}

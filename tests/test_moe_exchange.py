@@ -542,6 +542,9 @@ def test_every_token_to_every_chip_fills_the_wire_and_no_more(monkeypatch):
             moe_layer_module.EXCHANGE_WIRE_ROWS_PER_ROUTED_ROW]
         wire = [round(share * (B * S // 4) * k) for share in shares]
         assert wire == 4 * [call["wire_rows_bound"]] == 4 * [3 * 16]
+        # (the load is four plans' against one plan's: not the layer's)
+        for tree in (got, want):
+            del tree[0][1][1]["load"]
         for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(g, w, rtol=2e-4,
                                        atol=2e-5 * np.abs(w).max())

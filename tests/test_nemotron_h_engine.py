@@ -69,6 +69,9 @@ def test_the_engine_counts_a_row_over_the_bound(monkeypatch):
             gradient_accumulation_steps=GAS, seed=3), mesh=one_device())
     engine.train_batch(batch=packed_batch())
     assert engine.step_counts()["moe/rows_over_bound"] > 0
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
 
 
 def test_scopes_and_counts_of_a_toy_step():

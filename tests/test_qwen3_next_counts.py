@@ -35,6 +35,9 @@ def test_the_engine_counts_and_warns_of_rows_over_the_bound(monkeypatch):
     engine = _counting_engine()
     engine.train_batch(batch=batch)
     assert engine.step_counts() == {"moe/rows_over_bound": 0}
+    # ... and the router's load left the step beside it
+    load = engine.step_load()["totals"]
+    assert load["moe/routed_rows"] > 0 and load["moe/even_rows"] > 0
     assert not [w for w in warnings if "rows_over_bound" in w]
 
     monkeypatch.setattr(gg, "default_block_m", lambda: 8)

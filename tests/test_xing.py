@@ -251,7 +251,7 @@ def test_with_one_stream_the_block_is_joyais_block(kind):
             _one_stream_params(ours, layer, jax.random.PRNGKey(2)), ours,
             train=True, segment_ids=seg)
         assert float(aux) == pytest.approx(float(want_aux), rel=1e-6)
-        assert int(over) == 0
+        assert int(over[0]) == 0    # the rows over; then the load
     assert got.shape == (B, S, theirs.d_model)      # one stream: the row
     np.testing.assert_allclose(got, want, atol=2e-5 * float(
         jnp.abs(want).max()))
