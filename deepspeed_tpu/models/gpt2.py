@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, maybe_stream, qdot,
+from deepspeed_tpu.models.model import (Head, Model, maybe_stream, qdot,
                                         remat_policy, resolve_size)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
@@ -266,11 +266,13 @@ def _block(x, layer, config: GPT2Config, rng=None, segment_ids=None):
     return _block_finish(x, attn, layer, config)
 
 
-def forward(params: dict, batch: dict, config: GPT2Config, rng=None):
-    """Token ids [B, S] -> logits [B, S, V].  Layers run under ``lax.scan`` so
-    XLA compiles one block and (under ZeRO-3 shardings) gathers each layer's
-    params just-in-time, overlapping the all-gather with the previous layer's
-    compute — the reference's prefetch coordinator
+def head_inputs(params: dict, batch: dict, config: GPT2Config,
+                rng=None) -> Head:
+    """Token ids [B, S] -> the head's inputs: the normed last hidden state
+    and the tied embedding on its own axis.  Layers run under ``lax.scan``
+    so XLA compiles one block and (under ZeRO-3 shardings) gathers each
+    layer's params just-in-time, overlapping the all-gather with the
+    previous layer's compute — the reference's prefetch coordinator
     (partitioned_param_coordinator.py:256) collapses into XLA scheduling."""
     tokens = batch["input_ids"]
     B, S = tokens.shape
@@ -299,8 +301,12 @@ def forward(params: dict, batch: dict, config: GPT2Config, rng=None):
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"],
                         config.layer_norm_eps)
-        logits = x @ params["wte"].astype(dtype).T   # tied embedding
-    return logits
+    return Head(x, params["wte"], tied=True)
+
+
+def forward(params: dict, batch: dict, config: GPT2Config, rng=None):
+    """Token ids [B, S] -> logits [B, S, V]."""
+    return head_inputs(params, batch, config, rng).logits()
 
 
 # --------------------------------------------------------------------- decode
@@ -575,6 +581,13 @@ def gpt2_model(size: str = "125m", **overrides) -> Model:
     cfg_kwargs.update(overrides)
     config = GPT2Config(**cfg_kwargs)
     n_params = count_params(config)
+
+    def loss(params, batch, rng=None):
+        return head_inputs(params, batch, config, rng).token_loss(batch)
+
+    # ``token_loss`` of ``apply_fn``'s logits, taken without them: the
+    # stock loss that the NVMe tier's streamed head VJP mirrors
+    loss.causal_lm_loss = True
     return Model(
         config=config,
         init_fn=partial(init_params, config),
@@ -582,6 +595,7 @@ def gpt2_model(size: str = "125m", **overrides) -> Model:
         layer_init_fn=partial(init_layer_slice, config),
         nonblock_init_fn=partial(init_nonblock, config),
         apply_fn=lambda p, b, rng=None: forward(p, b, config, rng),
+        loss_fn=loss,
         logical_specs=logical_specs(config),
         flops_per_token=6.0 * n_params,
         meta={"name": f"gpt2-{size}", "n_params": n_params,

@@ -71,7 +71,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_branch,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_branch,
                                         held_share_model, maybe_stream,
                                         param_count, refuse_param_stream,
                                         remat_policy, resolve_size,
@@ -418,11 +419,12 @@ def embedded(params, batch, config: GraniteHybridConfig):
                 * config.embedding_multiplier).astype(dtype)
 
 
-def forward_with_aux(params, batch, config: GraniteHybridConfig,
-                     train: bool = True, rng=None):
-    """-> (logits, router loss summed over layers, routed rows over
-    ``held_rows_bound`` summed over layers: int32, 0 unless the experts
-    held are a subset)."""
+def head_with_aux(params, batch, config: GraniteHybridConfig,
+                  train: bool = True, rng=None):
+    """-> (the head's inputs, router loss summed over layers, routed rows
+    over ``held_rows_bound`` summed over layers: int32, 0 unless the
+    experts held are a subset).  The logits' muP divisor is applied to the
+    hidden state, and the head is the embedding table on its own axis."""
     refuse_param_stream(
         "granite-hybrid",
         "two stacks (ssm, attn) walked period by period")
@@ -435,7 +437,7 @@ def forward_with_aux(params, batch, config: GraniteHybridConfig,
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.norm_eps)
         x = (x.astype(jnp.float32) / config.logits_scaling).astype(dtype)
-        return x @ params["wte"].astype(dtype).T, aux, over
+    return Head(x, params["wte"], tied=True), aux, over
 
 
 def count_params(config: GraniteHybridConfig) -> int:
@@ -448,7 +450,7 @@ def granite_hybrid_model(size: str = "4.0-h-small", **overrides) -> Model:
         **overrides})
     return held_share_model(
         "granite-hybrid", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.num_layers, expert_matrices=3,
         # the table is tied: read once as a lookup and multiplied once as
         # the head, so every parameter but the absent experts' multiplies

@@ -62,11 +62,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
-                                        held_share_model, layer_block,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half, held_share_model,
+                                        layer_block, next_token_targets,
                                         param_count, qdot,
                                         refuse_param_stream, resolve_size,
-                                        segment_ids_of, token_loss)
+                                        segment_ids_of)
 from deepspeed_tpu.models.llama import _rms_norm, rope
 from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
                                      moe_logical_specs, named_sums)
@@ -391,21 +392,20 @@ def hidden_with_aux(params, batch, config: JoyAIConfig, train: bool = True,
     return x, jnp.sum(aux), jnp.sum(over, 0)
 
 
-def _logits(x, norm_w, lm_head, config: JoyAIConfig):
-    x = _rms_norm(x, norm_w, config.norm_eps)
-    return x @ lm_head.astype(jnp.dtype(config.dtype))
+def _head(x, norm_w, lm_head, config: JoyAIConfig):
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        return Head(_rms_norm(x, norm_w, config.norm_eps), lm_head)
 
 
-def forward_with_aux(params, batch, config, train: bool = True, rng=None,
-                     stack=None):
-    """-> (the main head's logits, router loss, rows over the bound): the
+def head_with_aux(params, batch, config, train: bool = True, rng=None,
+                  stack=None):
+    """-> (the main head's inputs, router loss, rows over the bound): the
     main model alone, as a forward pass reads it.  ``stack``: as
     :func:`loss_with_counts`."""
     hidden, _ = stack or (hidden_with_aux, mtp_hidden_with_aux)
     x, aux, over = hidden(params, batch, config, train, rng)
-    with jax.named_scope(SCOPE_HEAD_LOSS):
-        return (_logits(x, params["final_norm"], params["lm_head"], config),
-                aux, over)
+    return (_head(x, params["final_norm"], params["lm_head"], config), aux,
+            over)
 
 
 def mtp_input(params, x, batch, config):
@@ -472,18 +472,7 @@ def routed_rows(params, batch, config: JoyAIConfig):
 def mtp_targets(batch):
     """(targets [B, S]: token t+2 at position t; scored [B, S]: where t,
     t+1 and t+2 lie in one document and inside the sequence)."""
-    tokens = batch["input_ids"]
-    S = tokens.shape[1]
-    scored = jnp.broadcast_to(jnp.arange(S) < S - 2, tokens.shape)
-    seg = segment_ids_of(batch)
-    if seg is not None:
-        scored &= (seg == jnp.roll(seg, -1, axis=1)) \
-            & (seg == jnp.roll(seg, -2, axis=1))
-    mask = batch.get("attention_mask")
-    if mask is not None:
-        scored &= (jnp.roll(mask, -1, axis=1) != 0) \
-            & (jnp.roll(mask, -2, axis=1) != 0)
-    return jnp.roll(tokens, -2, axis=1), scored
+    return next_token_targets(batch, ahead=2)
 
 
 def _scored_nll(logits, targets):
@@ -500,9 +489,8 @@ def mtp_token_losses(params, batch, config, stack=None):
     x, _, _ = hidden(params, batch, config, train=False)
     h, _ = mtp_hidden(params, x, batch, config, train=False)
     targets, scored = mtp_targets(batch)
-    logits = _logits(h, params["mtp"]["final_norm"], params["lm_head"],
-                     config)
-    return _scored_nll(logits, targets), scored
+    head = _head(h, params["mtp"]["final_norm"], params["lm_head"], config)
+    return _scored_nll(head.logits(), targets), scored
 
 
 def loss_with_counts(params, batch, config, rng=None, stack=None):
@@ -513,32 +501,19 @@ def loss_with_counts(params, batch, config, rng=None, stack=None):
     file's."""
     hidden, mtp_hidden = stack or (hidden_with_aux, mtp_hidden_with_aux)
     x, aux, over = hidden(params, batch, config, True, rng)
-
-    # a head pass keeps nothing but its inputs for the backward: the two
-    # passes' [tokens, vocab] float32 logits then never live together
-    @jax.checkpoint
-    def main_loss(x, norm_w, lm_head):
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            return token_loss(_logits(x, norm_w, lm_head, config), batch)
-
-    loss = main_loss(x, params["final_norm"], params["lm_head"]) + aux
+    # neither head pass holds its [tokens, vocab] logits
+    # (models/model.py head_token_loss)
+    loss = _head(x, params["final_norm"], params["lm_head"],
+                 config).token_loss(batch) + aux
     if config.num_mtp_layers:
         with jax.named_scope(SCOPE_MTP):
             h, (mtp_aux, mtp_over) = mtp_hidden(
                 params, x, batch, config, True, rng)
-
-            @jax.checkpoint
-            def mtp_loss(h, norm_w, lm_head):
-                with jax.named_scope(SCOPE_HEAD_LOSS):
-                    targets, scored = mtp_targets(batch)
-                    nll = _scored_nll(_logits(h, norm_w, lm_head, config),
-                                      targets)
-                    scored = scored.astype(jnp.float32)
-                    return jnp.sum(nll * scored) \
-                        / jnp.maximum(scored.sum(), 1.0)
-
-            loss = loss + mtp_aux + config.mtp_loss_weight * mtp_loss(
-                h, params["mtp"]["final_norm"], params["lm_head"])
+            mtp_loss = _head(
+                h, params["mtp"]["final_norm"], params["lm_head"],
+                config).token_loss(batch, targets=mtp_targets(batch),
+                                   name="mtp")
+            loss = loss + mtp_aux + config.mtp_loss_weight * mtp_loss
             over = over + mtp_over
     return loss, named_sums(over)
 
@@ -553,7 +528,7 @@ def joyai_model(size: str = "llm-flash", **overrides) -> Model:
     head = config.d_model * config.vocab_size
     return held_share_model(
         "joyai", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         loss_with_counts=loss_with_counts,
         expert_layers=config.expert_layers + config.num_mtp_layers,
         expert_matrices=3, lookup_params=head,

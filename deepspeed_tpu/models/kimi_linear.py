@@ -56,7 +56,8 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models.joyai import (dense_mlp, dense_mlp_specs,
                                         latent_attention)
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half,
                                         held_share_model, layer_block,
                                         param_count, qdot,
                                         refuse_param_stream, resolve_size,
@@ -414,11 +415,11 @@ def _in_two_halves(config: KimiLinearConfig, kind, segment_ids, ffn,
     return lambda x, layer: ffn(mix(x, layer), layer)
 
 
-def forward_with_aux(params, batch, config: KimiLinearConfig,
-                     train: bool = True, rng=None):
-    """-> (logits, router loss summed over the expert layers, routed rows
-    over ``held_rows_bound`` summed over them: int32, 0 unless the experts
-    held are a subset)."""
+def head_with_aux(params, batch, config: KimiLinearConfig,
+                  train: bool = True, rng=None):
+    """-> (the head's inputs, router loss summed over the expert layers,
+    routed rows over ``held_rows_bound`` summed over them: int32, 0 unless
+    the experts held are a subset)."""
     refuse_param_stream(
         "kimi-linear", "a leading dense block and runs of two stacks (kda, "
         "mla) walked period by period")
@@ -436,7 +437,7 @@ def forward_with_aux(params, batch, config: KimiLinearConfig,
         aux, over = aux + run_aux, over + run_over
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.norm_eps)
-        return x @ params["lm_head"].astype(dtype), aux, over
+    return Head(x, params["lm_head"]), aux, over
 
 
 def layers_in_order(params, config: KimiLinearConfig):
@@ -488,7 +489,7 @@ def kimi_linear_model(size: str = "48b-a3b", **overrides) -> Model:
         **resolve_size(KIMI_LINEAR_SIZES, size, "kimi_linear"), **overrides})
     return held_share_model(
         "kimi-linear", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.expert_layers, expert_matrices=3,
         lookup_params=config.vocab_size * config.d_model,
         serving_needs=(

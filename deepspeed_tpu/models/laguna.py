@@ -52,15 +52,14 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
-                                        held_share_model, layer_block,
-                                        param_count, qdot,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half, held_share_model,
+                                        layer_block, param_count, qdot,
                                         refuse_param_stream, resolve_size,
-                                        scan_layer_kinds, segment_ids_of,
-                                        token_loss)
+                                        scan_layer_kinds, segment_ids_of)
 from deepspeed_tpu.models.llama import _rms_norm
 from deepspeed_tpu.moe.layer import (MoEConfig, init_moe_params,
-                                     moe_logical_specs, named_sums)
+                                     moe_logical_specs)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ATTN, SCOPE_ATTN_FULL, SCOPE_ATTN_SLIDING, SCOPE_BLOCK,
@@ -388,27 +387,13 @@ def hidden_with_aux(params, batch, config: LagunaConfig, train: bool = True,
     return x, aux, over
 
 
-def _logits(x, norm_w, lm_head, config: LagunaConfig):
-    x = _rms_norm(x, norm_w, config.norm_eps)
-    return x @ lm_head.astype(jnp.dtype(config.dtype))
-
-
-def forward_with_aux(params, batch, config: LagunaConfig, train: bool = True,
-                     rng=None):
-    """-> (logits, router loss, rows over the bound)."""
+def head_with_aux(params, batch, config: LagunaConfig, train: bool = True,
+                  rng=None):
+    """-> (the head's inputs, router loss, rows over the bound)."""
     x, aux, over = hidden_with_aux(params, batch, config, train, rng)
     with jax.named_scope(SCOPE_HEAD_LOSS):
-        return (_logits(x, params["final_norm"], params["lm_head"], config),
-                aux, over)
-
-
-def loss_with_counts(params, batch, config: LagunaConfig, rng=None):
-    """-> (cross-entropy + router losses, {rows over the bound, and the
-    step's load: ``moe/layer.py named_sums``})."""
-    logits, aux, over = forward_with_aux(params, batch, config, True, rng)
-    with jax.named_scope(SCOPE_HEAD_LOSS):
-        loss = token_loss(logits, batch)
-    return loss + aux, named_sums(over)
+        return (Head(_rms_norm(x, params["final_norm"], config.norm_eps),
+                     params["lm_head"]), aux, over)
 
 
 def layers_in_order(params, config: LagunaConfig):
@@ -461,8 +446,7 @@ def laguna_model(size: str = "s-2.1", **overrides) -> Model:
         **resolve_size(LAGUNA_SIZES, size, "laguna"), **overrides})
     return held_share_model(
         "laguna", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
-        loss_with_counts=loss_with_counts,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.expert_layers, expert_matrices=3,
         lookup_params=config.vocab_size * config.d_model,
         serving_needs=(

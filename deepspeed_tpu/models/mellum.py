@@ -51,7 +51,10 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models.laguna import (FULL, SLIDING, _rotary,
                                          rotary_table)
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+# head_nll_sum: the head's loss in chunks of tokens, first built here for
+# this family's 98,304 ids (1,024 tokens a chunk) and every family's since
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half, head_nll_sum,  # noqa: F401
                                         held_share_model, layer_block,
                                         param_count, qdot,
                                         refuse_param_stream, resolve_size,
@@ -291,124 +294,13 @@ def hidden_with_aux(params, batch, config: MellumConfig, train: bool = True,
     return x, aux, over
 
 
-def forward_with_aux(params, batch, config: MellumConfig, train: bool = True,
-                     rng=None):
-    """-> (logits, router loss, rows over a bound)."""
+def head_with_aux(params, batch, config: MellumConfig, train: bool = True,
+                  rng=None):
+    """-> (the head's inputs, router loss, rows over a bound)."""
     x, aux, over = hidden_with_aux(params, batch, config, train, rng)
     with jax.named_scope(SCOPE_HEAD_LOSS):
-        x = _rms_norm(x, params["final_norm"], config.norm_eps)
-        return (x @ params["lm_head"].astype(jnp.dtype(config.dtype)), aux,
-                over)
-
-
-#: tokens of one chip whose logits exist at a time in the loss
-HEAD_CHUNK_TOKENS = 1024
-
-
-def _chunk_nll(h, w, targets, scored):
-    """One chip's tokens ``h`` [t, D] through the head ``w`` [D, V], a
-    chunk at a time: -> (the scored positions' negative log likelihoods
-    summed, float32 []; its gradient in ``h`` [t, D]; and in ``w`` [D, V]
-    float32).  Only one chunk's logits [chunk, V] (float32) exist at a
-    time, and nothing is computed twice: the backward pass scales the two
-    gradients found here."""
-    t, D = h.shape
-    chunk = max(d for d in range(1, min(t, HEAD_CHUNK_TOKENS) + 1)
-                if t % d == 0)
-
-    def some_tokens(dw, args):
-        hc, target, keep = args
-        logits = jnp.dot(hc, w, preferred_element_type=jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        hit = jnp.arange(w.shape[1], dtype=jnp.int32)[None, :] \
-            == target[:, None]
-        nll = lse - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-        dlogits = ((jnp.exp(logits - lse[:, None]) - hit)
-                   * keep[:, None]).astype(h.dtype)
-        dw = dw + jnp.dot(hc.T, dlogits, preferred_element_type=jnp.float32)
-        return dw, (jnp.sum(nll * keep), jnp.dot(dlogits, w.T))
-
-    dw, (nll, dh) = lax.scan(
-        some_tokens, jnp.zeros(w.shape, jnp.float32),
-        (h.reshape(-1, chunk, D), targets.reshape(-1, chunk),
-         scored.reshape(-1, chunk)))
-    return jnp.sum(nll), dh.reshape(t, D), dw
-
-
-def _head_parts(h, w, targets, scored):
-    """:func:`_chunk_nll` on every chip's own tokens (a manual region over
-    the whole mesh: the head's gradient is summed over the chips ONCE,
-    outside, not chunk by chunk) -> (sums [chips], dh [B, S, D], dw
-    [chips, D, V] float32)."""
-    from deepspeed_tpu.comm.mesh import get_topology
-    from deepspeed_tpu.utils.jax_compat import shard_map
-    B, S, D = h.shape
-    topo = get_topology()
-    mesh = topo.mesh
-    axes = tuple(topo.data_parallel_axes)
-
-    def on_chip(h, w, targets, scored):
-        total, dh, dw = _chunk_nll(h.reshape(-1, D), w, targets.reshape(-1),
-                                   scored.reshape(-1))
-        return total[None], dh.reshape(h.shape), dw[None]
-
-    if mesh.size == 1 or B % topo.axis_size(axes):
-        return on_chip(h, w, targets, scored)
-    rows, every = P(axes), P(tuple(mesh.axis_names))
-    return shard_map(on_chip, mesh=mesh, in_specs=(rows, P(), rows, rows),
-                     out_specs=(every, rows, every), check_vma=False)(
-                         h, w, targets, scored)
-
-
-@jax.custom_vjp
-def head_nll_sum(h, w, targets, scored):
-    """The scored positions' negative log likelihoods of ``targets`` [B,
-    S] under ``softmax(h w)``, summed: float32 [].  ``h`` [B, S, D] is the
-    normed hidden state, ``w`` [D, V] the head in ``h``'s dtype, ``scored``
-    [B, S] float32 ones and zeros.  The logits are float32 and never whole
-    (:func:`_chunk_nll`): at 8,192 tokens a chip and 98,304 ids one pass
-    would hold 3.2 GB of them, and as much again for their gradient."""
-    return jnp.sum(_head_parts(h, w, targets, scored)[0])
-
-
-def _head_nll_fwd(h, w, targets, scored):
-    total, dh, dw = _head_parts(h, w, targets, scored)
-    return jnp.sum(total), (dh, dw)
-
-
-def _head_nll_bwd(res, g):
-    dh, dw = res
-    return ((g * dh).astype(dh.dtype),
-            (g * jnp.sum(dw, axis=0)).astype(dh.dtype), None, None)
-
-
-head_nll_sum.defvjp(_head_nll_fwd, _head_nll_bwd)
-
-
-def loss_with_counts(params, batch, config: MellumConfig, rng=None):
-    """-> (cross-entropy + router losses, {rows over a bound, and the
-    step's load: ``moe/layer.py named_sums``}).  The
-    cross-entropy is ``models.model.token_loss``'s — position t against
-    token t + 1, inside a document, not where ``attention_mask`` is 0 —
-    through :func:`head_nll_sum`."""
-    from deepspeed_tpu.moe.layer import named_sums
-    x, aux, over = hidden_with_aux(params, batch, config, True, rng)
-    with jax.named_scope(SCOPE_HEAD_LOSS):
-        h = _rms_norm(x, params["final_norm"], config.norm_eps)
-        ids = batch["input_ids"]
-        scored = (jnp.arange(ids.shape[1]) < ids.shape[1] - 1)[None, :] \
-            & jnp.ones(ids.shape, bool)
-        if batch.get("attention_mask") is not None:
-            scored &= jnp.roll(batch["attention_mask"], -1, axis=1) != 0
-        seg = segment_ids_of(batch)
-        if seg is not None:
-            scored &= seg == jnp.roll(seg, -1, axis=1)
-        scored = scored.astype(jnp.float32)
-        total = head_nll_sum(
-            h, params["lm_head"].astype(h.dtype), jnp.roll(ids, -1, axis=1),
-            scored)
-        loss = total / jnp.maximum(jnp.sum(scored), 1.0)
-    return loss + aux, named_sums(over)
+        return (Head(_rms_norm(x, params["final_norm"], config.norm_eps),
+                     params["lm_head"]), aux, over)
 
 
 def layers_in_order(params, config: MellumConfig):
@@ -459,8 +351,7 @@ def mellum_model(size: str = "12b-a2.5b", **overrides) -> Model:
         **resolve_size(MELLUM_SIZES, size, "mellum"), **overrides})
     return held_share_model(
         "mellum", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
-        loss_with_counts=loss_with_counts,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.num_layers, expert_matrices=3,
         lookup_params=config.vocab_size * config.d_model,
         serving_needs=(

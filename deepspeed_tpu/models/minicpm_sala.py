@@ -89,11 +89,10 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.llama import _rms_norm, rope
-from deepspeed_tpu.models.model import (Model, embed_tokens, maybe_stream,
-                                        param_count, qdot,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        maybe_stream, param_count, qdot,
                                         refuse_param_stream, remat_policy,
-                                        resolve_size, segment_ids_of,
-                                        token_loss)
+                                        resolve_size, segment_ids_of)
 from deepspeed_tpu.ops.sparse_attention import (BlockSelection,
                                                 select_blocks,
                                                 selected_attention,
@@ -390,9 +389,10 @@ def embedded(params, batch, config: MiniCPMSALAConfig):
     return (x.astype(jnp.float32) * config.scale_emb).astype(dtype)
 
 
-def forward(params, batch, config: MiniCPMSALAConfig):
+def head_inputs(params, batch, config: MiniCPMSALAConfig) -> Head:
+    """The head's inputs: the normed last hidden state under its muP
+    scale, and the head."""
     refuse_param_stream("minicpm-sala", "a subtree a layer, walked unrolled")
-    dtype = jnp.dtype(config.dtype)
     segment_ids = segment_ids_of(batch)
     x = embedded(params, batch, config)
     for index, kind in enumerate(config.kinds):
@@ -400,8 +400,12 @@ def forward(params, batch, config: MiniCPMSALAConfig):
             x, params["layers"][layer_name(index)])
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.norm_eps)
-        x = x * (config.dim_model_base / config.d_model)
-        return x @ params["lm_head"].astype(dtype)
+        return Head(x * (config.dim_model_base / config.d_model),
+                    params["lm_head"])
+
+
+def forward(params, batch, config: MiniCPMSALAConfig):
+    return head_inputs(params, batch, config).logits()
 
 
 def sparse_counts(params, batch, config: MiniCPMSALAConfig):
@@ -438,9 +442,7 @@ def minicpm_sala_model(size: str = "9b", **overrides) -> Model:
         return forward(params, batch, config)
 
     def loss(params, batch, rng=None):
-        logits = forward(params, batch, config)
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            return token_loss(logits, batch)
+        return head_inputs(params, batch, config).token_loss(batch)
 
     def no_serving(what):
         def refuse(*_, **__):

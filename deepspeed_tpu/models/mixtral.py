@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import Model, qdot, resolve_size, token_loss
+from deepspeed_tpu.models.model import Head, Model, qdot, resolve_size
 from deepspeed_tpu.models.llama import _rms_norm, rope
 from deepspeed_tpu.moe.layer import (STEP_LOAD, MoEConfig, layer_sums,
                                      moe_layer)
@@ -213,10 +213,10 @@ def _block(carry, layer, config: MixtralConfig, train: bool, rng=None,
                        train, rng)
 
 
-def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
-                     rng=None):
-    """-> (logits, router loss summed over layers, the layers' int32 sums
-    added up: ``moe/layer.py layer_sums``)."""
+def head_with_aux(params, batch, config: MixtralConfig, train: bool = True,
+                  rng=None):
+    """-> (the head's inputs, router loss summed over layers, the layers'
+    int32 sums added up: ``moe/layer.py layer_sums``)."""
     tokens = batch["input_ids"]
     dtype = jnp.dtype(config.dtype)
     with jax.named_scope(SCOPE_EMBED):
@@ -234,8 +234,7 @@ def forward_with_aux(params, batch, config: MixtralConfig, train: bool = True,
     x, (aux, sums) = lax.scan(block_fn, x, params["blocks"])
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.rms_norm_eps)
-        return (x @ params["lm_head"].astype(dtype), jnp.sum(aux),
-                jnp.sum(sums, 0))
+    return Head(x, params["lm_head"]), jnp.sum(aux), jnp.sum(sums, 0)
 
 
 # --------------------------------------------------------------------- decode
@@ -336,19 +335,17 @@ def mixtral_model(size: str = "8x7b", **overrides) -> Model:
         3 * config.num_layers * config.num_experts * config.d_model * config.d_ff)
 
     def loss_with_load(params, batch, rng=None):
-        logits, aux, sums = forward_with_aux(params, batch, config,
-                                             train=True, rng=rng)
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            # inside a document only, where the batch is packed; aux = the
-            # weighted router losses summed over layers (moe/layer.py)
-            return token_loss(logits, batch) + aux, \
-                dict(zip(STEP_LOAD, sums[1:]))
+        head, aux, sums = head_with_aux(params, batch, config,
+                                        train=True, rng=rng)
+        # inside a document only, where the batch is packed; aux = the
+        # weighted router losses summed over layers (moe/layer.py)
+        return head.token_loss(batch) + aux, dict(zip(STEP_LOAD, sums[1:]))
 
     return Model(
         config=config,
         init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
+        apply_fn=lambda p, b, rng=None: head_with_aux(
+            p, b, config, train=False, rng=rng)[0].logits(),
         loss_fn=lambda p, b, rng=None: loss_with_load(p, b, rng)[0],
         # nothing is left out of this loss (no bound; an einsum's capacity
         # drops are the reference's semantics), so no ``step_counts``: what

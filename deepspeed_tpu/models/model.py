@@ -7,10 +7,14 @@ hand-rolled pytrees — can be adapted to this.
 """
 import contextlib
 import contextvars
+import math
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional
+from functools import partial
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 
 # ---------------------------------------------------------------- param stream
 # ZeRO-Infinity parameter offload (reference: partitioned_param_swapper.py:36 +
@@ -500,18 +504,20 @@ class Model:
 
 
 def held_share_model(family: str, size: str, config, *, init_params,
-                     logical_specs, forward_with_aux, expert_layers: int,
+                     logical_specs, head_with_aux, expert_layers: int,
                      expert_matrices: int, serving_needs: str,
                      loss_with_counts=None, lookup_params: int = 0,
                      reused_params: int = 0, meta=None) -> Model:
     """The training-only :class:`Model` of a family whose expert layers may
     hold a share of their experts (``config.moe``: ``experts_held`` of
     ``num_experts``).  The family hands over ``init_params(config, rng)``,
-    ``logical_specs(config)``, ``forward_with_aux(params, batch, config,
-    train=, rng=) -> (logits, router loss, the expert layers' sums)`` and,
-    where its loss is more than the one head's cross-entropy and the
-    router loss, ``loss_with_counts(params, batch, config, rng) -> (loss,
-    {name: count})``.  Counted here, for ``flops_per_token = 6 * active``:
+    ``logical_specs(config)``, ``head_with_aux(params, batch, config,
+    train=, rng=) -> (its :class:`Head`, router loss, the expert layers'
+    sums)`` — whose logits are ``apply_fn``'s and whose
+    :func:`head_token_loss` the loss's — and, where its loss is more than
+    the one head's cross-entropy and the router loss,
+    ``loss_with_counts(params, batch, config, rng) -> (loss, {name:
+    count})``.  Counted here, for ``flops_per_token = 6 * active``:
     of ``expert_layers`` layers' experts (``expert_matrices`` matrices
     each) a token's weights pass through ``top_k`` of ``num_experts`` of
     those held; ``lookup_params`` are read and not multiplied (an
@@ -526,7 +532,6 @@ def held_share_model(family: str, size: str, config, *, init_params,
     count is a zero."""
     from functools import partial
     from deepspeed_tpu.moe.layer import ROWS_OVER_BOUND, named_sums
-    from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
     moe = config.moe
     n_params = param_count(partial(init_params, config))
     expert = expert_matrices * moe.d_model * moe.d_ff
@@ -536,15 +541,18 @@ def held_share_model(family: str, size: str, config, *, init_params,
 
     if loss_with_counts is None:
         def loss_with_counts(params, batch, config, rng=None):
-            logits, aux, over = forward_with_aux(params, batch, config,
-                                                 train=True, rng=rng)
-            with jax.named_scope(SCOPE_HEAD_LOSS):
-                # inside a document only, where the batch is packed; aux =
-                # the weighted load-balancing loss summed over layers
-                return token_loss(logits, batch) + aux, named_sums(over)
+            head, aux, over = head_with_aux(params, batch, config,
+                                            train=True, rng=rng)
+            # inside a document only, where the batch is packed; aux = the
+            # weighted load-balancing loss summed over layers
+            return head.token_loss(batch) + aux, named_sums(over)
 
     def with_counts(params, batch, rng=None):
         return loss_with_counts(params, batch, config, rng)
+
+    def logits(params, batch, rng=None):
+        return head_with_aux(params, batch, config, train=False,
+                             rng=rng)[0].logits()
 
     def no_serving(what):
         def refuse(*_, **__):
@@ -555,8 +563,7 @@ def held_share_model(family: str, size: str, config, *, init_params,
     return Model(
         config=config,
         init_fn=partial(init_params, config),
-        apply_fn=lambda p, b, rng=None: forward_with_aux(
-            p, b, config, train=False, rng=rng)[0],
+        apply_fn=logits,
         loss_fn=lambda p, b, rng=None: with_counts(p, b, rng)[0],
         # the rows a step's expert layers left out leave the step beside
         # its loss (no host callback: one inside the layer loop does not
@@ -583,7 +590,10 @@ def token_loss(logits, batch):
     """Mean next-token cross-entropy of ``logits`` [B, S, V] over the
     positions that count: position t is scored against token t+1, not
     where ``attention_mask`` is 0, and not across a document boundary of
-    a packed sequence (``segment_ids``)."""
+    a packed sequence (``segment_ids``).  For callers that have logits
+    (evaluation, serving, the references, tests); a training loss hands its
+    hidden state and its head to :func:`head_token_loss`, which never
+    holds them whole."""
     import jax.numpy as jnp
     import optax
     tokens = batch["input_ids"]
@@ -606,7 +616,240 @@ def token_loss(logits, batch):
     return losses.mean()
 
 
+# ------------------------------------------------- the head, never whole
+# Every family's training loss: the head's product, the cross-entropy and
+# their two gradients a block of tokens at a time.  Whole, the logits of a
+# micro-batch and their gradient are the largest arrays of a step (16,384
+# tokens x 25,008 ids: 1.5 GiB in bf16, as much again upcast).
+
+def head_chunk_tokens(tokens: int, vocab: int) -> int:
+    """Tokens of one chip whose float32 logits exist at a time: the
+    largest power of two whose logits XLA keeps on the chip
+    (``ops/pallas/vmem.py xla_keeps``: the chip's, 100 MiB on a v5e) where
+    that is 1,024 tokens or more; where it is not (more than 25,600 ids on
+    a v5e), four times as many, in HBM, and never under 1,024, and no
+    more than there are.  Why, under 25,600 ids: on the chip the softmax's
+    passes over the chunk cost no memory traffic and the loop holds nothing
+    but its float32 ``dw`` carry — at the ten vocabularies measured there
+    the fastest chunk is that one (Phi-4's head alone: 37.9 ms at 1,024,
+    46.2 at 2,048, 44.8 at 4,096, 41.4 with whole logits).  **Above it the
+    clause is two measured points and no more**: 50,257 / 50,304 ids
+    (GPT-2, OLMoE) read fastest at 2,048 (36.7 ms against 43.9 at 1,024
+    and 40.4 whole), where the carry ``[d_model, vocab]``, read and
+    written once a chunk, asks for wider chunks; 98,304 ids (Mellum2) fit
+    at 1,024 alone (0.84 GiB of ``temp``; 2.3 at 2,048).  A vocabulary
+    between or beyond them gets what joins the two, untimed.  The width
+    does not enter: the carry's bytes and the products' operations both
+    grow with it (scripts/head_loss_table.py on a v5e; PERF.md section 6,
+    PR 69)."""
+    from deepspeed_tpu.ops.pallas.vmem import xla_keeps
+    on_chip = 2 ** int(math.log2(xla_keeps() / (4 * vocab)))
+    return min(tokens,
+               on_chip if on_chip >= 1024 else max(1024, 4 * on_chip))
+
+
+def next_token_targets(batch, ahead: int = 1):
+    """-> (targets [B, S]: token t + ``ahead`` at position t; scored [B, S]
+    bool: where that token is inside the sequence, no position from t + 1
+    to it has ``attention_mask`` 0, and all of them lie in t's document
+    (``segment_ids``)) — :func:`token_loss`'s positions at ``ahead`` 1."""
+    ids = batch["input_ids"]
+    scored = (jnp.arange(ids.shape[1]) < ids.shape[1] - ahead)[None, :] \
+        & jnp.ones(ids.shape, bool)
+    steps = range(1, ahead + 1)
+    if batch.get("attention_mask") is not None:
+        for k in steps:
+            scored &= jnp.roll(batch["attention_mask"], -k, axis=1) != 0
+    seg = segment_ids_of(batch)
+    if seg is not None:
+        for k in steps:
+            scored &= seg == jnp.roll(seg, -k, axis=1)
+    return jnp.roll(ids, -ahead, axis=1), scored
+
+
+def _head_dot(a, b, contract):
+    """``a`` and ``b`` contracted on one axis each, float32 out."""
+    return lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _chunk_nll(h, w, targets, scored, tied, name):
+    """One chip's tokens ``h`` [t, D] through the head ``w`` ([D, V], or
+    [V, D] where ``tied``: the embedding table contracted on its own
+    axis, no transposed copy), a chunk at a time: -> (the scored
+    positions' negative log likelihoods summed, float32 []; its gradient
+    in ``h`` [t, D]; and in ``w``, float32 in ``w``'s layout).  Only one
+    chunk's logits [chunk, V] (float32) exist at a time, and nothing is
+    computed twice: the backward pass scales the two gradients found here.
+    The chunk is :func:`head_chunk_tokens`'s; tokens it does not divide
+    are padded with unscored ones, under one chunk's worth."""
+    from deepspeed_tpu.telemetry.tracing import count_in_step
+    t, D = h.shape
+    V = w.shape[0] if tied else w.shape[1]
+    chunks = -(-t // head_chunk_tokens(t, V))
+    chunk = -(-t // chunks)
+    count_in_step(head_chunks={name: {
+        "name": name, "tokens": t, "d_model": D, "vocab": V, "chunk": chunk,
+        "chunks": chunks, "whole_logits_bytes": 4 * t * V,
+        "chunk_logits_bytes": 4 * chunk * V, "tied": tied}})
+    if chunks * chunk > t:
+        pad = lambda a: jnp.pad(
+            a, ((0, chunks * chunk - t),) + ((0, 0),) * (a.ndim - 1))
+        h, targets, scored = pad(h), pad(targets), pad(scored)
+
+    def some_tokens(dw, args):
+        hc, target, keep = args
+        logits = _head_dot(hc, w, (1, 1)) if tied else jnp.dot(
+            hc, w, preferred_element_type=jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        hit = jnp.arange(V, dtype=jnp.int32)[None, :] == target[:, None]
+        nll = lse - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        dlogits = ((jnp.exp(logits - lse[:, None]) - hit)
+                   * keep[:, None]).astype(h.dtype)
+        dw = dw + (_head_dot(dlogits, hc, (0, 0)) if tied else jnp.dot(
+            hc.T, dlogits, preferred_element_type=jnp.float32))
+        return dw, (jnp.sum(nll * keep),
+                    jnp.dot(dlogits, w if tied else w.T))
+
+    dw, (nll, dh) = lax.scan(
+        some_tokens, jnp.zeros(w.shape, jnp.float32),
+        (h.reshape(-1, chunk, D), targets.reshape(-1, chunk),
+         scored.reshape(-1, chunk)))
+    return jnp.sum(nll), dh.reshape(-1, D)[:t], dw
+
+
+def _head_parts(h, w, targets, scored, tied, name):
+    """:func:`_chunk_nll` on every chip's own tokens (a manual region over
+    the data axes of the mesh: the head is gathered once, and its gradient
+    summed over the chips ONCE, outside, not chunk by chunk; every other
+    axis stays the partitioner's) -> (sums [chips], dh [B, S, D], dw
+    [chips, ...] float32: each chip's share)."""
+    from jax.sharding import PartitionSpec as P
+    from deepspeed_tpu.comm.mesh import get_topology
+    from deepspeed_tpu.utils.jax_compat import get_abstract_mesh, shard_map
+    B, S, D = h.shape
+
+    def on_chip(h, w, targets, scored):
+        total, dh, dw = _chunk_nll(
+            h.reshape(-1, D), w, targets.reshape(-1), scored.reshape(-1),
+            tied, name)
+        return total[None], dh.reshape(h.shape), dw[None]
+
+    topo = get_topology()
+    # axes an enclosing manual region already maps (the quantized gradient
+    # exchange is manual over the data axes): the tokens are local there
+    context = get_abstract_mesh()
+    outer = frozenset(context.manual_axes)
+    axes = tuple(a for a in topo.data_parallel_axes if a not in outer)
+    chips = topo.axis_size(axes)
+    if chips == 1 or B % chips:
+        return on_chip(h, w, targets, scored)
+    rows = P(axes)
+    return shard_map(on_chip, mesh=context if outer else topo.mesh,
+                     in_specs=(rows, P(), rows, rows),
+                     out_specs=(rows, rows, rows),
+                     axis_names=frozenset(axes), check_vma=False)(
+                         h, w, targets, scored)
+
+
+def _sum_of_chips(dw):
+    """The chips' float32 shares ``dw`` [chips, rows, columns] summed, in
+    float32: one all-reduce.  Rows that are no multiple of eight a chip
+    are padded to one for the sum: GPT-2's 50,257 over a v5e 2x2 stop the
+    TPU compiler in float32 (``Check failed: s_count.has_value()``, its
+    reduce-scatter emitter; 50,272 compile: tests/test_chip_compile.py)."""
+    chips, rows = dw.shape[:2]
+    whole = -(-rows // (8 * chips)) * 8 * chips
+    if chips == 1 or whole == rows:
+        return jnp.sum(dw, axis=0)
+    return jnp.sum(jnp.pad(dw, ((0, 0), (0, whole - rows), (0, 0))),
+                   axis=0)[:rows]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def head_nll_sum(h, w, targets, scored, tied=False, name="main"):
+    """The scored positions' negative log likelihoods of ``targets`` [B,
+    S] under ``softmax(h w)``, summed: float32 [].  ``h`` [B, S, D] is the
+    normed hidden state, ``w`` the head in ``h``'s dtype ([D, V]; the
+    embedding table [V, D] where ``tied``), ``scored`` [B, S] float32 ones
+    and zeros.  The logits are float32 and never whole
+    (:func:`_chunk_nll`): at 8,192 tokens a chip and 98,304 ids one pass
+    would hold 3.2 GB of them, and as much again for their gradient.
+    ``name`` is the call's in the step's account
+    (``tracing.head_chunks``)."""
+    return _head_nll_fwd(h, w, targets, scored, tied, name)[0]
+
+
+def _head_nll_fwd(h, w, targets, scored, tied, name):
+    total, dh, dw = _head_parts(h, w, targets, scored, tied, name)
+    return jnp.sum(total), (dh, dw)
+
+
+def _head_nll_bwd(tied, name, res, g):
+    dh, dw = res
+    # the chips' shares are summed and scaled in float32 and rounded once
+    grads = ((g * dh).astype(dh.dtype),
+             (g * _sum_of_chips(dw)).astype(dh.dtype))
+    if tied:
+        # the table's gradient is finished only when the embedding's rows
+        # arrive, at the end of the backward pass: rounded here, with
+        # ``dh``, which the backward pass wants first, the head's share
+        # waits for them in the head's dtype — left free, the compiler
+        # kept the loop's float32 carry until then, under every layer's
+        # backward (the four-chip ZeRO-3 cell's peak + 0.14 GiB; so - 0.24)
+        grads = lax.optimization_barrier(grads)
+    return (*grads, None, None)
+
+
+head_nll_sum.defvjp(_head_nll_fwd, _head_nll_bwd)
+
+
+def head_token_loss(h, w, batch, *, tied: bool = False, targets=None,
+                    name: str = "main"):
+    """:func:`token_loss` of ``h w`` without the logits: the mean over the
+    scored positions of :func:`head_nll_sum` (``targets``: the (targets,
+    scored) of another scoring than :func:`next_token_targets`'s of
+    ``batch`` — a prediction module's).  ``h`` [B, S, D] is the normed
+    hidden state with whatever the family scales its logits by applied;
+    ``w`` the head as its owner stores it, cast here: [D, V], or ``wte``
+    [V, D] where ``tied``.  bf16 operands into the three products, float32
+    accumulation, logits and loss."""
+    targets, scored = next_token_targets(batch) if targets is None \
+        else targets
+    scored = scored.astype(jnp.float32)
+    total = head_nll_sum(h, w.astype(h.dtype), targets, scored, tied, name)
+    return total / jnp.maximum(jnp.sum(scored), 1.0)
+
+
+class Head(NamedTuple):
+    """What a family's layers hand its head: ``h`` [B, S, D], the normed
+    hidden state (a family's scale on it applied), and ``w``, the head's
+    weight as stored — [D, V], or the embedding table [V, D] where
+    ``tied``."""
+    h: Any
+    w: Any
+    tied: bool = False
+
+    def logits(self):
+        """[B, S, V] in ``h``'s dtype, whole: for callers that want
+        them (evaluation, the references, tests)."""
+        from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            w = self.w.astype(self.h.dtype)
+            return self.h @ (w.T if self.tied else w)
+
+    def token_loss(self, batch, **how):
+        """:func:`head_token_loss` of this head, under ``ds.head_loss``."""
+        from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
+        with jax.named_scope(SCOPE_HEAD_LOSS):
+            return head_token_loss(self.h, self.w, batch, tied=self.tied,
+                                   **how)
+
+
 def _default_lm_loss(apply_fn):
+    """The causal-LM loss of a model that names none: :func:`token_loss`
+    of ``apply_fn``'s logits (a family that trains in a cell hands its
+    :class:`Head` over in a ``loss_fn`` of its own: no whole logits)."""
     from deepspeed_tpu.telemetry.tracing import SCOPE_HEAD_LOSS
 
     def loss_fn(params, batch, rng=None):

@@ -45,7 +45,8 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half,
                                         held_share_model, layer_block,
                                         no_experts, param_count, qdot,
                                         refuse_param_stream, resolve_size,
@@ -366,11 +367,11 @@ def _experts_block(x, layer, config: NemotronHConfig, train, rng=None,
 _BLOCKS = {SSM: _ssm_block, EXPERTS: _experts_block, ATTN: _attn_block}
 
 
-def forward_with_aux(params, batch, config: NemotronHConfig,
-                     train: bool = True, rng=None):
-    """-> (logits, router loss summed over layers, routed rows over
-    ``held_rows_bound`` summed over layers: int32, 0 unless the experts
-    held are a subset)."""
+def head_with_aux(params, batch, config: NemotronHConfig,
+                  train: bool = True, rng=None):
+    """-> (the head's inputs, router loss summed over layers, routed rows
+    over ``held_rows_bound`` summed over layers: int32, 0 unless the
+    experts held are a subset)."""
     refuse_param_stream(
         "nemotron-h",
         "three stacks (ssm, experts, attn) walked pattern by pattern")
@@ -383,8 +384,7 @@ def forward_with_aux(params, batch, config: NemotronHConfig,
          for kind, block in _BLOCKS.items()})
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _rms_norm(x, params["final_norm"], config.norm_eps)
-        logits = x @ params["lm_head"].astype(dtype)
-    return logits, aux, over
+    return Head(x, params["lm_head"]), aux, over
 
 
 def count_params(config: NemotronHConfig) -> int:
@@ -396,7 +396,7 @@ def nemotron_h_model(size: str = "3-nano-30b-a3b", **overrides) -> Model:
         **resolve_size(NEMOTRON_H_SIZES, size, "nemotron_h"), **overrides})
     return held_share_model(
         "nemotron-h", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.layers_of(EXPERTS), expert_matrices=2,
         lookup_params=config.vocab_size * config.d_model,
         serving_needs=(

@@ -73,11 +73,10 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.gpt2 import _layer_norm
 from deepspeed_tpu.models.llama import _rms_norm
-from deepspeed_tpu.models.model import (Model, embed_tokens, maybe_stream,
-                                        param_count, qdot,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        maybe_stream, param_count, qdot,
                                         refuse_param_stream, remat_policy,
-                                        resolve_size, segment_ids_of,
-                                        token_loss)
+                                        resolve_size, segment_ids_of)
 from deepspeed_tpu.ops.attention import causal_attention
 from deepspeed_tpu.ops.linear_attention import causal_conv
 from deepspeed_tpu.ops.selective_scan import selective_scan
@@ -391,7 +390,9 @@ def _block(x, layer, shared, config: Phi4FlashConfig, index, kind,
     return _mlp(x, layer, config), kept
 
 
-def forward(params, batch, config: Phi4FlashConfig):
+def head_inputs(params, batch, config: Phi4FlashConfig) -> Head:
+    """The head's inputs: the normed last hidden state, and the embedding
+    table on its own axis."""
     refuse_param_stream("phi4flash", "a subtree a layer, walked unrolled")
     dtype = jnp.dtype(config.dtype)
     segment_ids = segment_ids_of(batch)
@@ -410,9 +411,13 @@ def forward(params, batch, config: Phi4FlashConfig):
         elif index == config.kv_layer:
             kv = kept
     with jax.named_scope(SCOPE_HEAD_LOSS):
-        x = _layer_norm(x, params["lnf_w"], params["lnf_b"],
-                        config.layer_norm_eps)
-        return x @ params["wte"].astype(dtype).T
+        return Head(_layer_norm(x, params["lnf_w"], params["lnf_b"],
+                                config.layer_norm_eps),
+                    params["wte"], tied=True)
+
+
+def forward(params, batch, config: Phi4FlashConfig):
+    return head_inputs(params, batch, config).logits()
 
 
 def count_params(config: Phi4FlashConfig) -> int:
@@ -441,9 +446,7 @@ def phi4flash_model(size: str = "mini-flash", **overrides) -> Model:
         return forward(params, batch, config)
 
     def loss(params, batch, rng=None):
-        logits = forward(params, batch, config)
-        with jax.named_scope(SCOPE_HEAD_LOSS):
-            return token_loss(logits, batch)
+        return head_inputs(params, batch, config).token_loss(batch)
 
     def no_serving(what):
         def refuse(*_, **__):

@@ -44,7 +44,8 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.model import (Model, embed_tokens, expert_half,
+from deepspeed_tpu.models.model import (Head, Model, embed_tokens,
+                                        expert_half,
                                         held_share_model, layer_block,
                                         param_count, qdot,
                                         refuse_param_stream, resolve_size,
@@ -313,11 +314,11 @@ def _full_block(x, layer, config: Qwen3NextConfig, train, rng=None,
     return _moe_finish(x, layer, config, train, rng)
 
 
-def forward_with_aux(params, batch, config: Qwen3NextConfig,
-                     train: bool = True, rng=None):
-    """-> (logits, router loss summed over layers, routed rows over
-    ``held_rows_bound`` summed over layers: int32, 0 unless the experts
-    held are a subset)."""
+def head_with_aux(params, batch, config: Qwen3NextConfig,
+                  train: bool = True, rng=None):
+    """-> (the head's inputs, router loss summed over layers, routed rows
+    over ``held_rows_bound`` summed over layers: int32, 0 unless the
+    experts held are a subset)."""
     refuse_param_stream(
         "qwen3-next", "two stacks (linear, full) walked period by period")
     dtype = jnp.dtype(config.dtype)
@@ -329,8 +330,7 @@ def forward_with_aux(params, batch, config: Qwen3NextConfig,
          for kind, block in ((LINEAR, _linear_block), (FULL, _full_block))})
     with jax.named_scope(SCOPE_HEAD_LOSS):
         x = _norm(x, params["final_norm"], config.rms_norm_eps)
-        logits = x @ params["lm_head"].astype(dtype)
-    return logits, aux, over
+    return Head(x, params["lm_head"]), aux, over
 
 
 def _wq_halves(grads, config: Qwen3NextConfig):
@@ -348,7 +348,7 @@ def qwen3_next_model(size: str = "80b-a3b", **overrides) -> Model:
         **resolve_size(QWEN3_NEXT_SIZES, size, "qwen3_next"), **overrides})
     return held_share_model(
         "qwen3-next", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         expert_layers=config.num_layers, expert_matrices=3,
         serving_needs=(
             "serving a model with linear-attention layers needs a cache "

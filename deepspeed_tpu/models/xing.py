@@ -367,7 +367,7 @@ def mtp_hidden_with_aux(params, x, batch, config: XingConfig,
 
 
 _STACK = (hidden_with_aux, mtp_hidden_with_aux)
-forward_with_aux = partial(joyai.forward_with_aux, stack=_STACK)
+head_with_aux = partial(joyai.head_with_aux, stack=_STACK)
 loss_with_counts = partial(joyai.loss_with_counts, stack=_STACK)
 mtp_token_losses = partial(joyai.mtp_token_losses, stack=_STACK)
 
@@ -382,7 +382,7 @@ def xing_model(size: str = "4.0-29b-a4b", **overrides) -> Model:
     head = config.d_model * config.vocab_size
     return held_share_model(
         "xing", size, config, init_params=init_params,
-        logical_specs=logical_specs, forward_with_aux=forward_with_aux,
+        logical_specs=logical_specs, head_with_aux=head_with_aux,
         loss_with_counts=loss_with_counts,
         expert_layers=config.expert_layers + config.num_mtp_layers,
         expert_matrices=3, lookup_params=head,

@@ -1,7 +1,9 @@
 """The VMEM a Mosaic call's buffers may take, by device kind: one table and
 one rule for every kernel that sizes its blocks by it (``grouped_gemm``,
 ``ds_flash_attention``), and the rule by which a call with an XLA form
-beside its kernels takes one or the other (:func:`lowering`).
+beside its kernels takes one or the other (:func:`lowering`); and what
+XLA's own fusions keep there of one array (``XLA_KEEPS``: the width of the
+head's chunk, ``models/model.py head_chunk_tokens``).
 
 A call that asks for nothing is granted 16 MiB; ``UNASKED`` is what its
 buffers may fill of that, a quarter left for the compiler's own scratch.
@@ -18,6 +20,12 @@ import jax
 BUDGET = (("v5 lite", 64 << 20),)
 UNASKED = 12 << 20
 HEADROOM = 32 << 20
+#: bytes of one array that XLA's own fusions keep in VMEM and out of HBM, by
+#: device kind: of a v5e's 128 MiB, float32 [1024, 25008] and [2048, 12544]
+#: (98 MiB) stay, [2048, 16384] (128 MiB) does not (the compiled text's
+#: memory space ``S(1)``; scripts/head_loss_table.py).  The first row is
+#: also what a kind not listed is read as: the one chip this was read on.
+XLA_KEEPS = (("v5 lite", 100 << 20),)
 
 
 def device_kind() -> str:
@@ -27,6 +35,13 @@ def device_kind() -> str:
 def budget() -> int:
     kind = device_kind()
     return next((b for sub, b in BUDGET if sub in kind), UNASKED)
+
+
+def xla_keeps() -> int:
+    """:data:`XLA_KEEPS` of the chips of the mesh in use."""
+    from deepspeed_tpu.comm.mesh import get_topology
+    kind = str(get_topology().mesh.devices.flat[0].device_kind).lower()
+    return next((b for sub, b in XLA_KEEPS if sub in kind), XLA_KEEPS[0][1])
 
 
 def limit_for(need_bytes: int) -> Optional[int]:

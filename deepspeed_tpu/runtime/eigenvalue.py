@@ -41,12 +41,15 @@ class Eigenvalue:
         v, _ = self._normalize(v)
         grad_fn = jax.grad(loss_fn)
 
-        def hvp(vec):
+        # compiled: a loss with a manual region over some of the mesh's
+        # axes (the shared head's, over the data axes) has no eager form
+        @jax.jit
+        def hvp(params, vec):
             return jax.jvp(grad_fn, (params,), (vec,))[1]
 
         eig = jnp.float32(0.0)
         for _ in range(self.max_iter):
-            hv = hvp(v)
+            hv = hvp(params, v)
             new_eig = sum(jnp.vdot(a, b).real for a, b in zip(
                 jax.tree.leaves(v), jax.tree.leaves(hv)))
             v, _ = self._normalize(hv)

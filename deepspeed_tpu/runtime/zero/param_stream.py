@@ -42,8 +42,12 @@ __all__ = ["StreamedParamRunner", "uses_default_lm_loss",
 
 def uses_default_lm_loss(model) -> bool:
     """True when the model's loss is the stock causal-LM CE (the only
-    loss the streamed head VJP reproduces bit-for-bit)."""
-    return "_default_lm_loss" in getattr(model.loss_fn, "__qualname__", "")
+    loss the streamed head VJP reproduces bit-for-bit): the default one,
+    or a family's own that says so (``loss_fn.causal_lm_loss``:
+    ``models/gpt2.py`` takes that loss a chunk of tokens at a time)."""
+    return "_default_lm_loss" in getattr(
+        model.loss_fn, "__qualname__", "") or getattr(
+            model.loss_fn, "causal_lm_loss", False)
 
 
 def lm_loss_from_logits(logits, batch):

@@ -996,6 +996,20 @@ def flash_calls(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "flash_calls")
 
 
+def head_chunks(name: str = TRAIN_STEP_PROGRAM):
+    """The head-and-loss calls of the step as models/model.py
+    ``head_nll_sum`` traced them: one row per call (``name``: ``"main"``,
+    a prediction module's ``"mtp"``) — ``tokens`` of one chip, ``d_model``,
+    ``vocab``, the ``chunk`` of tokens whose logits exist at a time
+    (``head_chunk_tokens``) and how many ``chunks`` walk the tokens,
+    ``whole_logits_bytes`` ([tokens, vocab] float32: what the loss would
+    hold at once, and as much again for its gradient),
+    ``chunk_logits_bytes`` (what it holds), and ``tied`` (the head is the
+    embedding table, contracted on its own axis).  None where the step's
+    loss takes whole logits (``token_loss``)."""
+    return _account_rows(name, "head_chunks")
+
+
 def gradient_bytes(name: str = TRAIN_STEP_PROGRAM):
     """Bytes one device holds of the step's summed gradient tree — the
     accumulator ``accumulated_grads`` carries over the micro-batches and

@@ -27,6 +27,22 @@ without a query latent and without rotary (``NEIGHBOUR_FAMILIES``:
 ``f.digest(n)``) and the gated delta rule's own call with a decay a head,
 as its XLA form and as its kernels interpreted (``f.delta_rule_digest``),
 from before the rule took a decay a key channel.
+
+Every family's digest that the tests hold a step to was taken again at PR
+69, in all three files (``gpt2``, ``olmoe``, ``qwen3_next`` and
+``nemotron_h`` in flash_step_digests.json; ``gpt2`` and ``olmoe`` in both
+halves of held_prefix_step_digests.json; ``joyai`` and ``xing`` in
+kda_neighbour_step_digests.json): each toy step ends in a loss, and the
+loss no longer forms ``[tokens, vocabulary]`` logits — the head's product,
+the cross-entropy and their two gradients run a chunk of tokens at a time
+(``models/model.py head_token_loss``, a ``custom_vjp`` whose forward finds
+both gradients), so every step's text changed on purpose (the layers'
+code did not; the values' numbering follows what comes after).
+tests/test_head_loss.py holds the new head to ``token_loss`` of whole
+logits — loss, ``dh`` and ``dw``; the gated delta rule's two digests
+hold no loss and stood.  (Taken twice at PR 69: its review moved the sum
+of the chips' shares of the head's gradient, and its rounding, from the
+forward rule to the backward rule.)
 """
 import functools
 import hashlib

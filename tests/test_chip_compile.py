@@ -11,7 +11,9 @@ query heads to 2, 64 kept blocks a token, one packed sequence of 16,384),
 the selective scan's two and the flash kernels at a 64-wide
 score and a 128-wide value head at Phi-4-mini-flash's (one packed sequence
 of 16,384) go through Mosaic, AdamW's update of Granite's and Nemotron-H's
-stacked expert leaves is one fusion over the donated state, the
+stacked expert leaves is one fusion over the donated state, the head and
+its loss at Phi-4-mini-flash's and GPT-2 760M's shapes hold a chunk of
+logits and never the whole, the
 data-sharded flash kernel goes through the partitioner, and the library
 knows the chip's peaks.
 
@@ -632,6 +634,74 @@ def test_a_stacked_leafs_update_is_one_fusion_in_place_on_a_v5e(v5e, shape):
     assert memory.temp_size_in_bytes < leaf_bytes // 8
 
 
+@pytest.mark.parametrize("tokens,d_model,vocab,chunk,share", [
+    # phi-4-mini-flash-reasoning.packed-s16384-traces: a chunk's float32
+    # logits (98 MiB) stay in VMEM, so the loop holds its float32 ``dw``
+    # carry (244 MiB) and little else
+    (16384, 2560, 25008, 1024, 1 / 3),
+    # gpt2-760m.dense-s1024: 2,048 tokens' logits (393 MiB) are in HBM
+    # beside the carry (294) and their bf16 gradient (196) — 982 MiB where
+    # whole logits compile to 7,071; 1,024 would be 687 and 3.4 ms a step
+    # slower than whole logits (scripts/head_loss_table.py)
+    (12288, 1536, 50257, 2048, 1 / 2),
+], ids=["phi4_mini_flash", "gpt2_760m_dense"])
+def test_the_head_holds_a_chunk_of_logits_on_a_v5e(v5e, tokens, d_model,
+                                                   vocab, chunk, share):
+    """``head_token_loss`` with both gradients at a cell's shapes, the head
+    tied, alone: its ``temp`` is under ``share`` of what the whole float32
+    logits would take, and no ``[tokens, vocab]`` array is in the text."""
+    from deepspeed_tpu.comm.mesh import MeshTopology, set_topology
+    from deepspeed_tpu.models.model import head_chunk_tokens, head_token_loss
+    set_topology(MeshTopology(devices=v5e[:1]))
+    assert head_chunk_tokens(tokens, vocab) == chunk
+    batch = {"input_ids": _arg(v5e[0], (1, tokens), jnp.int32),
+             "segment_ids": _arg(v5e[0], (1, tokens), jnp.int32)}
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h, w, batch: head_token_loss(h, w, batch, tied=True),
+        argnums=(0, 1))).lower(
+            _arg(v5e[0], (1, tokens, d_model)),
+            _arg(v5e[0], (vocab, d_model)), batch).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < share * 4 * tokens * vocab, temp
+    text = compiled.as_text()
+    assert f"[{chunk},{vocab}]" in text
+    assert f"{tokens},{vocab}]" not in text
+
+
+def test_a_split_head_is_gathered_and_its_gradient_summed_once_on_a_v5e(v5e):
+    """gpt2-2.7b-zero3x4.dense-s2048's head over the four described chips:
+    ``wte`` [50257, 2560] split four ways along its width (ZeRO-3), four
+    sequences of 2,048 a chip.  The manual region gathers the table once
+    and each chip's float32 share of its gradient is summed over the chips
+    once, in float32, outside the chunk loop — over 50,272 rows: at 50,257,
+    no multiple of eight a chip, the same sum stops this compiler (``Check
+    failed: s_count.has_value()`` in its reduce-scatter emitter;
+    ``model._sum_of_chips`` pads for it)."""
+    import re
+    from deepspeed_tpu.comm.mesh import MeshTopology, set_topology
+    from deepspeed_tpu.models.model import head_token_loss
+    topo = MeshTopology(devices=v5e)
+    set_topology(topo)
+    on = lambda *spec: NamedSharding(topo.mesh, P(*spec))
+    rows, split = on(topo.data_parallel_axes), on(None, topo.zero_shard_axes)
+    tokens = jax.ShapeDtypeStruct((16, 2048), jnp.int32, sharding=rows)
+    text = jax.jit(
+        jax.value_and_grad(lambda h, w, batch: head_token_loss(
+            h, w, batch, tied=True), argnums=(0, 1)),
+        out_shardings=(on(), (rows, split))).lower(
+            jax.ShapeDtypeStruct((16, 2048, 2560), jnp.bfloat16,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((50257, 2560), jnp.bfloat16, sharding=split),
+            {"input_ids": tokens}).compile().as_text()
+    moved = re.findall(
+        r"= (\S+?)\{\S* (all-gather|all-reduce|reduce-scatter)(?:-start)?\(",
+        text)
+    assert sorted(moved) == [("bf16[50257,2560]", "all-gather"),
+                             ("f32[50272,2560]", "all-reduce"),
+                             ("f32[]", "all-reduce")], moved
+    assert "f32[2048,50257]" in text and "8192,50257]" not in text
+
+
 @pytest.mark.parametrize("entry,passes", [("optax", 5), ("in_place", 2)])
 def test_a_gradient_handed_over_in_pieces_is_joined_once_on_a_v5e(
         v5e, entry, passes):
@@ -1122,7 +1192,8 @@ def test_library_knows_the_chips_peaks(v5e):
     "scripts/ssd_table.py", "scripts/conv_table.py", "scripts/rope_table.py",
     "scripts/latent_attention_table.py --seed 1",
     "scripts/latent_attention_table.py --bits",
-    "scripts/optimizer_table.py --seed 1"])
+    "scripts/optimizer_table.py --seed 1",
+    "scripts/head_loss_table.py --seed 1"])
 def test_measurement_scripts_refuse_the_cpu(script):
     script, *args = script.split()
     out = subprocess.run(

@@ -213,7 +213,11 @@ def test_with_one_width_the_step_lowers_to_the_parents_text(family):
     result.  ``olmoe`` was taken again at PR 59, which changed its expert
     layer on purpose (a row is weighted where its expert is and the way
     back is ``sum_rows``: tests/test_grouped_gemm.py holds it to the dense
-    per-expert reference); the three others stood."""
+    per-expert reference); the three others stood.  All four were taken
+    again at PR 69: each step ends in the loss, which no longer forms
+    whole logits (``models/model.py head_token_loss``, held to
+    ``token_loss`` by tests/test_head_loss.py); tests/flash_step_texts.py
+    says which digests and why."""
     with open(os.path.join(HERE, "data", "flash_step_digests.json")) as f:
         want = json.load(f)
     assert flash_step_texts.digest(family) == want[family]

@@ -476,7 +476,10 @@ def test_a_step_with_no_held_plan_lowers_to_the_parents_text(
     experts' activation in the full plan alone (tests/test_grouped_gemm.py
     holds it to the dense reference and counts its kernels; the held
     families' text, kernels interpreted or not, stood at that PR: the same
-    digests at parent and change); the held families' entries stay PR 39's
+    digests at parent and change), and both again at PR 69, whose loss
+    takes the hidden state and the head and forms no whole logits
+    (``models/model.py head_token_loss``; tests/flash_step_texts.py says
+    which digests and why); the held families' entries stay PR 39's
     parent's,
     which the test below holds them to having left."""
     from tests import flash_step_texts

@@ -23,8 +23,13 @@ from tests.util import base_config
 #: with the instructions that carry each, at the parent of PR 56 — the
 #: loss one place above that (…736, 14.361671447753906) since PR 57: ``k``
 #: leaves a product of ``[c_kv | k_r]``, and this CPU's float32 dot sums
-#: its 40 terms, eight of them exact zeros, in another order than 32
-LOSS_BITS = 1097189737      # 14.361672401428223
+#: its 40 terms, eight of them exact zeros, in another order than 32;
+#: two places above that again (…738, 14.361673355102539) since PR 69:
+#: both heads' losses come from float32 logits a chunk of tokens at a time
+#: (``models/model.py head_token_loss``: a logsumexp less the target's
+#: logit, summed and then divided, where optax's form took a mean of
+#: per-token differences — tests/test_head_loss.py holds the two together)
+LOSS_BITS = 1097189738      # 14.361673355102539
 KERNELS = {"ds_flash_fwd": 255, "ds_flash_bwd_dkv": 198,
            "ds_flash_bwd_dq": 117, "ds_ggemm_fwd": 221, "ds_ggemm_dx": 108,
            "ds_ggemm_dw": 160, "ds_rowsum": 312}

@@ -2,7 +2,8 @@
 form without a query latent left alone: the rule's own call with a decay a
 head — its XLA form and its kernels interpreted — and JoyAI's and Xing4.0's
 toy steps lower to the text they had at PR 60's parent commit (digests
-taken there: tests/flash_step_texts.py says how).  Qwen3-Next's toy step is
+taken there: tests/flash_step_texts.py says how; the two families' taken
+again at PR 69, whose two head passes form no whole logits).  Qwen3-Next's toy step is
 held by tests/test_flash_head_widths.py and tests/test_held_live_prefix.py,
 JoyAI's first loss to the last bit by tests/test_joyai_split.py."""
 import json

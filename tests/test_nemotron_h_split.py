@@ -14,10 +14,17 @@ from tests.test_nemotron_h import (  # noqa: F401 (the fixture comes by name)
     micro, packed_batch, real_kernels, seeded_params, toy_model)
 
 #: float32 bits of the loss, and the SHA-256 of every gradient leaf's bytes
-#: in the tree's order, at the parent of PR 66
+#: in the tree's order, at the parent of PR 66 — the loss; the gradients'
+#: digest was taken again at PR 69 (there bbd042dd...969e7c): the head's two
+#: gradients are found in its forward pass from ``softmax - hit`` scaled
+#: by the count afterwards (``models/model.py head_nll_sum``), where
+#: autodiff scaled first, so the last bits of every leaf behind it moved;
+#: tests/test_head_loss.py holds the new head to the old one's loss, ``dh``
+#: and ``dw`` at 1e-5, tests/test_nemotron_h.py these gradients to the
+#: plain reference's
 LOSS_BITS = 1093894671      # 11.219253
 GRADS_SHA256 = (
-    "bbd042ddf1f24aac0abf2db7ed90735197086440e3d737d675057f8365969e7c")
+    "2ecf5971b102925177bdf1ec7c08b452271b6aa6fdbdf13f903bee9f606630fe")
 
 
 def loss_and_gradient_bits():
