@@ -15,6 +15,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.custom_derivatives import SymbolicZero
 
 # ---------------------------------------------------------------- param stream
 # ZeRO-Infinity parameter offload (reference: partitioned_param_swapper.py:36 +
@@ -522,7 +523,9 @@ def held_share_model(family: str, size: str, config, *, init_params,
     each) a token's weights pass through ``top_k`` of ``num_experts`` of
     those held; ``lookup_params`` are read and not multiplied (an
     embedding), ``reused_params`` multiplied a second time (a head behind
-    a second module).  The four serving entry points raise, naming
+    a second module; a stack that a token passes several times counts its
+    uses itself: models/ouro.py ``applied_params``, the same 6 a weight a
+    use).  The four serving entry points raise, naming
     ``serving_needs``; ``meta`` is the family's own beside ``name``,
     ``n_params``, ``active_params`` and ``step_counts`` (which of the sums
     the loss left out: the rest is ``engine.step_load()``).  The counts
@@ -673,16 +676,19 @@ def _head_dot(a, b, contract):
                            preferred_element_type=jnp.float32)
 
 
-def _chunk_nll(h, w, targets, scored, tied, name):
+def _chunk_nll(h, w, targets, scored, tied, name, per_token=False):
     """One chip's tokens ``h`` [t, D] through the head ``w`` ([D, V], or
     [V, D] where ``tied``: the embedding table contracted on its own
-    axis, no transposed copy), a chunk at a time: -> (the scored
-    positions' negative log likelihoods summed, float32 []; its gradient
-    in ``h`` [t, D]; and in ``w``, float32 in ``w``'s layout).  Only one
-    chunk's logits [chunk, V] (float32) exist at a time, and nothing is
-    computed twice: the backward pass scales the two gradients found here.
-    The chunk is :func:`head_chunk_tokens`'s; tokens it does not divide
-    are padded with unscored ones, under one chunk's worth."""
+    axis, no transposed copy), a chunk at a time: -> (the positions'
+    negative log likelihoods times ``scored`` [t] float32 — ones and
+    zeros, or any weights —, summed, float32 []; its gradient in ``h`` [t,
+    D]; and in ``w``, float32 in ``w``'s layout; with ``per_token`` also
+    every position's negative log likelihood [t] float32, which is the
+    sum's gradient in ``scored``).  Only one chunk's logits [chunk, V]
+    (float32) exist at a time, and nothing is computed twice: the backward
+    pass scales the gradients found here.  The chunk is
+    :func:`head_chunk_tokens`'s; tokens it does not divide are padded
+    with unscored ones, under one chunk's worth."""
     from deepspeed_tpu.telemetry.tracing import count_in_step
     t, D = h.shape
     V = w.shape[0] if tied else w.shape[1]
@@ -708,32 +714,37 @@ def _chunk_nll(h, w, targets, scored, tied, name):
                    * keep[:, None]).astype(h.dtype)
         dw = dw + (_head_dot(dlogits, hc, (0, 0)) if tied else jnp.dot(
             hc.T, dlogits, preferred_element_type=jnp.float32))
-        return dw, (jnp.sum(nll * keep),
+        return dw, (nll if per_token else jnp.sum(nll * keep),
                     jnp.dot(dlogits, w if tied else w.T))
 
     dw, (nll, dh) = lax.scan(
         some_tokens, jnp.zeros(w.shape, jnp.float32),
         (h.reshape(-1, chunk, D), targets.reshape(-1, chunk),
          scored.reshape(-1, chunk)))
+    if per_token:
+        nll = nll.reshape(-1)
+        return jnp.sum(nll * scored), dh.reshape(-1, D)[:t], dw, nll[:t]
     return jnp.sum(nll), dh.reshape(-1, D)[:t], dw
 
 
-def _head_parts(h, w, targets, scored, tied, name):
+def _head_parts(h, w, targets, scored, tied, name, per_token=False):
     """:func:`_chunk_nll` on every chip's own tokens (a manual region over
     the data axes of the mesh: the head is gathered once, and its gradient
     summed over the chips ONCE, outside, not chunk by chunk; every other
     axis stays the partitioner's) -> (sums [chips], dh [B, S, D], dw
-    [chips, ...] float32: each chip's share)."""
+    [chips, ...] float32: each chip's share; with ``per_token`` also the
+    positions' negative log likelihoods [B, S] float32)."""
     from jax.sharding import PartitionSpec as P
     from deepspeed_tpu.comm.mesh import get_topology
     from deepspeed_tpu.utils.jax_compat import get_abstract_mesh, shard_map
     B, S, D = h.shape
 
     def on_chip(h, w, targets, scored):
-        total, dh, dw = _chunk_nll(
+        total, dh, dw, *nll = _chunk_nll(
             h.reshape(-1, D), w, targets.reshape(-1), scored.reshape(-1),
-            tied, name)
-        return total[None], dh.reshape(h.shape), dw[None]
+            tied, name, per_token)
+        return (total[None], dh.reshape(h.shape), dw[None],
+                *(n.reshape(targets.shape) for n in nll))
 
     topo = get_topology()
     # axes an enclosing manual region already maps (the quantized gradient
@@ -747,7 +758,7 @@ def _head_parts(h, w, targets, scored, tied, name):
     rows = P(axes)
     return shard_map(on_chip, mesh=context if outer else topo.mesh,
                      in_specs=(rows, P(), rows, rows),
-                     out_specs=(rows, rows, rows),
+                     out_specs=(rows,) * (4 if per_token else 3),
                      axis_names=frozenset(axes), check_vma=False)(
                          h, w, targets, scored)
 
@@ -768,25 +779,42 @@ def _sum_of_chips(dw):
 
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def head_nll_sum(h, w, targets, scored, tied=False, name="main"):
-    """The scored positions' negative log likelihoods of ``targets`` [B,
-    S] under ``softmax(h w)``, summed: float32 [].  ``h`` [B, S, D] is the
-    normed hidden state, ``w`` the head in ``h``'s dtype ([D, V]; the
-    embedding table [V, D] where ``tied``), ``scored`` [B, S] float32 ones
-    and zeros.  The logits are float32 and never whole
+    """The positions' negative log likelihoods of ``targets`` [B, S] under
+    ``softmax(h w)``, each times its ``scored``, summed: float32 [].  ``h``
+    [B, S, D] is the normed hidden state, ``w`` the head in ``h``'s dtype
+    ([D, V]; the embedding table [V, D] where ``tied``), ``scored`` [B, S]
+    float32: ones and zeros (which positions count), or any weights (a
+    looped model's probabilities of leaving after this pass:
+    models/ouro.py).  The logits are float32 and never whole
     (:func:`_chunk_nll`): at 8,192 tokens a chip and 98,304 ids one pass
     would hold 3.2 GB of them, and as much again for their gradient.
     ``name`` is the call's in the step's account
-    (``tracing.head_chunks``)."""
-    return _head_nll_fwd(h, w, targets, scored, tied, name)[0]
+    (``tracing.head_chunks``).
+
+    Differentiable in ``h``, ``w`` and ``scored``.  The gradient in
+    ``scored`` is the positions' negative log likelihoods, [B, S] float32,
+    which the forward rule keeps **only where the caller differentiates
+    through** ``scored`` (the rule is told: ``symbolic_zeros``).  A caller
+    whose ``scored`` does not depend on what it differentiates — every
+    family but the looped one: ones and zeros made from the batch — gets
+    the forward and backward rules it had before ``scored`` took weights,
+    to the letter of its lowered step (tests/test_weighted_head.py)."""
+    return jnp.sum(_head_parts(h, w, targets, scored, tied, name)[0])
 
 
 def _head_nll_fwd(h, w, targets, scored, tied, name):
-    total, dh, dw = _head_parts(h, w, targets, scored, tied, name)
-    return jnp.sum(total), (dh, dw)
+    # the arguments as custom_vjp hands them under symbolic_zeros: the
+    # value, and whether the caller differentiates through it
+    total, *kept = _head_parts(h.value, w.value, targets.value,
+                               scored.value, tied, name,
+                               per_token=scored.perturbed)
+    return jnp.sum(total), tuple(kept)
 
 
 def _head_nll_bwd(tied, name, res, g):
-    dh, dw = res
+    dh, dw, *nll = res
+    if isinstance(g, SymbolicZero):
+        g = jnp.zeros(g.shape, g.dtype)
     # the chips' shares are summed and scaled in float32 and rounded once
     grads = ((g * dh).astype(dh.dtype),
              (g * _sum_of_chips(dw)).astype(dh.dtype))
@@ -798,10 +826,10 @@ def _head_nll_bwd(tied, name, res, g):
         # kept the loop's float32 carry until then, under every layer's
         # backward (the four-chip ZeRO-3 cell's peak + 0.14 GiB; so - 0.24)
         grads = lax.optimization_barrier(grads)
-    return (*grads, None, None)
+    return (*grads, None, g * nll[0] if nll else None)
 
 
-head_nll_sum.defvjp(_head_nll_fwd, _head_nll_bwd)
+head_nll_sum.defvjp(_head_nll_fwd, _head_nll_bwd, symbolic_zeros=True)
 
 
 def head_token_loss(h, w, batch, *, tied: bool = False, targets=None,
@@ -813,7 +841,11 @@ def head_token_loss(h, w, batch, *, tied: bool = False, targets=None,
     hidden state with whatever the family scales its logits by applied;
     ``w`` the head as its owner stores it, cast here: [D, V], or ``wte``
     [V, D] where ``tied``.  bf16 operands into the three products, float32
-    accumulation, logits and loss."""
+    accumulation, logits and loss.  ``scored`` here is ones and zeros made
+    from the batch, so this caller is :func:`head_nll_sum`'s without a
+    gradient in the weights: the program it was before they could be any
+    (a loss that weights its positions calls :func:`head_nll_sum`
+    itself)."""
     targets, scored = next_token_targets(batch) if targets is None \
         else targets
     scored = scored.astype(jnp.float32)

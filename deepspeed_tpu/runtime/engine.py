@@ -1118,10 +1118,12 @@ class DeepSpeedEngine:
         return dict(self._step_counts)
 
     def step_load(self) -> dict:
-        """What the router did to the steps run, from the sums that leave
-        the fused step beside the counts (``moe/layer.py step_load``: every
-        name a sum over a step's expert layer-calls, micro-batches and
-        chips; a fact no layer of the step made reads 0 and is left out):
+        """What the data did to the steps run, from the sums that leave
+        the fused step beside the counts — what the router did
+        (``moe/layer.py step_load``: every name a sum over a step's expert
+        layer-calls, micro-batches and chips), where a looped model's
+        tokens leave (``models/ouro.py STEP_LOAD``: the exit masses); a
+        fact no layer of the step made reads 0 and is left out:
         ``{"steps": resolved steps, "totals": {name: sum over them},
         "last": [{name: value} a step, newest last, at most 64]}``.  The
         last resolved step's are the registry's gauges of those names.

@@ -415,6 +415,12 @@ SCOPE_SPARSE_ATTN = "sparse_attn"
 SCOPE_SELECT = "select"
 SCOPE_ATTEND = "attend"
 SCOPE_LIGHTNING = "lightning"
+# beside ``ds.head_loss``, where a stack of layers runs several times and a
+# token may leave after any pass (models/ouro.py): the exit gate's product
+# on every pass's state, the distribution over the pass a token leaves
+# after, its entropy and the masses the step reports; the passes' heads
+# stay under ``ds.head_loss``
+SCOPE_EXIT_GATE = "ds.exit_gate"
 PHASES = ("forward", "recompute", "backward", "optimizer", "accumulate",
           "other")
 #: the name the engine registers its fused train step under (the cost
@@ -809,9 +815,9 @@ def step_load(name: str = TRAIN_STEP_PROGRAM):
     """Beside :func:`grouped_gemm_rows` and :func:`exchange_calls`, which
     are shapes: what the router did to the steps the program ``name`` has
     run, as its registrant has it NOW (the engine's ``step_load()``:
-    ``{"steps", "totals", "last"}`` of the ``moe/*`` sums that leave the
-    step beside its loss — data, no callback; it waits for the steps in
-    flight).  None where no such program is registered, or it is gone."""
+    ``{"steps", "totals", "last"}`` of the ``moe/*`` sums — and of a
+    looped model's ``ouro/*`` exit masses — that leave the step beside
+    its loss: data, no callback; it waits for the steps in flight).  None where no such program is registered, or it is gone."""
     with _PROGRAM_LOCK:
         thunk = _PROGRAM_THUNKS.get(name, {}).get("load")
     return thunk() if thunk else None
@@ -996,10 +1002,24 @@ def flash_calls(name: str = TRAIN_STEP_PROGRAM):
     return _account_rows(name, "flash_calls")
 
 
+def layer_loops(name: str = TRAIN_STEP_PROGRAM):
+    """The layer loops of the step that run their stack more than once
+    with the same weights, as models/ouro.py ``exit_states`` traced them:
+    one row per loop — ``passes`` over ``layers`` layers = ``applications``
+    a token goes through in one forward pass, ``shared_param_bytes`` (the
+    stacked layers' bytes, which every pass reads again and whose gradient
+    is the sum of the passes') and ``saved_carry_bytes`` (the carry into
+    every application: what a rematerialised backward pass is handed,
+    ``applications`` x one micro-batch's hidden state).  None where every
+    layer of the step runs once."""
+    return _account_rows(name, "layer_loops")
+
+
 def head_chunks(name: str = TRAIN_STEP_PROGRAM):
     """The head-and-loss calls of the step as models/model.py
     ``head_nll_sum`` traced them: one row per call (``name``: ``"main"``,
-    a prediction module's ``"mtp"``) — ``tokens`` of one chip, ``d_model``,
+    a prediction module's ``"mtp"``, a looped model's ``"exit1"`` ... one
+    after each pass) — ``tokens`` of one chip, ``d_model``,
     ``vocab``, the ``chunk`` of tokens whose logits exist at a time
     (``head_chunk_tokens``) and how many ``chunks`` walk the tokens,
     ``whole_logits_bytes`` ([tokens, vocab] float32: what the loss would

@@ -103,7 +103,8 @@ ENV_VARS = {
 #: module -> its module-level tuple of metric names that reach a registry
 #: by a variable: the sums a train step returns beside its loss, which the
 #: engine gauges under the names its model gave them (``step_load()``)
-METRIC_NAME_TUPLES = {"deepspeed_tpu/moe/layer.py": "STEP_LOAD"}
+METRIC_NAME_TUPLES = {"deepspeed_tpu/moe/layer.py": "STEP_LOAD",
+                      "deepspeed_tpu/models/ouro.py": "STEP_LOAD"}
 
 #: metric name (as exposed on /metrics, after the ServingMetrics
 #: ``serving/`` prefix normalization) -> one-line description
@@ -306,6 +307,23 @@ METRICS = {
                              "expert axis (every chip computes the same)",
     "moe/mean_chip_rows": "the mean over the chips where "
                           "moe/fullest_chip_rows is the maximum",
+    # --- a looped stack's exits (models/ouro.py): from a train step's own
+    # outputs, rounded, summed over its micro-batches (engine.step_load())
+    "ouro/exit_mass_1": "the scored positions' probability of leaving "
+                        "after pass 1 under the exit gates, summed over a "
+                        "train step's positions and micro-batches and "
+                        "rounded (from the step's own outputs); the four "
+                        "masses add up to ouro/scored_tokens",
+    "ouro/exit_mass_2": "the same after pass 2: lam_2 (1 - lam_1)",
+    "ouro/exit_mass_3": "the same after pass 3",
+    "ouro/exit_mass_4": "what is left for the last pass: the product of "
+                        "the earlier gates' (1 - lam)",
+    "ouro/scored_tokens": "the positions a train step's loss scored (the "
+                          "next token in the same document), summed as "
+                          "ouro/exit_mass_1 is",
+    "ouro/exit_pass_tokens": "sum over passes t of t x the mass leaving "
+                             "after t; over ouro/scored_tokens it is the "
+                             "pass a token is expected to leave after",
     # --- numerics observatory (training health, ISSUE 15)
     "num/grad_norm": "last resolved global gradient norm (-1 = "
                      "non-finite)",

@@ -42,6 +42,8 @@ CELLS = {
         (16384, 2560, 25008, True),
     "minicpm-sala.packed-s16384-longdocs": (16384, 4096, 9181, False),
     "granite-4.0-h-small.packed-s4096-gas1": (4096, 4096, 12544, True),
+    # four passes' states side by side through one head (models/ouro.py)
+    "ouro-2.6b.packed-s16384-traces": (65536, 2048, 49152, False),
 }
 CHUNKS = (1024, 2048, 4096, 8192)
 
@@ -91,8 +93,10 @@ def main():
                  "segment_ids": jnp.arange(t)[None, :] // 1000}
         chunks = sorted({c for c in map(int, args.chunks.split(","))
                          if c < t} | {rule(t, V)})
-        for name, chunk in [("whole", None)] + [("chunked", c)
-                                                for c in chunks]:
+        # whole float32 logits past half a chip's memory have no row (the
+        # looped cell's four passes side by side: 12.9 GB)
+        whole = [("whole", None)] if 4 * t * V <= 8 * 2 ** 30 else []
+        for name, chunk in whole + [("chunked", c) for c in chunks]:
             # the rule's answer for this row alone (read at trace time)
             model.head_chunk_tokens = (
                 rule if chunk is None else lambda *_, c=chunk: c)

@@ -114,6 +114,73 @@ def _xing():
         remat=True)
 
 
+def _tiny(module, function, size):
+    """A family's own ``tiny`` preset, float32, rematerialised."""
+    def build():
+        import importlib
+        return getattr(importlib.import_module(
+            "deepspeed_tpu.models." + module), function)(
+                size, **TINY_SIZES[module], dtype="float32", remat=True)
+    return build
+
+
+#: the toy sizes of the families below: their ``tiny`` presets as they
+#: stood when the digests were taken (written out, so that a preset that
+#: moves does not move a digest)
+TINY_SIZES = {
+    "laguna": dict(
+        vocab_size=256, max_seq_len=128, num_layers=5, d_model=32,
+        num_heads_full=4, num_heads_sliding=6, num_kv_heads=2, head_dim=16,
+        sliding_window=8, original_max_position_embeddings=16,
+        d_ff_dense=64, d_ff=16, num_experts=8, top_k=2,
+        shared_expert_d_ff=16),
+    "mellum": dict(
+        vocab_size=256, max_seq_len=128, num_layers=4, d_model=32,
+        num_heads=4, num_kv_heads=2, head_dim=16, sliding_window=8,
+        original_max_position_embeddings=16, d_ff=16, num_experts=8,
+        top_k=2),
+    "kimi_linear": dict(
+        vocab_size=256, max_seq_len=128, num_layers=8, d_model=32,
+        kda_num_heads=2, kda_head_dim=8, kda_gate_rank=8,
+        delta_rule_chunk=16, num_heads=2, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        d_ff_dense=64, d_ff=16, num_experts=8, top_k=2,
+        shared_expert_d_ff=16),
+    "phi4flash": dict(
+        vocab_size=256, max_seq_len=256, num_layers=8, d_model=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,
+        sliding_window=16, mamba_dt_rank=4, scan_chunk=16),
+    "minicpm_sala": dict(
+        vocab_size=256, max_seq_len=128, num_layers=4,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                     "lightning-attn"),
+        d_model=64, d_ff=128, num_heads=4, num_kv_heads=2, head_dim=16,
+        block_size=4, kernel_size=2, kernel_stride=1, topk=4,
+        init_blocks=1, window_size=8, dense_len=32, attend_query_chunk=16,
+        attend_key_spans=2, lightning_heads=4, lightning_head_dim=16,
+        scan_chunk=16, mlp_token_tile=32),
+    "granite_hybrid": dict(
+        vocab_size=256, max_seq_len=128, num_layers=4,
+        layer_types=("mamba", "attention", "mamba", "attention"),
+        d_model=32, num_heads=4, num_kv_heads=2, head_dim=16,
+        mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16,
+        chunk_size=16, d_ff=16, num_experts=8, top_k=2,
+        shared_expert_d_ff=32),
+}
+#: the families of the benchmark that none of the three sets below holds
+#: (tests/test_weighted_head.py: their steps at PR 70's parent commit,
+#: from before ``head_nll_sum`` took weights that are not ones and zeros)
+HEAD_FAMILIES = {
+    "laguna": _tiny("laguna", "laguna_model", "s-2.1"),
+    "mellum": _tiny("mellum", "mellum_model", "12b-a2.5b"),
+    "kimi_linear": _tiny("kimi_linear", "kimi_linear_model", "48b-a3b"),
+    "phi4flash": _tiny("phi4flash", "phi4flash_model", "mini-flash"),
+    "minicpm_sala": _tiny("minicpm_sala", "minicpm_sala_model", "9b"),
+    "granite_hybrid": _tiny("granite_hybrid", "granite_hybrid_model",
+                            "4.0-h-small"),
+}
+
+
 FAMILIES = {"gpt2": _gpt2, "olmoe": _olmoe, "qwen3_next": _qwen3_next,
             "nemotron_h": _nemotron_h}
 #: the families whose expert layers hold a subset of the experts — their
@@ -171,8 +238,8 @@ def digest(family: str, grouped_kernels: bool = False) -> str:
         if grouped_kernels:
             os.environ["DS_GGEMM_INTERPRET"] = "1"
         try:
-            model = {**FAMILIES, **HELD_FAMILIES,
-                     **NEIGHBOUR_FAMILIES}[family]()
+            model = {**FAMILIES, **HELD_FAMILIES, **NEIGHBOUR_FAMILIES,
+                     **HEAD_FAMILIES}[family]()
             shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
             batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
                      "segment_ids": jnp.zeros((2, 64), jnp.int32)}

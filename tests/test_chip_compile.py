@@ -1176,6 +1176,48 @@ def test_the_exchanged_layers_backward_waits_for_its_recompute(v5e,
                for kernel in kernel_of.values()) == 4
 
 
+def test_a_looped_stack_sums_its_four_uses_in_one_stacked_gradient(
+        v5e, monkeypatch):
+    """Ouro at the published widths, four layers four times over, 2,048
+    packed tokens, loss and every gradient compiled for the chip: the one
+    scan of 16 applications adds a layer's gradient into the stacked
+    accumulator where it lies (the gradients are the program's outputs:
+    776 MiB, of which the stack's 392), so ``temp`` holds the carries, the
+    head's float32 ``dw`` and a chunk's logits — 1,255 MiB — and not a
+    second stacked gradient (392 MiB more, as a scan of passes around a
+    scan of layers reads); the flash kernels are the loop's: forward,
+    forward again in the recompute, and the backward's two."""
+    from deepspeed_tpu.comm.mesh import (MeshTopology, reset_topology,
+                                         set_topology)
+    from deepspeed_tpu.models.ouro import ouro_model
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import vmem
+    from deepspeed_tpu.telemetry import tracing
+    monkeypatch.setattr(vmem, "device_kind",
+                        lambda: v5e[0].device_kind.lower())
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    set_topology(MeshTopology(devices=v5e[:1]))
+    try:
+        model = ouro_model("2.6b", num_layers=4, remat=True)
+        params = jax.tree.map(
+            lambda a: _arg(v5e[0], a.shape),
+            jax.eval_shape(model.init_fn, jax.random.PRNGKey(0)))
+        tokens = _arg(v5e[0], (1, 2048), jnp.int32)
+        compiled = jax.jit(jax.value_and_grad(model.loss)).lower(
+            params, {"input_ids": tokens, "segment_ids": tokens}).compile()
+    finally:
+        reset_topology()
+    memory = compiled.memory_analysis()
+    stack = 2 * 4 * 51_380_224
+    assert memory.output_size_in_bytes > stack
+    assert memory.temp_size_in_bytes < 1255 * 2 ** 20 + stack // 2, \
+        memory.temp_size_in_bytes / 2 ** 20
+    kernels = [row["kernel"] for row in tracing.parse_program_text(
+        compiled.as_text()).values() if row["kernel"]]
+    assert sorted(kernels) == ["ds_flash_bwd_dkv", "ds_flash_bwd_dq",
+                               "ds_flash_fwd", "ds_flash_fwd"]
+
+
 def test_library_knows_the_chips_peaks(v5e):
     from deepspeed_tpu.telemetry.mfu import peak_flops_per_device
     from deepspeed_tpu.telemetry.roofline import (hbm_bytes_per_s,

@@ -289,6 +289,7 @@ CELL_CHUNKS = {
     "phi-4-mini-flash-reasoning.packed-s16384-traces": 1024,
     "minicpm-sala.packed-s16384-longdocs": 2048,
     "granite-4.0-h-small.packed-s4096-gas1": 2048,
+    "ouro-2.6b.packed-s16384-traces": 2048,
 }
 
 
