@@ -19,8 +19,12 @@ VMEM, ``q``, ``k``, ``dq``, ``dk`` stay ``dk`` wide, and no operand is
 padded.
 ``segment_ids`` [B, S] int32 restricts attention to same-segment pairs —
 packed-sequence training the stock wrapper lacked (pass None for a single
-segment).  The [S, S] score matrix never materialises in HBM;
-VMEM holds one [block_q, block_k] tile.
+segment) — and bounds the kernels' tile loops: a q-block's loop starts at
+the first key block that holds an id of its own, a key block's stops at
+the last such q-block (``document_block_tables``), so other documents'
+tiles are skipped, not masked; the mask stays in the tile body for the
+tiles a boundary crosses and for ids in no order.  The [S, S] score matrix
+never materialises in HBM; VMEM holds one [block_q, block_k] tile.
 """
 import functools
 
@@ -49,7 +53,8 @@ def _window_first_kblock(iq, block_q, block_k, window):
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
                 has_seg, window=None):
     if has_seg:
-        q_ref, k_ref, v_ref, segq_ref, segk_ref, o_ref, lse_ref = refs
+        (first_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref, o_ref,
+         lse_ref) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref = refs
     iq = pl.program_id(2)
@@ -64,9 +69,13 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     n_kblocks = (_causal_kblocks(iq, block_q, block_k, seq_len)
                  if causal else seq_len // block_k)
-    # a window moves the loop's START: key blocks below it are never read
+    # a window moves the loop's START: key blocks below it are never read;
+    # so do the documents (document_block_tables): the blocks before the
+    # first that holds an id of this q-block's are other documents' whole
     first = (0 if window is None
              else _window_first_kblock(iq, block_q, block_k, window))
+    if has_seg:
+        first = jnp.maximum(first, first_ref[pl.program_id(0), iq])
 
     def body(j, carry):
         m, l, acc = carry
@@ -121,7 +130,7 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
     per-q [Bq, 1] column layout tile-pads the lane dim x128 and blows the
     VMEM budget at long S (16k-fp32-class working sets)."""
     if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        (last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          segq_ref, segk_ref, dk_ref, dv_ref) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -144,6 +153,10 @@ def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len, rep,
         # past it: q blocks beyond are never read
         stop = jnp.minimum(
             stop, ((ik + 1) * block_k + window - 2) // block_q + 1)
+    if has_seg:
+        # nor the q blocks past the last that holds an id of this key
+        # block's: later documents' whole
+        stop = jnp.minimum(stop, last_ref[pl.program_id(0), ik] + 1)
 
     def body(j, carry):
         dk, dv = carry
@@ -197,7 +210,7 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     rows); the dq accumulator itself stays [Bq, hd] (contraction over the
     sublane k dim of ds_t) and takes the scale once, after the loop."""
     if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        (first_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          segq_ref, segk_ref, dq_ref) = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
@@ -219,6 +232,8 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
                  if causal else seq_len // block_k)
     first = (0 if window is None
              else _window_first_kblock(iq, block_q, block_k, window))
+    if has_seg:
+        first = jnp.maximum(first, first_ref[pl.program_id(0), iq])
 
     def body(j, dq):
         k = k_ref[0, 0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
@@ -249,6 +264,102 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
 
 def _to_bhsd(x):
     return jnp.transpose(x, (0, 2, 1, 3))
+
+
+def _document_bounds(xp, segment_ids, block_q, block_k):
+    """:func:`document_block_tables` in ``xp`` (jax.numpy or numpy)."""
+    rows, seq_len = segment_ids.shape
+
+    def id_range(block):
+        ids = segment_ids.reshape(rows, seq_len // block, block)
+        return ids.min(axis=-1), ids.max(axis=-1)
+
+    q_lo, q_hi = id_range(block_q)
+    k_lo, k_hi = id_range(block_k)
+    # [rows, q blocks, key blocks]: the two blocks' id intervals meet
+    meets = ((k_lo[:, None, :] <= q_hi[:, :, None])
+             & (k_hi[:, None, :] >= q_lo[:, :, None]))
+    first_kblock = xp.argmax(meets, axis=2)
+    last_qblock = meets.shape[1] - 1 - xp.argmax(meets[:, ::-1], axis=1)
+    return first_kblock.astype(xp.int32), last_qblock.astype(xp.int32)
+
+
+def document_block_tables(segment_ids, block_q, block_k):
+    """What the documents add to the tile loops' bounds, once a call, in
+    XLA: ``first_kblock`` [B, S / block_q], the first key block whose
+    least-to-greatest id interval meets the q-block's, and ``last_qblock``
+    [B, S / block_k], the last q-block whose interval meets the key
+    block's.  A pair of equal ids lies in both blocks' intervals, so no
+    block outside the bounds holds a visible pair, whatever the order of
+    the ids (the mask in the tile body stays the arbiter); for the
+    monotone runs a packer writes the bounds are exact: the block of the
+    first key of the q-block's first query's document, and the block of
+    the last query of the key block's last key's document."""
+    return _document_bounds(jnp, segment_ids, block_q, block_k)
+
+
+def document_block_bounds(segment_ids, block_q, block_k):
+    """:func:`document_block_tables` on the host (numpy in, numpy out),
+    for the steps of a family whose loss returns no counts, and for tests
+    and scripts."""
+    import numpy as np
+    return _document_bounds(np, np.asarray(segment_ids), block_q, block_k)
+
+
+def visited_tiles(first_kblock, seq_len, block_q, block_k, causal=True,
+                  window=None):
+    """``(visited, positional)``: the score tiles one head's forward pass
+    visits over the rows of ``first_kblock`` (of either function above) —
+    the kernels' own ``n_kblocks - first`` summed over the q-blocks, each
+    from ``max(first by window, first_kblock)`` to its diagonal — and what
+    position alone visits there (:func:`tile_counts` a row)."""
+    import numpy as np
+    iq = np.arange(seq_len // block_q)
+    stop = (np.minimum((iq + 1) * block_q // block_k, seq_len // block_k)
+            if causal else np.full_like(iq, seq_len // block_k))
+    first = jnp.maximum if isinstance(first_kblock, jax.Array) else np.maximum
+    if window is not None:
+        first_kblock = first(
+            np.maximum(iq * block_q - (window - 1), 0) // block_k,
+            first_kblock)
+    positional = sum(tile_counts(seq_len, block_q, block_k, causal, window))
+    return ((stop - first_kblock).sum(),
+            positional * first_kblock.shape[0])
+
+
+#: what the documents did to a step's tile loops, among the sums that leave
+#: the fused step beside its loss (``engine.step_load()``): the score tiles
+#: one head's forward pass visited over the step's rows, a call shape of the
+#: step's packed ``tracing.flash_calls`` rows each, and what position alone
+#: would have visited there (those rows' ``tiles``)
+VISITED_TILES = "flash/visited_tiles"
+POSITIONAL_TILES = "flash/positional_tiles"
+STEP_LOAD = (VISITED_TILES, POSITIONAL_TILES)
+
+
+def step_tile_sums(segment_ids, calls):
+    """``{VISITED_TILES, POSITIONAL_TILES}`` (int32 scalars) of a
+    micro-batch's ``segment_ids`` [rows, S] over ``calls``, rows of
+    ``tracing.flash_calls``: :func:`visited_tiles` by each packed row's
+    blocks and window, from the tables its kernels' loops are bounded by —
+    made here once a micro-batch, not once a layer.  ``{}`` where no row
+    is a packed call over S keys."""
+    segment_ids = segment_ids.astype(jnp.int32)
+    seq_len = segment_ids.shape[1]
+    tables, sums = {}, []       # a table a block shape, however many rows
+    for row in calls:
+        if row["packed"] and row["seq_len"] == seq_len:
+            blocks = tuple(row["blocks"])
+            if blocks not in tables:
+                tables[blocks] = document_block_tables(segment_ids,
+                                                       *blocks)[0]
+            sums.append(visited_tiles(
+                tables[blocks], seq_len, *blocks, row.get("causal", True),
+                row.get("window")))
+    if not sums:
+        return {}
+    return {VISITED_TILES: sum(v for v, _ in sums).astype(jnp.int32),
+            POSITIONAL_TILES: jnp.int32(sum(p for _, p in sums))}
 
 
 def _choose_blocks(seq_len, block_q, block_k):
@@ -342,6 +453,19 @@ def _compiler_kw(q, block_q, block_k, packed, v=None):
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
+def _grid_kw(packed, grid, in_specs, out_specs):
+    """A call's grid and specs: as plain arguments — the call that always
+    was — or, for a packed call, as the grid spec that hands its first
+    operand (the documents' table of loop bounds) to the kernel and the
+    index maps as scalars in SMEM."""
+    if not packed:
+        return {"grid": grid, "in_specs": in_specs, "out_specs": out_specs}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"grid_spec": pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        out_specs=out_specs)}
+
+
 def window_k_tiles(window, block_q, block_k):
     """Key tiles the forward and dq loops visit for a q-block far enough
     from the sequence's start: from the tile of its first query's lowest
@@ -381,7 +505,7 @@ def _record_call(q, k, v, block_q, block_k, packed, causal, window=None,
     step is traced; ``tiles`` is :func:`tile_counts`.  A windowed call's
     row also holds its ``window`` and the key tiles a q-block visits; a
     call whose keys and values are another layer's, that layer
-    (``kv_of``)."""
+    (``kv_of``); one that is not causal, ``causal`` False."""
     from deepspeed_tpu.telemetry.tracing import count_in_step
     B, S, H, hd = q.shape
     bq, bk = _choose_blocks(S, block_q, block_k)
@@ -392,6 +516,7 @@ def _record_call(q, k, v, block_q, block_k, packed, causal, window=None,
            "tiles": tile_counts(S, bq, bk, causal, window)}
     key = f"{B}x{S}x{H}x{k.shape[2]}x{hd}x{v.shape[3]}x{int(packed)}"
     if not causal:
+        row.update(causal=False)
         key += "nc"     # every tile interior: a row of its own
     if window is not None:
         row.update(window=window,
@@ -419,7 +544,9 @@ def ds_flash_attention(q, k, v, segment_ids=None, causal=True,
     matches an integer primal); packed sequences attend only within their
     own segment (non-differentiable — a proper custom_vjp argument, NOT a
     closure capture: closed-over tracers break under jit/scan train
-    steps).  ``kv_of``: the layer whose keys and values these are, where
+    steps), and tiles that are another document's whole are never read
+    (:func:`document_block_tables`; any ids, no order assumed: same id
+    attends).  ``kv_of``: the layer whose keys and values these are, where
     it is not the caller's own — for the account's row only."""
     if segment_ids is not None:
         segment_ids = segment_ids.astype(jnp.int32)
@@ -497,27 +624,28 @@ def _fwd(q, k, v, segment_ids, causal, sm_scale, block_q, block_k,
         seq_len=S, has_seg=has_seg, window=window)
     operands = [qT, kT, vT]
     in_specs = [
-        pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, *_: (b, h, i, 0)),
         pl.BlockSpec((1, 1, S, hd),
-                     lambda b, h, i: (b, h // rep, 0, 0)),
+                     lambda b, h, i, *_: (b, h // rep, 0, 0)),
         pl.BlockSpec((1, 1, S, hv),
-                     lambda b, h, i: (b, h // rep, 0, 0)),
+                     lambda b, h, i, *_: (b, h // rep, 0, 0)),
     ]
     if has_seg:
         seg = segment_ids
-        operands += [seg[:, :, None], seg[:, None, :]]
-        in_specs += [pl.BlockSpec((1, bq, 1), lambda b, h, i: (b, i, 0)),
-                     pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0))]
+        first_kblock, _ = document_block_tables(seg, bq, bk)
+        operands = [first_kblock] + operands + [seg[:, :, None],
+                                                seg[:, None, :]]
+        in_specs += [pl.BlockSpec((1, bq, 1), lambda b, h, i, *_: (b, i, 0)),
+                     pl.BlockSpec((1, 1, S), lambda b, h, i, *_: (b, 0, 0))]
     oT, lse = pl.pallas_call(
-        kernel, grid=(B, H, S // bq),
+        kernel,
         name="ds_flash_fwd" if window is None else "ds_flash_win_fwd",
         **_ikw,
         **_compiler_kw(q, block_q, block_k, has_seg, v),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, hv), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
-        ],
+        **_grid_kw(has_seg, (B, H, S // bq), in_specs, [
+            pl.BlockSpec((1, 1, bq, hv), lambda b, h, i, *_: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, *_: (b, h, i, 0)),
+        ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, hv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
@@ -567,48 +695,48 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         seq_len=S, rep=rep, has_seg=has_seg, window=window)
     dkv_in = [qT, kT, vT, doT, lse_r, delta_r]
     dkv_specs = [
-        pl.BlockSpec((1, 1, S, hd), lambda b, i, h: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, S, hd), lambda b, i, h, *_: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bk, hd),
-                     lambda b, i, h: (b, h // rep, i, 0)),
+                     lambda b, i, h, *_: (b, h // rep, i, 0)),
         pl.BlockSpec((1, 1, bk, hv),
-                     lambda b, i, h: (b, h // rep, i, 0)),
-        pl.BlockSpec((1, 1, S, hv), lambda b, i, h: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, S), lambda b, i, h: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, S), lambda b, i, h: (b, h, 0, 0))]
+                     lambda b, i, h, *_: (b, h // rep, i, 0)),
+        pl.BlockSpec((1, 1, S, hv), lambda b, i, h, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, 1, S), lambda b, i, h, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, 1, S), lambda b, i, h, *_: (b, h, 0, 0))]
     dq_in = [qT, kT, vT, doT, lse_r, delta_r]
     dq_specs = [
-        pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, *_: (b, h, i, 0)),
         pl.BlockSpec((1, 1, S, hd),
-                     lambda b, h, i: (b, h // rep, 0, 0)),
+                     lambda b, h, i, *_: (b, h // rep, 0, 0)),
         pl.BlockSpec((1, 1, S, hv),
-                     lambda b, h, i: (b, h // rep, 0, 0)),
-        pl.BlockSpec((1, 1, bq, hv), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, 1, S), lambda b, h, i: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, S), lambda b, h, i: (b, h, 0, 0)),
+                     lambda b, h, i, *_: (b, h // rep, 0, 0)),
+        pl.BlockSpec((1, 1, bq, hv), lambda b, h, i, *_: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, 1, S), lambda b, h, i, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, 1, S), lambda b, h, i, *_: (b, h, 0, 0)),
     ]
     if has_seg:
         seg = segment_ids
         seg_col, seg_row = seg[:, :, None], seg[:, None, :]
+        first_kblock, last_qblock = document_block_tables(seg, bq, bk)
         # dkv: segq row slices [1, Bq] (whole-S row), segk column block
         # [Bk, 1] indexed by the k grid dim (no whole-S column staging)
-        dkv_in += [seg_row, seg_col]
-        dkv_specs += [pl.BlockSpec((1, 1, S), lambda b, i, h: (b, 0, 0)),
-                      pl.BlockSpec((1, bk, 1), lambda b, i, h: (b, i, 0))]
+        dkv_in = [last_qblock] + dkv_in + [seg_row, seg_col]
+        dkv_specs += [pl.BlockSpec((1, 1, S), lambda b, i, h, *_: (b, 0, 0)),
+                      pl.BlockSpec((1, bk, 1), lambda b, i, h, *_: (b, i, 0))]
         # dq: segq whole-S row (sliced [1, Bq] in-kernel), segk whole-S
         # column (sliced [Bk, 1] per key block in-kernel)
-        dq_in += [seg_row, seg_col]
-        dq_specs += [pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
-                     pl.BlockSpec((1, S, 1), lambda b, h, i: (b, 0, 0))]
+        dq_in = [first_kblock] + dq_in + [seg_row, seg_col]
+        dq_specs += [pl.BlockSpec((1, 1, S), lambda b, h, i, *_: (b, 0, 0)),
+                     pl.BlockSpec((1, S, 1), lambda b, h, i, *_: (b, 0, 0))]
     dkT, dvT = pl.pallas_call(
-        dkv_kernel, grid=(B, S // bk, H),
+        dkv_kernel,
         name="ds_flash_bwd_dkv" if window is None
         else "ds_flash_win_bwd_dkv", **_ikw, **_ckw,
-        in_specs=dkv_specs,
-        out_specs=[
+        **_grid_kw(has_seg, (B, S // bk, H), dkv_specs, [
             pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, i, h: (b, h // rep, i, 0)),
+                         lambda b, i, h, *_: (b, h // rep, i, 0)),
             pl.BlockSpec((1, 1, bk, hv),
-                         lambda b, i, h: (b, h // rep, i, 0))],
+                         lambda b, i, h, *_: (b, h // rep, i, 0))]),
         out_shape=[jax.ShapeDtypeStruct((B, KV, S, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, KV, S, hv), jnp.float32)],
     )(*dkv_in)
@@ -617,11 +745,11 @@ def _bwd_calls(q, k, v, do, lse, delta, segment_ids, causal, sm_scale,
         _dq_kernel, sm_scale=sm, causal=causal, block_q=bq, block_k=bk,
         seq_len=S, has_seg=has_seg, window=window)
     dqT = pl.pallas_call(
-        dq_kernel, grid=(B, H, S // bq),
+        dq_kernel,
         name="ds_flash_bwd_dq" if window is None else "ds_flash_win_bwd_dq",
         **_ikw, **_ckw,
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
+        **_grid_kw(has_seg, (B, H, S // bq), dq_specs, pl.BlockSpec(
+            (1, 1, bq, hd), lambda b, h, i, *_: (b, h, i, 0))),
         out_shape=jax.ShapeDtypeStruct(
             (B, H, S, hd), jnp.float32 if keep_fp32 else q.dtype),
     )(*dq_in)

@@ -24,7 +24,7 @@ from deepspeed_tpu.telemetry.numerics import (
     group_stats, group_stats_of, inject_nonfinite)
 from deepspeed_tpu.telemetry.tracing import (
     SCOPE_ACCUMULATE, SCOPE_FWD_BWD, SCOPE_OPTIMIZER, TRAIN_STEP_PROGRAM,
-    count_in_step, step_account)
+    count_in_step, rows_in_step, step_account)
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -77,6 +77,20 @@ def compress(ctx, params, step):
     return compress_params_traced(params, step, ctx.compression_plans)
 
 
+def flash_tile_sums(batch):
+    """What a packed micro-batch's documents did to the flash kernels'
+    tile loops (``ds_flash_attention.step_tile_sums`` over the calls the
+    loss above has just traced into the step's account): two more of the
+    sums that leave the step beside its loss; ``{}`` for a batch with no
+    ``segment_ids`` or a step with no packed flash call."""
+    segment_ids = batch.get("segment_ids") if isinstance(batch, dict) \
+        else None
+    if segment_ids is None:
+        return {}
+    from deepspeed_tpu.ops.pallas.ds_flash_attention import step_tile_sums
+    return step_tile_sums(segment_ids, rows_in_step("flash_calls"))
+
+
 def scaled_loss(ctx, params, batch, rng, scale, compress_step=None,
                 counted=False):
     """The model's loss times ``scale``; ``counted``: beside the model's
@@ -98,7 +112,8 @@ def scaled_loss(ctx, params, batch, rng, scale, compress_step=None,
         cparams = compress(ctx, cparams, compress_step)
     if counted:
         loss, counts = ctx.model.loss_with_counts_fn(cparams, batch, rng)
-        return loss.astype(jnp.float32) * scale, counts
+        return loss.astype(jnp.float32) * scale, {
+            **counts, **flash_tile_sums(batch)}
     loss = ctx.model.loss(cparams, batch, rng)
     return loss.astype(jnp.float32) * scale
 

@@ -780,6 +780,15 @@ def count_in_step(**counters):
             account[name] = int(value)
 
 
+def rows_in_step(key: str) -> list:
+    """The rows :func:`count_in_step` has merged under ``key`` so far into
+    the account being written (``[]`` outside :func:`step_account`): for
+    code of the step that is traced after them and counts by them."""
+    if _ACCOUNT_OPEN is None:
+        return []
+    return list(_STEP_COUNTERS[_ACCOUNT_OPEN].get(key, {}).values())
+
+
 def grouped_gemm_rows(name: str = TRAIN_STEP_PROGRAM):
     """What one grouped GEMM call of the step computes, beside
     :func:`layer_loop_gathers`: ``{"routed_rows_per_call",

@@ -103,8 +103,10 @@ ENV_VARS = {
 #: module -> its module-level tuple of metric names that reach a registry
 #: by a variable: the sums a train step returns beside its loss, which the
 #: engine gauges under the names its model gave them (``step_load()``)
-METRIC_NAME_TUPLES = {"deepspeed_tpu/moe/layer.py": "STEP_LOAD",
-                      "deepspeed_tpu/models/ouro.py": "STEP_LOAD"}
+METRIC_NAME_TUPLES = {
+    "deepspeed_tpu/moe/layer.py": "STEP_LOAD",
+    "deepspeed_tpu/models/ouro.py": "STEP_LOAD",
+    "deepspeed_tpu/ops/pallas/ds_flash_attention.py": "STEP_LOAD"}
 
 #: metric name (as exposed on /metrics, after the ServingMetrics
 #: ``serving/`` prefix normalization) -> one-line description
@@ -324,6 +326,18 @@ METRICS = {
     "ouro/exit_pass_tokens": "sum over passes t of t x the mass leaving "
                              "after t; over ouro/scored_tokens it is the "
                              "pass a token is expected to leave after",
+    # --- what a packed step's documents let the flash kernels skip
+    # (ops/pallas/ds_flash_attention.py step_tile_sums): from the step's
+    # own outputs, summed over its micro-batches (engine.step_load())
+    "flash/visited_tiles": "score tiles one head's forward pass visited "
+                           "over a train step's rows, a packed call shape "
+                           "of the step each: every q-block from the first "
+                           "key block of its own documents (and of its "
+                           "window) to its diagonal",
+    "flash/positional_tiles": "what position alone would have visited "
+                              "there (tracing.flash_calls() tiles a row): "
+                              "flash/visited_tiles over it is the share "
+                              "of the causal tiles the documents left",
     # --- numerics observatory (training health, ISSUE 15)
     "num/grad_norm": "last resolved global gradient norm (-1 = "
                      "non-finite)",

@@ -17,11 +17,19 @@ the score head (latent attention: 192 / 128), ``--v-head-dim``; ``--window
 ``tiles`` is a head's ``[interior, boundary]`` tiles
 (``ds_flash_attention.tile_counts``: wholly below the diagonal and inside
 the window, or crossed by one of them; None at a commit from before it).
+The packed column (PR 71): ``--rows`` rows a call, successive rows of the
+traffic's stream as a micro-batch holds them, ``tiles_positional`` — what
+position alone visits over them, a head's forward — and ``tiles_visited``,
+what the kernels timed visit: since PR 71 from each q-block's own documents
+(``ds_flash_attention.document_block_bounds``); at a commit from before it
+every positional tile.  ``--checkout DIR`` times the library of another
+checkout (the parent commit's, ``git archive`` into an ignored directory)
+on the same rows, seed and shapes: parent against change is two commands.
 
     python scripts/flash_window_table.py [--seed 1] [--blocks 512x512,256x256]
     python scripts/flash_window_table.py --traffic packed-s8192-gas2 \
         --heads 32 --kv-heads 32 --head-dim 192 --v-head-dim 128 \
-        --window 0 --full-heads 0
+        --window 0 --full-heads 0 --rows 2 [--checkout .chip_checkout/parent]
 
 Fails without a TPU: a time from the CPU is not a time.
 """
@@ -43,18 +51,17 @@ from scripts.bench_util import timed_chain
 DEFAULT_BLOCKS = "512x512,512x256,256x256,256x128,128x128,1024x512"
 
 
-def segments(traffic, seed):
+def segments(traffic, seed, rows=1):
     from harness import datagen
     if not traffic["segment_ids"]:
         return None
     documents = datagen.Documents(np.random.default_rng(seed),
                                   traffic["documents"])
-    seg = np.zeros(traffic["seq_len"], np.int32)
-    at = 0
-    for i, (n, _) in enumerate(documents.row(traffic["seq_len"])):
-        seg[at:at + n] = i
-        at += n
-    return jnp.asarray(seg[None])
+    seg = np.zeros((rows, traffic["seq_len"]), np.int32)
+    for row in seg:
+        lengths = [n for n, _ in documents.row(traffic["seq_len"])]
+        row[:] = np.repeat(np.arange(len(lengths)), lengths)
+    return jnp.asarray(seg)
 
 
 def main():
@@ -69,7 +76,13 @@ def main():
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--v-head-dim", type=int, default=None,
                     help="the value head's width (default: --head-dim)")
+    ap.add_argument("--rows", type=int, default=1,
+                    help="rows of a call: a micro-batch's sequences")
+    ap.add_argument("--checkout", default=None,
+                    help="time the deepspeed_tpu of this checkout's root")
     args = ap.parse_args()
+    if args.checkout:
+        sys.path.insert(0, os.path.abspath(args.checkout))
     if jax.devices()[0].platform != "tpu":
         sys.exit("flash_window_table: no TPU here; a kernel's time comes "
                  "from the chip")
@@ -83,7 +96,7 @@ def main():
         traffic = json.load(f)
     S, hd = traffic["seq_len"], args.head_dim
     hv = args.v_head_dim or hd
-    seg = segments(traffic, args.seed)
+    seg = segments(traffic, args.seed, args.rows)
     peak = device.peaks_for(jax.devices()[0].device_kind)["bf16_flops_per_s"]
     need = {  # required keys a query, times two, as the rooflines count them
         "windowed": window_roofline.keys_times_two(traffic, args.window),
@@ -91,10 +104,11 @@ def main():
 
     def time_call(heads, window, blocks):
         key = jax.random.split(jax.random.PRNGKey(args.seed), 3)
-        q = jax.random.normal(key[0], (1, S, heads, hd), jnp.bfloat16)
-        k = jax.random.normal(key[1], (1, S, args.kv_heads, hd),
+        q = jax.random.normal(key[0], (args.rows, S, heads, hd),
                               jnp.bfloat16)
-        v = jax.random.normal(key[2], (1, S, args.kv_heads, hv),
+        k = jax.random.normal(key[1], (args.rows, S, args.kv_heads, hd),
+                              jnp.bfloat16)
+        v = jax.random.normal(key[2], (args.rows, S, args.kv_heads, hv),
                               jnp.bfloat16)
         attend = lambda q, k, v: ds_flash_attention(
             q, k, v, segment_ids=seg, window=window, block_q=blocks[0],
@@ -138,6 +152,20 @@ def main():
     blocks = [tuple(int(n) for n in b.split("x"))
               for b in args.blocks.split(",")]
     tile_counts = getattr(dsf, "tile_counts", None)
+
+    def tiles_of_the_rows(bq, bk, window):
+        """(visited, positional) tiles of a head's forward over the rows:
+        the library's own count, or every positional tile where its tile
+        loops know position only."""
+        if tile_counts is None:
+            return None, None
+        positional = sum(tile_counts(S, bq, bk, True, window)) * args.rows
+        if seg is None or not hasattr(dsf, "document_block_bounds"):
+            return positional, positional
+        first, _ = dsf.document_block_bounds(np.asarray(seg), bq, bk)
+        return int(dsf.visited_tiles(first, S, bq, bk, True, window)[0]), \
+            positional
+
     for what, heads, window in (("windowed", args.heads, args.window),
                                 ("causal_same_heads", args.heads, None),
                                 ("full_layer", args.full_heads, None)):
@@ -155,13 +183,16 @@ def main():
             kind = "windowed" if what == "windowed" else "causal"
             # a forward call 4 * H hd per key, forward + backward 12 (hd
             # the mean of the two widths: scores at one, values at the other)
-            flops = 0.5 * S * heads * (hd + hv) / 2 * need[kind]
+            flops = 0.5 * args.rows * S * heads * (hd + hv) / 2 * need[kind]
+            visited, positional = tiles_of_the_rows(bq, bk, window)
             print(json.dumps({
-                "call": what, "heads": heads, "kv_heads": args.kv_heads,
+                "call": what, "rows": args.rows, "library": dsf.__file__,
+                "heads": heads, "kv_heads": args.kv_heads,
                 "head_dim": hd, "v_head_dim": hv,
                 "packed": seg is not None, "window": window,
                 "blocks": [bq, bk],
                 "tiles": tile_counts and tile_counts(S, bq, bk, True, window),
+                "tiles_visited": visited, "tiles_positional": positional,
                 "keys_visited_per_query": None if window is None
                 else window_k_tiles(window, bq, bk) * bk,
                 "required_keys_per_query": need[kind] / 2,

@@ -43,6 +43,18 @@ logits — loss, ``dh`` and ``dw``; the gated delta rule's two digests
 hold no loss and stood.  (Taken twice at PR 69: its review moved the sum
 of the chips' shares of the head's gradient, and its rounding, from the
 forward rule to the backward rule.)
+
+The four of flash_step_digests.json, and ``gpt2`` and ``olmoe`` in both
+halves of held_prefix_step_digests.json, were taken again at PR 71: every
+toy batch here is packed, and a packed flash call now hands its kernels
+the documents' loop bounds (``ds_flash_attention.document_block_tables``:
+one more operand a call, one more bound a tile loop) — ``gpt2`` too, whose
+step this file lowers with ``segment_ids``.  What stood: the call with no
+``segment_ids`` (tests/data/flash_dense_call_digest.json, taken at that
+PR's parent by tests/test_flash_document_skip.py ``dense_call_digests``),
+and every family of the other two files, whose toy steps at 64 positions
+take the XLA attention.  tests/test_flash_document_skip.py holds the new
+packed calls to the old ones' results, to the bit.
 """
 import functools
 import hashlib

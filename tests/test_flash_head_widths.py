@@ -216,8 +216,12 @@ def test_with_one_width_the_step_lowers_to_the_parents_text(family):
     per-expert reference); the three others stood.  All four were taken
     again at PR 69: each step ends in the loss, which no longer forms
     whole logits (``models/model.py head_token_loss``, held to
-    ``token_loss`` by tests/test_head_loss.py); tests/flash_step_texts.py
-    says which digests and why."""
+    ``token_loss`` by tests/test_head_loss.py), and at PR 71: the toy
+    batch is packed, and a packed call's tile loops take one more bound,
+    from its documents (tests/test_flash_document_skip.py holds the
+    results to the old calls', to the bit, and the call with no
+    ``segment_ids`` to its old text); tests/flash_step_texts.py says which
+    digests and why."""
     with open(os.path.join(HERE, "data", "flash_step_digests.json")) as f:
         want = json.load(f)
     assert flash_step_texts.digest(family) == want[family]

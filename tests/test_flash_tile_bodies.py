@@ -238,14 +238,25 @@ def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_len,
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
+def _by_position_alone(kernel):
+    """A parent's kernel under today's packed call (PR 71): the documents'
+    table of loop bounds, the call's first operand, is not handed on — its
+    loops are bounded by position and the other documents' tiles masked."""
+    def call(*refs, has_seg, **static):
+        return kernel(*(refs[1:] if has_seg else refs), has_seg=has_seg,
+                      **static)
+    return call
+
+
 @pytest.fixture
 def parent_kernels(monkeypatch):
     """``dsf._fwd`` / ``dsf._bwd_calls`` with the parent's three kernels:
     same grids, BlockSpecs and names, the old bodies."""
     def swap():
-        monkeypatch.setattr(dsf, "_fwd_kernel", _fwd_kernel)
-        monkeypatch.setattr(dsf, "_dkv_kernel", _dkv_kernel)
-        monkeypatch.setattr(dsf, "_dq_kernel", _dq_kernel)
+        for name, kernel in (("_fwd_kernel", _fwd_kernel),
+                             ("_dkv_kernel", _dkv_kernel),
+                             ("_dq_kernel", _dq_kernel)):
+            monkeypatch.setattr(dsf, name, _by_position_alone(kernel))
     return swap
 
 
@@ -397,26 +408,30 @@ def test_a_row_whose_first_tiles_are_another_documents(interpret_pallas,
 
 def _forward_kernel_alone(kernel, q, k, v, seg_q, seg_k, block):
     """The forward call as ``dsf._fwd`` makes it, with the q side's and the
-    k side's segment ids given apart (heads as batch, not causal)."""
+    k side's segment ids given apart (heads as batch, not causal); the
+    documents' table says nothing: every q-block from key block 0."""
+    from jax.experimental.pallas import tpu as pltpu
     B, s, hd = q.shape
     fn = functools.partial(kernel, sm_scale=hd ** -0.5, causal=False,
                            block_q=block, block_k=block, seq_len=s,
                            has_seg=True)
-    whole = lambda b, h, i: (b, h, 0, 0)
-    tile = lambda b, h, i: (b, h, i, 0)
+    whole = lambda b, h, i, *_: (b, h, 0, 0)
+    tile = lambda b, h, i, *_: (b, h, i, 0)
     return pl.pallas_call(
-        fn, grid=(B, 1, s // block),
-        in_specs=[pl.BlockSpec((1, 1, block, hd), tile),
-                  pl.BlockSpec((1, 1, s, hd), whole),
-                  pl.BlockSpec((1, 1, s, hd), whole),
-                  pl.BlockSpec((1, block, 1), lambda b, h, i: (b, i, 0)),
-                  pl.BlockSpec((1, 1, s), lambda b, h, i: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, 1, block, hd), tile),
-                   pl.BlockSpec((1, 1, block, 1), tile)],
+        fn, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, 1, s // block),
+            in_specs=[pl.BlockSpec((1, 1, block, hd), tile),
+                      pl.BlockSpec((1, 1, s, hd), whole),
+                      pl.BlockSpec((1, 1, s, hd), whole),
+                      pl.BlockSpec((1, block, 1),
+                                   lambda b, h, i, *_: (b, i, 0)),
+                      pl.BlockSpec((1, 1, s), lambda b, h, i, *_: (b, 0, 0))],
+            out_specs=[pl.BlockSpec((1, 1, block, hd), tile),
+                       pl.BlockSpec((1, 1, block, 1), tile)]),
         out_shape=[jax.ShapeDtypeStruct((B, 1, s, hd), q.dtype),
                    jax.ShapeDtypeStruct((B, 1, s, 1), jnp.float32)],
-    )(q[:, None], k[:, None], v[:, None], seg_q[:, :, None],
-      seg_k[:, None, :])
+    )(jnp.zeros((B, s // block), jnp.int32), q[:, None], k[:, None],
+      v[:, None], seg_q[:, :, None], seg_k[:, None, :])
 
 
 def test_a_row_that_sees_nothing_at_all_is_zero(interpret_pallas):
@@ -430,7 +445,8 @@ def test_a_row_that_sees_nothing_at_all_is_zero(interpret_pallas):
     seg_k = _segments(S, True)
     seg_q = jnp.where(jnp.arange(S)[None] % 5 == 0, 7, seg_k)
     new = _forward_kernel_alone(dsf._fwd_kernel, q, k, v, seg_q, seg_k, BQ)
-    old = _forward_kernel_alone(_fwd_kernel, q, k, v, seg_q, seg_k, BQ)
+    old = _forward_kernel_alone(_by_position_alone(_fwd_kernel), q, k, v,
+                                seg_q, seg_k, BQ)
     for a, b in zip(new, old):
         np.testing.assert_array_equal(a, b)
     blind = np.asarray(seg_q[0] == 7)

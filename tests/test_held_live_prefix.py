@@ -479,8 +479,10 @@ def test_a_step_with_no_held_plan_lowers_to_the_parents_text(
     digests at parent and change), and both again at PR 69, whose loss
     takes the hidden state and the head and forms no whole logits
     (``models/model.py head_token_loss``; tests/flash_step_texts.py says
-    which digests and why); the held families' entries stay PR 39's
-    parent's,
+    which digests and why), and at PR 71, whose packed flash calls bound
+    their tile loops by the documents too
+    (tests/test_flash_document_skip.py); the held families' entries stay
+    PR 39's parent's,
     which the test below holds them to having left."""
     from tests import flash_step_texts
     want = _parents_digests()[
